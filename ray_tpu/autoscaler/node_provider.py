@@ -68,7 +68,6 @@ class LocalNodeProvider(NodeProvider):
                "--gcs", self.gcs_address, "--node-id", node_id,
                "--resources", json.dumps(node_type.resources)]
         env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
         env.update(self._env)
         proc = subprocess.Popen(cmd, stdout=subprocess.PIPE,
                                 stderr=subprocess.DEVNULL, env=env)

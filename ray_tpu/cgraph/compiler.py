@@ -3,7 +3,7 @@
 Reference equivalent: `ray/dag/compiled_dag_node.py` (`experimental_compile`)
 — Ray's accelerated DAG. `dag.execute()` walks the lazy graph submitting a
 fresh task per node per call, paying submission, GCS, and scheduling cost
-every time; measured here that is ~1 ms/node (BENCH_r05). Compilation
+every time; measured at ~1 ms/node (round 5, CPU dev box). Compilation
 removes all of it for graphs whose *shape* is static:
 
 1. topologically sort the bound DAG of actor-method nodes;

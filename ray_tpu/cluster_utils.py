@@ -55,7 +55,6 @@ class Cluster:
         if object_store_memory:
             cmd += ["--object-store-memory", str(object_store_memory)]
         child_env = dict(os.environ)
-        child_env.setdefault("JAX_PLATFORMS", "cpu")
         if self._supervisor is not None:
             child_env["RAY_TPU_LOG_DIR"] = self._supervisor.log_dir
         child_env.update(env or {})

@@ -530,6 +530,8 @@ class ClusterRuntime:
         self._actor_seq_lock = threading.Lock()
         self._raylet_clients: Dict[str, RpcClient] = {self.raylet_address:
                                                       self._raylet}
+        self._worker_clients: Dict[str, RpcClient] = {}
+        self._dial_locks: Dict[str, asyncio.Lock] = {}
         self._actors: Dict[str, _ActorState] = {}
         self._actor_meta: Dict[str, Tuple[str, dict]] = {}
         self._fn = FunctionManager(
@@ -3249,7 +3251,7 @@ class ClusterRuntime:
                     # connection state so wedges are diagnosable.
                     started = worker.get("push_started", now)
                     if now - started > 30.0:
-                        client = (self._worker_clients or {}).get(
+                        client = self._worker_clients.get(
                             worker.get("worker_address"))
                         logger.warning(
                             "lease %s: push of %r in flight for %.0fs "
@@ -3367,27 +3369,34 @@ class ClusterRuntime:
                        address, last)
 
     # -- clients -------------------------------------------------------
+    async def _cached_client(self, cache: Dict[str, RpcClient],
+                             address: str,
+                             connect_timeout: float) -> RpcClient:
+        """The one live client for `address`. Concurrent first callers
+        share one dial: a second client would replace the first in the
+        cache, and the loop holds an unreferenced client's read task
+        only weakly, so it is collected with its calls still pending
+        and their replies are never read."""
+        client = cache.get(address)
+        if client is not None and client.connected:
+            return client
+        lock = self._dial_locks.setdefault(address, asyncio.Lock())
+        async with lock:
+            client = cache.get(address)   # may have changed while we waited
+            if client is None or not client.connected:
+                client = RpcClient(address)
+                await client.connect(timeout=connect_timeout)
+                cache[address] = client
+        return client
+
     async def _raylet_client(self, address: str,
                              connect_timeout: float = 10.0) -> RpcClient:
-        client = self._raylet_clients.get(address)
-        if client is None or not client.connected:
-            client = RpcClient(address)
-            await client.connect(timeout=connect_timeout)
-            self._raylet_clients[address] = client
-        return client
-
-    _worker_client_cache: Dict[str, RpcClient]
+        return await self._cached_client(self._raylet_clients, address,
+                                         connect_timeout)
 
     async def _worker_client(self, address: str) -> RpcClient:
-        cache = getattr(self, "_worker_clients", None)
-        if cache is None:
-            cache = self._worker_clients = {}
-        client = cache.get(address)
-        if client is None or not client.connected:
-            client = RpcClient(address)
-            await client.connect(timeout=10.0)
-            cache[address] = client
-        return client
+        return await self._cached_client(self._worker_clients, address,
+                                         10.0)
 
     # ==================================================================
     # actors (reference: actor lifecycle gcs_actor_manager.h:251, direct
@@ -4884,12 +4893,11 @@ class ClusterRuntime:
         """Isolate this worker process to its granted TPU chips (reference:
         accelerators/tpu.py:214). Must run before user code imports jax."""
         if chips:
-            from ray_tpu.core.jax_platform import enable_host_platform
+            from ray_tpu.core.jax_platform import claim_chip_platform
             from ray_tpu.parallel.tpu import visible_chip_env
 
             os.environ.update(visible_chip_env(chips))
-            # Undo the worker-default CPU pin: this worker owns chips now.
-            enable_host_platform()
+            claim_chip_platform()
 
     async def handle_actor_init(self, conn: ServerConnection, *,
                                 actor_id: str, cls_key: str, args: bytes,

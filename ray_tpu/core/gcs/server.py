@@ -629,8 +629,19 @@ class GcsServer:
         cfg = ray_config()
         period = cfg.health_check_period_ms / 1000.0
         threshold = cfg.health_check_failure_threshold
+        woke = time.monotonic()
         while True:
             await asyncio.sleep(period)
+            overslept = time.monotonic() - woke - period
+            woke += period + overslept
+            if overslept > period:
+                # This process did not run for that long (a loaded or
+                # frozen host: opening a TPU client pauses every process
+                # of a sandboxed VM for seconds). Heartbeats sent
+                # meanwhile are still queued behind this wake-up, so the
+                # silence is ours, not the nodes': credit it.
+                for node_id in self._heartbeats:
+                    self._heartbeats[node_id] += overslept
             if (self.replication is not None and self.replication.active
                     and not self.replication.is_leader()):
                 # Followers see no heartbeats (those are leader-gated):

@@ -99,17 +99,6 @@ class NodeSupervisor:
     def _child_env(self) -> dict:
         env = dict(os.environ)
         env["RAY_TPU_LOG_DIR"] = self.log_dir
-        # Capture the host's ambient platform FIRST so TPU-leased workers
-        # can restore it (jax_platform.enable_host_platform), then default
-        # children to CPU: workers must not grab the TPU chip the driver
-        # may be using, nor spend seconds initializing a TPU runtime per
-        # process. (Env alone is advisory — site PJRT plugins may ignore
-        # it; the authoritative pin is jax_platform.pin_worker_platform in
-        # worker_main.)
-        from ray_tpu.core.jax_platform import HOST_ENV
-
-        env.setdefault(HOST_ENV, env.get("JAX_PLATFORMS", ""))
-        env.setdefault("JAX_PLATFORMS", "cpu")
         return env
 
     def _spawn(self, name: str, cmd, pattern: str) -> str:

@@ -980,6 +980,9 @@ class Raylet(NodeLedger):
         worker_id = uuid.uuid4().hex
         env = dict(os.environ)
         env["RAY_TPU_NODE_ID"] = self.node_id
+        # A chip belongs to one process: workers stay on the CPU until a
+        # lease grants them chips (jax_platform.claim_chip_platform).
+        env["JAX_PLATFORMS"] = "cpu"
         # Unbuffered stdio: a task's print() must reach the log file (and
         # the driver, via the log monitor) while the task runs, not when
         # the worker exits.
@@ -1005,16 +1008,24 @@ class Raylet(NodeLedger):
         return worker
 
     async def _monitor_worker(self, worker: _Worker) -> None:
+        retiring_since = None
         while worker.proc.poll() is None:
             await asyncio.sleep(0.2)
+            if worker.state == "dead" and worker.held:
+                # Retired while still holding chips: its lease resources
+                # wait for the exit, so the exit must come.
+                now = time.monotonic()
+                retiring_since = retiring_since or now
+                if now - retiring_since > 5.0:
+                    worker.proc.kill()
         code = worker.proc.returncode
+        if worker.held:
+            self._release_lease_resources(worker)
+            self._try_dispatch()
         if worker.state != "dead":
             worker.state = "dead"
             if worker in self._idle:
                 self._idle.remove(worker)
-            if worker.held:
-                self._release_lease_resources(worker)
-                self._try_dispatch()
             if worker.actor_id:
                 try:
                     await self._gcs.update_actor(worker.actor_id, {
@@ -1613,8 +1624,15 @@ class Raylet(NodeLedger):
             # A worker that held TPU chips cannot be reused: libtpu pins
             # chip visibility at first jax init, so a recycled process
             # would silently compute on its OLD chips while the raylet
-            # leases them to someone else. Retire it instead.
-            had_chips = bool(worker.chip_ids)
+            # leases them to someone else. Retire it instead — and keep
+            # its chips out of the pool until the process has exited
+            # (_monitor_worker releases them): libtpu in the next holder
+            # cannot open a chip the old process still owns.
+            if worker.chip_ids and worker.proc.poll() is None:
+                worker.lease_id = None
+                worker.state = "dead"
+                worker.proc.terminate()
+                return
             if worker.ring_attached:
                 # The lease came back while a dispatch ring is still
                 # attached (the driver died, or its detach was lost):
@@ -1627,7 +1645,7 @@ class Raylet(NodeLedger):
             # lease holds — not the client's view.
             self._release_lease_resources(worker)
             worker.lease_id = None
-            if dead or had_chips or worker.proc.poll() is not None:
+            if dead or worker.proc.poll() is not None:
                 worker.state = "dead"
                 if worker.proc.poll() is None:
                     worker.proc.terminate()

@@ -43,13 +43,6 @@ def main() -> None:
 
     faulthandler.register(signal.SIGUSR1, all_threads=True, chain=False)
 
-    # Task workers must not initialize the host's TPU runtime unless their
-    # lease grants chips (site PJRT plugins ignore JAX_PLATFORMS, so this
-    # is a config-level pin applied lazily at jax import).
-    from ray_tpu.core.jax_platform import pin_worker_platform
-
-    pin_worker_platform()
-
     from ray_tpu.core.cluster_runtime import ClusterRuntime
     from ray_tpu.core.worker import set_runtime
 

@@ -21,7 +21,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu.ops.attention import attention
 from ray_tpu.ops.moe import moe_ffn
@@ -175,9 +175,8 @@ def _layer(x, lp, cfg: TransformerConfig, mesh, manual_sp, cos, sin,
     q = apply_rotary(q, cos, sin, pos)
     k = apply_rotary(k, cos, sin, pos)
     if mesh is not None and not manual_sp:
-        from ray_tpu.util.jax_compat import with_sharding_constraint
-        qkv_spec = P("dp", "sp", "tp", None)
-        q, k, v = (with_sharding_constraint(t, mesh, qkv_spec)
+        qkv_sharding = NamedSharding(mesh, P("dp", "sp", "tp", None))
+        q, k, v = (jax.lax.with_sharding_constraint(t, qkv_sharding)
                    for t in (q, k, v))
     o = attention(q, k, v, causal=True, mesh=mesh, positions=positions,
                   manual_sp=manual_sp)
@@ -196,8 +195,8 @@ def _layer(x, lp, cfg: TransformerConfig, mesh, manual_sp, cos, sin,
         aux = jnp.zeros((), jnp.float32)
     x = x + ff
     if mesh is not None and not manual_sp:
-        from ray_tpu.util.jax_compat import with_sharding_constraint
-        x = with_sharding_constraint(x, mesh, P("dp", "sp", None))
+        x = jax.lax.with_sharding_constraint(
+            x, NamedSharding(mesh, P("dp", "sp", None)))
     return x, aux
 
 
@@ -208,8 +207,8 @@ def backbone(params: Params, tokens: jax.Array, cfg: TransformerConfig,
     act = cfg.dtype
     x = jnp.take(params["embed"], tokens, axis=0).astype(act)
     if mesh is not None:
-        from ray_tpu.util.jax_compat import with_sharding_constraint
-        x = with_sharding_constraint(x, mesh, P("dp", "sp", None))
+        x = jax.lax.with_sharding_constraint(
+            x, NamedSharding(mesh, P("dp", "sp", None)))
     cos, sin = rotary_freqs(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
 
     def scan_body(carry, lp):

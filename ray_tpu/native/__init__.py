@@ -6,13 +6,17 @@ store (reference: `src/ray/object_manager/plasma/`, `store.cc`).
 
 The shared library is built on demand with g++ (no pybind11 in the image;
 plain C ABI + ctypes keeps the binding dependency-free) and cached next to
-the source; callers fall back to the pure-Python implementation when the
-toolchain is unavailable (`native_store_lib() is None`).
+the source under a name that carries a hash of the source, so a copied
+tree cannot load a library built from other code; callers fall back to
+the pure-Python implementation when the toolchain is unavailable
+(`native_store_lib() is None`).
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import logging
 import os
 import subprocess
@@ -22,16 +26,23 @@ logger = logging.getLogger(__name__)
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "store.cc")
-_LIB = os.path.join(_DIR, "libray_tpu_store.so")
+_LIB_PREFIX = os.path.join(_DIR, "libray_tpu_store.")
 
 _lock = threading.Lock()
 _lib = None
 _build_failed = False
 
 
-def _build() -> bool:
+def _lib_path() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return f"{_LIB_PREFIX}{digest}.so"
+
+
+def _build(lib_path: str) -> bool:
+    tmp = f"{lib_path}.{os.getpid()}.tmp"   # processes may build at once
     cmd = ["g++", "-O2", "-std=c++17", "-fPIC", "-shared", "-o",
-           _LIB + ".tmp", _SRC, "-lrt", "-pthread"]
+           tmp, _SRC, "-lrt", "-pthread"]
     try:
         proc = subprocess.run(cmd, capture_output=True, text=True,
                               timeout=120)
@@ -41,7 +52,13 @@ def _build() -> bool:
     if proc.returncode != 0:
         logger.warning("native store build failed:\n%s", proc.stderr[-2000:])
         return False
-    os.replace(_LIB + ".tmp", _LIB)
+    os.replace(tmp, lib_path)
+    for stale in glob.glob(_LIB_PREFIX + "*so"):
+        if stale != lib_path:
+            try:
+                os.remove(stale)
+            except OSError:
+                pass   # another process got there first
     return True
 
 
@@ -91,13 +108,12 @@ def native_store_lib():
             return _lib
         if _build_failed:
             return None
-        stale = (not os.path.exists(_LIB)
-                 or os.path.getmtime(_LIB) < os.path.getmtime(_SRC))
-        if stale and not _build():
+        lib_path = _lib_path()
+        if not os.path.exists(lib_path) and not _build(lib_path):
             _build_failed = True
             return None
         try:
-            _lib = _bind(ctypes.CDLL(_LIB))
+            _lib = _bind(ctypes.CDLL(lib_path))
         except OSError as exc:
             logger.warning("native store load failed: %s", exc)
             _build_failed = True

@@ -25,6 +25,15 @@ from jax.sharding import PartitionSpec as P
 _NEG_INF = -1e30
 
 
+def flash_block(seq_len: int, block: int = 1024) -> int:
+    """Largest lane-aligned block that divides the sequence (the kernel
+    requires seq_len % block == 0). 1024 measured fastest on v5e at
+    S=1024/hd=128 (fwd+bwd 10.26 ms vs 10.51 at 512, 13.12 for XLA
+    attention; .scratch sweep, round 5)."""
+    return next(b for b in (block, 512, 384, 256, 128)
+                if b <= seq_len and seq_len % b == 0)
+
+
 def flash_attention_tpu(q, k, v, *, causal: bool = True,
                         block: int = 1024):
     """Fused flash attention on TPU via the Pallas MHA kernel shipped with
@@ -36,12 +45,7 @@ def flash_attention_tpu(q, k, v, *, causal: bool = True,
     from jax.experimental.pallas.ops.tpu.flash_attention import (
         BlockSizes, flash_attention)
 
-    s = q.shape[1]
-    # Largest lane-aligned block that divides S (kernel requires s % blk == 0).
-    # 1024 measured fastest on v5e at S=1024/hd=128 (fwd+bwd 10.26 ms vs
-    # 10.51 at 512, 13.12 for XLA attention; .scratch sweep, round 5).
-    blk = next(b for b in (block, 512, 384, 256, 128)
-               if b <= s and s % b == 0)
+    blk = flash_block(q.shape[1], block)
     sizes = BlockSizes(
         block_q=blk, block_k_major=blk, block_k=blk, block_b=1,
         block_q_major_dkv=blk, block_k_major_dkv=blk, block_k_dkv=blk,
@@ -81,9 +85,7 @@ def ring_attention_manual(q, k, v, q_pos, *, axis_name: str = "sp",
     """Manual-collective ring attention body. Must run inside a shard_map
     where `axis_name` is a manual axis. q/k/v: local blocks [B, S_loc, H, D];
     q_pos: [S_loc] global positions of the local block."""
-    from ray_tpu.util.jax_compat import axis_size as _axis_size
-
-    axis_size = _axis_size(axis_name)
+    axis_size = jax.lax.axis_size(axis_name)
     b, s_loc, h, d = q.shape
     scale = d ** -0.5
     perm = [(j, (j + 1) % axis_size) for j in range(axis_size)]
@@ -124,14 +126,12 @@ def ring_attention(q, k, v, *, mesh, axis_name: str = "sp",
                    causal: bool = True, positions=None):
     """Sequence-parallel attention: shard_map manual over `axis_name` only;
     batch/head axes stay under the automatic (GSPMD) partitioner."""
-    from ray_tpu.util.jax_compat import shard_map
-
     if positions is None:
         positions = jnp.arange(q.shape[1])
     spec = P(None, axis_name, None, None)
     body = functools.partial(ring_attention_manual, axis_name=axis_name,
                              causal=causal)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=(spec, spec, spec, P(axis_name)),
         out_specs=spec, axis_names={axis_name}, check_vma=False,
     )(q, k, v, positions)

@@ -10,9 +10,9 @@ The framework's standard mesh axes (SURVEY.md §2.5, §7.6):
 - ``sp``  — sequence/context parallel (ring attention over ICI neighbors).
 - ``tp``  — tensor parallel (Megatron-style row/col sharding).
 
-On real hardware the mesh should follow the physical topology
-(`jax.experimental.mesh_utils.create_device_mesh` does this); on CPU test
-backends we reshape the flat device list.
+On real hardware the mesh follows the physical topology
+(`jax.experimental.mesh_utils.create_device_mesh`, whose failure is an
+error); on CPU test backends we reshape the flat device list.
 """
 
 from __future__ import annotations
@@ -76,11 +76,13 @@ def make_mesh(shape: Optional[Sequence[int]] = None,
     shape = tuple(shape)
     if int(np.prod(shape)) != n:
         raise ValueError(f"mesh shape {shape} != {n} devices")
-    try:
-        from jax.experimental import mesh_utils
-        arr = mesh_utils.create_device_mesh(shape, devices=devices)
-    except Exception:
+    if devices[0].platform == "cpu":
+        # CPU devices have no topology to follow.
         arr = np.array(devices).reshape(shape)
+    else:
+        from jax.experimental import mesh_utils
+
+        arr = mesh_utils.create_device_mesh(shape, devices=devices)
     return Mesh(arr, tuple(axis_names))
 
 
@@ -154,29 +156,18 @@ def make_hybrid_mesh(shape: Optional[Sequence[int]] = None, *,
             f"per-slice shape dp/slices x pp x sp x tp = "
             f"{dp // n_slices}x{pp}x{sp}x{tp} != {per_slice} "
             f"devices per slice")
-    try:
-        from jax.experimental import mesh_utils
-
-        arr = mesh_utils.create_hybrid_device_mesh(
-            (dp // n_slices, pp, sp, tp), (n_slices, 1, 1, 1),
-            devices=devices)
-    except Exception:
-        if any(getattr(d, "platform", None) == "tpu" for d in devices):
-            # On real hardware the id-sorted fallback has no ICI-topology
-            # awareness — collectives may land on non-adjacent chips.
-            # Run, but say so loudly instead of silently losing
-            # bandwidth.
-            import logging
-
-            logging.getLogger(__name__).warning(
-                "create_hybrid_device_mesh failed on TPU devices; "
-                "falling back to id-order layout (suboptimal ICI "
-                "placement)", exc_info=True)
-        # Manual fallback (CPU test backends): slice-major ordering, dp
-        # split into (slice, dp_inner) then flattened so slice is the
+    if devices[0].platform == "cpu":
+        # CPU devices have no topology to follow: slice-major ordering,
+        # dp split into (slice, dp_inner) then flattened so slice is the
         # OUTER dp factor.
         ordered = [d for sid in sorted(by_slice)
                    for d in sorted(by_slice[sid], key=lambda d: d.id)]
         arr = np.array(ordered).reshape(
             n_slices, dp // n_slices, pp, sp, tp).reshape(dp, pp, sp, tp)
+    else:
+        from jax.experimental import mesh_utils
+
+        arr = mesh_utils.create_hybrid_device_mesh(
+            (dp // n_slices, pp, sp, tp), (n_slices, 1, 1, 1),
+            devices=devices)
     return Mesh(arr, tuple(axis_names))

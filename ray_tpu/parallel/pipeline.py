@@ -26,9 +26,7 @@ def _gpipe_body(stage_params, x, positions, consts, *, stage_fn,
     x: [B, S(loc), D] activations (batch global/auto over dp); positions:
     [S(loc)] global positions; consts: replicated loop-invariant arrays
     (e.g. rotary tables) passed through to stage_fn."""
-    from ray_tpu.util.jax_compat import axis_size
-
-    n_stages = axis_size(axis)
+    n_stages = lax.axis_size(axis)
     rank = lax.axis_index(axis)
     stage_p = jax.tree.map(lambda a: jnp.squeeze(a, 0), stage_params)
 
@@ -86,8 +84,6 @@ def gpipe(stage_fn: Callable, stage_params, x, positions, consts=(), *,
     {pp, sp} — inside, the sequence dim is the local sp block and attention
     must use `ring_attention_manual`.
     """
-    from ray_tpu.util.jax_compat import shard_map
-
     manual = {pp_axis}
     sp_in_mesh = sp_axis in mesh.axis_names and mesh.shape[sp_axis] > 1
     if sp_in_mesh:
@@ -106,7 +102,7 @@ def gpipe(stage_fn: Callable, stage_params, x, positions, consts=(), *,
     body = functools.partial(
         _gpipe_body, stage_fn=stage_fn, axis=pp_axis,
         n_micro=num_microbatches)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(p_specs, x_spec, pos_spec, const_specs),
         out_specs=(x_spec, P()),

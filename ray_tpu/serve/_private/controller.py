@@ -364,7 +364,10 @@ class ServeController:
             deficit = 1 if not starting else 0
         for _ in range(max(deficit, 0)):
             try:
-                self._start_replica(state)
+                # Actor creation returns once the constructor has run,
+                # and a replica that loads a model takes a while: off
+                # the loop, so status() and routing stay answerable.
+                await asyncio.to_thread(self._start_replica, state)
             except Exception:
                 # Constructor failed synchronously (user __init__ error):
                 # back off one tick instead of crash-looping hot.

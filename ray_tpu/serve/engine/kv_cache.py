@@ -143,12 +143,10 @@ class KVCacheManager:
         self.block_size = int(block_size)
         self.kv_shape = tuple(kv_shape)
         if device_pool and array_ns is None:
-            try:
-                import jax.numpy as jnp
+            # Asked for a device pool: get one or raise.
+            import jax.numpy as jnp
 
-                array_ns = jnp
-            except Exception:  # jax unavailable: degrade to host pool
-                array_ns = np
+            array_ns = jnp
         self._ns = array_ns if array_ns is not None else np
         self._device = self._ns is not np
         self._dtype = dtype

@@ -37,6 +37,7 @@ instead of admissions being rejected.
 from __future__ import annotations
 
 import itertools
+import logging
 import threading
 import time
 from collections import deque
@@ -48,6 +49,9 @@ import numpy as np
 from ray_tpu.core import attribution, flight
 from ray_tpu.serve.engine.kv_cache import CacheOverflowError, KVCacheManager
 from ray_tpu.serve.engine.prefix_index import PrefixIndex
+
+
+logger = logging.getLogger(__name__)
 
 
 class EngineOverloadedError(RuntimeError):
@@ -74,8 +78,7 @@ class EngineConfig:
     replica_tag: str = ""          # fleet identity (metrics/digests)
     # Paged decode (PR 20): read KV inside the model's compiled step
     # through block tables instead of host-gathering per sequence.
-    # Requires a model with `supports_paged`; falls back to the
-    # host-gather loop otherwise. `device_pool=None` follows
+    # Requires a model with `supports_paged`. `device_pool=None` follows
     # `paged_decode` (a paged engine wants the pool device-resident so
     # the in-jit gather is zero-copy); set explicitly to mix modes.
     paged_decode: bool = False
@@ -215,8 +218,11 @@ class InferenceEngine:
         self.model = model
         self.config = config or EngineConfig()
         kv_shape = tuple(getattr(model, "kv_token_shape", ()))
-        self.paged = bool(self.config.paged_decode
-                          and getattr(model, "supports_paged", False))
+        self.paged = bool(self.config.paged_decode)
+        if self.paged and not getattr(model, "supports_paged", False):
+            raise ValueError(
+                f"paged_decode=True needs a model with supports_paged; "
+                f"{type(model).__name__} has none")
         device_pool = self.config.device_pool
         if device_pool is None:
             device_pool = self.paged
@@ -413,6 +419,8 @@ class InferenceEngine:
         try:
             self._decode_once(batch)
         except Exception as e:  # noqa: BLE001 — the loop must survive
+            logger.exception("decode step failed; failing %d stream(s)",
+                             len(batch))
             for seq in batch:
                 self._retire(seq, error=e)
         self.steps += 1
@@ -463,6 +471,7 @@ class InferenceEngine:
                              # seq is requeued; let the batch make
                              # progress before re-trying admission
             except Exception as e:  # noqa: BLE001
+                logger.exception("prefill of %s failed", seq.seq_id)
                 self.cache.free(seq.seq_id)
                 seq.stream._finish(e)
 
@@ -701,7 +710,10 @@ class InferenceEngine:
             while not self._stop.is_set():
                 try:
                     worked = self.step()
-                except Exception:  # noqa: BLE001 — belt and braces
+                except Exception as e:  # noqa: BLE001 — loop must survive
+                    logger.exception("engine step failed; failing "
+                                     "in-flight streams")
+                    self._fail_in_flight(e)
                     worked = False
                 if not worked:
                     self._work.wait(timeout=0.05)
@@ -717,14 +729,18 @@ class InferenceEngine:
         if self._thread is not None:
             self._thread.join(timeout=timeout_s)
             self._thread = None
-        # Fail whatever is still in flight so consumers unblock.
+        self._fail_in_flight(EngineStoppedError("engine stopped"))
+
+    def _fail_in_flight(self, error: BaseException) -> None:
+        """Finish every running and waiting stream with `error`, so
+        consumers unblock and see it."""
         with self._lock:
             leftovers = list(self._running) + list(self._waiting)
             self._running.clear()
             self._waiting.clear()
         for seq in leftovers:
             self.cache.free(seq.seq_id)
-            seq.stream._finish(EngineStoppedError("engine stopped"))
+            seq.stream._finish(error)
 
     def drain(self, timeout_s: float = 30.0) -> bool:
         """Block until no work remains (tests / graceful shutdown)."""

@@ -30,7 +30,6 @@ class TrainWorker:
             "node_id": ctx.get_node_id(),
             "pid": os.getpid(),
             "hostname": socket.gethostname(),
-            "ip": socket.gethostbyname(socket.gethostname()),
         }
 
     def execute(self, fn: Callable, *args: Any, **kwargs: Any) -> Any:
@@ -40,13 +39,6 @@ class TrainWorker:
     def ping(self) -> bool:
         """Liveness probe used by the executor while results are pending."""
         return True
-
-    def free_port(self) -> str:
-        s = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
-        s.bind(("", 0))
-        port = s.getsockname()[1]
-        s.close()
-        return f"{socket.gethostbyname(socket.gethostname())}:{port}"
 
     # -- training loop --------------------------------------------------
     def start_training(self, train_fn, config: Optional[dict],

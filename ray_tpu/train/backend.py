@@ -35,9 +35,13 @@ class BackendConfig:
 def _setup_jax_distributed(coordinator: str, world_size: int, rank: int,
                            platform: Optional[str],
                            cpu_devices_per_worker: Optional[int]) -> bool:
-    """Runs in each training worker BEFORE any JAX backend is touched."""
+    """Runs in each training worker before the gang uses JAX:
+    `jax.distributed.initialize` must precede the backend that uses it,
+    and a process opens its TPU client once."""
     import os
 
+    if platform is None:
+        platform = _leased_platform()
     if cpu_devices_per_worker and cpu_devices_per_worker > 1:
         flags = os.environ.get("XLA_FLAGS", "")
         if "xla_force_host_platform_device_count" not in flags:
@@ -46,66 +50,42 @@ def _setup_jax_distributed(coordinator: str, world_size: int, rank: int,
                 f"{cpu_devices_per_worker}").strip()
 
     import jax
+    import jax.extend.backend
 
-    if platform == "cpu" or (platform is None and not _has_tpu()):
+    # A pooled worker may have run JAX on the CPU for an earlier task,
+    # and the distributed client must exist before the backend that
+    # uses it.
+    jax.extend.backend.clear_backends()
+    if platform == "cpu":
         # Cross-process CPU collectives need the gloo transport
         # (the CPU analogue of the ICI fabric used on real slices).
         jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        # A preloaded jax may have pinned a different default platform
-        # regardless of JAX_PLATFORMS; the default backend decides
-        # process_count() inside jax array APIs, so pin it to cpu.
-        jax.config.update("jax_platforms", "cpu")
-        platform = "cpu"
-    else:
-        # A training worker targeting real chips must first undo the
-        # worker-default CPU pin (jax_platform.pin_worker_platform).
-        from ray_tpu.core.jax_platform import enable_host_platform
-
-        enable_host_platform()
-    try:
-        jax.distributed.initialize(
-            coordinator_address=coordinator,
-            num_processes=world_size, process_id=rank)
-    except RuntimeError as e:
-        if "already" in str(e).lower():
-            jax.distributed.shutdown()
-            jax.distributed.initialize(
-                coordinator_address=coordinator,
-                num_processes=world_size, process_id=rank)
-        else:
-            raise
-    # The process may have initialized device clients BEFORE distributed
-    # state existed (e.g. an eager import touching jax.devices()), freezing
-    # num_nodes=1. Drop them so the next backend lookup is rebuilt with the
-    # distributed world in place.
-    try:
-        from jax._src import xla_bridge as _xb
-
-        if _xb.backends_are_initialized():
-            _xb._clear_backends()
-    except Exception:
-        pass
+    # Explicit, so a platform that cannot be opened raises below instead
+    # of falling through to another one.
+    jax.config.update("jax_platforms", platform)
+    jax.distributed.initialize(
+        coordinator_address=coordinator,
+        num_processes=world_size, process_id=rank)
     got = jax.process_count(platform)
-    assert got == world_size, f"jax world size {got} != {world_size}"
+    if got != world_size:
+        raise RuntimeError(f"jax world size {got} != {world_size}")
     return True
+
+
+def _leased_platform() -> str:
+    """The platform of a worker whose `JaxConfig` names none: what it was
+    leased decides (`_apply_visible_chips` sets the variable for a chip
+    lease), not what the host looks like."""
+    import os
+
+    return "tpu" if os.environ.get("TPU_VISIBLE_CHIPS") else "cpu"
 
 
 def _teardown_jax_distributed() -> bool:
     import jax
 
-    try:
-        jax.distributed.shutdown()
-    except Exception:
-        pass
+    jax.distributed.shutdown()
     return True
-
-
-def _has_tpu() -> bool:
-    import os
-
-    return (os.environ.get("TPU_NAME") is not None
-            or os.path.exists("/dev/accel0")
-            or os.environ.get("JAX_PLATFORMS", "") not in ("", "cpu"))
 
 
 @dataclass
@@ -113,7 +93,8 @@ class JaxConfig(BackendConfig):
     """Backend config for JAX SPMD training.
 
     platform: "cpu" to force the CPU backend (tests / CI without chips),
-        "tpu" for real slices, None = autodetect.
+        "tpu" for real slices, None = "tpu" on a worker that was leased
+        chips (`ScalingConfig(use_tpu=True)`) and "cpu" otherwise.
     cpu_devices_per_worker: virtual host devices per worker process when
         on CPU (`xla_force_host_platform_device_count`).
     """
@@ -128,7 +109,11 @@ class JaxConfig(BackendConfig):
 
 class _JaxBackend(Backend):
     def on_start(self, worker_group, backend_config: JaxConfig) -> None:
-        coordinator = worker_group.execute_single(0, _free_port_on_worker)
+        # A gang on one node meets on loopback: a sealed machine may not
+        # resolve or route its own hostname.
+        one_node = len({m["node_id"] for m in worker_group.metadata}) == 1
+        coordinator = worker_group.execute_single(
+            0, _free_port_on_worker, one_node)
         n = len(worker_group)
         import ray_tpu
 
@@ -147,11 +132,13 @@ class _JaxBackend(Backend):
             pass
 
 
-def _free_port_on_worker() -> str:
+def _free_port_on_worker(loopback: bool) -> str:
     import socket
 
     s = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
     s.bind(("", 0))
     port = s.getsockname()[1]
     s.close()
-    return f"{socket.gethostbyname(socket.gethostname())}:{port}"
+    host = ("127.0.0.1" if loopback
+            else socket.gethostbyname(socket.gethostname()))
+    return f"{host}:{port}"

@@ -18,6 +18,8 @@ host RAM, but the code path (donation, in-jit gather, scatter
 write-back) is exactly what a TPU backend executes.
 """
 
+import threading
+
 import numpy as np
 import pytest
 
@@ -370,3 +372,61 @@ def test_engine_stats_surface_pool_and_phase_fields():
     for key in ("kv_gather_s", "model_step_s", "kv_write_s",
                 "jit_bucket_evictions"):
         assert key in st
+
+
+# ---------------------------------------------------------------------------
+# asked for the device path: get it or raise
+# ---------------------------------------------------------------------------
+def test_device_pool_without_jax_raises_not_degrades(monkeypatch):
+    """`device_pool=True` used to fall back to a numpy pool when jax
+    could not be imported; a serving replica would then run off the
+    device without a word."""
+    import sys
+
+    monkeypatch.setitem(sys.modules, "jax.numpy", None)  # import fails
+    with pytest.raises(ImportError):
+        KVCacheManager(num_blocks=4, block_size=4, kv_shape=KV,
+                       device_pool=True)
+
+
+def test_paged_decode_on_model_without_paged_support_raises():
+    class HostOnlyLM(TinyLM):
+        supports_paged = False
+
+    with pytest.raises(ValueError, match="supports_paged"):
+        InferenceEngine(HostOnlyLM(), EngineConfig(paged_decode=True))
+    # The host-gather loop still takes such a model.
+    assert not InferenceEngine(HostOnlyLM(), EngineConfig()).paged
+
+
+def test_step_failure_outside_decode_fails_streams_and_is_logged(caplog):
+    """An exception out of `step()` (a compile error on the chip, say)
+    used to be swallowed by the hosting loop: streams hung forever."""
+    eng = InferenceEngine(TinyLM(vocab_size=32), EngineConfig(
+        max_batch_size=2, block_size=4, num_blocks=16))
+
+    def boom():
+        raise RuntimeError("xla said no")
+
+    eng._ensure_capacity = boom
+    stream = eng.submit([3, 4, 5], 4)
+    seen = []
+
+    def consume():
+        try:
+            seen.extend(stream)
+        except RuntimeError as e:
+            seen.append(e)
+
+    consumer = threading.Thread(target=consume, daemon=True)
+    with caplog.at_level("ERROR"):
+        eng.start()
+        try:
+            consumer.start()
+            consumer.join(timeout=10)
+        finally:
+            eng.stop()
+    assert not consumer.is_alive(), "the stream hung"
+    assert isinstance(seen[-1], RuntimeError) and "xla said no" in str(
+        seen[-1]), seen
+    assert any("engine step failed" in r.message for r in caplog.records)

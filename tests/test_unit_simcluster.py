@@ -591,6 +591,55 @@ def test_health_loop_rescues_created_group_on_silently_dead_node():
     _run(scenario())
 
 
+def test_health_loop_does_not_charge_nodes_for_its_own_stall():
+    """Opening a TPU client froze every process of the chip machine for
+    8 s; the GCS woke, read the silence as five missed heartbeats, and
+    reaped a healthy node's actors. Time the health loop did not run is
+    credited to the nodes; a node that is really gone still dies."""
+    import time
+
+    from ray_tpu.core.simcluster import SimCluster
+
+    async def scenario():
+        cluster = SimCluster(num_nodes=4, seed=3)
+        await cluster.start()
+        try:
+            assert await cluster.wait_until(
+                lambda: cluster.registered_count() == 4, timeout=10)
+            verdicts = []
+            mark_dead = cluster.gcs._mark_node_dead
+
+            async def spy(node_id):
+                verdicts.append(node_id)
+                await mark_dead(node_id)
+
+            cluster.gcs._mark_node_dead = spy
+            # Heartbeats that arrive while the GCS is frozen sit in its
+            # socket until after the health loop's overdue wake-up.
+            thawed = asyncio.Event()
+            heartbeat = cluster.gcs.handle_heartbeat
+
+            async def queued_heartbeat(*args, **kwargs):
+                await thawed.wait()
+                return await heartbeat(*args, **kwargs)
+
+            cluster.gcs.handle_heartbeat = queued_heartbeat
+            time.sleep(1.0)            # the freeze: 3x the death threshold
+            await asyncio.sleep(0.15)  # the health loop runs first
+            thawed.set()
+            await asyncio.sleep(0.3)
+            assert verdicts == []
+            assert cluster.registered_count() == 4
+            victim = next(iter(cluster.raylets))
+            cluster.crash_raylet(victim)
+            assert await cluster.wait_until(
+                lambda: verdicts == [victim], timeout=10), verdicts
+        finally:
+            await cluster.stop()
+
+    _run(scenario())
+
+
 def test_reconstruction_degrades_to_typed_errors():
     """Exhausted budget and disabled retention keep today's typed
     failures: max_retries=0 (or lineage_reconstruction=False) objects
