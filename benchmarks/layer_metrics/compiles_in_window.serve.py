@@ -1,0 +1,7 @@
+"""Model step, compiles: programs compiled or fetched from the persistent
+cache in the replica during the window (JAX's monitoring event). Must
+read 0."""
+
+
+def read(ctx):
+    return ctx["counters"].get("compiles")

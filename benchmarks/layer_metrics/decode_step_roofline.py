@@ -1,0 +1,24 @@
+"""Kernels, serve: the least time one decode step could take over the
+time the device was busy in one. Bytes a step must read (every float32
+weight once, the live KV of its rows; `flops.decode_step_bytes`) over the
+chip's HBM bandwidth, against the device-busy time inside the
+benchmark's `decode_step` spans, per span, in the traced window. Decode
+is bound by bytes; the FLOP side is taken too and the larger one used."""
+
+from benchmarks.harness import flops
+
+
+def read(ctx):
+    trace, counters, peak = ctx["trace"], ctx["trace_counters"], ctx["peak"]
+    if not trace or not counters or not peak:
+        return None
+    span = trace["spans"].get("decode_step")
+    steps = counters.get("decode_steps")
+    if not span or not steps or not span["device_busy_s"]:
+        return None
+    live = counters["decode_live_tokens"] / steps
+    rows = counters["decode_rows"] / steps
+    least = flops.roofline_seconds(
+        flops.decode_step_flops(ctx["widths"], rows, live),
+        flops.decode_step_bytes(ctx["widths"], live), peak)
+    return 100.0 * least / (span["device_busy_s"] / span["count"])

@@ -1,0 +1,9 @@
+"""Device, serve: share of the traced window in which no operation ran on
+the chip."""
+
+
+def read(ctx):
+    trace = ctx["trace"]
+    if not trace:
+        return None
+    return 100.0 * (1.0 - trace["busy_s"] / trace["window_s"])
