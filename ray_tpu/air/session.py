@@ -24,6 +24,7 @@ import time
 from typing import Any, Dict, Iterator, Optional
 
 from ray_tpu.air.checkpoint import Checkpoint
+from ray_tpu.core import flight
 
 _session_lock = threading.Lock()
 _session: Optional["_TrainSession"] = None
@@ -71,11 +72,11 @@ class _TimedIter:
         return self
 
     def __next__(self):
-        t0 = time.perf_counter()
-        try:
+        # One span feeds the flight ring, a running profile
+        # (`rt:train.data_wait`) and the step's data-wait telemetry.
+        with flight.span("train", "data_wait", None, self._session._waits,
+                         "data_wait_s"):
             return next(self._it)
-        finally:
-            self._session._data_wait_s += time.perf_counter() - t0
 
 
 class _TimedShard:
@@ -139,7 +140,9 @@ class _TrainSession:
         self.stop_requested = False
         # -- step telemetry (reset at each report boundary) -------------
         self._step_t0 = time.perf_counter()
-        self._data_wait_s = 0.0
+        # Fed by `_TimedIter`'s span: stands still with the flight
+        # recorder off, and the step's wait then reads as compute.
+        self._waits = {"data_wait_s": 0.0}
         self._collective_s = 0.0
         self.last_telemetry: Optional[Dict[str, float]] = None
         # -- jax.profiler step capture (TrainConfig(profile_steps)) -----
@@ -172,7 +175,15 @@ class _TrainSession:
                     base, self.trial_name or "default",
                     f"rank{self.world_rank}")
                 os.makedirs(trace_dir, exist_ok=True)
-                jax.profiler.start_trace(trace_dir)
+                # Python tracer off: it records every Python call (half
+                # a million events in eight seconds of serving, PERF.md)
+                # and slows the host it observes; the program's own
+                # `flight.span`s (`rt:...`) say what it was there for.
+                options = jax.profiler.ProfileOptions()
+                options.python_tracer_level = 0
+                options.host_tracer_level = 2
+                jax.profiler.start_trace(trace_dir,
+                                         profiler_options=options)
                 self._profiling = True
                 self._profile_trace_dir = trace_dir
             elif self._profiling and self._steps_completed >= b:
@@ -213,7 +224,7 @@ class _TrainSession:
 
     def _close_step(self) -> Dict[str, float]:
         step_wall = max(0.0, time.perf_counter() - self._step_t0)
-        data_wait = min(self._data_wait_s, step_wall)
+        data_wait = min(self._waits["data_wait_s"], step_wall)
         collective = min(self._collective_s, step_wall - data_wait)
         telemetry = {
             "step_time_s": step_wall,
@@ -230,7 +241,7 @@ class _TrainSession:
                 hists[kind].observe(telemetry[f"{kind}_s"], tags=tags)
         except Exception:
             pass  # telemetry must never fail a training step
-        self._data_wait_s = 0.0
+        self._waits["data_wait_s"] = 0.0
         self._collective_s = 0.0
         self._steps_completed += 1
         self._maybe_profile()
@@ -238,12 +249,17 @@ class _TrainSession:
 
     def report(self, metrics: Dict[str, Any],
                checkpoint: Optional[Checkpoint] = None) -> None:
-        telemetry = self._close_step()
-        self.result_queue.put({"type": "report", "metrics": dict(metrics),
-                               "checkpoint": checkpoint,
-                               "telemetry": telemetry})
-        self.continue_event.wait()
-        self.continue_event.clear()
+        # `rt:train.report` in a profile; its self time is the session's
+        # own work, `train.report.wait` the executor's turn.
+        with flight.span("train", "report", self._steps_completed + 1):
+            telemetry = self._close_step()
+            self.result_queue.put({"type": "report",
+                                   "metrics": dict(metrics),
+                                   "checkpoint": checkpoint,
+                                   "telemetry": telemetry})
+            with flight.span("train", "report.wait"):
+                self.continue_event.wait()
+            self.continue_event.clear()
         # The next step starts when the executor releases this report.
         self._step_t0 = time.perf_counter()
         if self.stop_requested:

@@ -56,8 +56,19 @@ cost-model-v2 pressure gate), ``lease`` (acquire wait / return),
 producer-ownership migrations, ``busy_poll`` spin windows, and the
 raylet-side ``pin``/``unpin`` instants bracketing a worker's
 ring-attached span), ``gc`` (collector pauses), ``loop`` (heartbeat
-scheduling delays), ``stall`` (finalized episodes), ``engine`` (serve
-decode/prefill steps).
+scheduling delays), ``stall`` (finalized episodes), ``engine`` (the
+serve engine loop's phases), ``model`` (the engine model's host side of
+a prefill or decode call), ``train`` (a trainer loop's data wait and
+report).
+
+5. **Spans.** ``span(category, label)`` is the one way program code
+   times an interval: a context manager that records the interval in
+   the ring and, when JAX is already imported, is also a
+   ``jax.profiler.TraceAnnotation("rt:<category>.<label>")``, so the
+   same interval sits in the profiler's host plane, on the profiler's
+   clock, whenever anyone has a trace running. With ``into=dict,
+   key=str`` its duration is also added to a plain float, from the same
+   two clock reads.
 """
 
 from __future__ import annotations
@@ -153,6 +164,74 @@ def record(category: str, label: str, dur_us: int = 0,
 
 def instant(category: str, label: str, arg: Any = None) -> None:
     record(category, label, 0, arg)
+
+
+# jax.profiler.TraceAnnotation, once JAX is imported in this process.
+# Never imported from here: a process that stays off JAX (the driver of
+# a chip run, the scheduler's module) must not be pulled onto it.
+_annotation: Any = None
+
+
+def _trace_annotation():
+    global _annotation
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    _annotation = getattr(profiler, "TraceAnnotation", None)
+    return _annotation
+
+
+class span:
+    """Time one interval of program code: ``with flight.span("engine",
+    "sample"): ...``. On exit the interval goes into the ring as
+    ``record(category, label, dur_us, arg, t=start)``; if ``into`` is
+    given its duration (seconds) is added to ``into[key]`` from the same
+    two clock reads, and it stays readable as ``.dur``. When JAX is
+    imported the interval is also a profiler ``TraceAnnotation`` named
+    ``rt:<category>.<label>``: free when no trace is running, and in the
+    host plane of whatever trace is.
+
+    ``enabled`` is the only guard: with the recorder off a span reads no
+    clock, records nothing and leaves ``into`` alone (``.dur`` is 0.0).
+    ``arg`` may be set inside the block, for what is only known at its
+    end."""
+
+    __slots__ = ("category", "label", "arg", "into", "key", "dur",
+                 "_t0", "_ann")
+
+    def __init__(self, category: str, label: str, arg: Any = None,
+                 into: Optional[Dict[str, float]] = None,
+                 key: Optional[str] = None):
+        self.category = category
+        self.label = label
+        self.arg = arg
+        self.into = into
+        self.key = key
+        self.dur = 0.0
+        self._t0: Optional[float] = None
+        self._ann = None
+
+    def __enter__(self) -> "span":
+        if not enabled:
+            return self
+        annotation = _annotation or _trace_annotation()
+        if annotation is not None:
+            self._ann = annotation(f"rt:{self.category}.{self.label}")
+            self._ann.__enter__()
+        self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        t0 = self._t0
+        if t0 is None:
+            return False
+        self.dur = dur = time.monotonic() - t0
+        self._t0 = None
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
+        record(self.category, self.label, int(dur * 1e6), self.arg, t=t0)
+        if self.into is not None:
+            self.into[self.key] += dur
+        return False
 
 
 def enable() -> None:

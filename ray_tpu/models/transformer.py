@@ -162,38 +162,40 @@ def _layer(x, lp, cfg: TransformerConfig, mesh, manual_sp, cos, sin,
     h, hd = cfg.n_heads, cfg.head_dim
     act = cfg.dtype
 
-    # -- attention block -----------------------------------------------
-    y = _rmsnorm(x, lp["ln1"])
-    qkv = jnp.einsum("bsd,dkh->kbsh", y, lp["wqkv"].astype(act))
-    qkv = checkpoint_name(qkv, "qkv")
-    q = qkv[0].reshape(b, s, h, hd)
-    k = qkv[1].reshape(b, s, h, hd)
-    v = qkv[2].reshape(b, s, h, hd)
-    # positions=None means "standard arange" — kept None through to
-    # attention() so the fused TPU flash kernel stays eligible.
-    pos = jnp.arange(s) if positions is None else positions
-    q = apply_rotary(q, cos, sin, pos)
-    k = apply_rotary(k, cos, sin, pos)
-    if mesh is not None and not manual_sp:
-        qkv_sharding = NamedSharding(mesh, P("dp", "sp", "tp", None))
-        q, k, v = (jax.lax.with_sharding_constraint(t, qkv_sharding)
-                   for t in (q, k, v))
-    o = attention(q, k, v, causal=True, mesh=mesh, positions=positions,
-                  manual_sp=manual_sp)
-    o = checkpoint_name(o, "attn_out")
-    x = x + (o.reshape(b, s, h * hd) @ lp["wo"].astype(act))
+    # -- attention block (the scopes name the ops in a profile) ---------
+    with jax.named_scope("attn"):
+        y = _rmsnorm(x, lp["ln1"])
+        qkv = jnp.einsum("bsd,dkh->kbsh", y, lp["wqkv"].astype(act))
+        qkv = checkpoint_name(qkv, "qkv")
+        q = qkv[0].reshape(b, s, h, hd)
+        k = qkv[1].reshape(b, s, h, hd)
+        v = qkv[2].reshape(b, s, h, hd)
+        # positions=None means "standard arange" — kept None through to
+        # attention() so the fused TPU flash kernel stays eligible.
+        pos = jnp.arange(s) if positions is None else positions
+        q = apply_rotary(q, cos, sin, pos)
+        k = apply_rotary(k, cos, sin, pos)
+        if mesh is not None and not manual_sp:
+            qkv_sharding = NamedSharding(mesh, P("dp", "sp", "tp", None))
+            q, k, v = (jax.lax.with_sharding_constraint(t, qkv_sharding)
+                       for t in (q, k, v))
+        o = attention(q, k, v, causal=True, mesh=mesh, positions=positions,
+                      manual_sp=manual_sp)
+        o = checkpoint_name(o, "attn_out")
+        x = x + (o.reshape(b, s, h * hd) @ lp["wo"].astype(act))
 
     # -- FFN block ------------------------------------------------------
-    y = _rmsnorm(x, lp["ln2"])
-    if cfg.is_moe:
-        ff, aux = moe_ffn(y, lp["router"], lp["moe_w1"], lp["moe_w2"],
-                          top_k=cfg.moe_top_k,
-                          capacity_factor=cfg.capacity_factor)
-    else:
-        gu = jnp.einsum("bsd,dkf->kbsf", y, lp["w13"].astype(act))
-        ff = (jax.nn.silu(gu[0]) * gu[1]) @ lp["w2"].astype(act)
-        aux = jnp.zeros((), jnp.float32)
-    x = x + ff
+    with jax.named_scope("mlp"):
+        y = _rmsnorm(x, lp["ln2"])
+        if cfg.is_moe:
+            ff, aux = moe_ffn(y, lp["router"], lp["moe_w1"], lp["moe_w2"],
+                              top_k=cfg.moe_top_k,
+                              capacity_factor=cfg.capacity_factor)
+        else:
+            gu = jnp.einsum("bsd,dkf->kbsf", y, lp["w13"].astype(act))
+            ff = (jax.nn.silu(gu[0]) * gu[1]) @ lp["w2"].astype(act)
+            aux = jnp.zeros((), jnp.float32)
+        x = x + ff
     if mesh is not None and not manual_sp:
         x = jax.lax.with_sharding_constraint(
             x, NamedSharding(mesh, P("dp", "sp", None)))
@@ -205,7 +207,8 @@ def backbone(params: Params, tokens: jax.Array, cfg: TransformerConfig,
              ) -> Tuple[jax.Array, jax.Array]:
     """tokens [B,S] int32 -> (final hidden states [B,S,D], aux scalar)."""
     act = cfg.dtype
-    x = jnp.take(params["embed"], tokens, axis=0).astype(act)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0).astype(act)
     if mesh is not None:
         x = jax.lax.with_sharding_constraint(
             x, NamedSharding(mesh, P("dp", "sp", None)))
@@ -232,7 +235,9 @@ def forward(params: Params, tokens: jax.Array, cfg: TransformerConfig,
     # old code materialized (2 GB at B=16,S=1024,V=32k) never exists.
     # einsum instead of `x @ embed.T`: no materialized transpose, XLA
     # picks the contraction layout.
-    logits = jnp.einsum("bsd,vd->bsv", x, params["embed"].astype(cfg.dtype))
+    with jax.named_scope("lm_head"):
+        logits = jnp.einsum("bsd,vd->bsv", x,
+                            params["embed"].astype(cfg.dtype))
     return logits, aux
 
 
@@ -351,8 +356,9 @@ def lm_loss(params: Params, batch: Dict[str, jax.Array],
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
     if cfg.loss_chunk and inputs.shape[1] % cfg.loss_chunk == 0:
         x, aux = backbone(params, inputs, cfg, mesh)
-        loss = _chunked_nll(x, params["embed"].astype(cfg.dtype), targets,
-                            batch.get("mask"), cfg.loss_chunk)
+        with jax.named_scope("lm_head"):
+            loss = _chunked_nll(x, params["embed"].astype(cfg.dtype),
+                                targets, batch.get("mask"), cfg.loss_chunk)
     else:
         logits, aux = forward(params, inputs, cfg, mesh)
         loss = _token_nll(logits, targets, batch.get("mask"))

@@ -49,13 +49,15 @@ def make_train_step(loss_fn: Callable, optimizer,
 
     import optax
 
-    def step(params, opt_state, batch):
+    # The function's name is the device program's: `jit_train_step` in a
+    # profile's module line.
+    def train_step(params, opt_state, batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)
         updates, opt_state = optimizer.update(grads, opt_state, params)
         params = optax.apply_updates(params, updates)
         return params, opt_state, loss
 
-    return jax.jit(step, donate_argnums=(0, 1) if donate else ())
+    return jax.jit(train_step, donate_argnums=(0, 1) if donate else ())
 
 
 def make_eval_step(loss_fn: Callable) -> Callable:

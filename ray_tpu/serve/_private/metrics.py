@@ -129,7 +129,13 @@ def engine_metrics() -> Dict[str, Any]:
                 "Cumulative model time split by phase",
                 # prefill | decode | kv_gather | model_step | kv_write
                 # (the last three split the decode step — paged decode
-                # collapses kv_gather to table padding)
+                # collapses kv_gather to table padding), and the engine
+                # loop's own partition of its wall time
+                # (`InferenceEngine.phase_seconds`): park, reap, admit,
+                # capacity, prefill_match, prefill_kv_write,
+                # prefill_seal, tables, sample, emit, gauges, other,
+                # model_prefill_{prep,dispatch,wait,kv_d2h},
+                # model_decode_{prep,dispatch,wait}
                 tag_keys=("phase",)),
             "kv_pool_bytes": Gauge(
                 "serve_engine_kv_pool_bytes",

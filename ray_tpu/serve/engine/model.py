@@ -40,6 +40,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ray_tpu.core import flight
+
 
 def _next_pow2(n: int) -> int:
     p = 1
@@ -254,6 +256,16 @@ class TransformerEngineModel:
         self.prefill_tokens = 0
         self.decode_calls = 0
         self.jit_compiles = 0
+        # Host side of the calls, in seconds, each fed by its
+        # `flight.span`: input padding and upload (`prep`), the call of
+        # the jitted function (`dispatch`), the logits' arrival on the
+        # host (`wait`: the device's compute shows here), and the
+        # prompt KV's trip to the host. `InferenceEngine.stats()` reads
+        # them as `phase.model_<name>`.
+        self.phase: Dict[str, float] = dict.fromkeys(
+            ("prefill_prep_s", "prefill_dispatch_s", "prefill_wait_s",
+             "prefill_kv_d2h_s", "decode_prep_s", "decode_dispatch_s",
+             "decode_wait_s"), 0.0)
         self._jnp = jnp
 
     @property
@@ -289,49 +301,56 @@ class TransformerEngineModel:
         cfg = self._cfg
         h, hd = cfg.n_heads, cfg.head_dim
 
-        def run(params, tokens, length):
+        def prefill(params, tokens, length):
             # tokens [S_pad] int32 (zero-padded), length scalar int32.
             act = jnp.float32
-            x = params["embed"][tokens].astype(act)[None]   # [1,S,D]
+            with jax.named_scope("embed"):
+                x = params["embed"][tokens].astype(act)[None]   # [1,S,D]
             cos, sin = rotary_freqs(hd, cfg.max_seq_len, cfg.rope_theta)
             pos = jnp.arange(s_pad)
             valid = pos < length
             causal = (pos[:, None] >= pos[None, :]) & valid[None, :]
 
             def layer(x, lp):
-                y = _rmsnorm(x, lp["ln1"])
-                qkv = jnp.einsum("bsd,dkh->kbsh", y,
-                                 lp["wqkv"].astype(act))
-                q = qkv[0].reshape(1, s_pad, h, hd)
-                k = qkv[1].reshape(1, s_pad, h, hd)
-                v = qkv[2].reshape(1, s_pad, h, hd)
-                q = apply_rotary(q, cos, sin, pos)
-                k = apply_rotary(k, cos, sin, pos)
-                scale = hd ** -0.5
-                scores = jnp.einsum(
-                    "bqhd,bkhd->bhqk", q, k,
-                    preferred_element_type=jnp.float32) * scale
-                scores = jnp.where(causal[None, None], scores, -1e30)
-                probs = jax.nn.softmax(scores, axis=-1).astype(act)
-                o = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
-                x = x + (o.reshape(1, s_pad, h * hd)
-                         @ lp["wo"].astype(act))
-                y = _rmsnorm(x, lp["ln2"])
-                gu = jnp.einsum("bsd,dkf->kbsf", y,
-                                lp["w13"].astype(act))
-                x = x + (jax.nn.silu(gu[0]) * gu[1]) @ lp["w2"].astype(act)
+                with jax.named_scope("attn"):
+                    y = _rmsnorm(x, lp["ln1"])
+                    qkv = jnp.einsum("bsd,dkh->kbsh", y,
+                                     lp["wqkv"].astype(act))
+                    q = qkv[0].reshape(1, s_pad, h, hd)
+                    k = qkv[1].reshape(1, s_pad, h, hd)
+                    v = qkv[2].reshape(1, s_pad, h, hd)
+                    q = apply_rotary(q, cos, sin, pos)
+                    k = apply_rotary(k, cos, sin, pos)
+                    scale = hd ** -0.5
+                    scores = jnp.einsum(
+                        "bqhd,bkhd->bhqk", q, k,
+                        preferred_element_type=jnp.float32) * scale
+                    scores = jnp.where(causal[None, None], scores, -1e30)
+                    probs = jax.nn.softmax(scores, axis=-1).astype(act)
+                    o = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+                    x = x + (o.reshape(1, s_pad, h * hd)
+                             @ lp["wo"].astype(act))
+                with jax.named_scope("mlp"):
+                    y = _rmsnorm(x, lp["ln2"])
+                    gu = jnp.einsum("bsd,dkf->kbsf", y,
+                                    lp["w13"].astype(act))
+                    x = x + ((jax.nn.silu(gu[0]) * gu[1])
+                             @ lp["w2"].astype(act))
                 kv = jnp.stack([k[0], v[0]], axis=1)  # [S, 2, H, hd]
                 return x, kv
 
             x, kvs = jax.lax.scan(layer, x, params["layers"])
-            x = _rmsnorm(x, params["ln_f"])
-            last = x[0, length - 1]
-            logits = jnp.einsum("d,vd->v", last,
-                                params["embed"].astype(act))
+            with jax.named_scope("lm_head"):
+                x = _rmsnorm(x, params["ln_f"])
+                last = x[0, length - 1]
+                logits = jnp.einsum("d,vd->v", last,
+                                    params["embed"].astype(act))
             # kvs [L, S, 2, H, hd] -> [S, L, 2, H, hd]
             return logits, kvs.transpose(1, 0, 2, 3, 4)
 
-        return jax.jit(run)
+        # The function's name is the device program's: `jit_prefill` in
+        # a profile's module line.
+        return jax.jit(prefill)
 
     def _prefill_cached_math(self, params, tail_tokens, p_len, t_len,
                              prefix, t_pad: int, p_pad: int):
@@ -351,7 +370,8 @@ class TransformerEngineModel:
         h, hd = cfg.n_heads, cfg.head_dim
 
         act = jnp.float32
-        x = params["embed"][tail_tokens].astype(act)[None]  # [1,T,D]
+        with jax.named_scope("embed"):
+            x = params["embed"][tail_tokens].astype(act)[None]  # [1,T,D]
         cos, sin = rotary_freqs(hd, cfg.max_seq_len, cfg.rope_theta)
         tpos = p_len + jnp.arange(t_pad)      # absolute positions
         tail_valid = jnp.arange(t_pad) < t_len
@@ -363,47 +383,51 @@ class TransformerEngineModel:
 
         def layer(x, inputs):
             lp, pkv = inputs               # pkv [P, 2, H, hd]
-            y = _rmsnorm(x, lp["ln1"])
-            qkv = jnp.einsum("bsd,dkh->kbsh", y,
-                             lp["wqkv"].astype(act))
-            q = qkv[0].reshape(1, t_pad, h, hd)
-            k = qkv[1].reshape(1, t_pad, h, hd)
-            v = qkv[2].reshape(1, t_pad, h, hd)
-            q = apply_rotary(q, cos, sin, tpos)
-            k = apply_rotary(k, cos, sin, tpos)
-            pk = pkv[None, :, 0]           # [1, P, H, hd]
-            pv = pkv[None, :, 1]
-            scale = hd ** -0.5
-            sc_p = jnp.einsum(
-                "bqhd,bkhd->bhqk", q, pk,
-                preferred_element_type=jnp.float32) * scale
-            sc_t = jnp.einsum(
-                "bqhd,bkhd->bhqk", q, k,
-                preferred_element_type=jnp.float32) * scale
-            sc_p = jnp.where(pref_valid[None, None, None, :],
-                             sc_p, -1e30)
-            sc_t = jnp.where(causal_tt[None, None], sc_t, -1e30)
-            probs = jax.nn.softmax(
-                jnp.concatenate([sc_p, sc_t], axis=-1),
-                axis=-1).astype(act)
-            o = (jnp.einsum("bhqk,bkhd->bqhd",
-                            probs[..., :p_pad], pv)
-                 + jnp.einsum("bhqk,bkhd->bqhd",
-                              probs[..., p_pad:], v))
-            x = x + (o.reshape(1, t_pad, h * hd)
-                     @ lp["wo"].astype(act))
-            y = _rmsnorm(x, lp["ln2"])
-            gu = jnp.einsum("bsd,dkf->kbsf", y,
-                            lp["w13"].astype(act))
-            x = x + (jax.nn.silu(gu[0]) * gu[1]) @ lp["w2"].astype(act)
+            with jax.named_scope("attn"):
+                y = _rmsnorm(x, lp["ln1"])
+                qkv = jnp.einsum("bsd,dkh->kbsh", y,
+                                 lp["wqkv"].astype(act))
+                q = qkv[0].reshape(1, t_pad, h, hd)
+                k = qkv[1].reshape(1, t_pad, h, hd)
+                v = qkv[2].reshape(1, t_pad, h, hd)
+                q = apply_rotary(q, cos, sin, tpos)
+                k = apply_rotary(k, cos, sin, tpos)
+                pk = pkv[None, :, 0]           # [1, P, H, hd]
+                pv = pkv[None, :, 1]
+                scale = hd ** -0.5
+                sc_p = jnp.einsum(
+                    "bqhd,bkhd->bhqk", q, pk,
+                    preferred_element_type=jnp.float32) * scale
+                sc_t = jnp.einsum(
+                    "bqhd,bkhd->bhqk", q, k,
+                    preferred_element_type=jnp.float32) * scale
+                sc_p = jnp.where(pref_valid[None, None, None, :],
+                                 sc_p, -1e30)
+                sc_t = jnp.where(causal_tt[None, None], sc_t, -1e30)
+                probs = jax.nn.softmax(
+                    jnp.concatenate([sc_p, sc_t], axis=-1),
+                    axis=-1).astype(act)
+                o = (jnp.einsum("bhqk,bkhd->bqhd",
+                                probs[..., :p_pad], pv)
+                     + jnp.einsum("bhqk,bkhd->bqhd",
+                                  probs[..., p_pad:], v))
+                x = x + (o.reshape(1, t_pad, h * hd)
+                         @ lp["wo"].astype(act))
+            with jax.named_scope("mlp"):
+                y = _rmsnorm(x, lp["ln2"])
+                gu = jnp.einsum("bsd,dkf->kbsf", y,
+                                lp["w13"].astype(act))
+                x = x + ((jax.nn.silu(gu[0]) * gu[1])
+                         @ lp["w2"].astype(act))
             kv = jnp.stack([k[0], v[0]], axis=1)   # [T, 2, H, hd]
             return x, kv
 
         x, kvs = jax.lax.scan(layer, x, (params["layers"], prefix_l))
-        x = _rmsnorm(x, params["ln_f"])
-        last = x[0, t_len - 1]
-        logits = jnp.einsum("d,vd->v", last,
-                            params["embed"].astype(act))
+        with jax.named_scope("lm_head"):
+            x = _rmsnorm(x, params["ln_f"])
+            last = x[0, t_len - 1]
+            logits = jnp.einsum("d,vd->v", last,
+                                params["embed"].astype(act))
         # kvs [L, T, 2, H, hd] -> [T, L, 2, H, hd]
         return logits, kvs.transpose(1, 0, 2, 3, 4)
 
@@ -414,11 +438,11 @@ class TransformerEngineModel:
 
         self.jit_compiles += 1
 
-        def run(params, tail_tokens, p_len, t_len, prefix):
+        def prefill_cached(params, tail_tokens, p_len, t_len, prefix):
             return self._prefill_cached_math(
                 params, tail_tokens, p_len, t_len, prefix, t_pad, p_pad)
 
-        return jax.jit(run)
+        return jax.jit(prefill_cached)
 
     def _build_prefill_paged(self, t_pad: int, nbp_pad: int,
                              block_size: int):
@@ -434,15 +458,16 @@ class TransformerEngineModel:
         p_pad = nbp_pad * block_size
         kv_shape = self.kv_token_shape
 
-        def run(params, tail_tokens, p_len, t_len, pool, table):
+        def prefill_paged(params, tail_tokens, p_len, t_len, pool, table):
             # table [nbp_pad] int32, zero-padded (block 0 gathers are
             # masked by pref_valid). pool [N, bs, L, 2, H, hd].
-            prefix = jnp.take(pool, table, axis=0).reshape(
-                (p_pad,) + kv_shape).astype(jnp.float32)
+            with jax.named_scope("kv_gather"):
+                prefix = jnp.take(pool, table, axis=0).reshape(
+                    (p_pad,) + kv_shape).astype(jnp.float32)
             return self._prefill_cached_math(
                 params, tail_tokens, p_len, t_len, prefix, t_pad, p_pad)
 
-        return jax.jit(run)
+        return jax.jit(prefill_paged)
 
     def _decode_math(self, params, tokens, positions, cache,
                      b_pad: int, s_pad: int):
@@ -463,7 +488,8 @@ class TransformerEngineModel:
 
         # tokens [B], positions [B], cache [B, S_pad, L, 2, H, hd].
         act = jnp.float32
-        x = params["embed"][tokens].astype(act)       # [B, D]
+        with jax.named_scope("embed"):
+            x = params["embed"][tokens].astype(act)       # [B, D]
         cos, sin = rotary_freqs(hd, cfg.max_seq_len, cfg.rope_theta)
         slot = (jnp.arange(s_pad)[None, :]
                 == positions[:, None])[:, :, None, None]   # [B,S,1,1]
@@ -473,33 +499,37 @@ class TransformerEngineModel:
 
         def layer(x, inputs):
             lp, kv_l = inputs          # kv_l [B, S, 2, H, hd]
-            y = _rmsnorm(x, lp["ln1"])
-            qkv = jnp.einsum("bd,dkh->kbh", y,
-                             lp["wqkv"].astype(act))
-            q = qkv[0].reshape(b_pad, h, hd)
-            k = qkv[1].reshape(b_pad, h, hd)
-            v = qkv[2].reshape(b_pad, h, hd)
-            q = rot1(q, cos, sin, positions)
-            k = rot1(k, cos, sin, positions)
-            keys = jnp.where(slot, k[:, None], kv_l[:, :, 0])
-            vals = jnp.where(slot, v[:, None], kv_l[:, :, 1])
-            scale = hd ** -0.5
-            scores = jnp.einsum(
-                "bhd,bshd->bhs", q, keys,
-                preferred_element_type=jnp.float32) * scale
-            scores = jnp.where(attend[:, None, :], scores, -1e30)
-            probs = jax.nn.softmax(scores, axis=-1).astype(act)
-            o = jnp.einsum("bhs,bshd->bhd", probs, vals)
-            x = x + o.reshape(b_pad, h * hd) @ lp["wo"].astype(act)
-            y = _rmsnorm(x, lp["ln2"])
-            gu = jnp.einsum("bd,dkf->kbf", y, lp["w13"].astype(act))
-            x = x + (jax.nn.silu(gu[0]) * gu[1]) @ lp["w2"].astype(act)
+            with jax.named_scope("attn"):
+                y = _rmsnorm(x, lp["ln1"])
+                qkv = jnp.einsum("bd,dkh->kbh", y,
+                                 lp["wqkv"].astype(act))
+                q = qkv[0].reshape(b_pad, h, hd)
+                k = qkv[1].reshape(b_pad, h, hd)
+                v = qkv[2].reshape(b_pad, h, hd)
+                q = rot1(q, cos, sin, positions)
+                k = rot1(k, cos, sin, positions)
+                keys = jnp.where(slot, k[:, None], kv_l[:, :, 0])
+                vals = jnp.where(slot, v[:, None], kv_l[:, :, 1])
+                scale = hd ** -0.5
+                scores = jnp.einsum(
+                    "bhd,bshd->bhs", q, keys,
+                    preferred_element_type=jnp.float32) * scale
+                scores = jnp.where(attend[:, None, :], scores, -1e30)
+                probs = jax.nn.softmax(scores, axis=-1).astype(act)
+                o = jnp.einsum("bhs,bshd->bhd", probs, vals)
+                x = x + o.reshape(b_pad, h * hd) @ lp["wo"].astype(act)
+            with jax.named_scope("mlp"):
+                y = _rmsnorm(x, lp["ln2"])
+                gu = jnp.einsum("bd,dkf->kbf", y, lp["w13"].astype(act))
+                x = x + ((jax.nn.silu(gu[0]) * gu[1])
+                         @ lp["w2"].astype(act))
             return x, jnp.stack([k, v], axis=1)   # [B, 2, H, hd]
 
         x, new_kv = jax.lax.scan(layer, x, (params["layers"], cache))
-        x = _rmsnorm(x, params["ln_f"])
-        logits = jnp.einsum("bd,vd->bv", x,
-                            params["embed"].astype(act))
+        with jax.named_scope("lm_head"):
+            x = _rmsnorm(x, params["ln_f"])
+            logits = jnp.einsum("bd,vd->bv", x,
+                                params["embed"].astype(act))
         # new_kv [L, B, 2, H, hd] -> [B, L, 2, H, hd]
         return logits, new_kv.transpose(1, 0, 2, 3, 4)
 
@@ -508,11 +538,11 @@ class TransformerEngineModel:
 
         self.jit_compiles += 1
 
-        def run(params, tokens, positions, cache):
+        def decode(params, tokens, positions, cache):
             return self._decode_math(params, tokens, positions, cache,
                                      b_pad, s_pad)
 
-        return jax.jit(run)
+        return jax.jit(decode)
 
     def _build_decode_paged(self, b_pad: int, nb_pad: int,
                             block_size: int):
@@ -532,55 +562,74 @@ class TransformerEngineModel:
         s_pad = nb_pad * block_size
         kv_shape = self.kv_token_shape
 
-        def run(pool, params, tokens, positions, tables, wblocks, woffs):
+        def decode_paged(pool, params, tokens, positions, tables, wblocks,
+                         woffs):
             # tables [b_pad, nb_pad] int32, zero-padded (rows past the
             # batch and blocks past a row's coverage gather block 0;
             # `attend`/`slot` in the core mask the garbage). wblocks
             # padding rows point past the pool, so mode="drop" skips
             # them — dummy batch rows never touch real blocks.
-            flat = jnp.take(pool, tables.reshape(-1), axis=0)
-            cache = flat.reshape(
-                (b_pad, s_pad) + kv_shape).astype(jnp.float32)
+            with jax.named_scope("kv_gather"):
+                flat = jnp.take(pool, tables.reshape(-1), axis=0)
+                cache = flat.reshape(
+                    (b_pad, s_pad) + kv_shape).astype(jnp.float32)
             logits, new_kv = self._decode_math(
                 params, tokens, positions, cache, b_pad, s_pad)
-            new_pool = pool.at[wblocks, woffs].set(
-                new_kv.astype(pool.dtype), mode="drop")
+            with jax.named_scope("kv_write"):
+                new_pool = pool.at[wblocks, woffs].set(
+                    new_kv.astype(pool.dtype), mode="drop")
             return logits, new_pool
 
-        return jax.jit(run, donate_argnums=0)
+        return jax.jit(decode_paged, donate_argnums=0)
 
     # -- engine interface ----------------------------------------------
     def prefill(self, tokens: Sequence[int], prefix_kv=None):
-        jnp = self._jnp
+        with flight.span("model", "prefill", len(tokens)):
+            return self._prefill(tokens, prefix_kv)
+
+    def _prefill(self, tokens: Sequence[int], prefix_kv):
+        jnp, phase = self._jnp, self.phase
         self.prefill_calls += 1
         n = len(tokens)
         p = 0 if prefix_kv is None else int(np.asarray(prefix_kv).shape[0])
-        self.prefill_tokens += n - p
-        if p == 0:
-            s_pad = _next_pow2(max(n, 8))
-            fn = self._prefill_jit.get(s_pad)
-            if fn is None:
-                fn = self._prefill_jit[s_pad] = self._build_prefill(s_pad)
-            padded = np.zeros((s_pad,), np.int32)
-            padded[:n] = np.asarray(tokens, np.int32)
-            logits, kv = fn(self._params, jnp.asarray(padded),
-                            jnp.int32(n))
-            return np.asarray(logits), np.asarray(kv[:n])
         t = n - p
-        t_pad = _next_pow2(max(t, 8))
-        p_pad = _next_pow2(max(p, 8))
-        key = (t_pad, p_pad)
-        fn = self._prefill_cached_jit.get(key)
-        if fn is None:
-            fn = self._prefill_cached_jit[key] = \
-                self._build_prefill_cached(*key)
-        tail = np.zeros((t_pad,), np.int32)
-        tail[:t] = np.asarray(tokens[p:], np.int32)
-        cache = np.zeros((p_pad,) + self.kv_token_shape, np.float32)
-        cache[:p] = np.asarray(prefix_kv)
-        logits, kv = fn(self._params, jnp.asarray(tail), jnp.int32(p),
-                        jnp.int32(t), jnp.asarray(cache))
-        return np.asarray(logits), np.asarray(kv[:t])
+        self.prefill_tokens += t
+        with flight.span("model", "prefill.prep", None, phase,
+                         "prefill_prep_s"):
+            if p == 0:
+                s_pad = _next_pow2(max(n, 8))
+                fn = self._prefill_jit.get(s_pad)
+                if fn is None:
+                    fn = self._prefill_jit[s_pad] = \
+                        self._build_prefill(s_pad)
+                padded = np.zeros((s_pad,), np.int32)
+                padded[:n] = np.asarray(tokens, np.int32)
+                args = (jnp.asarray(padded), jnp.int32(n))
+            else:
+                t_pad = _next_pow2(max(t, 8))
+                p_pad = _next_pow2(max(p, 8))
+                key = (t_pad, p_pad)
+                fn = self._prefill_cached_jit.get(key)
+                if fn is None:
+                    fn = self._prefill_cached_jit[key] = \
+                        self._build_prefill_cached(*key)
+                tail = np.zeros((t_pad,), np.int32)
+                tail[:t] = np.asarray(tokens[p:], np.int32)
+                cache = np.zeros((p_pad,) + self.kv_token_shape,
+                                 np.float32)
+                cache[:p] = np.asarray(prefix_kv)
+                args = (jnp.asarray(tail), jnp.int32(p), jnp.int32(t),
+                        jnp.asarray(cache))
+        with flight.span("model", "prefill.dispatch", None, phase,
+                         "prefill_dispatch_s"):
+            logits, kv = fn(self._params, *args)
+        with flight.span("model", "prefill.logits_wait", None, phase,
+                         "prefill_wait_s"):
+            logits = np.asarray(logits)
+        with flight.span("model", "prefill.kv_d2h", None, phase,
+                         "prefill_kv_d2h_s"):
+            kv = np.asarray(kv[:t])
+        return logits, kv
 
     def decode(self, kvs: List[np.ndarray], last_tokens: Sequence[int],
                positions: Sequence[int]):
@@ -624,7 +673,6 @@ class TransformerEngineModel:
         `write_blocks` may be shorter than the batch; missing rows (and
         batch padding rows) scatter past the pool and are dropped, so
         an empty write list is a read-only step."""
-        jnp = self._jnp
         b = len(last_tokens)
         if isinstance(pool, np.ndarray):
             # Host-resident pool with paged tables: gather on host
@@ -640,33 +688,49 @@ class TransformerEngineModel:
             for i in range(min(len(write_blocks), b)):
                 pool[write_blocks[i], write_offs[i]] = new_kv[i]
             return logits, pool
+        with flight.span("model", "decode", b):
+            return self._decode_paged(pool, block_tables, last_tokens,
+                                      positions, write_blocks, write_offs,
+                                      block_size)
+
+    def _decode_paged(self, pool, block_tables, last_tokens, positions,
+                      write_blocks, write_offs, block_size: int):
+        jnp, phase = self._jnp, self.phase
+        b = len(last_tokens)
         self.decode_calls += 1
-        b_pad = _next_pow2(max(b, 1))
-        nb = max(int(p) // block_size + 1 for p in positions)
-        nb_pad = _next_pow2(max(nb, 1))
-        key = (b_pad, nb_pad, block_size)
-        fn = self._decode_paged_jit.get(key)
-        if fn is None:
-            fn = self._decode_paged_jit[key] = \
-                self._build_decode_paged(*key)
-        num_blocks = int(pool.shape[0])
-        tables = np.zeros((b_pad, nb_pad), np.int32)
-        toks = np.zeros((b_pad,), np.int32)
-        poss = np.zeros((b_pad,), np.int32)
-        wb = np.full((b_pad,), num_blocks, np.int32)   # default: drop
-        wo = np.zeros((b_pad,), np.int32)
-        for i in range(b):
-            row = np.asarray(block_tables[i][:nb_pad], np.int32)
-            tables[i, :row.shape[0]] = row
-            toks[i] = int(last_tokens[i])
-            poss[i] = int(positions[i])
-        k = min(len(write_blocks), b)
-        wb[:k] = np.asarray(write_blocks[:k], np.int32)
-        wo[:k] = np.asarray(write_offs[:k], np.int32)
-        logits, new_pool = fn(pool, self._params, jnp.asarray(toks),
-                              jnp.asarray(poss), jnp.asarray(tables),
-                              jnp.asarray(wb), jnp.asarray(wo))
-        return np.asarray(logits)[:b], new_pool
+        with flight.span("model", "decode.prep", None, phase,
+                         "decode_prep_s"):
+            b_pad = _next_pow2(max(b, 1))
+            nb = max(int(p) // block_size + 1 for p in positions)
+            nb_pad = _next_pow2(max(nb, 1))
+            key = (b_pad, nb_pad, block_size)
+            fn = self._decode_paged_jit.get(key)
+            if fn is None:
+                fn = self._decode_paged_jit[key] = \
+                    self._build_decode_paged(*key)
+            num_blocks = int(pool.shape[0])
+            tables = np.zeros((b_pad, nb_pad), np.int32)
+            toks = np.zeros((b_pad,), np.int32)
+            poss = np.zeros((b_pad,), np.int32)
+            wb = np.full((b_pad,), num_blocks, np.int32)  # default: drop
+            wo = np.zeros((b_pad,), np.int32)
+            for i in range(b):
+                row = np.asarray(block_tables[i][:nb_pad], np.int32)
+                tables[i, :row.shape[0]] = row
+                toks[i] = int(last_tokens[i])
+                poss[i] = int(positions[i])
+            k = min(len(write_blocks), b)
+            wb[:k] = np.asarray(write_blocks[:k], np.int32)
+            wo[:k] = np.asarray(write_offs[:k], np.int32)
+            args = (jnp.asarray(toks), jnp.asarray(poss),
+                    jnp.asarray(tables), jnp.asarray(wb), jnp.asarray(wo))
+        with flight.span("model", "decode.dispatch", None, phase,
+                         "decode_dispatch_s"):
+            logits, new_pool = fn(pool, self._params, *args)
+        with flight.span("model", "decode.logits_wait", None, phase,
+                         "decode_wait_s"):
+            logits = np.asarray(logits)[:b]
+        return logits, new_pool
 
     def prefill_paged(self, tokens: Sequence[int], pool,
                       block_table: Sequence[int], prefix_len: int,
@@ -675,24 +739,37 @@ class TransformerEngineModel:
         the device pool inside the jit. Returns host logits plus the
         tail KV as a DEVICE array [tail, *kv_token_shape] for
         `write_range`."""
-        jnp = self._jnp
+        with flight.span("model", "prefill", len(tokens)):
+            return self._prefill_paged(tokens, pool, block_table,
+                                       int(prefix_len), block_size)
+
+    def _prefill_paged(self, tokens, pool, block_table, p: int,
+                       block_size: int):
+        jnp, phase = self._jnp, self.phase
         self.prefill_calls += 1
-        n = len(tokens)
-        p = int(prefix_len)
-        t = n - p
+        t = len(tokens) - p
         self.prefill_tokens += t
-        t_pad = _next_pow2(max(t, 8))
-        nbp = (p + block_size - 1) // block_size
-        nbp_pad = _next_pow2(max(nbp, 1))
-        key = (t_pad, nbp_pad, block_size)
-        fn = self._prefill_paged_jit.get(key)
-        if fn is None:
-            fn = self._prefill_paged_jit[key] = \
-                self._build_prefill_paged(*key)
-        tail = np.zeros((t_pad,), np.int32)
-        tail[:t] = np.asarray(tokens[p:], np.int32)
-        table = np.zeros((nbp_pad,), np.int32)
-        table[:nbp] = np.asarray(block_table[:nbp], np.int32)
-        logits, kv = fn(self._params, jnp.asarray(tail), jnp.int32(p),
-                        jnp.int32(t), pool, jnp.asarray(table))
-        return np.asarray(logits), kv[:t]
+        with flight.span("model", "prefill.prep", None, phase,
+                         "prefill_prep_s"):
+            t_pad = _next_pow2(max(t, 8))
+            nbp = (p + block_size - 1) // block_size
+            nbp_pad = _next_pow2(max(nbp, 1))
+            key = (t_pad, nbp_pad, block_size)
+            fn = self._prefill_paged_jit.get(key)
+            if fn is None:
+                fn = self._prefill_paged_jit[key] = \
+                    self._build_prefill_paged(*key)
+            tail = np.zeros((t_pad,), np.int32)
+            tail[:t] = np.asarray(tokens[p:], np.int32)
+            table = np.zeros((nbp_pad,), np.int32)
+            table[:nbp] = np.asarray(block_table[:nbp], np.int32)
+            args = (jnp.asarray(tail), jnp.int32(p), jnp.int32(t))
+            table = jnp.asarray(table)
+        with flight.span("model", "prefill.dispatch", None, phase,
+                         "prefill_dispatch_s"):
+            logits, kv = fn(self._params, *args, pool, table)
+            kv = kv[:t]             # stays on the device
+        with flight.span("model", "prefill.logits_wait", None, phase,
+                         "prefill_wait_s"):
+            logits = np.asarray(logits)
+        return logits, kv

@@ -46,12 +46,28 @@ from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
-from ray_tpu.core import attribution, flight
+from ray_tpu.core import flight
 from ray_tpu.serve.engine.kv_cache import CacheOverflowError, KVCacheManager
 from ray_tpu.serve.engine.prefix_index import PrefixIndex
+from ray_tpu.util import tracing
 
 
 logger = logging.getLogger(__name__)
+
+# Where the loop's wall time goes, as `stats()["phase.<name>_s"]`: every
+# instant of the loop lies in one of these. All but `park` and `other`
+# are leaf spans inside `engine.step`; `other` is what a step spent in no
+# leaf. `tables` is the `kv_gather_s` clock under its paged name.
+_STEP_PHASES = ("reap", "admit", "capacity", "prefill_match",
+                "prefill_kv_write", "prefill_seal", "sample", "emit",
+                "gauges")
+# The model's host side of a call, kept by the model in its own `phase`
+# dict (`TransformerEngineModel`); a model without one reads 0.0.
+_MODEL_PHASES = ("prefill_prep", "prefill_dispatch", "prefill_wait",
+                 "prefill_kv_d2h", "decode_prep", "decode_dispatch",
+                 "decode_wait")
+# Clocks the engine had before the phases, still read under these names.
+_LEGACY_CLOCKS = ("prefill_s", "kv_gather_s", "model_step_s", "kv_write_s")
 
 
 class EngineOverloadedError(RuntimeError):
@@ -91,10 +107,16 @@ class TokenStream:
     asynchronously (`async for tok in stream`) — both see tokens as they
     are produced, so time-to-first-token decouples from completion."""
 
-    def __init__(self, request_id: str):
+    def __init__(self, request_id: str,
+                 clocks: Optional[Dict[str, float]] = None):
         self.request_id = request_id
         self._lock = threading.Lock()
         self._tokens: List[int] = []
+        # Push stamp of each token (perf_counter; 0.0 with the flight
+        # recorder off), and the engine's clocks that the consumer adds
+        # pickup - push to: the engine -> replica half of the stream hop.
+        self._stamps: List[float] = []
+        self._clocks = clocks
         self._done = False
         self._error: Optional[BaseException] = None
         self._waiters: List = []   # threading.Event | (loop, aio.Event)
@@ -103,8 +125,10 @@ class TokenStream:
 
     # -- producer (engine loop) ----------------------------------------
     def _push(self, token: int) -> None:
+        stamp = time.perf_counter() if flight.enabled else 0.0
         with self._lock:
             self._tokens.append(token)
+            self._stamps.append(stamp)
             waiters, self._waiters = self._waiters, []
         self._wake(waiters)
 
@@ -145,12 +169,21 @@ class TokenStream:
         with self._lock:
             return list(self._tokens)
 
+    def _picked_up(self, stamp: float) -> None:
+        """A consumer took the token pushed at `stamp`. Racing consumer
+        threads can lose an increment, as `flight.record` can."""
+        clocks = self._clocks
+        if stamp and clocks is not None:
+            clocks["stream_wake_s"] += time.perf_counter() - stamp
+            clocks["stream_wake_tokens"] += 1
+
     def __iter__(self):
         idx = 0
         while True:
             with self._lock:
                 if idx < len(self._tokens):
                     tok = self._tokens[idx]
+                    stamp = self._stamps[idx]
                     idx += 1
                 elif self._done:
                     if self._error is not None:
@@ -163,6 +196,7 @@ class TokenStream:
             if tok is None:
                 ev.wait()
                 continue
+            self._picked_up(stamp)
             yield tok
 
     async def __aiter__(self):
@@ -174,6 +208,7 @@ class TokenStream:
             with self._lock:
                 if idx < len(self._tokens):
                     tok = self._tokens[idx]
+                    stamp = self._stamps[idx]
                     idx += 1
                 elif self._done:
                     if self._error is not None:
@@ -186,6 +221,7 @@ class TokenStream:
             if tok is None:
                 await ev.wait()
                 continue
+            self._picked_up(stamp)
             yield tok
 
 
@@ -201,6 +237,10 @@ class _Sequence:
     submitted_at: float = field(default_factory=time.perf_counter)
     first_token_at: Optional[float] = None
     preemptions: int = 0
+    # When it last entered the waiting queue (submission, or preemption).
+    queued_at: float = field(default_factory=time.perf_counter)
+    # Trace id of the `serve.replica` span that submitted it, if any.
+    trace_id: Optional[str] = None
 
     @property
     def generated(self) -> int:
@@ -256,14 +296,19 @@ class InferenceEngine:
         self.prefix_imports = 0
         self.prefix_import_tokens = 0
         self.finished = 0
-        self.prefill_s = 0.0
-        self.decode_s = 0.0
         self.paged_steps = 0
-        # Decode-step phase split (the cost paged decode removes is the
-        # kv_gather slice): host gather / compiled step / cache write.
-        self.kv_gather_s = 0.0
-        self.model_step_s = 0.0
-        self.kv_write_s = 0.0
+        # Every clock of the loop, in seconds, each fed by one
+        # `flight.span` (so all of them stand still while the flight
+        # recorder is off): the phases, the older clocks (`prefill_s`;
+        # the decode step's split into host gather / compiled step /
+        # cache write), `step_s` (all of `engine.step`), and what is no
+        # phase: `thread_cpu_s`, `queue_wait_s`, `stream_wake_*`.
+        self._clocks: Dict[str, float] = dict.fromkeys(
+            _LEGACY_CLOCKS + tuple(f"{p}_s" for p in _STEP_PHASES)
+            + ("park_s", "other_s", "step_s", "thread_cpu_s",
+               "queue_wait_s", "stream_wake_s", "stream_wake_tokens"), 0.0)
+        self._cpu_thread: Optional[int] = None
+        self._cpu_at = 0.0
         self._ttfts: List[float] = []
         self._pushed: Dict[str, float] = {}
         # Retirement stamps feeding the queue-drain-rate estimate behind
@@ -290,11 +335,12 @@ class InferenceEngine:
                 f"prompt+max_new_tokens={worst} exceeds cache capacity "
                 f"{self.cache.capacity_tokens}")
         seq_id = f"seq-{next(self._ids)}"
-        stream = TokenStream(seq_id)
+        stream = TokenStream(seq_id, self._clocks)
         seq = _Sequence(seq_id=seq_id, prompt=prompt,
                         all_tokens=list(prompt), max_new_tokens=max_new,
                         priority=priority, arrival=time.monotonic(),
-                        stream=stream)
+                        stream=stream,
+                        trace_id=tracing.current_trace_id())
         with self._lock:
             if len(self._waiting) >= self.config.max_queue:
                 err = EngineOverloadedError(
@@ -403,19 +449,58 @@ class InferenceEngine:
         running and nothing admittable). Never raises for per-sequence
         failures — a poisoned sequence finishes its stream with the
         error; the loop survives."""
-        self._reap_cancelled()
-        self._admit()
+        clocks = self._clocks
+        leaves = self._leaf_seconds()
+        with flight.span("engine", "step", None, clocks, "step_s") as sp:
+            sp.arg = self._step()
+        if flight.enabled:
+            # What the step spent in no leaf; never negative, though a
+            # model called from another thread meanwhile can add to the
+            # leaves (the benchmark's set-up does).
+            clocks["other_s"] += max(
+                0.0, sp.dur - (self._leaf_seconds() - leaves))
+            # CPU time of this thread since its last step, park included.
+            cpu, thread = time.thread_time(), threading.get_ident()
+            if thread == self._cpu_thread:
+                clocks["thread_cpu_s"] += cpu - self._cpu_at
+            self._cpu_thread, self._cpu_at = thread, cpu
+        return sp.arg is not None
+
+    def _leaf_seconds(self) -> float:
+        """Sum of the leaf phases inside `engine.step`."""
+        clocks = self._clocks
+        total = clocks["kv_gather_s"]
+        for name in _STEP_PHASES:
+            total += clocks[f"{name}_s"]
+        model = getattr(self.model, "phase", None)
+        if model:
+            total += sum(model.values())
+        return total
+
+    def _step(self) -> Optional[int]:
+        """The iteration itself: the decode batch's size, None when
+        idle."""
+        clocks = self._clocks
+        with flight.span("engine", "reap", None, clocks, "reap_s"):
+            self._reap_cancelled()
+        prefilled = clocks["prefill_s"]
+        with flight.span("engine", "admit") as sp:
+            self._admit()
+        # Self time: the prefills inside it have their own phases.
+        clocks["admit_s"] += max(
+            0.0, sp.dur - (clocks["prefill_s"] - prefilled))
         with self._lock:
             batch = list(self._running)
         if not batch:
             self._update_gauges()
-            return False
-        self._ensure_capacity()
+            return None
+        with flight.span("engine", "capacity", None, clocks, "capacity_s"):
+            self._ensure_capacity()
         with self._lock:
             batch = list(self._running)
         if not batch:
             self._update_gauges()
-            return False
+            return None
         try:
             self._decode_once(batch)
         except Exception as e:  # noqa: BLE001 — the loop must survive
@@ -425,7 +510,7 @@ class InferenceEngine:
                 self._retire(seq, error=e)
         self.steps += 1
         self._update_gauges()
-        return True
+        return len(batch)
 
     def _reap_cancelled(self) -> None:
         with self._lock:
@@ -476,23 +561,48 @@ class InferenceEngine:
                 seq.stream._finish(e)
 
     def _prefill(self, seq: _Sequence) -> bool:
-        t0 = time.perf_counter()
+        # Engine steps in the flight ring: a decode-latency spike lines
+        # up against GC pauses / loop stalls in the merged timeline
+        # instead of being its own mystery; prefix_hit makes
+        # shared-prefill savings visible per admission in /api/timeline.
+        with flight.span("engine", "prefill", None, self._clocks,
+                         "prefill_s") as sp:
+            return self._prefill_inner(seq, sp)
+
+    def _prefill_inner(self, seq: _Sequence, sp: flight.span) -> bool:
+        clocks = self._clocks
+        admission = time.perf_counter()
         tokens = list(seq.all_tokens)
         n = len(tokens)
         hit = 0
-        if self.prefix_index is not None:
-            blocks, hit = self.prefix_index.match(tokens)
-            if hit:
-                self.cache.adopt(seq.seq_id, blocks, hit)
-        # Privatize from the first position this prefill writes: a
-        # partially-adopted shared block COWs here, planned into the
-        # same atomic free-block arithmetic as table growth.
-        ok = self.cache.allocate(seq.seq_id, n, writable_from=hit)
+        with flight.span("engine", "prefill.match", None, clocks,
+                         "prefill_match_s"):
+            if self.prefix_index is not None:
+                blocks, hit = self.prefix_index.match(tokens)
+                if hit:
+                    self.cache.adopt(seq.seq_id, blocks, hit)
+            # Privatize from the first position this prefill writes: a
+            # partially-adopted shared block COWs here, planned into the
+            # same atomic free-block arithmetic as table growth.
+            ok = self.cache.allocate(seq.seq_id, n, writable_from=hit)
         if not ok:   # lost capacity since the admission check: requeue
             self.cache.free(seq.seq_id)
             with self._lock:
                 self._waiting.appendleft(seq)
             return False
+        sp.arg = f"tokens={n} prefix_hit={hit}"
+        if flight.enabled:
+            # The wait this admission ends, as an event of its own that
+            # closes where the prefill opens; it carries the request's
+            # identifiers (the prefill's arg is read as it stands).
+            wait = admission - seq.queued_at
+            clocks["queue_wait_s"] += wait
+            queued_ago = time.perf_counter() - seq.queued_at
+            flight.record(
+                "engine", "queue_wait", dur_us=int(wait * 1e6),
+                arg=(seq.seq_id if seq.trace_id is None
+                     else f"{seq.seq_id} trace={seq.trace_id}"),
+                t=time.monotonic() - queued_ago)
         if hit == n:
             # Full prefix hit: every prompt position is already cached.
             # The first generated token is ONE decode step over the
@@ -530,32 +640,29 @@ class InferenceEngine:
                 # prompt, but only the unmatched tail is stored.
                 logits, kv = self.model.prefill(tokens)
                 tail_kv = kv[hit:]
-            self.cache.write_range(seq.seq_id, hit, tail_kv)
+            with flight.span("engine", "prefill.kv_write", None, clocks,
+                             "prefill_kv_write_s"):
+                self.cache.write_range(seq.seq_id, hit, tail_kv)
         else:
             logits, kv = self.model.prefill(tokens)
-            self.cache.write_range(seq.seq_id, 0, kv)
+            with flight.span("engine", "prefill.kv_write", None, clocks,
+                             "prefill_kv_write_s"):
+                self.cache.write_range(seq.seq_id, 0, kv)
         if self.prefix_index is not None:
             # Seal: every full prompt block becomes adoptable.
-            self.prefix_index.insert(tokens,
-                                     self.cache.block_table(seq.seq_id))
-        tok = int(np.argmax(np.asarray(logits)))
+            with flight.span("engine", "prefill.seal", None, clocks,
+                             "prefill_seal_s"):
+                self.prefix_index.insert(
+                    tokens, self.cache.block_table(seq.seq_id))
+        with flight.span("engine", "sample", None, clocks, "sample_s"):
+            tok = int(np.argmax(np.asarray(logits)))
         self.prefills += 1
         self.prefix_hit_tokens += hit
-        dt = time.perf_counter() - t0
-        self.prefill_s += dt
-        if flight.enabled:
-            # Engine steps in the flight ring: a decode-latency spike
-            # lines up against GC pauses / loop stalls in the merged
-            # timeline instead of being its own mystery; prefix_hit
-            # makes shared-prefill savings visible per admission in
-            # /api/timeline.
-            flight.record("engine", "prefill", dur_us=int(dt * 1e6),
-                          arg=f"tokens={n} prefix_hit={hit}",
-                          t=time.monotonic() - dt)
-        self._emit(seq, tok)
-        if not self._maybe_finish(seq):
-            with self._lock:
-                self._running.append(seq)
+        with flight.span("engine", "emit", None, clocks, "emit_s"):
+            self._emit(seq, tok)
+            if not self._maybe_finish(seq):
+                with self._lock:
+                    self._running.append(seq)
         return True
 
     def _ensure_capacity(self) -> None:
@@ -606,63 +713,57 @@ class InferenceEngine:
             # before fresh arrivals (no starvation).
             self._waiting.appendleft(seq)
         self.cache.free(seq.seq_id)
+        seq.queued_at = time.perf_counter()
         seq.preemptions += 1
         self.preemptions += 1
 
     def _decode_once(self, batch: List[_Sequence]) -> None:
-        t0 = time.perf_counter()
-        lasts = [s.all_tokens[-1] for s in batch]
-        poss = [len(s.all_tokens) - 1 for s in batch]
+        with flight.span("engine", "decode", len(batch)):
+            self._decode_inner(batch)
+
+    def _decode_inner(self, batch: List[_Sequence]) -> None:
+        clocks = self._clocks
+        b = len(batch)
         if self.paged:
             # Paged: hand the model the POOL + block tables + write
             # slots; gather, attention, AND the new tokens' KV
             # write-back all run inside ONE donated jit call. Host work
             # this step is int32 table padding — the KV payload never
-            # leaves the device in either direction.
-            tables = [self.cache.block_table(s.seq_id) for s in batch]
-            t1 = time.perf_counter()
-            logits = self.cache.paged_step(
-                [(s.seq_id, poss[i]) for i, s in enumerate(batch)],
-                lambda pool, blocks, offs: self.model.decode_paged(
-                    pool, tables, lasts, poss, blocks, offs,
-                    self.config.block_size))
-            t2 = time.perf_counter()
-            t3 = t2   # write is fused into the model step
+            # leaves the device in either direction (so `kv_gather_s`
+            # times the table build here, and `kv_write_s` stands still:
+            # the write is fused into the model step).
+            with flight.span("engine", "tables", b, clocks, "kv_gather_s"):
+                lasts = [s.all_tokens[-1] for s in batch]
+                poss = [len(s.all_tokens) - 1 for s in batch]
+                tables = [self.cache.block_table(s.seq_id) for s in batch]
+                entries = [(s.seq_id, poss[i]) for i, s in enumerate(batch)]
+            with flight.span("engine", "model_step", b, clocks,
+                             "model_step_s"):
+                logits = self.cache.paged_step(
+                    entries,
+                    lambda pool, blocks, offs: self.model.decode_paged(
+                        pool, tables, lasts, poss, blocks, offs,
+                        self.config.block_size))
             self.paged_steps += 1
         else:
-            kvs = [self.cache.gather(s.seq_id) for s in batch]
-            t1 = time.perf_counter()
-            logits, new_kv = self.model.decode(kvs, lasts, poss)
-            t2 = time.perf_counter()
-            for i, seq in enumerate(batch):
-                self.cache.write(seq.seq_id, poss[i], new_kv[i])
-            t3 = time.perf_counter()
-        logits = np.asarray(logits)
-        dt = t3 - t0
-        self.decode_s += dt
-        self.kv_gather_s += t1 - t0
-        self.model_step_s += t2 - t1
-        self.kv_write_s += t3 - t2
-        if attribution.enabled:
-            attribution.record("engine.kv_gather", t1 - t0)
-            attribution.record("engine.model_step", t2 - t1)
-            attribution.record("engine.kv_write", t3 - t2)
-        if flight.enabled:
-            now = time.monotonic()
-            flight.record("engine", "decode", dur_us=int(dt * 1e6),
-                          arg=len(batch), t=now - dt)
-            # Phase split inside the step: before/after this PR the
-            # kv_gather span is what shrinks in /api/timeline.
-            flight.record("engine", "kv_gather",
-                          dur_us=int((t1 - t0) * 1e6),
-                          arg=len(batch), t=now - dt)
-            flight.record("engine", "model_step",
-                          dur_us=int((t2 - t1) * 1e6),
-                          arg=len(batch), t=now - dt + (t1 - t0))
-        for i, seq in enumerate(batch):
-            tok = int(np.argmax(logits[i]))
-            self._emit(seq, tok)
-            self._maybe_finish(seq)
+            with flight.span("engine", "kv_gather", b, clocks,
+                             "kv_gather_s"):
+                lasts = [s.all_tokens[-1] for s in batch]
+                poss = [len(s.all_tokens) - 1 for s in batch]
+                kvs = [self.cache.gather(s.seq_id) for s in batch]
+            with flight.span("engine", "model_step", b, clocks,
+                             "model_step_s"):
+                logits, new_kv = self.model.decode(kvs, lasts, poss)
+            with flight.span("engine", "kv_write", b, clocks, "kv_write_s"):
+                for i, seq in enumerate(batch):
+                    self.cache.write(seq.seq_id, poss[i], new_kv[i])
+        with flight.span("engine", "sample", b, clocks, "sample_s"):
+            logits = np.asarray(logits)
+            toks = [int(np.argmax(logits[i])) for i in range(b)]
+        with flight.span("engine", "emit", b, clocks, "emit_s"):
+            for seq, tok in zip(batch, toks):
+                self._emit(seq, tok)
+                self._maybe_finish(seq)
 
     def _emit(self, seq: _Sequence, tok: int) -> None:
         seq.all_tokens.append(tok)
@@ -716,7 +817,9 @@ class InferenceEngine:
                     self._fail_in_flight(e)
                     worked = False
                 if not worked:
-                    self._work.wait(timeout=0.05)
+                    with flight.span("engine", "park", None, self._clocks,
+                                     "park_s"):
+                        self._work.wait(timeout=0.05)
                     self._work.clear()
 
         self._thread = threading.Thread(target=loop, daemon=True,
@@ -757,11 +860,67 @@ class InferenceEngine:
     def cow_copies(self) -> int:
         return self.cache.cow_copies
 
+    # The older clocks as attributes (`ray_tpu/perf.py` reads them so).
+    @property
+    def prefill_s(self) -> float:
+        return self._clocks["prefill_s"]
+
+    @property
+    def kv_gather_s(self) -> float:
+        return self._clocks["kv_gather_s"]
+
+    @property
+    def model_step_s(self) -> float:
+        return self._clocks["model_step_s"]
+
+    @property
+    def kv_write_s(self) -> float:
+        return self._clocks["kv_write_s"]
+
+    @property
+    def decode_s(self) -> float:
+        """A decode step up to its logits: gather + model step + write."""
+        clocks = self._clocks
+        return (clocks["kv_gather_s"] + clocks["model_step_s"]
+                + clocks["kv_write_s"])
+
+    def phase_seconds(self) -> Dict[str, float]:
+        """Where the loop's wall time went, by phase: every instant of
+        the loop (`loop_s`) lies in exactly one. The model's phases are
+        the model's own (`model.phase`)."""
+        clocks = self._clocks
+        model = getattr(self.model, "phase", None) or {}
+        out = {"park": clocks["park_s"], "tables": clocks["kv_gather_s"],
+               "other": clocks["other_s"]}
+        out.update((name, clocks[f"{name}_s"]) for name in _STEP_PHASES)
+        out.update((f"model_{name}", model.get(f"{name}_s", 0.0))
+                   for name in _MODEL_PHASES)
+        return out
+
     def stats(self) -> Dict[str, Any]:
+        """Counts, gauges and clocks of the engine. Every top-level
+        number but `running`, `waiting` and `ttft_p50_ms` only grows, and
+        all are there from construction, so two snapshots subtract key
+        by key.
+
+        Clocks (seconds; all fed by `flight.span`, so they stand still
+        while the flight recorder is off): `phase.<name>_s` partition
+        `loop_s`, the loop's wall time (`engine.step` spans plus the
+        park); `thread_cpu_s` is the loop thread's CPU time over the
+        same iterations; `queue_wait_s` sums, over admissions, the time
+        from entering the waiting queue to the start of the prefill;
+        `stream_wake_s` / `stream_wake_tokens` sum, over tokens handed
+        to a consumer, the time from `TokenStream._push` to pickup.
+        `prefill_s` is all of `_prefill`, `decode_s` a decode step up to
+        its logits = `kv_gather_s` + `model_step_s` + `kv_write_s`. Under
+        paged decode `kv_gather_s` is the block-table build (no KV is
+        gathered on the host) and `kv_write_s` stays 0 (the write is
+        fused into the model step)."""
         with self._lock:
             running = len(self._running)
             waiting = len(self._waiting)
         ttfts = sorted(self._ttfts)
+        clocks = self._clocks
         return {
             "steps": self.steps,
             "prefills": self.prefills,
@@ -789,9 +948,21 @@ class InferenceEngine:
             "kv_write_s": round(self.kv_write_s, 6),
             "ttft_p50_ms": (round(ttfts[len(ttfts) // 2] * 1e3, 3)
                             if ttfts else None),
+            **{f"phase.{name}_s": seconds
+               for name, seconds in self.phase_seconds().items()},
+            "loop_s": clocks["step_s"] + clocks["park_s"],
+            "thread_cpu_s": clocks["thread_cpu_s"],
+            "queue_wait_s": clocks["queue_wait_s"],
+            "stream_wake_s": clocks["stream_wake_s"],
+            "stream_wake_tokens": int(clocks["stream_wake_tokens"]),
         }
 
     def _update_gauges(self) -> None:
+        with flight.span("engine", "gauges", None, self._clocks,
+                         "gauges_s"):
+            self._push_gauges()
+
+    def _push_gauges(self) -> None:
         try:
             from ray_tpu.serve._private.metrics import engine_metrics
 
@@ -811,17 +982,19 @@ class InferenceEngine:
                 if cur > last:
                     m[key].inc(cur - last)
                     self._pushed[attr] = cur
-            for attr, phase in (("prefill_s", "prefill"),
-                                ("decode_s", "decode"),
-                                ("kv_gather_s", "kv_gather"),
-                                ("model_step_s", "model_step"),
-                                ("kv_write_s", "kv_write")):
-                cur = getattr(self, attr)
-                last = self._pushed.get(attr, 0.0)
+            # The loop's phases ride the same counter as further
+            # `phase` tags, every 64th step: a registry call each, on
+            # the loop they time.
+            phases = self.phase_seconds() if self.steps % 64 == 0 else {}
+            for attr in ("prefill", "decode", "kv_gather", "model_step",
+                         "kv_write"):
+                phases[attr] = getattr(self, f"{attr}_s")
+            for phase, cur in phases.items():
+                last = self._pushed.get(f"phase.{phase}", 0.0)
                 if cur > last:
                     m["step_phase"].inc(cur - last,
                                         tags={"phase": phase})
-                    self._pushed[attr] = cur
+                    self._pushed[f"phase.{phase}"] = cur
             m["kv_pool_bytes"].set(
                 float(self.cache.pool_bytes),
                 tags={"replica": self.replica_tag,

@@ -124,6 +124,15 @@ def current_traceparent() -> Optional[str]:
     return f"00-{ctx[0]}-{ctx[1]}-{'01' if ctx[2] else '00'}"
 
 
+def current_trace_id() -> Optional[str]:
+    """Trace id of the ACTIVE span (None outside any span or with
+    tracing off): what a component that keeps its own events, like the
+    serve engine's flight spans, stores to join them to the request's
+    trace."""
+    ctx = _ctx.get()
+    return ctx[0] if ctx is not None else None
+
+
 def _parse_traceparent(tp: Optional[str]):
     if not tp:
         return None

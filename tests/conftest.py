@@ -145,6 +145,21 @@ def _jax_on_cpu():
 
 
 @pytest.fixture
+def recorder():
+    """The flight recorder on, empty and large enough for a test's spans;
+    left as it was found."""
+    from ray_tpu.core import flight
+
+    prev = flight.enabled
+    flight.enable()
+    flight.configure(1 << 16)
+    flight.reset()
+    yield flight
+    if not prev:
+        flight.disable()
+
+
+@pytest.fixture
 def ray_start_local():
     """Local-mode runtime (reference fixture analog: ray_start_regular)."""
     import ray_tpu
