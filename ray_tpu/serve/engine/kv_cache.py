@@ -37,13 +37,16 @@ The manager owns two things:
   `install_block`, the batched `write_step`) goes through a
   donated-argument jitted update — the pool is threaded through the
   jit and donated back, so XLA aliases input to output and steady-state
-  decode neither copies the pool nor allocates a second one. The paged
-  decode path (`EngineConfig(paged_decode=True)`) reads the pool
-  *inside* the model's compiled step via `jnp.take` over block tables
-  (`with_pool` hands the live buffer to the dispatch under the lock),
-  which removes the per-step host `gather`/pad entirely; the
-  `host_gathers` counter proves it (the paged perf guard asserts it
-  stays zero across a whole decode run).
+  decode neither copies the pool nor allocates a second one. A
+  prefill's KV that is still on the device (`model.PromptKV`) is that
+  update's payload as it stands: the prompt KV never visits the host
+  on its way into a device pool. The paged decode path
+  (`EngineConfig(paged_decode=True)`) reads the pool *inside* the
+  model's compiled step via `jnp.take` over block tables (`with_pool`
+  hands the live buffer to the dispatch under the lock), which removes
+  the per-step host `gather`/pad entirely; the `host_gathers` counter
+  proves it (the paged perf guard asserts it stays zero across a whole
+  decode run).
 
 Determinism contract (the scheduler's loop must never crash on OOM):
 `allocate` is atomic — it either extends the table (and privatizes the
@@ -73,6 +76,15 @@ def _next_pow2(n: int) -> int:
     while p < n:
         p <<= 1
     return p
+
+
+def _device_rows(values):
+    """The device array behind a `write_range` payload, or None for a
+    host payload: a jax array itself, or the `padded` rows of a model's
+    prefill result (`model.PromptKV`: at least `len(values)` rows, the
+    rest the shape bucket's padding)."""
+    rows = getattr(values, "padded", values)
+    return rows if hasattr(rows, "block_until_ready") else None
 
 
 class _DevicePoolOps:
@@ -163,6 +175,10 @@ class KVCacheManager:
         # counts donated in-place pool mutations on the device path.
         self.host_gathers = 0
         self.pool_updates = 0
+        # `write_range` calls whose payload went from the device into a
+        # device pool, and those that passed through host memory.
+        self.range_writes_device = 0
+        self.range_writes_host = 0
         # LIFO free list: recently-freed blocks are cache-warm.
         self._free: List[int] = list(range(self.num_blocks - 1, -1, -1))
         self._refs: Dict[int, int] = {}          # block -> holder count
@@ -451,26 +467,24 @@ class KVCacheManager:
                       values, n: int) -> None:
         """ONE donated scatter for `n` token rows: a whole prefill
         range (any number of blocks, any offsets) or one decode step's
-        batch lands in a single dispatch. Rows pad to a pow2 bucket so
-        compiles stay bounded; padding rows point past the pool and
-        drop. Host payloads pad in numpy (one transfer, one dispatch);
-        device payloads (a paged prefill's tail KV) pad on-device so
-        they never round-trip through the host."""
-        n_pad = _next_pow2(max(n, 1))
-        b = np.full((n_pad,), self.num_blocks, np.int32)
-        o = np.zeros((n_pad,), np.int32)
-        b[:n] = blocks[:n]
-        o[:n] = offs[:n]
-        if hasattr(values, "block_until_ready"):   # already on device
-            vals = self._ns.asarray(values, self._dtype)
-            if n_pad != n:
-                vals = self._ns.zeros(
-                    (n_pad,) + self.kv_shape, self._dtype).at[:n].set(vals)
+        batch lands in a single dispatch. Compiles are per row bucket:
+        rows past `n` point past the pool and drop. A payload whose
+        rows are on the device (`_device_rows`) is scattered as it
+        stands, its own padding being the bucket; a host payload pads
+        to a pow2 bucket in numpy (one transfer, one dispatch)."""
+        vals = _device_rows(values)
+        if vals is not None:
+            vals = self._ns.asarray(vals, self._dtype)
         else:
-            padded = np.zeros((n_pad,) + self.kv_shape,
+            padded = np.zeros((_next_pow2(max(n, 1)),) + self.kv_shape,
                               np.dtype(self._dtype))
             padded[:n] = np.asarray(values)[:n]
             vals = self._ns.asarray(padded)
+        rows = int(vals.shape[0])
+        b = np.full((rows,), self.num_blocks, np.int32)
+        o = np.zeros((rows,), np.int32)
+        b[:n] = blocks[:n]
+        o[:n] = offs[:n]
         self._buffer = self._ops.scatter(
             self._buffer, self._ns.asarray(b), self._ns.asarray(o), vals)
         self.pool_updates += 1
@@ -492,11 +506,19 @@ class KVCacheManager:
         """Store KV entries for positions [start, start+len(values)) —
         the prefill bulk write. Shared blocks in the range privatize
         first (COW). The numpy pool writes block-sized slices in
-        place; the device pool resolves every token's (block, off)
-        slot and lands the whole range in one donated scatter."""
+        place, from one host copy of a device payload; the device pool
+        resolves every token's (block, off) slot and lands the whole
+        range in one donated scatter, a device payload without leaving
+        the device. `range_writes_device` / `range_writes_host` count
+        the calls by whether the payload reached the pool that way."""
         n = len(values)
         with self._lock:
+            if self._device and _device_rows(values) is not None:
+                self.range_writes_device += 1
+            else:
+                self.range_writes_host += 1
             if self._ns is np:
+                values = np.asarray(values)
                 pos = start
                 written = 0
                 while written < n:

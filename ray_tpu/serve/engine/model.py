@@ -11,7 +11,9 @@ The iteration scheduler drives any model through two calls:
   unmatched tail only — prefill-from-offset, the compute half of prefix
   sharing. Models advertise support with ``supports_prefix_prefill``;
   without it the engine falls back to full recompute with tail-only
-  writes (capacity sharing, no compute savings);
+  writes (capacity sharing, no compute savings). The KV result goes to
+  `KVCacheManager.write_range`; what that needs of it is `len()` (the
+  rows to write) and `np.asarray()` (`PromptKV` adds its device rows);
 - ``decode(kvs, last_tokens, positions) -> (logits [B, V],
   new_kv [B, *kv_token_shape])`` — one incremental step for a batch of
   sequences: ``kvs[i]`` is sequence i's cached KV gathered from the
@@ -75,6 +77,33 @@ class _JitLRU(OrderedDict):
         while len(self) > self.cap:
             self.popitem(last=False)
             self.evictions += 1
+
+
+class PromptKV:
+    """A prefill's KV as its jit returned it: `padded` is the device
+    array `[rows_pad, *kv_token_shape]` of the whole shape bucket, of
+    which the first `len()` rows are the prompt's (or its tail's) KV and
+    the rest whatever the padded positions computed.
+
+    A device pool scatters `padded` as it stands (`write_range` points
+    the rows past `len()` outside the pool, where they drop), so the KV
+    never visits the host and no device program's shape depends on the
+    prompt's length. `np.asarray()` is the `[len(), *kv_token_shape]`
+    host array, for a host pool or a test: one copy of the bucket, cut
+    on the host."""
+
+    __slots__ = ("padded", "_n")
+
+    def __init__(self, padded, n: int):
+        self.padded = padded
+        self._n = n
+
+    def __len__(self) -> int:
+        return self._n
+
+    def __array__(self, dtype=None, copy=None):
+        host = np.asarray(self.padded)[:self._n]
+        return host if dtype is None else host.astype(dtype, copy=False)
 
 
 class TinyLM:
@@ -259,9 +288,11 @@ class TransformerEngineModel:
         # Host side of the calls, in seconds, each fed by its
         # `flight.span`: input padding and upload (`prep`), the call of
         # the jitted function (`dispatch`), the logits' arrival on the
-        # host (`wait`: the device's compute shows here), and the
-        # prompt KV's trip to the host. `InferenceEngine.stats()` reads
-        # them as `phase.model_<name>`.
+        # host (`wait`: the device's compute shows here).
+        # `prefill_kv_d2h_s` timed the prompt KV's trip to the host
+        # while `prefill` made it; it stands still now, and stays for
+        # the readers of `phase.model_prefill_kv_d2h_s`.
+        # `InferenceEngine.stats()` reads them as `phase.model_<name>`.
         self.phase: Dict[str, float] = dict.fromkeys(
             ("prefill_prep_s", "prefill_dispatch_s", "prefill_wait_s",
              "prefill_kv_d2h_s", "decode_prep_s", "decode_dispatch_s",
@@ -584,6 +615,10 @@ class TransformerEngineModel:
 
     # -- engine interface ----------------------------------------------
     def prefill(self, tokens: Sequence[int], prefix_kv=None):
+        """Run the prompt (or, given the `[P, *kv_token_shape]` KV of
+        its first P positions, the tail). Returns the host logits that
+        predict the next token and the computed positions' KV as a
+        `PromptKV`: still on the device, in the jit's padded bucket."""
         with flight.span("model", "prefill", len(tokens)):
             return self._prefill(tokens, prefix_kv)
 
@@ -626,10 +661,7 @@ class TransformerEngineModel:
         with flight.span("model", "prefill.logits_wait", None, phase,
                          "prefill_wait_s"):
             logits = np.asarray(logits)
-        with flight.span("model", "prefill.kv_d2h", None, phase,
-                         "prefill_kv_d2h_s"):
-            kv = np.asarray(kv[:t])
-        return logits, kv
+        return logits, PromptKV(kv, t)
 
     def decode(self, kvs: List[np.ndarray], last_tokens: Sequence[int],
                positions: Sequence[int]):
@@ -737,8 +769,7 @@ class TransformerEngineModel:
                       block_size: int):
         """Prefill-from-offset with the adopted prefix gathered from
         the device pool inside the jit. Returns host logits plus the
-        tail KV as a DEVICE array [tail, *kv_token_shape] for
-        `write_range`."""
+        tail KV as a `PromptKV` for `write_range`, as `prefill` does."""
         with flight.span("model", "prefill", len(tokens)):
             return self._prefill_paged(tokens, pool, block_table,
                                        int(prefix_len), block_size)
@@ -768,8 +799,7 @@ class TransformerEngineModel:
         with flight.span("model", "prefill.dispatch", None, phase,
                          "prefill_dispatch_s"):
             logits, kv = fn(self._params, *args, pool, table)
-            kv = kv[:t]             # stays on the device
         with flight.span("model", "prefill.logits_wait", None, phase,
                          "prefill_wait_s"):
             logits = np.asarray(logits)
-        return logits, kv
+        return logits, PromptKV(kv, t)

@@ -32,6 +32,14 @@ token is one `model.decode` step over the adopted blocks. Preemption
 frees only a sequence's private tail (shared blocks survive and stay
 indexed), and cold prefixes are LRU-evicted under block pressure
 instead of admissions being rejected.
+
+A prefill hands its KV over in two calls, with or without a prefix hit:
+the model's prefill returns the logits and the KV (`len()` rows), and
+`cache.write_range` stores them. Where the model computed them on the
+device and the pool lives there (`TransformerEngineModel`, paged), the
+KV is the jit's padded output (`model.PromptKV`) and one donated scatter
+writes it: it never crosses to the host. Any other pairing (a numpy
+pool, `TinyLM`) converts once and writes on the host.
 """
 
 from __future__ import annotations
@@ -639,7 +647,7 @@ class InferenceEngine:
                 # Capacity-only sharing: the model recomputes the whole
                 # prompt, but only the unmatched tail is stored.
                 logits, kv = self.model.prefill(tokens)
-                tail_kv = kv[hit:]
+                tail_kv = np.asarray(kv)[hit:]
             with flight.span("engine", "prefill.kv_write", None, clocks,
                              "prefill_kv_write_s"):
                 self.cache.write_range(seq.seq_id, hit, tail_kv)
@@ -915,7 +923,10 @@ class InferenceEngine:
         its logits = `kv_gather_s` + `model_step_s` + `kv_write_s`. Under
         paged decode `kv_gather_s` is the block-table build (no KV is
         gathered on the host) and `kv_write_s` stays 0 (the write is
-        fused into the model step)."""
+        fused into the model step). `prefill_kv_device_writes` and
+        `prefill_kv_host_writes` count the prompt-KV writes into the
+        pool (`write_range`) that stayed on the device, and those that
+        passed through host memory."""
         with self._lock:
             running = len(self._running)
             waiting = len(self._waiting)
@@ -939,6 +950,8 @@ class InferenceEngine:
                              if self.prefix_index is not None else None),
             "paged": self.paged,
             "paged_steps": self.paged_steps,
+            "prefill_kv_device_writes": self.cache.range_writes_device,
+            "prefill_kv_host_writes": self.cache.range_writes_host,
             "jit_bucket_evictions": getattr(
                 self.model, "jit_cache_evictions", 0),
             "prefill_s": round(self.prefill_s, 6),

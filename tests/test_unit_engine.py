@@ -473,7 +473,9 @@ def test_transformer_prefill_matches_training_forward(tiny_transformer):
     model = TransformerEngineModel(params, cfg)
     prompt = [3, 17, 42, 9, 21]
     logits, kv = model.prefill(prompt)
-    assert kv.shape == (5, cfg.n_layers, 2, cfg.n_heads, cfg.head_dim)
+    assert len(kv) == 5
+    assert np.asarray(kv).shape == (5, cfg.n_layers, 2, cfg.n_heads,
+                                    cfg.head_dim)
     full, _ = forward(params, jnp.asarray([prompt], jnp.int32), cfg)
     np.testing.assert_allclose(logits, np.asarray(full)[0, -1],
                                atol=1e-4)
@@ -521,6 +523,7 @@ def test_transformer_prefill_from_offset_matches_full(tiny_transformer):
     model = TransformerEngineModel(params, cfg)
     prompt = [3, 17, 42, 9, 21, 5, 11, 2, 33, 40]
     full_logits, full_kv = model.prefill(prompt)
+    full_kv = np.asarray(full_kv)
     for p in (4, 8, 9):
         logits, tail_kv = model.prefill(prompt, prefix_kv=full_kv[:p])
         np.testing.assert_allclose(logits, full_logits, atol=1e-4)
@@ -597,3 +600,145 @@ def test_transformer_shape_buckets_are_bounded(tiny_transformer):
         assert b & (b - 1) == 0 and s & (s - 1) == 0
     assert len(model._decode_jit) <= 6
     assert len(model._prefill_jit) <= 3
+
+
+# ---------------------------------------------------------------------------
+# the prompt KV's hand-over: prefill's device rows -> the pool's scatter
+# ---------------------------------------------------------------------------
+def _pool_bytes(cache):
+    return cache.with_pool(lambda pool: np.array(pool))
+
+
+def _tokens(n, seed):
+    return np.random.default_rng(seed).integers(2, 64, n).tolist()
+
+
+@pytest.mark.parametrize("n", [5, 8, 33, 48, 63, 64])
+def test_prompt_kv_reaches_a_device_pool_without_the_host(
+        tiny_transformer, n):
+    """`model.prefill` then `cache.write_range`, as the scheduler's
+    no-hit path makes them: the padded device rows land exactly where
+    the host-payload path (`np.asarray` of the same KV) puts them, the
+    bucket's padding rows drop, and the counters say which way it went."""
+    from ray_tpu.serve.engine import (EngineConfig, InferenceEngine,
+                                      KVCacheManager,
+                                      TransformerEngineModel)
+
+    params, cfg = tiny_transformer
+    model = TransformerEngineModel(params, cfg)
+    eng = InferenceEngine(model, EngineConfig(
+        block_size=16, num_blocks=12, paged_decode=True))
+    cache = eng.cache
+    by_host = KVCacheManager(12, 16, kv_shape=model.kv_token_shape,
+                             device_pool=True)
+    # A neighbour whose last block the sequence under test follows.
+    prompts = {"neighbour": _tokens(19, 1), "s": _tokens(n, n)}
+    logits = {}
+    for sid, tokens in prompts.items():
+        for c in (cache, by_host):
+            assert c.allocate(sid, len(tokens), writable_from=0)
+        logits[sid], kv = model.prefill(tokens)
+        assert len(kv) == len(tokens)
+        assert not isinstance(kv, np.ndarray)
+        before = np.asarray(cache.gather("neighbour"))
+        cache.write_range(sid, 0, kv)
+        by_host.write_range(sid, 0, np.asarray(kv))
+    np.testing.assert_array_equal(
+        np.asarray(cache.gather("s")), np.asarray(by_host.gather("s")))
+    np.testing.assert_array_equal(np.asarray(cache.gather("s")),
+                                  np.asarray(kv))
+    # Nothing else in the pool moved: the neighbour's rows, and every
+    # block the two pools did not write (the padding rows dropped).
+    np.testing.assert_array_equal(np.asarray(cache.gather("neighbour")),
+                                  before)
+    np.testing.assert_array_equal(_pool_bytes(cache), _pool_bytes(by_host))
+    s = eng.stats()
+    assert s["prefill_kv_host_writes"] == 0
+    assert s["prefill_kv_device_writes"] == 2 == model.prefill_calls
+    assert (by_host.range_writes_device, by_host.range_writes_host) == (0, 2)
+
+    # The first generated token, read through each pool by the paged
+    # step, and by the full forward.
+    import jax.numpy as jnp
+
+    from ray_tpu.models.transformer import forward
+
+    tokens = prompts["s"]
+    tok = int(np.argmax(logits["s"]))
+    step = []
+    for c in (cache, by_host):
+        assert c.allocate("s", n + 1, writable_from=n)
+        table = c.block_table("s")
+        step.append(np.asarray(c.paged_step(
+            [("s", n)], lambda pool, blocks, offs: model.decode_paged(
+                pool, [table], [tok], [n], blocks, offs, 16)))[0])
+    np.testing.assert_array_equal(step[0], step[1])
+    full, _ = forward(params, jnp.asarray([tokens + [tok]], jnp.int32), cfg)
+    np.testing.assert_allclose(logits["s"], np.asarray(full)[0, n - 1],
+                               atol=1e-4)
+    np.testing.assert_allclose(step[0], np.asarray(full)[0, n], atol=1e-4)
+
+
+@pytest.mark.parametrize("shared, first, others", [
+    (0, 33, (40, 48, 63)), (32, 41, (44, 47, 48))],
+    ids=["no_hit", "prefix_hit"])
+def test_prefill_compiles_per_bucket_not_per_prompt_length(
+        tiny_transformer, shared, first, others):
+    """After one prefill in a bucket, other prompt lengths of the same
+    bucket build no program: not the model's jits, and no slice, pad or
+    scatter compiled for the exact length. `shared` tokens of prefix
+    hit take the paged prefill-from-offset, by the same hand-over (its
+    bucket is the tail's)."""
+    from benchmarks.harness.serve_cell import CompileCounter
+    from ray_tpu.serve.engine import (EngineConfig, InferenceEngine,
+                                      TransformerEngineModel)
+
+    params, cfg = tiny_transformer
+    model = TransformerEngineModel(params, cfg)
+    eng = InferenceEngine(model, EngineConfig(
+        block_size=16, num_blocks=32, paged_decode=True))
+    head = _tokens(shared, 7)
+    # JAX's own compile event, as the benchmark's cells count it.
+    events = CompileCounter()
+
+    def prefill(n, seed):
+        stream = eng.submit(head + _tokens(n - shared, seed), 1)
+        while eng.step():
+            pass
+        assert len(list(stream)) == 1
+
+    if shared:
+        prefill(shared + 1, 99)     # seals the head's two blocks
+    prefill(first, 0)
+    assert events.count > 0
+    warm = (events.count, model.jit_compiles)
+    for n in others:
+        prefill(n, n)
+    assert (events.count, model.jit_compiles) == warm
+    s = eng.stats()
+    assert s["prefix_hit_tokens"] == (4 * shared if shared else 0)
+    assert s["prefill_kv_host_writes"] == 0
+    assert s["prefill_kv_device_writes"] == s["prefills"] == \
+        (5 if shared else 4)
+
+
+def test_host_pool_takes_a_device_payload_through_one_host_copy(
+        tiny_transformer):
+    from ray_tpu.serve.engine import (EngineConfig, InferenceEngine,
+                                      TransformerEngineModel)
+
+    params, cfg = tiny_transformer
+    model = TransformerEngineModel(params, cfg)
+    eng = InferenceEngine(model, EngineConfig(block_size=16, num_blocks=8))
+    assert eng.cache.pool_residency == "host"
+    tokens = _tokens(21, 3)
+    assert eng.cache.allocate("s", 21, writable_from=0)
+    _, kv = model.prefill(tokens)
+    assert hasattr(kv.padded, "block_until_ready") and len(kv) == 21
+    eng.cache.write_range("s", 0, kv)
+    rows = eng.cache.gather("s")
+    assert rows.shape == (21,) + model.kv_token_shape
+    np.testing.assert_array_equal(rows, np.asarray(kv.padded)[:21])
+    s = eng.stats()
+    assert (s["prefill_kv_device_writes"], s["prefill_kv_host_writes"]) \
+        == (0, 1)

@@ -278,29 +278,40 @@ def tiny_transformer():
     return init_params(jax.random.PRNGKey(0), cfg), cfg
 
 
+@pytest.mark.parametrize("paged", [True, False])
 def test_the_transformer_model_splits_its_calls_and_the_engine_reads_it(
-        recorder, tiny_transformer):
+        recorder, tiny_transformer, paged):
+    """The prompt KV stays on the device when `prefill` returns, so
+    `kv_d2h` stands still: a host pool's copy is part of
+    `engine.prefill.kv_write`. The non-paged decode has no spans."""
     from ray_tpu.serve.engine import TransformerEngineModel
 
     params, cfg = tiny_transformer
     model = TransformerEngineModel(params, cfg, max_batch_size=2)
     eng = InferenceEngine(model, EngineConfig(
-        max_batch_size=2, block_size=4, num_blocks=32, paged_decode=True))
+        max_batch_size=2, block_size=4, num_blocks=32, paged_decode=paged))
     assert set(model.phase.values()) == {0.0}
     streams = [eng.submit([2, 3, 4, 5 + i], 6) for i in range(2)]
     while eng.step():
         pass
     assert all(len(list(s)) == 6 for s in streams)
     done = _clocks(eng)
+    ran = {"model_prefill_prep", "model_prefill_dispatch",
+           "model_prefill_wait"}
+    if paged:
+        ran |= {"model_decode_prep", "model_decode_dispatch",
+                "model_decode_wait"}
     for phase in ("model_prefill_prep", "model_prefill_dispatch",
                   "model_prefill_wait", "model_prefill_kv_d2h",
                   "model_decode_prep", "model_decode_dispatch",
                   "model_decode_wait"):
-        assert done[f"phase.{phase}_s"] == \
-            model.phase[phase[len("model_"):] + "_s"] > 0, phase
+        seconds = model.phase[phase[len("model_"):] + "_s"]
+        assert done[f"phase.{phase}_s"] == seconds, phase
+        assert (seconds > 0) == (phase in ran), phase
     assert _phase_sum(done) == pytest.approx(done["loop_s"], rel=0.02)
     labels = [e[3] for e in flight.snapshot(categories={"model"})]
-    assert labels.count("prefill") == 2 == labels.count("prefill.kv_d2h")
+    assert labels.count("prefill") == 2
+    assert labels.count("prefill.kv_d2h") == 0
     assert labels.count("decode") == labels.count("decode.prep") == \
         labels.count("decode.dispatch") == labels.count("decode.logits_wait")
     # Each model call lies inside the engine span that made it.
@@ -310,6 +321,9 @@ def test_the_transformer_model_splits_its_calls_and_the_engine_reads_it(
     parents = [e for e in ring if e[2] == "engine"
                and e[3] in ("prefill", "model_step")]
     assert all(any(_contains(p, c) for p in parents) for c in model_calls)
+    s = eng.stats()
+    assert (s["prefill_kv_device_writes"], s["prefill_kv_host_writes"]) \
+        == ((2, 0) if paged else (0, 2))
 
 
 def _module_name(jitted, *args):
