@@ -106,6 +106,38 @@ class PromptKV:
         return host if dtype is None else host.astype(dtype, copy=False)
 
 
+class DecodeStep:
+    """A paged decode step's result as its jit returned it: `ids`, the
+    `[len()]` greedy tokens the program sampled on the device
+    (`jnp.argmax`, ties to the lowest index as in `np.argmax`), already
+    on the host, and the step's `[b_pad, V]` float32 logits, left on the
+    device.
+
+    The scheduler's sampler takes `ids` and nothing else crosses.
+    `np.asarray()` is the `[len(), V]` host logits for whoever asks (a
+    check against a reference, the scheduler's fully-cached prompt, a
+    test): one fetch of the padded bucket, counted in the model's
+    `decode_d2h_bytes`, cut on the host and kept."""
+
+    __slots__ = ("ids", "_logits", "_model")
+
+    def __init__(self, ids: np.ndarray, logits, model):
+        self.ids = ids
+        self._logits = logits
+        self._model = model
+
+    def __len__(self) -> int:
+        return len(self.ids)
+
+    def __array__(self, dtype=None, copy=None):
+        if not isinstance(self._logits, np.ndarray):
+            padded = np.asarray(self._logits)
+            self._model.decode_d2h_bytes += padded.nbytes
+            self._logits = padded[:len(self.ids)]
+        host = self._logits
+        return host if dtype is None else host.astype(dtype, copy=False)
+
+
 class TinyLM:
     """Deterministic cache-exercising toy LM.
 
@@ -264,6 +296,7 @@ class TransformerEngineModel:
 
     def __init__(self, params, cfg, max_batch_size: int = 8,
                  jit_cache_cap: int = 32):
+        import jax
         import jax.numpy as jnp
 
         if cfg.is_moe:
@@ -285,10 +318,19 @@ class TransformerEngineModel:
         self.prefill_tokens = 0
         self.decode_calls = 0
         self.jit_compiles = 0
+        # What a paged decode step moves across the host boundary: host
+        # arrays among the jitted call's arguments, which it uploads
+        # (one a step), and bytes brought back (the
+        # step's ids; its logits only where somebody asked for them).
+        # `InferenceEngine.stats()` reads both under these names.
+        self.decode_h2d_arrays = 0
+        self.decode_d2h_bytes = 0
         # Host side of the calls, in seconds, each fed by its
         # `flight.span`: input padding and upload (`prep`), the call of
-        # the jitted function (`dispatch`), the logits' arrival on the
-        # host (`wait`: the device's compute shows here).
+        # the jitted function (`dispatch`; a paged decode step's one
+        # host buffer goes up inside it), the result's arrival on the
+        # host (`wait`: the device's compute shows here; a prefill waits
+        # for its logits, a paged decode step for its sampled ids).
         # `prefill_kv_d2h_s` timed the prompt KV's trip to the host
         # while `prefill` made it; it stands still now, and stays for
         # the readers of `phase.model_prefill_kv_d2h_s`.
@@ -298,6 +340,7 @@ class TransformerEngineModel:
              "prefill_kv_d2h_s", "decode_prep_s", "decode_dispatch_s",
              "decode_wait_s"), 0.0)
         self._jnp = jnp
+        self._tree_leaves = jax.tree_util.tree_leaves
 
     @property
     def jit_cache_evictions(self) -> int:
@@ -577,15 +620,21 @@ class TransformerEngineModel:
 
     def _build_decode_paged(self, b_pad: int, nb_pad: int,
                             block_size: int):
-        """Fused paged decode step: gather, attend, AND write back in
-        one compiled call. The per-sequence KV is gathered from the
-        device pool INSIDE the jit — `jnp.take` over the padded block
-        tables, reshaped to the contiguous [B, S, ...] layout the core
-        attends over — and each new token's K/V is scattered into its
-        (block, off) slot before returning. The pool is DONATED: XLA
+        """Fused paged decode step: gather, attend, write back AND
+        sample in one compiled call. The per-sequence KV is gathered
+        from the device pool INSIDE the jit — `jnp.take` over the padded
+        block tables, reshaped to the contiguous [B, S, ...] layout the
+        core attends over — and each new token's K/V is scattered into
+        its (block, off) slot before returning. The pool is DONATED: XLA
         aliases input to output, so steady-state decode is one dispatch
-        with no pool copy and no KV payload crossing the host
-        boundary in either direction."""
+        with no pool copy and no KV payload crossing the host boundary
+        in either direction.
+
+        What does cross: in, ONE int32 array `packed` `[b_pad, 4 +
+        nb_pad]`, a row a sequence: token, position, write block, write
+        offset, then its block table. Out, the `[b_pad]` int32 greedy
+        ids. The `[b_pad, V]` float32 logits are an output too, and stay
+        on the device unless fetched (`DecodeStep`)."""
         import jax
         import jax.numpy as jnp
 
@@ -593,13 +642,15 @@ class TransformerEngineModel:
         s_pad = nb_pad * block_size
         kv_shape = self.kv_token_shape
 
-        def decode_paged(pool, params, tokens, positions, tables, wblocks,
-                         woffs):
-            # tables [b_pad, nb_pad] int32, zero-padded (rows past the
-            # batch and blocks past a row's coverage gather block 0;
+        def decode_paged(pool, params, packed):
+            # tables [b_pad, nb_pad], zero-padded (rows past the batch
+            # and blocks past a row's coverage gather block 0;
             # `attend`/`slot` in the core mask the garbage). wblocks
             # padding rows point past the pool, so mode="drop" skips
             # them — dummy batch rows never touch real blocks.
+            tokens, positions = packed[:, 0], packed[:, 1]
+            wblocks, woffs = packed[:, 2], packed[:, 3]
+            tables = packed[:, 4:]
             with jax.named_scope("kv_gather"):
                 flat = jnp.take(pool, tables.reshape(-1), axis=0)
                 cache = flat.reshape(
@@ -609,7 +660,9 @@ class TransformerEngineModel:
             with jax.named_scope("kv_write"):
                 new_pool = pool.at[wblocks, woffs].set(
                     new_kv.astype(pool.dtype), mode="drop")
-            return logits, new_pool
+            with jax.named_scope("sample"):
+                ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return ids, logits, new_pool
 
         return jax.jit(decode_paged, donate_argnums=0)
 
@@ -697,14 +750,23 @@ class TransformerEngineModel:
                      write_blocks: Sequence[int],
                      write_offs: Sequence[int], block_size: int):
         """One fused incremental step reading KV straight out of the
-        device pool and writing the new tokens' KV back in-place. Host
-        work is O(B) table/token padding (int32 scalars); the KV
-        payload never touches the host. Returns host logits for the
-        sampler plus the post-write pool (the input pool was donated —
-        the caller MUST re-bind, e.g. via `KVCacheManager.paged_step`).
-        `write_blocks` may be shorter than the batch; missing rows (and
-        batch padding rows) scatter past the pool and are dropped, so
-        an empty write list is a read-only step."""
+        device pool and writing the new tokens' KV back in-place. The
+        step crosses the host boundary once each way with a few
+        integers: host work is one pass that writes tokens, positions,
+        write slots and block tables into one int32 buffer, which the
+        jitted call uploads; what comes back (and what this call waits
+        for) is the `[b]` int32 greedy ids the same program sampled.
+        Neither the KV payload nor the logits touch the host.
+
+        Returns ``(step, new_pool)``: `step` is a `DecodeStep` (`.ids`
+        for the sampler; `np.asarray(step)` fetches the `[b, V]`
+        float32 logits for whoever needs them), `new_pool` the
+        post-write pool (the input pool was donated — the caller MUST
+        re-bind, e.g. via `KVCacheManager.paged_step`). `write_blocks`
+        may be shorter than the batch; missing rows (and batch padding
+        rows) scatter past the pool and are dropped, so an empty write
+        list is a read-only step. A host-resident pool takes the
+        unpaged `decode` below and returns its host logits."""
         b = len(last_tokens)
         if isinstance(pool, np.ndarray):
             # Host-resident pool with paged tables: gather on host
@@ -727,7 +789,7 @@ class TransformerEngineModel:
 
     def _decode_paged(self, pool, block_tables, last_tokens, positions,
                       write_blocks, write_offs, block_size: int):
-        jnp, phase = self._jnp, self.phase
+        phase = self.phase
         b = len(last_tokens)
         self.decode_calls += 1
         with flight.span("model", "decode.prep", None, phase,
@@ -740,29 +802,36 @@ class TransformerEngineModel:
             if fn is None:
                 fn = self._decode_paged_jit[key] = \
                     self._build_decode_paged(*key)
-            num_blocks = int(pool.shape[0])
-            tables = np.zeros((b_pad, nb_pad), np.int32)
-            toks = np.zeros((b_pad,), np.int32)
-            poss = np.zeros((b_pad,), np.int32)
-            wb = np.full((b_pad,), num_blocks, np.int32)  # default: drop
-            wo = np.zeros((b_pad,), np.int32)
+            # One host buffer, a row a sequence: token, position,
+            # write block (default past the pool: dropped), write
+            # offset, block table.
+            packed = np.zeros((b_pad, 4 + nb_pad), np.int32)
+            packed[:, 2] = int(pool.shape[0])
             for i in range(b):
-                row = np.asarray(block_tables[i][:nb_pad], np.int32)
-                tables[i, :row.shape[0]] = row
-                toks[i] = int(last_tokens[i])
-                poss[i] = int(positions[i])
+                table = block_tables[i][:nb_pad]
+                packed[i, 0] = last_tokens[i]
+                packed[i, 1] = positions[i]
+                packed[i, 4:4 + len(table)] = table
             k = min(len(write_blocks), b)
-            wb[:k] = np.asarray(write_blocks[:k], np.int32)
-            wo[:k] = np.asarray(write_offs[:k], np.int32)
-            args = (jnp.asarray(toks), jnp.asarray(poss),
-                    jnp.asarray(tables), jnp.asarray(wb), jnp.asarray(wo))
+            packed[:k, 2] = write_blocks[:k]
+            packed[:k, 3] = write_offs[:k]
+            # The call's arguments that are still host arrays: what the
+            # jitted call below uploads for this step.
+            args = (pool, self._params, packed)
+            self.decode_h2d_arrays += sum(
+                isinstance(leaf, np.ndarray)
+                for leaf in self._tree_leaves(args))
         with flight.span("model", "decode.dispatch", None, phase,
                          "decode_dispatch_s"):
-            logits, new_pool = fn(pool, self._params, *args)
+            # The jitted call uploads the host buffer itself: one
+            # transfer, and no separate call that lets other threads in
+            # before the step is on the device.
+            ids, logits, new_pool = fn(*args)
         with flight.span("model", "decode.logits_wait", None, phase,
                          "decode_wait_s"):
-            logits = np.asarray(logits)[:b]
-        return logits, new_pool
+            ids = np.asarray(ids)
+            self.decode_d2h_bytes += ids.nbytes
+        return DecodeStep(ids[:b], logits, self), new_pool
 
     def prefill_paged(self, tokens: Sequence[int], pool,
                       block_table: Sequence[int], prefix_len: int,

@@ -40,6 +40,18 @@ device and the pool lives there (`TransformerEngineModel`, paged), the
 KV is the jit's padded output (`model.PromptKV`) and one donated scatter
 writes it: it never crosses to the host. Any other pairing (a numpy
 pool, `TinyLM`) converts once and writes on the host.
+
+A decode step's sampling adapts the same way, to what the model's step
+returned. `TransformerEngineModel.decode_paged` crosses the host
+boundary once each way with a few integers: one packed int32 array in
+(tokens, positions, write slots, block tables; the jitted call uploads
+it), the greedy ids its program sampled out (`model.DecodeStep`); the
+`[b, V]` logits stay on the device and `engine.sample` takes the ids.
+Any other model or path (`TinyLM`, a host pool, the unpaged `decode`)
+returns host logits and `engine.sample` takes their argmax: the same
+tokens. Who may ask a `DecodeStep` for logits, through `np.asarray`: a
+fully cached prompt's first token below, a reference check, a test;
+never the steady step.
 """
 
 from __future__ import annotations
@@ -766,12 +778,22 @@ class InferenceEngine:
                 for i, seq in enumerate(batch):
                     self.cache.write(seq.seq_id, poss[i], new_kv[i])
         with flight.span("engine", "sample", b, clocks, "sample_s"):
-            logits = np.asarray(logits)
-            toks = [int(np.argmax(logits[i])) for i in range(b)]
+            toks = self._greedy(logits)
         with flight.span("engine", "emit", b, clocks, "emit_s"):
             for seq, tok in zip(batch, toks):
                 self._emit(seq, tok)
                 self._maybe_finish(seq)
+
+    @staticmethod
+    def _greedy(step) -> List[int]:
+        """A decode step's greedy tokens: the ids its program sampled
+        where the model's result carries them (`model.DecodeStep`: no
+        logits cross to the host), else the argmax over the logits it
+        returned; ties go to the lowest index either way."""
+        ids = getattr(step, "ids", None)
+        if ids is None:
+            ids = np.argmax(np.asarray(step), axis=-1)
+        return ids.tolist()
 
     def _emit(self, seq: _Sequence, tok: int) -> None:
         seq.all_tokens.append(tok)
@@ -887,7 +909,8 @@ class InferenceEngine:
 
     @property
     def decode_s(self) -> float:
-        """A decode step up to its logits: gather + model step + write."""
+        """A decode step up to its result on the host (the paged step's
+        sampled ids; logits otherwise): gather + model step + write."""
         clocks = self._clocks
         return (clocks["kv_gather_s"] + clocks["model_step_s"]
                 + clocks["kv_write_s"])
@@ -920,13 +943,19 @@ class InferenceEngine:
         `stream_wake_s` / `stream_wake_tokens` sum, over tokens handed
         to a consumer, the time from `TokenStream._push` to pickup.
         `prefill_s` is all of `_prefill`, `decode_s` a decode step up to
-        its logits = `kv_gather_s` + `model_step_s` + `kv_write_s`. Under
-        paged decode `kv_gather_s` is the block-table build (no KV is
-        gathered on the host) and `kv_write_s` stays 0 (the write is
-        fused into the model step). `prefill_kv_device_writes` and
-        `prefill_kv_host_writes` count the prompt-KV writes into the
-        pool (`write_range`) that stayed on the device, and those that
-        passed through host memory."""
+        its result on the host = `kv_gather_s` + `model_step_s` +
+        `kv_write_s`. Under paged decode `kv_gather_s` is the block-table
+        build (no KV is gathered on the host) and `kv_write_s` stays 0
+        (the write is fused into the model step).
+        `prefill_kv_device_writes` and `prefill_kv_host_writes` count
+        the prompt-KV writes into the pool (`write_range`) that stayed
+        on the device, and those that passed through host memory.
+        `decode_h2d_arrays` and `decode_d2h_bytes` are what the model's
+        paged decode steps moved across the host boundary: host arrays
+        among the arguments of its jitted calls, which the call uploads
+        (one a step), and bytes brought back (a step's sampled
+        ids, 4 a padded row; its `[b_pad, V]` logits only where
+        somebody fetched them); 0 for a model that keeps no such count."""
         with self._lock:
             running = len(self._running)
             waiting = len(self._waiting)
@@ -952,6 +981,9 @@ class InferenceEngine:
             "paged_steps": self.paged_steps,
             "prefill_kv_device_writes": self.cache.range_writes_device,
             "prefill_kv_host_writes": self.cache.range_writes_host,
+            "decode_h2d_arrays": getattr(
+                self.model, "decode_h2d_arrays", 0),
+            "decode_d2h_bytes": getattr(self.model, "decode_d2h_bytes", 0),
             "jit_bucket_evictions": getattr(
                 self.model, "jit_cache_evictions", 0),
             "prefill_s": round(self.prefill_s, 6),

@@ -742,3 +742,191 @@ def test_host_pool_takes_a_device_payload_through_one_host_copy(
     s = eng.stats()
     assert (s["prefill_kv_device_writes"], s["prefill_kv_host_writes"]) \
         == (0, 1)
+
+
+# ---------------------------------------------------------------------------
+# a paged decode step's host boundary: one packed upload in, sampled ids
+# out, the logits left on the device
+# ---------------------------------------------------------------------------
+def _paged_transformer_engine(params, cfg, **config):
+    from ray_tpu.serve.engine import TransformerEngineModel
+
+    config.setdefault("block_size", 16)
+    model = TransformerEngineModel(params, cfg)
+    model.eos_token = None      # random weights: no token ends a stream
+    return model, InferenceEngine(model, EngineConfig(
+        num_blocks=64, paged_decode=True, **config))
+
+
+def _wrap_decode_paged(model, after):
+    """Pass every paged step's result through `after`."""
+    inner = model.decode_paged
+
+    def decode_paged(*args):
+        step, pool = inner(*args)
+        return after(step), pool
+
+    model.decode_paged = decode_paged
+
+
+@pytest.mark.parametrize("b, block_size, prompt_len, new_tokens", [
+    (1, 16, 5, 4), (3, 16, 6, 4), (8, 16, 5, 4), (2, 4, 3, 7)],
+    ids=["b1", "b3", "b8", "row_crosses_blocks"])
+def test_sampled_ids_are_the_argmax_of_the_steps_own_logits(
+        tiny_transformer, b, block_size, prompt_len, new_tokens):
+    """The ids the packed program samples equal `np.argmax` of the
+    logits fetched from the same step, and the token streams through the
+    ids equal those through the scheduler's logits fallback (the same
+    model, handing plain host logits to `engine.sample`)."""
+    from ray_tpu.serve.engine.model import DecodeStep
+
+    params, cfg = tiny_transformer
+    prompts = [_tokens(prompt_len + i % 2, 100 + i) for i in range(b)]
+    streams, seen = {}, []
+
+    def check(step):
+        assert isinstance(step, DecodeStep)
+        seen.append(len(step))
+        np.testing.assert_array_equal(
+            step.ids, np.argmax(np.asarray(step), axis=-1))
+        return step
+
+    for way, after in (("ids", check), ("logits", np.asarray)):
+        model, eng = _paged_transformer_engine(
+            params, cfg, block_size=block_size, max_batch_size=b)
+        _wrap_decode_paged(model, after)
+        handles = [eng.submit(p, new_tokens) for p in prompts]
+        _drive(eng)
+        streams[way] = [h.tokens_so_far() for h in handles]
+        assert eng.stats()["paged_steps"] == new_tokens - 1
+    assert streams["ids"] == streams["logits"]
+    assert all(len(s) == new_tokens for s in streams["ids"])
+    assert seen == [b] * (new_tokens - 1)
+
+
+def test_a_tie_in_the_logits_goes_to_the_lowest_index(tiny_transformer):
+    """Every row of the embedding the same: every logit of a row ties,
+    and the jit's argmax, like `np.argmax`, takes index 0."""
+    import jax.numpy as jnp
+
+    params, cfg = tiny_transformer
+    tied = dict(params, embed=jnp.tile(params["embed"][3:4],
+                                       (cfg.vocab_size, 1)))
+    model, eng = _paged_transformer_engine(tied, cfg)
+    step = eng.cache.mutate_pool(lambda pool: model.decode_paged(
+        pool, [[0]] * 3, [5, 9, 2], [3, 7, 0], [], [], 16))
+    logits = np.asarray(step)
+    assert np.abs(logits).min() > 0
+    np.testing.assert_array_equal(np.ptp(logits, axis=-1), 0.0)
+    assert step.ids.tolist() == [0, 0, 0] == \
+        np.argmax(logits, axis=-1).tolist()
+
+
+@pytest.mark.parametrize("b", [1, 3, 8])
+def test_a_step_uploads_one_array_and_brings_back_its_ids(
+        tiny_transformer, b):
+    """`decode_h2d_arrays` grows by 1 a step and `decode_d2h_bytes` by
+    the padded ids; `np.asarray(step)` has `b` rows, fetches the padded
+    logits once and adds their bytes; an empty write list leaves the
+    pool as it was."""
+    from ray_tpu.serve.engine.model import _next_pow2
+
+    params, cfg = tiny_transformer
+    model, eng = _paged_transformer_engine(params, cfg)
+    b_pad = _next_pow2(b)
+    assert eng.cache.allocate("s", 20, writable_from=0)
+    eng.cache.write_range("s", 0, model.prefill(_tokens(20, 4))[1])
+    table = eng.cache.block_table("s")
+    pool_before = _pool_bytes(eng.cache)
+    before = eng.stats()
+    step = eng.cache.mutate_pool(lambda pool: model.decode_paged(
+        pool, [table] * b, [7] * b, [19] * b, [], [], 16))
+    np.testing.assert_array_equal(_pool_bytes(eng.cache), pool_before)
+    after = eng.stats()
+    assert after["decode_h2d_arrays"] - before["decode_h2d_arrays"] == 1
+    assert after["decode_d2h_bytes"] - before["decode_d2h_bytes"] \
+        == 4 * b_pad
+    assert len(step) == b and step.ids.dtype == np.int32
+
+    logits = np.asarray(step)
+    assert logits.shape == (b, cfg.vocab_size)
+    assert logits.dtype == np.float32
+    fetched = eng.stats()["decode_d2h_bytes"] - after["decode_d2h_bytes"]
+    assert fetched == 4 * b_pad * cfg.vocab_size
+    assert np.asarray(step) is logits
+    assert eng.stats()["decode_d2h_bytes"] \
+        == after["decode_d2h_bytes"] + fetched
+    # The same rows read the same cache: one row's logits, b times.
+    np.testing.assert_array_equal(logits, np.tile(logits[:1], (b, 1)))
+
+
+def test_the_upload_count_follows_the_calls_arguments(tiny_transformer):
+    """`decode_h2d_arrays` counts what the jitted call is handed from the
+    host: weights left on the host would go up with every step, and show."""
+    import jax
+
+    params, cfg = tiny_transformer
+    model, eng = _paged_transformer_engine(params, cfg)
+    assert eng.cache.allocate("s", 20, writable_from=0)
+    eng.cache.write_range("s", 0, model.prefill(_tokens(20, 4))[1])
+    table = eng.cache.block_table("s")
+
+    def one_step():
+        before = model.decode_h2d_arrays
+        step = eng.cache.mutate_pool(lambda pool: model.decode_paged(
+            pool, [table], [7], [19], [], [], 16))
+        return model.decode_h2d_arrays - before, step.ids
+
+    count, ids = one_step()
+    assert count == 1
+    model._params = jax.tree_util.tree_map(np.asarray, model._params)
+    count_host, ids_host = one_step()
+    assert count_host == 1 + len(jax.tree_util.tree_leaves(params))
+    np.testing.assert_array_equal(ids_host, ids)
+
+
+def test_a_served_stream_fetches_no_logits(tiny_transformer):
+    """Through the engine, every paged step is one upload and 4 bytes a
+    padded row back; a fully cached prompt's first token is the one
+    place the scheduler asks a step for its logits."""
+    params, cfg = tiny_transformer
+    model, eng = _paged_transformer_engine(params, cfg, max_batch_size=4)
+    assert (eng.stats()["decode_h2d_arrays"],
+            eng.stats()["decode_d2h_bytes"]) == (0, 0)
+    prompt = _tokens(32, 8)          # two full blocks: fully cacheable
+    handles = [eng.submit(_tokens(5, i), 6) for i in range(3)]
+    handles.append(eng.submit(prompt, 6))
+    _drive(eng)
+    s = eng.stats()
+    assert s["paged_steps"] == 5 == s["decode_h2d_arrays"]
+    assert s["decode_d2h_bytes"] == 5 * 4 * 4
+    again = eng.submit(prompt, 2)
+    _drive(eng)
+    assert again.tokens_so_far() == handles[-1].tokens_so_far()[:2]
+    s2 = eng.stats()
+    assert s2["prefix_hit_tokens"] == 32
+    # The read-only step of the full hit (b_pad 1) and one decode step;
+    # the first fetched its [1, V] logits.
+    assert s2["decode_h2d_arrays"] - s["decode_h2d_arrays"] == 2
+    assert s2["decode_d2h_bytes"] - s["decode_d2h_bytes"] \
+        == 2 * 4 + 4 * cfg.vocab_size
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["host", "paged"])
+def test_a_model_that_returns_logits_is_sampled_on_the_host(paged):
+    """`TinyLM` returns host logits on either path: the engine emits the
+    oracle's tokens and counts nothing across the boundary. Both
+    counters are in `stats()` from construction."""
+    model = TinyLM(vocab_size=32)
+    eng = InferenceEngine(model, EngineConfig(
+        block_size=4, num_blocks=64, max_batch_size=3, paged_decode=paged))
+    s = eng.stats()
+    assert (s["decode_h2d_arrays"], s["decode_d2h_bytes"]) == (0, 0)
+    prompts = [[5, 9, 3], [7, 2, 11, 4, 6], [12]]
+    handles = [eng.submit(p, 9) for p in prompts]
+    _drive(eng)
+    for p, h in zip(prompts, handles):
+        assert h.tokens_so_far() == model.oracle(p, 9)
+    s = eng.stats()
+    assert s["paged_steps"] == (8 if paged else 0)
+    assert (s["decode_h2d_arrays"], s["decode_d2h_bytes"]) == (0, 0)

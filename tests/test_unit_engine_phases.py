@@ -362,9 +362,7 @@ def test_device_programs_are_named_after_their_functions(tiny_transformer):
         jnp.zeros((2,), i32), cache) == "jit_decode"
     assert _module_name(
         model._build_decode_paged(2, 2, 4), pool, params,
-        jnp.zeros((2,), i32), jnp.zeros((2,), i32),
-        jnp.zeros((2, 2), i32), jnp.zeros((2,), i32),
-        jnp.zeros((2,), i32)) == "jit_decode_paged"
+        jnp.zeros((2, 4 + 2), i32)) == "jit_decode_paged"
 
     optimizer = optax.sgd(0.1)
     step = make_train_step(lambda p, b: lm_loss(p, b, cfg), optimizer)
