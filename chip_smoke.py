@@ -8,7 +8,7 @@ weights are random, made from a seed):
   worker that leases every local chip -> `jax.distributed` -> mesh ->
   `make_train_step`, fed by a `ray_tpu.data` iterator;
 - serve: `serve.run` -> replica actor holding `{"TPU": 1}` ->
-  `InferenceEngine(paged_decode=True)` -> device KV pool ->
+  `InferenceEngine` -> device KV pool ->
   `TransformerEngineModel`, asked through a streaming handle and the HTTP
   proxy.
 
@@ -258,8 +258,7 @@ def _smoke_deployment(chips: int):
             # Random weights give no token the meaning "end of sequence".
             self.model.eos_token = None
             self.engine = InferenceEngine(self.model, EngineConfig(
-                paged_decode=True, max_batch_size=8, block_size=16,
-                num_blocks=512))
+                max_batch_size=8, block_size=16, num_blocks=512))
             self.engine.start()
             jax.block_until_ready(params)
             self.init_s = time.perf_counter() - t0

@@ -808,36 +808,6 @@ def run_ha_bench(scale: float = 1.0, n_nodes: int = 0) -> Dict[str, Any]:
         return asyncio.run(bench(os.path.join(td, "gcs.pkl")))
 
 
-_PAGED_BENCH_MODEL = None
-
-
-def _paged_bench_model():
-    """One `TransformerEngineModel` shared across bench rounds and
-    modes: the fold-best runner calls `run_llm_serve_bench` repeatedly,
-    and re-paying XLA compiles per round would swamp the decode-loop
-    cost the paged section measures."""
-    global _PAGED_BENCH_MODEL
-    if _PAGED_BENCH_MODEL is None:
-        import jax
-
-        from ray_tpu.models.transformer import (TransformerConfig,
-                                                init_params)
-        from ray_tpu.serve.engine import TransformerEngineModel
-
-        # d_model 256 / 8 heads -> 4 KB of KV per token: big enough
-        # that the per-step host materialization (zeros + per-sequence
-        # row copies + transfer) the paged path removes is a real
-        # fraction of the step, small enough to stay CPU-cheap. (At
-        # d_model 64 the payload is 1 KB/token and XLA's CPU gather
-        # overhead eats the win.)
-        cfg = TransformerConfig(vocab_size=128, d_model=256, n_layers=2,
-                                n_heads=8, d_ff=256, max_seq_len=256)
-        _PAGED_BENCH_MODEL = TransformerEngineModel(
-            init_params(jax.random.PRNGKey(0), cfg), cfg,
-            max_batch_size=16)
-    return _PAGED_BENCH_MODEL
-
-
 def run_llm_serve_bench(scale: float = 1.0) -> Dict[str, Any]:
     """LLM-serving scenario: the continuous-batching engine vs the
     `@serve.batch`-style static policy on the SAME mixed-length
@@ -864,11 +834,9 @@ def run_llm_serve_bench(scale: float = 1.0) -> Dict[str, Any]:
         the prompt's blocks) vs off (cold: every request re-prefills),
         with warm/cold TTFT p50s, llm_prefix_hit_tokens and
         llm_prefix_cow_copies riding along
-      llm_paged_vs_host                  : device-resident paged decode
-        (in-jit block gather + donated pool writes) vs the host-gather
-        loop over the flagship TransformerEngineModel, with
-        llm_paged_steps / llm_paged_host_gathers / llm_paged_parity
-        structural asserts riding along
+      llm_paged_steps / llm_paged_host_gathers : the mixed workload's
+        engine ran its decode steps through `decode_paged` and gathered
+        no sequence's KV on the host
     """
     import numpy as np  # noqa: F401  (engine dependency, imported early)
 
@@ -907,6 +875,8 @@ def run_llm_serve_bench(scale: float = 1.0) -> Dict[str, Any]:
         if policy == "continuous":
             st = eng.stats()
             out["llm_ttft_p50_ms"] = st["ttft_p50_ms"]
+            out["llm_paged_steps"] = st["paged_steps"]
+            out["llm_paged_host_gathers"] = st["cache"]["host_gathers"]
     out["llm_engine_vs_static"] = round(
         out["llm_engine_tok_s"] / max(out["llm_static_tok_s"], 1e-9), 2)
 
@@ -1004,78 +974,6 @@ def run_llm_serve_bench(scale: float = 1.0) -> Dict[str, Any]:
     out["llm_prefix_ttft_cold_over_warm"] = round(
         out["llm_prefix_cold_ttft_p50_ms"]
         / max(out["llm_prefix_warm_ttft_p50_ms"], 1e-9), 2)
-
-    # -- paged vs host-gather decode (PR 20) ---------------------------
-    # The device-resident paged path (pool + block tables into ONE
-    # fused donated jit per step: in-jit `jnp.take` gather, decode
-    # math, in-place KV scatter) vs the identical loop materializing
-    # the padded KV batch on the host every step — over the flagship
-    # TransformerEngineModel (TinyLM's numpy decode would hide the
-    # data movement this path removes). The host side pays O(batch)
-    # per-sequence gathers + row copies + a zeros alloc per step, so
-    # the measured win scales with occupancy and decode length; the
-    # workload is therefore FIXED (batch 16, 96-token decodes), not
-    # `scale`d — shrinking it shrinks the structural gap the floor
-    # guards, not just the runtime. Rounds INTERLEAVE the two modes so
-    # box-level drift (turbo, neighbors) common-modes out of the
-    # fold-best ratio, and each engine gets two untimed warmups: the
-    # first compiles the cold-cache buckets, the second the
-    # prefix-hit/adoption buckets only repeat traffic reaches.
-    model = _paged_bench_model()
-
-    def paged_workload():
-        reqs = []
-        for i in range(16):
-            plen = 6 + (i * 7) % 19
-            prompt = [2 + ((i * 5 + j) % 120) for j in range(plen)]
-            reqs.append((prompt, 96))
-        return reqs
-
-    def paged_engine(paged: bool):
-        eng = InferenceEngine(model, EngineConfig(
-            max_batch_size=16, block_size=16, num_blocks=192,
-            max_queue=256, paged_decode=paged))
-        # Threaded (production-shaped) drive: the engine loop overlaps
-        # with the consumer drain, the same overlap a replica serves
-        # under — and the shape where one-dispatch-per-step pays most.
-        eng.start()
-        return eng
-
-    def run_paged_round(eng):
-        reqs = paged_workload()
-        t0 = time.perf_counter()
-        streams = [eng.submit(p, n) for p, n in reqs]
-        toks = [tuple(s) for s in streams]
-        dt = time.perf_counter() - t0
-        assert all(s.finished for s in streams)
-        return sum(len(t) for t in toks) / dt, toks
-
-    eng_pg = paged_engine(True)
-    eng_hg = paged_engine(False)
-    for eng in (eng_pg, eng_hg):
-        run_paged_round(eng)
-        run_paged_round(eng)
-    best_pg = best_hg = 0.0
-    toks_pg = toks_hg = None
-    for _ in range(3):
-        r, toks_pg = run_paged_round(eng_pg)
-        best_pg = max(best_pg, r)
-        r, toks_hg = run_paged_round(eng_hg)
-        best_hg = max(best_hg, r)
-    out["llm_paged_tok_s"] = round(best_pg, 1)
-    out["llm_hostgather_tok_s"] = round(best_hg, 1)
-    out["llm_paged_vs_host"] = round(best_pg / max(best_hg, 1e-9), 2)
-    # Structural honesty: the paged engine actually ran paged steps,
-    # never host-gathered KV, and emitted token-for-token what the
-    # host-gather loop emitted.
-    out["llm_paged_steps"] = eng_pg.paged_steps
-    out["llm_paged_host_gathers"] = eng_pg.cache.host_gathers
-    out["llm_paged_parity"] = int(toks_pg == toks_hg)
-    out["llm_paged_kv_gather_ms"] = round(eng_pg.kv_gather_s * 1e3, 2)
-    out["llm_hostgather_kv_gather_ms"] = round(
-        eng_hg.kv_gather_s * 1e3, 2)
-    eng_pg.stop()
-    eng_hg.stop()
     return out
 
 

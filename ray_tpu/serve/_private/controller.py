@@ -247,14 +247,18 @@ class ServeController:
         until the route table or any listed deployment's routing version
         moves past the caller's snapshot, or timeout_s elapses; returns
         the changed snapshots. `versions` maps "__routes__" and
-        deployment names to the caller's last-seen versions."""
+        deployment names to the caller's last-seen versions; a caller
+        hears only of the keys it listed (a router lists its deployment
+        and not "__routes__": told of the route table, which it cannot
+        acknowledge, its poll would return at once, every time)."""
         self._ensure_reconciler()
         loop = asyncio.get_running_loop()
         deadline = loop.time() + timeout_s
 
         def changed() -> Dict[str, Any]:
             out: Dict[str, Any] = {}
-            if self._routes_version > versions.get("__routes__", -1):
+            if self._routes_version > versions.get(
+                    "__routes__", self._routes_version):
                 out["__routes__"] = {"version": self._routes_version,
                                      "routes": dict(self._routes)}
             for name, seen in versions.items():

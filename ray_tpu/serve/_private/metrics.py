@@ -127,9 +127,9 @@ def engine_metrics() -> Dict[str, Any]:
             "step_phase": Counter(
                 "serve_engine_step_seconds",
                 "Cumulative model time split by phase",
-                # prefill | decode | kv_gather | model_step | kv_write
-                # (the last three split the decode step — paged decode
-                # collapses kv_gather to table padding), and the engine
+                # prefill | decode | kv_gather | model_step (the last
+                # two split the decode step: kv_gather is the block
+                # tables' build), and the engine
                 # loop's own partition of its wall time
                 # (`InferenceEngine.phase_seconds`): park, reap, admit,
                 # capacity, prefill_match, prefill_kv_write,

@@ -34,6 +34,9 @@ class Router:
         self._session_affinity: Dict[str, str] = {}
         self._lock = threading.Lock()
         self._poller: Optional[threading.Thread] = None
+        # The long poll is answering: it delivers every change of the
+        # table, so a request need not ask the controller itself.
+        self._poll_live = False
 
     def _apply(self, table: Dict[str, Any]) -> None:
         with self._lock:
@@ -49,8 +52,13 @@ class Router:
 
         self._ensure_poller()
         now = time.monotonic()
-        if not force and now - self._last_refresh \
-                < self._refresh_interval_s:
+        if not force and (
+                (self._poll_live and self._version != -2)
+                or now - self._last_refresh < self._refresh_interval_s):
+            # The table is current (the poller hears of every change
+            # within a round trip) or fresh enough. Only the first
+            # request, one after `invalidate`, and requests while the
+            # controller does not answer the poll ask on their own path.
             return
         try:
             table = ray_tpu.get(
@@ -95,9 +103,15 @@ class Router:
                         # Deployment gone: stop holding a controller
                         # slot. A redeploy restarts the poller through
                         # _refresh -> _ensure_poller.
+                        self._poll_live = False
                         return
+                self._poll_live = True
             except Exception:
-                time.sleep(1.0)  # controller restarting: retry
+                # Controller restarting: requests fall back to the timed
+                # refresh (which keeps the cached replicas) until the
+                # poll is answered again.
+                self._poll_live = False
+                time.sleep(1.0)
 
     def _choose(self, model_id: Optional[str] = None,
                 session_id: Optional[str] = None) -> Tuple[str, Any]:

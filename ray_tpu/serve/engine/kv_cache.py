@@ -28,25 +28,25 @@ The manager owns two things:
   `adopt` / `free` are what the iteration scheduler calls between
   decode steps;
 - **storage**: the preallocated `[num_blocks, block_size, *kv_shape]`
-  buffer itself, with `write` / `write_range` / `gather` translating
-  logical token positions through the table. The buffer namespace
-  is pluggable: numpy (default — zero-copy views, exact, fast under
-  `JAX_PLATFORMS=cpu`) or a **device-resident pool**
-  (`device_pool=True`): the buffer lives as one `jax.numpy` array and
-  every mutation (`write`, `write_range`, COW privatize,
-  `install_block`, the batched `write_step`) goes through a
+  buffer itself. The engine's model reads it through block tables
+  inside its own step: `paged_step` (a decode step: slots resolved,
+  the donated pool re-bound), `mutate_pool` (a read-only step) and
+  `with_pool` (the paged prefill) hand the live buffer to the dispatch
+  under the lock, and `write_range` stores a prefill's rows. `write` /
+  `gather` are the manager's own by-position write and read (tests of
+  tables, refcounts and COW use them; the engine calls neither, so the
+  `host_gathers` counter stays 0 over a served run and the benchmark
+  asserts it). The buffer lives in the array namespace the engine read
+  off its model (`array_ns`): numpy (`TinyLM`: views, exact, no XLA
+  compile under `JAX_PLATFORMS=cpu`) or `jax.numpy`, a
+  **device-resident pool** whose every mutation (`write`,
+  `write_range`, COW privatize, `install_block`) goes through a
   donated-argument jitted update — the pool is threaded through the
   jit and donated back, so XLA aliases input to output and steady-state
   decode neither copies the pool nor allocates a second one. A
   prefill's KV that is still on the device (`model.PromptKV`) is that
   update's payload as it stands: the prompt KV never visits the host
-  on its way into a device pool. The paged decode path
-  (`EngineConfig(paged_decode=True)`) reads the pool *inside* the
-  model's compiled step via `jnp.take` over block tables (`with_pool`
-  hands the live buffer to the dispatch under the lock), which removes
-  the per-step host `gather`/pad entirely; the `host_gathers` counter
-  proves it (the paged perf guard asserts it stays zero across a whole
-  decode run).
+  on its way into a device pool.
 
 Determinism contract (the scheduler's loop must never crash on OOM):
 `allocate` is atomic — it either extends the table (and privatizes the
@@ -111,10 +111,9 @@ class _DevicePoolOps:
 
         def scatter(pool, blocks, offs, vals):
             # Batched token write: one (block, off) slot per row —
-            # a whole prefill range or one decode step's batch in a
-            # single dispatch. Padding rows carry block == num_blocks
-            # (out of range) and are dropped, so one compile per pow2
-            # row bucket suffices.
+            # a whole prefill range in a single dispatch. Padding rows
+            # carry block == num_blocks (out of range) and are dropped,
+            # so one compile per pow2 row bucket suffices.
             return pool.at[blocks, offs].set(vals, mode="drop")
 
         self.copy_block = jax.jit(copy_block, donate_argnums=0)
@@ -148,17 +147,12 @@ class KVCacheManager:
 
     def __init__(self, num_blocks: int, block_size: int,
                  kv_shape: Tuple[int, ...] = (), dtype=np.float32,
-                 array_ns=None, device_pool: bool = False):
+                 array_ns=None):
         if num_blocks <= 0 or block_size <= 0:
             raise ValueError("num_blocks and block_size must be positive")
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
         self.kv_shape = tuple(kv_shape)
-        if device_pool and array_ns is None:
-            # Asked for a device pool: get one or raise.
-            import jax.numpy as jnp
-
-            array_ns = jnp
         self._ns = array_ns if array_ns is not None else np
         self._device = self._ns is not np
         self._dtype = dtype
@@ -170,9 +164,9 @@ class KVCacheManager:
             (self.num_blocks, self.block_size) + self.kv_shape, dtype)
         # Data-movement honesty counters: `host_gathers` counts calls
         # that materialize per-sequence KV for host-side consumption
-        # (the cost the paged path exists to remove — its perf guard
-        # asserts this stays 0 across a decode run); `pool_updates`
-        # counts donated in-place pool mutations on the device path.
+        # (`gather`: the engine makes none, and the benchmark asserts
+        # this stays 0 across a run); `pool_updates` counts donated
+        # in-place pool mutations on the device path.
         self.host_gathers = 0
         self.pool_updates = 0
         # `write_range` calls whose payload went from the device into a
@@ -184,10 +178,6 @@ class KVCacheManager:
         self._refs: Dict[int, int] = {}          # block -> holder count
         self._tables: Dict[str, List[int]] = {}
         self._lens: Dict[str, int] = {}
-        # Precomputed per-sequence index arrays for `gather` — rebuilt
-        # lazily after any table mutation instead of re-converting the
-        # Python list on every decode step.
-        self._table_arrays: Dict[str, np.ndarray] = {}
         # Reentrant: `with_pool` callbacks legitimately read tables /
         # lengths through the public accessors while the lock is held.
         self._lock = threading.RLock()
@@ -314,8 +304,6 @@ class KVCacheManager:
             for i in range(first, last):
                 if self._refs.get(table[i], 0) > 1:
                     self._privatize_locked(seq_id, i)
-        if grow:
-            self._table_arrays.pop(seq_id, None)
 
     def adopt(self, seq_id: str, blocks: Sequence[int],
               n_tokens: int) -> None:
@@ -337,7 +325,6 @@ class KVCacheManager:
                 self._refs[b] += 1
             self._tables[seq_id] = list(blocks)
             self._lens[seq_id] = n_tokens
-            self._table_arrays.pop(seq_id, None)
             self.adoptions += 1
 
     def retain(self, block: int) -> None:
@@ -373,7 +360,6 @@ class KVCacheManager:
         with self._lock:
             table = self._tables.pop(seq_id, [])
             self._lens.pop(seq_id, None)
-            self._table_arrays.pop(seq_id, None)
             freed = 0
             for b in reversed(table):
                 if self._release_locked(b):
@@ -450,7 +436,6 @@ class KVCacheManager:
         self._refs[new] = 1
         self._refs[old] -= 1          # shared => was > 1, stays >= 1
         table[block_idx] = new
-        self._table_arrays.pop(seq_id, None)
         self.cow_copies += 1
         return new
 
@@ -466,8 +451,8 @@ class KVCacheManager:
     def _pool_scatter(self, blocks: np.ndarray, offs: np.ndarray,
                       values, n: int) -> None:
         """ONE donated scatter for `n` token rows: a whole prefill
-        range (any number of blocks, any offsets) or one decode step's
-        batch lands in a single dispatch. Compiles are per row bucket:
+        range (any number of blocks, any offsets) lands in a single
+        dispatch. Compiles are per row bucket:
         rows past `n` point past the pool and drop. A payload whose
         rows are on the device (`_device_rows`) is scattered as it
         stands, its own padding being the bucket; a host payload pads
@@ -543,35 +528,6 @@ class KVCacheManager:
                 self._pool_scatter(blocks, offs, values, n)
             self._lens[seq_id] = max(self._lens.get(seq_id, 0), start + n)
 
-    def write_step(self, entries: Sequence[Tuple[str, int]],
-                   values) -> None:
-        """Batched one-token-per-sequence decode-step write: row i of
-        `values` (`[b_pad, *kv_shape]`) lands at `entries[i]`'s
-        (seq_id, pos) slot. Padding rows past `len(entries)` are
-        ignored (device path: scattered to an out-of-range block and
-        dropped, so one compile covers every batch bucket). Shared
-        blocks privatize first (COW), same as `write`."""
-        b = len(entries)
-        rows = int(values.shape[0])
-        with self._lock:
-            blocks = np.full((rows,), self.num_blocks, np.int32)
-            offs = np.zeros((rows,), np.int32)
-            for i, (seq_id, pos) in enumerate(entries):
-                blk, off = self._writable_block(seq_id, pos)
-                blocks[i] = blk
-                offs[i] = off
-                self._lens[seq_id] = max(
-                    self._lens.get(seq_id, 0), pos + 1)
-            if self._ns is np:
-                vals = np.asarray(values)
-                self._buffer[blocks[:b], offs[:b]] = vals[:b]
-            else:
-                self._buffer = self._ops.scatter(
-                    self._buffer, self._ns.asarray(blocks),
-                    self._ns.asarray(offs),
-                    self._ns.asarray(values, self._dtype))
-                self.pool_updates += 1
-
     def with_pool(self, fn):
         """Run `fn(pool)` on the live device buffer under the cache
         lock — the in-jit reader's entry point (paged prefill passes
@@ -625,25 +581,19 @@ class KVCacheManager:
                     self._lens.get(seq_id, 0), pos + 1)
             return result
 
-    def _table_array(self, seq_id: str) -> np.ndarray:
-        arr = self._table_arrays.get(seq_id)
-        if arr is None:
-            arr = np.asarray(self._tables.get(seq_id, ()), np.int64)
-            self._table_arrays[seq_id] = arr
-        return arr
-
     def gather(self, seq_id: str, length: Optional[int] = None):
-        """Contiguous `[length, *kv_shape]` view of a sequence's cache —
-        what the model's decode step attends over. One fancy-indexing
-        gather over whole blocks through the precomputed per-sequence
-        index array (no per-position work)."""
+        """Contiguous `[length, *kv_shape]` view of a sequence's cache,
+        by position (the engine's model reads the pool through block
+        tables instead; this is for tests and tools). One fancy-indexing
+        gather over whole blocks (no per-position work)."""
         with self._lock:
             self.host_gathers += 1
             n = self._lens.get(seq_id, 0) if length is None else length
             if n == 0:
                 return self._buffer[0, 0:0]
             nblocks = math.ceil(n / self.block_size)
-            idx = self._table_array(seq_id)[:nblocks]
+            idx = np.asarray(self._tables.get(seq_id, ())[:nblocks],
+                             np.int64)
             if self._ns is np:
                 out = self._buffer[idx].reshape(
                     (nblocks * self.block_size,) + self.kv_shape)

@@ -1,24 +1,34 @@
 """Decode-step model shims for the continuous-batching engine.
 
-The iteration scheduler drives any model through two calls:
+The iteration scheduler drives any model through three calls. The model
+reads the KV pool (``[num_blocks, block_size, *kv_token_shape]``) itself,
+through block tables; the engine never gathers a sequence's KV for it:
 
-- ``prefill(tokens, prefix_kv=None) -> (next_token_logits [V],
-  kv [S-P, *kv_token_shape])`` — run the prompt once, return the logits
-  that predict the first generated token plus the per-position KV
-  entries to cache. When the engine adopted a shared prefix,
-  ``prefix_kv`` is the gathered ``[P, *kv_token_shape]`` cache of
-  positions ``[0, P)`` and the model computes (and returns) KV for the
-  unmatched tail only — prefill-from-offset, the compute half of prefix
-  sharing. Models advertise support with ``supports_prefix_prefill``;
-  without it the engine falls back to full recompute with tail-only
-  writes (capacity sharing, no compute savings). The KV result goes to
-  `KVCacheManager.write_range`; what that needs of it is `len()` (the
-  rows to write) and `np.asarray()` (`PromptKV` adds its device rows);
-- ``decode(kvs, last_tokens, positions) -> (logits [B, V],
-  new_kv [B, *kv_token_shape])`` — one incremental step for a batch of
-  sequences: ``kvs[i]`` is sequence i's cached KV gathered from the
-  block manager (``[positions[i], *kv_token_shape]``), ``last_tokens[i]``
-  the most recent token (not yet cached), ``positions[i]`` its position.
+- ``prefill(tokens) -> (next_token_logits [V], kv [S, *kv_token_shape])``
+  — run the prompt once, return the logits that predict the first
+  generated token plus the per-position KV entries to cache. The KV
+  result goes to `KVCacheManager.write_range`; what that needs of it is
+  `len()` (the rows to write) and `np.asarray()` (`PromptKV` adds its
+  device rows);
+- ``prefill_paged(tokens, pool, block_table, prefix_len, block_size)
+  -> (logits [V], kv [S-P, *kv_token_shape])`` — the same for a prompt
+  whose first ``prefix_len`` positions the engine adopted from a shared
+  prefix: the model reads them out of ``pool`` through ``block_table``
+  and computes (and returns) KV for the unmatched tail only —
+  prefill-from-offset, the compute half of prefix sharing;
+- ``decode_paged(pool, block_tables, last_tokens, positions,
+  write_blocks, write_offs, block_size) -> (step, new_pool)`` — one
+  incremental step for a batch of sequences: row i reads its cached
+  positions ``[0, positions[i])`` through ``block_tables[i]``,
+  ``last_tokens[i]`` is its most recent token (not yet cached), and its
+  KV lands at ``(write_blocks[i], write_offs[i])`` (a write list shorter
+  than the batch writes only those rows; empty = read-only). ``step`` is
+  host logits ``[B, V]`` or a `DecodeStep`.
+
+Beside them, three attributes: ``kv_token_shape`` and ``kv_dtype`` (a
+pool row) and ``kv_pool_ns``, the array namespace the model's step reads
+its pool in (numpy or `jax.numpy`; absent means numpy). It is no option:
+each model class has one value and the engine builds its pool from it.
 
 Two implementations:
 
@@ -38,7 +48,7 @@ Two implementations:
 from __future__ import annotations
 
 from collections import OrderedDict
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
@@ -53,12 +63,13 @@ def _next_pow2(n: int) -> int:
 
 
 class _JitLRU(OrderedDict):
-    """Bounded LRU of compiled shape buckets. Bucket pairs accumulate
-    over a replica's lifetime ((batch, seq) for decode, (tail, prefix)
-    for cached prefill, and the paged triples add a block dimension) —
-    unbounded dicts would pin every compiled executable forever. `get`
-    refreshes recency; inserting past `cap` drops the coldest bucket
-    (the executable is re-built on next use) and counts the eviction."""
+    """Bounded LRU of compiled shape buckets. Buckets accumulate over a
+    replica's lifetime (a prompt length for prefill, (batch, blocks,
+    block size) for the paged step, (tail, prefix blocks, block size)
+    for the paged prefill) — unbounded dicts would pin every compiled
+    executable forever. `get` refreshes recency; inserting past `cap`
+    drops the coldest bucket (the executable is re-built on next use)
+    and counts the eviction."""
 
     def __init__(self, cap: int):
         super().__init__()
@@ -152,8 +163,7 @@ class TinyLM:
 
     kv_token_shape: Tuple[int, ...] = (1,)
     kv_dtype = np.float32
-    supports_prefix_prefill = True
-    supports_paged = True
+    kv_pool_ns = np
 
     def __init__(self, vocab_size: int = 32, eos_period: int = 0,
                  step_delay_s: float = 0.0,
@@ -200,6 +210,8 @@ class TinyLM:
 
     def decode(self, kvs: List[np.ndarray], last_tokens: Sequence[int],
                positions: Sequence[int]):
+        """TinyLM's own arithmetic over each row's gathered KV: what
+        `decode_paged` calls once it has read the pool."""
         self.decode_calls += 1
         if self.step_delay_s:
             import time
@@ -284,15 +296,12 @@ class TransformerEngineModel:
     Prefill runs a full causal forward (same math as the training
     model's CPU path — rmsnorm, fused qkv, rotary, `plain_attention`
     scaling, silu-gated FFN, tied embeddings) while collecting K/V;
-    decode attends one query token against the gathered cache. Both are
-    jit-compiled per shape bucket: sequence lengths pad to the next
-    power of two (>= block multiple), batches pad with masked dummy
-    rows, so compiles are bounded by the bucket count, not the request
-    mix. MoE configs are rejected (dense engine path only).
+    decode attends one query token against the cache it gathers from
+    the pool inside its jit. Both are jit-compiled per shape bucket:
+    sequence lengths pad to the next power of two (>= block multiple),
+    batches pad with masked dummy rows, so compiles are bounded by the
+    bucket count, not the request mix. MoE configs are rejected (dense engine path only).
     """
-
-    supports_prefix_prefill = True
-    supports_paged = True
 
     def __init__(self, params, cfg, max_batch_size: int = 8,
                  jit_cache_cap: int = 32):
@@ -310,8 +319,6 @@ class TransformerEngineModel:
         self.kv_dtype = np.float32
         self._max_batch = max_batch_size
         self._prefill_jit = _JitLRU(jit_cache_cap)   # S_pad -> fn
-        self._prefill_cached_jit = _JitLRU(jit_cache_cap)
-        self._decode_jit = _JitLRU(jit_cache_cap)
         self._decode_paged_jit = _JitLRU(jit_cache_cap)
         self._prefill_paged_jit = _JitLRU(jit_cache_cap)
         self.prefill_calls = 0
@@ -343,12 +350,16 @@ class TransformerEngineModel:
         self._tree_leaves = jax.tree_util.tree_leaves
 
     @property
+    def kv_pool_ns(self):
+        """The pool is a `jax.numpy` array: the jitted steps read it
+        through block tables and the donated scatter writes it."""
+        return self._jnp
+
+    @property
     def jit_cache_evictions(self) -> int:
         """Compiled shape buckets dropped by the LRU caps (the
         `serve_engine_jit_bucket_evictions` counter)."""
         return (self._prefill_jit.evictions
-                + self._prefill_cached_jit.evictions
-                + self._decode_jit.evictions
                 + self._decode_paged_jit.evictions
                 + self._prefill_paged_jit.evictions)
 
@@ -431,9 +442,8 @@ class TransformerEngineModel:
         """Traced body of prefill-from-offset: tail queries attend over
         the prefix KV plus the tail's own keys — the prompt's matched
         head is never recomputed. `prefix` rows beyond `p_len` are
-        masked out of attention (`pref_valid`), so callers may hand in
-        zero padding (host path) or stale pool garbage (paged gather)
-        interchangeably."""
+        masked out of attention (`pref_valid`): the paged gather hands
+        in whatever the gathered blocks hold there."""
         import jax
         import jax.numpy as jnp
 
@@ -505,19 +515,6 @@ class TransformerEngineModel:
         # kvs [L, T, 2, H, hd] -> [T, L, 2, H, hd]
         return logits, kvs.transpose(1, 0, 2, 3, 4)
 
-    def _build_prefill_cached(self, t_pad: int, p_pad: int):
-        """One jit per (tail, prefix) bucket pair — prefix handed in as
-        a gathered host array (zero beyond p_len)."""
-        import jax
-
-        self.jit_compiles += 1
-
-        def prefill_cached(params, tail_tokens, p_len, t_len, prefix):
-            return self._prefill_cached_math(
-                params, tail_tokens, p_len, t_len, prefix, t_pad, p_pad)
-
-        return jax.jit(prefill_cached)
-
     def _build_prefill_paged(self, t_pad: int, nbp_pad: int,
                              block_size: int):
         """Paged prefill-from-offset: the prefix is gathered from the
@@ -546,10 +543,9 @@ class TransformerEngineModel:
     def _decode_math(self, params, tokens, positions, cache,
                      b_pad: int, s_pad: int):
         """Traced body of one incremental step. `cache` rows past each
-        sequence's `position` may hold ANYTHING — zero padding on the
-        host-gather path, stale reused-block data on the paged path —
-        so the new token's K/V OVERWRITES its slot (`jnp.where`, not an
-        add) and `attend` masks everything past `position`."""
+        sequence's `position` may hold ANYTHING (stale reused-block
+        data), so the new token's K/V OVERWRITES its slot (`jnp.where`,
+        not an add) and `attend` masks everything past `position`."""
         import jax
         import jax.numpy as jnp
 
@@ -607,17 +603,6 @@ class TransformerEngineModel:
         # new_kv [L, B, 2, H, hd] -> [B, L, 2, H, hd]
         return logits, new_kv.transpose(1, 0, 2, 3, 4)
 
-    def _build_decode(self, b_pad: int, s_pad: int):
-        import jax
-
-        self.jit_compiles += 1
-
-        def decode(params, tokens, positions, cache):
-            return self._decode_math(params, tokens, positions, cache,
-                                     b_pad, s_pad)
-
-        return jax.jit(decode)
-
     def _build_decode_paged(self, b_pad: int, nb_pad: int,
                             block_size: int):
         """Fused paged decode step: gather, attend, write back AND
@@ -667,82 +652,34 @@ class TransformerEngineModel:
         return jax.jit(decode_paged, donate_argnums=0)
 
     # -- engine interface ----------------------------------------------
-    def prefill(self, tokens: Sequence[int], prefix_kv=None):
-        """Run the prompt (or, given the `[P, *kv_token_shape]` KV of
-        its first P positions, the tail). Returns the host logits that
-        predict the next token and the computed positions' KV as a
-        `PromptKV`: still on the device, in the jit's padded bucket."""
+    def prefill(self, tokens: Sequence[int]):
+        """Run the prompt. Returns the host logits that predict the
+        next token and the prompt's KV as a `PromptKV`: still on the
+        device, in the jit's padded bucket."""
         with flight.span("model", "prefill", len(tokens)):
-            return self._prefill(tokens, prefix_kv)
+            return self._prefill(tokens)
 
-    def _prefill(self, tokens: Sequence[int], prefix_kv):
+    def _prefill(self, tokens: Sequence[int]):
         jnp, phase = self._jnp, self.phase
         self.prefill_calls += 1
         n = len(tokens)
-        p = 0 if prefix_kv is None else int(np.asarray(prefix_kv).shape[0])
-        t = n - p
-        self.prefill_tokens += t
+        self.prefill_tokens += n
         with flight.span("model", "prefill.prep", None, phase,
                          "prefill_prep_s"):
-            if p == 0:
-                s_pad = _next_pow2(max(n, 8))
-                fn = self._prefill_jit.get(s_pad)
-                if fn is None:
-                    fn = self._prefill_jit[s_pad] = \
-                        self._build_prefill(s_pad)
-                padded = np.zeros((s_pad,), np.int32)
-                padded[:n] = np.asarray(tokens, np.int32)
-                args = (jnp.asarray(padded), jnp.int32(n))
-            else:
-                t_pad = _next_pow2(max(t, 8))
-                p_pad = _next_pow2(max(p, 8))
-                key = (t_pad, p_pad)
-                fn = self._prefill_cached_jit.get(key)
-                if fn is None:
-                    fn = self._prefill_cached_jit[key] = \
-                        self._build_prefill_cached(*key)
-                tail = np.zeros((t_pad,), np.int32)
-                tail[:t] = np.asarray(tokens[p:], np.int32)
-                cache = np.zeros((p_pad,) + self.kv_token_shape,
-                                 np.float32)
-                cache[:p] = np.asarray(prefix_kv)
-                args = (jnp.asarray(tail), jnp.int32(p), jnp.int32(t),
-                        jnp.asarray(cache))
+            s_pad = _next_pow2(max(n, 8))
+            fn = self._prefill_jit.get(s_pad)
+            if fn is None:
+                fn = self._prefill_jit[s_pad] = self._build_prefill(s_pad)
+            padded = np.zeros((s_pad,), np.int32)
+            padded[:n] = np.asarray(tokens, np.int32)
+            args = (jnp.asarray(padded), jnp.int32(n))
         with flight.span("model", "prefill.dispatch", None, phase,
                          "prefill_dispatch_s"):
             logits, kv = fn(self._params, *args)
         with flight.span("model", "prefill.logits_wait", None, phase,
                          "prefill_wait_s"):
             logits = np.asarray(logits)
-        return logits, PromptKV(kv, t)
-
-    def decode(self, kvs: List[np.ndarray], last_tokens: Sequence[int],
-               positions: Sequence[int]):
-        jnp = self._jnp
-        self.decode_calls += 1
-        b = len(last_tokens)
-        # Bucket from the ACTUAL batch — never clamp below it (the
-        # engine's max_batch_size is an independent knob; clamping
-        # would drop rows). Bucket count stays O(log max-batch-seen).
-        b_pad = _next_pow2(max(b, 1))
-        s_pad = _next_pow2(max(max(int(p) for p in positions) + 1, 8))
-        key = (b_pad, s_pad)
-        fn = self._decode_jit.get(key)
-        if fn is None:
-            fn = self._decode_jit[key] = self._build_decode(*key)
-        cache = np.zeros((b_pad, s_pad) + self.kv_token_shape,
-                         np.float32)
-        toks = np.zeros((b_pad,), np.int32)
-        poss = np.zeros((b_pad,), np.int32)
-        for i in range(b):
-            n = int(positions[i])
-            if n:
-                cache[i, :n] = np.asarray(kvs[i])
-            toks[i] = int(last_tokens[i])
-            poss[i] = n
-        logits, new_kv = fn(self._params, jnp.asarray(toks),
-                            jnp.asarray(poss), jnp.asarray(cache))
-        return np.asarray(logits)[:b], np.asarray(new_kv)[:b]
+        return logits, PromptKV(kv, n)
 
     def decode_paged(self, pool, block_tables: List[Sequence[int]],
                      last_tokens: Sequence[int],
@@ -765,24 +702,8 @@ class TransformerEngineModel:
         re-bind, e.g. via `KVCacheManager.paged_step`). `write_blocks`
         may be shorter than the batch; missing rows (and batch padding
         rows) scatter past the pool and are dropped, so an empty write
-        list is a read-only step. A host-resident pool takes the
-        unpaged `decode` below and returns its host logits."""
-        b = len(last_tokens)
-        if isinstance(pool, np.ndarray):
-            # Host-resident pool with paged tables: gather on host
-            # (still table-driven), step, write rows back in place.
-            kvs = []
-            for i in range(b):
-                n = int(positions[i])
-                nb_i = n // block_size + 1
-                idx = np.asarray(list(block_tables[i])[:nb_i], np.int64)
-                kvs.append(pool[idx].reshape(
-                    (-1,) + self.kv_token_shape)[:n])
-            logits, new_kv = self.decode(kvs, last_tokens, positions)
-            for i in range(min(len(write_blocks), b)):
-                pool[write_blocks[i], write_offs[i]] = new_kv[i]
-            return logits, pool
-        with flight.span("model", "decode", b):
+        list is a read-only step."""
+        with flight.span("model", "decode", len(last_tokens)):
             return self._decode_paged(pool, block_tables, last_tokens,
                                       positions, write_blocks, write_offs,
                                       block_size)

@@ -21,34 +21,39 @@ batch (the `@serve.batch` shape: form once, hold to completion) — the
 honest baseline the `llm_serve` bench compares continuous batching
 against, paying identical per-step bookkeeping.
 
+One loop drives every model, through three calls (`model.py` states
+the protocol): `prefill` -> `cache.write_range` for a prompt,
+`decode_paged` inside `cache.paged_step` for a step, `prefill_paged`
+for a prompt whose head is already cached. The model reads the KV pool
+itself, through block tables; the engine never gathers a sequence's KV.
+Where the pool lives is the model's to say (`kv_pool_ns`: numpy for
+`TinyLM`, `jax.numpy` for `TransformerEngineModel`).
+
 Prefix sharing (on by default): admission consults a radix prefix
 index (`prefix_index.PrefixIndex`) and ADOPTS the longest cached prefix
 by reference — matched blocks cost a refcount bump instead of prefill
 compute and duplicate cache capacity; only the unmatched tail is
-prefilled (`model.prefill(tokens, prefix_kv)` when the model supports
-prefix prefill, full recompute with tail-only writes otherwise). A
-prompt that is fully cached skips the prefill pass entirely: its first
-token is one `model.decode` step over the adopted blocks. Preemption
-frees only a sequence's private tail (shared blocks survive and stay
-indexed), and cold prefixes are LRU-evicted under block pressure
-instead of admissions being rejected.
+prefilled (`model.prefill_paged` reads the matched head out of the
+pool). A prompt that is fully cached skips the prefill pass entirely:
+its first token is one read-only `decode_paged` step over the adopted
+blocks. Preemption frees only a sequence's private tail (shared blocks
+survive and stay indexed), and cold prefixes are LRU-evicted under
+block pressure instead of admissions being rejected.
 
 A prefill hands its KV over in two calls, with or without a prefix hit:
 the model's prefill returns the logits and the KV (`len()` rows), and
 `cache.write_range` stores them. Where the model computed them on the
-device and the pool lives there (`TransformerEngineModel`, paged), the
-KV is the jit's padded output (`model.PromptKV`) and one donated scatter
-writes it: it never crosses to the host. Any other pairing (a numpy
-pool, `TinyLM`) converts once and writes on the host.
+device (`TransformerEngineModel`), the KV is the jit's padded output
+(`model.PromptKV`) and one donated scatter writes it: it never crosses
+to the host. A numpy pool (`TinyLM`) writes on the host.
 
-A decode step's sampling adapts the same way, to what the model's step
-returned. `TransformerEngineModel.decode_paged` crosses the host
-boundary once each way with a few integers: one packed int32 array in
-(tokens, positions, write slots, block tables; the jitted call uploads
-it), the greedy ids its program sampled out (`model.DecodeStep`); the
-`[b, V]` logits stay on the device and `engine.sample` takes the ids.
-Any other model or path (`TinyLM`, a host pool, the unpaged `decode`)
-returns host logits and `engine.sample` takes their argmax: the same
+A decode step's sampling adapts to what the model's step returned.
+`TransformerEngineModel.decode_paged` crosses the host boundary once
+each way with a few integers: one packed int32 array in (tokens,
+positions, write slots, block tables; the jitted call uploads it), the
+greedy ids its program sampled out (`model.DecodeStep`); the `[b, V]`
+logits stay on the device and `engine.sample` takes the ids. A model
+that returns host logits (`TinyLM`) has their argmax taken: the same
 tokens. Who may ask a `DecodeStep` for logits, through `np.asarray`: a
 fully cached prompt's first token below, a reference check, a test;
 never the steady step.
@@ -77,7 +82,7 @@ logger = logging.getLogger(__name__)
 # Where the loop's wall time goes, as `stats()["phase.<name>_s"]`: every
 # instant of the loop lies in one of these. All but `park` and `other`
 # are leaf spans inside `engine.step`; `other` is what a step spent in no
-# leaf. `tables` is the `kv_gather_s` clock under its paged name.
+# leaf. `tables` is the `kv_gather_s` clock (its name in `stats()`).
 _STEP_PHASES = ("reap", "admit", "capacity", "prefill_match",
                 "prefill_kv_write", "prefill_seal", "sample", "emit",
                 "gauges")
@@ -87,7 +92,7 @@ _MODEL_PHASES = ("prefill_prep", "prefill_dispatch", "prefill_wait",
                  "prefill_kv_d2h", "decode_prep", "decode_dispatch",
                  "decode_wait")
 # Clocks the engine had before the phases, still read under these names.
-_LEGACY_CLOCKS = ("prefill_s", "kv_gather_s", "model_step_s", "kv_write_s")
+_LEGACY_CLOCKS = ("prefill_s", "kv_gather_s", "model_step_s")
 
 
 class EngineOverloadedError(RuntimeError):
@@ -109,16 +114,18 @@ class EngineConfig:
     max_queue: int = 64            # waiting-queue bound (backpressure)
     max_new_tokens_default: int = 64
     policy: str = "continuous"     # "continuous" | "static"
-    kv_array_ns: Any = None        # numpy (default) or jax.numpy
     prefix_sharing: bool = True    # adopt cached prompt prefixes
     replica_tag: str = ""          # fleet identity (metrics/digests)
-    # Paged decode (PR 20): read KV inside the model's compiled step
-    # through block tables instead of host-gathering per sequence.
-    # Requires a model with `supports_paged`. `device_pool=None` follows
-    # `paged_decode` (a paged engine wants the pool device-resident so
-    # the in-jit gather is zero-copy); set explicitly to mix modes.
-    paged_decode: bool = False
-    device_pool: Optional[bool] = None
+    # Selects nothing: the paged step is the engine. The name stays, to
+    # accept `True`, because the benchmark's cell files pass it; it goes
+    # with the next `benchmark` issue (ROADMAP S6).
+    paged_decode: bool = True
+
+    def __post_init__(self):
+        if self.paged_decode is not True:
+            raise ValueError(
+                "paged_decode accepts only True: the host-gather decode "
+                "loop is gone and every model runs the paged step")
 
 
 class TokenStream:
@@ -277,21 +284,19 @@ class InferenceEngine:
     def __init__(self, model, config: Optional[EngineConfig] = None):
         self.model = model
         self.config = config or EngineConfig()
-        kv_shape = tuple(getattr(model, "kv_token_shape", ()))
-        self.paged = bool(self.config.paged_decode)
-        if self.paged and not getattr(model, "supports_paged", False):
+        missing = [name for name in ("prefill", "decode_paged",
+                                     "prefill_paged")
+                   if not callable(getattr(model, name, None))]
+        if missing:
             raise ValueError(
-                f"paged_decode=True needs a model with supports_paged; "
-                f"{type(model).__name__} has none")
-        device_pool = self.config.device_pool
-        if device_pool is None:
-            device_pool = self.paged
+                f"{type(model).__name__} lacks {', '.join(missing)}: the "
+                f"engine drives a model through prefill, decode_paged and "
+                f"prefill_paged (serve/engine/model.py)")
         self.cache = KVCacheManager(
             self.config.num_blocks, self.config.block_size,
-            kv_shape=kv_shape,
+            kv_shape=tuple(getattr(model, "kv_token_shape", ())),
             dtype=getattr(model, "kv_dtype", np.float32),
-            array_ns=self.config.kv_array_ns,
-            device_pool=bool(device_pool))
+            array_ns=getattr(model, "kv_pool_ns", None))
         self.prefix_index: Optional[PrefixIndex] = None
         if self.config.prefix_sharing:
             self.prefix_index = PrefixIndex(self.cache,
@@ -320,8 +325,8 @@ class InferenceEngine:
         # Every clock of the loop, in seconds, each fed by one
         # `flight.span` (so all of them stand still while the flight
         # recorder is off): the phases, the older clocks (`prefill_s`;
-        # the decode step's split into host gather / compiled step /
-        # cache write), `step_s` (all of `engine.step`), and what is no
+        # the decode step's split into block tables / compiled step),
+        # `step_s` (all of `engine.step`), and what is no
         # phase: `thread_cpu_s`, `queue_wait_s`, `stream_wake_*`.
         self._clocks: Dict[str, float] = dict.fromkeys(
             _LEGACY_CLOCKS + tuple(f"{p}_s" for p in _STEP_PHASES)
@@ -626,40 +631,24 @@ class InferenceEngine:
         if hit == n:
             # Full prefix hit: every prompt position is already cached.
             # The first generated token is ONE decode step over the
-            # adopted blocks — no prefill pass at all. (The returned
-            # new_kv duplicates what the shared block already holds;
-            # writing it would force a pointless COW, so drop it.)
-            if self.paged:
-                table = self.cache.block_table(seq.seq_id)
-                # Empty write list = read-only fused step; mutate_pool
-                # re-binds the buffer the donating jit returns.
-                logits = self.cache.mutate_pool(
-                    lambda pool: self.model.decode_paged(
-                        pool, [table], [tokens[-1]], [n - 1], [], [],
-                        self.config.block_size))
-            else:
-                ctx = self.cache.gather(seq.seq_id, n - 1)
-                logits, _ = self.model.decode([ctx], [tokens[-1]],
-                                              [n - 1])
+            # adopted blocks — no prefill pass at all. The write list
+            # is empty (the shared block already holds this KV; writing
+            # it would force a pointless COW): a read-only fused step,
+            # and mutate_pool re-binds the buffer the donating jit
+            # returns.
+            table = self.cache.block_table(seq.seq_id)
+            logits = self.cache.mutate_pool(
+                lambda pool: self.model.decode_paged(
+                    pool, [table], [tokens[-1]], [n - 1], [], [],
+                    self.config.block_size))
             logits = np.asarray(logits)[0]
         elif hit:
-            if self.paged and hasattr(self.model, "prefill_paged"):
-                # Paged prefill-from-offset: the adopted prefix is
-                # gathered from the pool inside the jit — no host
-                # materialization of the matched head.
-                table = self.cache.block_table(seq.seq_id)
-                logits, tail_kv = self.cache.with_pool(
-                    lambda pool: self.model.prefill_paged(
-                        tokens, pool, table, hit,
-                        self.config.block_size))
-            elif getattr(self.model, "supports_prefix_prefill", False):
-                prefix_kv = self.cache.gather(seq.seq_id, hit)
-                logits, tail_kv = self.model.prefill(tokens, prefix_kv)
-            else:
-                # Capacity-only sharing: the model recomputes the whole
-                # prompt, but only the unmatched tail is stored.
-                logits, kv = self.model.prefill(tokens)
-                tail_kv = np.asarray(kv)[hit:]
+            # Prefill-from-offset: the model reads the adopted prefix
+            # out of the pool through the block table.
+            table = self.cache.block_table(seq.seq_id)
+            logits, tail_kv = self.cache.with_pool(
+                lambda pool: self.model.prefill_paged(
+                    tokens, pool, table, hit, self.config.block_size))
             with flight.span("engine", "prefill.kv_write", None, clocks,
                              "prefill_kv_write_s"):
                 self.cache.write_range(seq.seq_id, hit, tail_kv)
@@ -744,39 +733,24 @@ class InferenceEngine:
     def _decode_inner(self, batch: List[_Sequence]) -> None:
         clocks = self._clocks
         b = len(batch)
-        if self.paged:
-            # Paged: hand the model the POOL + block tables + write
-            # slots; gather, attention, AND the new tokens' KV
-            # write-back all run inside ONE donated jit call. Host work
-            # this step is int32 table padding — the KV payload never
-            # leaves the device in either direction (so `kv_gather_s`
-            # times the table build here, and `kv_write_s` stands still:
-            # the write is fused into the model step).
-            with flight.span("engine", "tables", b, clocks, "kv_gather_s"):
-                lasts = [s.all_tokens[-1] for s in batch]
-                poss = [len(s.all_tokens) - 1 for s in batch]
-                tables = [self.cache.block_table(s.seq_id) for s in batch]
-                entries = [(s.seq_id, poss[i]) for i, s in enumerate(batch)]
-            with flight.span("engine", "model_step", b, clocks,
-                             "model_step_s"):
-                logits = self.cache.paged_step(
-                    entries,
-                    lambda pool, blocks, offs: self.model.decode_paged(
-                        pool, tables, lasts, poss, blocks, offs,
-                        self.config.block_size))
-            self.paged_steps += 1
-        else:
-            with flight.span("engine", "kv_gather", b, clocks,
-                             "kv_gather_s"):
-                lasts = [s.all_tokens[-1] for s in batch]
-                poss = [len(s.all_tokens) - 1 for s in batch]
-                kvs = [self.cache.gather(s.seq_id) for s in batch]
-            with flight.span("engine", "model_step", b, clocks,
-                             "model_step_s"):
-                logits, new_kv = self.model.decode(kvs, lasts, poss)
-            with flight.span("engine", "kv_write", b, clocks, "kv_write_s"):
-                for i, seq in enumerate(batch):
-                    self.cache.write(seq.seq_id, poss[i], new_kv[i])
+        # Hand the model the POOL + block tables + write slots: the read
+        # through the tables, the step AND the new tokens' KV write-back
+        # are the model's one call (`TransformerEngineModel`: one
+        # donated jit). The engine's own work is the int32 tables, which
+        # `kv_gather_s` times; no KV payload passes through here.
+        with flight.span("engine", "tables", b, clocks, "kv_gather_s"):
+            lasts = [s.all_tokens[-1] for s in batch]
+            poss = [len(s.all_tokens) - 1 for s in batch]
+            tables = [self.cache.block_table(s.seq_id) for s in batch]
+            entries = [(s.seq_id, poss[i]) for i, s in enumerate(batch)]
+        with flight.span("engine", "model_step", b, clocks,
+                         "model_step_s"):
+            logits = self.cache.paged_step(
+                entries,
+                lambda pool, blocks, offs: self.model.decode_paged(
+                    pool, tables, lasts, poss, blocks, offs,
+                    self.config.block_size))
+        self.paged_steps += 1
         with flight.span("engine", "sample", b, clocks, "sample_s"):
             toks = self._greedy(logits)
         with flight.span("engine", "emit", b, clocks, "emit_s"):
@@ -904,16 +878,11 @@ class InferenceEngine:
         return self._clocks["model_step_s"]
 
     @property
-    def kv_write_s(self) -> float:
-        return self._clocks["kv_write_s"]
-
-    @property
     def decode_s(self) -> float:
-        """A decode step up to its result on the host (the paged step's
-        sampled ids; logits otherwise): gather + model step + write."""
+        """A decode step up to its result on the host (the sampled ids,
+        or logits): the block tables + the model's step."""
         clocks = self._clocks
-        return (clocks["kv_gather_s"] + clocks["model_step_s"]
-                + clocks["kv_write_s"])
+        return clocks["kv_gather_s"] + clocks["model_step_s"]
 
     def phase_seconds(self) -> Dict[str, float]:
         """Where the loop's wall time went, by phase: every instant of
@@ -943,10 +912,11 @@ class InferenceEngine:
         `stream_wake_s` / `stream_wake_tokens` sum, over tokens handed
         to a consumer, the time from `TokenStream._push` to pickup.
         `prefill_s` is all of `_prefill`, `decode_s` a decode step up to
-        its result on the host = `kv_gather_s` + `model_step_s` +
-        `kv_write_s`. Under paged decode `kv_gather_s` is the block-table
-        build (no KV is gathered on the host) and `kv_write_s` stays 0
-        (the write is fused into the model step).
+        its result on the host = `kv_gather_s` (the block-table build:
+        no KV is gathered on the host) + `model_step_s` (the model's
+        step, which also writes the new tokens' KV).
+        `paged` reads True and `paged_steps` counts the decode steps:
+        every step is the paged one.
         `prefill_kv_device_writes` and `prefill_kv_host_writes` count
         the prompt-KV writes into the pool (`write_range`) that stayed
         on the device, and those that passed through host memory.
@@ -977,7 +947,7 @@ class InferenceEngine:
             "cache": self.cache.stats(),
             "prefix_index": (self.prefix_index.stats()
                              if self.prefix_index is not None else None),
-            "paged": self.paged,
+            "paged": True,
             "paged_steps": self.paged_steps,
             "prefill_kv_device_writes": self.cache.range_writes_device,
             "prefill_kv_host_writes": self.cache.range_writes_host,
@@ -990,7 +960,6 @@ class InferenceEngine:
             "decode_s": round(self.decode_s, 6),
             "kv_gather_s": round(self.kv_gather_s, 6),
             "model_step_s": round(self.model_step_s, 6),
-            "kv_write_s": round(self.kv_write_s, 6),
             "ttft_p50_ms": (round(ttfts[len(ttfts) // 2] * 1e3, 3)
                             if ttfts else None),
             **{f"phase.{name}_s": seconds
@@ -1031,8 +1000,7 @@ class InferenceEngine:
             # `phase` tags, every 64th step: a registry call each, on
             # the loop they time.
             phases = self.phase_seconds() if self.steps % 64 == 0 else {}
-            for attr in ("prefill", "decode", "kv_gather", "model_step",
-                         "kv_write"):
+            for attr in ("prefill", "decode", "kv_gather", "model_step"):
                 phases[attr] = getattr(self, f"{attr}_s")
             for phase, cur in phases.items():
                 last = self._pushed.get(f"phase.{phase}", 0.0)

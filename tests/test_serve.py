@@ -279,6 +279,85 @@ def test_delete_deployment(serve_instance):
     assert "Tmp" not in serve.status()
 
 
+@pytest.mark.parametrize("listed, waits", [
+    ("deployment", True),        # a router: its deployment, up to date
+    ("routes_stale", False),     # a proxy that has not seen the table
+    ("routes_current", True),    # a proxy that has
+])
+def test_long_poll_blocks_until_a_listed_key_moves(serve_instance, listed,
+                                                    waits):
+    """A handle's router lists its deployment and not "__routes__". Told
+    of the route table all the same, its long poll returned at once,
+    every time: some 500 calls a second from every process that held a
+    handle, half a core there and half a core in the controller."""
+    import ray_tpu
+
+    serve = serve_instance
+
+    @serve.deployment
+    class Poll:
+        def __call__(self, _):
+            return 1
+
+    handle = serve.run(Poll.bind(), route_prefix="/poll")
+    assert handle.remote(None).result(timeout_s=30) == 1
+    controller = handle._controller
+    routes = ray_tpu.get(controller.listen_for_change.remote(
+        {"__routes__": -1}, timeout_s=5.0), timeout=20)["__routes__"]
+    versions = {
+        "deployment": {"Poll": handle._router._version},
+        "routes_stale": {"__routes__": routes["version"] - 1},
+        "routes_current": {"__routes__": routes["version"]},
+    }[listed]
+    t0 = time.monotonic()
+    out = ray_tpu.get(controller.listen_for_change.remote(
+        versions, timeout_s=0.6), timeout=20)
+    took = time.monotonic() - t0
+    if waits:
+        assert out == {} and took >= 0.5, (out, took)
+    else:
+        assert out["__routes__"]["routes"] == routes["routes"]
+
+
+def test_requests_ask_the_controller_only_when_the_poll_cannot_know(
+        serve_instance, monkeypatch):
+    """The router's long poll hears of every change of the table, so a
+    request a second after the last one routes without a controller
+    round trip of its own (it used to pay one); the first request and
+    the one after `invalidate` still ask."""
+    from ray_tpu.serve._private.router import Router
+
+    serve = serve_instance
+    applied = []
+    apply = Router._apply
+    monkeypatch.setattr(
+        Router, "_apply",
+        lambda self, table: (applied.append(table["version"]),
+                             apply(self, table))[1])
+
+    @serve.deployment
+    class Quiet:
+        def __call__(self, _):
+            return 1
+
+    handle = serve.run(Quiet.bind(), route_prefix="/quiet")
+    assert handle.remote(None).result(timeout_s=30) == 1
+    router = handle._router
+    deadline = time.monotonic() + 10
+    while not router._poll_live and time.monotonic() < deadline:
+        time.sleep(0.05)
+    assert router._poll_live
+    seen = len(applied)
+    assert seen >= 1
+    for _ in range(2):
+        time.sleep(router._refresh_interval_s + 0.1)
+        assert handle.remote(None).result(timeout_s=30) == 1
+    assert len(applied) == seen, applied
+    router.invalidate()
+    assert handle.remote(None).result(timeout_s=30) == 1
+    assert len(applied) > seen and router._version == applied[-1]
+
+
 def test_model_composition(serve_instance):
     """Deployment graph: ingress holds a handle to a child deployment
     (reference: serve deployment_graph_build + handle-injection); the

@@ -20,6 +20,7 @@ token, deterministic per seed). The subsystem must then prove:
   replica, zero residual inflight anywhere, zero lost conversations.
 """
 
+import threading
 import time
 
 import pytest
@@ -45,9 +46,21 @@ def _config(plan=None) -> FleetConfig:
         fault_plan=plan)
 
 
+def _hold_steps(fleet, gate) -> None:
+    """Every replica's loop waits at the top of an iteration while
+    `gate` is shut: outside the cache lock, so routing and shipping go
+    on meanwhile."""
+    for rid in fleet.live_replicas():
+        eng = fleet.replica(rid).engine
+        eng.step = lambda step=eng.step: gate.wait(10.0) and step()
+
+
 def test_replica_kill_mid_decode_recovers_every_conversation():
     plan = FaultPlan(seed=19)
     fleet = ServeFleet(_config(plan))
+    gate = threading.Event()
+    gate.set()
+    _hold_steps(fleet, gate)
 
     kill_stamp = []
 
@@ -67,8 +80,13 @@ def test_replica_kill_mid_decode_recovers_every_conversation():
 
         prompts = [SYS + [2 + (i % 9), 3 + (i % 5), 4 + (i % 7)]
                    for i in range(8)]
+        # No engine iterates until the whole burst is in: the holder
+        # stays alive and loaded while the router spills (and ships),
+        # and the kill lands mid-burst, however slow this thread is.
+        gate.clear()
         convs = [fleet.submit(p, 24, session_id=f"s{i}")
                  for i, p in enumerate(prompts)]
+        gate.set()
         oracle = TinyLM(vocab_size=64)
         for p, c in zip(prompts, convs):
             assert list(c.stream) == oracle.oracle(p, 24), \

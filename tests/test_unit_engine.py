@@ -514,10 +514,12 @@ def test_transformer_incremental_decode_matches_full_recompute(
 
 
 def test_transformer_prefill_from_offset_matches_full(tiny_transformer):
-    """Prefill-from-offset (tail attends over cached prefix KV) equals
-    the full prefill's logits and tail KV — the compute half of prefix
-    sharing on the real-model path."""
-    from ray_tpu.serve.engine import TransformerEngineModel
+    """Prefill-from-offset (the prefix's KV written into a pool, the
+    tail through `prefill_paged`, which gathers the prefix inside its
+    jit) equals the full prefill's logits and tail KV — the compute half
+    of prefix sharing on the real-model path."""
+    from ray_tpu.serve.engine import (KVCacheManager,
+                                      TransformerEngineModel)
 
     params, cfg = tiny_transformer
     model = TransformerEngineModel(params, cfg)
@@ -525,7 +527,14 @@ def test_transformer_prefill_from_offset_matches_full(tiny_transformer):
     full_logits, full_kv = model.prefill(prompt)
     full_kv = np.asarray(full_kv)
     for p in (4, 8, 9):
-        logits, tail_kv = model.prefill(prompt, prefix_kv=full_kv[:p])
+        cache = KVCacheManager(8, 4, kv_shape=model.kv_token_shape,
+                               array_ns=model.kv_pool_ns)
+        assert cache.allocate("s", len(prompt), writable_from=0)
+        cache.write_range("s", 0, full_kv[:p])
+        table = cache.block_table("s")
+        logits, tail_kv = cache.with_pool(
+            lambda pool: model.prefill_paged(prompt, pool, table, p, 4))
+        assert len(tail_kv) == len(prompt) - p
         np.testing.assert_allclose(logits, full_logits, atol=1e-4)
         np.testing.assert_allclose(tail_kv, full_kv[p:], atol=1e-4)
 
@@ -596,9 +605,9 @@ def test_transformer_shape_buckets_are_bounded(tiny_transformer):
     while eng.step():
         pass
     # ...compile only power-of-two buckets, not one shape per mix.
-    for b, s in model._decode_jit:
-        assert b & (b - 1) == 0 and s & (s - 1) == 0
-    assert len(model._decode_jit) <= 6
+    for b, nb, block_size in model._decode_paged_jit:
+        assert b & (b - 1) == 0 and nb & (nb - 1) == 0 and block_size == 8
+    assert len(model._decode_paged_jit) <= 6
     assert len(model._prefill_jit) <= 3
 
 
@@ -627,10 +636,10 @@ def test_prompt_kv_reaches_a_device_pool_without_the_host(
     params, cfg = tiny_transformer
     model = TransformerEngineModel(params, cfg)
     eng = InferenceEngine(model, EngineConfig(
-        block_size=16, num_blocks=12, paged_decode=True))
+        block_size=16, num_blocks=12))
     cache = eng.cache
     by_host = KVCacheManager(12, 16, kv_shape=model.kv_token_shape,
-                             device_pool=True)
+                             array_ns=model.kv_pool_ns)
     # A neighbour whose last block the sequence under test follows.
     prompts = {"neighbour": _tokens(19, 1), "s": _tokens(n, n)}
     logits = {}
@@ -696,7 +705,7 @@ def test_prefill_compiles_per_bucket_not_per_prompt_length(
     params, cfg = tiny_transformer
     model = TransformerEngineModel(params, cfg)
     eng = InferenceEngine(model, EngineConfig(
-        block_size=16, num_blocks=32, paged_decode=True))
+        block_size=16, num_blocks=32))
     head = _tokens(shared, 7)
     # JAX's own compile event, as the benchmark's cells count it.
     events = CompileCounter()
@@ -724,24 +733,25 @@ def test_prefill_compiles_per_bucket_not_per_prompt_length(
 
 def test_host_pool_takes_a_device_payload_through_one_host_copy(
         tiny_transformer):
-    from ray_tpu.serve.engine import (EngineConfig, InferenceEngine,
+    """A numpy pool (the manager's default, `TinyLM`'s) handed a
+    `PromptKV`: one host copy of the bucket, cut to `len()` rows, and
+    counted as a host write."""
+    from ray_tpu.serve.engine import (KVCacheManager,
                                       TransformerEngineModel)
 
     params, cfg = tiny_transformer
     model = TransformerEngineModel(params, cfg)
-    eng = InferenceEngine(model, EngineConfig(block_size=16, num_blocks=8))
-    assert eng.cache.pool_residency == "host"
+    cache = KVCacheManager(8, 16, kv_shape=model.kv_token_shape)
+    assert cache.pool_residency == "host"
     tokens = _tokens(21, 3)
-    assert eng.cache.allocate("s", 21, writable_from=0)
+    assert cache.allocate("s", 21, writable_from=0)
     _, kv = model.prefill(tokens)
     assert hasattr(kv.padded, "block_until_ready") and len(kv) == 21
-    eng.cache.write_range("s", 0, kv)
-    rows = eng.cache.gather("s")
+    cache.write_range("s", 0, kv)
+    rows = cache.gather("s")
     assert rows.shape == (21,) + model.kv_token_shape
     np.testing.assert_array_equal(rows, np.asarray(kv.padded)[:21])
-    s = eng.stats()
-    assert (s["prefill_kv_device_writes"], s["prefill_kv_host_writes"]) \
-        == (0, 1)
+    assert (cache.range_writes_device, cache.range_writes_host) == (0, 1)
 
 
 # ---------------------------------------------------------------------------
@@ -755,7 +765,7 @@ def _paged_transformer_engine(params, cfg, **config):
     model = TransformerEngineModel(params, cfg)
     model.eos_token = None      # random weights: no token ends a stream
     return model, InferenceEngine(model, EngineConfig(
-        num_blocks=64, paged_decode=True, **config))
+        num_blocks=64, **config))
 
 
 def _wrap_decode_paged(model, after):
@@ -912,14 +922,13 @@ def test_a_served_stream_fetches_no_logits(tiny_transformer):
         == 2 * 4 + 4 * cfg.vocab_size
 
 
-@pytest.mark.parametrize("paged", [False, True], ids=["host", "paged"])
-def test_a_model_that_returns_logits_is_sampled_on_the_host(paged):
-    """`TinyLM` returns host logits on either path: the engine emits the
+def test_a_model_that_returns_logits_is_sampled_on_the_host():
+    """`TinyLM`'s step returns host logits: the engine emits the
     oracle's tokens and counts nothing across the boundary. Both
     counters are in `stats()` from construction."""
     model = TinyLM(vocab_size=32)
     eng = InferenceEngine(model, EngineConfig(
-        block_size=4, num_blocks=64, max_batch_size=3, paged_decode=paged))
+        block_size=4, num_blocks=64, max_batch_size=3))
     s = eng.stats()
     assert (s["decode_h2d_arrays"], s["decode_d2h_bytes"]) == (0, 0)
     prompts = [[5, 9, 3], [7, 2, 11, 4, 6], [12]]
@@ -928,5 +937,5 @@ def test_a_model_that_returns_logits_is_sampled_on_the_host(paged):
     for p, h in zip(prompts, handles):
         assert h.tokens_so_far() == model.oracle(p, 9)
     s = eng.stats()
-    assert s["paged_steps"] == (8 if paged else 0)
+    assert s["paged_steps"] == 8
     assert (s["decode_h2d_arrays"], s["decode_d2h_bytes"]) == (0, 0)

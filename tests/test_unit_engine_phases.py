@@ -32,12 +32,10 @@ def _phase_sum(clocks):
     return sum(v for k, v in clocks.items() if k.startswith("phase."))
 
 
-@pytest.mark.parametrize("paged", [False, True])
 def test_phases_are_there_from_the_start_only_grow_and_sum_to_the_loop(
-        recorder, paged):
+        recorder):
     eng = InferenceEngine(TinyLM(), EngineConfig(
-        block_size=4, num_blocks=256, max_batch_size=4,
-        paged_decode=paged))
+        block_size=4, num_blocks=256, max_batch_size=4))
     first = _clocks(eng)              # raises KeyError if one is missing
     assert set(first.values()) == {0}
     assert {k for k in eng.stats() if k.startswith("phase.")} == \
@@ -64,10 +62,8 @@ def test_phases_are_there_from_the_start_only_grow_and_sum_to_the_loop(
     assert stats["kv_gather_s"] == pytest.approx(done["phase.tables_s"],
                                                  abs=1e-6)
     assert stats["decode_s"] == pytest.approx(
-        stats["kv_gather_s"] + stats["model_step_s"] + stats["kv_write_s"],
-        abs=3e-6)
-    assert (stats["kv_write_s"] == 0) == paged
-    assert stats["prefill_s"] > 0
+        stats["kv_gather_s"] + stats["model_step_s"], abs=3e-6)
+    assert stats["model_step_s"] > 0 and stats["prefill_s"] > 0
 
 
 def test_the_hosted_loop_counts_its_park_in_loop_s(recorder):
@@ -114,7 +110,7 @@ def _contains(outer, inner, slack_us=2):
 
 def test_engine_spans_nest_in_the_flight_ring(recorder):
     eng = InferenceEngine(TinyLM(), EngineConfig(
-        block_size=4, num_blocks=64, max_batch_size=2, paged_decode=True))
+        block_size=4, num_blocks=64, max_batch_size=2))
     eng.submit([3, 5, 7, 9, 2, 4, 6, 8], 1)    # one prefill, one step
     assert eng.step() is False                 # finished at its first token
     events = flight.snapshot(categories={"engine"})
@@ -278,18 +274,16 @@ def tiny_transformer():
     return init_params(jax.random.PRNGKey(0), cfg), cfg
 
 
-@pytest.mark.parametrize("paged", [True, False])
 def test_the_transformer_model_splits_its_calls_and_the_engine_reads_it(
-        recorder, tiny_transformer, paged):
+        recorder, tiny_transformer):
     """The prompt KV stays on the device when `prefill` returns, so
-    `kv_d2h` stands still: a host pool's copy is part of
-    `engine.prefill.kv_write`. The non-paged decode has no spans."""
+    `kv_d2h` stands still."""
     from ray_tpu.serve.engine import TransformerEngineModel
 
     params, cfg = tiny_transformer
     model = TransformerEngineModel(params, cfg, max_batch_size=2)
     eng = InferenceEngine(model, EngineConfig(
-        max_batch_size=2, block_size=4, num_blocks=32, paged_decode=paged))
+        max_batch_size=2, block_size=4, num_blocks=32))
     assert set(model.phase.values()) == {0.0}
     streams = [eng.submit([2, 3, 4, 5 + i], 6) for i in range(2)]
     while eng.step():
@@ -297,10 +291,8 @@ def test_the_transformer_model_splits_its_calls_and_the_engine_reads_it(
     assert all(len(list(s)) == 6 for s in streams)
     done = _clocks(eng)
     ran = {"model_prefill_prep", "model_prefill_dispatch",
-           "model_prefill_wait"}
-    if paged:
-        ran |= {"model_decode_prep", "model_decode_dispatch",
-                "model_decode_wait"}
+           "model_prefill_wait", "model_decode_prep",
+           "model_decode_dispatch", "model_decode_wait"}
     for phase in ("model_prefill_prep", "model_prefill_dispatch",
                   "model_prefill_wait", "model_prefill_kv_d2h",
                   "model_decode_prep", "model_decode_dispatch",
@@ -313,7 +305,8 @@ def test_the_transformer_model_splits_its_calls_and_the_engine_reads_it(
     assert labels.count("prefill") == 2
     assert labels.count("prefill.kv_d2h") == 0
     assert labels.count("decode") == labels.count("decode.prep") == \
-        labels.count("decode.dispatch") == labels.count("decode.logits_wait")
+        labels.count("decode.dispatch") == \
+        labels.count("decode.logits_wait") == eng.paged_steps > 0
     # Each model call lies inside the engine span that made it.
     ring = flight.snapshot()
     model_calls = [e for e in ring if e[2] == "model"
@@ -323,7 +316,7 @@ def test_the_transformer_model_splits_its_calls_and_the_engine_reads_it(
     assert all(any(_contains(p, c) for p in parents) for c in model_calls)
     s = eng.stats()
     assert (s["prefill_kv_device_writes"], s["prefill_kv_host_writes"]) \
-        == ((2, 0) if paged else (0, 2))
+        == (2, 0)
 
 
 def _module_name(jitted, *args):
@@ -348,18 +341,10 @@ def test_device_programs_are_named_after_their_functions(tiny_transformer):
     i32 = jnp.int32
     assert _module_name(model._build_prefill(8), params,
                         jnp.zeros((8,), i32), i32(3)) == "jit_prefill"
-    prefix = jnp.zeros((8,) + model.kv_token_shape, jnp.float32)
-    assert _module_name(
-        model._build_prefill_cached(8, 8), params, jnp.zeros((8,), i32),
-        i32(4), i32(3), prefix) == "jit_prefill_cached"
     pool = jnp.zeros((16, 4) + model.kv_token_shape, jnp.float32)
     assert _module_name(
         model._build_prefill_paged(8, 2, 4), params, jnp.zeros((8,), i32),
         i32(4), i32(3), pool, jnp.zeros((2,), i32)) == "jit_prefill_paged"
-    cache = jnp.zeros((2, 8) + model.kv_token_shape, jnp.float32)
-    assert _module_name(
-        model._build_decode(2, 8), params, jnp.zeros((2,), i32),
-        jnp.zeros((2,), i32), cache) == "jit_decode"
     assert _module_name(
         model._build_decode_paged(2, 2, 4), pool, params,
         jnp.zeros((2, 4 + 2), i32)) == "jit_decode_paged"

@@ -362,8 +362,24 @@ def test_double_death_migrates_twice_without_leaks():
         fleet.stop()
 
 
+class _HeldLM(TinyLM):
+    """A decode step returns only once `hold` is set (None: at once)."""
+
+    hold = None
+
+    def decode_paged(self, *args):
+        if self.hold is not None:
+            assert self.hold.wait(timeout=10.0)
+        return super().decode_paged(*args)
+
+
 def test_all_replicas_dead_fails_conversations_not_hangs():
-    fleet = _fleet(num_replicas=1)
+    fleet = _fleet(num_replicas=1,
+                   model_factory=lambda: _HeldLM(vocab_size=64))
+    # The kill lands mid-stream by construction, however fast the
+    # machine: no decode step returns before the engine is told to stop.
+    engine = fleet._replicas["replica-0"].engine
+    engine.model.hold = engine._stop
     fleet.start()
     try:
         conv = fleet.submit(SYS + [7], 64, session_id="s0")

@@ -1,17 +1,17 @@
-"""Device-resident paged decode: donated KV pool + in-jit block gather.
+"""The paged decode step over both pool residencies.
 
-Unit tier for PR 20. The KV block pool can live as a jax array
-(`KVCacheManager(device_pool=True)`) whose every mutation is a
-donated-arg jitted update, and the engine's paged path
-(`EngineConfig(paged_decode=True)`) hands the pool + block tables into
-ONE fused compiled step per decode iteration (in-jit `jnp.take`
-gather, decode math, in-place KV scatter). Correctness here is
-token-level: TinyLM's next token is a function of the CACHED kv
-contents, so any table/gather/scatter indexing bug changes the output
-against `TinyLM.oracle`; the transformer tests compare against the
-host-gather engine AND greedy full-recompute. COW, adoption,
-preemption and cross-engine shipping semantics must be bit-identical
-in both pool residencies.
+The KV block pool lives where the engine's model says (`kv_pool_ns`):
+numpy for `TinyLM`, a jax array for `TransformerEngineModel`, whose
+every mutation is a donated-arg jitted update. The engine hands the
+pool + block tables into ONE model call per decode iteration (the
+transformer's: in-jit `jnp.take` gather, decode math, in-place KV
+scatter). Correctness here is token-level: TinyLM's next token is a
+function of the CACHED kv contents, so any table/gather/scatter
+indexing bug changes the output against `TinyLM.oracle` (a subclass
+that asks for `jax.numpy` puts the same oracle over the device pool);
+the transformer tests compare against greedy full-recompute. COW,
+adoption, preemption and cross-engine shipping semantics must be
+bit-identical in both pool residencies.
 
 Everything runs under `JAX_PLATFORMS=cpu` — the device pool is then
 host RAM, but the code path (donation, in-jit gather, scatter
@@ -36,6 +36,27 @@ def _drive(eng):
         pass
 
 
+def _device_mgr(num_blocks, block_size=4, **kw):
+    import jax.numpy as jnp
+
+    return KVCacheManager(num_blocks=num_blocks, block_size=block_size,
+                          kv_shape=KV, array_ns=jnp, **kw)
+
+
+def _tinylm(pool, **kw):
+    """`TinyLM` over its numpy pool (`host`), or the subclass that asks
+    for `jax.numpy` (`device`): the exact oracle over the device pool's
+    scatter, COW and adoption."""
+    if pool == "host":
+        return TinyLM(**kw)
+    import jax.numpy as jnp
+
+    class DeviceTinyLM(TinyLM):
+        kv_pool_ns = jnp
+
+    return DeviceTinyLM(**kw)
+
+
 # ---------------------------------------------------------------------------
 # device pool: manager-level storage semantics
 # ---------------------------------------------------------------------------
@@ -44,8 +65,7 @@ def test_device_pool_write_gather_matches_numpy():
     donated scatter land exactly where the numpy pool puts them —
     including a range that starts and ends mid-block."""
     host = KVCacheManager(num_blocks=8, block_size=4, kv_shape=KV)
-    dev = KVCacheManager(num_blocks=8, block_size=4, kv_shape=KV,
-                         device_pool=True)
+    dev = _device_mgr(8)
     assert dev.pool_residency == "device"
     vals = np.arange(11 * 6, dtype=np.float32).reshape(11, *KV)
     for mgr in (host, dev):
@@ -65,8 +85,7 @@ def test_device_pool_bfloat16_roundtrip():
     """A bfloat16 pool stores and gathers with bf16 rounding only —
     the dtype a TPU-resident pool would actually use."""
     jnp = pytest.importorskip("jax.numpy")
-    mgr = KVCacheManager(num_blocks=4, block_size=4, kv_shape=KV,
-                         dtype=jnp.bfloat16, device_pool=True)
+    mgr = _device_mgr(4, dtype=jnp.bfloat16)
     assert mgr.allocate("s", 6)
     vals = np.linspace(0.0, 2.0, 6 * 6, dtype=np.float32).reshape(
         6, *KV)
@@ -81,8 +100,7 @@ def test_device_pool_cow_privatizes_before_write():
     """A write into a shared block on the device pool copies it first:
     the writer sees its new value, the other holder keeps reading the
     original bytes."""
-    mgr = KVCacheManager(num_blocks=8, block_size=4, kv_shape=KV,
-                         device_pool=True)
+    mgr = _device_mgr(8)
     assert mgr.allocate("a", 4)
     vals = np.ones((4,) + KV, np.float32)
     mgr.write_range("a", 0, vals)
@@ -97,37 +115,12 @@ def test_device_pool_cow_privatizes_before_write():
     np.testing.assert_array_equal(got[:2], vals[:2])
 
 
-@pytest.mark.parametrize("device_pool", [False, True])
-def test_write_step_batched_one_token_writes(device_pool):
-    """`write_step` lands row i of a padded [b_pad, *kv] batch at
-    entry i's slot; padding rows are dropped (device: scattered out of
-    range), and shared blocks privatize first."""
-    mgr = KVCacheManager(num_blocks=8, block_size=4, kv_shape=KV,
-                         device_pool=device_pool)
-    assert mgr.allocate("a", 3) and mgr.allocate("b", 6)
-    base = np.zeros((6,) + KV, np.float32)
-    mgr.write_range("a", 0, base[:2])
-    mgr.write_range("b", 0, base)
-    batch = np.zeros((4,) + KV, np.float32)     # b_pad=4, 2 live rows
-    batch[0] = 11.0
-    batch[1] = 22.0
-    batch[2:] = 99.0                            # must never land
-    mgr.write_step([("a", 2), ("b", 5)], batch)
-    assert mgr.seq_len("a") == 3 and mgr.seq_len("b") == 6
-    np.testing.assert_array_equal(np.asarray(mgr.gather("a"))[2],
-                                  batch[0])
-    np.testing.assert_array_equal(np.asarray(mgr.gather("b"))[5],
-                                  batch[1])
-    assert not np.any(np.asarray(mgr.gather("b"))[:5] == 99.0)
-
-
 def test_paged_step_resolves_slots_and_rebinds_pool():
     """`paged_step` hands the model's fused step private (block, off)
     slots (COW backstop included), re-binds the donated pool it
     returns, and advances lens — the whole decode write path in one
     call."""
-    mgr = KVCacheManager(num_blocks=8, block_size=4, kv_shape=KV,
-                         device_pool=True)
+    mgr = _device_mgr(8)
     assert mgr.allocate("a", 4)
     vals = np.ones((4,) + KV, np.float32)
     mgr.write_range("a", 0, vals)
@@ -160,8 +153,7 @@ def test_with_pool_is_reentrant():
     """`with_pool` callbacks may call public accessors (the scheduler's
     paged prefill reads tables while holding the pool) — the cache lock
     is reentrant."""
-    mgr = KVCacheManager(num_blocks=4, block_size=4, kv_shape=KV,
-                         device_pool=True)
+    mgr = _device_mgr(4)
     assert mgr.allocate("s", 2)
     table = mgr.with_pool(lambda pool: mgr.block_table("s"))
     assert table == mgr.block_table("s")
@@ -170,14 +162,13 @@ def test_with_pool_is_reentrant():
 # ---------------------------------------------------------------------------
 # TinyLM: oracle-exact through the paged engine
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("device_pool", [False, True])
-def test_tinylm_paged_engine_matches_oracle(device_pool):
+@pytest.mark.parametrize("pool", ["host", "device"])
+def test_tinylm_paged_engine_matches_oracle(pool):
     """Paged decode (both pool residencies) reproduces TinyLM.oracle
     token-for-token, with zero host gathers."""
-    m = TinyLM(vocab_size=32)
+    m = _tinylm(pool, vocab_size=32)
     eng = InferenceEngine(m, EngineConfig(
-        max_batch_size=4, block_size=4, num_blocks=64,
-        paged_decode=True, device_pool=device_pool))
+        max_batch_size=4, block_size=4, num_blocks=64))
     prompts = [[1 + (i * 3 + j) % 20 for j in range(3 + i % 5)]
                for i in range(6)]
     streams = [eng.submit(p, 8) for p in prompts]
@@ -187,8 +178,7 @@ def test_tinylm_paged_engine_matches_oracle(device_pool):
     st = eng.stats()
     assert st["paged"] and st["paged_steps"] > 0
     assert st["cache"]["host_gathers"] == 0
-    assert st["cache"]["pool_residency"] == (
-        "device" if device_pool else "host")
+    assert st["cache"]["pool_residency"] == pool
 
 
 def test_tinylm_paged_survives_preemption_and_adoption():
@@ -196,10 +186,11 @@ def test_tinylm_paged_survives_preemption_and_adoption():
     sharing adopts blocks by reference — the paged read must still be
     oracle-exact afterwards (stale pool rows from freed blocks never
     leak through the block tables)."""
-    m = TinyLM(vocab_size=32)
+    m = _tinylm("device", vocab_size=32)
     eng = InferenceEngine(m, EngineConfig(
         max_batch_size=4, block_size=4, num_blocks=8,
-        paged_decode=True, device_pool=True, prefix_sharing=True))
+        prefix_sharing=True))
+    assert eng.cache.pool_residency == "device"
     base = [2, 4, 6, 8]
     prompts = [base + [10 + i] for i in range(4)]
     streams = [eng.submit(p, 6) for p in prompts]
@@ -211,7 +202,7 @@ def test_tinylm_paged_survives_preemption_and_adoption():
 
 
 # ---------------------------------------------------------------------------
-# transformer: paged == host-gather == full recompute
+# transformer: paged == full recompute
 # ---------------------------------------------------------------------------
 @pytest.fixture(scope="module")
 def tiny_transformer():
@@ -236,30 +227,24 @@ def _transformer_engine(tiny_transformer, **cfg_kw):
         max_batch_size=4, block_size=8, num_blocks=24, **cfg_kw))
 
 
-def test_transformer_paged_matches_host_and_full_recompute(
-        tiny_transformer):
+def test_transformer_paged_matches_full_recompute(tiny_transformer):
     """The fused paged engine (device pool, in-jit gather, in-place
-    scatter) emits token-for-token what the host-gather engine emits —
-    and both match greedy full-forward recompute."""
+    scatter) emits token-for-token what greedy full-forward recompute
+    (`models.transformer.forward`, no cache) emits."""
     import jax.numpy as jnp
 
     from ray_tpu.models.transformer import forward
 
     params, cfg = tiny_transformer
     prompts = [[3, 17, 42, 9, 21, 5], [7, 7], [11, 23, 4, 50, 8, 9, 13]]
-    outs = []
-    for paged in (False, True):
-        _, eng = _transformer_engine(tiny_transformer,
-                                     paged_decode=paged)
-        streams = [eng.submit(p, 6) for p in prompts]
-        _drive(eng)
-        outs.append([s.tokens_so_far() for s in streams])
-        if paged:
-            assert eng.paged_steps > 0
-            assert eng.cache.host_gathers == 0
-            assert eng.cache.pool_residency == "device"
-    assert outs[0] == outs[1]
-    for p, toks in zip(prompts, outs[1]):
+    _, eng = _transformer_engine(tiny_transformer)
+    streams = [eng.submit(p, 6) for p in prompts]
+    _drive(eng)
+    assert eng.paged_steps > 0
+    assert eng.cache.host_gathers == 0
+    assert eng.cache.pool_residency == "device"
+    for p, s in zip(prompts, streams):
+        toks = s.tokens_so_far()
         seq, oracle = list(p), []
         for _ in range(6):
             lg, _ = forward(params, jnp.asarray([seq], jnp.int32), cfg)
@@ -280,7 +265,6 @@ def test_transformer_sharing_paged_matches_unshared(tiny_transformer):
     outs = []
     for sharing in (False, True):
         _, eng = _transformer_engine(tiny_transformer,
-                                     paged_decode=True,
                                      prefix_sharing=sharing)
         streams = []
         for p, n in reqs:       # staged: block seals before next admit
@@ -300,21 +284,19 @@ def test_transformer_ship_then_paged_decode_parity(tiny_transformer):
     same tokens as computing locally."""
     base = [3, 17, 42, 9, 21, 5, 11, 2]
     tail = [33, 40]
-    _, src = _transformer_engine(tiny_transformer, paged_decode=True,
-                                 prefix_sharing=True)
+    _, src = _transformer_engine(tiny_transformer, prefix_sharing=True)
     src.submit(base + tail, 4)
     _drive(src)
     chunks, kvs = src.export_prefix(base)
     assert chunks and len(kvs) == len(chunks)
 
-    _, dst = _transformer_engine(tiny_transformer, paged_decode=True,
-                                 prefix_sharing=True)
+    _, dst = _transformer_engine(tiny_transformer, prefix_sharing=True)
     assert dst.import_prefix(chunks, kvs) == len(base)
     s_dst = dst.submit(base + tail, 4)
     _drive(dst)
     assert dst.prefix_hit_tokens >= len(base)   # adoption engaged
 
-    _, ref = _transformer_engine(tiny_transformer, paged_decode=True)
+    _, ref = _transformer_engine(tiny_transformer)
     s_ref = ref.submit(base + tail, 4)
     _drive(ref)
     assert s_dst.tokens_so_far() == s_ref.tokens_so_far()
@@ -354,49 +336,127 @@ def test_transformer_jit_cache_cap_evicts_and_reports(tiny_transformer):
         model.jit_cache_evictions
 
 
-def test_engine_stats_surface_pool_and_phase_fields():
-    m = TinyLM(vocab_size=32)
-    eng = InferenceEngine(m, EngineConfig(
-        max_batch_size=2, block_size=4, num_blocks=16,
-        paged_decode=True))
+@pytest.mark.parametrize("pool", ["host", "device"])
+def test_engine_stats_surface_pool_and_phase_fields(pool):
+    eng = InferenceEngine(_tinylm(pool, vocab_size=32), EngineConfig(
+        max_batch_size=2, block_size=4, num_blocks=16))
     eng.submit([2, 3, 4], 4)
     _drive(eng)
     st = eng.stats()
     assert st["paged"] is True
-    assert st["paged_steps"] > 0
+    assert st["paged_steps"] == st["steps"] > 0
     cache = st["cache"]
-    assert cache["pool_residency"] == "device"
+    assert cache["pool_residency"] == pool
     assert cache["pool_bytes"] > 0
     assert cache["host_gathers"] == 0
-    assert cache["pool_updates"] > 0
-    for key in ("kv_gather_s", "model_step_s", "kv_write_s",
+    # Donated updates are the device pool's; a numpy pool is written in
+    # place and counts none.
+    assert (cache["pool_updates"] > 0) == (pool == "device")
+    for key in ("kv_gather_s", "model_step_s", "decode_s",
                 "jit_bucket_evictions"):
         assert key in st
+    assert "kv_write_s" not in st
 
 
 # ---------------------------------------------------------------------------
-# asked for the device path: get it or raise
+# one loop: the model says where its pool lives, nothing selects a path
 # ---------------------------------------------------------------------------
-def test_device_pool_without_jax_raises_not_degrades(monkeypatch):
-    """`device_pool=True` used to fall back to a numpy pool when jax
-    could not be imported; a serving replica would then run off the
-    device without a word."""
-    import sys
+@pytest.mark.parametrize("kind", ["tinylm", "tinylm_device", "transformer"])
+def test_the_pools_residency_follows_the_model(kind, tiny_transformer):
+    """No option places the pool: `TinyLM` gets numpy, a subclass that
+    asks for `jax.numpy` and `TransformerEngineModel` get a jax array,
+    under the same default `EngineConfig()`."""
+    import jax
 
-    monkeypatch.setitem(sys.modules, "jax.numpy", None)  # import fails
-    with pytest.raises(ImportError):
-        KVCacheManager(num_blocks=4, block_size=4, kv_shape=KV,
-                       device_pool=True)
+    if kind == "transformer":
+        model, _ = _transformer_engine(tiny_transformer)
+    else:
+        model = _tinylm("device" if kind.endswith("device") else "host")
+    eng = InferenceEngine(model, EngineConfig(block_size=4, num_blocks=8))
+    want = "host" if kind == "tinylm" else "device"
+    assert eng.cache.pool_residency == want
+    assert eng.stats()["cache"]["pool_residency"] == want
+    pool = eng.cache.with_pool(lambda pool: pool)
+    assert isinstance(pool, np.ndarray if want == "host" else jax.Array)
+    assert pool.shape == (8, 4) + tuple(model.kv_token_shape)
 
 
-def test_paged_decode_on_model_without_paged_support_raises():
-    class HostOnlyLM(TinyLM):
-        supports_paged = False
+@pytest.mark.parametrize("kwargs, error", [
+    ({"paged_decode": False}, ValueError),
+    ({"device_pool": True}, TypeError),
+    ({"kv_array_ns": np}, TypeError)],
+    ids=["paged_decode_false", "device_pool", "kv_array_ns"])
+def test_no_engine_option_selects_a_decode_loop_or_a_pool(kwargs, error):
+    """`paged_decode` is kept as a name that accepts `True` (the
+    benchmark's cell files pass it); the two pool options are gone."""
+    with pytest.raises(error):
+        EngineConfig(**kwargs)
+    assert EngineConfig().paged_decode is True
+    assert EngineConfig(paged_decode=True) == EngineConfig()
 
-    with pytest.raises(ValueError, match="supports_paged"):
-        InferenceEngine(HostOnlyLM(), EngineConfig(paged_decode=True))
-    # The host-gather loop still takes such a model.
-    assert not InferenceEngine(HostOnlyLM(), EngineConfig()).paged
+
+def test_default_engine_is_paged_through_no_partial_and_full_hit():
+    """`EngineConfig()` over `TinyLM`: a cold prompt, a prompt that
+    shares two sealed blocks (partial hit: `prefill_paged`) and the
+    first prompt again (full hit: one read-only step) all emit the
+    oracle's tokens, every decode step is a paged step, and the engine
+    gathers no sequence's KV on the host."""
+    m = TinyLM(vocab_size=32)
+    eng = InferenceEngine(m, EngineConfig(block_size=4, num_blocks=32))
+    base = [3, 5, 7, 9, 2, 4, 6, 8]              # two full blocks
+    hits = []
+    for prompt in (base, base + [11, 12, 13], base):
+        before = eng.prefix_hit_tokens
+        stream = eng.submit(prompt, 6)
+        _drive(eng)
+        assert stream.tokens_so_far() == m.oracle(prompt, 6)
+        hits.append(eng.prefix_hit_tokens - before)
+    assert hits == [0, 8, 8]
+    st = eng.stats()
+    assert st["paged"] is True
+    assert st["paged_steps"] == st["steps"] == 15
+    assert st["cache"]["host_gathers"] == 0
+    # Two prefills ran the model's prefill; the full hit ran none.
+    assert (m.prefill_calls, st["prefills"]) == (2, 3)
+
+
+def test_transformer_compiles_only_its_three_programs(tiny_transformer,
+                                                      caplog):
+    """Over a run with sharing on (no hit, partial hit, full hit,
+    decode), the model compiles `prefill`, `prefill_paged` and
+    `decode_paged` and no other program of its own."""
+    import re
+
+    import jax
+
+    from ray_tpu.serve.engine.model import _JitLRU
+
+    model, eng = _transformer_engine(tiny_transformer)
+    base = [3, 17, 42, 9, 21, 5, 11, 2]          # seals one 8-block
+    with jax.log_compiles(), caplog.at_level("WARNING", logger="jax"):
+        for prompt in (base + [33], base + [40, 41], base):
+            eng.submit(prompt, 3)
+            _drive(eng)
+    assert eng.prefix_hit_tokens == 16
+    compiled = set(re.findall(r"Finished XLA compilation of jit\((\w+)\)",
+                              caplog.text))
+    assert {n for n in compiled if n.startswith(("prefill", "decode"))} \
+        == {"prefill", "prefill_paged", "decode_paged"}
+    caches = {k for k, v in vars(model).items() if isinstance(v, _JitLRU)}
+    assert caches == {"_prefill_jit", "_prefill_paged_jit",
+                      "_decode_paged_jit"}
+    assert model.jit_compiles == sum(len(getattr(model, k)) for k in caches)
+
+
+def test_a_model_without_the_paged_protocol_is_refused_at_construction():
+    class GatheredOnlyLM:
+        """The old host-gather protocol: `prefill` and `decode`."""
+        kv_token_shape = (1,)
+        prefill = TinyLM.prefill
+        decode = TinyLM.decode
+
+    with pytest.raises(ValueError, match="decode_paged, prefill_paged"):
+        InferenceEngine(GatheredOnlyLM())
 
 
 def test_step_failure_outside_decode_fails_streams_and_is_logged(caplog):

@@ -12,6 +12,7 @@ untested:
 """
 
 import asyncio
+import contextvars
 import gc
 
 import pytest
@@ -111,18 +112,24 @@ def test_max_batch_size_flushes_immediately_and_timer_is_harmless():
 # ---------------------------------------------------------------------------
 # multiplex LRU
 # ---------------------------------------------------------------------------
-class _TrackedModel:
-    alive = 0
+@pytest.fixture
+def _TrackedModel():
+    """A model class that counts its live instances; a class of its own
+    for each test, so that one test's leak is not the next one's count."""
+    class Tracked:
+        alive = 0
 
-    def __init__(self, model_id):
-        self.model_id = model_id
-        type(self).alive += 1
+        def __init__(self, model_id):
+            self.model_id = model_id
+            type(self).alive += 1
 
-    def __del__(self):
-        type(self).alive -= 1
+        def __del__(self):
+            type(self).alive -= 1
+
+    return Tracked
 
 
-def test_multiplex_single_flight_concurrent_cold_load():
+def test_multiplex_single_flight_concurrent_cold_load(_TrackedModel):
     from ray_tpu.serve.multiplex import _ModelMultiplexWrapper
 
     loads = []
@@ -144,7 +151,7 @@ def test_multiplex_single_flight_concurrent_cold_load():
     assert w.model_ids == ["m1"]
 
 
-def test_multiplex_eviction_defers_until_inflight_drains():
+def test_multiplex_eviction_defers_until_inflight_drains(_TrackedModel):
     from ray_tpu.serve.multiplex import (_ModelMultiplexWrapper,
                                          _begin_request_loans,
                                          _end_request_loans)
@@ -158,10 +165,15 @@ def test_multiplex_eviction_defers_until_inflight_drains():
         token_a = _begin_request_loans()
         m1 = await w.load("m1")
         assert _TrackedModel.alive == 1
-        # ...request B (its own scope) loads m2: m1 must be EVICTED
-        # from the LRU but kept alive while A still runs it.
-        token_b = _begin_request_loans()
-        m2 = await w.load("m2")
+        # ...request B (its own scope, in its own context as a replica
+        # runs each request: closing A's scope first then leaves no
+        # loan list behind in this task's context, and with it no
+        # reference to `w`) loads m2: m1 must be EVICTED from the LRU
+        # but kept alive while A still runs it.
+        ctx_b = contextvars.copy_context()
+        token_b = ctx_b.run(_begin_request_loans)
+        m2 = await asyncio.get_running_loop().create_task(
+            w.load("m2"), context=ctx_b)
         assert w.model_ids == ["m2"]
         del m1
         gc.collect()
@@ -171,7 +183,7 @@ def test_multiplex_eviction_defers_until_inflight_drains():
         _end_request_loans(token_a)
         gc.collect()
         assert _TrackedModel.alive == 1
-        _end_request_loans(token_b)
+        ctx_b.run(_end_request_loans, token_b)
         del m2
         return w
 
@@ -181,7 +193,7 @@ def test_multiplex_eviction_defers_until_inflight_drains():
     assert _TrackedModel.alive == 0
 
 
-def test_multiplex_eviction_immediate_without_loan_scope():
+def test_multiplex_eviction_immediate_without_loan_scope(_TrackedModel):
     """Direct calls with no request scope keep the old behavior:
     eviction frees the model right away."""
     from ray_tpu.serve.multiplex import _ModelMultiplexWrapper
