@@ -1,16 +1,18 @@
 """The quickest proof that the system still starts on the chip.
 
-Drives both main paths once, through the entry points a user calls, at the
-widths of the 503M dense model (depth and weights are the only cuts: the
-weights are random, made from a seed):
+Drives both main paths once, through the entry points a user calls:
+training at the widths of the 503M dense model, serving at the
+benchmark's serving widths (`SERVE_WIDTHS`), depth 12 both (depth and
+weights are the only cuts: the weights are random, made from a seed):
 
 - train: `JaxTrainer.fit` -> Tune trial actor -> placement group -> one
   worker that leases every local chip -> `jax.distributed` -> mesh ->
   `make_train_step`, fed by a `ray_tpu.data` iterator;
 - serve: `serve.run` -> replica actor holding `{"TPU": 1}` ->
   `InferenceEngine` -> device KV pool ->
-  `TransformerEngineModel`, asked through a streaming handle and the HTTP
-  proxy.
+  `TransformerEngineModel` (its decode step attends over the pool's
+  pages in place, through the Pallas kernel), asked through a streaming
+  handle and the HTTP proxy.
 
 A chip belongs to one process at a time, so this process never touches
 JAX: every device fact below is reported by the worker that owned the
@@ -42,6 +44,10 @@ from ray_tpu.train import JaxTrainer, RunConfig, ScalingConfig
 # bench.py's model, the one configuration the repo has profiled on a chip.
 WIDTHS = dict(vocab_size=32768, d_model=1536, n_layers=12, n_heads=12,
               d_ff=6144)
+# What the serving section runs: the same block at the benchmark's serving
+# widths (`olmo-1b`: 16 heads of 128), which the paged decode kernel takes
+# (`ops.paged_attention.kernel_eligible`: a multiple of 8 heads of 128).
+SERVE_WIDTHS = dict(WIDTHS, d_model=2048, n_heads=16, d_ff=8192)
 SEED = 0
 
 
@@ -362,14 +368,26 @@ def serve_phase(widths: dict, *, expect_platform: str, chips: int,
              f"{report['pool_platforms']}")
     _require(stats["paged"] and stats["paged_steps"] > 0,
              f"serve: paged_steps={stats['paged_steps']}")
+    # On the chip (`SERVE_WIDTHS`) every step's attention reads the
+    # pool's pages in place through the Pallas kernel; off it, none does.
+    inplace = stats["decode_attn_inplace_steps"]
+    _require(inplace == (stats["paged_steps"]
+                         if expect_platform == "tpu" else 0),
+             f"serve: {inplace} of {stats['paged_steps']} paged steps "
+             f"attended over the pool's pages in place")
     out = {"device": dev, "jit_compiles": report["jit_compiles"],
            "paged_steps": stats["paged_steps"],
+           "attn_inplace_steps": inplace,
+           "kv_pages_read_per_step": round(
+               stats["decode_kv_pages_read"] / max(inplace, 1), 2),
            "replica_init_s": report["init_s"],
            "cold_wall_s": round(time.perf_counter() - t0, 1)}
     print(f"serve: platform={dev['platform']} device_kind={dev['kind']!r} "
           f"devices={dev['count']} requests={len(streams)} "
           f"tokens={sum(len(s) for s in streams)} "
           f"paged_steps={out['paged_steps']} host_gathers=0 "
+          f"attn_inplace_steps={inplace} "
+          f"kv_pages_read_per_step={out['kv_pages_read_per_step']} "
           f"pool=device/{report['pool_platforms'][0]} "
           f"jit_compiles={out['jit_compiles']} "
           f"replica_init_s={out['replica_init_s']} "
@@ -418,7 +436,8 @@ def main() -> int:
                  "This script runs the 503M model on a TPU or not at all.")
         trained = train_phase(WIDTHS, expect_platform="tpu", chips=chips)
         _wait_for_chips(chips)
-        served = serve_phase(WIDTHS, expect_platform="tpu", chips=chips)
+        served = serve_phase(SERVE_WIDTHS, expect_platform="tpu",
+                             chips=chips)
         _require(trained["device"]["kind"] == served["device"]["kind"],
                  f"phases saw different devices: {trained['device']} "
                  f"and {served['device']}")

@@ -33,12 +33,19 @@ def default_compile_cache_dir() -> str:
 def use_compile_cache() -> str:
     """Point JAX's persistent compilation cache somewhere stable and
     return the directory in use. When `JAX_COMPILATION_CACHE_DIR` is set,
-    JAX already reads it and nothing is set in code."""
+    JAX already reads it and no directory is set in code.
+
+    Every program is kept, however quickly it compiled: JAX's default
+    leaves out what compiled in under a second, and the paged decode
+    step's twenty shape buckets compile in 0.9-1.5 s each on the chip,
+    so a replica's warm-up either found them or compiled them again
+    (25 s) by the machine's mood."""
+    import jax
+
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     placed = os.environ.get(CACHE_ENV)
     if placed:
         return placed
-    import jax
-
     path = default_compile_cache_dir()
     jax.config.update("jax_compilation_cache_dir", path)
     return path

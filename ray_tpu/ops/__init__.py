@@ -2,7 +2,9 @@
 
 The reference has no sequence-parallel or long-context kernels anywhere
 (SURVEY.md §2.5 — ring attention/Ulysses absent, delegated to DeepSpeed user
-code); these are designed new for the ICI mesh.
+code); these are designed new for the ICI mesh. `paged_attention` (decode
+attention over the serving engine's paged KV pool, a Pallas kernel) is
+imported by the engine alone.
 """
 
 from ray_tpu.ops.attention import attention, plain_attention, ring_attention

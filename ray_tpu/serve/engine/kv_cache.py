@@ -29,7 +29,12 @@ The manager owns two things:
   decode steps;
 - **storage**: the preallocated `[num_blocks, block_size, *kv_shape]`
   buffer itself. The engine's model reads it through block tables
-  inside its own step: `paged_step` (a decode step: slots resolved,
+  inside its own step, and where they lie: the transformer's decode
+  attention fetches a row's blocks of one layer straight out of this
+  buffer (`ops/paged_attention.py`; on the chip a Pallas kernel whose
+  block index is `table[row, page]`, so the `[block, slot, *kv_shape]`
+  layout here is that kernel's contract), and no step gathers a dense
+  copy of a batch's cache. `paged_step` (a decode step: slots resolved,
   the donated pool re-bound), `mutate_pool` (a read-only step) and
   `with_pool` (the paged prefill) hand the live buffer to the dispatch
   under the lock, and `write_range` stores a prefill's rows. `write` /

@@ -296,17 +296,24 @@ class TransformerEngineModel:
     Prefill runs a full causal forward (same math as the training
     model's CPU path — rmsnorm, fused qkv, rotary, `plain_attention`
     scaling, silu-gated FFN, tied embeddings) while collecting K/V;
-    decode attends one query token against the cache it gathers from
-    the pool inside its jit. Both are jit-compiled per shape bucket:
-    sequence lengths pad to the next power of two (>= block multiple),
-    batches pad with masked dummy rows, so compiles are bounded by the
-    bucket count, not the request mix. MoE configs are rejected (dense engine path only).
+    decode attends one query token a row against that row's pages of
+    the pool, read where they lie through the block table, one layer
+    at a time (`ops/paged_attention.py`: a Pallas kernel on the chip at
+    a multiple of 8 heads of 128, a per-layer XLA gather elsewhere;
+    the choice is made from backend and widths, no option). No dense
+    copy of the batch's cache is ever built. Both are jit-compiled per
+    shape bucket: sequence lengths pad to the next power of two (>=
+    block multiple), batches pad with masked dummy rows, so compiles
+    are bounded by the bucket count, not the request mix. MoE configs
+    are rejected (dense engine path only).
     """
 
     def __init__(self, params, cfg, max_batch_size: int = 8,
                  jit_cache_cap: int = 32):
         import jax
         import jax.numpy as jnp
+
+        from ray_tpu.ops.paged_attention import kernel_eligible
 
         if cfg.is_moe:
             raise ValueError("TransformerEngineModel supports dense "
@@ -332,6 +339,16 @@ class TransformerEngineModel:
         # `InferenceEngine.stats()` reads both under these names.
         self.decode_h2d_arrays = 0
         self.decode_d2h_bytes = 0
+        # How a paged decode step reads the pool: the steps whose
+        # attention read pages in place through the Pallas kernel (all
+        # of them on the chip at 8k heads of 128, none elsewhere: fixed
+        # by backend and widths, `ops.paged_attention.kernel_eligible`),
+        # and the live pages the tables of those steps named
+        # (`position // block_size + 1` a row, the page the new token
+        # lands in included); a layer reads each once.
+        self._attn_inplace = kernel_eligible(cfg.n_heads, cfg.head_dim)
+        self.decode_attn_inplace_steps = 0
+        self.decode_kv_pages_read = 0
         # Host side of the calls, in seconds, each fed by its
         # `flight.span`: input padding and upload (`prep`), the call of
         # the jitted function (`dispatch`; a paged decode step's one
@@ -540,35 +557,38 @@ class TransformerEngineModel:
 
         return jax.jit(prefill_paged)
 
-    def _decode_math(self, params, tokens, positions, cache,
-                     b_pad: int, s_pad: int):
-        """Traced body of one incremental step. `cache` rows past each
-        sequence's `position` may hold ANYTHING (stale reused-block
-        data), so the new token's K/V OVERWRITES its slot (`jnp.where`,
-        not an add) and `attend` masks everything past `position`."""
+    def _decode_math(self, params, tokens, positions, pool, tables,
+                     b_pad: int):
+        """Traced body of one incremental step. Each layer's attention
+        reads the row's cached positions `[0, position)` out of `pool`
+        through `tables`, for that layer alone
+        (`ops.paged_attention.paged_decode_attention`: pages in place
+        on the chip, a per-layer XLA gather elsewhere), and takes the
+        new token's K/V as the step computed them: they reach the pool
+        after the scan, in the caller's one scatter. Pool rows at or
+        past a row's `position` may hold ANYTHING (stale reused-block
+        data, block 0 behind a padded table entry): attention masks
+        them."""
         import jax
         import jax.numpy as jnp
 
         from ray_tpu.models.transformer import _rmsnorm
+        from ray_tpu.ops.paged_attention import paged_decode_attention
         from ray_tpu.ops.rotary import rotary_freqs
 
         cfg = self._cfg
         h, hd = cfg.n_heads, cfg.head_dim
         rot1 = self._rot1
 
-        # tokens [B], positions [B], cache [B, S_pad, L, 2, H, hd].
+        # tokens [B], positions [B], tables [B, nb_pad],
+        # pool [N, bs, L, 2, H, hd].
         act = jnp.float32
         with jax.named_scope("embed"):
             x = params["embed"][tokens].astype(act)       # [B, D]
         cos, sin = rotary_freqs(hd, cfg.max_seq_len, cfg.rope_theta)
-        slot = (jnp.arange(s_pad)[None, :]
-                == positions[:, None])[:, :, None, None]   # [B,S,1,1]
-        attend = (jnp.arange(s_pad)[None, :]
-                  <= positions[:, None])               # [B, S]
-        cache = cache.transpose(2, 0, 1, 3, 4, 5)  # [L,B,S,2,H,hd]
 
         def layer(x, inputs):
-            lp, kv_l = inputs          # kv_l [B, S, 2, H, hd]
+            lp, li = inputs            # li: this layer's index in pool
             with jax.named_scope("attn"):
                 y = _rmsnorm(x, lp["ln1"])
                 qkv = jnp.einsum("bd,dkh->kbh", y,
@@ -578,15 +598,9 @@ class TransformerEngineModel:
                 v = qkv[2].reshape(b_pad, h, hd)
                 q = rot1(q, cos, sin, positions)
                 k = rot1(k, cos, sin, positions)
-                keys = jnp.where(slot, k[:, None], kv_l[:, :, 0])
-                vals = jnp.where(slot, v[:, None], kv_l[:, :, 1])
-                scale = hd ** -0.5
-                scores = jnp.einsum(
-                    "bhd,bshd->bhs", q, keys,
-                    preferred_element_type=jnp.float32) * scale
-                scores = jnp.where(attend[:, None, :], scores, -1e30)
-                probs = jax.nn.softmax(scores, axis=-1).astype(act)
-                o = jnp.einsum("bhs,bshd->bhd", probs, vals)
+                with jax.named_scope("kv_gather"):
+                    o = paged_decode_attention(q, k, v, pool, tables,
+                                               positions, li)
                 x = x + o.reshape(b_pad, h * hd) @ lp["wo"].astype(act)
             with jax.named_scope("mlp"):
                 y = _rmsnorm(x, lp["ln2"])
@@ -595,7 +609,9 @@ class TransformerEngineModel:
                          @ lp["w2"].astype(act))
             return x, jnp.stack([k, v], axis=1)   # [B, 2, H, hd]
 
-        x, new_kv = jax.lax.scan(layer, x, (params["layers"], cache))
+        x, new_kv = jax.lax.scan(
+            layer, x, (params["layers"],
+                       jnp.arange(cfg.n_layers, dtype=jnp.int32)))
         with jax.named_scope("lm_head"):
             x = _rmsnorm(x, params["ln_f"])
             logits = jnp.einsum("bd,vd->bv", x,
@@ -605,15 +621,15 @@ class TransformerEngineModel:
 
     def _build_decode_paged(self, b_pad: int, nb_pad: int,
                             block_size: int):
-        """Fused paged decode step: gather, attend, write back AND
-        sample in one compiled call. The per-sequence KV is gathered
-        from the device pool INSIDE the jit — `jnp.take` over the padded
-        block tables, reshaped to the contiguous [B, S, ...] layout the
-        core attends over — and each new token's K/V is scattered into
-        its (block, off) slot before returning. The pool is DONATED: XLA
-        aliases input to output, so steady-state decode is one dispatch
-        with no pool copy and no KV payload crossing the host boundary
-        in either direction.
+        """Fused paged decode step: attend, write back AND sample in
+        one compiled call. No copy of the batch's cache is built: each
+        layer's attention reads the rows' pages out of the device pool
+        where they lie, through the padded block tables (`_decode_math`),
+        and each new token's K/V is scattered into its (block, off) slot
+        before returning. The pool is DONATED: XLA aliases input to
+        output, so steady-state decode is one dispatch with no pool copy
+        and no KV payload crossing the host boundary in either
+        direction.
 
         What does cross: in, ONE int32 array `packed` `[b_pad, 4 +
         nb_pad]`, a row a sequence: token, position, write block, write
@@ -624,24 +640,18 @@ class TransformerEngineModel:
         import jax.numpy as jnp
 
         self.jit_compiles += 1
-        s_pad = nb_pad * block_size
-        kv_shape = self.kv_token_shape
 
         def decode_paged(pool, params, packed):
             # tables [b_pad, nb_pad], zero-padded (rows past the batch
-            # and blocks past a row's coverage gather block 0;
-            # `attend`/`slot` in the core mask the garbage). wblocks
+            # and blocks past a row's coverage name block 0; attention
+            # masks by position, so nothing of it is read). wblocks
             # padding rows point past the pool, so mode="drop" skips
             # them — dummy batch rows never touch real blocks.
             tokens, positions = packed[:, 0], packed[:, 1]
             wblocks, woffs = packed[:, 2], packed[:, 3]
             tables = packed[:, 4:]
-            with jax.named_scope("kv_gather"):
-                flat = jnp.take(pool, tables.reshape(-1), axis=0)
-                cache = flat.reshape(
-                    (b_pad, s_pad) + kv_shape).astype(jnp.float32)
             logits, new_kv = self._decode_math(
-                params, tokens, positions, cache, b_pad, s_pad)
+                params, tokens, positions, pool, tables, b_pad)
             with jax.named_scope("kv_write"):
                 new_pool = pool.at[wblocks, woffs].set(
                     new_kv.astype(pool.dtype), mode="drop")
@@ -716,7 +726,11 @@ class TransformerEngineModel:
         with flight.span("model", "decode.prep", None, phase,
                          "decode_prep_s"):
             b_pad = _next_pow2(max(b, 1))
-            nb = max(int(p) // block_size + 1 for p in positions)
+            pages = [int(p) // block_size + 1 for p in positions]
+            nb = max(pages)
+            if self._attn_inplace:
+                self.decode_attn_inplace_steps += 1
+                self.decode_kv_pages_read += sum(pages)
             nb_pad = _next_pow2(max(nb, 1))
             key = (b_pad, nb_pad, block_size)
             fn = self._decode_paged_jit.get(key)

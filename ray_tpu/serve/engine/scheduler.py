@@ -925,7 +925,14 @@ class InferenceEngine:
         among the arguments of its jitted calls, which the call uploads
         (one a step), and bytes brought back (a step's sampled
         ids, 4 a padded row; its `[b_pad, V]` logits only where
-        somebody fetched them); 0 for a model that keeps no such count."""
+        somebody fetched them); 0 for a model that keeps no such count.
+        `decode_attn_inplace_steps` counts the paged steps whose
+        attention read the pool's pages in place through the Pallas
+        kernel (every step on the chip at heads of 128; none on the CPU,
+        at other head sizes, or for `TinyLM`), `decode_kv_pages_read`
+        the live pages the block tables of those steps named
+        (`position // block_size + 1` a row): what a layer of such a
+        step reads of the pool."""
         with self._lock:
             running = len(self._running)
             waiting = len(self._waiting)
@@ -954,6 +961,10 @@ class InferenceEngine:
             "decode_h2d_arrays": getattr(
                 self.model, "decode_h2d_arrays", 0),
             "decode_d2h_bytes": getattr(self.model, "decode_d2h_bytes", 0),
+            "decode_attn_inplace_steps": getattr(
+                self.model, "decode_attn_inplace_steps", 0),
+            "decode_kv_pages_read": getattr(
+                self.model, "decode_kv_pages_read", 0),
             "jit_bucket_evictions": getattr(
                 self.model, "jit_cache_evictions", 0),
             "prefill_s": round(self.prefill_s, 6),

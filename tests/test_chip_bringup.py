@@ -25,17 +25,25 @@ def test_compile_cache_is_placed_from_outside_or_next_to_the_package(
     from ray_tpu.core.jax_platform import use_compile_cache
 
     before = jax.config.jax_compilation_cache_dir
+    least = jax.config.jax_persistent_cache_min_compile_time_secs
     try:
-        # Set from outside: JAX reads it itself, nothing is set in code.
+        # Set from outside: JAX reads it itself, no directory is set in
+        # code. Either way every program is kept, the quick ones too
+        # (the paged decode step's buckets compile in about a second).
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
         assert use_compile_cache() == "/some/dir"
         assert jax.config.jax_compilation_cache_dir == before
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
         placed = os.path.join(REPO, ".jax_cache")
         assert use_compile_cache() == placed
         assert jax.config.jax_compilation_cache_dir == placed
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          least)
 
 
 @pytest.mark.unit

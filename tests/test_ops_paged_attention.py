@@ -1,0 +1,301 @@
+"""Decode attention over the paged KV pool (`ops/paged_attention.py`).
+
+The Pallas kernel runs here in interpret mode at small shapes with heads
+of 128, against the XLA body it replaces off the chip and against a
+dense float64 reference that gathers each row's cache by hand. At the
+end of the file the kernel and the whole paged decode step are compiled
+for a described v5e at `olmo-1b`'s widths: nothing runs, but the chip's
+compiler says what it would refuse, and whether it would copy the pool.
+"""
+
+import numpy as np
+import pytest
+
+pytestmark = pytest.mark.unit
+
+N_BLOCKS, BS, LAYERS, HEADS, HD = 12, 4, 3, 8, 128
+STALE = 1.0e4     # what a reused block may still hold past `position`
+
+
+def _dense_reference(q, k_new, v_new, pool, tables, positions, layer):
+    """Row by row in float64: gather positions [0, position) through
+    the table, append the step's own key and value, softmax."""
+    out = np.zeros(q.shape, np.float64)
+    bs = pool.shape[1]
+    for i, pos in enumerate(positions):
+        rows = [pool[tables[i][t // bs], t % bs, layer]
+                for t in range(pos)]
+        keys = np.stack([r[0] for r in rows] + [k_new[i]]).astype(np.float64)
+        vals = np.stack([r[1] for r in rows] + [v_new[i]]).astype(np.float64)
+        scores = np.einsum("hd,shd->hs", q[i].astype(np.float64),
+                           keys) * q.shape[-1] ** -0.5
+        probs = np.exp(scores - scores.max(axis=-1, keepdims=True))
+        probs /= probs.sum(axis=-1, keepdims=True)
+        out[i] = np.einsum("hs,shd->hd", probs, vals)
+    return out
+
+
+def _case(name):
+    """(tables [B, nb], positions [B], pool) for one named layout."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    pool = rng.normal(size=(N_BLOCKS, BS, LAYERS, 2, HEADS, HD)).astype(
+        np.float32)
+    nb = 4
+    if name == "one_token_row":
+        # Nothing cached: the row attends over its own token alone.
+        tables, positions = [[3, 0, 0, 0], [5, 6, 0, 0]], [0, 6]
+    elif name == "row_ends_on_block_edge":
+        # Positions 8 and 16: the cached part fills whole pages, and
+        # the second row fills the whole table.
+        tables, positions = [[1, 2, 9, 0], [4, 5, 6, 7]], [8, 16]
+    elif name == "rows_shorter_than_table":
+        tables, positions = [[7, 0, 0, 0], [2, 3, 0, 0], [8, 9, 10, 11]], \
+            [3, 5, 15]
+    elif name == "padded_batch_rows":
+        # Rows past the batch: table of zeros, position 0, as
+        # `decode_paged` pads them; block 0 holds another row's data.
+        tables, positions = [[0, 1, 0, 0], [0, 0, 0, 0], [0, 0, 0, 0],
+                             [0, 0, 0, 0]], [7, 0, 0, 0]
+    elif name == "shuffled_tables":
+        perm = rng.permutation(N_BLOCKS)
+        tables = [perm[:4].tolist(), perm[4:8].tolist(),
+                  perm[8:12].tolist()]
+        positions = [13, 9, 15]
+    elif name == "shared_tables":
+        # Two rows read the same prefix blocks (prefix sharing), then
+        # part ways.
+        tables, positions = [[2, 5, 8, 0], [2, 5, 9, 0], [2, 5, 0, 0]], \
+            [10, 11, 8]
+    elif name == "stale_data_past_position":
+        # A reused block: rows at and past `position`, and the blocks
+        # behind padded table entries, hold large stale values.
+        tables, positions = [[1, 2, 0, 0], [3, 0, 0, 0]], [6, 1]
+        pool[2, 2:] = STALE
+        pool[3, 1:] = STALE
+        pool[0] = -STALE
+    elif name == "one_row_one_page":
+        nb = 1
+        tables, positions = [[6]], [3]
+    else:
+        raise KeyError(name)
+    assert all(len(t) == nb for t in tables)
+    return (np.asarray(tables, np.int32), np.asarray(positions, np.int32),
+            pool)
+
+
+CASES = ["one_token_row", "row_ends_on_block_edge",
+         "rows_shorter_than_table", "padded_batch_rows", "shuffled_tables",
+         "shared_tables", "stale_data_past_position", "one_row_one_page"]
+
+
+def _inputs(name):
+    tables, positions, pool = _case(name)
+    rng = np.random.default_rng(len(name))
+    q, k_new, v_new = (rng.normal(size=(len(positions), HEADS, HD)).astype(
+        np.float32) for _ in range(3))
+    return q, k_new, v_new, pool, tables, positions
+
+
+@pytest.mark.parametrize("name", CASES)
+def test_kernel_matches_xla_body_and_dense_reference(name):
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import paged_attention as pa
+
+    args = _inputs(name)
+    for layer in (0, LAYERS - 1):
+        want = _dense_reference(*args, layer)
+        dev = [jnp.asarray(a) for a in args]
+        xla = np.asarray(pa.paged_decode_attention_xla(*dev, layer))
+        kernel = np.asarray(pa.paged_decode_attention_kernel(
+            *dev, jnp.int32(layer), interpret=True))
+        assert np.isfinite(kernel).all()
+        np.testing.assert_allclose(kernel, xla, atol=2e-5, rtol=2e-5)
+        np.testing.assert_allclose(kernel, want, atol=2e-5, rtol=2e-5)
+        np.testing.assert_allclose(xla, want, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("name", ["padded_batch_rows",
+                                  "stale_data_past_position"])
+def test_result_ignores_what_lies_past_position(name):
+    """Rewrite everything a row must not read (pool rows at or past its
+    position, every block its live pages do not name): neither body's
+    result moves by a bit."""
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import paged_attention as pa
+
+    q, k_new, v_new, pool, tables, positions = _inputs(name)
+    live = np.zeros(pool.shape[:2], bool)
+    for table, pos in zip(tables, positions):
+        for t in range(pos):
+            live[table[t // BS], t % BS] = True
+    other = np.where(live[:, :, None, None, None, None], pool,
+                     np.float32(-3 * STALE))
+    for body in (pa.paged_decode_attention_xla,
+                 lambda *a: pa.paged_decode_attention_kernel(
+                     *a, interpret=True)):
+        got = [np.asarray(body(*(jnp.asarray(a) for a in (
+            q, k_new, v_new, p, tables, positions)), jnp.int32(1)))
+            for p in (pool, other)]
+        np.testing.assert_array_equal(got[0], got[1])
+
+
+def test_kernel_eligibility_follows_backend_and_widths(monkeypatch):
+    """Off the chip nothing is eligible; on it, a multiple of 8 heads of
+    a multiple of 128: what the code can see, no option."""
+    import jax
+
+    from ray_tpu.ops import paged_attention as pa
+
+    assert not pa.kernel_eligible(16, 128)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert pa.kernel_eligible(16, 128) and pa.kernel_eligible(8, 256)
+    assert not pa.kernel_eligible(32, 64)      # smollm2's heads
+    assert not pa.kernel_eligible(12, 128)     # the pool's layout differs
+    assert not pa.kernel_eligible(4, 16)       # the unit tests' models
+
+
+# ---------------------------------------------------------------------------
+# the engine's step over the kernel, interpreted
+# ---------------------------------------------------------------------------
+def _tiny_model(**kw):
+    import jax
+
+    from ray_tpu.models.transformer import TransformerConfig, init_params
+    from ray_tpu.serve.engine import TransformerEngineModel
+
+    cfg = TransformerConfig(vocab_size=64, d_model=HEADS * HD, n_layers=2,
+                            n_heads=HEADS, d_ff=64, max_seq_len=64)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    return TransformerEngineModel(params, cfg, **kw)
+
+
+def _generate(model, prompts, new_tokens):
+    from ray_tpu.serve.engine import EngineConfig, InferenceEngine
+
+    model.eos_token = None
+    eng = InferenceEngine(model, EngineConfig(
+        max_batch_size=4, block_size=4, num_blocks=32))
+    streams = [eng.submit(p, new_tokens) for p in prompts]
+    while eng.step():
+        pass
+    return [list(s) for s in streams], eng.stats()
+
+
+def test_engine_step_through_the_kernel_matches_the_xla_step(monkeypatch):
+    """The same requests through the engine twice: the XLA body (what
+    the CPU picks) and the kernel, steered here and interpreted. Same
+    tokens, and the counters say which steps read pages in place."""
+    from functools import partial
+
+    from ray_tpu.ops import paged_attention as pa
+
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(2, 64, n).tolist() for n in (1, 4, 9)]
+    want, plain = _generate(_tiny_model(), prompts, 6)
+    assert plain["paged_steps"] > 0
+    assert (plain["decode_attn_inplace_steps"],
+            plain["decode_kv_pages_read"]) == (0, 0)
+
+    monkeypatch.setattr(pa, "kernel_eligible", lambda h, hd: hd % 128 == 0)
+    monkeypatch.setattr(pa, "paged_decode_attention_kernel", partial(
+        pa.paged_decode_attention_kernel, interpret=True))
+    got, stats = _generate(_tiny_model(), prompts, 6)
+    assert got == want
+    assert stats["decode_attn_inplace_steps"] == stats["paged_steps"] > 0
+    # Every row of every step names `position // 4 + 1` pages; the rows
+    # decode positions n .. n + 4 (the prefill gave the first token).
+    assert stats["decode_kv_pages_read"] == sum(
+        pos // 4 + 1 for n in (1, 4, 9) for pos in range(n, n + 5))
+
+
+# ---------------------------------------------------------------------------
+# compiled for the chip, not run (no chip here)
+# ---------------------------------------------------------------------------
+OLMO = dict(vocab_size=50304, d_model=2048, n_layers=16, n_heads=16,
+            d_ff=8192, rope_theta=10000.0)
+POOL = (1024, 16, 16, 2, 16, 128)       # the serve cells' pool: 4.3 GB
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no compiler, no test
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent
+    cache but cannot be read back without the chip."""
+    import jax
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+
+
+@pytest.mark.parametrize("b_pad,nb_pad", [(1, 1), (8, 32), (8, 64)])
+def test_kernel_compiles_for_v5e_at_olmo_widths(one_chip, no_compile_cache,
+                                                b_pad, nb_pad):
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import paged_attention as pa
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    row = spec((b_pad,) + POOL[-2:])
+    compiled = jax.jit(pa.paged_decode_attention_kernel).lower(
+        row, row, row, spec(POOL), spec((b_pad, nb_pad), jnp.int32),
+        spec((b_pad,), jnp.int32), spec((), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # The pool is an operand as it stands: no converted copy of it.
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_decode_step_compiles_for_v5e_without_a_copy_of_the_pool(
+        one_chip, no_compile_cache, monkeypatch):
+    """The whole `jit_decode_paged` of the (8, 64) bucket at `olmo-1b`'s
+    widths, the kernel steered on (the backend here is the CPU): the
+    donated pool is aliased to the output, and what the program holds
+    besides its arguments is the bf16 weights (2.15 GB), not a dense
+    copy of the batch's cache nor a second pool."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.transformer import TransformerConfig, init_params
+    from ray_tpu.ops import paged_attention as pa
+    from ray_tpu.serve.engine import TransformerEngineModel
+
+    monkeypatch.setattr(pa, "kernel_eligible", lambda h, hd: True)
+    cfg = TransformerConfig(**OLMO, max_seq_len=1024)
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    model = TransformerEngineModel(params, cfg)
+    assert (1024, 16) + model.kv_token_shape == POOL
+    compiled = model._build_decode_paged(8, 64, 16).lower(
+        jax.ShapeDtypeStruct(POOL, jnp.float32, sharding=one_chip), params,
+        jax.ShapeDtypeStruct((8, 4 + 64), jnp.int32,
+                             sharding=one_chip)).compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    assert "tpu_custom_call" in text
+    pool_bytes = int(np.prod(POOL)) * 4
+    assert memory.alias_size_in_bytes == pool_bytes
+    assert memory.temp_size_in_bytes < 2.5e9
+    for dense in ("f32[512,16,16,2,16,128]", "f32[16,8,1024,2,16,128]",
+                  "f32[8,1024,16,2,16,128]"):
+        assert dense not in text

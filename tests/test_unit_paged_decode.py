@@ -4,8 +4,8 @@ The KV block pool lives where the engine's model says (`kv_pool_ns`):
 numpy for `TinyLM`, a jax array for `TransformerEngineModel`, whose
 every mutation is a donated-arg jitted update. The engine hands the
 pool + block tables into ONE model call per decode iteration (the
-transformer's: in-jit `jnp.take` gather, decode math, in-place KV
-scatter). Correctness here is token-level: TinyLM's next token is a
+transformer's: attention over the pool's pages through the tables, one
+layer at a time, decode math, in-place KV scatter). Correctness here is token-level: TinyLM's next token is a
 function of the CACHED kv contents, so any table/gather/scatter
 indexing bug changes the output against `TinyLM.oracle` (a subclass
 that asks for `jax.numpy` puts the same oracle over the device pool);
@@ -14,8 +14,10 @@ adoption, preemption and cross-engine shipping semantics must be
 bit-identical in both pool residencies.
 
 Everything runs under `JAX_PLATFORMS=cpu` — the device pool is then
-host RAM, but the code path (donation, in-jit gather, scatter
-write-back) is exactly what a TPU backend executes.
+host RAM, but the code path (donation, reads through the tables, scatter
+write-back) is what a TPU backend executes, but for the attention
+itself: the chip's Pallas kernel is tested, interpreted, in
+`test_ops_paged_attention.py`.
 """
 
 import threading
@@ -228,7 +230,7 @@ def _transformer_engine(tiny_transformer, **cfg_kw):
 
 
 def test_transformer_paged_matches_full_recompute(tiny_transformer):
-    """The fused paged engine (device pool, in-jit gather, in-place
+    """The fused paged engine (device pool, pages read in place, in-place
     scatter) emits token-for-token what greedy full-forward recompute
     (`models.transformer.forward`, no cache) emits."""
     import jax.numpy as jnp
@@ -446,6 +448,57 @@ def test_transformer_compiles_only_its_three_programs(tiny_transformer,
     assert caches == {"_prefill_jit", "_prefill_paged_jit",
                       "_decode_paged_jit"}
     assert model.jit_compiles == sum(len(getattr(model, k)) for k in caches)
+
+
+def _all_avals(jaxpr):
+    """Every value a jaxpr computes, inner jaxprs (scan, pjit) included."""
+    import jax
+
+    for eqn in jaxpr.eqns:
+        for var in eqn.outvars:
+            yield var.aval
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _all_avals(sub)
+
+
+def test_decode_step_builds_no_dense_copy_of_the_batchs_cache(
+        tiny_transformer):
+    """The step reads the pool through the tables a layer at a time:
+    besides the pool itself (the donated scatter's result), nothing it
+    computes is as large as `[b_pad, s_pad, L, 2, H, hd]`, the whole
+    cache of the batch; the largest read is one layer of it."""
+    import jax
+    import jax.numpy as jnp
+
+    model, eng = _transformer_engine(tiny_transformer)
+    b_pad, nb_pad, bs = 4, 8, eng.config.block_size
+    layers, _, heads, hd = model.kv_token_shape
+    pool = jax.ShapeDtypeStruct(
+        (eng.config.num_blocks, bs) + model.kv_token_shape, jnp.float32)
+    jaxpr = jax.make_jaxpr(model._build_decode_paged(b_pad, nb_pad, bs))(
+        pool, model._params,
+        jax.ShapeDtypeStruct((b_pad, 4 + nb_pad), jnp.int32))
+    one_layer = b_pad * nb_pad * bs * 2 * heads * hd
+    sizes = sorted({int(np.prod(a.shape)) for a in _all_avals(jaxpr.jaxpr)
+                    if a.shape != pool.shape})
+    assert sizes[-1] == one_layer, sizes[-3:]
+    assert one_layer * layers > sizes[-1]
+
+
+def test_stats_carry_the_in_place_attention_counters(tiny_transformer):
+    """`decode_attn_inplace_steps` and `decode_kv_pages_read` are
+    top-level numbers of `stats()` from construction; on the CPU no
+    step goes through the kernel, so both stand at 0 after a run."""
+    _, eng = _transformer_engine(tiny_transformer)
+    keys = ("decode_attn_inplace_steps", "decode_kv_pages_read")
+    assert [eng.stats()[k] for k in keys] == [0, 0]
+    eng.submit([3, 17, 42, 9], 5)
+    _drive(eng)
+    assert eng.stats()["paged_steps"] > 0
+    assert [eng.stats()[k] for k in keys] == [0, 0]
+    tiny = InferenceEngine(TinyLM(), EngineConfig(
+        max_batch_size=2, block_size=4, num_blocks=8))
+    assert [tiny.stats()[k] for k in keys] == [0, 0]
 
 
 def test_a_model_without_the_paged_protocol_is_refused_at_construction():
