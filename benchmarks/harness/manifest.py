@@ -1,9 +1,10 @@
 """BENCHMARK.json and the data files it names, found by name.
 
-A later PR adds a configuration, a traffic mix, a cell or a per-layer
-metric as new files plus one entry in BENCHMARK.json; nothing here (or
-anywhere else under `benchmarks/`) is edited for it:
+A later PR adds an architecture, a configuration, a traffic mix, a cell
+or a per-layer metric as new files plus one entry in BENCHMARK.json;
+nothing here (or anywhere else under `benchmarks/`) is edited for it:
 
+    benchmarks/families/<family>.py         what one architecture runs
     benchmarks/configs/<config>.json        the sizes as they are run
     benchmarks/traffic/<mix>.json           kind + parameters of one mix
     benchmarks/cells/<cell>.json            configuration, mix, chips, settings
@@ -27,12 +28,9 @@ NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 KINDS = {"serve_open", "serve_closed", "train"}
-
-# Published config.json keys -> `TransformerConfig` fields.
-WIDTH_KEYS = {"vocab_size": "vocab_size", "hidden_size": "d_model",
-              "num_hidden_layers": "n_layers",
-              "num_attention_heads": "n_heads",
-              "intermediate_size": "d_ff", "rope_theta": "rope_theta"}
+# The family of a configuration that names none: the block the benchmark
+# began with (`families/dense.py`).
+DEFAULT_FAMILY = "dense"
 
 
 def _load_json(path: str) -> dict:
@@ -48,26 +46,37 @@ def bench_dir(root: str = ROOT) -> str:
     return os.path.join(root, "benchmarks")
 
 
-def model_widths(config: dict) -> dict:
-    """The `TransformerConfig` fields of a published config. The block the
-    tree runs is plain multi-head attention with tied embeddings, a
-    silu-gated FFN and no biases: a config that says otherwise is refused
-    here, not run as something else."""
-    problems = []
-    if config.get("num_key_value_heads",
-                  config["num_attention_heads"]) != \
-            config["num_attention_heads"]:
-        problems.append("grouped-query heads")
-    if not config.get("tie_word_embeddings", False):
-        problems.append("untied embeddings")
-    if config.get("attention_bias", False):
-        problems.append("attention biases")
-    if config.get("hidden_act", "silu") != "silu":
-        problems.append(f"activation {config.get('hidden_act')}")
-    if problems:
-        raise ValueError("the tree's one block cannot run this config: "
-                         + ", ".join(problems))
-    return {ours: config[theirs] for theirs, ours in WIDTH_KEYS.items()}
+_FAMILIES: Dict[str, object] = {}
+
+
+def load_family(name: str = DEFAULT_FAMILY, root: str = ROOT):
+    """The module benchmarks/families/<name>.py: what one architecture
+    runs, its reference, counts, tolerances and drive (the names are in
+    benchmarks/README.md). Loaded once a process; its top level imports
+    neither JAX nor the program."""
+    if not NAME_RE.match(name):
+        raise ValueError(f"bad family name {name!r}")
+    path = os.path.join(bench_dir(root), "families", f"{name}.py")
+    if path not in _FAMILIES:
+        spec = importlib.util.spec_from_file_location(
+            f"bench_family_{re.sub(r'[^A-Za-z0-9_]', '_', name)}", path)
+        module = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(module)
+        _FAMILIES[path] = module
+    return _FAMILIES[path]
+
+
+def family_of(cell: dict):
+    """The family module of a cell that `load_cell` resolved."""
+    return load_family(cell["family"], cell["root"])
+
+
+def model_widths(config: dict, root: str = ROOT) -> dict:
+    """The widths the configuration's family makes of its published keys:
+    opaque here but for `vocab_size`, from which the load generator draws
+    token ids. What the family cannot run it refuses."""
+    return load_family(config.get("family", DEFAULT_FAMILY),
+                       root).widths(config)
 
 
 def load_cell(name: str, root: str = ROOT) -> dict:
@@ -99,8 +108,9 @@ def load_cell(name: str, root: str = ROOT) -> dict:
 
     return {
         "name": name, "chips": entry["chips"], "settings": settings,
-        "config_name": entry["config"], "config": config,
-        "widths": model_widths(config),
+        "root": root, "config_name": entry["config"], "config": config,
+        "family": config.get("family", DEFAULT_FAMILY),
+        "widths": model_widths(config, root),
         "traffic_name": entry["traffic"], "traffic": traffic,
         "end_to_end": [m for m in manifest["end_to_end"]
                        if reported_here(m)],
@@ -122,10 +132,11 @@ def load_reader(metric_name: str, root: str = ROOT
     return module.read
 
 
-def read_layer_metrics(cell: dict, ctx: dict, root: str = ROOT
+def read_layer_metrics(cell: dict, ctx: dict, root: Optional[str] = None
                        ) -> Dict[str, dict]:
     """Every per-layer metric of the cell whose reader found something
     to read; a reader that returns None leaves its metric out."""
+    root = root or cell.get("root", ROOT)
     out = {}
     for metric in cell["per_layer"]:
         value = load_reader(metric["name"], root)(ctx)

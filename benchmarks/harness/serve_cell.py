@@ -1,6 +1,8 @@
 """A serving cell: `serve.run` -> one replica holding the chip ->
-`InferenceEngine(paged_decode=True)` -> `TransformerEngineModel`, asked
-through streaming `DeploymentHandle`s from this (the harness's) process.
+`InferenceEngine(paged_decode=True)` -> the engine model of the
+configuration's family (`benchmarks/families/<name>.py`: weights, model,
+warm-up, drive, reference, tolerance), asked through streaming
+`DeploymentHandle`s from this (the harness's) process.
 
 The shape is `chip_smoke.py`'s: this process never imports JAX, and every
 device fact is reported by the replica that owns the chip. The deployment
@@ -22,19 +24,6 @@ from benchmarks.harness import loadgen, manifest, stats
 from benchmarks.harness import trace as trace_mod
 
 DEPLOYMENT = "bench_lm"
-# Tolerance of engine logits against the float32 reference at one
-# position: the root-mean-square of the difference over the
-# root-mean-square of the reference's logits. The engine keeps float32
-# weights and activations but multiplies with XLA's default precision,
-# which on a TPU rounds both operands to bf16 (the trace shows the
-# weights converted each step) and accumulates in float32: measured
-# 0.009-0.012 on the chip at the published widths, 1e-6 on the CPU
-# (PERF.md, Findings). A wrong position, a stale or missing KV row or a
-# dropped layer gives about 1: forty times the limit. The largest single
-# logit's difference (over the same rms) is held to five times the limit;
-# over 50 k logits it sits at four to five times the rms difference.
-LOGIT_TOLERANCE = 0.025
-
 
 def device_facts() -> dict:
     """The device as JAX reports it to the process that owns the chip."""
@@ -71,42 +60,42 @@ class CompileCounter:
             self.count += 1
 
 
-def check_against_reference(engine, params, cfg, prompt_lengths, steps: int,
-                            seed: int) -> dict:
-    """Prefill, then `steps` decode steps through the paged cache, against
-    the reference's full forward on the same weights: logits, not tokens
-    (with random weights the largest logit changes on rounding). Uses the
-    engine's model and cache as the scheduler does, on sequences of its
-    own, while the engine is idle."""
-    import jax.numpy as jnp
+def held_bytes(params, engine) -> dict:
+    """The dtypes the replica holds: of the weights (the one most of
+    their bytes are in) and of the KV pool. The family's counts take
+    their bytes a value from this, not from a constant."""
+    import jax
 
-    from benchmarks.harness import reference
+    by_dtype: dict = {}
+    for leaf in jax.tree.leaves(params):
+        by_dtype[leaf.dtype] = by_dtype.get(leaf.dtype, 0) + leaf.nbytes
+    weights = np.dtype(max(by_dtype, key=by_dtype.get))
+    pool = np.dtype(engine.cache.with_pool(lambda pool: pool.dtype))
+    return {"weights": {"dtype": str(weights),
+                        "bytes_per_value": weights.itemsize},
+            "kv_pool": {"dtype": str(pool),
+                        "bytes_per_value": pool.itemsize}}
 
-    ref = reference.make_logits_fn(cfg.n_heads, cfg.rope_theta)
-    cache, model = engine.cache, engine.model
-    block = engine.config.block_size
+
+def check_against_reference(family, engine, served: dict, widths: dict,
+                            prompt_lengths, steps: int, seed: int,
+                            reference_widths: Optional[dict] = None) -> dict:
+    """The family's drive (prefill, then `steps` decode steps through the
+    engine's cache, as the scheduler makes them) against the family's
+    plain reference on the same weights: logits, not tokens (with random
+    weights the largest logit changes on rounding). `reference_widths`
+    hands the reference other widths than the engine runs: how the tests
+    see the check fail."""
+    ref = family.reference_logits(reference_widths or widths)
+    tolerance = family.LOGIT_TOLERANCE
     rng = np.random.default_rng([seed, 12])
     errors, largest = [], []
     for i, n in enumerate(prompt_lengths):
-        tokens = rng.integers(2, cfg.vocab_size, n).tolist()
-        sid, got = f"bench-check-{i}", []
-        cache.allocate(sid, n, writable_from=0)
-        logits, kv = model.prefill(tokens)
-        cache.write_range(sid, 0, kv)
-        got.append(np.asarray(logits))
-        for _ in range(steps):
-            tok = int(np.argmax(got[-1]))
-            tokens.append(tok)
-            pos = len(tokens) - 1
-            cache.allocate(sid, len(tokens), writable_from=pos)
-            table = cache.block_table(sid)
-            logits = cache.paged_step(
-                [(sid, pos)],
-                lambda pool, blocks, offs: model.decode_paged(
-                    pool, [table], [tok], [pos], blocks, offs, block))
-            got.append(np.asarray(logits)[0])
-        cache.free(sid)
-        want = np.asarray(ref(params, jnp.asarray(tokens, jnp.int32)))
+        prompt = rng.integers(2, widths["vocab_size"], n).tolist()
+        got, tokens = family.drive(engine, served, prompt, steps,
+                                   f"bench-check-{i}")
+        want = np.asarray(ref(served["params"],
+                              np.asarray(tokens, np.int32)))
         for j, row in enumerate(got):
             expect = want[n - 1 + j]
             scale = np.sqrt(np.mean(expect * expect))
@@ -115,10 +104,10 @@ def check_against_reference(engine, params, cfg, prompt_lengths, steps: int,
             largest.append(float(np.max(np.abs(row - expect)) / scale))
     return {"max_error": max(errors), "errors": errors,
             "largest_single_logit": max(largest),
-            "tolerance": LOGIT_TOLERANCE,
+            "tolerance": tolerance,
             "ok": bool(np.isfinite(errors + largest).all()
-                       and max(errors) <= LOGIT_TOLERANCE
-                       and max(largest) <= 5 * LOGIT_TOLERANCE)}
+                       and max(errors) <= tolerance
+                       and max(largest) <= 5 * tolerance)}
 
 
 def make_deployment(chips: int):
@@ -131,29 +120,16 @@ def make_deployment(chips: int):
         """Runs in the replica that leased the chip."""
 
         def __init__(self, spec: dict):
-            import jax
-
-            from ray_tpu.models.transformer import (TransformerConfig,
-                                                    init_params)
-            from ray_tpu.serve.engine import (EngineConfig,
-                                              InferenceEngine,
-                                              TransformerEngineModel)
+            from ray_tpu.serve.engine import InferenceEngine
 
             self.compiles = CompileCounter()
             self.spec = spec
-            self.cfg = TransformerConfig(**spec["widths"],
-                                         max_seq_len=spec["max_seq_len"])
-            # Weights on the device in one jitted call from the seed.
-            self.params = jax.jit(lambda: init_params(
-                jax.random.PRNGKey(spec["seed"] % (2 ** 31 - 1)),
-                self.cfg))()
-            engine = dict(spec["engine"])
-            self.model = TransformerEngineModel(
-                self.params, self.cfg,
-                max_batch_size=engine["max_batch_size"])
-            # Random weights give no token the meaning "end of sequence".
-            self.model.eos_token = None
-            self.engine = InferenceEngine(self.model, EngineConfig(**engine))
+            self.family = manifest.load_family(spec["family"], spec["root"])
+            self.served = self.family.build_serving(
+                spec["widths"], spec["settings"], spec["seed"])
+            self.model = self.served["model"]
+            self.engine = InferenceEngine(self.model,
+                                          self.served["engine_config"])
             self.traced = {"decode_steps": 0, "decode_rows": 0,
                            "decode_live_tokens": 0}
             if spec["trace"]:
@@ -167,34 +143,37 @@ def make_deployment(chips: int):
             import jax
 
             model, engine, traced = self.model, self.engine, self.traced
-            prefill, decode, step = (model.prefill, model.decode_paged,
-                                     engine.step)
+            calls = self.family.TRACED_CALLS
+            rows_and_live = self.family.decode_step_rows_and_live
+            prefill = getattr(model, calls["prefill"])
+            decode = getattr(model, calls["decode_step"])
+            step = engine.step
 
             def traced_prefill(*args, **kwargs):
                 with jax.profiler.TraceAnnotation("bench:prefill"):
                     return prefill(*args, **kwargs)
 
-            def traced_decode(pool, tables, lasts, positions, *rest, **kw):
+            def traced_decode(*args, **kwargs):
+                rows, live = rows_and_live(args, kwargs)
                 traced["decode_steps"] += 1
-                traced["decode_rows"] += len(positions)
-                traced["decode_live_tokens"] += sum(
-                    int(p) + 1 for p in positions)
+                traced["decode_rows"] += rows
+                traced["decode_live_tokens"] += live
                 with jax.profiler.TraceAnnotation("bench:decode_step"):
-                    return decode(pool, tables, lasts, positions, *rest,
-                                  **kw)
+                    return decode(*args, **kwargs)
 
             def traced_step():
                 with jax.profiler.TraceAnnotation("bench:engine_step"):
                     return step()
 
-            model.prefill, model.decode_paged = traced_prefill, traced_decode
+            setattr(model, calls["prefill"], traced_prefill)
+            setattr(model, calls["decode_step"], traced_decode)
             engine.step = traced_step
 
         # -- set-up: warm every shape the mix can reach, then check -----
         def prepare(self, shapes: dict) -> dict:
             t0 = time.perf_counter()
             rng = np.random.default_rng([self.spec["seed"], 11])
-            vocab = self.cfg.vocab_size
+            vocab = self.spec["widths"]["vocab_size"]
             streams = [self.engine.submit(
                 rng.integers(2, vocab, n).tolist(), 1)
                 for n in shapes["prompt_lengths"]]
@@ -202,19 +181,15 @@ def make_deployment(chips: int):
                 if len(list(stream)) != 1:
                     raise RuntimeError("a warm-up prefill gave no token")
             t1 = time.perf_counter()
-            block = self.engine.config.block_size
             for nb in shapes["decode_tables"]:
                 for b in shapes["decode_batches"]:
-                    # A read-only fused step (empty write list) over
-                    # block 0: compiles and runs the (b, nb) bucket.
-                    self.engine.cache.mutate_pool(
-                        lambda pool: self.model.decode_paged(
-                            pool, [[0] * nb] * b, [2] * b,
-                            [nb * block - 1] * b, [], [], block))
+                    self.family.warm_bucket(self.engine, self.served, b, nb)
             t2 = time.perf_counter()
-            check = self._check(self.spec["check_prompts"],
-                                self.spec["check_decode_steps"])
+            settings = self.spec["settings"]
+            check = self._check(settings["check_prompts"],
+                                settings["check_decode_steps"])
             return {"device": device_facts(), "check": check,
+                    "held": held_bytes(self.served["params"], self.engine),
                     "warm_prefill_s": t1 - t0, "warm_decode_s": t2 - t1,
                     "check_s": time.perf_counter() - t2,
                     "compiles": self.compiles.count,
@@ -224,8 +199,8 @@ def make_deployment(chips: int):
 
         def _check(self, prompt_lengths: List[int], steps: int) -> dict:
             return check_against_reference(
-                self.engine, self.params, self.cfg, prompt_lengths, steps,
-                self.spec["seed"])
+                self.family, self.engine, self.served, self.spec["widths"],
+                prompt_lengths, steps, self.spec["seed"])
 
         # -- the served path ---------------------------------------------
         def generate(self, req: dict):
@@ -245,10 +220,11 @@ def make_deployment(chips: int):
             out.update({f"cache.{k}": s["cache"][k] for k in (
                 "host_gathers", "pool_updates", "cow_copies", "adoptions")})
             out.update({
-                "model.prefill_tokens": self.model.prefill_tokens,
-                "model.prefill_calls": self.model.prefill_calls,
-                "model.decode_calls": self.model.decode_calls,
-                "model.jit_compiles": self.model.jit_compiles,
+                # A model that compiles nothing of its own has no such
+                # counter; JAX's own event (`compiles`) still counts.
+                **{f"model.{name}": getattr(self.model, name, 0) for name in (
+                    "prefill_tokens", "prefill_calls", "decode_calls",
+                    "jit_compiles")},
                 "compiles": self.compiles.count, **self.traced})
             return out
 
@@ -382,10 +358,10 @@ def run(cell: dict, *, seed: int, seconds: float, trace: bool, t0: float,
     _require(shapes["longest_context"] <= settings["max_seq_len"],
              f"the mix reaches context {shapes['longest_context']}, the "
              f"cell's max_seq_len is {settings['max_seq_len']}")
-    spec = {"widths": widths, "max_seq_len": settings["max_seq_len"],
-            "engine": settings["engine"], "seed": seed, "trace": trace,
-            "check_prompts": settings["check_prompts"],
-            "check_decode_steps": settings["check_decode_steps"]}
+    family = manifest.family_of(cell)
+    spec = {"family": cell["family"], "root": cell["root"],
+            "widths": widths, "settings": settings, "seed": seed,
+            "trace": trace}
     app = make_deployment(chips).bind(spec)
     try:
         handle = serve.run(app, route_prefix="/bench",
@@ -409,8 +385,8 @@ def run(cell: dict, *, seed: int, seconds: float, trace: bool, t0: float,
               f"{prepared['warm_prefill_s']:.1f} warm_decode_s="
               f"{prepared['warm_decode_s']:.1f} check_s="
               f"{prepared['check_s']:.1f} compiles_or_cache_fetches="
-              f"{prepared['compiles']} check={prepared['check']}",
-              flush=True)
+              f"{prepared['compiles']} held={prepared['held']} "
+              f"check={prepared['check']}", flush=True)
 
         streaming = handle.options(stream=True, method_name="generate")
 
@@ -506,9 +482,19 @@ def run(cell: dict, *, seed: int, seconds: float, trace: bool, t0: float,
         problems.append("no paged decode step ran")
     for problem in problems:
         print(f"NOT CORRECT: {problem}", flush=True)
+    check = prepared["check"]
+    checks = {
+        "logit_rms_gap": [check["max_error"], check["tolerance"]],
+        "largest_logit_gap": [check["largest_single_logit"],
+                              5 * check["tolerance"]],
+        "compiles_in_window": [counters["compiles"]
+                               + counters["model.jit_compiles"], 0],
+        "failed_requests": [sample["failed"], 0],
+        "host_gathers": [counters["cache.host_gathers"], 0]}
     values = dict(end_to_end(sample, [m["name"] for m in cell["end_to_end"]]),
                   setup_s=setup_s)
     ctx = {"cell": cell, "kind": kind, "widths": widths,
+           "counts": family.counts(widths, prepared["held"]),
            "peak": cell["peaks"].get(device["kind"]),
            "window_s": sample["window_s"], "counters": counters,
            "client": {key: sample[key] for key in (
@@ -517,4 +503,4 @@ def run(cell: dict, *, seed: int, seconds: float, trace: bool, t0: float,
            "trace_counters": traced.get("counters")}
     return {"correct": not problems, "attempted": sample["attempted"],
             "failed": sample["failed"], "values": values, "ctx": ctx,
-            "device": device}
+            "device": device, "checks": checks}

@@ -1,8 +1,9 @@
 """A training cell: `JaxTrainer.fit` -> one worker leasing the cell's
-chips -> `get_mesh()` -> `init_sharded` -> `iter_jax_batches` ->
-`make_train_step`, with `train.report` every step as Ray Train users
-write it. The loop below is the benchmark's own, built on
-`chip_smoke.py`'s; this (the harness's) process never imports JAX.
+chips -> `get_mesh()` -> the seeded sharded weights and the loss of the
+configuration's family (`benchmarks/families/<name>.py`) ->
+`iter_jax_batches` -> `make_train_step`, with `train.report` every step
+as Ray Train users write it. The loop below is the benchmark's own, built
+on `chip_smoke.py`'s; this (the harness's) process never imports JAX.
 """
 
 from __future__ import annotations
@@ -14,49 +15,33 @@ import threading
 import time
 import numpy as np
 
-from benchmarks.harness import flops, loadgen
+from benchmarks.harness import loadgen, manifest
 from benchmarks.harness import trace as trace_mod
 from benchmarks.harness.serve_cell import (CompileCounter, _require,
                                            device_facts, trace_directory)
-
-# Tolerance of the first step's loss (bf16 activations, float32
-# accumulation in the reductions, the flash kernel on one chip) against
-# the float32 reference's loss on the same batch and weights. The loss is
-# a mean over 16k-33k tokens, so bf16 rounding of single logits (2^-8
-# relative, on logits of order one) averages out: measured differences
-# are under 0.003 (PERF.md, Findings). At seeded random weights the loss
-# sits about 0.5 above ln(V); attention or the FFN gone wrong moves it by
-# more than 0.02.
-LOSS_TOLERANCE = 0.01
 
 
 def _train_loop(config: dict) -> None:
     """Runs in the worker that leased the chips."""
     import jax
-    import jax.numpy as jnp
     import optax
     from jax.sharding import NamedSharding, PartitionSpec
 
-    from benchmarks.harness import reference, trace
+    from benchmarks.harness import trace
     from ray_tpu import train
-    from ray_tpu.models.transformer import (TransformerConfig, init_params,
-                                            lm_loss, param_specs)
-    from ray_tpu.parallel.spmd import init_sharded, make_train_step
+    from ray_tpu.parallel.spmd import make_train_step
 
     compiles = CompileCounter()
     seconds, seq, batch = config["seconds"], config["seq"], config["batch"]
-    cfg = TransformerConfig(
-        **config["widths"], max_seq_len=seq, dtype=jnp.bfloat16,
-        remat=True, remat_policy=config["remat_policy"])
-    optimizer = optax.adamw(config["learning_rate"])
-    shape = tuple(config["mesh"]) if config["mesh"] else None
+    family = manifest.load_family(config["family"], config["root"])
+    trainer = config["trainer"]
+    optimizer = optax.adamw(trainer["learning_rate"])
+    shape = tuple(trainer["mesh"]) if trainer["mesh"] else None
     mesh = train.get_mesh(shape, devices=(
         jax.devices()[:int(np.prod(shape))] if shape else None))
-    # Weights on the devices, sharded, in one jitted call from the seed.
-    params = init_sharded(
-        lambda: init_params(
-            jax.random.PRNGKey(config["seed"] % (2 ** 31 - 1)), cfg),
-        param_specs(cfg), mesh)
+    built = family.build_training(config["widths"], trainer, seq,
+                                  config["seed"], mesh)
+    params = built["params"]
     # Adam's moments are placed like the parameters they belong to. Left to
     # itself `jit(optimizer.init)` hands back replicated zeros (nothing in
     # them depends on a sharded input): 9.4 GB a chip for olmo-1b, and the
@@ -68,8 +53,7 @@ def _train_loop(config: dict) -> None:
         jax.tree.map(lambda p: p.sharding, params),
         transform_non_params=lambda _: replicated)
     opt_state = jax.jit(optimizer.init, out_shardings=opt_shardings)(params)
-    step = make_train_step(lambda p, b: lm_loss(p, b, cfg, mesh=mesh),
-                           optimizer)
+    step = make_train_step(built["loss_fn"], optimizer)
     shard = train.get_dataset_shard("train")
 
     def epochs():
@@ -82,9 +66,8 @@ def _train_loop(config: dict) -> None:
     # Pallas kernels lower to this custom call; XLA attention leaves none.
     attention = ("pallas_flash" if "tpu_custom_call" in step.lower(
         params, opt_state, probe).as_text() else "xla")
-    reference_loss = reference.lm_loss(
-        params, probe["tokens"], n_heads=cfg.n_heads,
-        rope_theta=cfg.rope_theta)
+    reference_loss = family.reference_loss(config["widths"])(
+        params, probe["tokens"])
     warm_losses = []
     for i in range(config["warm_steps"]):     # one repeated probe batch
         params, opt_state, loss = step(params, opt_state, probe)
@@ -186,11 +169,10 @@ def run(cell: dict, *, seed: int, seconds: float, trace: bool, t0: float,
     trainer = JaxTrainer(
         _train_loop,
         train_loop_config={
+            "family": cell["family"], "root": cell["root"],
             "widths": widths, "seq": seq, "batch": batch, "seed": seed,
             "seconds": seconds, "warm_steps": traffic["warm_steps"],
-            "mesh": settings["trainer"]["mesh"],
-            "remat_policy": settings["trainer"]["remat_policy"],
-            "learning_rate": settings["trainer"]["learning_rate"],
+            "trainer": settings["trainer"],
             "trace_steps": settings["trace_steps"],
             "trace_dir": trace_dir},
         scaling_config=ScalingConfig(num_workers=1, use_tpu=chips > 0,
@@ -240,13 +222,15 @@ def run(cell: dict, *, seed: int, seconds: float, trace: bool, t0: float,
           f"{s['data_wait_s']:.3f} report_s={s['report_s']:.3f} "
           f"last_loss={s['last_loss']:.4f} compiles={s['compiles']}",
           flush=True)
+    family = manifest.family_of(cell)
+    tolerance = family.LOSS_TOLERANCE
     problems = []
     losses = s["warm_losses"]
-    if abs(losses[0] - s["reference_loss"]) > LOSS_TOLERANCE:
+    if not abs(losses[0] - s["reference_loss"]) <= tolerance:
         problems.append(
             f"first loss {losses[0]:.5f} against the reference's "
             f"{s['reference_loss']:.5f}: off by more than "
-            f"{LOSS_TOLERANCE}")
+            f"{tolerance}")
     if not (np.isfinite(losses + [s["last_loss"]]).all()
             and losses[-1] < losses[0]):
         problems.append(f"loss not finite and falling on the repeated "
@@ -275,8 +259,12 @@ def run(cell: dict, *, seed: int, seconds: float, trace: bool, t0: float,
                         "report_s": s["report_s"],
                         "compiles": s["compiles"]},
            "trace": reduced, "trace_counters": s["trace_counters"],
-           "flops_per_token": flops.train_flops_per_token(widths, seq)}
+           "counts": family.counts(widths)}
+    checks = {
+        "first_loss_gap": [abs(losses[0] - s["reference_loss"]), tolerance],
+        "loss_fall_on_probe": [losses[0] - losses[-1], 0],
+        "compiles_in_window": [s["compiles"], 0]}
     return {"correct": not problems, "attempted": s["steps"], "failed": 0,
             "values": {"train_tokens_per_s_per_chip": rate,
                        "setup_s": s["t_open_wall"] - t0},
-            "ctx": ctx, "device": device}
+            "ctx": ctx, "device": device, "checks": checks}

@@ -1,6 +1,7 @@
 """Kernels, serve: the least time one decode step could take over the
-time the device was busy in one. Bytes a step must read (every float32
-weight once, the live KV of its rows; `flops.decode_step_bytes`) over the
+time the device was busy in one. Bytes a step must read (every weight
+once, the live KV of its rows, each at the bytes a value the replica holds
+it in; the family's `ctx["counts"]["decode_step_bytes"]`) over the
 chip's HBM bandwidth, against the device-busy time inside the
 benchmark's `decode_step` spans, per span, in the traced window. Decode
 is bound by bytes; the FLOP side is taken too and the larger one used."""
@@ -18,7 +19,8 @@ def read(ctx):
         return None
     live = counters["decode_live_tokens"] / steps
     rows = counters["decode_rows"] / steps
+    counts = ctx["counts"]
     least = flops.roofline_seconds(
-        flops.decode_step_flops(ctx["widths"], rows, live),
-        flops.decode_step_bytes(ctx["widths"], live), peak)
+        counts["decode_step_flops"](rows, live),
+        counts["decode_step_bytes"](rows, live), peak)
     return 100.0 * least / (span["device_busy_s"] / span["count"])

@@ -32,12 +32,15 @@ if ROOT not in sys.path:
 
 def run_cell(workload: str, seed: int, seconds: float, trace: bool,
              t0: float, expect_platform: str = "tpu",
-             sweep_rates: tuple = ()) -> dict:
+             sweep_rates: tuple = (), root: str = ROOT) -> dict:
     """Run the cell on a cluster that is already up and return the
-    result object (the tests call this with `expect_platform="cpu"`)."""
+    result object (the tests call this with `expect_platform="cpu"`, and
+    with the `root` of a copy they have added files to). `checks`, its
+    last key, holds every number that decided `correct` beside its
+    limit."""
     from benchmarks.harness import manifest
 
-    cell = manifest.load_cell(workload)
+    cell = manifest.load_cell(workload, root)
     if cell["traffic"]["kind"] == "train":
         from benchmarks.harness import train_cell
 
@@ -63,16 +66,17 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             m["name"]: {"value": float(outcome["values"][m["name"]]),
                         "unit": m["unit"]}
             for m in cell["end_to_end"]}
-        return result
-    ctx = outcome["ctx"]
-    for name, value in outcome["values"].items():
-        print(f"traced run, not judged: {name}={value}", flush=True)
-    result["metrics"] = manifest.read_layer_metrics(cell, ctx)
-    reduced = ctx.get("trace")
-    if reduced:
-        device["busy_s"] = reduced["busy_s"]
-        device["window_s"] = reduced["window_s"]
-        result["breakdown"] = reduced["breakdown"]
+    else:
+        ctx = outcome["ctx"]
+        for name, value in outcome["values"].items():
+            print(f"traced run, not judged: {name}={value}", flush=True)
+        result["metrics"] = manifest.read_layer_metrics(cell, ctx)
+        reduced = ctx.get("trace")
+        if reduced:
+            device["busy_s"] = reduced["busy_s"]
+            device["window_s"] = reduced["window_s"]
+            result["breakdown"] = reduced["breakdown"]
+    result["checks"] = outcome["checks"]
     return result
 
 
@@ -130,6 +134,9 @@ def main(argv=None) -> int:
     finally:
         ray_tpu.shutdown()
     if not result.get("sweep"):
+        for name, (value, limit) in result["checks"].items():
+            print(f"compared: {name}={value} limit={limit}",
+                  file=sys.stderr, flush=True)
         print(json.dumps(result), flush=True)
     return 0
 

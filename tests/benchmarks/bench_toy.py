@@ -1,17 +1,24 @@
 """The benchmark's cells at toy widths, for the CPU tests: the same data
-files, with the sizes turned down."""
+files, with the sizes turned down. The cells come from the manifest and
+the toy widths from each cell's family, so a cell added later is run at
+toy size without an edit here."""
 
 import copy
 
 from benchmarks.harness import manifest
 
-WIDTHS = dict(vocab_size=512, d_model=64, n_layers=2, n_heads=4, d_ff=128,
-              rope_theta=10000.0)
+CELLS = [w["name"] for w in manifest.load_manifest()["workloads"]]
+
+
+def cells_of(*kinds: str) -> list:
+    """The manifest's cells whose traffic is of one of `kinds`."""
+    return [name for name in CELLS
+            if manifest.load_cell(name)["traffic"]["kind"] in kinds]
 
 
 def cell(name: str) -> dict:
     out = copy.deepcopy(manifest.load_cell(name))
-    out["widths"] = dict(WIDTHS)
+    out["widths"] = manifest.family_of(out).toy_widths(out["widths"])
     traffic, settings = out["traffic"], out["settings"]
     if traffic["kind"] == "train":
         traffic.update(seq_len=64, global_batch=8, dataset_steps=8)

@@ -30,7 +30,7 @@ def off_chip_script():
     benchmark says so, exits non-zero and prints no result."""
     proc = subprocess.Popen(
         [sys.executable, "benchmarks/run.py", "--workload",
-         "olmo-1b.serve.chat-steady", "--seed", "1", "--seconds", "1",
+         toy.CELLS[0], "--seed", "1", "--seconds", "1",
          "--trace", "0"], cwd=REPO, stdout=subprocess.PIPE,
         stderr=subprocess.PIPE, text=True)
     yield proc
@@ -39,9 +39,10 @@ def off_chip_script():
 
 
 @pytest.mark.cluster
+# Every second cell is run with the profiler on.
 @pytest.mark.parametrize("name,trace", [
-    ("olmo-1b.serve.chat-steady", False),
-    ("olmo-1b.serve.decode-heavy", True)])
+    (name, i % 2 == 1)
+    for i, name in enumerate(toy.cells_of("serve_open", "serve_closed"))])
 def test_serving_cells_at_toy_widths(cluster, off_chip_script, name, trace):
     cell = toy.cell(name)
     out = serve_cell.run(cell, seed=2 ** 31 + 7, seconds=2.0, trace=trace,
@@ -56,6 +57,8 @@ def test_serving_cells_at_toy_widths(cluster, off_chip_script, name, trace):
     assert ctx["counters"]["cache.host_gathers"] == 0
     for metric in cell["end_to_end"]:
         assert out["values"][metric["name"]] > 0, metric["name"]
+    assert out["checks"]["logit_rms_gap"][0] <= \
+        out["checks"]["logit_rms_gap"][1]
     layer = manifest.read_layer_metrics(cell, ctx)
     assert layer["decode_step_ms"]["value"] > 0
     assert layer["compiles_in_window.serve"]["value"] == 0
@@ -71,8 +74,7 @@ def test_serving_cells_at_toy_widths(cluster, off_chip_script, name, trace):
 
 @pytest.mark.cluster
 @pytest.mark.parametrize("name,trace", [
-    ("smollm2-1.7b.train.seq2k", True),
-    ("olmo-1b.train.fsdp4", False)])
+    (name, i % 2 == 0) for i, name in enumerate(toy.cells_of("train"))])
 def test_training_cells_at_toy_widths(cluster, off_chip_script, name, trace):
     cell = toy.cell(name)
     out = train_cell.run(cell, seed=2 ** 31 + 7, seconds=1.5, trace=trace,
@@ -85,6 +87,8 @@ def test_training_cells_at_toy_widths(cluster, off_chip_script, name, trace):
     ctx = out["ctx"]
     assert ctx["counters"]["compiles"] == 0
     assert ctx["counters"]["steps"] == out["attempted"] >= 3
+    assert out["checks"]["first_loss_gap"][0] <= \
+        out["checks"]["first_loss_gap"][1]
     layer = manifest.read_layer_metrics(cell, ctx)
     assert layer["compiles_in_window.train"]["value"] == 0
     assert 0 <= layer["data_wait_pct"]["value"] < 100
@@ -99,7 +103,8 @@ def test_off_the_chip_the_command_gives_no_result_line(off_chip_script):
     out, err = off_chip_script.communicate(timeout=120)
     assert off_chip_script.returncode != 0
     assert out == ""
-    assert "needs 1 TPU chip" in err
+    assert f"needs {manifest.load_cell(toy.CELLS[0])['chips']} TPU chip" \
+        in err
 
 
 def test_the_harness_touches_no_tpu_library_at_import_time():

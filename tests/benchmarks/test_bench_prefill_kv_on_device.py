@@ -24,11 +24,12 @@ def test_reader_on_recorded_counters(counters, expected):
 def test_entry_names_the_serve_cells_and_the_gap_tail():
     entry = {m["name"]: m for m in
              manifest.load_manifest()["per_layer"]}[NAME]
+    # A later PR appends its serving cells to the list.
+    assert set(SERVE_CELLS) <= set(entry.pop("workloads"))
     assert entry == {
         "name": NAME, "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "KV cache",
-        "moves": "serve_itl_p99_ms", "workloads": SERVE_CELLS}
-    assert manifest.load_manifest()["per_layer"][-1]["name"] == NAME
+        "moves": "serve_itl_p99_ms"}
     for name in SERVE_CELLS:
         cell = manifest.load_cell(name)
         assert NAME in [m["name"] for m in cell["per_layer"]]

@@ -129,7 +129,8 @@ def test_new_entries_name_their_cells_layers_and_sources():
     entries = {m["name"]: m for m in manifest.load_manifest()["per_layer"]}
     assert NEW_METRICS.keys() <= entries.keys()
     for name, cells in NEW_METRICS.items():
-        assert entries[name]["workloads"] == cells, name
+        # A later PR appends its cells; these come first.
+        assert entries[name]["workloads"][:len(cells)] == cells, name
         assert entries[name]["moves"] == (
             "serve_itl_p99_ms" if len(cells) == 2 else "serve_ttft_p95_ms")
         assert callable(_reader(name))
