@@ -3,8 +3,11 @@
 The reference has no sequence-parallel or long-context kernels anywhere
 (SURVEY.md §2.5 — ring attention/Ulysses absent, delegated to DeepSpeed user
 code); these are designed new for the ICI mesh. `paged_attention` (decode
-attention over the serving engine's paged KV pool, a Pallas kernel) is
-imported by the engine alone.
+attention over the serving engine's paged KV pool, a Pallas kernel),
+`delta_rule` (the gated delta rule: chunked for prefill, one step for
+decode) and `experts` (a dropless expert layer that holds a range of the
+routed experts) are imported by the engine's models alone; `moe` is the
+training model's capacity-drop dispatch.
 """
 
 from ray_tpu.ops.attention import attention, plain_attention, ring_attention

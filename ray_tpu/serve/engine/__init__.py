@@ -38,6 +38,7 @@ Typical replica:
 
 from ray_tpu.serve.engine.kv_cache import (CacheOverflowError,
                                            KVCacheManager)
+from ray_tpu.serve.engine.hybrid_model import HybridEngineModel
 from ray_tpu.serve.engine.model import TinyLM, TransformerEngineModel
 from ray_tpu.serve.engine.prefix_index import PrefixIndex
 from ray_tpu.serve.engine.scheduler import (EngineConfig,
@@ -47,6 +48,7 @@ from ray_tpu.serve.engine.scheduler import (EngineConfig,
 
 __all__ = [
     "CacheOverflowError", "EngineConfig", "EngineOverloadedError",
-    "EngineStoppedError", "InferenceEngine", "KVCacheManager",
+    "EngineStoppedError", "HybridEngineModel", "InferenceEngine",
+    "KVCacheManager",
     "PrefixIndex", "TinyLM", "TokenStream", "TransformerEngineModel",
 ]

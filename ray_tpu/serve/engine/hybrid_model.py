@@ -1,0 +1,506 @@
+"""The engine model of the hybrid sparse decoder (`models/hybrid_moe.py`):
+grouped-query attention over the paged KV pool in one layer of four,
+delta-rule linear attention over a per-sequence state in the other
+three, a sparse-expert layer with its held experts in every layer.
+
+It is driven through the engine's three calls (`model.py`), and declares
+what `TransformerEngineModel` does not: `state_shapes`, the state a
+sequence keeps beside its KV rows. The cache manager then holds a slot a
+sequence (`kv_cache.py`), a prefill's result carries the state it ended
+on, and `decode_paged` takes the state pool and the rows' slots beside
+the KV pool and hands both pools back.
+
+Arithmetic: weights and the KV pool in `cfg.dtype` (bf16 on the chip);
+the residual stream, norms, softmax, router scores, decay, beta, gates
+and the delta-rule state in float32; a matrix product takes both
+operands in `cfg.dtype` and accumulates in float32, the router's and the
+delta rule's own products excepted (float32 at the highest precision);
+logits float32.
+
+A decode step is one compiled program, as the dense model's: in, one
+int32 array ``[b_pad, 5 + nb_pad]`` (token, position, write block, write
+offset, state slot, block table); out, one int32 array ``[b_pad + 3]``:
+the greedy ids and the step's three expert counters. The delta-rule
+layers of a step run in slot order over the whole state pool (a row's
+input scattered to its slot, the layer's output gathered back): the
+pool is read and written where it lies, a slot no row of the step uses
+keeps its state bit for bit, and no copy of the batch's state is built.
+"""
+
+from __future__ import annotations
+
+from typing import Dict, List, Sequence
+
+import numpy as np
+
+from ray_tpu.core import flight
+from ray_tpu.serve.engine.model import (DecodeStep, PromptKV, _JitLRU,
+                                        _next_pow2)
+
+
+class PromptState(PromptKV):
+    """A prefill's KV rows with the state the prompt ended on: `state`,
+    a dict of device arrays a sequence (`state_shapes`), which
+    `KVCacheManager.write_range` stores in the sequence's slot."""
+
+    __slots__ = ("state",)
+
+    def __init__(self, padded, n: int, state: dict):
+        super().__init__(padded, n)
+        self.state = state
+
+
+class HybridEngineModel:
+    """Incremental decoding over `models/hybrid_moe.py` weights.
+
+    KV entry a token: ``[n_periods, 2, n_kv_heads, head_dim]`` (the GQA
+    layers alone keep KV). State a sequence: ``s`` ``[n_kda_layers, H,
+    dk, dv]`` float32, the delta rule's, and ``conv`` ``[n_kda_layers,
+    taps - 1, 3 H dk]``, the last inputs of the short convolutions.
+    Prefill runs the prompt once (chunked delta rule, `kda_chunk`
+    positions a chunk) in pow2 length buckets; a decode step is jitted a
+    (batch, table) bucket. A prompt is never prefilled from an offset:
+    the engine adopts no prefix over a model with state."""
+
+    def __init__(self, params, cfg, max_batch_size: int = 8,
+                 jit_cache_cap: int = 32, kda_chunk: int = 64):
+        import jax
+        import jax.numpy as jnp
+
+        from ray_tpu.models.hybrid_moe import KDA_PER_PERIOD
+        from ray_tpu.ops.paged_attention import kernel_eligible
+
+        self._params = params
+        self._cfg = cfg
+        self._chunk = kda_chunk
+        self.vocab_size = cfg.vocab_size
+        self.eos_token = 1
+        dtype = jnp.dtype(cfg.dtype)
+        self.kv_token_shape = (cfg.n_periods, 2, cfg.n_kv_heads,
+                               cfg.head_dim)
+        self.kv_dtype = dtype
+        dk = cfg.kda_head_dim
+        self.state_shapes = {
+            "s": ((cfg.n_kda_layers, cfg.kda_heads, dk, dk), jnp.float32),
+            "conv": ((cfg.n_kda_layers, cfg.conv_kernel - 1,
+                      3 * cfg.kda_width), dtype)}
+        self._kda_per_period = KDA_PER_PERIOD
+        self._prefill_jit = _JitLRU(jit_cache_cap)
+        self._decode_paged_jit = _JitLRU(jit_cache_cap)
+        self.prefill_calls = 0
+        self.prefill_tokens = 0
+        self.decode_calls = 0
+        self.jit_compiles = 0
+        # As `TransformerEngineModel`'s: what a decode step moves across
+        # the host boundary, and how it reads the KV pool.
+        self.decode_h2d_arrays = 0
+        self.decode_d2h_bytes = 0
+        self._attn_inplace = kernel_eligible(cfg.n_heads, cfg.head_dim,
+                                             cfg.n_kv_heads)
+        self.decode_attn_inplace_steps = 0
+        self.decode_kv_pages_read = 0
+        # The expert layers' counts over decode steps, summed over
+        # layers, computed inside the step and fetched with its ids:
+        # (token, expert) pairs on held experts; (layer, expert) pairs
+        # with at least one token; the largest load of a held expert.
+        self.moe_local_assignments = 0
+        self.moe_expert_touches = 0
+        self.moe_max_expert_load = 0
+        self.phase: Dict[str, float] = dict.fromkeys(
+            ("prefill_prep_s", "prefill_dispatch_s", "prefill_wait_s",
+             "prefill_kv_d2h_s", "decode_prep_s", "decode_dispatch_s",
+             "decode_wait_s"), 0.0)
+        self._jnp = jnp
+        self._tree_leaves = jax.tree_util.tree_leaves
+
+    @property
+    def kv_pool_ns(self):
+        return self._jnp
+
+    @property
+    def jit_cache_evictions(self) -> int:
+        return (self._prefill_jit.evictions
+                + self._decode_paged_jit.evictions)
+
+    # -- shared math ---------------------------------------------------
+    def _norm(self, x, scale):
+        import jax
+        import jax.numpy as jnp
+
+        var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+        return x * jax.lax.rsqrt(var + self._cfg.norm_eps) * scale
+
+    @staticmethod
+    def _mm(y, w):
+        """Both operands in the weights' dtype, float32 out."""
+        import jax.numpy as jnp
+
+        return jnp.dot(y.astype(w.dtype), w,
+                       preferred_element_type=jnp.float32)
+
+    def _over_periods(self, body, carry, xs):
+        """`lax.scan` of `body` over the periods; one period runs
+        inline, its weights sliced by a constant."""
+        import jax
+
+        if self._cfg.n_periods > 1:
+            return jax.lax.scan(body, carry, xs)
+        carry, ys = body(carry, jax.tree.map(lambda a: a[0], xs))
+        return carry, jax.tree.map(lambda a: a[None], ys)
+
+    def _kda_inputs(self, y, lp, window, live):
+        """The delta rule's q, k, v, g, beta for tokens `y` ``[T, d]``
+        from the convolution's `window` ``[taps, T, 3 H dk]`` (a token's
+        own projection last). `live` ``[T]``: a token that is padding
+        gets ``g = 0``, ``beta = 0`` and leaves the state alone."""
+        import jax
+        import jax.numpy as jnp
+
+        cfg, f32 = self._cfg, jnp.float32
+        t = y.shape[0]
+        h, dk = cfg.kda_heads, cfg.kda_head_dim
+        mixed = jax.nn.silu(jnp.sum(
+            window.astype(f32) * lp["conv"][:, None, :], axis=0))
+        q, k, v = (mixed[:, i * h * dk:(i + 1) * h * dk].reshape(t, h, dk)
+                   for i in range(3))
+
+        def l2norm(x):
+            return x * jax.lax.rsqrt(
+                jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+        q, k = l2norm(q) * dk ** -0.5, l2norm(k)
+        g = -jnp.exp(lp["a_log"])[None, :, None] * jax.nn.softplus(
+            self._mm(self._mm(y, lp["wf1"]), lp["wf2"])
+            + lp["dt_bias"]).reshape(t, h, dk)
+        beta = 2.0 * jax.nn.sigmoid(self._mm(y, lp["wb"]))
+        g = jnp.where(live[:, None, None], g, 0.0)
+        beta = jnp.where(live[:, None], beta, 0.0)
+        return q, k, v, g, beta
+
+    def _kda_output(self, y, o, lp):
+        """``W_o(rmsnorm_head(o) * sigmoid(W_g2 W_g1 y))``."""
+        import jax
+
+        t = y.shape[0]
+        o = self._norm(o, lp["onorm"]).reshape(t, -1)
+        gate = jax.nn.sigmoid(self._mm(self._mm(y, lp["wg1"]), lp["wg2"]))
+        return self._mm(o * gate, lp["wo"])
+
+    def _experts(self, x, ln2, mp, valid):
+        """The expert layer's residual add; returns the new `x` and the
+        layer's three counts."""
+        import jax
+        import jax.numpy as jnp
+
+        from ray_tpu.ops.experts import held_experts_ffn, route
+
+        cfg = self._cfg
+        y = self._norm(x, ln2)
+        with jax.named_scope("moe_route"):
+            experts, weights = route(y, mp["router"], mp["select_bias"],
+                                     cfg.top_k, cfg.routed_scaling)
+        with jax.named_scope("moe_experts"):
+            routed, load = held_experts_ffn(
+                y, experts, weights, mp["w_gate"], mp["w_up"],
+                mp["w_down"], cfg.experts_held, valid)
+            shared = self._mm(
+                jax.nn.silu(self._mm(y, mp["shared_gate"]))
+                * self._mm(y, mp["shared_up"]), mp["shared_down"])
+        counts = jnp.stack([jnp.sum(load), jnp.sum(load > 0),
+                            jnp.max(load)]).astype(jnp.int32)
+        return x + shared + routed, counts
+
+    # -- prefill -------------------------------------------------------
+    def _build_prefill(self, s_pad: int):
+        import jax
+        import jax.numpy as jnp
+
+        from ray_tpu.ops.delta_rule import delta_rule_chunked
+
+        self.jit_compiles += 1
+        cfg, f32 = self._cfg, jnp.float32
+        hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+        h, dk = cfg.kda_heads, cfg.kda_head_dim
+        taps = cfg.conv_kernel
+        chunk = min(self._chunk, s_pad)
+
+        def prefill(params, tokens, length):
+            act = params["embed"].dtype
+            with jax.named_scope("embed"):
+                x = params["embed"][tokens].astype(f32)        # [S, d]
+            pos = jnp.arange(s_pad)
+            live = pos < length
+            causal = (pos[:, None] >= pos[None, :]) & live[None, :]
+
+            def gqa(x, ln, lp):
+                y = self._norm(x, ln)
+                q = self._mm(y, lp["wq"]).reshape(s_pad, hkv, hq // hkv, hd)
+                k = self._mm(y, lp["wk"]).astype(act).reshape(
+                    s_pad, hkv, hd)
+                v = self._mm(y, lp["wv"]).astype(act).reshape(
+                    s_pad, hkv, hd)
+                scores = jnp.einsum("qkgd,skd->kgqs", q.astype(act), k,
+                                    preferred_element_type=f32) * hd ** -0.5
+                scores = jnp.where(causal[None, None], scores, -1e30)
+                probs = jax.nn.softmax(scores, axis=-1)
+                o = jnp.einsum("kgqs,skd->qkgd", probs.astype(act), v,
+                               preferred_element_type=f32)
+                gate = jax.nn.sigmoid(self._mm(y, lp["wgate"]))
+                out = self._mm(o.reshape(s_pad, hq * hd) * gate, lp["wo"])
+                return x + out, jnp.stack([k, v], axis=1)
+
+            def kda(x, ln, lp):
+                y = self._norm(x, ln)
+                pre = jnp.concatenate(
+                    [self._mm(y, lp[w]) for w in ("wq", "wk", "wv")],
+                    axis=-1).astype(act)                   # [S, 3 H dk]
+                padded = jnp.concatenate(
+                    [jnp.zeros((taps - 1, pre.shape[1]), act), pre])
+                window = jnp.stack([padded[j:j + s_pad]
+                                    for j in range(taps)])
+                q, k, v, g, beta = self._kda_inputs(y, lp, window, live)
+                o, s_end = delta_rule_chunked(
+                    q, k, v, g, beta, jnp.zeros((h, dk, dk), f32), chunk)
+                # The inputs of positions length-3 .. length-1: what the
+                # next token's convolution reads.
+                tail = jax.lax.dynamic_slice_in_dim(padded, length,
+                                                    taps - 1, axis=0)
+                return x + self._kda_output(y, o, lp), s_end, tail
+
+            def period(x, pp):
+                with jax.named_scope("gqa_attn"):
+                    x, kv = gqa(x, pp["ln1"][0], pp["gqa"])
+                x, _ = self._experts(x, pp["ln2"][0],
+                                     pp["moe"][0], live)
+                states, tails = [], []
+                for j in range(self._kda_per_period):
+                    with jax.named_scope("kda"):
+                        x, s_end, tail = kda(x, pp["ln1"][1 + j],
+                                             pp["kda"][j])
+                    x, _ = self._experts(x, pp["ln2"][1 + j],
+                                         pp["moe"][1 + j], live)
+                    states.append(s_end)
+                    tails.append(tail)
+                return x, (kv, jnp.stack(states), jnp.stack(tails))
+
+            stacked = {k: params[k] for k in
+                       ("ln1", "ln2", "gqa", "kda", "moe")}
+            x, (kv, states, tails) = self._over_periods(period, x, stacked)
+            with jax.named_scope("lm_head"):
+                last = self._norm(x[length - 1], params["ln_f"])
+                logits = self._mm(last[None], params["head"])[0]
+            state = {"s": states.reshape((-1,) + states.shape[2:]),
+                     "conv": tails.reshape((-1,) + tails.shape[2:])}
+            # kv [P, S, 2, Hkv, hd] -> [S, P, 2, Hkv, hd]
+            return logits, kv.transpose(1, 0, 2, 3, 4), state
+
+        return jax.jit(prefill)
+
+    # -- decode --------------------------------------------------------
+    def _build_decode_paged(self, b_pad: int, nb_pad: int,
+                            block_size: int):
+        import jax
+        import jax.numpy as jnp
+
+        from ray_tpu.ops.delta_rule import delta_rule_step
+        from ray_tpu.ops.paged_attention import paged_decode_attention
+
+        self.jit_compiles += 1
+        cfg, f32 = self._cfg, jnp.float32
+        hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+        per = self._kda_per_period
+
+        def decode_paged(pool, state, params, packed):
+            tokens, positions = packed[:, 0], packed[:, 1]
+            wblocks, woffs, slots = packed[:, 2], packed[:, 3], packed[:, 4]
+            tables = packed[:, 5:]
+            n_slots = state["s"].shape[0]
+            # A padding row names slot `n_slots`: its scatter drops, it
+            # routes to no expert, and what it gathers is thrown away.
+            valid = slots < n_slots
+            used = jnp.zeros((n_slots,), bool).at[slots].set(
+                True, mode="drop")
+            with jax.named_scope("embed"):
+                x = params["embed"][tokens].astype(f32)        # [B, d]
+
+            def gqa(x, ln, lp, layer):
+                y = self._norm(x, ln)
+                q = self._mm(y, lp["wq"]).reshape(b_pad, hq, hd)
+                k = self._mm(y, lp["wk"]).astype(pool.dtype).reshape(
+                    b_pad, hkv, hd)
+                v = self._mm(y, lp["wv"]).astype(pool.dtype).reshape(
+                    b_pad, hkv, hd)
+                with jax.named_scope("kv_gather"):
+                    o = paged_decode_attention(q, k, v, pool, tables,
+                                               positions, layer)
+                gate = jax.nn.sigmoid(self._mm(y, lp["wgate"]))
+                out = self._mm(o.reshape(b_pad, hq * hd) * gate, lp["wo"])
+                return x + out, jnp.stack([k, v], axis=1)
+
+            def kda(x, ln, lp, state, layer):
+                # Slot order: row i's input at slot slots[i].
+                y = jnp.zeros((n_slots, x.shape[1]), f32).at[slots].set(
+                    self._norm(x, ln), mode="drop")
+                tail = jax.lax.dynamic_index_in_dim(
+                    state["conv"], layer, axis=1, keepdims=False)
+                s = jax.lax.dynamic_index_in_dim(
+                    state["s"], layer, axis=1, keepdims=False)
+                pre = jnp.concatenate(
+                    [self._mm(y, lp[w]) for w in ("wq", "wk", "wv")],
+                    axis=-1).astype(tail.dtype)
+                window = jnp.concatenate([tail, pre[:, None]], axis=1)
+                q, k, v, g, beta = self._kda_inputs(
+                    y, lp, window.transpose(1, 0, 2), used)
+                o, s = delta_rule_step(s, q, k, v, g, beta)
+                new_tail = jnp.where(used[:, None, None], window[:, 1:],
+                                     tail)
+                state = {
+                    "s": jax.lax.dynamic_update_index_in_dim(
+                        state["s"], s, layer, axis=1),
+                    "conv": jax.lax.dynamic_update_index_in_dim(
+                        state["conv"], new_tail, layer, axis=1)}
+                out = self._kda_output(y, o, lp)
+                return x + out[jnp.minimum(slots, n_slots - 1)], state
+
+            def period(carry, xs):
+                x, state, counts = carry
+                pp, p = xs
+                with jax.named_scope("gqa_attn"):
+                    x, kv = gqa(x, pp["ln1"][0], pp["gqa"], p)
+                x, c = self._experts(x, pp["ln2"][0],
+                                     pp["moe"][0], valid)
+                counts += c
+                for j in range(per):
+                    with jax.named_scope("kda"):
+                        x, state = kda(x, pp["ln1"][1 + j],
+                                       pp["kda"][j], state,
+                                       p * per + j)
+                    x, c = self._experts(x, pp["ln2"][1 + j],
+                                         pp["moe"][1 + j], valid)
+                    counts += c
+                return (x, state, counts), kv
+
+            stacked = {k: params[k] for k in
+                       ("ln1", "ln2", "gqa", "kda", "moe")}
+            (x, state, counts), new_kv = self._over_periods(
+                period, (x, state, jnp.zeros((3,), jnp.int32)),
+                (stacked, jnp.arange(cfg.n_periods, dtype=jnp.int32)))
+            with jax.named_scope("lm_head"):
+                logits = self._mm(self._norm(x, params["ln_f"]),
+                                  params["head"])
+            with jax.named_scope("kv_write"):
+                # new_kv [P, B, 2, Hkv, hd] -> [B, P, 2, Hkv, hd]
+                new_pool = pool.at[wblocks, woffs].set(
+                    new_kv.transpose(1, 0, 2, 3, 4), mode="drop")
+            with jax.named_scope("sample"):
+                ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return jnp.concatenate([ids, counts]), logits, new_pool, state
+
+        return jax.jit(decode_paged, donate_argnums=(0, 1))
+
+    # -- engine interface ----------------------------------------------
+    def prefill(self, tokens: Sequence[int]):
+        """Run the prompt. Returns the host logits that predict the next
+        token and a `PromptState`: the prompt's KV rows and the state it
+        ended on, both still on the device."""
+        with flight.span("model", "prefill", len(tokens)):
+            return self._prefill(tokens)
+
+    def _prefill(self, tokens: Sequence[int]):
+        jnp, phase = self._jnp, self.phase
+        self.prefill_calls += 1
+        n = len(tokens)
+        self.prefill_tokens += n
+        with flight.span("model", "prefill.prep", None, phase,
+                         "prefill_prep_s"):
+            s_pad = _next_pow2(max(n, 8))
+            fn = self._prefill_jit.get(s_pad)
+            if fn is None:
+                fn = self._prefill_jit[s_pad] = self._build_prefill(s_pad)
+            padded = np.zeros((s_pad,), np.int32)
+            padded[:n] = np.asarray(tokens, np.int32)
+            args = (jnp.asarray(padded), jnp.int32(n))
+        with flight.span("model", "prefill.dispatch", None, phase,
+                         "prefill_dispatch_s"):
+            logits, kv, state = fn(self._params, *args)
+        with flight.span("model", "prefill.logits_wait", None, phase,
+                         "prefill_wait_s"):
+            logits = np.asarray(logits)
+        return logits, PromptState(kv, n, state)
+
+    def prefill_paged(self, tokens: Sequence[int], pool,
+                      block_table: Sequence[int], prefix_len: int,
+                      block_size: int):
+        """The engine adopts no prefix over a model with state, so the
+        offset is always 0 and this is `prefill`."""
+        if prefix_len:
+            raise ValueError(
+                "a prefix's KV blocks do not restore the recurrent state: "
+                "this model prefills a prompt whole")
+        return self.prefill(tokens)
+
+    def decode_paged(self, pool, block_tables: List[Sequence[int]],
+                     last_tokens: Sequence[int],
+                     positions: Sequence[int],
+                     write_blocks: Sequence[int],
+                     write_offs: Sequence[int], block_size: int,
+                     state=None, slots: Sequence[int] = ()):
+        """One fused step, as `TransformerEngineModel.decode_paged`,
+        over both pools: `state` is the cache's state pool and
+        `slots[i]` row i's slot (a list shorter than the batch leaves
+        the other rows without a slot: they read and write no state,
+        as in a warm-up). Returns ``(step, new_pool, new_state)``; both
+        pools were donated."""
+        with flight.span("model", "decode", len(last_tokens)):
+            return self._decode_paged(pool, block_tables, last_tokens,
+                                      positions, write_blocks, write_offs,
+                                      block_size, state, slots)
+
+    def _decode_paged(self, pool, block_tables, last_tokens, positions,
+                      write_blocks, write_offs, block_size: int, state,
+                      slots):
+        phase = self.phase
+        b = len(last_tokens)
+        self.decode_calls += 1
+        with flight.span("model", "decode.prep", None, phase,
+                         "decode_prep_s"):
+            b_pad = _next_pow2(max(b, 1))
+            pages = [int(p) // block_size + 1 for p in positions]
+            if self._attn_inplace:
+                self.decode_attn_inplace_steps += 1
+                self.decode_kv_pages_read += sum(pages)
+            nb_pad = _next_pow2(max(max(pages), 1))
+            key = (b_pad, nb_pad, block_size)
+            fn = self._decode_paged_jit.get(key)
+            if fn is None:
+                fn = self._decode_paged_jit[key] = \
+                    self._build_decode_paged(*key)
+            # One host buffer, a row a sequence; a write block past the
+            # pool and a slot past the state pool are dropped.
+            packed = np.zeros((b_pad, 5 + nb_pad), np.int32)
+            packed[:, 2] = int(pool.shape[0])
+            packed[:, 4] = int(state["s"].shape[0])
+            for i in range(b):
+                table = block_tables[i][:nb_pad]
+                packed[i, 0] = last_tokens[i]
+                packed[i, 1] = positions[i]
+                packed[i, 5:5 + len(table)] = table
+            k = min(len(write_blocks), b)
+            packed[:k, 2] = write_blocks[:k]
+            packed[:k, 3] = write_offs[:k]
+            packed[:min(len(slots), b), 4] = slots[:b]
+            args = (pool, state, self._params, packed)
+            self.decode_h2d_arrays += sum(
+                isinstance(leaf, np.ndarray)
+                for leaf in self._tree_leaves(args))
+        with flight.span("model", "decode.dispatch", None, phase,
+                         "decode_dispatch_s"):
+            out, logits, new_pool, new_state = fn(*args)
+        with flight.span("model", "decode.logits_wait", None, phase,
+                         "decode_wait_s"):
+            out = np.asarray(out)
+            self.decode_d2h_bytes += out.nbytes
+        self.moe_local_assignments += int(out[b_pad])
+        self.moe_expert_touches += int(out[b_pad + 1])
+        self.moe_max_expert_load += int(out[b_pad + 2])
+        return DecodeStep(out[:b], logits, self), new_pool, new_state

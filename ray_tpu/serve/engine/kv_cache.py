@@ -53,6 +53,19 @@ The manager owns two things:
   update's payload as it stands: the prompt KV never visits the host
   on its way into a device pool.
 
+**State slots** (beside the blocks, for a model that declares
+`state_shapes`): a recurrent or linear-attention layer keeps a state of
+fixed size a sequence, whatever its length, where an attention layer
+keeps a row a token. The manager then holds a second pool, one slot a
+sequence: `allocate` takes a slot with a sequence's first block, `free`
+returns it, `can_allocate` and `stats()` count both, `write_range`
+stores the state a prefill ended on (its payload's `state`) and
+`paged_step` resolves each row's slot with its write block and donates
+both pools to the model's one step. A slot cannot be shared or adopted:
+blocks of KV restore a prefix, a state does not (the engine builds no
+prefix index over such a model). A model that declares no state gets no
+second pool and the calls it has always had.
+
 Determinism contract (the scheduler's loop must never crash on OOM):
 `allocate` is atomic — it either extends the table (and privatizes the
 requested write range) or changes nothing and returns False; the
@@ -145,6 +158,25 @@ def _pool_ops(block_size: int,
         return ops
 
 
+_STATE_WRITER = None
+
+
+def _state_writer():
+    """The donated update that stores one sequence's state in its slot
+    (a dict of arrays into a dict of pools); one jitted function a
+    process, compiled per pool shapes."""
+    global _STATE_WRITER
+    if _STATE_WRITER is None:
+        import jax
+
+        _STATE_WRITER = jax.jit(
+            lambda pools, slot, state: {
+                name: pool.at[slot].set(state[name].astype(pool.dtype))
+                for name, pool in pools.items()},
+            donate_argnums=0)
+    return _STATE_WRITER
+
+
 class KVCacheManager:
     """Fixed-size refcounted blocks in one preallocated buffer +
     per-sequence block tables. Thread-safe (the engine loop and
@@ -152,9 +184,16 @@ class KVCacheManager:
 
     def __init__(self, num_blocks: int, block_size: int,
                  kv_shape: Tuple[int, ...] = (), dtype=np.float32,
-                 array_ns=None):
+                 array_ns=None, state_shapes: Optional[dict] = None,
+                 state_slots: int = 0):
+        """`state_shapes`: what the model keeps a sequence beside its KV
+        rows, ``{name: (shape, dtype)}``; with it, `state_slots` slots
+        of each, zeroed, in the pool's namespace."""
         if num_blocks <= 0 or block_size <= 0:
             raise ValueError("num_blocks and block_size must be positive")
+        if state_shapes and state_slots <= 0:
+            raise ValueError("a model with per-sequence state needs "
+                             "state_slots > 0")
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
         self.kv_shape = tuple(kv_shape)
@@ -194,6 +233,23 @@ class KVCacheManager:
         # evictable capacity as available instead of rejecting.
         self._reclaimer: Optional[Callable[[int], int]] = None
         self._evictable: Optional[Callable[[], int]] = None
+        # The second pool: `[state_slots, *shape]` a declared state, a
+        # slot a sequence (None: the model declared none). The two
+        # counters sum, over paged steps, the slots in use and the slots
+        # there are: their quotient is the pool's occupancy while it
+        # decodes.
+        self._state = None
+        self._set_state = None
+        self._slots: Dict[str, int] = {}
+        self._free_slots: List[int] = []
+        self.state_slot_steps_in_use = 0
+        self.state_slot_steps = 0
+        if state_shapes:
+            self._state = {
+                name: self._ns.zeros((state_slots,) + tuple(shape), dt)
+                for name, (shape, dt) in state_shapes.items()}
+            self._free_slots = list(range(state_slots - 1, -1, -1))
+            self._set_state = _state_writer() if self._device else None
 
     def set_reclaimer(self, reclaim: Optional[Callable[[int], int]],
                       evictable: Optional[Callable[[], int]] = None
@@ -256,12 +312,20 @@ class KVCacheManager:
         """Would `allocate(...)` succeed right now — counting blocks a
         reclaim could evict as available?"""
         with self._lock:
+            if self._lacks_slot(seq_id):
+                return False
             grow, cow = self._plan(seq_id, target_tokens, writable_from)
             shortfall = grow + cow - len(self._free)
         if shortfall <= 0:
             return True
         return (self._evictable is not None
                 and self._evictable() >= shortfall)
+
+    def _lacks_slot(self, seq_id: str) -> bool:
+        """A sequence that has no state slot yet, and none is free (no
+        reclaim helps: slots are never shared or indexed)."""
+        return (self._state is not None and seq_id not in self._slots
+                and not self._free_slots)
 
     def allocate(self, seq_id: str, target_tokens: int,
                  writable_from: Optional[int] = None) -> bool:
@@ -280,6 +344,8 @@ class KVCacheManager:
                 f"({self.num_blocks}x{self.block_size})")
         while True:
             with self._lock:
+                if self._lacks_slot(seq_id):
+                    return False
                 grow, cow = self._plan(seq_id, target_tokens,
                                        writable_from)
                 shortfall = grow + cow - len(self._free)
@@ -299,6 +365,8 @@ class KVCacheManager:
     def _commit(self, seq_id: str, target_tokens: int, grow: int,
                 writable_from: Optional[int]) -> None:
         table = self._tables.setdefault(seq_id, [])
+        if self._state is not None and seq_id not in self._slots:
+            self._slots[seq_id] = self._free_slots.pop()
         for _ in range(grow):
             b = self._free.pop()
             self._refs[b] = 1
@@ -318,6 +386,10 @@ class KVCacheManager:
         The adopted coverage is recorded as the sequence's written
         length, so `gather` serves it immediately."""
         with self._lock:
+            if self._state is not None:
+                raise ValueError(
+                    "blocks of KV do not restore a sequence's state: "
+                    "nothing is adopted beside a state pool")
             if self._tables.get(seq_id):
                 raise ValueError(
                     f"adopt requires an empty table for {seq_id!r}")
@@ -365,6 +437,9 @@ class KVCacheManager:
         with self._lock:
             table = self._tables.pop(seq_id, [])
             self._lens.pop(seq_id, None)
+            slot = self._slots.pop(seq_id, None)
+            if slot is not None:
+                self._free_slots.append(slot)
             freed = 0
             for b in reversed(table):
                 if self._release_locked(b):
@@ -502,6 +577,7 @@ class KVCacheManager:
         the device. `range_writes_device` / `range_writes_host` count
         the calls by whether the payload reached the pool that way."""
         n = len(values)
+        state = getattr(values, "state", None)
         with self._lock:
             if self._device and _device_rows(values) is not None:
                 self.range_writes_device += 1
@@ -532,6 +608,30 @@ class KVCacheManager:
                     pos += take
                 self._pool_scatter(blocks, offs, values, n)
             self._lens[seq_id] = max(self._lens.get(seq_id, 0), start + n)
+            if state is not None and self._state is not None:
+                self._write_state(self._slots[seq_id], state)
+
+    def _write_state(self, slot: int, state: dict) -> None:
+        """The state a prefill ended on, into its sequence's slot: one
+        donated update on the device, slice writes on the host."""
+        if self._set_state is None:
+            for name, value in state.items():
+                self._state[name][slot] = np.asarray(value)
+        else:
+            self._state = self._set_state(self._state, np.int32(slot),
+                                          state)
+            self.pool_updates += 1
+
+    def slot_of(self, seq_id: str) -> Optional[int]:
+        with self._lock:
+            return self._slots.get(seq_id)
+
+    def read_state(self, seq_id: str) -> dict:
+        """Host copy of a sequence's state (tests and tools)."""
+        with self._lock:
+            slot = self._slots[seq_id]
+            return {name: np.array(np.asarray(pool[slot]))
+                    for name, pool in self._state.items()}
 
     def with_pool(self, fn):
         """Run `fn(pool)` on the live device buffer under the cache
@@ -569,7 +669,12 @@ class KVCacheManager:
         the buffer and records the written lengths. One jit dispatch
         per decode step; the KV payload never exists outside the pool.
         All under the cache lock: readers can neither see the
-        pre-write pool after lens advance nor race the donation."""
+        pre-write pool after lens advance nor race the donation.
+
+        Beside a state pool the call is ``fn(pool, blocks, offs, state,
+        slots)`` with each entry's slot, and returns ``(result,
+        new_pool, new_state)``: both pools donated to the one step and
+        re-bound here."""
         with self._lock:
             blocks: List[int] = []
             offs: List[int] = []
@@ -577,7 +682,14 @@ class KVCacheManager:
                 blk, off = self._writable_block(seq_id, pos)
                 blocks.append(blk)
                 offs.append(off)
-            result, new_pool = fn(self._buffer, blocks, offs)
+            if self._state is None:
+                result, new_pool = fn(self._buffer, blocks, offs)
+            else:
+                slots = [self._slots[seq_id] for seq_id, _ in entries]
+                result, new_pool, self._state = fn(
+                    self._buffer, blocks, offs, self._state, slots)
+                self.state_slot_steps_in_use += len(self._slots)
+                self.state_slot_steps += self.state_slots
             self._buffer = new_pool
             if self._device:
                 self.pool_updates += 1
@@ -622,6 +734,18 @@ class KVCacheManager:
             n *= d
         return n * np.dtype(self._dtype).itemsize
 
+    @property
+    def state_slots(self) -> int:
+        return len(self._slots) + len(self._free_slots)
+
+    @property
+    def state_bytes(self) -> int:
+        """Size of the state pool in bytes (0 without one)."""
+        if self._state is None:
+            return 0
+        return sum(int(np.prod(pool.shape)) * np.dtype(pool.dtype).itemsize
+                   for pool in self._state.values())
+
     def stats(self) -> Dict[str, float]:
         with self._lock:
             used = self.num_blocks - len(self._free)
@@ -640,4 +764,7 @@ class KVCacheManager:
                 "pool_bytes": self.pool_bytes,
                 "host_gathers": self.host_gathers,
                 "pool_updates": self.pool_updates,
+                "state_slots": self.state_slots,
+                "state_slots_in_use": len(self._slots),
+                "state_bytes": self.state_bytes,
             }

@@ -30,7 +30,21 @@ pool row) and ``kv_pool_ns``, the array namespace the model's step reads
 its pool in (numpy or `jax.numpy`; absent means numpy). It is no option:
 each model class has one value and the engine builds its pool from it.
 
-Two implementations:
+A model may declare a fourth, ``state_shapes``: what it keeps a SEQUENCE
+beside its KV rows, ``{name: (shape, dtype)}`` (a recurrent or
+linear-attention layer's state, a convolution's tail). The cache manager
+then holds a slot a sequence beside the blocks and the contract grows by
+two things, for that model alone: the KV result of ``prefill`` carries
+``state``, the arrays the prompt ended on (`hybrid_model.PromptState`),
+which `write_range` stores in the sequence's slot; and ``decode_paged``
+takes two more arguments, ``state`` (the pools, ``[slots, *shape]`` a
+name) and ``slots`` (row i's slot), and returns ``(step, new_pool,
+new_state)``. Blocks of KV do not restore such a sequence's prefix, so
+the engine builds no prefix index over a model that declares state,
+never calls its ``prefill_paged`` with an offset, and recomputes a
+preempted row by ``prefill``, as for any model.
+
+Three implementations:
 
 - **TinyLM** — a deterministic pure-numpy model whose next token is a
   fixed function of the *cached* KV contents, so every block-table bug
@@ -43,6 +57,12 @@ Two implementations:
   per (batch, seq) *bucket*: inputs are padded up to power-of-two
   bucket sizes so the number of distinct compiled shapes stays
   O(log max_batch * log max_seq) instead of one per request mix.
+- **HybridEngineModel** (`hybrid_model.py`) — the hybrid sparse decoder
+  of `models/hybrid_moe.py`: grouped-query attention over the pool in
+  one layer of four, delta-rule linear attention over a declared
+  per-sequence state in the others, a dropless expert layer that holds a
+  range of the routed experts; the same buckets, packed upload and
+  sampled-ids return.
 """
 
 from __future__ import annotations
@@ -304,8 +324,11 @@ class TransformerEngineModel:
     copy of the batch's cache is ever built. Both are jit-compiled per
     shape bucket: sequence lengths pad to the next power of two (>=
     block multiple), batches pad with masked dummy rows, so compiles
-    are bounded by the bucket count, not the request mix. MoE configs
-    are rejected (dense engine path only).
+    are bounded by the bucket count, not the request mix. It declares
+    no per-sequence state: KV rows are all a sequence keeps, and the
+    engine shares prefixes over it. The training model's capacity-drop
+    MoE (`cfg.is_moe`, `ops/moe.py`) is refused here; sparse experts are
+    served by `HybridEngineModel` through `ops/experts.py`.
     """
 
     def __init__(self, params, cfg, max_batch_size: int = 8,

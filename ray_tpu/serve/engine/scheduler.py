@@ -40,6 +40,14 @@ blocks. Preemption frees only a sequence's private tail (shared blocks
 survive and stay indexed), and cold prefixes are LRU-evicted under
 block pressure instead of admissions being rejected.
 
+A model with per-sequence state (`state_shapes`: a recurrent or
+linear-attention layer's state) gets a slot a sequence in the cache
+beside its blocks, sized here from `max_batch_size`, and no prefix
+sharing: blocks of KV do not restore such a sequence's prefix, so no
+index is built, every prompt is prefilled whole, and a preempted row's
+slot is freed and its state recomputed by prefill. Decided from what the
+model declares; no option.
+
 A prefill hands its KV over in two calls, with or without a prefix hit:
 the model's prefill returns the logits and the KV (`len()` rows), and
 `cache.write_range` stores them. Where the model computed them on the
@@ -292,13 +300,23 @@ class InferenceEngine:
                 f"{type(model).__name__} lacks {', '.join(missing)}: the "
                 f"engine drives a model through prefill, decode_paged and "
                 f"prefill_paged (serve/engine/model.py)")
+        # A model may declare a state it keeps a sequence beside its KV
+        # rows (`state_shapes`): the cache then holds a slot a sequence,
+        # one for each row of the batch.
+        state_shapes = getattr(model, "state_shapes", None)
         self.cache = KVCacheManager(
             self.config.num_blocks, self.config.block_size,
             kv_shape=tuple(getattr(model, "kv_token_shape", ())),
             dtype=getattr(model, "kv_dtype", np.float32),
-            array_ns=getattr(model, "kv_pool_ns", None))
+            array_ns=getattr(model, "kv_pool_ns", None),
+            state_shapes=state_shapes,
+            state_slots=self.config.max_batch_size if state_shapes else 0)
         self.prefix_index: Optional[PrefixIndex] = None
-        if self.config.prefix_sharing:
+        # Adopting blocks of KV restores a prefix only where KV is all a
+        # sequence keeps: over a model with per-sequence state no index
+        # is built, nothing is adopted, exported or imported, and every
+        # prompt is prefilled whole.
+        if self.config.prefix_sharing and not state_shapes:
             self.prefix_index = PrefixIndex(self.cache,
                                             self.config.block_size)
             self.cache.set_reclaimer(self.prefix_index.evict,
@@ -745,11 +763,13 @@ class InferenceEngine:
             entries = [(s.seq_id, poss[i]) for i, s in enumerate(batch)]
         with flight.span("engine", "model_step", b, clocks,
                          "model_step_s"):
+            # `state`: the state pool and the rows' slots, where the
+            # model keeps a state a sequence; nothing otherwise.
             logits = self.cache.paged_step(
                 entries,
-                lambda pool, blocks, offs: self.model.decode_paged(
+                lambda pool, blocks, offs, *state: self.model.decode_paged(
                     pool, tables, lasts, poss, blocks, offs,
-                    self.config.block_size))
+                    self.config.block_size, *state))
         self.paged_steps += 1
         with flight.span("engine", "sample", b, clocks, "sample_s"):
             toks = self._greedy(logits)
@@ -932,7 +952,18 @@ class InferenceEngine:
         at other head sizes, or for `TinyLM`), `decode_kv_pages_read`
         the live pages the block tables of those steps named
         (`position // block_size + 1` a row): what a layer of such a
-        step reads of the pool."""
+        step reads of the pool.
+        `moe_local_assignments`, `moe_expert_touches` and
+        `moe_max_expert_load` are a sparse-expert model's own counts
+        over its paged decode steps, summed over layers: (token, expert)
+        pairs that fell on experts held here, (layer, expert) pairs with
+        at least one token, and the largest count any held expert took
+        in a layer; computed inside the step and fetched with its
+        sampled ids; 0 for a model without experts.
+        `state_slot_steps_in_use` and `state_slot_steps` sum, over paged
+        steps, the state slots in use and the slots there are (a model
+        with per-sequence state; 0 otherwise); `cache` has the gauges
+        `state_slots`, `state_slots_in_use` and `state_bytes`."""
         with self._lock:
             running = len(self._running)
             waiting = len(self._waiting)
@@ -965,6 +996,14 @@ class InferenceEngine:
                 self.model, "decode_attn_inplace_steps", 0),
             "decode_kv_pages_read": getattr(
                 self.model, "decode_kv_pages_read", 0),
+            "moe_local_assignments": getattr(
+                self.model, "moe_local_assignments", 0),
+            "moe_expert_touches": getattr(
+                self.model, "moe_expert_touches", 0),
+            "moe_max_expert_load": getattr(
+                self.model, "moe_max_expert_load", 0),
+            "state_slot_steps_in_use": self.cache.state_slot_steps_in_use,
+            "state_slot_steps": self.cache.state_slot_steps,
             "jit_bucket_evictions": getattr(
                 self.model, "jit_cache_evictions", 0),
             "prefill_s": round(self.prefill_s, 6),

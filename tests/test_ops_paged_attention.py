@@ -198,7 +198,8 @@ def test_engine_step_through_the_kernel_matches_the_xla_step(monkeypatch):
     assert (plain["decode_attn_inplace_steps"],
             plain["decode_kv_pages_read"]) == (0, 0)
 
-    monkeypatch.setattr(pa, "kernel_eligible", lambda h, hd: hd % 128 == 0)
+    monkeypatch.setattr(pa, "kernel_eligible",
+                        lambda h, hd, hkv=None: hd % 128 == 0)
     monkeypatch.setattr(pa, "paged_decode_attention_kernel", partial(
         pa.paged_decode_attention_kernel, interpret=True))
     got, stats = _generate(_tiny_model(), prompts, 6)
@@ -280,7 +281,7 @@ def test_decode_step_compiles_for_v5e_without_a_copy_of_the_pool(
     from ray_tpu.ops import paged_attention as pa
     from ray_tpu.serve.engine import TransformerEngineModel
 
-    monkeypatch.setattr(pa, "kernel_eligible", lambda h, hd: True)
+    monkeypatch.setattr(pa, "kernel_eligible", lambda h, hd, hkv=None: True)
     cfg = TransformerConfig(**OLMO, max_seq_len=1024)
     params = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
@@ -299,3 +300,146 @@ def test_decode_step_compiles_for_v5e_without_a_copy_of_the_pool(
     for dense in ("f32[512,16,16,2,16,128]", "f32[16,8,1024,2,16,128]",
                   "f32[8,1024,16,2,16,128]"):
         assert dense not in text
+
+
+# ---------------------------------------------------------------------------
+# grouped heads over a bf16 pool: 64 query heads on 8 key/value heads
+# ---------------------------------------------------------------------------
+Q_HEADS = 64
+
+
+def _grouped_inputs(name, pool_dtype):
+    """The layouts above with `Q_HEADS` query heads over the pool's
+    `HEADS` key/value heads, the pool rounded to `pool_dtype`."""
+    import jax.numpy as jnp
+
+    tables, positions, pool = _case(name)
+    rng = np.random.default_rng(len(name) + 100)
+    b = len(positions)
+    q = rng.normal(size=(b, Q_HEADS, HD)).astype(np.float32)
+    k_new, v_new = (np.asarray(jnp.asarray(
+        rng.normal(size=(b, HEADS, HD)), pool_dtype).astype(jnp.float32))
+        for _ in range(2))
+    pool = jnp.asarray(np.clip(pool, -STALE, STALE), pool_dtype)
+    return q, k_new, v_new, pool, tables, positions
+
+
+def _grouped_reference(q, k_new, v_new, pool, tables, positions, layer):
+    """Query head i over key head i // group: the ungrouped reference on
+    the pool with each key/value head repeated `group` times."""
+    group = q.shape[1] // k_new.shape[1]
+    return _dense_reference(
+        q, np.repeat(k_new, group, axis=1), np.repeat(v_new, group, axis=1),
+        np.repeat(np.asarray(pool.astype("float32")), group, axis=4),
+        tables, positions, layer)
+
+
+@pytest.mark.parametrize("pool_dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("name", ["one_token_row", "rows_shorter_than_table",
+                                  "padded_batch_rows", "shuffled_tables",
+                                  "stale_data_past_position"])
+def test_grouped_heads_kernel_matches_its_xla_twin(name, pool_dtype):
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import paged_attention as pa
+
+    args = _grouped_inputs(name, pool_dtype)
+    for layer in (0, LAYERS - 1):
+        want = _grouped_reference(*args, layer)
+        dev = [jnp.asarray(a) for a in args]
+        xla = np.asarray(pa.paged_decode_attention_xla(*dev, layer))
+        kernel = np.asarray(pa.paged_decode_attention_kernel(
+            *dev, jnp.int32(layer), interpret=True))
+        assert kernel.shape == (len(args[-1]), Q_HEADS, HD)
+        assert np.isfinite(kernel).all()
+        np.testing.assert_allclose(kernel, xla, atol=2e-5, rtol=2e-5)
+        np.testing.assert_allclose(xla, want, atol=2e-5, rtol=2e-5)
+
+
+def test_grouped_eligibility_follows_the_pool_rows_heads(monkeypatch):
+    """With grouped heads the pool's rows are `n_kv_heads` wide: those
+    decide, from backend and widths alone."""
+    import jax
+
+    from ray_tpu.ops import paged_attention as pa
+
+    assert not pa.kernel_eligible(64, 128, 8)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert pa.kernel_eligible(64, 128, 8)
+    assert pa.kernel_eligible(16, 128) and pa.kernel_eligible(16, 128, 16)
+    assert not pa.kernel_eligible(64, 128, 4)      # 4 rows: half a tile
+    assert not pa.kernel_eligible(64, 64, 8)
+    assert not pa.kernel_eligible(12, 128, 8)      # no whole groups
+
+
+HYBRID_POOL = (4096, 16, 1, 2, 8, 128)      # the hybrid cell's pool, bf16
+
+
+@pytest.mark.parametrize("b_pad,nb_pad", [(1, 4), (32, 64)])
+def test_grouped_kernel_compiles_for_v5e_over_the_bf16_pool(
+        one_chip, no_compile_cache, b_pad, nb_pad):
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import paged_attention as pa
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(pa.paged_decode_attention_kernel).lower(
+        spec((b_pad, Q_HEADS, 128)), spec((b_pad, 8, 128), jnp.bfloat16),
+        spec((b_pad, 8, 128), jnp.bfloat16),
+        spec(HYBRID_POOL, jnp.bfloat16), spec((b_pad, nb_pad), jnp.int32),
+        spec((b_pad,), jnp.int32), spec((), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_hybrid_decode_step_compiles_for_v5e_with_both_pools_in_place(
+        one_chip, no_compile_cache, monkeypatch):
+    """The whole (32, 64) decode step of the hybrid model at its
+    published widths, the kernel steered on: the KV pool and the state
+    pool are both aliased to the outputs, and beside its arguments the
+    program holds tens of megabytes: no copy of a pool, of the batch's
+    state or of a layer's expert matrices."""
+    import json
+    import os
+
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks.harness import manifest
+    from ray_tpu.models.hybrid_moe import init_params
+    from ray_tpu.ops import paged_attention as pa
+    from ray_tpu.serve.engine import HybridEngineModel
+
+    monkeypatch.setattr(pa, "kernel_eligible", lambda *heads: True)
+    family = manifest.load_family("solar_open2")
+    with open(os.path.join(manifest.ROOT, "benchmarks", "configs",
+                           "solar-open2-250b.json")) as f:
+        cfg = family.model_config(family.widths(json.load(f)))
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(on_chip, jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    weight_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                       for a in jax.tree_util.tree_leaves(params))
+    assert 6.6e9 < weight_bytes < 6.65e9
+    model = HybridEngineModel(params, cfg, max_batch_size=32)
+    assert (4096, 16) + model.kv_token_shape == HYBRID_POOL
+    state = {name: jax.ShapeDtypeStruct((33,) + tuple(shape), dtype,
+                                        sharding=one_chip)
+             for name, (shape, dtype) in model.state_shapes.items()}
+    compiled = model._build_decode_paged(32, 64, 16).lower(
+        jax.ShapeDtypeStruct(HYBRID_POOL, jnp.bfloat16, sharding=one_chip),
+        state, params, jax.ShapeDtypeStruct((32, 5 + 64), jnp.int32,
+                                            sharding=one_chip)).compile()
+    memory = compiled.memory_analysis()
+    assert "tpu_custom_call" in compiled.as_text()
+    pools = int(np.prod(HYBRID_POOL)) * 2 + 33 * (
+        3 * 64 * 128 * 128 * 4 + 3 * 3 * 3 * 8192 * 2)
+    # Both pools, at the chip's layout (the tails' rows pad a little).
+    assert pools <= memory.alias_size_in_bytes < 1.01 * pools
+    assert memory.temp_size_in_bytes < 200e6
