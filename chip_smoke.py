@@ -102,7 +102,7 @@ def _train_loop(config: dict) -> None:
     from ray_tpu import train
     from ray_tpu.models.transformer import (TransformerConfig, init_params,
                                             lm_loss, param_specs)
-    from ray_tpu.ops.attention import flash_block
+    from ray_tpu.ops.attention import flash_tiles
     from ray_tpu.parallel.spmd import init_sharded, make_train_step
 
     t_mesh = time.perf_counter()
@@ -156,7 +156,7 @@ def _train_loop(config: dict) -> None:
             "device": device, "mesh": dict(mesh.shape), "losses": losses,
             "param_leaf_device_counts": placement,
             "attention": attention,
-            "flash_block": (flash_block(config["seq"])
+            "flash_tiles": (repr(flash_tiles(config["seq"], cfg.head_dim))
                             if attention == "pallas_flash" else None),
             "first_step_s": round(first_step_s, 2),
             "warm_step_s": round(statistics.median(step_s[1:]), 4),
@@ -224,7 +224,7 @@ def train_phase(widths: dict, *, expect_platform: str, chips: int,
         print(f"train: platform={dev['platform']} "
               f"device_kind={dev['kind']!r} devices={dev['count']} "
               f"mesh={s['mesh']} attention={s['attention']} "
-              f"flash_block={s['flash_block']} "
+              f"flash_tiles={s['flash_tiles']} "
               f"losses={[round(x, 4) for x in losses]} "
               f"first_step_s={s['first_step_s']} "
               f"warm_step_s={s['warm_step_s']} compiles={s['compiles']} "

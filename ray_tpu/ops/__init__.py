@@ -2,7 +2,9 @@
 
 The reference has no sequence-parallel or long-context kernels anywhere
 (SURVEY.md §2.5 — ring attention/Ulysses absent, delegated to DeepSpeed user
-code); these are designed new for the ICI mesh. `paged_attention` (decode
+code); these are designed new for the ICI mesh. `flash_attention` holds
+the one-chip train step's causal flash kernels (Pallas; `attention()`
+picks them and their tiles). `paged_attention` (decode
 attention over the serving engine's paged KV pool, a Pallas kernel),
 `delta_rule` (the gated delta rule: chunked for prefill, one step for
 decode) and `experts` (a dropless expert layer that holds a range of the

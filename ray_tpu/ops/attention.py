@@ -1,4 +1,6 @@
-"""Attention: plain (XLA-fused) and ring (sequence-parallel over ICI).
+"""Attention: plain (XLA-fused), flash (one chip: the Pallas kernels of
+`ops/flash_attention.py`, their tiles chosen here from the call's
+``(seq_len, head_dim)``) and ring (sequence-parallel over ICI).
 
 Ring attention (SURVEY.md §2.5 / §5 — absent from the reference, built new):
 each ``sp`` rank holds one sequence block of Q/K/V; K/V blocks rotate around
@@ -22,39 +24,72 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ray_tpu.ops.flash_attention import FlashTiles, flash_mha
+
 _NEG_INF = -1e30
 
 
-def flash_block(seq_len: int, block: int = 1024) -> int:
-    """Largest lane-aligned block that divides the sequence (the kernel
-    requires seq_len % block == 0). 1024 measured fastest on v5e at
-    S=1024/hd=128 (fwd+bwd 10.26 ms vs 10.51 at 512, 13.12 for XLA
-    attention; .scratch sweep, round 5)."""
-    return next(b for b in (block, 512, 384, 256, 128)
-                if b <= seq_len and seq_len % b == 0)
+# Tiles of the three flash kernels by (seq_len, head_dim): what a sweep on
+# the chip found fastest for that shape, each kernel timed alone over 24
+# (15 at S = 1024) settings of its row tile, major and minor; bf16 on a
+# TPU v5e, milliseconds a call (PERF.md, Findings, PR 34).
+_SWEPT_TILES = {
+    # [8, 32, 2048, 64]: forward 3.91 (4.00 with the log-sum-exp saved),
+    # dK/dV 6.38, dQ 5.10; whole forward + backward 13.20 against 21.60 for
+    # the kernels shipped with JAX at tiles of 1024. PR 34.
+    (2048, 64): FlashTiles(
+        block_q=512, block_k_major=2048, block_k=512,
+        block_k_dkv=512, block_q_major_dkv=1024, block_q_dkv=512,
+        block_q_dq=512, block_k_major_dq=2048, block_k_dq=512),
+    # [8, 16, 1024, 128]: forward 0.46 (0.46), dK/dV 0.75, dQ 0.61; whole
+    # 1.78 against 3.33. One forward tile beats skipping a quarter of it:
+    # the online softmax's upkeep a minor costs more there. PR 34.
+    (1024, 128): FlashTiles(
+        block_q=1024, block_k_major=1024, block_k=1024,
+        block_k_dkv=512, block_q_major_dkv=1024, block_q_dkv=512,
+        block_q_dq=1024, block_k_major_dq=1024, block_k_dq=512),
+}
 
 
-def flash_attention_tpu(q, k, v, *, causal: bool = True,
-                        block: int = 1024):
-    """Fused flash attention on TPU via the Pallas MHA kernel shipped with
-    JAX (jax.experimental.pallas.ops.tpu.flash_attention) — O(S) memory, no
-    materialized [B,H,S,S] score matrix, differentiable (custom VJP).
+def flash_tiles(seq_len: int, head_dim: int) -> FlashTiles:
+    """The tiles `flash_attention_tpu` runs the kernels with: the swept
+    entry for a shape somebody measured, else `_tiles_by_rule`."""
+    return (_SWEPT_TILES.get((seq_len, head_dim))
+            or _tiles_by_rule(seq_len))
 
-    q/k/v: [B, S, H, D] (we transpose to the kernel's [B, H, S, D]).
+
+def _tiles_by_rule(seq_len: int) -> FlashTiles:
+    """For a shape nobody swept, what the sweeps agree on at either head
+    size. Row tiles and minors of 512: the diagonal then leaves 10 of 16
+    tile pairs at S = 2048 (tiles of 1024 leave 3 of 4), while minors of
+    256 lose more to the statistics' upkeep and the matrix unit's fill
+    than the 36 of 64 pairs save. Majors of up to 2048: a grid step costs
+    about 0.35 us and re-reads its row tile's neighbours, a minor inside
+    one costs nothing, and 2048 rows of two operands fit VMEM beside the
+    score tiles. Every size is a multiple of 128 that divides `seq_len`
+    (the largest such under the target), a minor divides its major."""
+    def largest(at_most, unit):
+        return max(b for b in range(unit, min(at_most, seq_len) + 1, unit)
+                   if seq_len % b == 0)
+
+    block = largest(512, 128)
+    major = largest(2048, block)
+    return FlashTiles(
+        block_q=block, block_k_major=major, block_k=block,
+        block_k_dkv=block, block_q_major_dkv=major, block_q_dkv=block,
+        block_q_dq=block, block_k_major_dq=major, block_k_dq=block)
+
+
+def flash_attention_tpu(q, k, v):
+    """Causal flash attention on the TPU through the tree's Pallas kernels
+    (`ops/flash_attention.py`): O(S) memory, no materialized [B,H,S,S]
+    score matrix, differentiable (custom VJP).
+
+    q/k/v: [B, S, H, D] (we transpose to the kernels' [B, H, S, D]).
     """
-    from jax.experimental.pallas.ops.tpu.flash_attention import (
-        BlockSizes, flash_attention)
-
-    blk = flash_block(q.shape[1], block)
-    sizes = BlockSizes(
-        block_q=blk, block_k_major=blk, block_k=blk, block_b=1,
-        block_q_major_dkv=blk, block_k_major_dkv=blk, block_k_dkv=blk,
-        block_q_dkv=blk, block_k_major_dq=blk, block_k_dq=blk,
-        block_q_dq=blk)
+    tiles = flash_tiles(q.shape[1], q.shape[3])
     qt, kt, vt = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
-    o = flash_attention(qt, kt, vt, causal=causal,
-                        sm_scale=q.shape[-1] ** -0.5, block_sizes=sizes)
-    return o.transpose(0, 2, 1, 3)
+    return flash_mha(qt, kt, vt, tiles).transpose(0, 2, 1, 3)
 
 
 def _flash_eligible(q) -> bool:
@@ -165,5 +200,5 @@ def attention(q, k, v, *, causal: bool = True, mesh=None,
     unsharded = mesh is None or all(
         mesh.shape[a] == 1 for a in mesh.axis_names)
     if positions is None and causal and unsharded and _flash_eligible(q):
-        return flash_attention_tpu(q, k, v, causal=True)
+        return flash_attention_tpu(q, k, v)
     return plain_attention(q, k, v, causal=causal, positions=positions)

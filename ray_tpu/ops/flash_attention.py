@@ -1,0 +1,371 @@
+"""Causal flash attention on the TPU: three Pallas kernels and their VJP.
+
+The bodies of `jax.experimental.pallas.ops.tpu.flash_attention` (online
+softmax forward, a dK/dV kernel, a dQ kernel; bf16 operands, float32
+scores, statistics and accumulators) cut to what `attention()` sends here
+(causal, equal query and key lengths, no bias, no segments), with what
+the shipped bodies leave on the table at the causal diagonal:
+
+- A grid step holds a *major* tile and walks it in *minor* tiles. A minor
+  tile that lies wholly above the diagonal is not computed (the shipped
+  kernels skip whole major tiles only, and compute then mask every minor
+  inside one that runs), and the mask (two iotas, a compare, a select) is
+  built only on minor tiles the diagonal crosses.
+- The forward hands the backward one row of log-sum-exp a query,
+  ``[B, H, 1, S]`` float32, instead of ``l`` and ``m`` broadcast over 128
+  lanes (``[B, H, S, 128]`` float32 each, written by the forward and read
+  by both backward kernels: 268 MB apiece at ``[8, 32, 2048]``).
+- The dK/dV kernel works on transposed scores ``[k, q]``, so the row
+  statistics broadcast along sublanes and ``p^T @ dO`` and ``dS^T @ Q``
+  need no transpose of a score tile.
+
+Tiles are chosen by `ops.attention.flash_tiles` from ``(seq_len,
+head_dim)``; `ops.attention.plain_attention` is the plain form the tests
+hold the kernels to.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+
+import jax
+import jax.numpy as jnp
+
+# Pallas is imported where it is used: `ops.attention` imports this module
+# in every process, and the import costs a worker that never reaches the
+# chip's path about a second.
+_LANES = 128
+_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+# Every grid is (batch, head, the kernel's row tile, the major it walks
+# and accumulates over).
+_GRID_SEMANTICS = ("parallel", "parallel", "parallel", "arbitrary")
+
+
+@dataclasses.dataclass(frozen=True)
+class FlashTiles:
+    """Rows and columns of the score tiles. ``*_major`` is what a grid
+    step brings into VMEM beside the kernel's own row tile; the minor
+    (the name without ``_major``) is what one pass of the inner loop
+    computes, skips or masks. Every size is a multiple of 128 that
+    divides the sequence, a minor divides its major."""
+    block_q: int              # forward: query rows a grid step
+    block_k_major: int
+    block_k: int
+    block_k_dkv: int          # dK/dV: key rows a grid step
+    block_q_major_dkv: int
+    block_q_dkv: int
+    block_q_dq: int           # dQ: query rows a grid step
+    block_k_major_dq: int
+    block_k_dq: int
+
+    def check(self, seq_len: int) -> None:
+        for name, size in dataclasses.asdict(self).items():
+            if size % _LANES or seq_len % size:
+                raise ValueError(f"{name}={size} is no multiple of "
+                                 f"{_LANES} that divides {seq_len}")
+        for major, minor in (("block_k_major", "block_k"),
+                             ("block_q_major_dkv", "block_q_dkv"),
+                             ("block_k_major_dq", "block_k_dq")):
+            if getattr(self, major) % getattr(self, minor):
+                raise ValueError(f"{minor} does not divide {major}")
+
+
+def _lanes(x, n: int):
+    """``x`` is ``[rows, 128]`` with equal lanes: the same at ``n``."""
+    if n <= _LANES:
+        return x[:, :n]
+    return jnp.tile(x, (1, n // _LANES))
+
+
+def _runs(row_tile, row_block, col_tile, col_block):
+    """Whether a tile has an entry on or below the diagonal (its bottom
+    left corner is): rows are queries, columns keys."""
+    return (row_tile + 1) * row_block - 1 >= col_tile * col_block
+
+
+def _query_tile_specs(bq: int, bkm: int, d: int):
+    """Block specs of the two kernels whose grid is (batch, head, query
+    tile, key major): a ``[bq, d]`` query-side tile, a ``[bkm, d]`` key
+    major, and a ``[1, bq]`` slice of a ``[B, H, 1, S]`` row."""
+    from jax.experimental import pallas as pl
+
+    def kv_map(bi, hi, qi, kj):
+        # A key major above the diagonal is not computed; staying on
+        # major 0 (the next query tile's first) keeps it from being
+        # fetched.
+        return (bi, hi, jnp.where(_runs(qi, bq, kj, bkm), kj, 0), 0)
+
+    return (pl.BlockSpec((None, None, bq, d),
+                         lambda bi, hi, qi, kj: (bi, hi, qi, 0)),
+            pl.BlockSpec((None, None, bkm, d), kv_map),
+            pl.BlockSpec((None, None, 1, bq),
+                         lambda bi, hi, qi, kj: (bi, hi, 0, qi)))
+
+
+def _causal_step(step, offset, q_first, n_q, k_first, n_k):
+    """Call ``step(offset, masked)`` for the minor tile of queries
+    ``q_first..`` and keys ``k_first..`` if the causal mask leaves
+    anything of it; ``masked`` if it takes anything away. The two tests
+    are on program ids, so each is a branch on the chip."""
+    from jax.experimental import pallas as pl
+
+    runs = k_first <= q_first + n_q - 1
+    crossed = k_first + n_k - 1 > q_first
+    pl.when(runs & jnp.logical_not(crossed))(
+        functools.partial(step, offset, False))
+    pl.when(runs & crossed)(functools.partial(step, offset, True))
+
+
+def _causal(s, first_row, first_col, rows_are_queries: bool = True):
+    at0 = first_row + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    at1 = first_col + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    keep = at1 <= at0 if rows_are_queries else at0 <= at1
+    return jnp.where(keep, s, _MASK_VALUE)
+
+
+# -- forward ---------------------------------------------------------------
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
+                *, scale: float, block_k: int):
+    from jax.experimental import pallas as pl
+
+    block_q, d = q_ref.shape
+    block_k_major = k_ref.shape[0]
+    qi, kj = pl.program_id(2), pl.program_id(3)
+    q0, k0 = qi * block_q, kj * block_k_major
+
+    @pl.when(kj == 0)
+    def _start():
+        m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def step(start, masked):
+        k = k_ref[pl.ds(start, block_k), :]
+        v = v_ref[pl.ds(start, block_k), :]
+        s = jax.lax.dot_general(q_ref[...], k, _NT,
+                                preferred_element_type=jnp.float32) * scale
+        if masked:
+            s = _causal(s, q0, k0 + start)
+        m_prev = m_ref[...]
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - _lanes(m_next, block_k))
+        alpha = jnp.exp(m_prev - m_next)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[...] = m_next
+        acc_ref[...] = acc_ref[...] * _lanes(alpha, d) + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+
+    for start in range(0, block_k_major, block_k):
+        _causal_step(step, start, q0, block_q, k0 + start, block_k)
+
+    @pl.when(kj == pl.num_programs(3) - 1)
+    def _finish():
+        l = l_ref[...]
+        o_ref[...] = (acc_ref[...] * _lanes(1.0 / l, d)).astype(o_ref.dtype)
+        if lse_ref is not None:
+            lse_ref[...] = (m_ref[...] + jnp.log(l)).T[:1]
+
+
+def _forward(q, k, v, tiles: FlashTiles, save_lse: bool, interpret: bool):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, s, d = q.shape
+    if not q.shape == k.shape == v.shape:
+        raise ValueError(f"q, k and v differ in shape: {q.shape}, "
+                         f"{k.shape}, {v.shape}")
+    tiles.check(s)
+    bq, bkm, bk = tiles.block_q, tiles.block_k_major, tiles.block_k
+    q_spec, kv_spec, row_spec = _query_tile_specs(bq, bkm, d)
+    out_shape = [jax.ShapeDtypeStruct(q.shape, q.dtype)]
+    out_specs = [q_spec]
+    if save_lse:
+        out_shape.append(jax.ShapeDtypeStruct((b, h, 1, s), jnp.float32))
+        out_specs.append(row_spec)
+        body = _fwd_kernel
+    else:
+        def body(q_ref, k_ref, v_ref, o_ref, *scratch, **kw):
+            return _fwd_kernel(q_ref, k_ref, v_ref, o_ref, None, *scratch,
+                               **kw)
+    out = pl.pallas_call(
+        functools.partial(body, scale=d ** -0.5, block_k=bk),
+        grid=(b, h, s // bq, s // bkm),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((bq, _LANES), jnp.float32),
+                        pltpu.VMEM((bq, _LANES), jnp.float32),
+                        pltpu.VMEM((bq, d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=_GRID_SEMANTICS),
+        name=f"flash_mha_fwd_block_q_{bq}_block_k_major_{bkm}_block_k_{bk}",
+        interpret=interpret,
+    )(q, k, v)
+    return out if save_lse else (out[0], None)
+
+
+# -- backward: dK and dV ---------------------------------------------------
+def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dk_ref,
+                dv_ref, dk_acc, dv_acc, *, scale: float, block_q: int):
+    from jax.experimental import pallas as pl
+
+    block_k, _ = k_ref.shape
+    block_q_major = q_ref.shape[0]
+    kj, qi = pl.program_id(2), pl.program_id(3)
+    k0, q0 = kj * block_k, qi * block_q_major
+
+    @pl.when(qi == 0)
+    def _start():
+        dk_acc[...] = jnp.zeros(dk_acc.shape, jnp.float32)
+        dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
+
+    def step(start, masked):
+        rows = pl.ds(start, block_q)
+        q, do = q_ref[rows, :], do_ref[rows, :]
+        # Scores with keys on the rows: [block_k, block_q].
+        s = jax.lax.dot_general(k_ref[...], q, _NT,
+                                preferred_element_type=jnp.float32) * scale
+        if masked:
+            s = _causal(s, k0, q0 + start, rows_are_queries=False)
+        p = jnp.exp(s - lse_ref[:, rows])
+        dv_acc[...] += jnp.dot(p.astype(do.dtype), do,
+                               preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(v_ref[...], do, _NT,
+                                 preferred_element_type=jnp.float32)
+        ds = (dp - di_ref[:, rows]) * p * scale
+        dk_acc[...] += jnp.dot(ds.astype(q.dtype), q,
+                               preferred_element_type=jnp.float32)
+
+    for start in range(0, block_q_major, block_q):
+        _causal_step(step, start, q0 + start, block_q, k0, block_k)
+
+    @pl.when(qi == pl.num_programs(3) - 1)
+    def _finish():
+        dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _backward_dkv(q, k, v, do, lse, di, tiles: FlashTiles, interpret: bool):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, s, d = q.shape
+    bk, bqm, bq = (tiles.block_k_dkv, tiles.block_q_major_dkv,
+                   tiles.block_q_dkv)
+
+    def q_tile(kj, qi):
+        # Query tiles before the diagonal are not computed: wait on the
+        # first that is.
+        return jnp.maximum(qi, (kj * bk) // bqm)
+
+    q_spec = pl.BlockSpec((None, None, bqm, d), lambda bi, hi, kj, qi:
+                          (bi, hi, q_tile(kj, qi), 0))
+    row_spec = pl.BlockSpec((None, None, 1, bqm), lambda bi, hi, kj, qi:
+                            (bi, hi, 0, q_tile(kj, qi)))
+    k_spec = pl.BlockSpec((None, None, bk, d), lambda bi, hi, kj, qi:
+                          (bi, hi, kj, 0))
+    return pl.pallas_call(
+        functools.partial(_dkv_kernel, scale=d ** -0.5, block_q=bq),
+        grid=(b, h, s // bk, s // bqm),
+        in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+        out_specs=[k_spec, k_spec],
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
+                        pltpu.VMEM((bk, d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=_GRID_SEMANTICS),
+        name=(f"flash_mha_bwd_dkv_block_k_{bk}_block_q_major_{bqm}"
+              f"_block_q_{bq}"),
+        interpret=interpret,
+    )(q, k, v, do, lse, di)
+
+
+# -- backward: dQ ----------------------------------------------------------
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref,
+               lse_col, di_col, dq_acc, *, scale: float, block_k: int):
+    from jax.experimental import pallas as pl
+
+    block_q, _ = q_ref.shape
+    block_k_major = k_ref.shape[0]
+    qi, kj = pl.program_id(2), pl.program_id(3)
+    q0, k0 = qi * block_q, kj * block_k_major
+
+    @pl.when(kj == 0)
+    def _start():
+        # The statistics arrive a row a query tile; this kernel's scores
+        # have queries on the rows.
+        lse_col[...] = jnp.broadcast_to(lse_ref[...], (_LANES, block_q)).T
+        di_col[...] = jnp.broadcast_to(di_ref[...], (_LANES, block_q)).T
+        dq_acc[...] = jnp.zeros(dq_acc.shape, jnp.float32)
+
+    def step(start, masked):
+        k = k_ref[pl.ds(start, block_k), :]
+        v = v_ref[pl.ds(start, block_k), :]
+        s = jax.lax.dot_general(q_ref[...], k, _NT,
+                                preferred_element_type=jnp.float32) * scale
+        if masked:
+            s = _causal(s, q0, k0 + start)
+        p = jnp.exp(s - _lanes(lse_col[...], block_k))
+        dp = jax.lax.dot_general(do_ref[...], v, _NT,
+                                 preferred_element_type=jnp.float32)
+        ds = (dp - _lanes(di_col[...], block_k)) * p * scale
+        dq_acc[...] += jnp.dot(ds.astype(k.dtype), k,
+                               preferred_element_type=jnp.float32)
+
+    for start in range(0, block_k_major, block_k):
+        _causal_step(step, start, q0, block_q, k0 + start, block_k)
+
+    @pl.when(kj == pl.num_programs(3) - 1)
+    def _finish():
+        dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
+
+
+def _backward_dq(q, k, v, do, lse, di, tiles: FlashTiles, interpret: bool):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, s, d = q.shape
+    bq, bkm, bk = tiles.block_q_dq, tiles.block_k_major_dq, tiles.block_k_dq
+    q_spec, kv_spec, row_spec = _query_tile_specs(bq, bkm, d)
+    return pl.pallas_call(
+        functools.partial(_dq_kernel, scale=d ** -0.5, block_k=bk),
+        grid=(b, h, s // bq, s // bkm),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        scratch_shapes=[pltpu.VMEM((bq, _LANES), jnp.float32),
+                        pltpu.VMEM((bq, _LANES), jnp.float32),
+                        pltpu.VMEM((bq, d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=_GRID_SEMANTICS),
+        name=f"flash_mha_bwd_dq_block_q_{bq}_block_k_major_{bkm}_block_k_{bk}",
+        interpret=interpret,
+    )(q, k, v, do, lse, di)
+
+
+# -- the differentiable call -----------------------------------------------
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def flash_mha(q, k, v, tiles: FlashTiles, interpret: bool = False):
+    """Causal softmax attention. q/k/v: ``[B, H, S, D]``, ``D`` 64 or a
+    multiple of 128, ``S`` a multiple of every tile."""
+    return _forward(q, k, v, tiles, False, interpret)[0]
+
+
+def _flash_mha_fwd(q, k, v, tiles, interpret):
+    o, lse = _forward(q, k, v, tiles, True, interpret)
+    return o, (q, k, v, o, lse)
+
+
+def _flash_mha_bwd(tiles, interpret, residuals, do):
+    q, k, v, o, lse = residuals
+    di = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32),
+                 axis=-1)[:, :, None, :]                    # [B, H, 1, S]
+    dk, dv = _backward_dkv(q, k, v, do, lse, di, tiles, interpret)
+    dq = _backward_dq(q, k, v, do, lse, di, tiles, interpret)
+    return dq, dk, dv
+
+
+flash_mha.defvjp(_flash_mha_fwd, _flash_mha_bwd)

@@ -1,4 +1,5 @@
-"""Ring attention == plain attention, forward and backward."""
+"""Ring attention == plain attention, forward and backward; the flash
+kernels' tiles and the kernels themselves (interpreted: no chip here)."""
 
 import jax
 import jax.numpy as jnp
@@ -54,6 +55,108 @@ def test_ring_gradients_match(mesh):
     for a, b in zip(g_ref, g_ring):
         np.testing.assert_allclose(np.asarray(b), np.asarray(a),
                                    rtol=2e-4, atol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# the flash kernels: which tiles, handed on as chosen, and what they compute
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("seq_len,head_dim", [
+    (2048, 64), (1024, 128), (2048, 128), (4096, 64), (384, 128),
+    (8192, 128), (128, 64), (640, 128)])
+def test_flash_tiles_fit_the_sequence(seq_len, head_dim):
+    import dataclasses
+    import importlib
+
+    A = importlib.import_module("ray_tpu.ops.attention")
+    tiles = A.flash_tiles(seq_len, head_dim)
+    tiles.check(seq_len)        # what the kernels' wrappers would refuse
+    sizes = dataclasses.asdict(tiles)
+    assert all(b % 128 == 0 and seq_len % b == 0 for b in sizes.values())
+    for major, minor in (("block_k_major", "block_k"),
+                         ("block_q_major_dkv", "block_q_dkv"),
+                         ("block_k_major_dq", "block_k_dq")):
+        assert sizes[major] % sizes[minor] == 0, (major, minor)
+    if (seq_len, head_dim) in ((2048, 64), (1024, 128)):
+        # the two shapes a sweep on the chip measured: its entry, as is
+        assert tiles is A._SWEPT_TILES[(seq_len, head_dim)]
+    else:
+        assert (seq_len, head_dim) not in A._SWEPT_TILES
+        assert tiles == A._tiles_by_rule(seq_len)
+
+
+def test_flash_tiles_check_refuses_what_does_not_tile():
+    from ray_tpu.ops.flash_attention import FlashTiles
+
+    good = dict(block_q=256, block_k_major=512, block_k=256,
+                block_k_dkv=256, block_q_major_dkv=512, block_q_dkv=256,
+                block_q_dq=256, block_k_major_dq=512, block_k_dq=256)
+    FlashTiles(**good).check(1024)
+    for bad in (dict(block_q=192), dict(block_k=384), dict(block_q_dq=768),
+                dict(block_k_major_dq=256, block_k_dq=512)):
+        with pytest.raises(ValueError):
+            FlashTiles(**{**good, **bad}).check(1024)
+
+
+def test_flash_attention_tpu_hands_the_chosen_tiles_to_the_kernel(
+        monkeypatch):
+    import importlib
+
+    A = importlib.import_module("ray_tpu.ops.attention")
+    seen = []
+
+    def kernel(q, k, v, tiles):
+        seen.append((q.shape, tiles))
+        return q
+
+    monkeypatch.setattr(A, "flash_mha", kernel)
+    for s, d in ((2048, 64), (1024, 128), (384, 128)):
+        x = jnp.zeros((1, s, 2, d), jnp.bfloat16)
+        assert A.flash_attention_tpu(x, x, x).shape == x.shape
+        shape, tiles = seen[-1]
+        assert shape == (1, 2, s, d)            # the kernels' [B, H, S, D]
+        assert tiles == A.flash_tiles(s, d)
+    assert seen[0][1] is A._SWEPT_TILES[(2048, 64)]
+
+
+# Tiles that put every case of the walk over minor tiles on the grid at
+# S = 512: minors skipped above the diagonal, crossed by it, clear of it;
+# majors of the whole sequence and of a part; unequal rows and columns.
+_WALKS = {
+    "minors_in_one_major": (256, 512, 128, 256, 512, 128, 256, 512, 128),
+    "majors_and_minors": (128, 256, 128, 128, 256, 128, 128, 256, 128),
+    "wide_rows": (512, 256, 256, 512, 256, 128, 512, 128, 128),
+    "one_tile": (512, 512, 512, 512, 512, 512, 512, 512, 512),
+}
+
+
+@pytest.mark.parametrize("head_dim", [64, 128])
+@pytest.mark.parametrize("walk", sorted(_WALKS))
+def test_flash_kernels_match_plain_attention(walk, head_dim):
+    """Output and the three gradients of the interpreted kernels against
+    `plain_attention`, in float32 so that only the algorithm differs."""
+    from ray_tpu.ops import plain_attention
+    from ray_tpu.ops.flash_attention import FlashTiles, flash_mha
+
+    tiles = FlashTiles(*_WALKS[walk])
+    tiles.check(512)
+    ks = jax.random.split(jax.random.PRNGKey(2), 4)
+    q, k, v, do = (jax.random.normal(kk, (1, 2, 512, head_dim),
+                                     jnp.float32) for kk in ks)
+
+    def flash(q, k, v):
+        return flash_mha(q, k, v, tiles, True)
+
+    def plain(q, k, v):
+        return plain_attention(*(t.transpose(0, 2, 1, 3)
+                                 for t in (q, k, v))).transpose(0, 2, 1, 3)
+
+    got = (flash(q, k, v),) + jax.grad(
+        lambda *a: jnp.sum(flash(*a) * do), argnums=(0, 1, 2))(q, k, v)
+    want = (plain(q, k, v),) + jax.grad(
+        lambda *a: jnp.sum(plain(*a) * do), argnums=(0, 1, 2))(q, k, v)
+    for name, a, b in zip(("o", "dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-4, err_msg=name)
 
 
 class _null:

@@ -443,3 +443,40 @@ def test_hybrid_decode_step_compiles_for_v5e_with_both_pools_in_place(
     # Both pools, at the chip's layout (the tails' rows pad a little).
     assert pools <= memory.alias_size_in_bytes < 1.01 * pools
     assert memory.temp_size_in_bytes < 200e6
+
+
+# ---------------------------------------------------------------------------
+# the train step's flash kernels (`ops/flash_attention.py`), compiled for
+# the chip at the shapes the train cells and `flash_tiles`' entries name.
+# They live here because a described topology belongs in one test file:
+# the worker that runs it loads the TPU's library, and only one may.
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("shape", [
+    (8, 32, 2048, 64),      # smollm2-1.7b.train.seq2k
+    (8, 16, 1024, 128),     # the other swept shape
+    (4, 16, 2048, 128),     # olmo-1b's, by the rule
+    (1, 8, 8192, 128),      # a long sequence, by the rule
+])
+def test_flash_kernels_compile_for_v5e_at_the_chosen_tiles(
+        one_chip, no_compile_cache, shape):
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.attention import flash_tiles
+    from ray_tpu.ops.flash_attention import flash_mha
+
+    tiles = flash_tiles(shape[2], shape[3])
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v, do):
+        return jnp.sum(flash_mha(q, k, v, tiles).astype(jnp.float32)
+                       * do.astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x, x).compile().as_text()
+    # The device trace names an operation after its kernel, and the
+    # benchmark's `flash_attn_roofline` finds the three by "flash".
+    for kernel in (f"flash_mha_fwd_block_q_{tiles.block_q}_",
+                   f"flash_mha_bwd_dkv_block_k_{tiles.block_k_dkv}_",
+                   f"flash_mha_bwd_dq_block_q_{tiles.block_q_dq}_"):
+        assert kernel in text, kernel
