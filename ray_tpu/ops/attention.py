@@ -24,7 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.ops.flash_attention import FlashTiles, flash_mha
+from ray_tpu.ops.flash_attention import (FlashTiles, flash_mha,
+                                          prefill_attention_fwd)
 
 _NEG_INF = -1e30
 
@@ -113,6 +114,38 @@ def plain_attention(q, k, v, *, causal: bool = True, positions=None):
         scores = jnp.where(mask[None, None], scores, _NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+def banded_attention(q, k, v, window: int = None):
+    """The plain form of `prefill_attention`: q ``[H, S, D]`` over k, v
+    ``[Hkv, S, D]`` (query head ``i`` on key head ``i // group``), causal,
+    with `window` only the keys ``j`` with ``i - j < window``. The whole
+    ``[H, S, S]`` float32 score matrix is built: for short prompts, the
+    CPU and the tests. Float32 out."""
+    h, s, d = q.shape
+    hkv = k.shape[0]
+    qg = q.reshape(hkv, h // hkv, s, d)
+    scores = jnp.einsum("kgqd,ksd->kgqs", qg, k,
+                        preferred_element_type=jnp.float32) * d ** -0.5
+    idx = jnp.arange(s)
+    keep = idx[:, None] >= idx[None, :]
+    if window is not None:
+        keep &= idx[:, None] - idx[None, :] < window
+    probs = jax.nn.softmax(jnp.where(keep, scores, _NEG_INF), axis=-1)
+    out = jnp.einsum("kgqs,ksd->kgqd", probs.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(h, s, d)
+
+
+def prefill_attention(q, k, v, window: int = None):
+    """One prompt's attention in the serving prefill (grouped heads, an
+    optional window, forward only): the Pallas forward of
+    `ops/flash_attention.py` on a TPU for heads of a multiple of 128 and a
+    length that tiles, `banded_attention` elsewhere."""
+    s, d = q.shape[1], q.shape[2]
+    if jax.default_backend() == "tpu" and d % 128 == 0 and s % 128 == 0:
+        return prefill_attention_fwd(q, k, v, window)
+    return banded_attention(q, k, v, window)
 
 
 def ring_attention_manual(q, k, v, q_pos, *, axis_name: str = "sp",

@@ -31,17 +31,25 @@ import jax
 import jax.numpy as jnp
 
 
-def route(y, router_w, select_bias, top_k: int, scaling: float = 1.0):
-    """y ``[T, d]``; router_w ``[d, E]``; select_bias ``[E]``. Scores
-    are ``sigmoid(y W)`` in float32 over all ``E`` experts; the `top_k`
-    largest of ``score + select_bias`` are chosen; a chosen expert's
-    weight is its score over the chosen scores' sum, times `scaling`.
+def route(y, router_w, select_bias, top_k: int, scaling: float = 1.0,
+          scoring: str = "sigmoid"):
+    """y ``[T, d]``; router_w ``[d, E]``; select_bias ``[E]`` or None.
+    Scores are ``sigmoid(y W)``, or with `scoring` "softmax" the softmax
+    of ``y W`` over all ``E`` experts, in float32; the `top_k` largest
+    of ``score + select_bias`` are chosen; a chosen expert's weight is
+    its score over the chosen scores' sum, times `scaling`.
     Returns ``(experts [T, k] int32, weights [T, k] float32)``."""
     f32 = jnp.float32
-    scores = jax.nn.sigmoid(jnp.dot(
-        y.astype(f32), router_w.astype(f32),
-        precision=jax.lax.Precision.HIGHEST))
-    _, experts = jax.lax.top_k(scores + select_bias.astype(f32), top_k)
+    if scoring not in ("sigmoid", "softmax"):
+        raise ValueError(f"a router scores by sigmoid or softmax, not "
+                         f"{scoring!r}")
+    logits = jnp.dot(y.astype(f32), router_w.astype(f32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = (jax.nn.sigmoid(logits) if scoring == "sigmoid"
+              else jax.nn.softmax(logits, axis=-1))
+    ranked = (scores if select_bias is None
+              else scores + select_bias.astype(f32))
+    _, experts = jax.lax.top_k(ranked, top_k)
     chosen = jnp.take_along_axis(scores, experts, axis=-1)
     weights = chosen / jnp.sum(chosen, axis=-1, keepdims=True) * scaling
     return experts.astype(jnp.int32), weights

@@ -369,3 +369,112 @@ def _flash_mha_bwd(tiles, interpret, residuals, do):
 
 
 flash_mha.defvjp(_flash_mha_fwd, _flash_mha_bwd)
+
+
+# -- the serving prefill's forward: grouped heads, an optional window ------
+def _prefill_fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
+                        *, scale: float, window, n_visit: int):
+    """One query tile of one query head against the `kj`-th key tile it
+    visits: all tiles up to the diagonal without a window, the last
+    `n_visit` up to the diagonal with one. A tile wholly above the
+    diagonal or wholly left of the window is skipped; the masks are
+    built only on a tile the diagonal or the window's edge crosses."""
+    from jax.experimental import pallas as pl
+
+    block, d = q_ref.shape
+    qi, kj = pl.program_id(1), pl.program_id(2)
+    kt = kj if window is None else qi - (n_visit - 1) + kj
+    q0, k0 = qi * block, kt * block
+
+    @pl.when(kj == 0)
+    def _start():
+        m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def step(masked):
+        s = jax.lax.dot_general(q_ref[...], k_ref[...], _NT,
+                                preferred_element_type=jnp.float32) * scale
+        if masked:
+            at_q = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            at_k = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            keep = at_k <= at_q
+            if window is not None:
+                keep &= at_q - at_k < window
+            s = jnp.where(keep, s, _MASK_VALUE)
+        m_prev = m_ref[...]
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - _lanes(m_next, block))
+        alpha = jnp.exp(m_prev - m_next)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[...] = m_next
+        acc_ref[...] = acc_ref[...] * _lanes(alpha, d) + jnp.dot(
+            p.astype(v_ref.dtype), v_ref[...],
+            preferred_element_type=jnp.float32)
+
+    runs = (kt >= 0) & (kt <= qi)
+    crossed = kt == qi
+    if window is not None:
+        # The nearest and the farthest (query, key) pair of the tile.
+        runs &= q0 - (k0 + block - 1) < window
+        crossed |= q0 + block - 1 - k0 >= window
+    pl.when(runs & jnp.logical_not(crossed))(functools.partial(step, False))
+    pl.when(runs & crossed)(functools.partial(step, True))
+
+    @pl.when(kj == n_visit - 1)
+    def _finish():
+        o_ref[...] = (acc_ref[...] * _lanes(1.0 / l_ref[...], d)
+                      ).astype(o_ref.dtype)
+
+
+def prefill_attention_fwd(q, k, v, window: int = None, *, block: int = None,
+                          interpret: bool = False):
+    """Causal softmax attention of one sequence for the serving prefill,
+    forward only: q ``[H, S, D]`` over k, v ``[Hkv, S, D]``, query head
+    ``i`` on key head ``i // (H // Hkv)``; with `window` a query sees the
+    keys ``j`` with ``i - j < window`` alone. Float32 out. ``S`` is a
+    multiple of `block` (512, or ``S`` below that), ``D`` of 128.
+
+    The training kernels above are left as they are (their tiles, their
+    names): this one runs under ``flash_prefill_fwd_causal`` or
+    ``flash_prefill_fwd_window_<w>`` in a device trace."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    h, s, d = q.shape
+    hkv = k.shape[0]
+    if k.shape != (hkv, s, d) or v.shape != k.shape or h % hkv:
+        raise ValueError(f"q {q.shape} does not go over k {k.shape}, "
+                         f"v {v.shape}")
+    block = block or min(512, s)
+    if s % block or block % _LANES:
+        raise ValueError(f"block={block} is no multiple of {_LANES} that "
+                         f"divides {s}")
+    group = h // hkv
+    n_tiles = s // block
+    n_visit = (n_tiles if window is None
+               else min(n_tiles, -(-(window - 1) // block) + 1))
+
+    def kv_map(hi, qi, kj):
+        kt = kj if window is None else qi - (n_visit - 1) + kj
+        # A tile that is skipped is not fetched: stay on one that runs.
+        return (hi // group, jnp.clip(kt, 0, qi), 0)
+
+    q_spec = pl.BlockSpec((None, block, d), lambda hi, qi, kj: (hi, qi, 0))
+    kv_spec = pl.BlockSpec((None, block, d), kv_map)
+    return pl.pallas_call(
+        functools.partial(_prefill_fwd_kernel, scale=d ** -0.5,
+                          window=window, n_visit=n_visit),
+        grid=(h, n_tiles, n_visit),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
+        scratch_shapes=[pltpu.VMEM((block, _LANES), jnp.float32),
+                        pltpu.VMEM((block, _LANES), jnp.float32),
+                        pltpu.VMEM((block, d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name=("flash_prefill_fwd_causal" if window is None
+              else f"flash_prefill_fwd_window_{window}"),
+        interpret=interpret,
+    )(q, k, v)

@@ -26,3 +26,55 @@ def apply_rotary(x, cos, sin, positions=None):
     x1, x2 = jnp.split(x, 2, axis=-1)
     out = jnp.concatenate([x1 * c - x2 * s, x1 * s + x2 * c], axis=-1)
     return out.astype(x.dtype)
+
+
+def rotary_inv_freq(rot_dim: int, theta: float, yarn: dict = None):
+    """Inverse frequencies ``[rot_dim // 2]`` float32 of a rotation over
+    `rot_dim` values of a head: ``theta ** (-2 i / rot_dim)``, or, with
+    `yarn` (``factor``, ``original_max_position_embeddings``,
+    ``beta_fast``, ``beta_slow``), YaRN's blend of them: a frequency that
+    turns more than ``beta_fast`` times over the original context stays,
+    one that turns less than ``beta_slow`` times is divided by ``factor``,
+    and a linear ramp over the pair index lies between the two (bounds
+    floored and ceiled, as `rope_type` "yarn" computes them)."""
+    import math
+
+    exponent = jnp.arange(0, rot_dim, 2, dtype=jnp.float32) / rot_dim
+    inv = 1.0 / (theta ** exponent)
+    if not yarn:
+        return inv
+    original = yarn["original_max_position_embeddings"]
+
+    def pair_of(turns):
+        return (rot_dim * math.log(original / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(pair_of(yarn["beta_fast"])), 0)
+    high = min(math.ceil(pair_of(yarn["beta_slow"])), rot_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(rot_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return inv / yarn["factor"] * ramp + inv * (1.0 - ramp)
+
+
+def rotary_cos_sin(positions, inv_freq, attention_factor: float = 1.0):
+    """cos and sin ``[*positions.shape, rot_dim // 2]`` float32 of the
+    angles ``position * inv_freq``, each times `attention_factor` (YaRN
+    scales the rotated values, and so the scores, through its tables)."""
+    ang = positions.astype(jnp.float32)[..., None] * inv_freq
+    return jnp.cos(ang) * attention_factor, jnp.sin(ang) * attention_factor
+
+
+def apply_rotary_partial(x, cos, sin):
+    """Rotate the first ``2 * cos.shape[-1]`` values of every head and
+    pass the rest through. x ``[T, H, D]``; cos, sin ``[T, rot_dim //
+    2]`` at the tokens' positions (`rotary_cos_sin`). The rotated part
+    pairs value ``i`` with value ``i + rot_dim // 2``, as `apply_rotary`
+    does over the whole head. Computed and returned in float32."""
+    half = cos.shape[-1]
+    x = x.astype(jnp.float32)
+    x1, x2, rest = x[..., :half], x[..., half:2 * half], x[..., 2 * half:]
+    c, s = cos[:, None, :], sin[:, None, :]
+    return jnp.concatenate([x1 * c - x2 * s, x1 * s + x2 * c, rest],
+                           axis=-1)

@@ -39,6 +39,7 @@ Typical replica:
 from ray_tpu.serve.engine.kv_cache import (CacheOverflowError,
                                            KVCacheManager)
 from ray_tpu.serve.engine.hybrid_model import HybridEngineModel
+from ray_tpu.serve.engine.laguna_model import LagunaEngineModel
 from ray_tpu.serve.engine.model import TinyLM, TransformerEngineModel
 from ray_tpu.serve.engine.prefix_index import PrefixIndex
 from ray_tpu.serve.engine.scheduler import (EngineConfig,
@@ -49,6 +50,6 @@ from ray_tpu.serve.engine.scheduler import (EngineConfig,
 __all__ = [
     "CacheOverflowError", "EngineConfig", "EngineOverloadedError",
     "EngineStoppedError", "HybridEngineModel", "InferenceEngine",
-    "KVCacheManager",
+    "KVCacheManager", "LagunaEngineModel",
     "PrefixIndex", "TinyLM", "TokenStream", "TransformerEngineModel",
 ]

@@ -29,13 +29,13 @@ keeps its state bit for bit, and no copy of the batch's state is built.
 
 from __future__ import annotations
 
-from typing import Dict, List, Sequence
+from typing import List, Sequence
 
 import numpy as np
 
 from ray_tpu.core import flight
-from ray_tpu.serve.engine.model import (DecodeStep, PromptKV, _JitLRU,
-                                        _next_pow2)
+from ray_tpu.serve.engine.model import PromptKV, _next_pow2
+from ray_tpu.serve.engine.sparse_model import SparseEngineModel
 
 
 class PromptState(PromptKV):
@@ -50,7 +50,7 @@ class PromptState(PromptKV):
         self.state = state
 
 
-class HybridEngineModel:
+class HybridEngineModel(SparseEngineModel):
     """Incremental decoding over `models/hybrid_moe.py` weights.
 
     KV entry a token: ``[n_periods, 2, n_kv_heads, head_dim]`` (the GQA
@@ -60,83 +60,29 @@ class HybridEngineModel:
     Prefill runs the prompt once (chunked delta rule, `kda_chunk`
     positions a chunk) in pow2 length buckets; a decode step is jitted a
     (batch, table) bucket. A prompt is never prefilled from an offset:
-    the engine adopts no prefix over a model with state."""
+    the engine adopts no prefix over a model with state. The norm, the
+    product helper, the expert layer, the counters and the host side of
+    a call are `sparse_model.SparseEngineModel`'s."""
 
     def __init__(self, params, cfg, max_batch_size: int = 8,
                  jit_cache_cap: int = 32, kda_chunk: int = 64):
-        import jax
         import jax.numpy as jnp
 
         from ray_tpu.models.hybrid_moe import KDA_PER_PERIOD
         from ray_tpu.ops.paged_attention import kernel_eligible
 
-        self._params = params
-        self._cfg = cfg
+        super().__init__(params, cfg, jit_cache_cap)
         self._chunk = kda_chunk
-        self.vocab_size = cfg.vocab_size
-        self.eos_token = 1
-        dtype = jnp.dtype(cfg.dtype)
         self.kv_token_shape = (cfg.n_periods, 2, cfg.n_kv_heads,
                                cfg.head_dim)
-        self.kv_dtype = dtype
         dk = cfg.kda_head_dim
         self.state_shapes = {
             "s": ((cfg.n_kda_layers, cfg.kda_heads, dk, dk), jnp.float32),
             "conv": ((cfg.n_kda_layers, cfg.conv_kernel - 1,
-                      3 * cfg.kda_width), dtype)}
+                      3 * cfg.kda_width), self.kv_dtype)}
         self._kda_per_period = KDA_PER_PERIOD
-        self._prefill_jit = _JitLRU(jit_cache_cap)
-        self._decode_paged_jit = _JitLRU(jit_cache_cap)
-        self.prefill_calls = 0
-        self.prefill_tokens = 0
-        self.decode_calls = 0
-        self.jit_compiles = 0
-        # As `TransformerEngineModel`'s: what a decode step moves across
-        # the host boundary, and how it reads the KV pool.
-        self.decode_h2d_arrays = 0
-        self.decode_d2h_bytes = 0
         self._attn_inplace = kernel_eligible(cfg.n_heads, cfg.head_dim,
                                              cfg.n_kv_heads)
-        self.decode_attn_inplace_steps = 0
-        self.decode_kv_pages_read = 0
-        # The expert layers' counts over decode steps, summed over
-        # layers, computed inside the step and fetched with its ids:
-        # (token, expert) pairs on held experts; (layer, expert) pairs
-        # with at least one token; the largest load of a held expert.
-        self.moe_local_assignments = 0
-        self.moe_expert_touches = 0
-        self.moe_max_expert_load = 0
-        self.phase: Dict[str, float] = dict.fromkeys(
-            ("prefill_prep_s", "prefill_dispatch_s", "prefill_wait_s",
-             "prefill_kv_d2h_s", "decode_prep_s", "decode_dispatch_s",
-             "decode_wait_s"), 0.0)
-        self._jnp = jnp
-        self._tree_leaves = jax.tree_util.tree_leaves
-
-    @property
-    def kv_pool_ns(self):
-        return self._jnp
-
-    @property
-    def jit_cache_evictions(self) -> int:
-        return (self._prefill_jit.evictions
-                + self._decode_paged_jit.evictions)
-
-    # -- shared math ---------------------------------------------------
-    def _norm(self, x, scale):
-        import jax
-        import jax.numpy as jnp
-
-        var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-        return x * jax.lax.rsqrt(var + self._cfg.norm_eps) * scale
-
-    @staticmethod
-    def _mm(y, w):
-        """Both operands in the weights' dtype, float32 out."""
-        import jax.numpy as jnp
-
-        return jnp.dot(y.astype(w.dtype), w,
-                       preferred_element_type=jnp.float32)
 
     def _over_periods(self, body, carry, xs):
         """`lax.scan` of `body` over the periods; one period runs
@@ -185,30 +131,6 @@ class HybridEngineModel:
         o = self._norm(o, lp["onorm"]).reshape(t, -1)
         gate = jax.nn.sigmoid(self._mm(self._mm(y, lp["wg1"]), lp["wg2"]))
         return self._mm(o * gate, lp["wo"])
-
-    def _experts(self, x, ln2, mp, valid):
-        """The expert layer's residual add; returns the new `x` and the
-        layer's three counts."""
-        import jax
-        import jax.numpy as jnp
-
-        from ray_tpu.ops.experts import held_experts_ffn, route
-
-        cfg = self._cfg
-        y = self._norm(x, ln2)
-        with jax.named_scope("moe_route"):
-            experts, weights = route(y, mp["router"], mp["select_bias"],
-                                     cfg.top_k, cfg.routed_scaling)
-        with jax.named_scope("moe_experts"):
-            routed, load = held_experts_ffn(
-                y, experts, weights, mp["w_gate"], mp["w_up"],
-                mp["w_down"], cfg.experts_held, valid)
-            shared = self._mm(
-                jax.nn.silu(self._mm(y, mp["shared_gate"]))
-                * self._mm(y, mp["shared_up"]), mp["shared_down"])
-        counts = jnp.stack([jnp.sum(load), jnp.sum(load > 0),
-                            jnp.max(load)]).astype(jnp.int32)
-        return x + shared + routed, counts
 
     # -- prefill -------------------------------------------------------
     def _build_prefill(self, s_pad: int):
@@ -407,37 +329,8 @@ class HybridEngineModel:
             return self._prefill(tokens)
 
     def _prefill(self, tokens: Sequence[int]):
-        jnp, phase = self._jnp, self.phase
-        self.prefill_calls += 1
-        n = len(tokens)
-        self.prefill_tokens += n
-        with flight.span("model", "prefill.prep", None, phase,
-                         "prefill_prep_s"):
-            s_pad = _next_pow2(max(n, 8))
-            fn = self._prefill_jit.get(s_pad)
-            if fn is None:
-                fn = self._prefill_jit[s_pad] = self._build_prefill(s_pad)
-            padded = np.zeros((s_pad,), np.int32)
-            padded[:n] = np.asarray(tokens, np.int32)
-            args = (jnp.asarray(padded), jnp.int32(n))
-        with flight.span("model", "prefill.dispatch", None, phase,
-                         "prefill_dispatch_s"):
-            logits, kv, state = fn(self._params, *args)
-        with flight.span("model", "prefill.logits_wait", None, phase,
-                         "prefill_wait_s"):
-            logits = np.asarray(logits)
+        logits, (kv, state), n = self._run_prefill(tokens)
         return logits, PromptState(kv, n, state)
-
-    def prefill_paged(self, tokens: Sequence[int], pool,
-                      block_table: Sequence[int], prefix_len: int,
-                      block_size: int):
-        """The engine adopts no prefix over a model with state, so the
-        offset is always 0 and this is `prefill`."""
-        if prefix_len:
-            raise ValueError(
-                "a prefix's KV blocks do not restore the recurrent state: "
-                "this model prefills a prompt whole")
-        return self.prefill(tokens)
 
     def decode_paged(self, pool, block_tables: List[Sequence[int]],
                      last_tokens: Sequence[int],
@@ -490,17 +383,5 @@ class HybridEngineModel:
             packed[:k, 3] = write_offs[:k]
             packed[:min(len(slots), b), 4] = slots[:b]
             args = (pool, state, self._params, packed)
-            self.decode_h2d_arrays += sum(
-                isinstance(leaf, np.ndarray)
-                for leaf in self._tree_leaves(args))
-        with flight.span("model", "decode.dispatch", None, phase,
-                         "decode_dispatch_s"):
-            out, logits, new_pool, new_state = fn(*args)
-        with flight.span("model", "decode.logits_wait", None, phase,
-                         "decode_wait_s"):
-            out = np.asarray(out)
-            self.decode_d2h_bytes += out.nbytes
-        self.moe_local_assignments += int(out[b_pad])
-        self.moe_expert_touches += int(out[b_pad + 1])
-        self.moe_max_expert_load += int(out[b_pad + 2])
-        return DecodeStep(out[:b], logits, self), new_pool, new_state
+        step, (new_pool, new_state) = self._run_decode(fn, args, b, b_pad)
+        return step, new_pool, new_state

@@ -66,6 +66,37 @@ blocks of KV restore a prefix, a state does not (the engine builds no
 prefix index over such a model). A model that declares no state gets no
 second pool and the calls it has always had.
 
+**Layer groups** (for a model whose layers do not all keep the same
+positions): a *layer group* is a set of layers that share one pool, one
+free list and one block table a sequence. A model declares them
+(`kv_groups`: name -> the group's row shape and, for layers of
+sliding-window attention, the window's length); the first, `global`,
+is this manager itself and keeps every position, and each further group
+is a manager of its own inside it, with the same block size. A group with
+a **window** of `w` positions keeps, of a sequence whose next query
+stands at position `p`, only the blocks that hold a position `j` with
+``p - j < w``: a block every position of which has left the window goes
+back to that group's free list in the step that leaves it
+(`release_expired`, which the scheduler calls before it grows the
+tables; `allocate` trims too, so the bound holds whoever calls), and a
+sequence never holds more than ``ceil(w / block_size) + 1`` of the
+group's blocks whatever its length. Its table is compact: entry 0 is
+logical block `base`, which `step_tables` hands the model beside the
+table and `ops/paged_attention.py` takes as a row's `starts`.
+`can_allocate` / `allocate` / `free` count every group and stay atomic
+over them; `write_range` stores every row of a prefill in the global
+group and only the rows the window still reaches in a window group (the
+payload's `groups`); `paged_step` resolves a write slot a group and
+hands the model dicts, ``{group: pool}``, ``{group: blocks}``, ``{group:
+offs}``, all pools donated and re-bound. `stats()` counts a group
+(`groups`): `blocks`, `blocks_in_use`, `block_steps_in_use` /
+`block_steps` (summed over paged steps: their quotient is the pool's
+occupancy while it decodes) and `window_blocks_released`. No prefix is
+adopted beside a window group (an adopted prefix would need the window
+layers' rows at its end), and groups are not combined with state slots.
+A model that declares no groups is one global group, with the calls and
+answers it has always had.
+
 Determinism contract (the scheduler's loop must never crash on OOM):
 `allocate` is atomic — it either extends the table (and privatizes the
 requested write range) or changes nothing and returns False; the
@@ -182,18 +213,34 @@ class KVCacheManager:
     per-sequence block tables. Thread-safe (the engine loop and
     `stats()` callers race)."""
 
+    GLOBAL = "global"       # the name of the group this manager is
+
     def __init__(self, num_blocks: int, block_size: int,
                  kv_shape: Tuple[int, ...] = (), dtype=np.float32,
                  array_ns=None, state_shapes: Optional[dict] = None,
-                 state_slots: int = 0):
+                 state_slots: int = 0, window: Optional[int] = None,
+                 groups: Optional[dict] = None):
         """`state_shapes`: what the model keeps a sequence beside its KV
         rows, ``{name: (shape, dtype)}``; with it, `state_slots` slots
-        of each, zeroed, in the pool's namespace."""
+        of each, zeroed, in the pool's namespace. `groups`: the further
+        layer groups, ``{name: {"num_blocks", "kv_shape", "window"}}``,
+        each a manager of its own with this one's block size, dtype and
+        namespace; `window`: this group's own (a sub-manager's)."""
         if num_blocks <= 0 or block_size <= 0:
             raise ValueError("num_blocks and block_size must be positive")
         if state_shapes and state_slots <= 0:
             raise ValueError("a model with per-sequence state needs "
                              "state_slots > 0")
+        if groups and state_shapes:
+            raise ValueError("layer groups beside state slots: no model "
+                             "of the tree needs both")
+        if window is not None and num_blocks < math.ceil(
+                window / block_size) + 1:
+            raise ValueError(
+                f"a window of {window} needs "
+                f"{math.ceil(window / block_size) + 1} blocks a sequence; "
+                f"the group has {num_blocks}")
+        self.window = window
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
         self.kv_shape = tuple(kv_shape)
@@ -222,6 +269,18 @@ class KVCacheManager:
         self._refs: Dict[int, int] = {}          # block -> holder count
         self._tables: Dict[str, List[int]] = {}
         self._lens: Dict[str, int] = {}
+        # A window group's table is compact: entry 0 is logical block
+        # `_base[seq]` (absent: 0, as in every group without a window).
+        self._base: Dict[str, int] = {}
+        self.window_blocks_released = 0
+        # Blocks in use and blocks there are, summed over paged steps.
+        self.block_steps_in_use = 0
+        self.block_steps = 0
+        self._groups: Dict[str, "KVCacheManager"] = {
+            name: KVCacheManager(
+                g["num_blocks"], block_size, tuple(g["kv_shape"]), dtype,
+                array_ns, window=g.get("window"))
+            for name, g in (groups or {}).items()}
         # Reentrant: `with_pool` callbacks legitimately read tables /
         # lengths through the public accessors while the lock is held.
         self._lock = threading.RLock()
@@ -292,6 +351,55 @@ class KVCacheManager:
         with self._lock:
             return self._refs.get(block, 0)
 
+    def _first_live(self, target_tokens: int) -> int:
+        """The first logical block that holds a position the query at
+        ``target_tokens - 1`` sees (0 without a window)."""
+        if self.window is None:
+            return 0
+        return max(0, target_tokens - self.window) // self.block_size
+
+    def _trim_locked(self, seq_id: str, target_tokens: int) -> int:
+        """Give back the blocks of `seq_id` every position of which has
+        left the window of the query at ``target_tokens - 1``."""
+        table = self._tables.get(seq_id)
+        if self.window is None or not table:
+            return 0
+        base = self._base.get(seq_id, 0)
+        drop = min(len(table), self._first_live(target_tokens) - base)
+        if drop <= 0:
+            return 0
+        for b in table[:drop]:
+            self._release_locked(b)
+        del table[:drop]
+        self._base[seq_id] = base + drop      # an emptied table's base
+        #                                       is set by `_commit`
+        self.window_blocks_released += drop
+        return drop
+
+    def release_expired(self, seq_id: str, target_tokens: int) -> int:
+        """Before the step that makes `seq_id` `target_tokens` long:
+        every window group gives back the blocks that step no longer
+        sees. Returns how many went back to a free list."""
+        with self._lock:
+            return self._trim_locked(seq_id, target_tokens) + sum(
+                g.release_expired(seq_id, target_tokens)
+                for g in self._groups.values())
+
+    def _shortfall(self, seq_id: str, target_tokens: int,
+                   writable_from: Optional[int]) -> Tuple[int, int]:
+        """(blocks missing, blocks to grow by) for this group alone, the
+        blocks a trim would give back counted as free."""
+        if self.window is None:
+            grow, cow = self._plan(seq_id, target_tokens, writable_from)
+            return grow + cow - len(self._free), grow
+        table = self._tables.get(seq_id, ())
+        first = self._first_live(target_tokens)
+        base = self._base.get(seq_id, first) if table else first
+        drop = max(0, min(len(table), first - base))
+        grow = max(0, self.blocks_for(target_tokens) - max(base, first)
+                   - (len(table) - drop))
+        return grow - drop - len(self._free), grow
+
     def _plan(self, seq_id: str, target_tokens: int,
               writable_from: Optional[int]) -> Tuple[int, int]:
         """(growth deficit, COW copies) to cover `target_tokens` with
@@ -312,14 +420,25 @@ class KVCacheManager:
         """Would `allocate(...)` succeed right now — counting blocks a
         reclaim could evict as available?"""
         with self._lock:
-            if self._lacks_slot(seq_id):
+            if self._lacks_slot(seq_id) or self._groups_short(
+                    seq_id, target_tokens):
                 return False
-            grow, cow = self._plan(seq_id, target_tokens, writable_from)
-            shortfall = grow + cow - len(self._free)
+            shortfall, _ = self._shortfall(seq_id, target_tokens,
+                                           writable_from)
         if shortfall <= 0:
             return True
         return (self._evictable is not None
                 and self._evictable() >= shortfall)
+
+    def _members(self) -> Dict[str, "KVCacheManager"]:
+        """Every layer group by name, this one (`global`) first."""
+        return {self.GLOBAL: self, **self._groups}
+
+    def _groups_short(self, seq_id: str, target_tokens: int) -> bool:
+        """A further group lacks blocks for `target_tokens` (no reclaim
+        helps: nothing of such a group is indexed)."""
+        return any(g._shortfall(seq_id, target_tokens, None)[0] > 0
+                   for g in self._groups.values())
 
     def _lacks_slot(self, seq_id: str) -> bool:
         """A sequence that has no state slot yet, and none is free (no
@@ -346,12 +465,19 @@ class KVCacheManager:
             with self._lock:
                 if self._lacks_slot(seq_id):
                     return False
-                grow, cow = self._plan(seq_id, target_tokens,
-                                       writable_from)
-                shortfall = grow + cow - len(self._free)
+                plans = {}
+                if self._groups:    # not on the one-group step's path
+                    plans = {g: g._shortfall(seq_id, target_tokens, None)
+                             for g in self._groups.values()}
+                    if any(short > 0 for short, _ in plans.values()):
+                        return False
+                shortfall, grow = self._shortfall(seq_id, target_tokens,
+                                                  writable_from)
                 if shortfall <= 0:
                     self._commit(seq_id, target_tokens, grow,
                                  writable_from)
+                    for g, (_, g_grow) in plans.items():
+                        g._commit(seq_id, target_tokens, g_grow, None)
                     return True
             # Block pressure: evict cold indexed prefixes (the
             # reclaimer calls `release`, which takes the lock — so the
@@ -364,7 +490,11 @@ class KVCacheManager:
 
     def _commit(self, seq_id: str, target_tokens: int, grow: int,
                 writable_from: Optional[int]) -> None:
+        if self.window is not None:
+            self._trim_locked(seq_id, target_tokens)
         table = self._tables.setdefault(seq_id, [])
+        if self.window is not None and not table:
+            self._base[seq_id] = self._first_live(target_tokens)
         if self._state is not None and seq_id not in self._slots:
             self._slots[seq_id] = self._free_slots.pop()
         for _ in range(grow):
@@ -390,6 +520,11 @@ class KVCacheManager:
                 raise ValueError(
                     "blocks of KV do not restore a sequence's state: "
                     "nothing is adopted beside a state pool")
+            if self._groups or self.window is not None:
+                raise ValueError(
+                    "an adopted prefix would need the window layers' "
+                    "rows at its end: nothing is adopted beside a "
+                    "window group")
             if self._tables.get(seq_id):
                 raise ValueError(
                     f"adopt requires an empty table for {seq_id!r}")
@@ -437,6 +572,7 @@ class KVCacheManager:
         with self._lock:
             table = self._tables.pop(seq_id, [])
             self._lens.pop(seq_id, None)
+            self._base.pop(seq_id, None)
             slot = self._slots.pop(seq_id, None)
             if slot is not None:
                 self._free_slots.append(slot)
@@ -444,7 +580,8 @@ class KVCacheManager:
             for b in reversed(table):
                 if self._release_locked(b):
                     freed += 1
-            return freed
+            return freed + sum(g.free(seq_id)
+                               for g in self._groups.values())
 
     # -- cross-replica shipping (PR 19) --------------------------------
     def read_block(self, block: int) -> np.ndarray:
@@ -489,12 +626,14 @@ class KVCacheManager:
     # -- storage -------------------------------------------------------
     def _slot(self, seq_id: str, pos: int) -> Tuple[int, int]:
         table = self._tables.get(seq_id)
-        if table is None or pos // self.block_size >= len(table):
+        idx = pos // self.block_size - self._base.get(seq_id, 0)
+        if table is None or not 0 <= idx < len(table):
             raise IndexError(
                 f"position {pos} of sequence {seq_id!r} has no allocated "
                 f"block (table covers "
-                f"{len(table or ()) * self.block_size} tokens)")
-        return pos // self.block_size, pos % self.block_size
+                f"{len(table or ()) * self.block_size} tokens from block "
+                f"{self._base.get(seq_id, 0)})")
+        return idx, pos % self.block_size
 
     def _privatize_locked(self, seq_id: str, block_idx: int) -> int:
         """The COW fault: copy a shared block into a fresh private one
@@ -579,14 +718,20 @@ class KVCacheManager:
         n = len(values)
         state = getattr(values, "state", None)
         with self._lock:
+            for name, g in self._groups.items():
+                g.write_range(seq_id, start, values.groups[name])
             if self._device and _device_rows(values) is not None:
                 self.range_writes_device += 1
             else:
                 self.range_writes_host += 1
+            # A window group stores only the rows it still holds blocks
+            # for: those before its table's first block are skipped.
+            skip = min(n, max(0, self._base.get(seq_id, 0)
+                              * self.block_size - start))
             if self._ns is np:
                 values = np.asarray(values)
-                pos = start
-                written = 0
+                pos = start + skip
+                written = skip
                 while written < n:
                     block, off = self._writable_block(seq_id, pos)
                     take = min(self.block_size - off, n - written)
@@ -595,10 +740,10 @@ class KVCacheManager:
                     written += take
                     pos += take
             elif n:
-                blocks = np.empty((n,), np.int32)
-                offs = np.empty((n,), np.int32)
-                pos = start
-                i = 0
+                blocks = np.full((n,), self.num_blocks, np.int32)
+                offs = np.zeros((n,), np.int32)
+                pos = start + skip
+                i = skip
                 while i < n:
                     block, off = self._writable_block(seq_id, pos)
                     take = min(self.block_size - off, n - i)
@@ -676,12 +821,10 @@ class KVCacheManager:
         new_pool, new_state)``: both pools donated to the one step and
         re-bound here."""
         with self._lock:
-            blocks: List[int] = []
-            offs: List[int] = []
-            for seq_id, pos in entries:
-                blk, off = self._writable_block(seq_id, pos)
-                blocks.append(blk)
-                offs.append(off)
+            self._count_block_steps()
+            if self._groups:
+                return self._grouped_step(entries, fn)
+            blocks, offs = self._write_slots(entries)
             if self._state is None:
                 result, new_pool = fn(self._buffer, blocks, offs)
             else:
@@ -690,13 +833,65 @@ class KVCacheManager:
                     self._buffer, blocks, offs, self._state, slots)
                 self.state_slot_steps_in_use += len(self._slots)
                 self.state_slot_steps += self.state_slots
-            self._buffer = new_pool
-            if self._device:
-                self.pool_updates += 1
-            for seq_id, pos in entries:
-                self._lens[seq_id] = max(
-                    self._lens.get(seq_id, 0), pos + 1)
+            self._rebind_after_step(new_pool, entries)
             return result
+
+    def _count_block_steps(self) -> None:
+        self.block_steps_in_use += self.num_blocks - len(self._free)
+        self.block_steps += self.num_blocks
+
+    def _write_slots(self, entries) -> Tuple[List[int], List[int]]:
+        blocks: List[int] = []
+        offs: List[int] = []
+        for seq_id, pos in entries:
+            blk, off = self._writable_block(seq_id, pos)
+            blocks.append(blk)
+            offs.append(off)
+        return blocks, offs
+
+    def _rebind_after_step(self, new_pool, entries) -> None:
+        self._buffer = new_pool
+        if self._device:
+            self.pool_updates += 1
+        for seq_id, pos in entries:
+            self._lens[seq_id] = max(self._lens.get(seq_id, 0), pos + 1)
+
+    def _grouped_step(self, entries, fn):
+        """`paged_step` over every layer group: ``fn(pools, blocks,
+        offs)`` with a dict a group each, returning ``(result,
+        new_pools)``; every pool was donated and is re-bound."""
+        members = self._members()
+        pools, blocks, offs = {}, {}, {}
+        for name, g in members.items():
+            if g is not self:
+                g._count_block_steps()
+            pools[name] = g._buffer
+            blocks[name], offs[name] = g._write_slots(entries)
+        result, new_pools = fn(pools, blocks, offs)
+        for name, g in members.items():
+            g._rebind_after_step(new_pools[name], entries)
+        return result
+
+    def step_tables(self, seq_id: str):
+        """What a decode step reads `seq_id` through: its block table,
+        or with layer groups ``{group: (base, table)}``, `base` the
+        logical block the table's first entry is (0 in the global
+        group, the first block the window still reaches in a window
+        group)."""
+        with self._lock:
+            if not self._groups:
+                return list(self._tables.get(seq_id, ()))
+            return {name: (g._base.get(seq_id, 0),
+                           list(g._tables.get(seq_id, ())))
+                    for name, g in self._members().items()}
+
+    @property
+    def grouped(self) -> bool:
+        return bool(self._groups)
+
+    def group(self, name: str) -> "KVCacheManager":
+        """The manager of one layer group (`global`: this one)."""
+        return self if name == self.GLOBAL else self._groups[name]
 
     def gather(self, seq_id: str, length: Optional[int] = None):
         """Contiguous `[length, *kv_shape]` view of a sequence's cache,
@@ -746,11 +941,26 @@ class KVCacheManager:
         return sum(int(np.prod(pool.shape)) * np.dtype(pool.dtype).itemsize
                    for pool in self._state.values())
 
+    def _group_stats(self) -> Dict[str, float]:
+        with self._lock:
+            return {
+                "blocks": self.num_blocks,
+                "blocks_in_use": self.num_blocks - len(self._free),
+                "block_steps_in_use": self.block_steps_in_use,
+                "block_steps": self.block_steps,
+                "window": self.window or 0,
+                "window_blocks_released": self.window_blocks_released,
+                "pool_bytes": self.pool_bytes,
+            }
+
     def stats(self) -> Dict[str, float]:
         with self._lock:
             used = self.num_blocks - len(self._free)
             shared = sum(1 for n in self._refs.values() if n > 1)
+            groups = {name: g._group_stats()
+                      for name, g in self._members().items()}
             return {
+                "groups": groups,
                 "num_blocks": self.num_blocks,
                 "block_size": self.block_size,
                 "used_blocks": used,

@@ -44,7 +44,21 @@ the engine builds no prefix index over a model that declares state,
 never calls its ``prefill_paged`` with an offset, and recomputes a
 preempted row by ``prefill``, as for any model.
 
-Three implementations:
+A model may declare a fifth, ``kv_groups``: further layer groups of
+its KV beside the one `kv_token_shape` describes, ``{name: {"kv_shape":
+..., "window": ...}}`` (layers of sliding-window attention keep a
+window's rows, in a pool of their own whose blocks the cache manager
+gives back as they leave the window: `kv_cache.py`). The contract then
+changes in three places, for that model alone: the KV result of
+``prefill`` carries ``groups``, a `PromptKV` a further group
+(`laguna_model.PromptGroups`); ``decode_paged`` takes ``pool``,
+``write_blocks`` and ``write_offs`` as dicts a group (the first group is
+``"global"``) and ``block_tables[i]`` as ``{group: (base, table)}``, a
+window group's table compact from logical block ``base``, and returns
+``(step, new_pools)``; and the engine builds no prefix index (an adopted
+prefix would need the window layers' rows at its end).
+
+Four implementations:
 
 - **TinyLM** — a deterministic pure-numpy model whose next token is a
   fixed function of the *cached* KV contents, so every block-table bug
@@ -63,6 +77,13 @@ Three implementations:
   per-sequence state in the others, a dropless expert layer that holds a
   range of the routed experts; the same buckets, packed upload and
   sampled-ids return.
+- **LagunaEngineModel** (`laguna_model.py`) — the window-and-global
+  sparse decoder of `models/laguna.py`: full and sliding-window layers
+  of different head counts over two layer groups of the cache, a gate a
+  head, two rotary schemes, a dense first layer and held experts behind
+  a softmax router. What it shares with the hybrid model (norm, product
+  helper, expert layer, counters, the host side of a call) is
+  `sparse_model.SparseEngineModel`.
 """
 
 from __future__ import annotations

@@ -124,6 +124,10 @@ class EngineConfig:
     policy: str = "continuous"     # "continuous" | "static"
     prefix_sharing: bool = True    # adopt cached prompt prefixes
     replica_tag: str = ""          # fleet identity (metrics/digests)
+    # Blocks of each further layer group of a model that declares some
+    # (`kv_groups`, e.g. {"window": 640}); `num_blocks` is the global
+    # group's.
+    group_blocks: Optional[Dict[str, int]] = None
     # Selects nothing: the paged step is the engine. The name stays, to
     # accept `True`, because the benchmark's cell files pass it; it goes
     # with the next `benchmark` issue (ROADMAP S6).
@@ -304,19 +308,33 @@ class InferenceEngine:
         # rows (`state_shapes`): the cache then holds a slot a sequence,
         # one for each row of the batch.
         state_shapes = getattr(model, "state_shapes", None)
+        # A model may declare further layer groups (`kv_groups`: layers
+        # of sliding-window attention keep a window's rows, in a pool of
+        # their own); the config says how many blocks each gets.
+        kv_groups = getattr(model, "kv_groups", None) or {}
+        sizes = self.config.group_blocks or {}
+        if set(kv_groups) != set(sizes):
+            raise ValueError(
+                f"the model's layer groups {sorted(kv_groups)} and the "
+                f"config's group_blocks {sorted(sizes)} differ")
         self.cache = KVCacheManager(
             self.config.num_blocks, self.config.block_size,
             kv_shape=tuple(getattr(model, "kv_token_shape", ())),
             dtype=getattr(model, "kv_dtype", np.float32),
             array_ns=getattr(model, "kv_pool_ns", None),
             state_shapes=state_shapes,
-            state_slots=self.config.max_batch_size if state_shapes else 0)
+            state_slots=self.config.max_batch_size if state_shapes else 0,
+            groups={name: dict(group, num_blocks=sizes[name])
+                    for name, group in kv_groups.items()})
         self.prefix_index: Optional[PrefixIndex] = None
         # Adopting blocks of KV restores a prefix only where KV is all a
         # sequence keeps: over a model with per-sequence state no index
         # is built, nothing is adopted, exported or imported, and every
-        # prompt is prefilled whole.
-        if self.config.prefix_sharing and not state_shapes:
+        # prompt is prefilled whole. Nor over a model with a window
+        # group: an adopted prefix would need the window layers' rows at
+        # its end, which the blocks of the global group do not hold.
+        if (self.config.prefix_sharing and not state_shapes
+                and not kv_groups):
             self.prefix_index = PrefixIndex(self.cache,
                                             self.config.block_size)
             self.cache.set_reclaimer(self.prefix_index.evict,
@@ -348,7 +366,8 @@ class InferenceEngine:
         # phase: `thread_cpu_s`, `queue_wait_s`, `stream_wake_*`.
         self._clocks: Dict[str, float] = dict.fromkeys(
             _LEGACY_CLOCKS + tuple(f"{p}_s" for p in _STEP_PHASES)
-            + ("park_s", "other_s", "step_s", "thread_cpu_s",
+            + ("window_release_s", "park_s", "other_s", "step_s",
+               "thread_cpu_s",
                "queue_wait_s", "stream_wake_s", "stream_wake_tokens"), 0.0)
         self._cpu_thread: Optional[int] = None
         self._cpu_at = 0.0
@@ -538,6 +557,18 @@ class InferenceEngine:
             self._update_gauges()
             return None
         with flight.span("engine", "capacity", None, clocks, "capacity_s"):
+            if self.cache.grouped:
+                # Blocks every position of which has left a window
+                # group's window go back to its free list before the
+                # tables grow. A span and a clock of its own inside
+                # `capacity` (whose clock holds it too: the phases stay
+                # a partition), so that its host time has a name in the
+                # idle attribution.
+                with flight.span("engine", "window_release", None, clocks,
+                                 "window_release_s"):
+                    for seq in batch:
+                        self.cache.release_expired(seq.seq_id,
+                                                   len(seq.all_tokens))
             self._ensure_capacity()
         with self._lock:
             batch = list(self._running)
@@ -759,7 +790,7 @@ class InferenceEngine:
         with flight.span("engine", "tables", b, clocks, "kv_gather_s"):
             lasts = [s.all_tokens[-1] for s in batch]
             poss = [len(s.all_tokens) - 1 for s in batch]
-            tables = [self.cache.block_table(s.seq_id) for s in batch]
+            tables = [self.cache.step_tables(s.seq_id) for s in batch]
             entries = [(s.seq_id, poss[i]) for i, s in enumerate(batch)]
         with flight.span("engine", "model_step", b, clocks,
                          "model_step_s"):
@@ -963,12 +994,23 @@ class InferenceEngine:
         `state_slot_steps_in_use` and `state_slot_steps` sum, over paged
         steps, the state slots in use and the slots there are (a model
         with per-sequence state; 0 otherwise); `cache` has the gauges
-        `state_slots`, `state_slots_in_use` and `state_bytes`."""
+        `state_slots`, `state_slots_in_use` and `state_bytes`.
+        `kv_<group>_block_steps_in_use` / `kv_<group>_block_steps` sum,
+        over paged steps, a layer group's blocks in use and the blocks
+        it has, and `kv_<group>_window_blocks_released` counts the blocks
+        a window group gave back when they left the window
+        (`cache["groups"]` has the gauges); `decode_kv_pages_read_global`
+        and `decode_kv_pages_read_window` split `decode_kv_pages_read`
+        for a model with a window group (0 otherwise);
+        `window_release_s` is the host time of that release (the span
+        `engine.window_release`, inside `engine.capacity`, whose
+        `phase.capacity_s` holds it too)."""
         with self._lock:
             running = len(self._running)
             waiting = len(self._waiting)
         ttfts = sorted(self._ttfts)
         clocks = self._clocks
+        cache = self.cache.stats()
         return {
             "steps": self.steps,
             "prefills": self.prefills,
@@ -982,7 +1024,7 @@ class InferenceEngine:
             "finished": self.finished,
             "running": running,
             "waiting": waiting,
-            "cache": self.cache.stats(),
+            "cache": cache,
             "prefix_index": (self.prefix_index.stats()
                              if self.prefix_index is not None else None),
             "paged": True,
@@ -1004,6 +1046,11 @@ class InferenceEngine:
                 self.model, "moe_max_expert_load", 0),
             "state_slot_steps_in_use": self.cache.state_slot_steps_in_use,
             "state_slot_steps": self.cache.state_slot_steps,
+            "decode_kv_pages_read_global": getattr(
+                self.model, "decode_kv_pages_read_global", 0),
+            "decode_kv_pages_read_window": getattr(
+                self.model, "decode_kv_pages_read_window", 0),
+            **self._group_counters(cache["groups"]),
             "jit_bucket_evictions": getattr(
                 self.model, "jit_cache_evictions", 0),
             "prefill_s": round(self.prefill_s, 6),
@@ -1014,12 +1061,26 @@ class InferenceEngine:
                             if ttfts else None),
             **{f"phase.{name}_s": seconds
                for name, seconds in self.phase_seconds().items()},
+            "window_release_s": clocks["window_release_s"],
             "loop_s": clocks["step_s"] + clocks["park_s"],
             "thread_cpu_s": clocks["thread_cpu_s"],
             "queue_wait_s": clocks["queue_wait_s"],
             "stream_wake_s": clocks["stream_wake_s"],
             "stream_wake_tokens": int(clocks["stream_wake_tokens"]),
         }
+
+    @staticmethod
+    def _group_counters(groups: Dict[str, dict]) -> Dict[str, int]:
+        """The cache's counters a layer group, flat (`kv_<group>_...`),
+        so that two snapshots subtract: blocks in use and blocks there
+        are, summed over paged steps, and the blocks a window group gave
+        back. A model without groups has the `global` group alone."""
+        out = {}
+        for name, g in groups.items():
+            for key in ("block_steps_in_use", "block_steps",
+                        "window_blocks_released"):
+                out[f"kv_{name}_{key}"] = g[key]
+        return out
 
     def _update_gauges(self) -> None:
         with flight.span("engine", "gauges", None, self._clocks,

@@ -1,0 +1,175 @@
+"""What the engine models of the sparse decoders share
+(`hybrid_model.py`, `laguna_model.py`): the norm, the product helper, the
+expert layer with its three counts, the prefill's bucket and dispatch,
+the decode step's one upload and one fetch, and the counters the engine's
+`stats()` reads. A model keeps its own layers, its packed row and its
+pools."""
+
+from __future__ import annotations
+
+from typing import Dict, Sequence
+
+import numpy as np
+
+from ray_tpu.core import flight
+from ray_tpu.serve.engine.model import DecodeStep, _JitLRU, _next_pow2
+
+
+class SparseEngineModel:
+    """Base of an engine model over seeded weights `params` and a config
+    `cfg` with `vocab_size`, `norm_eps`, `dtype`, `top_k`,
+    `routed_scaling`, `experts_held` (and `router_scoring`, where the
+    router does not score by sigmoid). A subclass builds its jitted
+    programs (`_build_prefill(s_pad)`) and packs its decode row."""
+
+    def __init__(self, params, cfg, jit_cache_cap: int = 32):
+        import jax
+        import jax.numpy as jnp
+
+        self._params = params
+        self._cfg = cfg
+        self.vocab_size = cfg.vocab_size
+        self.eos_token = 1
+        self.kv_dtype = jnp.dtype(cfg.dtype)
+        self._prefill_jit = _JitLRU(jit_cache_cap)
+        self._decode_paged_jit = _JitLRU(jit_cache_cap)
+        self.prefill_calls = 0
+        self.prefill_tokens = 0
+        self.decode_calls = 0
+        self.jit_compiles = 0
+        # As `TransformerEngineModel`'s: what a decode step moves across
+        # the host boundary, and how it reads the KV pool.
+        self.decode_h2d_arrays = 0
+        self.decode_d2h_bytes = 0
+        self.decode_attn_inplace_steps = 0
+        self.decode_kv_pages_read = 0
+        # The expert layers' counts over decode steps, summed over
+        # layers, computed inside the step and fetched with its ids:
+        # (token, expert) pairs on held experts; (layer, expert) pairs
+        # with at least one token; the largest load of a held expert.
+        self.moe_local_assignments = 0
+        self.moe_expert_touches = 0
+        self.moe_max_expert_load = 0
+        self.phase: Dict[str, float] = dict.fromkeys(
+            ("prefill_prep_s", "prefill_dispatch_s", "prefill_wait_s",
+             "prefill_kv_d2h_s", "decode_prep_s", "decode_dispatch_s",
+             "decode_wait_s"), 0.0)
+        self._jnp = jnp
+        self._tree_leaves = jax.tree_util.tree_leaves
+
+    @property
+    def kv_pool_ns(self):
+        return self._jnp
+
+    @property
+    def jit_cache_evictions(self) -> int:
+        return (self._prefill_jit.evictions
+                + self._decode_paged_jit.evictions)
+
+    # -- shared math ---------------------------------------------------
+    def _norm(self, x, scale):
+        import jax
+        import jax.numpy as jnp
+
+        var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+        return x * jax.lax.rsqrt(var + self._cfg.norm_eps) * scale
+
+    @staticmethod
+    def _mm(y, w):
+        """Both operands in the weights' dtype, float32 out."""
+        import jax.numpy as jnp
+
+        return jnp.dot(y.astype(w.dtype), w,
+                       preferred_element_type=jnp.float32)
+
+    def _gated_ffn(self, y, gate, up, down):
+        """``W_down(silu(W_gate y) * W_up y)``."""
+        import jax
+
+        return self._mm(jax.nn.silu(self._mm(y, gate)) * self._mm(y, up),
+                        down)
+
+    def _experts(self, x, ln2, mp, valid):
+        """The expert layer's residual add; returns the new `x` and the
+        layer's three counts."""
+        import jax
+        import jax.numpy as jnp
+
+        from ray_tpu.ops.experts import held_experts_ffn, route
+
+        cfg = self._cfg
+        y = self._norm(x, ln2)
+        with jax.named_scope("moe_route"):
+            experts, weights = route(
+                y, mp["router"], mp.get("select_bias"), cfg.top_k,
+                cfg.routed_scaling,
+                getattr(cfg, "router_scoring", "sigmoid"))
+        with jax.named_scope("moe_experts"):
+            routed, load = held_experts_ffn(
+                y, experts, weights, mp["w_gate"], mp["w_up"],
+                mp["w_down"], cfg.experts_held, valid)
+            shared = self._gated_ffn(y, mp["shared_gate"], mp["shared_up"],
+                                     mp["shared_down"])
+        counts = jnp.stack([jnp.sum(load), jnp.sum(load > 0),
+                            jnp.max(load)]).astype(jnp.int32)
+        return x + shared + routed, counts
+
+    # -- the host side of the two calls --------------------------------
+    def _run_prefill(self, tokens: Sequence[int]):
+        """The prompt through its pow2 length bucket's program. Returns
+        the host logits, what else the program returned, and the
+        prompt's length."""
+        jnp, phase = self._jnp, self.phase
+        self.prefill_calls += 1
+        n = len(tokens)
+        self.prefill_tokens += n
+        with flight.span("model", "prefill.prep", None, phase,
+                         "prefill_prep_s"):
+            s_pad = _next_pow2(max(n, 8))
+            fn = self._prefill_jit.get(s_pad)
+            if fn is None:
+                fn = self._prefill_jit[s_pad] = self._build_prefill(s_pad)
+            padded = np.zeros((s_pad,), np.int32)
+            padded[:n] = np.asarray(tokens, np.int32)
+            args = (jnp.asarray(padded), jnp.int32(n))
+        with flight.span("model", "prefill.dispatch", None, phase,
+                         "prefill_dispatch_s"):
+            logits, *rest = fn(self._params, *args)
+        with flight.span("model", "prefill.logits_wait", None, phase,
+                         "prefill_wait_s"):
+            logits = np.asarray(logits)
+        return logits, rest, n
+
+    def _run_decode(self, fn, args, b: int, b_pad: int):
+        """One dispatch of a decode bucket's program `fn` over `args`
+        (the packed host array among them: the step's one upload).
+        Fetches its int32 result (``[b_pad + 3]``: greedy ids, then the
+        step's three expert counters), counts both, and returns the
+        `DecodeStep` and what else the program returned (the pools)."""
+        phase = self.phase
+        self.decode_h2d_arrays += sum(
+            isinstance(leaf, np.ndarray)
+            for leaf in self._tree_leaves(args))
+        with flight.span("model", "decode.dispatch", None, phase,
+                         "decode_dispatch_s"):
+            out, logits, *rest = fn(*args)
+        with flight.span("model", "decode.logits_wait", None, phase,
+                         "decode_wait_s"):
+            out = np.asarray(out)
+            self.decode_d2h_bytes += out.nbytes
+        self.moe_local_assignments += int(out[b_pad])
+        self.moe_expert_touches += int(out[b_pad + 1])
+        self.moe_max_expert_load += int(out[b_pad + 2])
+        return DecodeStep(out[:b], logits, self), rest
+
+    def prefill_paged(self, tokens: Sequence[int], pool,
+                      block_table: Sequence[int], prefix_len: int,
+                      block_size: int):
+        """The engine adopts no prefix over these models (a state, a
+        window group), so the offset is always 0 and this is
+        `prefill`."""
+        if prefix_len:
+            raise ValueError(
+                "a prefix's KV blocks do not restore what this model "
+                "keeps a sequence: it prefills a prompt whole")
+        return self.prefill(tokens)

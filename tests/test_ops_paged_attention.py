@@ -480,3 +480,114 @@ def test_flash_kernels_compile_for_v5e_at_the_chosen_tiles(
                    f"flash_mha_bwd_dkv_block_k_{tiles.block_k_dkv}_",
                    f"flash_mha_bwd_dq_block_q_{tiles.block_q_dq}_"):
         assert kernel in text, kernel
+
+
+# ---------------------------------------------------------------------------
+# the window-and-global model's kernels (`laguna-s-2.1`), compiled for the
+# chip at its published widths: the windowed paged decode kernel at groups
+# of 9 query heads a key head and the global one at groups of 6, the
+# prefill's windowed grouped flash forward, and the whole decode step.
+# ---------------------------------------------------------------------------
+GLOBAL_POOL = (8192, 16, 3, 2, 8, 128)      # 1.6 GB in bf16
+WINDOW_POOL = (640, 16, 9, 2, 8, 128)       # 0.38 GB
+
+
+@pytest.mark.parametrize("heads, pool, window, nb_pad", [
+    (48, GLOBAL_POOL, None, 512), (72, WINDOW_POOL, 512, 33)],
+    ids=["global_group6", "window_group9"])
+def test_windowed_grouped_kernel_compiles_for_v5e_over_its_group_pool(
+        one_chip, no_compile_cache, heads, pool, window, nb_pad):
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import paged_attention as pa
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def call(q, k, v, pool, tables, positions, layer, starts):
+        return pa.paged_decode_attention_kernel(
+            q, k, v, pool, tables, positions, layer, window, starts)
+
+    compiled = jax.jit(call).lower(
+        spec((16, heads, 128)), spec((16, 8, 128), jnp.bfloat16),
+        spec((16, 8, 128), jnp.bfloat16), spec(pool, jnp.bfloat16),
+        spec((16, nb_pad), jnp.int32), spec((16,), jnp.int32),
+        spec((), jnp.int32), spec((16,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # The device trace tells a window layer's calls from a global one's.
+    assert ("paged_window_decode_attention" in text) == (window is not None)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("heads, window, seq", [
+    (48, None, 4096), (72, 512, 4096), (72, 512, 1024)],
+    ids=["causal_4096", "window_4096", "window_1024"])
+def test_prefill_flash_forward_compiles_for_v5e(one_chip, no_compile_cache,
+                                                heads, window, seq):
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.flash_attention import prefill_attention_fwd
+
+    def spec(h):
+        return jax.ShapeDtypeStruct((h, seq, 128), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    text = jax.jit(lambda q, k, v: prefill_attention_fwd(
+        q, k, v, window)).lower(spec(heads), spec(8),
+                                spec(8)).compile().as_text()
+    assert ("flash_prefill_fwd_causal" if window is None
+            else f"flash_prefill_fwd_window_{window}") in text
+    assert "flash_mha_fwd" not in text          # the train step's name
+
+
+def test_laguna_decode_step_compiles_for_v5e_with_both_pools_in_place(
+        one_chip, no_compile_cache, monkeypatch):
+    """The whole (16, 512) decode step at the published widths, the
+    kernel steered on: both groups' pools are aliased to the outputs,
+    twelve kernel calls (3 global, 9 window), and beside its arguments
+    the program holds tens of megabytes: no copy of a pool or of a
+    layer's expert matrices."""
+    import json
+    import os
+
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks.harness import manifest
+    from ray_tpu.models.laguna import init_params
+    from ray_tpu.ops import paged_attention as pa
+    from ray_tpu.serve.engine import LagunaEngineModel
+
+    monkeypatch.setattr(pa, "kernel_eligible", lambda *heads: True)
+    family = manifest.load_family("laguna")
+    with open(os.path.join(manifest.ROOT, "benchmarks", "configs",
+                           "laguna-s-2.1.json")) as f:
+        cfg = family.model_config(family.widths(json.load(f)))
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(on_chip, jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    weight_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                       for a in jax.tree_util.tree_leaves(params))
+    assert 8.6e9 < weight_bytes < 8.7e9
+    model = LagunaEngineModel(params, cfg, max_batch_size=16)
+    assert (8192, 16) + model.kv_token_shape == GLOBAL_POOL
+    assert (640, 16) + model.kv_groups["window"]["kv_shape"] == WINDOW_POOL
+    assert model.window_table_blocks(16) == 33
+    pools = {"global": jax.ShapeDtypeStruct(GLOBAL_POOL, jnp.bfloat16,
+                                            sharding=one_chip),
+             "window": jax.ShapeDtypeStruct(WINDOW_POOL, jnp.bfloat16,
+                                            sharding=one_chip)}
+    compiled = model._build_decode_paged(16, 512, 16).lower(
+        pools, params, jax.ShapeDtypeStruct((16, 6 + 512 + 33), jnp.int32,
+                                            sharding=one_chip)).compile()
+    memory, text = compiled.memory_analysis(), compiled.as_text()
+    assert text.count("paged_window_decode_attention") >= 9
+    both = (int(np.prod(GLOBAL_POOL)) + int(np.prod(WINDOW_POOL))) * 2
+    assert both <= memory.alias_size_in_bytes < 1.01 * both
+    assert memory.temp_size_in_bytes < 100e6
