@@ -16,18 +16,27 @@ Two bodies, one result:
 
 - `paged_decode_attention_kernel`: a Pallas TPU kernel (the pattern of
   `jax.experimental.pallas.ops.tpu.paged_attention`, for this pool's
-  layout). Grid ``(row, page)``; block
-  tables, positions and the layer index are scalar-prefetched, and the
-  pool's `BlockSpec` index map picks block ``tables[row, page]`` at
-  layer ``layer``: a ``[block_size, 2, n_heads, head_dim]`` slab,
-  ``block_size`` contiguous runs of the pool. Online softmax in float32,
-  started from the step's own key and value. With one key head a query
-  head the scores are products and sums on the vector unit; with grouped
-  heads a key head's page meets its group of query heads in two small
-  matrix products. Pages past a row's last
-  cached position are not fetched (the index map stays on the last live
-  page, and the pipeline skips a block index it already holds) and not
-  computed (`pl.when`).
+  layout). The grid is the rows; block tables, positions and the layer
+  index are scalar-prefetched and the pool stays in HBM. A grid step is
+  one row: it walks the table columns that hold a key the row sees in
+  *groups* of `pages_per_step` pages (by a page's bytes and the table's
+  width: 32 pages of 64 KB, 8 of 256 KB, never more than the table
+  names; a row's groups are of one size, so 33 live pages go as 17 and
+  16). A group's pages, ``[block_size, 2, n_kv_heads, head_dim]`` slabs
+  of the pool at layer ``layer``, are copied into one of two VMEM slabs
+  by one async copy a page while the group before it is attended from
+  the other; a row's last group starts the next row's first, so only a
+  call's first copy is waited for with nothing to do. Columns past a
+  row's last cached position (or left of a window) are neither fetched
+  nor stepped through: the walk runs to the row's own live count, not
+  to the table's width. Online softmax in float32, started from the
+  step's own key and value, `_BYTES_A_PASS` of pages an update (256 keys
+  of a bf16 pool). With one key head a query head the scores are
+  products and sums on the vector unit; with grouped heads the keys stay
+  as they lie (``keys x n_kv_heads`` rows): one matrix product gives
+  every query head against every row, the rows of other key heads are
+  masked beside the positions the row does not see, and a second product
+  takes the probabilities to the values.
 - `paged_decode_attention_xla`: plain XLA, ``pool[tables, :, layer]``
   for one layer and a masked softmax. The kernel's reference in the
   tests, and what runs off the chip and for head sizes the kernel does
@@ -39,15 +48,16 @@ j < window`` only (itself and the ``window - 1`` before it). Such a row's
 table is *compact*: column 0 names logical block ``starts[row]`` of the
 sequence, not block 0, because the cache manager has released the blocks
 before it (`kv_cache.py`, a layer group with a window), so a table is
-``ceil(window / block_size) + 1`` columns wide at any length. The page
-loop then starts at the first page that holds a live key: a column whose
-positions all lie left of the window or at or past ``position`` is
-neither fetched nor computed, and the first and last live pages are
-masked. The windowed kernel runs under a name of its own,
-``paged_window_decode_attention``, so that a device trace tells a window
-layer's calls from a global layer's. The model counts the live pages its
-steps' tables named a group (`decode_kv_pages_read_global`,
-`decode_kv_pages_read_window`).
+``ceil(window / block_size) + 1`` columns wide at any length. The walk
+then starts at the first column that holds a live key, and the first and
+last live pages are masked. The windowed kernel runs under a name of its
+own, ``paged_window_decode_attention``, so that a device trace tells a
+window layer's calls from a global layer's; every device operation the
+attention adds is the kernel's own call, under one of the two names.
+The model counts the live pages its steps' tables named a group
+(`decode_kv_pages_read_global`, `decode_kv_pages_read_window`) and the
+groups they were fetched in (`decode_kv_page_groups_read*`, by
+`page_groups`, the same arithmetic on the host).
 
 `paged_decode_attention` picks by what it can see (`kernel_eligible`:
 the backend, the head size and the head count), as
@@ -57,6 +67,7 @@ the backend, the head size and the head count), as
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -117,142 +128,297 @@ def paged_decode_attention_xla(q, k_new, v_new, pool, tables, positions,
     return out.reshape(b, h, hd)
 
 
-def _page_positions(starts_ref, positions_ref, block_size: int, window):
-    """Of this grid step: the row's position, the position of the page's
-    first slot, and whether the page holds a key the row sees.
-    `starts_ref` is None where every table begins at block 0."""
-    from jax.experimental import pallas as pl
-
-    row, page = pl.program_id(0), pl.program_id(1)
-    position = positions_ref[row]
-    first = page * block_size
-    if starts_ref is not None:
-        first += starts_ref[row] * block_size
-    live = first < position
-    if window is not None:
-        live &= first + block_size > position - window + 1
-    return position, first, live
+# Two slabs of a group's pages may take this much VMEM, and one pass of
+# the body this many bytes of pages (`pages_per_step`).
+_VMEM_FOR_PAGES = 4 << 20
+_BYTES_A_PASS = 1 << 20
 
 
-def _seen(at, position, window):
-    keep = at < position
+def _pow2_at_most(n: int) -> int:
+    return 1 << (max(int(n), 1).bit_length() - 1)
+
+
+def pages_per_step(page_bytes: int, table_width: int) -> int:
+    """How many pages the kernel brings into VMEM together (a *group*),
+    from what it can see: a page's bytes in the pool (``block_size x 2 x
+    n_kv_heads x head_dim x itemsize``: 64 KB in the two sparse models'
+    bf16 pools, 256 KB in `olmo-1b`'s float32 one) and the table's width.
+    The largest power of two whose two slabs fit `_VMEM_FOR_PAGES` (32
+    pages of 64 KB, 8 of 256 KB), and no more than the table can name
+    (1, 2, 4 at short contexts). A row's ``n`` live pages then go in
+    ``ceil(n / pages)`` groups of one size: nothing is padded."""
+    most = _pow2_at_most(_VMEM_FOR_PAGES // (2 * page_bytes))
+    width = max(int(table_width), 1)
+    return min(most, 1 << (width - 1).bit_length())
+
+
+def pool_pages_per_step(pool, table_width: int) -> int:
+    """`pages_per_step` for a pool ``[N, bs, L, 2, Hkv, hd]`` as it is
+    held and a table of `table_width` columns."""
+    return pages_per_step(
+        pool.shape[1] * math.prod(pool.shape[3:]) * pool.dtype.itemsize,
+        table_width)
+
+
+def live_pages(position: int, block_size: int, window: int = None) -> int:
+    """The pages that hold a cached position the row at `position` sees:
+    ``[0, position)``, under a window ``[position - window + 1,
+    position)``."""
+    oldest = 0 if window is None else max(0, position - window + 1)
+    return -(-position // block_size) - oldest // block_size
+
+
+def page_groups(pool, table_width: int, positions, window: int = None
+                ) -> int:
+    """On the host: the groups the kernel fetches for a step's rows at
+    `positions` from `pool` through tables of `table_width` columns: a
+    row's live pages ÷ `pool_pages_per_step`, rounded up; a row with
+    nothing cached has none."""
+    pages = pool_pages_per_step(pool, table_width)
+    return sum(-(-live_pages(int(p), pool.shape[1], window) // pages)
+               for p in positions)
+
+
+def _pages_a_pass(pages: int, page_bytes: int) -> int:
+    """Of a group's pages, how many one pass of the body attends (one
+    online-softmax update): `_BYTES_A_PASS` of them where the group
+    holds as many (256 keys of the bf16 pools, 64 of the float32 one;
+    passes of half as many cost the global layers' call 40% more, of
+    twice as many nothing less: my chip run, PR 36), and a divisor of
+    the group. The body's code grows with a pass's keys, not with the
+    group."""
+    return math.gcd(pages, _pow2_at_most(_BYTES_A_PASS // page_bytes))
+
+
+def _seen(at, position, until, window):
+    """Keys at positions `at` that the query at `position` sees, short
+    of `until`: the end of the group's pages in the slab or `position`,
+    whichever comes first."""
+    keep = at < until
     if window is not None:
         keep &= position - at < window
     return keep
 
 
-def _kernel_body(tables_ref, positions_ref, layer_ref, starts_ref, q_ref,
-                 k_new_ref, v_new_ref, page_ref, o_ref, m_ref, l_ref,
-                 acc_ref, *, block_size: int, scale: float, window=None):
-    from jax.experimental import pallas as pl
-
-    del tables_ref, layer_ref      # the index maps' business
-    page = pl.program_id(1)
-    position, first, live = _page_positions(starts_ref, positions_ref,
-                                            block_size, window)
-    q = q_ref[...].astype(jnp.float32)                       # [H, hd]
-
-    @pl.when(page == 0)
-    def _start_from_own_token():
-        own = jnp.sum(q * k_new_ref[...].astype(jnp.float32), axis=-1,
-                      keepdims=True) * scale                 # [H, 1]
-        m_ref[...] = own
-        l_ref[...] = jnp.ones_like(l_ref)
-        acc_ref[...] = v_new_ref[...].astype(jnp.float32)
-
-    @pl.when(live)
-    def _attend_page():
-        keys = page_ref[:, 0].astype(jnp.float32)            # [bs, H, hd]
-        vals = page_ref[:, 1].astype(jnp.float32)
-        scores = jnp.sum(q[None] * keys, axis=-1,
-                         keepdims=True) * scale              # [bs, H, 1]
-        at = first + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
-        scores = jnp.where(_seen(at, position, window), scores, _NEG_INF)
-        m_prev = m_ref[...]
-        m_next = jnp.maximum(m_prev, jnp.max(scores, axis=0))
-        alpha = jnp.exp(m_prev - m_next)
-        p = jnp.exp(scores - m_next[None])                   # [bs, H, 1]
-        m_ref[...] = m_next
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=0)
-        acc_ref[...] = alpha * acc_ref[...] + jnp.sum(p * vals, axis=0)
-
-    @pl.when(page == pl.num_programs(1) - 1)
-    def _finish():
-        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
-
-
-def _grouped_kernel_body(tables_ref, positions_ref, layer_ref, starts_ref,
-                         q_ref, k_new_ref, v_new_ref, page_ref, o_ref,
-                         m_ref, l_ref, acc_ref, *, block_size: int,
-                         scale: float, group: int, window=None):
-    """`_kernel_body` for `group` query heads a key head: q, o and the
-    scratch are ``[H, ...]``, the step's own K/V and the page's
-    ``[Hkv, hd]``. A key head's ``[bs, hd]`` page meets its ``[group,
-    hd]`` queries in a matrix product, and the probabilities its values
-    in another."""
-    from jax.experimental import pallas as pl
-
-    del tables_ref, layer_ref
-    page = pl.program_id(1)
-    position, first, live = _page_positions(starts_ref, positions_ref,
-                                            block_size, window)
+def _start_from_own_token(q, k_new_ref, v_new_ref, m_ref, l_ref, acc_ref,
+                          scale: float):
+    """The running softmax starts from the step's own key and value."""
     f32 = jnp.float32
-    q = q_ref[...].astype(f32)                               # [H, hd]
-    n_kv = k_new_ref.shape[0]
-
-    def per_key_head(fn):
-        """``fn(j, rows j*group .. (j+1)*group of q)`` for every key
-        head, stacked back to ``[H, ...]``."""
-        return jnp.concatenate(
-            [fn(j, slice(j * group, (j + 1) * group))
-             for j in range(n_kv)], axis=0)
-
-    @pl.when(page == 0)
-    def _start_from_own_token():
-        k_own = k_new_ref[...].astype(f32)                   # [Hkv, hd]
-        v_own = v_new_ref[...].astype(f32)
-        m_ref[...] = per_key_head(lambda j, rows: jnp.sum(
-            q[rows] * k_own[j][None], axis=-1, keepdims=True)) * scale
-        l_ref[...] = jnp.ones_like(l_ref)
-        acc_ref[...] = per_key_head(lambda j, rows: jnp.broadcast_to(
-            v_own[j][None], (group, v_own.shape[-1])))
-
-    @pl.when(live)
-    def _attend_page():
-        kv = page_ref[...].astype(f32)               # [bs, 2, Hkv, hd]
-        scores = per_key_head(lambda j, rows: jax.lax.dot_general(
-            q[rows], kv[:, 0, j], (((1,), (1,)), ((), ())),
-            preferred_element_type=f32)) * scale             # [H, bs]
-        at = first + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-        scores = jnp.where(_seen(at, position, window), scores, _NEG_INF)
-        m_prev = m_ref[...]
-        m_next = jnp.maximum(m_prev, jnp.max(scores, axis=1,
-                                             keepdims=True))
-        alpha = jnp.exp(m_prev - m_next)
-        p = jnp.exp(scores - m_next)                         # [H, bs]
-        m_ref[...] = m_next
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = alpha * acc_ref[...] + per_key_head(
-            lambda j, rows: jnp.dot(p[rows], kv[:, 1, j],
-                                    preferred_element_type=f32))
-
-    @pl.when(page == pl.num_programs(1) - 1)
-    def _finish():
-        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+    h, n_kv = q.shape[0], k_new_ref.shape[0]
+    k_own = k_new_ref[...].astype(f32)                       # [Hkv, hd]
+    v_own = v_new_ref[...].astype(f32)
+    if h != n_kv:
+        # Query head i reads key head i // group.
+        group = h // n_kv
+        k_own, v_own = (jnp.concatenate(
+            [jnp.broadcast_to(x[j][None], (group, x.shape[-1]))
+             for j in range(n_kv)], axis=0) for x in (k_own, v_own))
+    m_ref[...] = jnp.sum(q * k_own, axis=-1, keepdims=True) * scale
+    l_ref[...] = jnp.ones_like(l_ref)
+    acc_ref[...] = v_own
 
 
-def _without_starts(body, tables_ref, positions_ref, layer_ref, *refs):
-    """A kernel body for three prefetched scalars: no `starts_ref`."""
-    return body(tables_ref, positions_ref, layer_ref, None, *refs)
+def _attend(q, kv, first, position, until, m_ref, l_ref, acc_ref, *,
+            scale: float, window):
+    """One online-softmax update over the keys of `kv` ``[T, 2, H, hd]``
+    (float32; key t at position ``first + t``, those from `until` on
+    not this group's), one key head a query head: products and sums on
+    the vector unit."""
+    keys, vals = kv[:, 0], kv[:, 1]                          # [T, H, hd]
+    scores = jnp.sum(q[None] * keys, axis=-1,
+                     keepdims=True) * scale                  # [T, H, 1]
+    at = first + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
+    scores = jnp.where(_seen(at, position, until, window), scores,
+                       _NEG_INF)
+    m_prev = m_ref[...]
+    m_next = jnp.maximum(m_prev, jnp.max(scores, axis=0))
+    alpha = jnp.exp(m_prev - m_next)
+    p = jnp.exp(scores - m_next[None])                       # [T, H, 1]
+    m_ref[...] = m_next
+    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=0)
+    acc_ref[...] = alpha * acc_ref[...] + jnp.sum(p * vals, axis=0)
 
 
+def _attend_grouped(q, kv, first, position, until, m_ref, l_ref, acc_ref,
+                    *, scale: float, window):
+    """`_attend` for ``H`` query heads over kv's ``Hkv`` key heads, query
+    head i reading key head ``i // group``. The keys stay as they lie,
+    ``T x Hkv`` rows of ``hd``: one matrix product gives every query
+    head's score with every row, the rows of another key head are masked
+    with the positions the query does not see, and one more product takes
+    the probabilities to the values. (Taking a key head's ``[T, hd]`` out
+    of the slab first costs a pass over its sublanes a head: that pass,
+    not the products, bounded the kernel a page a grid step.)"""
+    f32 = jnp.float32
+    t, _, n_kv, hd = kv.shape
+    h = q.shape[0]
+    keys = kv[:, 0].reshape(t * n_kv, hd)
+    vals = kv[:, 1].reshape(t * n_kv, hd)
+    scores = jax.lax.dot_general(
+        q, keys, (((1,), (1,)), ((), ())),
+        preferred_element_type=f32) * scale              # [H, T * Hkv]
+    column = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+    head = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
+    key_head = jax.lax.rem(column, jnp.int32(n_kv))
+    keep = (_seen(first + jax.lax.div(column, jnp.int32(n_kv)), position,
+                  until, window)
+            & (key_head == jax.lax.div(head, jnp.int32(h // n_kv))))
+    scores = jnp.where(keep, scores, _NEG_INF)
+    m_prev = m_ref[...]
+    m_next = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_next)
+    p = jnp.exp(scores - m_next)                         # [H, T * Hkv]
+    m_ref[...] = m_next
+    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+    acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
+        p, vals, preferred_element_type=f32)
+
+
+# A row's walk, as `_row_walks` hands it to the kernel.
+_FIRST, _COUNT, _SIZE, _GROUPS, _BLOCK0 = range(5)
+
+
+def _row_walks(positions, starts, block_size: int, table_width: int,
+               pages: int, window):
+    """``[B, 5]`` int32, a row: the first table column that holds a key
+    the row sees, how many do (`live_pages`), the size and number of the
+    groups they go in (``ceil(n / pages)`` groups of one size: 33 pages
+    at 32 a group are 17 and 16, not 32 and 1, a group of one page
+    leaving the copy after it nothing to hide behind), and the logical
+    block of that first column. Worked out here, for all rows at once and
+    outside the kernel: as scalar arithmetic inside it, it was half of
+    what lowering the kernel cost a program at every start."""
+    start = 0 if starts is None else starts
+    end = (positions + block_size - 1) // block_size - start
+    first = jnp.zeros_like(positions)
+    if window is not None:
+        first = jnp.maximum(
+            jnp.maximum(positions - window + 1, 0) // block_size - start, 0)
+    count = jnp.clip(end - first, 0, table_width - first)
+    groups = (count + pages - 1) // pages
+    size = -(-count // jnp.maximum(groups, 1))
+    return jnp.stack([first, count, size, groups, start + first],
+                     axis=1).astype(jnp.int32)
+
+
+def _kernel_body(tables_ref, positions_ref, layer_ref, walks_ref, q_ref,
+                 k_new_ref, v_new_ref, pool_ref, o_ref, slabs, arrived,
+                 ahead_ref, m_ref, l_ref, acc_ref, *, block_size: int,
+                 scale: float, window):
+    """One grid step is one row. Its live table columns are walked in
+    groups (`_row_walks`): a group's pages are copied from the pool in
+    HBM into one of two VMEM slabs while the group before it is attended
+    from the other, and the row's last group starts the next row's
+    first. Scalars are worked with `lax`'s own operations: every `jnp`
+    operator in here is traced and lowered once a program and kernel, at
+    every start of a replica, and a step's programs are many."""
+    from jax import lax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    i32 = jnp.int32
+    row, last_row = pl.program_id(0), pl.num_programs(0) - 1
+    layer = layer_ref[0]
+    pages = slabs.shape[1]
+    a_pass = _pages_a_pass(pages, math.prod(slabs.shape[2:])
+                           * jnp.dtype(slabs.dtype).itemsize)
+
+    def fetch(r, column, count, slab):
+        def one(i, _):
+            pltpu.make_async_copy(
+                pool_ref.at[tables_ref[r, lax.add(column, i)], :, layer],
+                slabs.at[slab, i], arrived.at[slab]).start()
+        lax.fori_loop(i32(0), count, one, None)
+
+    @pl.when(lax.eq(row, i32(0)))
+    def _first_row():
+        # A pass may read a slab's pages that no copy of this call has
+        # filled (masked, but 0 x NaN is NaN): what they hold is finite.
+        zeros = jnp.zeros(slabs.shape[2:], slabs.dtype)
+
+        def clear(i, _):
+            slabs[lax.div(i, i32(pages)), lax.rem(i, i32(pages))] = zeros
+        lax.fori_loop(i32(0), i32(2 * pages), clear, None)
+        ahead_ref[0] = i32(0)   # the slab of this row's first group
+        ahead_ref[1] = i32(0)   # whether the row before started its copy
+
+    q = q_ref[...].astype(jnp.float32)                       # [H, hd]
+    _start_from_own_token(q, k_new_ref, v_new_ref, m_ref, l_ref, acc_ref,
+                          scale)
+    position = positions_ref[row]
+    first, n, size, n_groups, block0 = (walks_ref[row, k] for k in range(5))
+    attend = functools.partial(
+        _attend if q.shape[0] == k_new_ref.shape[0] else _attend_grouped,
+        scale=scale, window=window)
+
+    @pl.when(lax.gt(n, i32(0)))
+    def _attend_row():
+        @pl.when(lax.eq(ahead_ref[1], i32(0)))
+        def _fetch_own_first_group():
+            fetch(row, first, size, ahead_ref[0])
+
+        following = lax.min(lax.add(row, i32(1)), last_row)
+        has_next = lax.bitwise_and(lax.lt(row, last_row),
+                                   lax.gt(walks_ref[following, _COUNT],
+                                          i32(0)))
+
+        def group(g, slab):
+            done = lax.mul(g, size)
+            count = lax.min(lax.sub(n, done), size)
+            other = lax.sub(i32(1), slab)
+            more = lax.lt(lax.add(g, i32(1)), n_groups)
+
+            # While this group is attended the copy of the next one
+            # runs: the row's next, or the next row's first.
+            @pl.when(lax.bitwise_or(more, has_next))
+            def _fetch_the_next_group():
+                ahead = lax.add(done, size)
+                fetch(lax.select(more, row, following),
+                      lax.select(more, lax.add(first, ahead),
+                                 walks_ref[following, _FIRST]),
+                      lax.select(more, lax.min(lax.sub(n, ahead), size),
+                                 walks_ref[following, _SIZE]), other)
+
+            def arrive(i, _):
+                pltpu.make_async_copy(pool_ref.at[0, :, layer],
+                                      slabs.at[slab, 0],
+                                      arrived.at[slab]).wait()
+            lax.fori_loop(i32(0), count, arrive, None)
+            # What the slab holds past the group's pages is another
+            # group's, or nothing.
+            at = lax.mul(lax.add(block0, done), i32(block_size))
+            until = lax.min(position, lax.add(
+                at, lax.mul(count, i32(block_size))))
+
+            def a_pass_over(c, _):
+                page = lax.mul(c, i32(a_pass))
+                kv = slabs[slab, pl.ds(page, a_pass)].astype(jnp.float32)
+                kv = kv.reshape((a_pass * block_size,) + kv.shape[2:])
+                attend(q, kv, lax.add(at, lax.mul(page, i32(block_size))),
+                       position, until, m_ref, l_ref, acc_ref)
+            lax.fori_loop(i32(0), lax.div(lax.add(count, i32(a_pass - 1)),
+                                          i32(a_pass)), a_pass_over, None)
+            return other
+
+        ahead_ref[0] = lax.fori_loop(i32(0), n_groups, group, ahead_ref[0])
+        ahead_ref[1] = has_next.astype(i32)
+
+    o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("window", "pages", "interpret"))
 def paged_decode_attention_kernel(q, k_new, v_new, pool, tables,
                                   positions, layer, window: int = None,
-                                  starts=None, *, interpret: bool = False):
+                                  starts=None, *, pages: int = None,
+                                  interpret: bool = False):
     """Same arguments and result as `paged_decode_attention_xla`. The
-    pool is an operand of the kernel as it stands in HBM; a grid step
-    brings one page of one layer into VMEM (double-buffered by the
-    pipeline)."""
+    pool stays in HBM as it stands; the kernel copies a row's live pages
+    of one layer into VMEM, `pages` at a time (`pages_per_step`, where
+    None), two slabs deep. Jitted for the step that calls it a layer: its
+    layers of one shape (the layer index is an argument) are then traced
+    and lowered to the kernel's MLIR once, not once a layer, which a
+    step's program pays at every start, compiled or fetched (12 calls
+    cost a `repo-context` bucket 2.4 s of 4.0: my chip run, PR 36)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -263,64 +429,37 @@ def paged_decode_attention_kernel(q, k_new, v_new, pool, tables,
     if pool.shape[3:] != (2, hkv, hd) or h % hkv:
         raise ValueError(f"pool {pool.shape} does not hold K and V rows "
                          f"of {(hkv, hd)} for {h} query heads")
-    body = functools.partial(_kernel_body, block_size=bs, scale=hd ** -0.5,
-                             window=window)
-    if h != hkv:
-        body = functools.partial(_grouped_kernel_body, block_size=bs,
-                                 scale=hd ** -0.5, group=h // hkv,
-                                 window=window)
-    prefetched = [tables.reshape(-1).astype(jnp.int32),
-                  positions.astype(jnp.int32),
-                  jnp.reshape(layer, (1,)).astype(jnp.int32)]
-    # A table that begins at block 0 under no window (every layer of a
-    # model without a window group) is the kernel it was before there
-    # were windows, to the scalar: the grid's steps bound this kernel,
-    # and the compact table's index arithmetic, done for every table,
-    # cost `decode-heavy` 0.15 ms a step (my chip run, PR 35).
-    compact = starts is not None or window is not None
-    if compact:
-        prefetched.append((jnp.zeros((b,), jnp.int32) if starts is None
-                           else starts).astype(jnp.int32))
-    else:
-        body = functools.partial(_without_starts, body)
+    if pages is None:
+        pages = pool_pages_per_step(pool, nb)
+    positions = positions.astype(jnp.int32)
+    prefetched = [tables.astype(jnp.int32), positions,
+                  jnp.reshape(layer, (1,)).astype(jnp.int32),
+                  _row_walks(positions, starts, bs, nb, pages, window)]
 
-    def row_map(row, page, *prefetched_refs):
+    def row_map(row, *prefetched_refs):
         return (row, 0, 0)
-
-    def page_map(row, page, tables_ref, positions_ref, layer_ref,
-                 *starts_ref):
-        # Stay on the page of the row's last cached position (column 0
-        # for a row with nothing cached), and with a window on or after
-        # the first page that holds a key the row sees: a block index
-        # the pipeline already holds is not fetched again.
-        position = positions_ref[row]
-        last = (jnp.maximum(position, 1) - 1) // bs
-        column = jnp.minimum(page, last)
-        if compact:
-            start = starts_ref[0][row]
-            column = jnp.minimum(page, last - start)
-            if window is not None:
-                oldest = jnp.maximum(position - window + 1, 0) // bs - start
-                column = jnp.maximum(column, oldest)
-            column = jnp.clip(column, 0, nb - 1)
-        return (tables_ref[row * nb + column], 0, layer_ref[0], 0, 0, 0)
 
     row_spec = pl.BlockSpec((None, h, hd), row_map)
     kv_row_spec = pl.BlockSpec((None, hkv, hd), row_map)
     return pl.pallas_call(
-        body,
+        functools.partial(_kernel_body, block_size=bs, scale=hd ** -0.5,
+                          window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetched),
-            grid=(b, nb),
+            grid=(b,),
             in_specs=[row_spec, kv_row_spec, kv_row_spec,
-                      pl.BlockSpec((None, bs, None, 2, hkv, hd), page_map)],
+                      pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=row_spec,
-            scratch_shapes=[pltpu.VMEM((h, 1), jnp.float32),
+            scratch_shapes=[pltpu.VMEM((2, pages, bs, 2, hkv, hd),
+                                       pool.dtype),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.SMEM((2,), jnp.int32),
+                            pltpu.VMEM((h, 1), jnp.float32),
                             pltpu.VMEM((h, 1), jnp.float32),
                             pltpu.VMEM((h, hd), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((b, h, hd), jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         name=("paged_decode_attention" if window is None
               else "paged_window_decode_attention"),
         interpret=interpret,

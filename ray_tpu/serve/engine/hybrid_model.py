@@ -69,9 +69,11 @@ class HybridEngineModel(SparseEngineModel):
         import jax.numpy as jnp
 
         from ray_tpu.models.hybrid_moe import KDA_PER_PERIOD
-        from ray_tpu.ops.paged_attention import kernel_eligible
+        from ray_tpu.ops.paged_attention import (kernel_eligible,
+                                                 page_groups)
 
         super().__init__(params, cfg, jit_cache_cap)
+        self._page_groups = page_groups
         self._chunk = kda_chunk
         self.kv_token_shape = (cfg.n_periods, 2, cfg.n_kv_heads,
                                cfg.head_dim)
@@ -359,10 +361,12 @@ class HybridEngineModel(SparseEngineModel):
                          "decode_prep_s"):
             b_pad = _next_pow2(max(b, 1))
             pages = [int(p) // block_size + 1 for p in positions]
+            nb_pad = _next_pow2(max(max(pages), 1))
             if self._attn_inplace:
                 self.decode_attn_inplace_steps += 1
                 self.decode_kv_pages_read += sum(pages)
-            nb_pad = _next_pow2(max(max(pages), 1))
+                self.decode_kv_page_groups_read += self._page_groups(
+                    pool, nb_pad, positions)
             key = (b_pad, nb_pad, block_size)
             fn = self._decode_paged_jit.get(key)
             if fn is None:

@@ -69,9 +69,11 @@ class LagunaEngineModel(SparseEngineModel):
 
     def __init__(self, params, cfg, max_batch_size: int = 8,
                  jit_cache_cap: int = 32):
-        from ray_tpu.ops.paged_attention import kernel_eligible
+        from ray_tpu.ops.paged_attention import (kernel_eligible,
+                                                 page_groups)
 
         super().__init__(params, cfg, jit_cache_cap)
+        self._page_groups = page_groups
         row = (2, cfg.n_kv_heads, cfg.head_dim)
         self.kv_token_shape = (cfg.n_full_layers,) + row
         self.kv_groups = {WINDOW: {
@@ -83,9 +85,13 @@ class LagunaEngineModel(SparseEngineModel):
                                 cfg.n_kv_heads))
         # Live pages the steps' tables named, a group: pages that hold a
         # cached position the row's query sees (their sum is
-        # `decode_kv_pages_read`).
+        # `decode_kv_pages_read`), and the groups of pages the kernel
+        # fetched them in (`ops.paged_attention.page_groups`; their sum
+        # is `decode_kv_page_groups_read`).
         self.decode_kv_pages_read_global = 0
         self.decode_kv_pages_read_window = 0
+        self.decode_kv_page_groups_read_global = 0
+        self.decode_kv_page_groups_read_window = 0
 
     def window_table_blocks(self, block_size: int) -> int:
         """Blocks of the window group a sequence holds at the most."""
@@ -290,6 +296,8 @@ class LagunaEngineModel(SparseEngineModel):
             # Pages that hold a cached position: [0, p) in the global
             # group, [max(0, p - window + 1), p) in the window group.
             cached = [-(-int(p) // block_size) for p in positions]
+            nb_pad = _next_pow2(max(max(int(p) // block_size + 1
+                                        for p in positions), 1))
             if self._attn_inplace:
                 self.decode_attn_inplace_steps += 1
                 in_window = sum(
@@ -298,8 +306,12 @@ class LagunaEngineModel(SparseEngineModel):
                 self.decode_kv_pages_read_global += sum(cached)
                 self.decode_kv_pages_read_window += in_window
                 self.decode_kv_pages_read += sum(cached) + in_window
-            nb_pad = _next_pow2(max(max(int(p) // block_size + 1
-                                        for p in positions), 1))
+                groups = (
+                    self._page_groups(pools[GLOBAL], nb_pad, positions),
+                    self._page_groups(pools[WINDOW], tw, positions, window))
+                self.decode_kv_page_groups_read_global += groups[0]
+                self.decode_kv_page_groups_read_window += groups[1]
+                self.decode_kv_page_groups_read += sum(groups)
             key = (b_pad, nb_pad, block_size)
             fn = self._decode_paged_jit.get(key)
             if fn is None:

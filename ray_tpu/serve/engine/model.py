@@ -357,7 +357,8 @@ class TransformerEngineModel:
         import jax
         import jax.numpy as jnp
 
-        from ray_tpu.ops.paged_attention import kernel_eligible
+        from ray_tpu.ops.paged_attention import (kernel_eligible,
+                                                 page_groups)
 
         if cfg.is_moe:
             raise ValueError("TransformerEngineModel supports dense "
@@ -389,10 +390,14 @@ class TransformerEngineModel:
         # by backend and widths, `ops.paged_attention.kernel_eligible`),
         # and the live pages the tables of those steps named
         # (`position // block_size + 1` a row, the page the new token
-        # lands in included); a layer reads each once.
+        # lands in included); a layer reads each once, in the groups of
+        # pages the kernel fetches together (`page_groups`: a row's live
+        # pages ÷ `pages_per_step`, rounded up).
         self._attn_inplace = kernel_eligible(cfg.n_heads, cfg.head_dim)
+        self._page_groups = page_groups
         self.decode_attn_inplace_steps = 0
         self.decode_kv_pages_read = 0
+        self.decode_kv_page_groups_read = 0
         # Host side of the calls, in seconds, each fed by its
         # `flight.span`: input padding and upload (`prep`), the call of
         # the jitted function (`dispatch`; a paged decode step's one
@@ -772,10 +777,12 @@ class TransformerEngineModel:
             b_pad = _next_pow2(max(b, 1))
             pages = [int(p) // block_size + 1 for p in positions]
             nb = max(pages)
+            nb_pad = _next_pow2(max(nb, 1))
             if self._attn_inplace:
                 self.decode_attn_inplace_steps += 1
                 self.decode_kv_pages_read += sum(pages)
-            nb_pad = _next_pow2(max(nb, 1))
+                self.decode_kv_page_groups_read += self._page_groups(
+                    pool, nb_pad, positions)
             key = (b_pad, nb_pad, block_size)
             fn = self._decode_paged_jit.get(key)
             if fn is None:

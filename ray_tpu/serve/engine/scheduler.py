@@ -983,7 +983,14 @@ class InferenceEngine:
         at other head sizes, or for `TinyLM`), `decode_kv_pages_read`
         the live pages the block tables of those steps named
         (`position // block_size + 1` a row): what a layer of such a
-        step reads of the pool.
+        step reads of the pool; `decode_kv_page_groups_read` the groups
+        of pages the kernel fetched them in (a row's pages that hold a
+        cached position it sees, from the first such page on, in groups
+        of at most `ops.paged_attention.pages_per_step` pages: 32 of
+        64 KB, 8 of 256 KB, no more than the table's width; a row's
+        groups are of one size). Pages ÷ groups is what a
+        fetch brings: near the group size at long contexts, near 1
+        where tables are a column or two wide.
         `moe_local_assignments`, `moe_expert_touches` and
         `moe_max_expert_load` are a sparse-expert model's own counts
         over its paged decode steps, summed over layers: (token, expert)
@@ -1001,7 +1008,8 @@ class InferenceEngine:
         a window group gave back when they left the window
         (`cache["groups"]` has the gauges); `decode_kv_pages_read_global`
         and `decode_kv_pages_read_window` split `decode_kv_pages_read`
-        for a model with a window group (0 otherwise);
+        for a model with a window group (0 otherwise), and
+        `decode_kv_page_groups_read_global` / `_window` its groups;
         `window_release_s` is the host time of that release (the span
         `engine.window_release`, inside `engine.capacity`, whose
         `phase.capacity_s` holds it too)."""
@@ -1038,6 +1046,8 @@ class InferenceEngine:
                 self.model, "decode_attn_inplace_steps", 0),
             "decode_kv_pages_read": getattr(
                 self.model, "decode_kv_pages_read", 0),
+            "decode_kv_page_groups_read": getattr(
+                self.model, "decode_kv_page_groups_read", 0),
             "moe_local_assignments": getattr(
                 self.model, "moe_local_assignments", 0),
             "moe_expert_touches": getattr(
@@ -1050,6 +1060,10 @@ class InferenceEngine:
                 self.model, "decode_kv_pages_read_global", 0),
             "decode_kv_pages_read_window": getattr(
                 self.model, "decode_kv_pages_read_window", 0),
+            "decode_kv_page_groups_read_global": getattr(
+                self.model, "decode_kv_page_groups_read_global", 0),
+            "decode_kv_page_groups_read_window": getattr(
+                self.model, "decode_kv_page_groups_read_window", 0),
             **self._group_counters(cache["groups"]),
             "jit_bucket_evictions": getattr(
                 self.model, "jit_cache_evictions", 0),

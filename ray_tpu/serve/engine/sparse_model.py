@@ -43,6 +43,7 @@ class SparseEngineModel:
         self.decode_d2h_bytes = 0
         self.decode_attn_inplace_steps = 0
         self.decode_kv_pages_read = 0
+        self.decode_kv_page_groups_read = 0
         # The expert layers' counts over decode steps, summed over
         # layers, computed inside the step and fetched with its ids:
         # (token, expert) pairs on held experts; (layer, expert) pairs

@@ -155,6 +155,61 @@ def test_served_requests_follow_the_reference_and_count_by_group(toy):
     assert groups["window"]["blocks_in_use"] == 0
 
 
+def test_the_page_group_counters_are_a_direct_count_by_group():
+    """The model counts, beside the live pages of each layer group, the
+    groups the paged kernel fetches them in; `stats()` carries them flat.
+    Steered on here (the CPU's steps take the XLA body, which the
+    counters do not ask about), against a walk written out over the
+    positions of every decode call: a row's live columns from the first
+    its query sees, `pages_per_step` at a time."""
+    from ray_tpu.ops import paged_attention as pa
+
+    served, engine = _serve(seed=17)
+    model = served["model"]
+    model._attn_inplace = True
+    calls = []
+    inner = model._decode_paged
+
+    def recording(pools, block_tables, last_tokens, positions, *rest):
+        calls.append(([int(p) for p in positions],
+                      {g: pool for g, pool in pools.items()}))
+        return inner(pools, block_tables, last_tokens, positions, *rest)
+
+    model._decode_paged = recording
+    rng = np.random.default_rng(11)
+    streams = [engine.submit(rng.integers(2, 512, n).tolist(), 30)
+               for n in (5, 33, 60)]
+    while engine.step():
+        pass
+    assert all(len(list(s)) == 30 for s in streams)
+    window, want = TOY["window"], {"global": [0, 0], "window": [0, 0]}
+    for positions, pools in calls:
+        widest = max(p // BLOCK + 1 for p in positions)
+        widths = {"global": 1 << (widest - 1).bit_length(),
+                  "window": WINDOW_BLOCKS}
+        for group, bound in (("global", None), ("window", window)):
+            pages = pa.pool_pages_per_step(pools[group], widths[group])
+            for p in positions:
+                live = [c for c in range(-(-p // BLOCK))
+                        if bound is None or c * BLOCK + BLOCK > p - bound + 1]
+                want[group][0] += len(live)
+                want[group][1] += len(range(0, len(live), pages))
+    stats = engine.stats()
+    assert stats["decode_attn_inplace_steps"] == stats["paged_steps"] > 0
+    for group, (pages, groups) in want.items():
+        assert stats[f"decode_kv_pages_read_{group}"] == pages > 0
+        assert stats[f"decode_kv_page_groups_read_{group}"] == groups > 0
+    assert stats["decode_kv_page_groups_read"] == \
+        want["global"][1] + want["window"][1]
+    assert stats["decode_kv_pages_read"] == \
+        want["global"][0] + want["window"][0]
+    # Tables this narrow are one fetch a row: pages ÷ groups is the live
+    # pages of a row, as the benchmark's `decode_kv_pages_per_fetch`
+    # would read it.
+    assert stats["decode_kv_pages_read_global"] \
+        > stats["decode_kv_page_groups_read_global"]
+
+
 def test_a_preempted_row_frees_both_groups_and_is_recomputed():
     """A window pool that holds two sequences' windows but not three:
     the third row is preempted, both of its tables are freed, and every

@@ -96,8 +96,16 @@ def _inputs(name):
     return q, k_new, v_new, pool, tables, positions
 
 
+# Pages a fetch: one (a page a step, as the kernel was), what
+# `pages_per_step` takes for the table (None), one that leaves these
+# tables of 4 a ragged last group, and more than a table names.
+PAGES = [1, None, 3, 8]
+PAGES_IDS = ["pages1", "pages_by_rule", "pages3_ragged", "pages8_wider"]
+
+
+@pytest.mark.parametrize("pages", PAGES, ids=PAGES_IDS)
 @pytest.mark.parametrize("name", CASES)
-def test_kernel_matches_xla_body_and_dense_reference(name):
+def test_kernel_matches_xla_body_and_dense_reference(name, pages):
     import jax.numpy as jnp
 
     from ray_tpu.ops import paged_attention as pa
@@ -108,19 +116,21 @@ def test_kernel_matches_xla_body_and_dense_reference(name):
         dev = [jnp.asarray(a) for a in args]
         xla = np.asarray(pa.paged_decode_attention_xla(*dev, layer))
         kernel = np.asarray(pa.paged_decode_attention_kernel(
-            *dev, jnp.int32(layer), interpret=True))
+            *dev, jnp.int32(layer), pages=pages, interpret=True))
         assert np.isfinite(kernel).all()
         np.testing.assert_allclose(kernel, xla, atol=2e-5, rtol=2e-5)
         np.testing.assert_allclose(kernel, want, atol=2e-5, rtol=2e-5)
         np.testing.assert_allclose(xla, want, atol=2e-5, rtol=2e-5)
 
 
+@pytest.mark.parametrize("pages", PAGES, ids=PAGES_IDS)
 @pytest.mark.parametrize("name", ["padded_batch_rows",
                                   "stale_data_past_position"])
-def test_result_ignores_what_lies_past_position(name):
+def test_result_ignores_what_lies_past_position(name, pages):
     """Rewrite everything a row must not read (pool rows at or past its
     position, every block its live pages do not name): neither body's
-    result moves by a bit."""
+    result moves by a bit, whatever a fetch brings beside the live
+    pages."""
     import jax.numpy as jnp
 
     from ray_tpu.ops import paged_attention as pa
@@ -134,11 +144,97 @@ def test_result_ignores_what_lies_past_position(name):
                      np.float32(-3 * STALE))
     for body in (pa.paged_decode_attention_xla,
                  lambda *a: pa.paged_decode_attention_kernel(
-                     *a, interpret=True)):
+                     *a, pages=pages, interpret=True)):
         got = [np.asarray(body(*(jnp.asarray(a) for a in (
             q, k_new, v_new, p, tables, positions)), jnp.int32(1)))
             for p in (pool, other)]
         np.testing.assert_array_equal(got[0], got[1])
+
+
+def test_a_slab_no_copy_filled_never_reaches_the_result():
+    """A fetch of 8 pages for rows that hold 1 or 2: the slab's other
+    pages are what the call's first step cleared, whatever VMEM held,
+    and a masked key's value of NaN would otherwise poison the sum
+    (0 x NaN). Here: a pool of NaN everywhere but the live rows."""
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import paged_attention as pa
+
+    q, k_new, v_new, pool, tables, positions = _inputs(
+        "stale_data_past_position")
+    live = np.zeros(pool.shape[:2], bool)
+    for table, pos in zip(tables, positions):
+        for t in range(pos):
+            live[table[t // BS], t % BS] = True
+    # Whole live pages are copied, stale rows and all: those stay finite
+    # (the pool never holds anything else); every other block is NaN.
+    named = np.zeros(pool.shape[0], bool)
+    named[[table[t // BS] for table, pos in zip(tables, positions)
+           for t in range(pos)]] = True
+    poisoned = np.where(named[:, None, None, None, None, None], pool,
+                        np.float32("nan"))
+    got = [np.asarray(pa.paged_decode_attention_kernel(
+        *(jnp.asarray(a) for a in (q, k_new, v_new, p, tables, positions)),
+        jnp.int32(1), pages=8, interpret=True)) for p in (pool, poisoned)]
+    assert np.isfinite(got[1]).all()
+    np.testing.assert_array_equal(got[0], got[1])
+
+
+# The three models' pools as the cells hold them: a page's bytes, the
+# widths their tables take, and what a fetch then brings.
+@pytest.mark.parametrize("page_bytes, width, want", [
+    (16 * 2 * 8 * 128 * 2, 512, 32),    # laguna global, bf16: 64 KB
+    (16 * 2 * 8 * 128 * 2, 128, 32),
+    (16 * 2 * 8 * 128 * 2, 33, 32),     # its window group's 33 columns
+    (16 * 2 * 8 * 128 * 2, 64, 32),     # solar-open2's one KV layer
+    (16 * 2 * 8 * 128 * 2, 16, 16),
+    (16 * 2 * 16 * 128 * 4, 64, 8),     # olmo-1b, float32: 256 KB
+    (16 * 2 * 16 * 128 * 4, 32, 8),
+    (16 * 2 * 16 * 128 * 4, 4, 4),      # short contexts: the table's width
+    (16 * 2 * 16 * 128 * 4, 2, 2),
+    (16 * 2 * 16 * 128 * 4, 1, 1),
+    (4 * 2 * 8 * 128 * 4, 4, 4),        # this file's pool
+    (16 << 20, 64, 1),                  # a page past the budget: one
+], ids=lambda v: str(v))
+def test_pages_per_step_follows_page_bytes_and_table_width(page_bytes,
+                                                           width, want):
+    from ray_tpu.ops import paged_attention as pa
+
+    pages = pa.pages_per_step(page_bytes, width)
+    assert pages == want
+    # Two slabs fit the budget, a pass divides the group, and no knob.
+    assert pages == 1 or 2 * pages * page_bytes <= pa._VMEM_FOR_PAGES
+    assert pages % pa._pages_a_pass(pages, page_bytes) == 0
+    assert pages & (pages - 1) == 0
+
+
+@pytest.mark.parametrize("window", [None, 40], ids=["global", "window40"])
+@pytest.mark.parametrize("pages_wanted", [1, 2, 8])
+def test_page_groups_is_a_direct_count_of_the_kernels_walk(window,
+                                                           pages_wanted):
+    """`page_groups` against a walk written out: a row's live columns
+    from the first that holds a key it sees, `pages` at a time."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import paged_attention as pa
+
+    bs, width = 16, {1: 1, 2: 2, 8: 64}[pages_wanted]
+    # A float32 pool of 256 KB pages: 8 a fetch, or the table's width.
+    pool = jax.ShapeDtypeStruct((64, bs, 3, 2, 16, 128), jnp.float32)
+    pages = pa.pool_pages_per_step(pool, width)
+    assert pages == pages_wanted
+    positions = [p for p in (0, 1, 15, 16, 17, 40, 129, 500, 1000)
+                 if p < width * bs]
+    direct = 0
+    for p in positions:
+        columns = [c for c in range(width)
+                   if c * bs < p and (window is None
+                                      or c * bs + bs > p - window + 1)]
+        assert len(columns) == pa.live_pages(p, bs, window)
+        direct += len(range(0, len(columns), pages))
+    assert pa.page_groups(pool, width, positions, window) == direct
+    assert pa.page_groups(pool, width, [0], window) == 0
 
 
 def test_kernel_eligibility_follows_backend_and_widths(monkeypatch):
@@ -209,6 +305,11 @@ def test_engine_step_through_the_kernel_matches_the_xla_step(monkeypatch):
     # decode positions n .. n + 4 (the prefill gave the first token).
     assert stats["decode_kv_pages_read"] == sum(
         pos // 4 + 1 for n in (1, 4, 9) for pos in range(n, n + 5))
+    # Tables of 1, 2 and 4 columns here, and pages of 32 KB: a fetch
+    # brings a row's every live page, so a row that has one is one group.
+    assert stats["decode_kv_page_groups_read"] == sum(
+        1 for n in (1, 4, 9) for pos in range(n, n + 5) if pos > 0)
+    assert plain["decode_kv_page_groups_read"] == 0
 
 
 # ---------------------------------------------------------------------------
@@ -334,11 +435,12 @@ def _grouped_reference(q, k_new, v_new, pool, tables, positions, layer):
         tables, positions, layer)
 
 
+@pytest.mark.parametrize("pages", PAGES, ids=PAGES_IDS)
 @pytest.mark.parametrize("pool_dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("name", ["one_token_row", "rows_shorter_than_table",
                                   "padded_batch_rows", "shuffled_tables",
                                   "stale_data_past_position"])
-def test_grouped_heads_kernel_matches_its_xla_twin(name, pool_dtype):
+def test_grouped_heads_kernel_matches_its_xla_twin(name, pool_dtype, pages):
     import jax.numpy as jnp
 
     from ray_tpu.ops import paged_attention as pa
@@ -349,7 +451,7 @@ def test_grouped_heads_kernel_matches_its_xla_twin(name, pool_dtype):
         dev = [jnp.asarray(a) for a in args]
         xla = np.asarray(pa.paged_decode_attention_xla(*dev, layer))
         kernel = np.asarray(pa.paged_decode_attention_kernel(
-            *dev, jnp.int32(layer), interpret=True))
+            *dev, jnp.int32(layer), pages=pages, interpret=True))
         assert kernel.shape == (len(args[-1]), Q_HEADS, HD)
         assert np.isfinite(kernel).all()
         np.testing.assert_allclose(kernel, xla, atol=2e-5, rtol=2e-5)

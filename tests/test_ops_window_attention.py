@@ -91,13 +91,26 @@ def _paged_case(heads, window, lengths, seed=0, dtype=jnp.float32):
 LENGTHS = [0, 5, 40, 48, 49, 64, 137]
 
 
+# Pages a fetch: one, what `pages_per_step` takes for the table (the
+# compact table's 11 columns under a window of 40: 16; the global table's
+# 36: 64), 3 (a ragged last group in both tables), 4 (a row's 9 live
+# pages go as three groups of 3, a page short of the slab, which a pass
+# of the body reads whole) and 16 (more than the compact table names).
+PAGES = [1, None, 3, 4, 16]
+PAGES_IDS = ["pages1", "pages_by_rule", "pages3_ragged", "pages4_short",
+             "pages16"]
+
+
+@pytest.mark.parametrize("pages", PAGES, ids=PAGES_IDS)
 @pytest.mark.parametrize("heads", [48, 72], ids=["group6", "group9"])
 @pytest.mark.parametrize("window", [40, None], ids=["window40", "global"])
-def test_windowed_paged_attention_matches_the_band_mask(heads, window):
+def test_windowed_paged_attention_matches_the_band_mask(heads, window,
+                                                        pages):
     args, want = _paged_case(heads, window, LENGTHS)
     twin = pa.paged_decode_attention_xla(*args)
     assert float(jnp.max(jnp.abs(twin - want))) < 2e-5
-    kernel = pa.paged_decode_attention_kernel(*args, interpret=True)
+    kernel = pa.paged_decode_attention_kernel(*args, pages=pages,
+                                              interpret=True)
     assert float(jnp.max(jnp.abs(kernel - want))) < 2e-5
 
 
@@ -110,11 +123,34 @@ def test_a_window_table_one_block_short_changes_the_result():
     assert (gaps[:2] < 2e-5).all() and (gaps[2:] > 1e-3).all()
 
 
-def test_windowed_kernel_reads_a_bf16_pool_like_its_twin():
+@pytest.mark.parametrize("pages", PAGES, ids=PAGES_IDS)
+def test_windowed_kernel_reads_a_bf16_pool_like_its_twin(pages):
     args, _ = _paged_case(72, 40, LENGTHS, dtype=jnp.bfloat16)
     twin = pa.paged_decode_attention_xla(*args)
-    kernel = pa.paged_decode_attention_kernel(*args, interpret=True)
+    kernel = pa.paged_decode_attention_kernel(*args, pages=pages,
+                                              interpret=True)
     assert float(jnp.max(jnp.abs(kernel - twin))) < 1e-4
+
+
+def test_the_group_walk_starts_at_the_first_page_the_window_reaches():
+    """A compact table whose first live key lies in column 1, not 0 (the
+    cache manager releases a block a step after it left the window):
+    with a fetch of 2 pages the walk starts there, so columns 1-2 are
+    one group; garbage in column 0's block never reaches the result."""
+    args, want = _paged_case(72, 40, [137])
+    q, k, v, pool, tables, lengths, layer, window, starts = args
+    # Hand the row the block before its window too, full of NaN.
+    nan_block = min(set(range(pool.shape[0]))
+                    - set(np.asarray(tables).ravel().tolist()))
+    assert int(tables[0, -1]) == 0      # the table has a column to spare
+    pool = pool.at[nan_block].set(jnp.nan)
+    tables = jnp.concatenate(
+        [jnp.full((1, 1), nan_block, jnp.int32), tables[:, :-1]], axis=1)
+    shifted = (q, k, v, pool, tables, lengths, layer, window, starts - 1)
+    for pages in (1, 2, None):
+        kernel = pa.paged_decode_attention_kernel(*shifted, pages=pages,
+                                                  interpret=True)
+        assert float(jnp.max(jnp.abs(kernel - want))) < 2e-5
 
 
 @pytest.mark.parametrize("heads, hkv", [(6, 1), (9, 1), (18, 2)],
