@@ -998,6 +998,10 @@ class InferenceEngine:
         at least one token, and the largest count any held expert took
         in a layer; computed inside the step and fetched with its
         sampled ids; 0 for a model without experts.
+        `moe_steps_kernel` and `moe_steps_scan` count such a model's
+        programs run, prefills and decode steps (each runs every expert
+        layer once), by the body their expert layers were traced with:
+        `ops.experts`' Pallas kernel or its scan.
         `state_slot_steps_in_use` and `state_slot_steps` sum, over paged
         steps, the state slots in use and the slots there are (a model
         with per-sequence state; 0 otherwise); `cache` has the gauges
@@ -1054,6 +1058,8 @@ class InferenceEngine:
                 self.model, "moe_expert_touches", 0),
             "moe_max_expert_load": getattr(
                 self.model, "moe_max_expert_load", 0),
+            "moe_steps_kernel": getattr(self.model, "moe_steps_kernel", 0),
+            "moe_steps_scan": getattr(self.model, "moe_steps_scan", 0),
             "state_slot_steps_in_use": self.cache.state_slot_steps_in_use,
             "state_slot_steps": self.cache.state_slot_steps,
             "decode_kv_pages_read_global": getattr(

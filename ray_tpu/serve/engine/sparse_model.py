@@ -51,6 +51,12 @@ class SparseEngineModel:
         self.moe_local_assignments = 0
         self.moe_expert_touches = 0
         self.moe_max_expert_load = 0
+        # Programs run (a prefill, a decode step: each runs every expert
+        # layer once) by the body their expert layers got when they were
+        # traced, `ops.experts`' kernel or its scan, found by their rows.
+        self.moe_steps_kernel = 0
+        self.moe_steps_scan = 0
+        self._experts_kernel_at: Dict[int, bool] = {}
         self.phase: Dict[str, float] = dict.fromkeys(
             ("prefill_prep_s", "prefill_dispatch_s", "prefill_wait_s",
              "prefill_kv_d2h_s", "decode_prep_s", "decode_dispatch_s",
@@ -96,10 +102,13 @@ class SparseEngineModel:
         import jax
         import jax.numpy as jnp
 
-        from ray_tpu.ops.experts import held_experts_ffn, route
+        from ray_tpu.ops.experts import (held_experts_ffn, kernel_eligible,
+                                         route)
 
         cfg = self._cfg
         y = self._norm(x, ln2)
+        self._experts_kernel_at[y.shape[0]] = kernel_eligible(
+            *y.shape, mp["w_gate"].shape[2], mp["w_gate"].dtype)
         with jax.named_scope("moe_route"):
             experts, weights = route(
                 y, mp["router"], mp.get("select_bias"), cfg.top_k,
@@ -114,6 +123,13 @@ class SparseEngineModel:
         counts = jnp.stack([jnp.sum(load), jnp.sum(load > 0),
                             jnp.max(load)]).astype(jnp.int32)
         return x + shared + routed, counts
+
+    def _count_experts_step(self, rows: int) -> None:
+        """A program of `rows` rows has been dispatched (so traced)."""
+        if self._experts_kernel_at.get(rows):
+            self.moe_steps_kernel += 1
+        else:
+            self.moe_steps_scan += 1
 
     # -- the host side of the two calls --------------------------------
     def _run_prefill(self, tokens: Sequence[int]):
@@ -136,6 +152,7 @@ class SparseEngineModel:
         with flight.span("model", "prefill.dispatch", None, phase,
                          "prefill_dispatch_s"):
             logits, *rest = fn(self._params, *args)
+        self._count_experts_step(s_pad)
         with flight.span("model", "prefill.logits_wait", None, phase,
                          "prefill_wait_s"):
             logits = np.asarray(logits)
@@ -154,6 +171,7 @@ class SparseEngineModel:
         with flight.span("model", "decode.dispatch", None, phase,
                          "decode_dispatch_s"):
             out, logits, *rest = fn(*args)
+        self._count_experts_step(b_pad)
         with flight.span("model", "decode.logits_wait", None, phase,
                          "decode_wait_s"):
             out = np.asarray(out)

@@ -90,6 +90,55 @@ def test_prefill_then_decode_through_both_groups_matches_the_reference(
     assert window.free_blocks() == ENGINE["group_blocks"]["window"]
 
 
+@pytest.mark.parametrize("n, steps", [(16, 3), (130, 4)])
+def test_the_experts_kernel_through_the_engine_matches_the_reference(
+        toy, n, steps, monkeypatch):
+    """The same drive with the held experts' layer through its Pallas
+    kernel, steered on here and interpreted (the backend is the CPU and
+    the toy widths are no whole lanes): a decode step's rows and a short
+    prompt's through every sparse layer, against the reference and the
+    scan's rows; a prompt of more rows than a tile keeps the scan. The
+    model counts its programs by the body their expert layers got."""
+    from functools import partial
+
+    from ray_tpu.ops import experts as ex
+
+    plain, plain_engine, ref = toy
+    prompt = np.random.default_rng(n).integers(
+        2, TOY["vocab_size"], n).tolist()
+    before = plain_engine.stats()
+    scanned, _ = FAMILY.drive(plain_engine, plain, prompt, steps,
+                              f"scan-{n}")
+    after = plain_engine.stats()
+    # Off the chip every program keeps the scan: a prefill and the steps.
+    assert after["moe_steps_kernel"] == before["moe_steps_kernel"] == 0
+    assert after["moe_steps_scan"] - before["moe_steps_scan"] == 1 + steps
+    calls, interpreted = [], partial(ex.grouped_ffn_kernel, interpret=True)
+
+    def kernel(*args, **kwargs):
+        calls.append(args[0].shape)
+        return interpreted(*args, **kwargs)
+
+    monkeypatch.setattr(ex, "kernel_eligible",
+                        lambda t, *widths: t <= ex._ROWS_MOST)
+    monkeypatch.setattr(ex, "grouped_ffn_kernel", kernel)
+    served, engine = _serve()
+    got, tokens = FAMILY.drive(engine, served, prompt, steps, f"kernel-{n}")
+    # Traced once a sparse layer in the decode step's program, and in
+    # the prompt's where its bucket is one tile (16 rows; 256 are not).
+    sparse = TOY["n_periods"] * 4 - 1
+    short = n <= ex._ROWS_MOST
+    assert len(calls) == (2 if short else 1) * sparse
+    stats = engine.stats()
+    assert stats["moe_steps_kernel"] == steps + short
+    assert stats["moe_steps_scan"] == 1 - short
+    want = np.asarray(ref(served["params"], np.asarray(tokens, np.int32)))
+    for j, row in enumerate(got):
+        assert _gap(row, want[n - 1 + j]) < TOLERANCE, (n, j)
+        assert _gap(row, scanned[j]) < TOLERANCE, (n, j)
+    assert served["own_limits"][-1]["ok"]
+
+
 def test_a_window_one_block_short_is_off_the_reference(toy):
     """The same weights served with a window of 8 instead of 24: rows
     past position 8 differ from the reference's."""

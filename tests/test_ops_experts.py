@@ -27,11 +27,11 @@ def _weights(seed=0):
 
 def _dense(w, experts, weights, lo, hi, valid=None):
     y = np.asarray(w["y"], np.float64)
-    out = np.zeros((T, D))
-    for t in range(T):
+    out = np.zeros(y.shape)
+    for t in range(y.shape[0]):
         if valid is not None and not valid[t]:
             continue
-        for j in range(K):
+        for j in range(experts.shape[1]):
             e = int(experts[t, j])
             if lo <= e < hi:
                 gate = y[t] @ np.asarray(w["w_gate"][e], np.float64)
@@ -130,6 +130,9 @@ def test_it_runs_under_jit_with_a_fixed_number_of_tiles():
 
     assert [_tile_rows(t) for t in (1, 8, 32, 100, 128, 1024)] == \
         [8, 8, 32, 128, 128, 128]
+    # The kernel's rows: a whole tile of the chip at two bytes a value.
+    assert [_tile_rows(t, 16) for t in (1, 8, 16, 32, 100, 128)] == \
+        [16, 16, 16, 32, 128, 128]
     w = _weights(5)
 
     @jax.jit
@@ -141,3 +144,195 @@ def test_it_runs_under_jit_with_a_fixed_number_of_tiles():
     out, _ = run(w["y"])
     np.testing.assert_allclose(out, _dense(w, experts, weights, 4, 8),
                                atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# the grouped gated-FFN kernel (what the chip runs at a decode step's
+# rows), interpreted, against the scan (what runs here) and the dense loop
+# ---------------------------------------------------------------------------
+def _case(t, d, f, e, k, seed, dtype="float32", send_all_to=None,
+          keep_off=None):
+    """Inputs of `t` tokens over `e` experts of ``[d, f]``: `send_all_to`
+    an expert every token chooses, `keep_off` a range no token does."""
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.experts import route
+
+    rng = np.random.default_rng(seed)
+
+    def mat(*shape):
+        return jnp.asarray(rng.normal(size=shape) / np.sqrt(shape[-2]),
+                           jnp.float32).astype(dtype)
+
+    bias = 0.1 * rng.normal(size=(e,))
+    if send_all_to is not None:
+        bias[send_all_to] = 100.0
+    if keep_off is not None:
+        bias[keep_off[0]:keep_off[1]] = -100.0
+    y = jnp.asarray(rng.normal(size=(t, d)), jnp.float32)
+    router = jnp.asarray(rng.normal(size=(d, e)) / np.sqrt(d), jnp.float32)
+    experts, weights = route(y, router, jnp.asarray(bias, jnp.float32), k)
+    return {"y": y, "experts": experts, "weights": weights,
+            "w_gate": mat(e, d, f), "w_up": mat(e, d, f),
+            "w_down": mat(e, f, d)}
+
+
+def _through(c, lo, hi, valid, monkeypatch, kernel: bool, block=None):
+    """`held_experts_ffn` over a case through the scan, or through the
+    kernel steered on and interpreted (the backend here is the CPU)."""
+    from functools import partial
+
+    from ray_tpu.ops import experts as ex
+
+    if kernel:
+        monkeypatch.setattr(ex, "kernel_eligible",
+                            lambda t, *widths: t <= ex._ROWS_MOST)
+        monkeypatch.setattr(ex, "grouped_ffn_kernel", partial(
+            ex.grouped_ffn_kernel, interpret=True, block=block))
+    return _held(c, c["experts"], c["weights"], lo, hi, valid)
+
+
+KERNEL_CASES = {
+    # name: (case arguments, held, rows off, f block)
+    # Both cells' shapes cut small: 16 rows, top 10 of 64 with 8 held at
+    # f = 256 (repo-context), top 8 of 80 with 10 held at f = 384, three
+    # blocks of 128 by the rule's divisors (decode-wide).
+    "repo_context_cut_small": (
+        dict(t=16, d=256, f=256, e=64, k=10, seed=10), (8, 16), None, None),
+    "decode_wide_cut_small": (
+        dict(t=16, d=256, f=384, e=80, k=8, seed=11), (10, 20), None, 128),
+    "repo_context_cut_small_bf16": (
+        dict(t=16, d=256, f=256, e=64, k=10, seed=12, dtype="bfloat16"),
+        (8, 16), None, 128),
+    "decode_wide_cut_small_bf16": (
+        dict(t=16, d=256, f=384, e=80, k=8, seed=13, dtype="bfloat16"),
+        (10, 20), None, None),
+    "one_row": (dict(t=1, d=128, f=256, e=16, k=4, seed=14), (0, 8), None,
+                128),
+    "t_100_in_a_tile_of_128": (
+        dict(t=100, d=128, f=256, e=16, k=4, seed=15), (2, 10), None, 256),
+    "a_whole_tile_of_128_rows": (
+        dict(t=128, d=128, f=256, e=8, k=4, seed=16), (0, 4), None, None),
+    "a_whole_tile_of_128_rows_bf16": (
+        dict(t=128, d=128, f=256, e=8, k=4, seed=17, dtype="bfloat16"),
+        (0, 4), None, 128),
+    "every_pair_on_one_held_expert": (
+        dict(t=16, d=128, f=256, e=8, k=1, seed=18, send_all_to=3),
+        (2, 6), None, 128),
+    "an_expert_nobody_chose": (
+        dict(t=16, d=128, f=256, e=16, k=4, seed=19, keep_off=(5, 7)),
+        (4, 8), None, 128),
+    "no_pair_on_any_held_expert": (
+        dict(t=16, d=128, f=256, e=16, k=4, seed=20, keep_off=(4, 8)),
+        (4, 8), None, 128),
+    "padded_batch_rows_off": (
+        dict(t=16, d=128, f=256, e=16, k=4, seed=21), (0, 8),
+        [True] * 5 + [False] * 11, 128),
+    "padded_batch_rows_off_bf16": (
+        dict(t=16, d=128, f=256, e=16, k=4, seed=22, dtype="bfloat16"),
+        (0, 8), [False] * 3 + [True] * 13, 128),
+    "more_held_experts_than_pairs": (       # tiles: T k = 6, not 16 held
+        dict(t=3, d=128, f=256, e=16, k=2, seed=23), (0, 16), None, 128),
+    "f_of_five_blocks": (                   # 1,280's shape in small
+        dict(t=16, d=128, f=640, e=16, k=4, seed=24), (0, 8), None, 128),
+}
+
+
+@pytest.mark.parametrize("name", list(KERNEL_CASES))
+def test_kernel_matches_the_scan_and_the_dense_loop(name, monkeypatch):
+    import jax.numpy as jnp
+
+    args, (lo, hi), rows_on, block = KERNEL_CASES[name]
+    c = _case(**args)
+    valid = None if rows_on is None else jnp.asarray(rows_on)
+    want, want_load = _through(c, lo, hi, valid, monkeypatch, kernel=False)
+    got, load = _through(c, lo, hi, valid, monkeypatch, kernel=True,
+                         block=block)
+    bf16 = args.get("dtype") == "bfloat16"
+    # The same operands and float32 sums: the order of the sums differs.
+    np.testing.assert_allclose(got, want, atol=2e-5 if bf16 else 5e-6)
+    np.testing.assert_allclose(
+        got, _dense(c, c["experts"], c["weights"], lo, hi, rows_on),
+        atol=5e-2 if bf16 else 2e-5)
+    # The pairs on each held expert, to the integer.
+    np.testing.assert_array_equal(load, want_load)
+    experts = np.asarray(c["experts"])
+    on = np.ones(len(experts), bool) if rows_on is None else np.asarray(
+        rows_on)
+    np.testing.assert_array_equal(
+        load, [(experts[on] == e).sum() for e in range(lo, hi)])
+    if name == "every_pair_on_one_held_expert":
+        assert load.tolist() == [0, 16, 0, 0]
+    if name == "an_expert_nobody_chose":
+        assert load.tolist()[1:3] == [0, 0] and np.asarray(load).any()
+    if name == "no_pair_on_any_held_expert":
+        assert not np.asarray(load).any() and not np.asarray(got).any()
+    if rows_on is not None:
+        assert not np.asarray(got)[~on].any()
+
+
+def test_a_prompt_of_several_tiles_an_expert_keeps_the_scan(monkeypatch):
+    """More rows than a tile: an expert may have several tiles, and the
+    kernel, which gives every touched expert the whole batch, is not
+    chosen; the scan's result is the dense loop's."""
+    import jax
+
+    from ray_tpu.ops import experts as ex
+
+    def no_kernel(*args, **kwargs):
+        raise AssertionError("the kernel was chosen for a prompt")
+
+    c = _case(t=300, d=128, f=256, e=8, k=4, seed=25)
+    monkeypatch.setattr(ex, "grouped_ffn_kernel", no_kernel)
+    got, load = _held(c, c["experts"], c["weights"], 0, 4)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ex.kernel_eligible(128, 128, 256, c["w_gate"].dtype)
+    again, _ = _held(c, c["experts"], c["weights"], 0, 4)
+    np.testing.assert_array_equal(got, again)
+    np.testing.assert_allclose(
+        got, _dense(c, c["experts"], c["weights"], 0, 4), atol=1e-5)
+    assert int(load.max()) > ex._ROWS_MOST      # several tiles an expert
+
+
+def test_eligibility_follows_backend_rows_widths_and_dtype(monkeypatch):
+    """Off the chip nothing is eligible; on it, a batch of one tile,
+    bfloat16 or float32 weights and weight blocks of whole lanes: what
+    the code can see, no option."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import experts as ex
+
+    assert not ex.kernel_eligible(16, 3072, 1024, jnp.bfloat16)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ex.kernel_eligible(16, 3072, 1024, jnp.bfloat16)
+    assert ex.kernel_eligible(128, 4096, 1280, jnp.bfloat16)
+    assert ex.kernel_eligible(16, 3072, 1024, jnp.float32)
+    assert ex.kernel_eligible(16, 3072, 1024, "bfloat16")
+    assert not ex.kernel_eligible(256, 3072, 1024, jnp.bfloat16)  # a prompt
+    assert not ex.kernel_eligible(4096, 4096, 1280, jnp.bfloat16)
+    assert not ex.kernel_eligible(16, 3072, 1024, jnp.float16)
+    assert not ex.kernel_eligible(16, 3072, 1024, jnp.float8_e4m3fn)
+    assert not ex.kernel_eligible(16, 32, 24, jnp.bfloat16)  # the unit tests'
+    assert not ex.kernel_eligible(16, 3072, 1000, jnp.bfloat16)
+
+
+@pytest.mark.parametrize("d, f, want", [
+    # laguna-s-2.1's experts, [3072, 1024] in bf16: the whole of f.
+    (3072, 1024, 1024),
+    # solar-open2-250b's, [4096, 1280]: 1,280 = 2 x 640; the whole of it,
+    # two deep, would be 63 MB.
+    (4096, 1280, 640),
+    # Wider still: the budget decides. Narrow: never more than f itself.
+    (8192, 2048, 512), (1024, 256, 256), (1024, 128, 128),
+], ids=["laguna", "solar", "wide", "narrow", "one_lane_block"])
+def test_f_block_follows_the_widths_and_the_vmem_budget(d, f, want):
+    from ray_tpu.ops import experts as ex
+
+    block = ex.f_block(d, f, 2)
+    assert block == want
+    assert f % block == 0 and block % 128 == 0
+    # Three blocks, two deep, inside the budget.
+    assert 2 * 3 * d * block * 2 <= ex._VMEM_FOR_WEIGHTS
+    # Four-byte operands halve what fits.
+    assert ex.f_block(d, f, 4) <= block

@@ -8,6 +8,8 @@ for a described v5e at `olmo-1b`'s widths: nothing runs, but the chip's
 compiler says what it would refuse, and whether it would copy the pool.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -648,8 +650,9 @@ def test_prefill_flash_forward_compiles_for_v5e(one_chip, no_compile_cache,
 def test_laguna_decode_step_compiles_for_v5e_with_both_pools_in_place(
         one_chip, no_compile_cache, monkeypatch):
     """The whole (16, 512) decode step at the published widths, the
-    kernel steered on: both groups' pools are aliased to the outputs,
-    twelve kernel calls (3 global, 9 window), and beside its arguments
+    kernels steered on: both groups' pools are aliased to the outputs,
+    twelve attention kernel calls (3 global, 9 window), eleven of the
+    experts' kernel, and beside its arguments
     the program holds tens of megabytes: no copy of a pool or of a
     layer's expert matrices."""
     import json
@@ -660,10 +663,12 @@ def test_laguna_decode_step_compiles_for_v5e_with_both_pools_in_place(
 
     from benchmarks.harness import manifest
     from ray_tpu.models.laguna import init_params
+    from ray_tpu.ops import experts as ex
     from ray_tpu.ops import paged_attention as pa
     from ray_tpu.serve.engine import LagunaEngineModel
 
     monkeypatch.setattr(pa, "kernel_eligible", lambda *heads: True)
+    monkeypatch.setattr(ex, "kernel_eligible", lambda t, *widths: t <= 128)
     family = manifest.load_family("laguna")
     with open(os.path.join(manifest.ROOT, "benchmarks", "configs",
                            "laguna-s-2.1.json")) as f:
@@ -690,6 +695,50 @@ def test_laguna_decode_step_compiles_for_v5e_with_both_pools_in_place(
                                             sharding=one_chip)).compile()
     memory, text = compiled.memory_analysis(), compiled.as_text()
     assert text.count("paged_window_decode_attention") >= 9
+    # Eleven expert layers, each one call of the grouped FFN kernel.
+    assert text.count("held_experts_ffn_decode") >= 11
     both = (int(np.prod(GLOBAL_POOL)) + int(np.prod(WINDOW_POOL))) * 2
     assert both <= memory.alias_size_in_bytes < 1.01 * both
     assert memory.temp_size_in_bytes < 100e6
+
+
+# ---------------------------------------------------------------------------
+# the held experts' grouped gated-FFN kernel (`ops/experts.py`), compiled
+# for the chip at both sparse cells' decode shapes, at a short prompt's
+# whole tile of 128 rows and in float32, with the block `f_block` chooses.
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("tokens, top_k, n_held, d, f, dtype", [
+    (16, 10, 32, 3072, 1024, "bfloat16"),   # repo-context's decode step
+    (16, 8, 40, 4096, 1280, "bfloat16"),    # decode-wide's
+    (1, 10, 32, 3072, 1024, "bfloat16"),    # a lone row: ten tiles, not 32
+    (128, 8, 40, 4096, 1280, "bfloat16"),   # a short prompt: one whole tile
+    (8, 10, 32, 3072, 1024, "float32"),     # float32 weights: tiles of 8
+], ids=["repo_context_decode", "decode_wide_decode", "one_row",
+        "a_prompt_of_128", "float32_weights"])
+def test_experts_kernel_compiles_for_v5e_at_the_cells_shapes(
+        one_chip, no_compile_cache, monkeypatch, tokens, top_k, n_held, d,
+        f, dtype):
+    """`held_experts_ffn` whole, the kernel steered on (the backend here
+    is the CPU): beside its arguments the program holds no copy, slice or
+    conversion of an expert stack (0.2 GB a matrix at either cell)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import experts as ex
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ex.kernel_eligible(tokens, d, f, dtype)
+
+    def spec(shape, dtype=dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(functools.partial(
+        ex.held_experts_ffn, held=(n_held, 2 * n_held))).lower(
+        spec((tokens, d), jnp.float32), spec((tokens, top_k), jnp.int32),
+        spec((tokens, top_k), jnp.float32), spec((n_held, d, f)),
+        spec((n_held, d, f)), spec((n_held, f, d))).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "held_experts_ffn_decode" in text
+    assert "while" not in text                  # no scan beside it
+    stack = n_held * d * f * jnp.dtype(dtype).itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < stack / 64
