@@ -24,11 +24,12 @@ ENGINE = {"paged_decode": True, "max_batch_size": 3, "block_size": 16,
 TOLERANCE = 2e-4       # float32 against float32; a stale state gives ~1
 
 
-def _serve(widths=TOY, seed=7, **engine):
+def _serve(widths=TOY, seed=7, max_seq_len=128, **engine):
     from ray_tpu.serve.engine import InferenceEngine
 
     served = FAMILY.build_serving(
-        widths, {"max_seq_len": 128, "engine": dict(ENGINE, **engine)}, seed)
+        widths, {"max_seq_len": max_seq_len,
+                 "engine": dict(ENGINE, **engine)}, seed)
     return served, InferenceEngine(served["model"], served["engine_config"])
 
 
@@ -64,6 +65,42 @@ def test_prefill_then_decode_through_the_cache_matches_the_reference(toy, n):
     for j, row in enumerate(got):
         assert _gap(row, want[n - 1 + j]) < TOLERANCE, (n, j)
     assert engine.cache.stats()["state_slots_in_use"] == 0
+
+
+def test_a_long_prompt_through_the_prompt_kernel_matches_the_reference(
+        monkeypatch):
+    """A prompt of more rows than a tile through `held_experts_ffn_prefill`
+    inside the engine's prefill, steered on here and interpreted, at a
+    `d_model` of one sublane of 128 lanes (the kernel moves a row as its
+    sublanes), against the reference; the decode steps keep the scan,
+    and the model counts one program by the kernel."""
+    from functools import partial
+
+    from ray_tpu.ops import experts as ex
+
+    widths = dict(TOY, d_model=128)
+    n, steps = 130, 2
+    prompt = np.random.default_rng(n).integers(
+        2, TOY["vocab_size"], n).tolist()
+    calls, interpreted = [], partial(ex.held_experts_ffn_prefill,
+                                     interpret=True)
+
+    def kernel(*args, **kwargs):
+        calls.append(args[0].shape)
+        return interpreted(*args, **kwargs)
+
+    monkeypatch.setattr(ex, "kernel_eligible",
+                        lambda t, *widths: t > ex._ROWS_MOST)
+    monkeypatch.setattr(ex, "held_experts_ffn_prefill", kernel)
+    served, engine = _serve(widths, max_seq_len=512)
+    got, tokens = FAMILY.drive(engine, served, prompt, steps, "prompt-kernel")
+    assert calls and set(calls) == {(256, 128)}
+    stats = engine.stats()
+    assert (stats["moe_steps_kernel"], stats["moe_steps_scan"]) == (1, steps)
+    want = np.asarray(FAMILY.reference_logits(widths)(
+        served["params"], np.asarray(tokens, np.int32)))
+    for j, row in enumerate(got):
+        assert _gap(row, want[n - 1 + j]) < TOLERANCE, (n, j)
 
 
 def test_a_model_of_one_period_runs_inline_and_matches(toy):

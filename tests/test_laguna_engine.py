@@ -139,6 +139,44 @@ def test_the_experts_kernel_through_the_engine_matches_the_reference(
     assert served["own_limits"][-1]["ok"]
 
 
+def test_a_long_prompt_through_the_prompt_kernel_matches_the_reference(
+        monkeypatch):
+    """A prompt of more rows than a tile through `held_experts_ffn_prefill`
+    inside the engine's prefill, steered on here and interpreted, at a
+    `d_model` of one sublane of 128 lanes (the kernel moves a row as its
+    sublanes): every sparse layer's call, against the reference; the
+    decode steps keep the scan, and the model counts one program by the
+    kernel."""
+    from functools import partial
+
+    from ray_tpu.ops import experts as ex
+
+    widths = dict(TOY, d_model=128)
+    n, steps = 130, 2
+    prompt = np.random.default_rng(n).integers(
+        2, TOY["vocab_size"], n).tolist()
+    calls, interpreted = [], partial(ex.held_experts_ffn_prefill,
+                                     interpret=True)
+
+    def kernel(*args, **kwargs):
+        calls.append(args[0].shape)
+        return interpreted(*args, **kwargs)
+
+    monkeypatch.setattr(ex, "kernel_eligible",
+                        lambda t, *widths: t > ex._ROWS_MOST)
+    monkeypatch.setattr(ex, "held_experts_ffn_prefill", kernel)
+    served, engine = _serve(widths)
+    got, tokens = FAMILY.drive(engine, served, prompt, steps, "prompt-kernel")
+    assert calls == [(256, 128)] * (TOY["n_periods"] * 4 - 1)
+    stats = engine.stats()
+    assert (stats["moe_steps_kernel"], stats["moe_steps_scan"]) == (1, steps)
+    want = np.asarray(FAMILY.reference_logits(widths)(
+        served["params"], np.asarray(tokens, np.int32)))
+    for j, row in enumerate(got):
+        assert _gap(row, want[n - 1 + j]) < TOLERANCE, (n, j)
+    assert served["own_limits"][-1]["ok"]
+
+
 def test_a_window_one_block_short_is_off_the_reference(toy):
     """The same weights served with a window of 8 instead of 24: rows
     past position 8 differ from the reference's."""

@@ -1,6 +1,9 @@
 """The dropless expert layer that is told which experts it holds
 (`ops/experts.py`), against a dense loop over (token, choice) pairs."""
 
+import functools
+import re
+
 import numpy as np
 import pytest
 
@@ -177,18 +180,25 @@ def _case(t, d, f, e, k, seed, dtype="float32", send_all_to=None,
             "w_down": mat(e, f, d)}
 
 
-def _through(c, lo, hi, valid, monkeypatch, kernel: bool, block=None):
+def _through(c, lo, hi, valid, monkeypatch, kernel: bool, block=None,
+             tiles=None):
     """`held_experts_ffn` over a case through the scan, or through the
-    kernel steered on and interpreted (the backend here is the CPU)."""
+    kernels steered on and interpreted (the backend here is the CPU): a
+    batch of one tile through `grouped_ffn_kernel` at `block`, a longer
+    prompt through `held_experts_ffn_prefill` at `tiles` (rows a tile,
+    columns a block), the rule's where None."""
     from functools import partial
 
     from ray_tpu.ops import experts as ex
 
+    monkeypatch.setattr(ex, "kernel_eligible", lambda t, *widths: kernel)
     if kernel:
-        monkeypatch.setattr(ex, "kernel_eligible",
-                            lambda t, *widths: t <= ex._ROWS_MOST)
         monkeypatch.setattr(ex, "grouped_ffn_kernel", partial(
             ex.grouped_ffn_kernel, interpret=True, block=block))
+        monkeypatch.setattr(ex, "held_experts_ffn_prefill", partial(
+            ex.held_experts_ffn_prefill, interpret=True))
+        if tiles is not None:
+            monkeypatch.setattr(ex, "prefill_tiles", lambda *shape: tiles)
     return _held(c, c["experts"], c["weights"], lo, hi, valid)
 
 
@@ -271,33 +281,209 @@ def test_kernel_matches_the_scan_and_the_dense_loop(name, monkeypatch):
         assert not np.asarray(got)[~on].any()
 
 
-def test_a_prompt_of_several_tiles_an_expert_keeps_the_scan(monkeypatch):
-    """More rows than a tile: an expert may have several tiles, and the
-    kernel, which gives every touched expert the whole batch, is not
-    chosen; the scan's result is the dense loop's."""
+PROMPT_CASES = {
+    # name: (case arguments, held, rows off, (rows a tile, columns a
+    # block) or None for the rule's). A row is d / 128 sublanes and whole
+    # tiles of the chip's memory at d a multiple of 1,024.
+    "rows_256_k_2": (
+        dict(t=256, d=1024, f=256, e=16, k=2, seed=30), (0, 8), None, None),
+    "rows_256_k_8": (
+        dict(t=256, d=1024, f=256, e=32, k=8, seed=31), (8, 16), None,
+        None),
+    "rows_512_k_2": (
+        dict(t=512, d=1024, f=256, e=16, k=2, seed=32), (4, 12), None,
+        None),
+    "rows_512_k_8_bf16": (
+        dict(t=512, d=1024, f=256, e=32, k=8, seed=33, dtype="bfloat16"),
+        (0, 8), None, None),
+    "rows_1024_k_2_bf16": (
+        dict(t=1024, d=1024, f=128, e=16, k=2, seed=34, dtype="bfloat16"),
+        (0, 4), None, None),
+    "rows_1024_k_8": (
+        dict(t=1024, d=1024, f=128, e=64, k=8, seed=35), (16, 24), None,
+        (256, 128)),
+    # 256 pairs on one expert: two tiles of 128 in a row, one weight fetch.
+    "every_pair_on_one_held_expert": (
+        dict(t=256, d=1024, f=256, e=8, k=1, seed=36, send_all_to=3),
+        (2, 6), None, None),
+    "no_pair_on_any_held_expert": (
+        dict(t=256, d=1024, f=256, e=16, k=4, seed=37, keep_off=(4, 8)),
+        (4, 8), None, None),
+    "an_expert_nobody_chose": (
+        dict(t=256, d=1024, f=256, e=16, k=4, seed=38, keep_off=(5, 7)),
+        (4, 8), None, None),
+    "padded_rows_off": (
+        dict(t=256, d=1024, f=256, e=16, k=4, seed=39), (0, 8),
+        [True] * 150 + [False] * 106, None),
+    "padded_rows_off_bf16": (
+        dict(t=512, d=1024, f=256, e=16, k=4, seed=40, dtype="bfloat16"),
+        (0, 8), [False] * 40 + [True] * 300 + [False] * 172, (64, 256)),
+    "rows_300_no_multiple_of_the_tile": (
+        dict(t=300, d=1024, f=256, e=8, k=4, seed=41), (0, 4), None, None),
+    "rows_300_in_tiles_of_32_bf16": (
+        dict(t=300, d=1024, f=256, e=8, k=4, seed=42, dtype="bfloat16"),
+        (4, 8), None, (32, 256)),
+    "f_of_three_blocks": (
+        dict(t=256, d=1024, f=384, e=16, k=4, seed=43), (0, 8), None,
+        (128, 128)),
+    "f_of_three_blocks_bf16": (
+        dict(t=256, d=2048, f=384, e=16, k=4, seed=44, dtype="bfloat16"),
+        (8, 16), None, (128, 128)),
+}
+
+
+@pytest.mark.parametrize("name", list(PROMPT_CASES))
+def test_prompt_kernel_matches_the_scan_and_the_dense_loop(name,
+                                                           monkeypatch):
+    """More rows than a tile: on the chip `held_experts_ffn_prefill`
+    over the expert-sorted row tiles, which gathers its own rows and
+    folds its own result; here interpreted beside the scan."""
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import experts as ex
+
+    args, (lo, hi), rows_on, tiles = PROMPT_CASES[name]
+    c = _case(**args)
+    assert args["t"] > ex._ROWS_MOST
+    valid = None if rows_on is None else jnp.asarray(rows_on)
+    want, want_load = _through(c, lo, hi, valid, monkeypatch, kernel=False)
+    got, load = _through(c, lo, hi, valid, monkeypatch, kernel=True,
+                         tiles=tiles)
+    bf16 = args.get("dtype") == "bfloat16"
+    # The same operands, float32 sums and order of tiles; several blocks
+    # of f add up in another order.
+    np.testing.assert_allclose(got, want, atol=2e-5 if bf16 else 5e-6)
+    on = (np.ones(args["t"], bool) if rows_on is None
+          else np.asarray(rows_on))
+    some = np.flatnonzero(on)[::17]             # the dense loop is slow
+    dense = _dense(dict(c, y=c["y"][some]), np.asarray(c["experts"])[some],
+                   np.asarray(c["weights"])[some], lo, hi)
+    np.testing.assert_allclose(np.asarray(got)[some], dense,
+                               atol=5e-2 if bf16 else 5e-5)
+    np.testing.assert_array_equal(load, want_load)
+    experts = np.asarray(c["experts"])
+    np.testing.assert_array_equal(
+        load, [(experts[on] == e).sum() for e in range(lo, hi)])
+    if name == "every_pair_on_one_held_expert":
+        assert load.tolist() == [0, 256, 0, 0]
+    if name == "an_expert_nobody_chose":
+        assert load.tolist()[1:3] == [0, 0] and np.asarray(load).any()
+    if name == "no_pair_on_any_held_expert":
+        assert not np.asarray(load).any() and not np.asarray(got).any()
+    if "rows_1024" in name or "rows_300" in name:
+        assert int(load.max()) > ex._ROWS_MOST  # several tiles an expert
+    if rows_on is not None:
+        assert not np.asarray(got)[~on].any()
+
+
+def test_the_prompt_kernels_name_is_not_the_decode_kernels():
+    """`held_experts_ffn_decode_roofline` sums every operation whose
+    name begins with the decode kernel's and divides the decode steps'
+    bytes by it: a prompt's kernel under such a name would add time and
+    no bytes."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks.layer_metrics import held_experts_ffn_decode_roofline
+    from ray_tpu.ops import experts as ex
+
+    def spec(*shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    def names(fn, *args, **static):
+        text = str(jax.make_jaxpr(functools.partial(fn, **static))(*args))
+        return set(re.findall(r"held_experts_ffn\w*", text))
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    stacks = [spec(4, 1024, 256, dtype=bf16), spec(4, 1024, 256, dtype=bf16),
+              spec(4, 256, 1024, dtype=bf16)]
+    prompt = names(ex.held_experts_ffn_prefill, spec(256, 1024, dtype=f32),
+                   spec(12), spec(12), spec(12), spec(1024),
+                   spec(1024, dtype=f32), *stacks, tile=128, block=256)
+    decode = names(ex.grouped_ffn_kernel, spec(16, 1024, dtype=bf16),
+                   spec(4), spec(4, 16, 1, dtype=f32), *stacks)
+    assert prompt == {"held_experts_ffn_prefill"}
+    assert decode == {"held_experts_ffn_decode"}
+    reads = held_experts_ffn_decode_roofline.KERNEL
+    assert all(reads.match(name) for name in decode)
+    assert not any(reads.match(name) for name in prompt)
+
+
+@pytest.mark.parametrize("rows, d, f, dtype, want", [
+    # Both sparse cells' prefill buckets.
+    (1024, 3072, 1024, "bfloat16", True), (4096, 3072, 1024, "bfloat16", True),
+    (256, 4096, 1280, "bfloat16", True), (512, 4096, 1280, "float32", True),
+    # The [T, d] float32 sum alone is 100 MB: the scan.
+    (8192, 3072, 1024, "bfloat16", False),
+    # A row that is no whole tiles of the chip's memory: the scan.
+    (1024, 3072 + 128, 1024, "bfloat16", False),
+    (1024, 3072, 1000, "bfloat16", False),
+    (1024, 3072, 1024, "float16", False),
+], ids=["repo_context_1024", "repo_context_4096", "decode_wide_256",
+        "float32_512", "sum_past_vmem", "row_of_25_sublanes",
+        "f_no_whole_lanes", "float16"])
+def test_a_prompt_takes_the_kernel_where_its_sum_fits_vmem(
+        rows, d, f, dtype, want, monkeypatch):
     import jax
 
     from ray_tpu.ops import experts as ex
 
-    def no_kernel(*args, **kwargs):
-        raise AssertionError("the kernel was chosen for a prompt")
-
-    c = _case(t=300, d=128, f=256, e=8, k=4, seed=25)
-    monkeypatch.setattr(ex, "grouped_ffn_kernel", no_kernel)
-    got, load = _held(c, c["experts"], c["weights"], 0, 4)
+    assert not ex.kernel_eligible(rows, d, f, dtype)        # off the chip
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert ex.kernel_eligible(128, 128, 256, c["w_gate"].dtype)
-    again, _ = _held(c, c["experts"], c["weights"], 0, 4)
-    np.testing.assert_array_equal(got, again)
-    np.testing.assert_allclose(
-        got, _dense(c, c["experts"], c["weights"], 0, 4), atol=1e-5)
-    assert int(load.max()) > ex._ROWS_MOST      # several tiles an expert
+    assert ex.kernel_eligible(rows, d, f, dtype) == want
+    if want:
+        itemsize = 4 if dtype == "float32" else 2
+        tile, block = ex.prefill_tiles(rows, 8, 32, d, f, itemsize)
+        assert rows % tile == 0 and f % block == 0 and block % 128 == 0
+        assert ex._prefill_vmem(rows, d, tile, block,
+                                itemsize) <= ex._VMEM_MOST
+
+
+@pytest.mark.parametrize("rows, k, held, d, f, want", [
+    (1024, 10, 32, 3072, 1024, (128, 1024)),        # the rule's
+    (2048, 10, 32, 3072, 1024, (96, 512)),          # a swept entry
+    (4096, 10, 32, 3072, 1024, (192, 512)),         # a swept entry
+    (256, 8, 40, 4096, 1280, (128, 640)),           # the rule's
+    (512, 8, 40, 4096, 1280, (128, 640)),           # the rule's
+    (4096, 8, 32, 3072, 1024, (128, 1024)),         # another k: the rule's
+], ids=["repo_context_1024", "repo_context_2048", "repo_context_4096",
+        "decode_wide_256", "decode_wide_512", "unswept_k"])
+def test_prefill_tiles_at_the_cells_buckets_are_the_ones_timed(
+        rows, k, held, d, f, want):
+    """The tiles PR 46's chip runs were made with, bfloat16 weights: two
+    buckets by their swept entry, the others by the rule; every one fits
+    the chip's VMEM and tiles of no whole sublanes are not chosen."""
+    from ray_tpu.ops import experts as ex
+
+    tile, block = ex.prefill_tiles(rows, k, held, d, f, 2)
+    assert (tile, block) == want
+    assert tile % 8 == 0 and f % block == 0
+    assert ex._prefill_vmem(rows, d, tile, block, 2) <= ex._VMEM_MOST
+
+
+def test_pairs_past_the_sorts_32_bit_key_are_refused():
+    """The one sort's key is ``expert x T k + pair`` in 32 bits; a call
+    whose pairs pass it raises at trace, where a shape in reach sorts."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import experts as ex
+
+    def pairs(rows, k):
+        return (jax.ShapeDtypeStruct((rows, k), jnp.int32),
+                jax.ShapeDtypeStruct((rows, k), jnp.float32))
+
+    sort = functools.partial(ex._pairs_by_expert, n_held=32)
+    assert [a.shape for a in jax.eval_shape(sort, *pairs(4096, 10))] \
+        == [(40960,)] * 3
+    with pytest.raises(ValueError, match="32-bit key"):
+        jax.eval_shape(sort, *pairs(1 << 22, 16))
 
 
 def test_eligibility_follows_backend_rows_widths_and_dtype(monkeypatch):
-    """Off the chip nothing is eligible; on it, a batch of one tile,
-    bfloat16 or float32 weights and weight blocks of whole lanes: what
-    the code can see, no option."""
+    """Off the chip nothing is eligible; on it, bfloat16 or float32
+    weights and weight blocks of whole lanes, a batch of one tile or a
+    longer prompt (the next test): what the code can see, no option."""
     import jax
     import jax.numpy as jnp
 
@@ -309,8 +495,8 @@ def test_eligibility_follows_backend_rows_widths_and_dtype(monkeypatch):
     assert ex.kernel_eligible(128, 4096, 1280, jnp.bfloat16)
     assert ex.kernel_eligible(16, 3072, 1024, jnp.float32)
     assert ex.kernel_eligible(16, 3072, 1024, "bfloat16")
-    assert not ex.kernel_eligible(256, 3072, 1024, jnp.bfloat16)  # a prompt
-    assert not ex.kernel_eligible(4096, 4096, 1280, jnp.bfloat16)
+    assert ex.kernel_eligible(256, 3072, 1024, jnp.bfloat16)  # a prompt
+    assert ex.kernel_eligible(4096, 4096, 1280, jnp.bfloat16)
     assert not ex.kernel_eligible(16, 3072, 1024, jnp.float16)
     assert not ex.kernel_eligible(16, 3072, 1024, jnp.float8_e4m3fn)
     assert not ex.kernel_eligible(16, 32, 24, jnp.bfloat16)  # the unit tests'

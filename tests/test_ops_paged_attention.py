@@ -742,3 +742,46 @@ def test_experts_kernel_compiles_for_v5e_at_the_cells_shapes(
     assert "while" not in text                  # no scan beside it
     stack = n_held * d * f * jnp.dtype(dtype).itemsize
     assert compiled.memory_analysis().temp_size_in_bytes < stack / 64
+
+
+# The prompt's kernel (`held_experts_ffn_prefill`) at both sparse cells'
+# prefill buckets, with the tiles `prefill_tiles` chooses.
+@pytest.mark.parametrize("tokens, top_k, n_held, d, f", [
+    (1024, 10, 32, 3072, 1024), (2048, 10, 32, 3072, 1024),
+    (4096, 10, 32, 3072, 1024),             # repo-context's three buckets
+    (256, 8, 40, 4096, 1280), (512, 8, 40, 4096, 1280),     # decode-wide's
+], ids=["repo_context_1024", "repo_context_2048", "repo_context_4096",
+        "decode_wide_256", "decode_wide_512"])
+def test_experts_prompt_kernel_compiles_for_v5e_at_the_cells_buckets(
+        one_chip, no_compile_cache, monkeypatch, tokens, top_k, n_held, d,
+        f):
+    """`held_experts_ffn` whole for a prompt, the kernel steered on: the
+    chip's compiler takes the rows' copies (a row is whole sublanes), the
+    tables in SMEM (40,960 tokens and as many weights at 4,096 rows) and
+    the ``[T, d]`` float32 sum in VMEM beside the weight blocks; beside
+    its arguments the program holds nothing of ``[rows, d]`` (the rows
+    the shapes must allow are 11 times T at 4,096): no scan, no copy of
+    an expert stack, and temporaries of a few ``[T, d]``."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import experts as ex
+
+    dtype = "bfloat16"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ex.kernel_eligible(tokens, d, f, dtype)
+
+    def spec(shape, dtype=dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(functools.partial(
+        ex.held_experts_ffn, held=(n_held, 2 * n_held))).lower(
+        spec((tokens, d), jnp.float32), spec((tokens, top_k), jnp.int32),
+        spec((tokens, top_k), jnp.float32), spec((n_held, d, f)),
+        spec((n_held, d, f)), spec((n_held, f, d))).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "held_experts_ffn_prefill" in text
+    assert "held_experts_ffn_decode" not in text
+    assert "while" not in text                  # no scan beside it
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 3 * tokens * d * 4 + (1 << 20)
