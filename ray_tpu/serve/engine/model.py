@@ -350,6 +350,24 @@ class TransformerEngineModel:
     engine shares prefixes over it. The training model's capacity-drop
     MoE (`cfg.is_moe`, `ops/moe.py`) is refused here; sparse experts are
     served by `HybridEngineModel` through `ops/experts.py`.
+
+    A layer's four weight products (`qkv`, `wo`, `w13`, `w2`) take the
+    whole stack of the layers' matrices and the layer's index
+    (`ops/weight_matmul.py`: on the chip a Pallas kernel that streams
+    that layer's float32 tiles from HBM once and rounds them to bf16 in
+    VMEM; XLA's product elsewhere, chosen from backend, widths and
+    rows, no option). The programs' layer scans close over the stacks
+    and scan over the layer index and the norms alone, so nothing copies
+    a layer out of a stack. The model holds the weights in the dtype it
+    was given, in a tree of its own: `wqkv` and `w13` laid out once, at
+    construction, as 3-D ``[L, d, 3 d]`` and ``[L, d, 2 f]`` (the chip
+    keeps the training tree's 4-D ``[L, d, 3, d]`` in tiles a kernel
+    cannot read in place and would re-lay them in front of every call);
+    `wo`, `w2`, the norms and the embedding are the caller's own arrays.
+    A caller that keeps its tree (a benchmark's reference check) so
+    holds `wqkv` and `w13` twice: 3.0 GB more at `olmo-1b`'s widths,
+    where the programs used to hold a 2.15 GB bf16 copy of the stacks
+    while they ran.
     """
 
     def __init__(self, params, cfg, max_batch_size: int = 8,
@@ -363,7 +381,13 @@ class TransformerEngineModel:
         if cfg.is_moe:
             raise ValueError("TransformerEngineModel supports dense "
                              "configs only (num_experts == 0)")
-        self._params = params
+        # `wqkv [L, d, 3, d]` and `w13 [L, d, 2, f]` as `[L, d, 3 d]` and
+        # `[L, d, 2 f]`, once (one pass over the two): the shape
+        # `ops.weight_matmul`'s kernel reads in place.
+        lay = jax.jit(lambda w: w.reshape(w.shape[0], w.shape[1], -1))
+        layers = dict(params["layers"])
+        layers.update(wqkv=lay(layers["wqkv"]), w13=lay(layers["w13"]))
+        self._params = dict(params, layers=layers)
         self._cfg = cfg
         self.vocab_size = cfg.vocab_size
         self.eos_token = 1
@@ -398,6 +422,13 @@ class TransformerEngineModel:
         self.decode_attn_inplace_steps = 0
         self.decode_kv_pages_read = 0
         self.decode_kv_page_groups_read = 0
+        # Programs run (a prefill, a decode step: each runs every layer's
+        # four weight products once) by the body those products got when
+        # the program was traced, `ops.weight_matmul`'s kernel or XLA's
+        # product, found by their rows.
+        self.dense_steps_kernel = 0
+        self.dense_steps_xla = 0
+        self._products_kernel_at: Dict[int, bool] = {}
         # Host side of the calls, in seconds, each fed by its
         # `flight.span`: input padding and upload (`prep`), the call of
         # the jitted function (`dispatch`; a paged decode step's one
@@ -441,6 +472,40 @@ class TransformerEngineModel:
         return jnp.concatenate([x1 * c - x2 * s, x1 * s + x2 * c],
                                axis=-1).astype(x.dtype)
 
+    def _products(self, layers, rows: int, phase: str):
+        """The weight products of a program of `rows` rows, while it is
+        traced: ``product(y, key, li)`` is ``y [rows, K]`` against layer
+        `li` of the stack ``layers[key]``, float32. The layer scan's
+        body closes over the stacks through it. In a device trace the
+        kernel's calls are `stacked_weight_matmul_<phase>`. Notes which
+        body the rows get, for `_count_products`."""
+        from ray_tpu.ops.weight_matmul import (kernel_eligible,
+                                               stacked_weight_matmul)
+
+        stacks = {key: layers[key] for key in ("wqkv", "wo", "w13", "w2")}
+        self._products_kernel_at[rows] = all(
+            kernel_eligible(rows, *w.shape[1:], w.dtype)
+            for w in stacks.values())
+        name = f"stacked_weight_matmul_{phase}"
+
+        def product(y, key, li):
+            return stacked_weight_matmul(y, stacks[key], li, name)
+
+        return product
+
+    def _count_products(self, rows: int) -> None:
+        """A program of `rows` rows has been dispatched (so traced)."""
+        if self._products_kernel_at.get(rows):
+            self.dense_steps_kernel += 1
+        else:
+            self.dense_steps_xla += 1
+
+    def _layer_xs(self, layers):
+        """What the layer scans scan over: the two norms' scales and the
+        layer's index. The four matrix stacks are closed over."""
+        return (layers["ln1"], layers["ln2"],
+                self._jnp.arange(self._cfg.n_layers, dtype=self._jnp.int32))
+
     def _build_prefill(self, s_pad: int):
         import jax
         import jax.numpy as jnp
@@ -451,6 +516,7 @@ class TransformerEngineModel:
         self.jit_compiles += 1
         cfg = self._cfg
         h, hd = cfg.n_heads, cfg.head_dim
+        d, f = h * hd, cfg.d_ff
 
         def prefill(params, tokens, length):
             # tokens [S_pad] int32 (zero-padded), length scalar int32.
@@ -461,15 +527,15 @@ class TransformerEngineModel:
             pos = jnp.arange(s_pad)
             valid = pos < length
             causal = (pos[:, None] >= pos[None, :]) & valid[None, :]
+            product = self._products(params["layers"], s_pad, "prefill")
 
-            def layer(x, lp):
+            def layer(x, inputs):
+                ln1, ln2, li = inputs
                 with jax.named_scope("attn"):
-                    y = _rmsnorm(x, lp["ln1"])
-                    qkv = jnp.einsum("bsd,dkh->kbsh", y,
-                                     lp["wqkv"].astype(act))
-                    q = qkv[0].reshape(1, s_pad, h, hd)
-                    k = qkv[1].reshape(1, s_pad, h, hd)
-                    v = qkv[2].reshape(1, s_pad, h, hd)
+                    y = _rmsnorm(x, ln1)
+                    qkv = product(y[0], "wqkv", li)       # [S, 3 d]
+                    q, k, v = (qkv[:, i * d:(i + 1) * d].reshape(
+                        1, s_pad, h, hd) for i in range(3))
                     q = apply_rotary(q, cos, sin, pos)
                     k = apply_rotary(k, cos, sin, pos)
                     scale = hd ** -0.5
@@ -479,18 +545,17 @@ class TransformerEngineModel:
                     scores = jnp.where(causal[None, None], scores, -1e30)
                     probs = jax.nn.softmax(scores, axis=-1).astype(act)
                     o = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
-                    x = x + (o.reshape(1, s_pad, h * hd)
-                             @ lp["wo"].astype(act))
+                    x = x + product(o.reshape(s_pad, d), "wo", li)[None]
                 with jax.named_scope("mlp"):
-                    y = _rmsnorm(x, lp["ln2"])
-                    gu = jnp.einsum("bsd,dkf->kbsf", y,
-                                    lp["w13"].astype(act))
-                    x = x + ((jax.nn.silu(gu[0]) * gu[1])
-                             @ lp["w2"].astype(act))
+                    y = _rmsnorm(x, ln2)
+                    gu = product(y[0], "w13", li)         # [S, 2 f]
+                    x = x + product(jax.nn.silu(gu[:, :f]) * gu[:, f:],
+                                    "w2", li)[None]
                 kv = jnp.stack([k[0], v[0]], axis=1)  # [S, 2, H, hd]
                 return x, kv
 
-            x, kvs = jax.lax.scan(layer, x, params["layers"])
+            x, kvs = jax.lax.scan(layer, x,
+                                  self._layer_xs(params["layers"]))
             with jax.named_scope("lm_head"):
                 x = _rmsnorm(x, params["ln_f"])
                 last = x[0, length - 1]
@@ -518,6 +583,8 @@ class TransformerEngineModel:
 
         cfg = self._cfg
         h, hd = cfg.n_heads, cfg.head_dim
+        d, f = h * hd, cfg.d_ff
+        product = self._products(params["layers"], t_pad, "prefill")
 
         act = jnp.float32
         with jax.named_scope("embed"):
@@ -532,14 +599,12 @@ class TransformerEngineModel:
         prefix_l = prefix.transpose(1, 0, 2, 3, 4)  # [L,P,2,H,hd]
 
         def layer(x, inputs):
-            lp, pkv = inputs               # pkv [P, 2, H, hd]
+            (ln1, ln2, li), pkv = inputs   # pkv [P, 2, H, hd]
             with jax.named_scope("attn"):
-                y = _rmsnorm(x, lp["ln1"])
-                qkv = jnp.einsum("bsd,dkh->kbsh", y,
-                                 lp["wqkv"].astype(act))
-                q = qkv[0].reshape(1, t_pad, h, hd)
-                k = qkv[1].reshape(1, t_pad, h, hd)
-                v = qkv[2].reshape(1, t_pad, h, hd)
+                y = _rmsnorm(x, ln1)
+                qkv = product(y[0], "wqkv", li)           # [T, 3 d]
+                q, k, v = (qkv[:, i * d:(i + 1) * d].reshape(
+                    1, t_pad, h, hd) for i in range(3))
                 q = apply_rotary(q, cos, sin, tpos)
                 k = apply_rotary(k, cos, sin, tpos)
                 pk = pkv[None, :, 0]           # [1, P, H, hd]
@@ -561,18 +626,17 @@ class TransformerEngineModel:
                                 probs[..., :p_pad], pv)
                      + jnp.einsum("bhqk,bkhd->bqhd",
                                   probs[..., p_pad:], v))
-                x = x + (o.reshape(1, t_pad, h * hd)
-                         @ lp["wo"].astype(act))
+                x = x + product(o.reshape(t_pad, d), "wo", li)[None]
             with jax.named_scope("mlp"):
-                y = _rmsnorm(x, lp["ln2"])
-                gu = jnp.einsum("bsd,dkf->kbsf", y,
-                                lp["w13"].astype(act))
-                x = x + ((jax.nn.silu(gu[0]) * gu[1])
-                         @ lp["w2"].astype(act))
+                y = _rmsnorm(x, ln2)
+                gu = product(y[0], "w13", li)             # [T, 2 f]
+                x = x + product(jax.nn.silu(gu[:, :f]) * gu[:, f:],
+                                "w2", li)[None]
             kv = jnp.stack([k[0], v[0]], axis=1)   # [T, 2, H, hd]
             return x, kv
 
-        x, kvs = jax.lax.scan(layer, x, (params["layers"], prefix_l))
+        x, kvs = jax.lax.scan(
+            layer, x, (self._layer_xs(params["layers"]), prefix_l))
         with jax.named_scope("lm_head"):
             x = _rmsnorm(x, params["ln_f"])
             last = x[0, t_len - 1]
@@ -627,7 +691,9 @@ class TransformerEngineModel:
 
         cfg = self._cfg
         h, hd = cfg.n_heads, cfg.head_dim
+        d, f = h * hd, cfg.d_ff
         rot1 = self._rot1
+        product = self._products(params["layers"], b_pad, "decode")
 
         # tokens [B], positions [B], tables [B, nb_pad],
         # pool [N, bs, L, 2, H, hd].
@@ -637,30 +703,28 @@ class TransformerEngineModel:
         cos, sin = rotary_freqs(hd, cfg.max_seq_len, cfg.rope_theta)
 
         def layer(x, inputs):
-            lp, li = inputs            # li: this layer's index in pool
+            # li: this layer's index, in the pool and in the stacks.
+            ln1, ln2, li = inputs
             with jax.named_scope("attn"):
-                y = _rmsnorm(x, lp["ln1"])
-                qkv = jnp.einsum("bd,dkh->kbh", y,
-                                 lp["wqkv"].astype(act))
-                q = qkv[0].reshape(b_pad, h, hd)
-                k = qkv[1].reshape(b_pad, h, hd)
-                v = qkv[2].reshape(b_pad, h, hd)
+                y = _rmsnorm(x, ln1)
+                qkv = product(y, "wqkv", li)              # [B, 3 d]
+                q, k, v = (qkv[:, i * d:(i + 1) * d].reshape(b_pad, h, hd)
+                           for i in range(3))
                 q = rot1(q, cos, sin, positions)
                 k = rot1(k, cos, sin, positions)
                 with jax.named_scope("kv_gather"):
                     o = paged_decode_attention(q, k, v, pool, tables,
                                                positions, li)
-                x = x + o.reshape(b_pad, h * hd) @ lp["wo"].astype(act)
+                x = x + product(o.reshape(b_pad, d), "wo", li)
             with jax.named_scope("mlp"):
-                y = _rmsnorm(x, lp["ln2"])
-                gu = jnp.einsum("bd,dkf->kbf", y, lp["w13"].astype(act))
-                x = x + ((jax.nn.silu(gu[0]) * gu[1])
-                         @ lp["w2"].astype(act))
+                y = _rmsnorm(x, ln2)
+                gu = product(y, "w13", li)                # [B, 2 f]
+                x = x + product(jax.nn.silu(gu[:, :f]) * gu[:, f:],
+                                "w2", li)
             return x, jnp.stack([k, v], axis=1)   # [B, 2, H, hd]
 
-        x, new_kv = jax.lax.scan(
-            layer, x, (params["layers"],
-                       jnp.arange(cfg.n_layers, dtype=jnp.int32)))
+        x, new_kv = jax.lax.scan(layer, x,
+                                 self._layer_xs(params["layers"]))
         with jax.named_scope("lm_head"):
             x = _rmsnorm(x, params["ln_f"])
             logits = jnp.einsum("bd,vd->bv", x,
@@ -735,6 +799,7 @@ class TransformerEngineModel:
         with flight.span("model", "prefill.dispatch", None, phase,
                          "prefill_dispatch_s"):
             logits, kv = fn(self._params, *args)
+        self._count_products(s_pad)
         with flight.span("model", "prefill.logits_wait", None, phase,
                          "prefill_wait_s"):
             logits = np.asarray(logits)
@@ -776,8 +841,12 @@ class TransformerEngineModel:
                          "decode_prep_s"):
             b_pad = _next_pow2(max(b, 1))
             pages = [int(p) // block_size + 1 for p in positions]
-            nb = max(pages)
-            nb_pad = _next_pow2(max(nb, 1))
+            # A table may come wider than its row's live pages (a fully
+            # cached prompt's one read-only step comes as wide as its
+            # next step: `scheduler._prefill_inner`); the bucket holds
+            # the widest.
+            nb = max(max(pages), max(len(t) for t in block_tables))
+            nb_pad = _next_pow2(nb)
             if self._attn_inplace:
                 self.decode_attn_inplace_steps += 1
                 self.decode_kv_pages_read += sum(pages)
@@ -813,6 +882,7 @@ class TransformerEngineModel:
             # transfer, and no separate call that lets other threads in
             # before the step is on the device.
             ids, logits, new_pool = fn(*args)
+        self._count_products(b_pad)
         with flight.span("model", "decode.logits_wait", None, phase,
                          "decode_wait_s"):
             ids = np.asarray(ids)
@@ -854,6 +924,7 @@ class TransformerEngineModel:
         with flight.span("model", "prefill.dispatch", None, phase,
                          "prefill_dispatch_s"):
             logits, kv = fn(self._params, *args, pool, table)
+        self._count_products(t_pad)
         with flight.span("model", "prefill.logits_wait", None, phase,
                          "prefill_wait_s"):
             logits = np.asarray(logits)

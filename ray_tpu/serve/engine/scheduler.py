@@ -684,8 +684,14 @@ class InferenceEngine:
             # is empty (the shared block already holds this KV; writing
             # it would force a pointless COW): a read-only fused step,
             # and mutate_pool re-binds the buffer the donating jit
-            # returns.
+            # returns. The table goes in as wide as the sequence's next
+            # step will have it (position n: one more column where the
+            # prompt ends on a block's edge; the column names block 0,
+            # which the position masks), so that a model that compiles
+            # a program a table width runs this step in that step's
+            # program and never compiles one for it alone.
             table = self.cache.block_table(seq.seq_id)
+            table += [0] * (n // self.config.block_size + 1 - len(table))
             logits = self.cache.mutate_pool(
                 lambda pool: self.model.decode_paged(
                     pool, [table], [tokens[-1]], [n - 1], [], [],
@@ -1002,6 +1008,11 @@ class InferenceEngine:
         programs run, prefills and decode steps (each runs every expert
         layer once), by the body their expert layers were traced with:
         `ops.experts`' Pallas kernel or its scan.
+        `dense_steps_kernel` and `dense_steps_xla` count the dense
+        model's programs run (each runs every layer's four weight
+        products once) by the body those products were traced with:
+        `ops.weight_matmul`'s Pallas kernel or XLA's product; 0 for a
+        model that has no such products.
         `state_slot_steps_in_use` and `state_slot_steps` sum, over paged
         steps, the state slots in use and the slots there are (a model
         with per-sequence state; 0 otherwise); `cache` has the gauges
@@ -1060,6 +1071,9 @@ class InferenceEngine:
                 self.model, "moe_max_expert_load", 0),
             "moe_steps_kernel": getattr(self.model, "moe_steps_kernel", 0),
             "moe_steps_scan": getattr(self.model, "moe_steps_scan", 0),
+            "dense_steps_kernel": getattr(
+                self.model, "dense_steps_kernel", 0),
+            "dense_steps_xla": getattr(self.model, "dense_steps_xla", 0),
             "state_slot_steps_in_use": self.cache.state_slot_steps_in_use,
             "state_slot_steps": self.cache.state_slot_steps,
             "decode_kv_pages_read_global": getattr(

@@ -5,7 +5,8 @@ of 128, against the XLA body it replaces off the chip and against a
 dense float64 reference that gathers each row's cache by hand. At the
 end of the file the kernel and the whole paged decode step are compiled
 for a described v5e at `olmo-1b`'s widths: nothing runs, but the chip's
-compiler says what it would refuse, and whether it would copy the pool.
+compiler says what it would refuse, and whether it would copy the pool
+or, since PR 50 (`ops/weight_matmul.py`), the weights.
 """
 
 import functools
@@ -314,6 +315,54 @@ def test_engine_step_through_the_kernel_matches_the_xla_step(monkeypatch):
     assert plain["decode_kv_page_groups_read"] == 0
 
 
+def test_engine_programs_through_the_weight_kernel_match_xlas(monkeypatch):
+    """A prompt and three decode steps through the engine's cache twice
+    (`benchmarks/families/dense.py`'s drive): XLA's product of the
+    bf16-rounded operands (what the chip's default precision makes of
+    the float32 product), and `ops.weight_matmul`'s kernel, steered on
+    here and interpreted. The same greedy tokens, and the counters say
+    which body every program was traced with. The logits differ by what
+    a bf16 rounding makes of another order of a float32 sum: where a
+    sum's last bit moves a value across a bf16 boundary, the next
+    product sees 2^-8 of it (1e-3 of the logits' size here; a wrong
+    layer index or a missed block of K gives about 1)."""
+    from functools import partial
+
+    import jax.numpy as jnp
+
+    from benchmarks.families import dense
+    from ray_tpu.ops import weight_matmul as wm
+    from ray_tpu.serve.engine import EngineConfig, InferenceEngine
+
+    def rounded(x, w_stack, layer, name=None):
+        return jnp.dot(x.astype(jnp.bfloat16),
+                       w_stack[layer].astype(jnp.bfloat16),
+                       preferred_element_type=jnp.float32)
+
+    def drive():
+        model = _tiny_model()
+        engine = InferenceEngine(model, EngineConfig(
+            max_batch_size=4, block_size=4, num_blocks=32))
+        prompt = np.random.default_rng(5).integers(2, 64, 9).tolist()
+        rows, tokens = dense.drive(engine, {"model": model}, prompt, 3, "s")
+        return np.stack(rows), tokens, engine.stats()
+
+    monkeypatch.setattr(wm, "stacked_weight_matmul", rounded)
+    want, want_tokens, plain = drive()
+    assert (plain["dense_steps_kernel"], plain["dense_steps_xla"]) == (0, 4)
+    monkeypatch.undo()
+
+    monkeypatch.setattr(wm, "kernel_eligible", lambda rows, k, n, dt: True)
+    monkeypatch.setattr(wm, "stacked_weight_matmul_kernel", partial(
+        wm.stacked_weight_matmul_kernel, interpret=True))
+    got, got_tokens, stats = drive()
+    assert got_tokens == want_tokens
+    assert np.sqrt(np.mean((got - want) ** 2)) < 3e-3 * np.sqrt(
+        np.mean(want ** 2))
+    # One prefill (a bucket of 16 rows) and three steps (a row each).
+    assert (stats["dense_steps_kernel"], stats["dense_steps_xla"]) == (4, 0)
+
+
 # ---------------------------------------------------------------------------
 # compiled for the chip, not run (no chip here)
 # ---------------------------------------------------------------------------
@@ -370,39 +419,139 @@ def test_kernel_compiles_for_v5e_at_olmo_widths(one_chip, no_compile_cache,
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
+# `olmo-1b`'s four stacks of layer matrices as the engine model holds
+# them, and one layer of each: what no instruction of a compiled program
+# may produce (a rounded or re-laid copy of a stack, a layer copied out).
+STACKS = {"wqkv": (16, 2048, 6144), "wo": (16, 2048, 2048),
+          "w13": (16, 2048, 16384), "w2": (16, 8192, 2048)}
+
+
+def _weight_sized_results(text: str):
+    """The instructions of a compiled program whose result is a stack of
+    layer matrices or one layer's matrix (bf16 or float32, by any name of
+    instruction but the program's parameters and the loop's plumbing)."""
+    import re
+
+    shapes = set(STACKS.values()) | {s[1:] for s in STACKS.values()}
+    found = []
+    for dtype, dims, op in re.findall(
+            r"= (f32|bf16)\[([\d,]+)\]\S* ([\w-]+)\(", text):
+        shape = tuple(int(d) for d in dims.split(",") if d != "1")
+        if shape in shapes and op not in (
+                "parameter", "get-tuple-element", "bitcast"):
+            found.append(f"{dtype}[{dims}] {op}")
+    return found
+
+
+def _abstract_olmo_model(one_chip):
+    """`TransformerEngineModel` at `olmo-1b`'s widths over shapes, not
+    arrays: built under `eval_shape`, which also gives the shapes of the
+    tree it lays out for its programs."""
+    import jax
+
+    from ray_tpu.models.transformer import TransformerConfig, init_params
+    from ray_tpu.serve.engine import TransformerEngineModel
+
+    cfg = TransformerConfig(**OLMO, max_seq_len=1024)
+    held = {}
+
+    def build(params):
+        held["model"] = TransformerEngineModel(params, cfg)
+        return held["model"]._params
+
+    laid = jax.eval_shape(build, jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    assert {k: laid["layers"][k].shape for k in STACKS} == STACKS
+    return held["model"], jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        laid)
+
+
+def _steer_both_kernels_on(monkeypatch):
+    """The backend here is the CPU: what the chip would choose."""
+    from ray_tpu.ops import paged_attention as pa
+    from ray_tpu.ops import weight_matmul as wm
+
+    rows_most = wm._ROWS_MOST
+    monkeypatch.setattr(pa, "kernel_eligible", lambda h, hd, hkv=None: True)
+    monkeypatch.setattr(wm, "kernel_eligible",
+                        lambda rows, k, n, dtype: rows <= rows_most)
+
+
 def test_decode_step_compiles_for_v5e_without_a_copy_of_the_pool(
         one_chip, no_compile_cache, monkeypatch):
     """The whole `jit_decode_paged` of the (8, 64) bucket at `olmo-1b`'s
-    widths, the kernel steered on (the backend here is the CPU): the
-    donated pool is aliased to the output, and what the program holds
-    besides its arguments is the bf16 weights (2.15 GB), not a dense
-    copy of the batch's cache nor a second pool."""
+    widths, both kernels steered on: the donated pool is aliased to the
+    output, and the program holds next to nothing besides its arguments:
+    no dense copy of the batch's cache, no second pool and, since PR 50,
+    no bf16 copy of the weight stacks (2.15 GB until then) nor a layer
+    copied out of one; the float32 stacks are the kernels' operands as
+    the model holds them."""
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.models.transformer import TransformerConfig, init_params
-    from ray_tpu.ops import paged_attention as pa
-    from ray_tpu.serve.engine import TransformerEngineModel
-
-    monkeypatch.setattr(pa, "kernel_eligible", lambda h, hd, hkv=None: True)
-    cfg = TransformerConfig(**OLMO, max_seq_len=1024)
-    params = jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
-    model = TransformerEngineModel(params, cfg)
+    _steer_both_kernels_on(monkeypatch)
+    model, laid = _abstract_olmo_model(one_chip)
     assert (1024, 16) + model.kv_token_shape == POOL
     compiled = model._build_decode_paged(8, 64, 16).lower(
-        jax.ShapeDtypeStruct(POOL, jnp.float32, sharding=one_chip), params,
+        jax.ShapeDtypeStruct(POOL, jnp.float32, sharding=one_chip), laid,
         jax.ShapeDtypeStruct((8, 4 + 64), jnp.int32,
                              sharding=one_chip)).compile()
     text, memory = compiled.as_text(), compiled.memory_analysis()
-    assert "tpu_custom_call" in text
+    # One paged attention call and four weight products a layer.
+    assert text.count("tpu_custom_call") == 5
     pool_bytes = int(np.prod(POOL)) * 4
     assert memory.alias_size_in_bytes == pool_bytes
-    assert memory.temp_size_in_bytes < 2.5e9
+    assert memory.temp_size_in_bytes < 64 << 20
     for dense in ("f32[512,16,16,2,16,128]", "f32[16,8,1024,2,16,128]",
-                  "f32[8,1024,16,2,16,128]"):
+                  "f32[8,1024,16,2,16,128]", "bf16[16,2048,", "bf16[16,8192,"):
         assert dense not in text
+    assert _weight_sized_results(text) == []
+
+
+def test_prefill_bucket_compiles_for_v5e_without_a_copy_of_the_weights(
+        one_chip, no_compile_cache, monkeypatch):
+    """`jit_prefill` of the 256 bucket, whose products take the kernel:
+    as the decode step, no rounded copy of a stack and no layer copied
+    out of one."""
+    import jax
+    import jax.numpy as jnp
+
+    _steer_both_kernels_on(monkeypatch)
+    model, laid = _abstract_olmo_model(one_chip)
+    compiled = model._build_prefill(256).lower(
+        laid, jax.ShapeDtypeStruct((256,), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    assert text.count("tpu_custom_call") == 4
+    assert memory.temp_size_in_bytes < 64 << 20
+    assert "bf16[16,2048," not in text and "bf16[16,8192," not in text
+    assert _weight_sized_results(text) == []
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("rows", [8, 256])
+@pytest.mark.parametrize("name", list(STACKS))
+def test_weight_kernel_compiles_for_v5e_at_olmo_widths(
+        one_chip, no_compile_cache, name, rows, dtype):
+    """`stacked_weight_matmul_kernel` alone at each of the model's four
+    ``(K, N)``, a decode step's rows and a prompt's, the tiles the chip
+    runs with: the stack is an operand as it stands."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import weight_matmul as wm
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    _, k, n = STACKS[name]
+    compiled = jax.jit(wm.stacked_weight_matmul_kernel).lower(
+        spec((rows, k)), spec(STACKS[name], dtype),
+        spec((), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+    assert _weight_sized_results(compiled.as_text()) == []
 
 
 # ---------------------------------------------------------------------------

@@ -564,6 +564,37 @@ def test_transformer_engine_sharing_matches_no_sharing(tiny_transformer):
     assert outs[0] == outs[1]
 
 
+def test_a_fully_cached_prompt_compiles_no_program_of_its_own(
+        tiny_transformer):
+    """A prompt that ends on a block's edge, sent again once it is
+    cached: its first token is one read-only decode step at position
+    ``n - 1``, whose live pages are a column fewer than its next step's.
+    The scheduler hands the table in at the next step's width and the
+    model's bucket holds the widest table, so the step runs in the
+    program the first request's decode steps compiled: same tokens,
+    no compile (a closed loop that wraps around its requests inside a
+    measured window met one: PERF.md, PR 50)."""
+    from ray_tpu.serve.engine import (EngineConfig, InferenceEngine,
+                                      TransformerEngineModel)
+
+    params, cfg = tiny_transformer
+    model = TransformerEngineModel(params, cfg, max_batch_size=2)
+    model.eos_token = None
+    eng = InferenceEngine(model, EngineConfig(
+        max_batch_size=2, block_size=4, num_blocks=32))
+    prompt = [3, 17, 42, 9, 21, 5, 11, 2]      # two whole blocks
+    first = eng.submit(prompt, 3)
+    while eng.step():
+        pass
+    compiled, hits = model.jit_compiles, eng.prefix_hit_tokens
+    again = eng.submit(prompt, 3)
+    while eng.step():
+        pass
+    assert eng.prefix_hit_tokens - hits == len(prompt)
+    assert again.tokens_so_far() == first.tokens_so_far()
+    assert model.jit_compiles == compiled
+
+
 def test_prefill_flight_event_carries_prefix_hit():
     """Engine prefill events in the flight ring report the shared-
     prefill savings (`prefix_hit`) so /api/timeline shows them."""

@@ -339,14 +339,16 @@ def test_device_programs_are_named_after_their_functions(tiny_transformer):
     params, cfg = tiny_transformer
     model = TransformerEngineModel(params, cfg, max_batch_size=2)
     i32 = jnp.int32
-    assert _module_name(model._build_prefill(8), params,
+    # The model's programs take its own tree (two stacks re-laid).
+    laid = model._params
+    assert _module_name(model._build_prefill(8), laid,
                         jnp.zeros((8,), i32), i32(3)) == "jit_prefill"
     pool = jnp.zeros((16, 4) + model.kv_token_shape, jnp.float32)
     assert _module_name(
-        model._build_prefill_paged(8, 2, 4), params, jnp.zeros((8,), i32),
+        model._build_prefill_paged(8, 2, 4), laid, jnp.zeros((8,), i32),
         i32(4), i32(3), pool, jnp.zeros((2,), i32)) == "jit_prefill_paged"
     assert _module_name(
-        model._build_decode_paged(2, 2, 4), pool, params,
+        model._build_decode_paged(2, 2, 4), pool, laid,
         jnp.zeros((2, 4 + 2), i32)) == "jit_decode_paged"
 
     optimizer = optax.sgd(0.1)
