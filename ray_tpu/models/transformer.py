@@ -24,6 +24,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu.ops.attention import attention
+from ray_tpu.ops.flash_attention import ATTN_LSE, ATTN_OUT
 from ray_tpu.ops.moe import moe_ffn
 from ray_tpu.ops.rotary import apply_rotary, rotary_freqs
 
@@ -129,15 +130,21 @@ _REMAT_POLICIES = {
     None: None,
     "dots": "dots_with_no_batch_dims_saveable",
     "dots_batch": "dots_saveable",
-    # Save ONLY the attention outputs (checkpoint_name'd in _layer):
-    # ~B*S*d bf16 per layer — 50 MB at 16x1024x1536 — buys the backward
-    # out of re-running the flash kernel (the priciest recompute in the
-    # layer: the only O(S^2) op). The FLOPs/HBM sweet spot on v5e.
-    "save_attn": ("names", ("attn_out",)),
-    # Additionally save the fused QKV projection (3x bigger than
-    # attn_out): backward skips the qkv GEMM recompute too. Worth it
-    # when HBM has headroom.
-    "save_attn_qkv": ("names", ("attn_out", "qkv")),
+    # Save what the attention's backward needs of its forward, under the
+    # names the path that `ops.attention.attention` took gives it: ONE
+    # copy of the output a layer (~B*S*d bf16, 50 MB at 16x1024x1536),
+    # [B,S,H,D] from the XLA and ring paths, [B,S,H*D] from the flash
+    # kernels' VJP with its [B,H,1,S] float32 log-sum-exp (B*H*S*4 bytes,
+    # 2.1 MB at 8x32x2048). The backward then re-runs the layer up to q,
+    # k and v (norm, the qkv product, rotary, the kernels' transposes) and
+    # from the output on (wo, the FFN whole), and no attention: the only
+    # O(S^2) op of the layer, on the flash path a forward kernel a layer.
+    "save_attn": ("names", (ATTN_OUT, ATTN_LSE)),
+    # Additionally save the fused QKV projection (3x bigger than the
+    # output): the backward skips the norm and the qkv product too, and
+    # recomputes rotary, the transposes, wo and the FFN. Worth it when
+    # HBM has headroom.
+    "save_attn_qkv": ("names", (ATTN_OUT, ATTN_LSE, "qkv")),
 }
 
 
@@ -179,9 +186,9 @@ def _layer(x, lp, cfg: TransformerConfig, mesh, manual_sp, cos, sin,
             qkv_sharding = NamedSharding(mesh, P("dp", "sp", "tp", None))
             q, k, v = (jax.lax.with_sharding_constraint(t, qkv_sharding)
                        for t in (q, k, v))
+        # attention() names what a checkpoint policy may save of it.
         o = attention(q, k, v, causal=True, mesh=mesh, positions=positions,
                       manual_sp=manual_sp)
-        o = checkpoint_name(o, "attn_out")
         x = x + (o.reshape(b, s, h * hd) @ lp["wo"].astype(act))
 
     # -- FFN block ------------------------------------------------------

@@ -22,9 +22,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.ops.flash_attention import (FlashTiles, flash_mha,
+from ray_tpu.ops.flash_attention import (ATTN_OUT, FlashTiles, flash_mha,
                                           prefill_attention_fwd)
 
 _NEG_INF = -1e30
@@ -87,6 +88,9 @@ def flash_attention_tpu(q, k, v):
     score matrix, differentiable (custom VJP).
 
     q/k/v: [B, S, H, D] (we transpose to the kernels' [B, H, S, D]).
+    Under `jax.checkpoint` the kernels' VJP names what it keeps itself
+    (the output, once, and the log-sum-exp); the transpose handed back
+    here carries no name, so a names policy keeps one copy.
     """
     tiles = flash_tiles(q.shape[1], q.shape[3])
     qt, kt, vt = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
@@ -211,7 +215,15 @@ def attention(q, k, v, *, causal: bool = True, mesh=None,
     - `manual_sp=True`: already inside a shard_map manual over `sp_axis`
       (e.g. a pipeline stage) — run the ring body directly.
     - mesh shards the sequence axis — wrap in shard_map ring.
+    - one chip, causal, default positions, a shape the kernels take: the
+      flash kernels.
     - otherwise plain attention.
+
+    Whichever path ran, what a checkpointed layer may keep of it for the
+    backward carries the `jax.checkpoint` name ``attn_out``: the output
+    itself on the XLA and ring paths; on the flash path the kernels' VJP
+    names it (as ``[B, S, H * D]``) and its log-sum-exp, ``attn_lse``
+    (`ops/flash_attention.py:_flash_mha_fwd`).
     """
     if manual_sp:
         if positions is None:
@@ -220,18 +232,21 @@ def attention(q, k, v, *, causal: bool = True, mesh=None,
             # positions from the rank instead.
             rank = jax.lax.axis_index(sp_axis)
             positions = rank * q.shape[1] + jnp.arange(q.shape[1])
-        return ring_attention_manual(q, k, v, positions, axis_name=sp_axis,
-                                     causal=causal)
-    if mesh is not None and sp_axis in mesh.axis_names \
+        o = ring_attention_manual(q, k, v, positions, axis_name=sp_axis,
+                                  causal=causal)
+    elif mesh is not None and sp_axis in mesh.axis_names \
             and mesh.shape[sp_axis] > 1:
-        return ring_attention(q, k, v, mesh=mesh, axis_name=sp_axis,
-                              causal=causal, positions=positions)
-    # positions=None means standard arange — exactly what the fused TPU
-    # kernel's causal mask implements. Single-chip only: a pallas_call has
-    # no SPMD partitioning rule, so under a >1-device mesh (dp/tp sharded
-    # q/k/v) we stay on the XLA path instead of forcing an all-gather.
-    unsharded = mesh is None or all(
-        mesh.shape[a] == 1 for a in mesh.axis_names)
-    if positions is None and causal and unsharded and _flash_eligible(q):
-        return flash_attention_tpu(q, k, v)
-    return plain_attention(q, k, v, causal=causal, positions=positions)
+        o = ring_attention(q, k, v, mesh=mesh, axis_name=sp_axis,
+                           causal=causal, positions=positions)
+    else:
+        # positions=None means standard arange — exactly what the fused
+        # TPU kernel's causal mask implements. Single-chip only: a
+        # pallas_call has no SPMD partitioning rule, so under a >1-device
+        # mesh (dp/tp sharded q/k/v) we stay on the XLA path instead of
+        # forcing an all-gather.
+        unsharded = mesh is None or all(
+            mesh.shape[a] == 1 for a in mesh.axis_names)
+        if positions is None and causal and unsharded and _flash_eligible(q):
+            return flash_attention_tpu(q, k, v)
+        o = plain_attention(q, k, v, causal=causal, positions=positions)
+    return checkpoint_name(o, ATTN_OUT)

@@ -31,6 +31,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 # Pallas is imported where it is used: `ops.attention` imports this module
 # in every process, and the import costs a worker that never reaches the
@@ -41,6 +42,12 @@ _NT = (((1,), (1,)), ((), ()))      # a @ b.T
 # Every grid is (batch, head, the kernel's row tile, the major it walks
 # and accumulates over).
 _GRID_SEMANTICS = ("parallel", "parallel", "parallel", "arbitrary")
+# `jax.checkpoint` names of what the backward needs of the forward beside
+# q, k and v: the output and its log-sum-exp. `ops.attention.attention`
+# puts the first on its other paths' output; the second exists on the
+# kernels' path alone.
+ATTN_OUT = "attn_out"
+ATTN_LSE = "attn_lse"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -356,13 +363,35 @@ def flash_mha(q, k, v, tiles: FlashTiles, interpret: bool = False):
 
 def _flash_mha_fwd(q, k, v, tiles, interpret):
     o, lse = _forward(q, k, v, tiles, True, interpret)
-    return o, (q, k, v, o, lse)
+    # What the backward needs of the forward beside q, k and v gets its
+    # `jax.checkpoint` name here, and the named values ARE the primal
+    # output and the residuals: a policy that saves the two names leaves
+    # the backward no forward kernel to run again (unnamed, or named on
+    # the caller's transpose alone, `jax.checkpoint` re-runs this rule
+    # for them). The output is kept as ``[B, S, H * D]``: whole lanes at
+    # any head size (as the kernel wrote it, ``[.., S, 64]`` lies in
+    # tiles of 128 lanes in HBM: twice the bytes a layer), and the shape
+    # the caller's output projection reads, so the way back to the
+    # kernels' shape folds into the caller's transpose.
+    b, h, s, d = o.shape
+    rows = checkpoint_name(o.transpose(0, 2, 1, 3).reshape(b, s, h * d),
+                           ATTN_OUT)
+    lse = checkpoint_name(lse, ATTN_LSE)
+    return (rows.reshape(b, s, h, d).transpose(0, 2, 1, 3),
+            (q, k, v, rows, lse))
 
 
 def _flash_mha_bwd(tiles, interpret, residuals, do):
-    q, k, v, o, lse = residuals
-    di = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32),
-                 axis=-1)[:, :, None, :]                    # [B, H, 1, S]
+    q, k, v, rows, lse = residuals
+    b, h, s, d = q.shape
+    # Of the output the kernels take only `di`, a head's sum of o * do a
+    # query: summed in the shape the output was kept in (`do` is the
+    # transpose of a cotangent that arrives in that shape), so no
+    # `[B, H, S, D]` copy of the output is made for it.
+    do_rows = do.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+    di = jnp.sum((rows.astype(jnp.float32) * do_rows.astype(jnp.float32)
+                  ).reshape(b, s, h, d), axis=-1)           # [B, S, H]
+    di = di.transpose(0, 2, 1)[:, :, None, :]               # [B, H, 1, S]
     dk, dv = _backward_dkv(q, k, v, do, lse, di, tiles, interpret)
     dq = _backward_dq(q, k, v, do, lse, di, tiles, interpret)
     return dq, dk, dv

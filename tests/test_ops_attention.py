@@ -159,6 +159,126 @@ def test_flash_kernels_match_plain_attention(walk, head_dim):
                                    rtol=2e-4, atol=2e-4, err_msg=name)
 
 
+# ---------------------------------------------------------------------------
+# what a checkpointed layer keeps of its attention, by the path it took
+# ---------------------------------------------------------------------------
+_B, _S, _H = 1, 256, 2
+
+
+def _checkpointed_layer(policy, head_dim, flash, monkeypatch,
+                        checkpoint=True):
+    """``layer(x, lp)`` through `models.transformer._layer`, checkpointed
+    with the tree's own policy as `backbone` does it, and its arguments;
+    with `flash` the dispatch is steered onto the kernels, interpreted."""
+    import importlib
+
+    from ray_tpu.models import transformer as T
+    from ray_tpu.ops.flash_attention import flash_mha
+    from ray_tpu.ops.rotary import rotary_freqs
+
+    if flash:
+        A = importlib.import_module("ray_tpu.ops.attention")
+        monkeypatch.setattr(A, "_flash_eligible", lambda q: True)
+        monkeypatch.setattr(
+            A, "flash_mha", lambda q, k, v, tiles: flash_mha(q, k, v, tiles,
+                                                             True))
+    cfg = T.TransformerConfig(
+        vocab_size=64, d_model=_H * head_dim, n_layers=1, n_heads=_H,
+        d_ff=128, max_seq_len=_S, dtype=jnp.float32, remat=checkpoint,
+        remat_policy=policy)
+    lp = jax.tree.map(lambda a: a[0],
+                      T.init_params(jax.random.PRNGKey(3), cfg)["layers"])
+    x = jax.random.normal(jax.random.PRNGKey(4), (_B, _S, cfg.d_model),
+                          jnp.float32)
+    cos, sin = rotary_freqs(head_dim, _S, cfg.rope_theta)
+
+    # A function of its own a case: `jax.checkpoint` keeps the trace of a
+    # function it has seen, whichever path `attention()` took in it.
+    def fn(*args):
+        return T._layer(*args)
+
+    if checkpoint:
+        fn = T._checkpoint_layer(fn, policy)
+    return (lambda x, lp: fn(x, lp, cfg, None, False, cos, sin, None)[0],
+            x, lp)
+
+
+def _grads(layer):
+    return jax.grad(lambda x, lp: jnp.sum(jnp.square(layer(x, lp))),
+                    argnums=(0, 1))
+
+
+def _pallas_call_names(jaxpr):
+    """The `name` of every `pallas_call` in `jaxpr` and its sub-jaxprs."""
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["name"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names.extend(_pallas_call_names(sub))
+    return names
+
+
+_POLICIES = ["save_attn", "save_attn_qkv"]
+
+
+@pytest.mark.parametrize("head_dim", [64, 128])
+@pytest.mark.parametrize("policy", _POLICIES)
+def test_checkpointed_layer_runs_each_flash_kernel_once(
+        policy, head_dim, monkeypatch):
+    """The backward of a checkpointed layer holds no second forward
+    kernel: the VJP's residuals are the saved names."""
+    layer, x, lp = _checkpointed_layer(policy, head_dim, True, monkeypatch)
+    names = _pallas_call_names(
+        jax.make_jaxpr(_grads(layer))(x, lp).jaxpr)
+    kinds = sorted(n.split("_block")[0] for n in names)
+    assert kinds == ["flash_mha_bwd_dkv", "flash_mha_bwd_dq",
+                     "flash_mha_fwd"], names
+
+
+@pytest.mark.parametrize("head_dim", [64, 128])
+@pytest.mark.parametrize("policy", _POLICIES)
+def test_checkpointed_flash_layer_gradients_are_the_whole_layers(
+        policy, head_dim, monkeypatch):
+    """Keeping the kernels' output and statistics changes nothing the
+    layer computes: the gradients of the layer without `jax.checkpoint`."""
+    layer, x, lp = _checkpointed_layer(policy, head_dim, True, monkeypatch)
+    whole, _, _ = _checkpointed_layer(policy, head_dim, True, monkeypatch,
+                                      checkpoint=False)
+    got = _grads(layer)(x, lp)
+    want = _grads(whole)(x, lp)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("head_dim", [64, 128])
+@pytest.mark.parametrize("policy", _POLICIES)
+@pytest.mark.parametrize("path", ["flash", "plain"])
+def test_checkpointed_layer_saves_one_copy_of_the_attention_output(
+        path, policy, head_dim, monkeypatch):
+    """Beside its arguments the layer keeps the names of its policy: on
+    the kernels' path their output once, as ``[B, S, H * D]`` (not the
+    kernels' ``[B, H, S, D]`` and the caller's ``[B, S, H, D]`` both), with
+    the ``[B, H, 1, S]`` statistics; on the plain path the ``[B, S, H, D]``
+    output alone, as ever."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    layer, x, lp = _checkpointed_layer(policy, head_dim, path == "flash",
+                                       monkeypatch)
+    kept = sorted((aval.shape, aval.dtype.name)
+                  for aval, why in saved_residuals(layer, x, lp)
+                  if not why.startswith(("from the argument",
+                                         "from a constant")))
+    want = [((_B, _S, _H * head_dim), "float32"),
+            ((_B, _H, 1, _S), "float32")]
+    if path == "plain":
+        want = [((_B, _S, _H, head_dim), "float32")]
+    if policy == "save_attn_qkv":
+        want.append(((3, _B, _S, _H * head_dim), "float32"))
+    assert kept == sorted(want)
+
+
 class _null:
     def __enter__(self):
         return None

@@ -735,6 +735,46 @@ def test_flash_kernels_compile_for_v5e_at_the_chosen_tiles(
         assert kernel in text, kernel
 
 
+@pytest.mark.parametrize("policy", ["save_attn", "save_attn_qkv"])
+def test_checkpointed_train_step_compiles_for_v5e_with_one_flash_forward(
+        one_chip, no_compile_cache, monkeypatch, policy):
+    """The gradient of `smollm2-1.7b.train.seq2k`'s layers (two of them,
+    a small vocabulary) through the kernels: the chip's compiler is handed
+    the forward kernel once a layer, not a second time for the backward,
+    and what the layers keep of the attention is the output in whole
+    lanes (the kernels' own ``[.., 2048, 64]`` lies in tiles of 128 lanes:
+    twice the bytes) and a row of statistics a query."""
+    import importlib
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.transformer import (TransformerConfig, init_params,
+                                            lm_loss)
+
+    # `attention()` asks the backend, and the backend here is the CPU.
+    monkeypatch.setattr(importlib.import_module("ray_tpu.ops.attention"),
+                        "_flash_eligible", lambda q: True)
+    cfg = TransformerConfig(
+        vocab_size=1024, d_model=2048, n_layers=2, n_heads=32, d_ff=8192,
+        max_seq_len=2048, dtype=jnp.bfloat16, remat=True,
+        remat_policy=policy)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    batch = {"tokens": jax.ShapeDtypeStruct((8, 2049), jnp.int32,
+                                            sharding=one_chip)}
+    text = jax.jit(jax.grad(lambda p, b: lm_loss(p, b, cfg))).lower(
+        params, batch).compile().as_text()
+    calls = re.findall(r"custom-call\(.*?flash_mha_(fwd|bwd_dkv|bwd_dq)_",
+                       text)
+    assert sorted(calls) == ["bwd_dkv", "bwd_dq", "fwd"], calls
+    # The two layers' kept values, stacked by the scan.
+    assert "f32[2,8,32,1,2048]" in text
+    assert "bf16[2,8,32,2048,64]" not in text
+
+
 # ---------------------------------------------------------------------------
 # the window-and-global model's kernels (`laguna-s-2.1`), compiled for the
 # chip at its published widths: the windowed paged decode kernel at groups
