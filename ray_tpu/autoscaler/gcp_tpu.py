@@ -28,6 +28,7 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
 from ray_tpu.autoscaler.node_provider import NodeProvider, NodeType
+from ray_tpu.core import procs
 
 logger = logging.getLogger(__name__)
 
@@ -175,14 +176,7 @@ class FakeGcpTpuApi(GcpTpuApi):
         sl = self.slices.pop(name, None)
         if sl is None:
             return
-        for proc in sl.procs:
-            if proc.poll() is None:
-                proc.terminate()
-        for proc in sl.procs:
-            try:
-                proc.wait(timeout=5)
-            except Exception:
-                proc.kill()
+        procs.end_processes(sl.procs, grace_s=procs.RAYLET_GRACE_S)
 
     def list_slices(self) -> List[dict]:
         return [self.get_slice(n) for n in list(self.slices)]
@@ -192,14 +186,7 @@ class FakeGcpTpuApi(GcpTpuApi):
             self.delete_slice(name)
         # Belt-and-braces: anything ever spawned dies with the fake —
         # a slice deleted mid-provisioning can otherwise strand hosts.
-        for proc in self._all_procs:
-            if proc.poll() is None:
-                proc.kill()
-        for proc in self._all_procs:
-            try:
-                proc.wait(timeout=5)
-            except Exception:
-                pass
+        procs.end_processes(self._all_procs, grace_s=procs.RAYLET_GRACE_S)
         self._all_procs.clear()
 
 

@@ -16,6 +16,8 @@ import sys
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
+from ray_tpu.core import procs
+
 
 @dataclass
 class NodeType:
@@ -79,11 +81,7 @@ class LocalNodeProvider(NodeProvider):
         node = self._nodes.pop(node_id, None)
         if node is None:
             return
-        node.proc.terminate()
-        try:
-            node.proc.wait(timeout=5)
-        except Exception:
-            node.proc.kill()
+        procs.end_processes([node.proc], grace_s=procs.RAYLET_GRACE_S)
 
     def non_terminated_nodes(self) -> List[str]:
         return [nid for nid, n in self._nodes.items()

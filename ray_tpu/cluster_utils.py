@@ -15,6 +15,7 @@ import sys
 import time
 from typing import Dict, List, Optional
 
+from ray_tpu.core import procs
 from ray_tpu.core.ids import NodeID
 from ray_tpu.core.node import NodeSupervisor, detect_node_resources
 
@@ -82,14 +83,8 @@ class Cluster:
         raise TimeoutError(f"cluster did not reach {count} nodes")
 
     def shutdown(self) -> None:
-        for proc in self._extra_raylets:
-            if proc.poll() is None:
-                proc.terminate()
-        for proc in self._extra_raylets:
-            try:
-                proc.wait(timeout=3)
-            except subprocess.TimeoutExpired:
-                proc.kill()
+        procs.end_processes(self._extra_raylets,
+                            grace_s=procs.RAYLET_GRACE_S)
         self._extra_raylets.clear()
         if self._supervisor is not None:
             self._supervisor.stop()

@@ -4463,7 +4463,6 @@ class ClusterRuntime:
                     (time.perf_counter() - _tmark) * 1e6)
             ok = True
         except BaseException as e:  # noqa: BLE001
-            self._die_if_orphaned()
             results = self._package_error(task_id, num_returns, name, e)
         finally:
             # Drop frame refs to args/value so only genuinely retained
@@ -4502,20 +4501,6 @@ class ClusterRuntime:
         return [self._package_result(oid_for(i), v)
                 for i, v in enumerate(value)]
 
-    def _die_if_orphaned(self) -> None:
-        """A worker whose raylet died is a zombie: its object store, lease
-        and chip bookkeeping are gone. Reporting the resulting plumbing
-        errors (ConnectionLost on arg fetch / result store) to the owner
-        would surface them as USER task failures, which don't retry.
-        Exit instead — the owner observes worker death as a SYSTEM
-        failure and retries/reconstructs (reference: workers exit on
-        raylet socket EOF, node_manager.cc disconnect handling)."""
-        if self.mode == "worker" and not self._raylet.connected:
-            logging.getLogger(__name__).warning(
-                "raylet connection lost mid-task; exiting so the owner "
-                "retries elsewhere")
-            os._exit(1)
-
     def _package_error(self, task_id: str, num_returns: int, name: str,
                        exc: BaseException) -> List[dict]:
         wrapped = (exc if isinstance(exc, (RayTaskError, RayActorError,
@@ -4549,11 +4534,6 @@ class ClusterRuntime:
             if attr_on:
                 attribution.record("wire.decode_task",
                                    time.perf_counter() - _t0)
-        # Refuse work the moment our raylet is gone (don't wait to fail
-        # on the result store): the pusher holds a stale lease on a dead
-        # node; exiting here converts it to a clean worker-death retry
-        # without a wasted duplicate execution.
-        self._die_if_orphaned()
         if spec.get("streaming"):
             return await self._execute_streaming(spec, actor=False)
         loop = asyncio.get_running_loop()
@@ -4755,10 +4735,6 @@ class ClusterRuntime:
 
         def run_and_reply():
             try:
-                # Refuse work the moment our raylet is gone, exactly
-                # like handle_push_task: exiting converts the stale
-                # lease into a clean worker-death retry at the owner.
-                self._die_if_orphaned()
                 reply = self._execute_task(spec)
                 if attr_on:
                     attr = {"decode": decode_us}
@@ -4867,7 +4843,6 @@ class ClusterRuntime:
                     fut.result()
                 return None
             except BaseException as e:  # noqa: BLE001
-                self._die_if_orphaned()
                 wrapped = (e if isinstance(e, RayTaskError)
                            else RayTaskError.from_exception(
                                spec.get("name", "task"), e))
@@ -5030,7 +5005,6 @@ class ClusterRuntime:
                     (time.perf_counter() - _tmark) * 1e6)
             ok = True
         except BaseException as e:  # noqa: BLE001
-            self._die_if_orphaned()
             results = self._package_error(task_id, num_returns, name, e)
         finally:
             # See _execute_task: only genuinely retained arg refs (here

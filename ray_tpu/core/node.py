@@ -17,6 +17,7 @@ import sys
 import time
 from typing import Dict, Optional
 
+from ray_tpu.core import procs
 from ray_tpu.core.ids import NodeID
 
 
@@ -161,13 +162,8 @@ class NodeSupervisor:
             self.dashboard_address = None
 
     def stop(self) -> None:
-        for name, proc in reversed(list(self.processes.items())):
-            if proc.poll() is None:
-                proc.terminate()
-        deadline = time.time() + 3
-        for proc in self.processes.values():
-            try:
-                proc.wait(timeout=max(0.1, deadline - time.time()))
-            except subprocess.TimeoutExpired:
-                proc.kill()
+        # One shared grace, sized for the slowest: a raylet waits for its
+        # own workers before it leaves, and is not killed over that.
+        procs.end_processes(reversed(list(self.processes.values())),
+                            grace_s=procs.RAYLET_GRACE_S)
         self.processes.clear()

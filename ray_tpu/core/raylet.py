@@ -19,6 +19,7 @@ import sys
 import time
 from typing import Any, Dict, List, Optional
 
+from ray_tpu.core import procs
 from ray_tpu.core.config import ray_config
 from ray_tpu.core.gcs.client import GcsClient
 from ray_tpu.core.object_store import NativeObjectStore, make_store
@@ -569,27 +570,15 @@ class Raylet(NodeLedger):
             flight.unwatch_loop(self._flight_watch)
         for t in self._tasks + list(self._monitors.values()):
             t.cancel()
-        for w in self._workers.values():
-            if w.proc.poll() is None:
-                w.proc.terminate()
-        # One shared grace window for the whole pool: the supervisor
-        # SIGKILLs *us* after ~3 s, and any worker still alive at that
-        # point would be orphaned — so escalate to SIGKILL well inside
-        # that budget rather than waiting per worker.
-        deadline = time.monotonic() + 1.5
-        for w in self._workers.values():
-            try:
-                w.proc.wait(timeout=max(0.05, deadline - time.monotonic()))
-            except Exception:
-                w.proc.kill()
+        # The monitors are gone, so the rule they kept is ours now: a
+        # worker's chips are free only when the process has exited, and
+        # whoever starts next on this machine must find them free. Off
+        # the loop: workers shutting down cleanly still talk to us.
+        await asyncio.to_thread(
+            procs.end_processes, [w.proc for w in self._workers.values()])
         self.store.shutdown()
         await self._rpc.stop()
         await self._gcs.close()
-        # Final sweep: anything that slipped in between the first loop and
-        # the RPC server going down dies hard.
-        for w in self._workers.values():
-            if w.proc.poll() is None:
-                w.proc.kill()
 
     async def _register_with_gcs(self) -> None:
         reply = await self._gcs.register_node(
@@ -998,7 +987,10 @@ class Raylet(NodeLedger):
             os.makedirs(log_dir, exist_ok=True)
         log_path = os.path.join(log_dir, f"worker-{worker_id[:8]}.log")
         out = open(log_path, "ab")
-        proc = subprocess.Popen(cmd, env=env, stdout=out, stderr=out)
+        # A worker does not outlive its raylet, whatever it is doing when
+        # the raylet dies: the kernel holds that rule (procs.py).
+        proc = subprocess.Popen(cmd, env=env, stdout=out, stderr=out,
+                                preexec_fn=procs.die_with_parent)
         out.close()  # the child holds the fd; the tailer reopens by path
         worker = _Worker(worker_id, proc)
         worker.log_path = log_path
@@ -1008,16 +1000,12 @@ class Raylet(NodeLedger):
         return worker
 
     async def _monitor_worker(self, worker: _Worker) -> None:
-        retiring_since = None
         while worker.proc.poll() is None:
             await asyncio.sleep(0.2)
             if worker.state == "dead" and worker.held:
                 # Retired while still holding chips: its lease resources
                 # wait for the exit, so the exit must come.
-                now = time.monotonic()
-                retiring_since = retiring_since or now
-                if now - retiring_since > 5.0:
-                    worker.proc.kill()
+                await asyncio.to_thread(procs.end_processes, [worker.proc])
         code = worker.proc.returncode
         if worker.held:
             self._release_lease_resources(worker)

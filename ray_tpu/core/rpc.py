@@ -247,6 +247,9 @@ class RpcServer:
         try:
             await conn.serve()
         finally:
+            # The peer hung up: let go of the transport too, or (3.12)
+            # Server.wait_closed() counts it until stop()'s wait runs out.
+            conn.close()
             self._conns.pop(conn.conn_id, None)
             on_disc = getattr(self._handlers, "on_client_disconnect", None)
             if on_disc is not None:

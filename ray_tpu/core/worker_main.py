@@ -58,21 +58,22 @@ def main() -> None:
         logging.error("raylet rejected registration; exiting")
         sys.exit(1)
 
+    # No watch on the raylet here: a worker does not outlive its raylet
+    # because the kernel SIGKILLs it when the raylet dies (the raylet
+    # spawns it with procs.die_with_parent), also in the middle of the
+    # start-up above.
     stop = threading.Event()
     signal.signal(signal.SIGTERM, lambda *a: stop.set())
-    # Watchdog: a worker must not outlive its raylet (reference: workers
-    # exit on raylet socket EOF, node_manager disconnect handling).
-    while not stop.wait(timeout=1.0):
-        if not runtime._raylet.connected:
-            logging.info("raylet connection lost; exiting")
-            break
+    stop.wait()
     # Graceful shutdown can wedge on non-daemon task threads (a user task
     # blocked in get() against a dying cluster); the process must still
-    # exit promptly or it orphans past the raylet's kill window. Arm a
-    # hard-exit backstop, attempt the clean path, then force the issue.
+    # exit before its ender's grace runs out. Arm a hard-exit backstop,
+    # attempt the clean path, then force the issue.
     import os
 
-    killer = threading.Timer(3.0, lambda: os._exit(1))
+    from ray_tpu.core.procs import WORKER_EXIT_S
+
+    killer = threading.Timer(WORKER_EXIT_S, lambda: os._exit(1))
     killer.daemon = True
     killer.start()
     try:

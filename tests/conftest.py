@@ -72,6 +72,14 @@ def _ray_tpu_processes(any_session: bool = False):
     return found
 
 
+@pytest.fixture
+def session_processes():
+    """`session_processes()`: the runtime processes this session started
+    that are alive at this moment, `[(pid, command line)]`. For tests of
+    what a shutdown leaves behind, with no grace added."""
+    return _ray_tpu_processes
+
+
 @pytest.fixture(autouse=True, scope="module")
 def _no_leaked_clusters(request):
     """Fail any module that leaks runtime processes (raylets, GCS, workers).

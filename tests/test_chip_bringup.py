@@ -61,7 +61,7 @@ def test_unset_platform_follows_the_lease_not_the_host(monkeypatch):
     assert _leased_platform() == "tpu"
 
 
-@pytest.fixture(scope="module")
+@pytest.fixture
 def one_fake_chip():
     import ray_tpu
 
@@ -73,12 +73,14 @@ def one_fake_chip():
 
 @pytest.mark.cluster
 def test_chip_lease_opens_the_tpu_or_raises_and_hands_over_after_exit(
-        one_fake_chip):
+        one_fake_chip, session_processes):
     """A worker that was leased a chip is on the explicit `tpu` platform
     and, with no chip to open, raises: no CPU devices come back. And the
     raylet used to return a retiring worker's chips to the pool in the
     same call that sent it SIGTERM, so libtpu in the next holder could
-    meet a chip the old process still owned."""
+    meet a chip the old process still owned. The session's end keeps
+    the same rule towards whatever runs next on the machine: when
+    `shutdown()` returns, nothing of the session is alive."""
     ray = one_fake_chip
 
     @ray.remote(resources={"TPU": 1.0})
@@ -112,6 +114,9 @@ def test_chip_lease_opens_the_tpu_or_raises_and_hands_over_after_exit(
     # Another function, so another lease: it waits for the only chip.
     assert ray.get(next_holder.remote(first_pid), timeout=60) == "gone"
     assert ray.get(unleased, timeout=60) == ("cpu", "cpu")
+    assert session_processes() != []
+    ray.shutdown()
+    assert session_processes() == []  # at once: no moment of grace
 
 
 @pytest.mark.cluster
