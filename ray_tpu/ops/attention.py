@@ -120,12 +120,14 @@ def plain_attention(q, k, v, *, causal: bool = True, positions=None):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def banded_attention(q, k, v, window: int = None):
-    """The plain form of `prefill_attention`: q ``[H, S, D]`` over k, v
-    ``[Hkv, S, D]`` (query head ``i`` on key head ``i // group``), causal,
-    with `window` only the keys ``j`` with ``i - j < window``. The whole
-    ``[H, S, S]`` float32 score matrix is built: for short prompts, the
-    CPU and the tests. Float32 out."""
+def banded_attention(q, k, v, window: int = None, sink=None):
+    """The plain form of `prefill_attention`: q ``[H, S, D]`` over k
+    ``[Hkv, S, D]`` and v ``[Hkv, S, Dv]`` (query head ``i`` on key head
+    ``i // group``), causal, with `window` only the keys ``j`` with ``i -
+    j < window``, with `sink` ``[H]`` one more softmax column a head, of
+    that logit and no value. The whole ``[H, S, S]`` float32 score matrix
+    is built: for short prompts, the CPU and the tests. Float32 out,
+    ``[H, S, Dv]``."""
     h, s, d = q.shape
     hkv = k.shape[0]
     qg = q.reshape(hkv, h // hkv, s, d)
@@ -135,21 +137,27 @@ def banded_attention(q, k, v, window: int = None):
     keep = idx[:, None] >= idx[None, :]
     if window is not None:
         keep &= idx[:, None] - idx[None, :] < window
-    probs = jax.nn.softmax(jnp.where(keep, scores, _NEG_INF), axis=-1)
+    scores = jnp.where(keep, scores, _NEG_INF)
+    if sink is not None:
+        scores = jnp.concatenate([scores, jnp.broadcast_to(
+            sink.astype(jnp.float32).reshape(hkv, h // hkv, 1, 1),
+            scores.shape[:3] + (1,))], axis=-1)
+    probs = jax.nn.softmax(scores, axis=-1)[..., :s]
     out = jnp.einsum("kgqs,ksd->kgqd", probs.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
-    return out.reshape(h, s, d)
+    return out.reshape(h, s, v.shape[2])
 
 
-def prefill_attention(q, k, v, window: int = None):
+def prefill_attention(q, k, v, window: int = None, sink=None):
     """One prompt's attention in the serving prefill (grouped heads, an
-    optional window, forward only): the Pallas forward of
-    `ops/flash_attention.py` on a TPU for heads of a multiple of 128 and a
-    length that tiles, `banded_attention` elsewhere."""
-    s, d = q.shape[1], q.shape[2]
-    if jax.default_backend() == "tpu" and d % 128 == 0 and s % 128 == 0:
-        return prefill_attention_fwd(q, k, v, window)
-    return banded_attention(q, k, v, window)
+    optional window, an optional sink, forward only): the Pallas forward
+    of `ops/flash_attention.py` on a TPU for values of a multiple of 128
+    (keys may be wider: 192 over 128) and a length that tiles,
+    `banded_attention` elsewhere."""
+    s, dv = q.shape[1], v.shape[2]
+    if jax.default_backend() == "tpu" and dv % 128 == 0 and s % 128 == 0:
+        return prefill_attention_fwd(q, k, v, window, sink)
+    return banded_attention(q, k, v, window, sink)
 
 
 def ring_attention_manual(q, k, v, q_pos, *, axis_name: str = "sp",

@@ -401,24 +401,32 @@ flash_mha.defvjp(_flash_mha_fwd, _flash_mha_bwd)
 
 
 # -- the serving prefill's forward: grouped heads, an optional window ------
-def _prefill_fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-                        *, scale: float, window, n_visit: int):
+def _prefill_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale: float, window,
+                        n_visit: int, with_sink: bool):
     """One query tile of one query head against the `kj`-th key tile it
     visits: all tiles up to the diagonal without a window, the last
     `n_visit` up to the diagonal with one. A tile wholly above the
     diagonal or wholly left of the window is skipped; the masks are
-    built only on a tile the diagonal or the window's edge crosses."""
+    built only on a tile the diagonal or the window's edge crosses.
+    With a sink (``[1, 128]``, the head's logit on every lane) the
+    running softmax starts from that column, which carries no value."""
     from jax.experimental import pallas as pl
 
-    block, d = q_ref.shape
+    sink_ref = rest[0] if with_sink else None
+    o_ref, m_ref, l_ref, acc_ref = rest[int(with_sink):]
+    block, d = o_ref.shape
     qi, kj = pl.program_id(1), pl.program_id(2)
     kt = kj if window is None else qi - (n_visit - 1) + kj
     q0, k0 = qi * block, kt * block
 
     @pl.when(kj == 0)
     def _start():
-        m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
-        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        if with_sink:
+            m_ref[...] = jnp.broadcast_to(sink_ref[...], m_ref.shape)
+            l_ref[...] = jnp.ones(l_ref.shape, jnp.float32)
+        else:
+            m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
+            l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
     def step(masked):
@@ -456,13 +464,28 @@ def _prefill_fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
                       ).astype(o_ref.dtype)
 
 
-def prefill_attention_fwd(q, k, v, window: int = None, *, block: int = None,
-                          interpret: bool = False):
+def prefill_block(seq_len: int, window: int = None) -> int:
+    """The prefill forward's tile: 512 rows and keys (or the sequence,
+    below that); under a window of at most half of it, the power of two
+    that holds the window, no smaller than the lanes: a window of 128
+    then computes 256 keys a query, not 1,024."""
+    block = min(512, seq_len)
+    if window is not None:
+        while block >= 2 * max(window, _LANES) and block % 2 == 0:
+            block //= 2
+    return block
+
+
+def prefill_attention_fwd(q, k, v, window: int = None, sink=None, *,
+                          block: int = None, interpret: bool = False):
     """Causal softmax attention of one sequence for the serving prefill,
-    forward only: q ``[H, S, D]`` over k, v ``[Hkv, S, D]``, query head
-    ``i`` on key head ``i // (H // Hkv)``; with `window` a query sees the
-    keys ``j`` with ``i - j < window`` alone. Float32 out. ``S`` is a
-    multiple of `block` (512, or ``S`` below that), ``D`` of 128.
+    forward only: q ``[H, S, D]`` over k ``[Hkv, S, D]`` and v ``[Hkv,
+    S, Dv]``, query head ``i`` on key head ``i // (H // Hkv)``; with
+    `window` a query sees the keys ``j`` with ``i - j < window`` alone;
+    with `sink` ``[H]`` a head's softmax has one more column of that
+    logit and no value. Float32 out, ``[H, S, Dv]``. ``S`` is a multiple
+    of `block` (`prefill_block`), ``Dv`` of 128; keys of another width
+    (192) go in filled up with zeros to whole lanes.
 
     The training kernels above are left as they are (their tiles, their
     names): this one runs under ``flash_prefill_fwd_causal`` or
@@ -471,14 +494,19 @@ def prefill_attention_fwd(q, k, v, window: int = None, *, block: int = None,
     from jax.experimental.pallas import tpu as pltpu
 
     h, s, d = q.shape
-    hkv = k.shape[0]
-    if k.shape != (hkv, s, d) or v.shape != k.shape or h % hkv:
+    hkv, dv = v.shape[0], v.shape[2]
+    if k.shape != (hkv, s, d) or v.shape[:2] != (hkv, s) or h % hkv:
         raise ValueError(f"q {q.shape} does not go over k {k.shape}, "
                          f"v {v.shape}")
-    block = block or min(512, s)
+    block = block or prefill_block(s, window)
     if s % block or block % _LANES:
         raise ValueError(f"block={block} is no multiple of {_LANES} that "
                          f"divides {s}")
+    scale = d ** -0.5
+    if d % _LANES:
+        fill = ((0, 0), (0, 0), (0, -d % _LANES))
+        q, k = jnp.pad(q, fill), jnp.pad(k, fill)
+        d = q.shape[2]
     group = h // hkv
     n_tiles = s // block
     n_visit = (n_tiles if window is None
@@ -489,21 +517,32 @@ def prefill_attention_fwd(q, k, v, window: int = None, *, block: int = None,
         # A tile that is skipped is not fetched: stay on one that runs.
         return (hi // group, jnp.clip(kt, 0, qi), 0)
 
-    q_spec = pl.BlockSpec((None, block, d), lambda hi, qi, kj: (hi, qi, 0))
-    kv_spec = pl.BlockSpec((None, block, d), kv_map)
+    def q_map(hi, qi, kj):
+        return (hi, qi, 0)
+
+    in_specs = [pl.BlockSpec((None, block, d), q_map),
+                pl.BlockSpec((None, block, d), kv_map),
+                pl.BlockSpec((None, block, dv), kv_map)]
+    operands = [q, k, v]
+    if sink is not None:
+        in_specs.append(pl.BlockSpec((None, 1, _LANES),
+                                     lambda hi, qi, kj: (hi, 0, 0)))
+        operands.append(jnp.broadcast_to(
+            sink.astype(jnp.float32)[:, None, None], (h, 1, _LANES)))
     return pl.pallas_call(
-        functools.partial(_prefill_fwd_kernel, scale=d ** -0.5,
-                          window=window, n_visit=n_visit),
+        functools.partial(_prefill_fwd_kernel, scale=scale,
+                          window=window, n_visit=n_visit,
+                          with_sink=sink is not None),
         grid=(h, n_tiles, n_visit),
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((None, block, dv), q_map),
+        out_shape=jax.ShapeDtypeStruct((h, s, dv), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block, _LANES), jnp.float32),
                         pltpu.VMEM((block, _LANES), jnp.float32),
-                        pltpu.VMEM((block, d), jnp.float32)],
+                        pltpu.VMEM((block, dv), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         name=("flash_prefill_fwd_causal" if window is None
               else f"flash_prefill_fwd_window_{window}"),
         interpret=interpret,
-    )(q, k, v)
+    )(*operands)

@@ -76,42 +76,100 @@ from ray_tpu.ops.attention import _NEG_INF
 
 
 def kernel_eligible(n_heads: int, head_dim: int,
-                    n_kv_heads: int = None) -> bool:
+                    n_kv_heads: int = None, v_head_dim: int = None) -> bool:
     """The kernel needs the TPU backend and a pool whose ``[n_kv_heads,
-    head_dim]`` rows are whole ``(8, 128)`` tiles: heads that fill the
+    v_head_dim]`` slots (`kv_row`) are whole tiles: values that fill the
     lanes (heads of 64 and the unit tests' tiny models take the XLA
-    body) and a multiple of 8 of them (`n_kv_heads` where heads are
+    body; keys may be wider than values, in slots of their own) and 4
+    key/value heads or a multiple of 8 (`n_kv_heads` where heads are
     grouped, else `n_heads`). At 12 heads the chip keeps the pool in
     another layout (it tiles the K/V axis instead, so as not to pad 12
     to 16), and the compiler would hand the kernel a converted copy of
     the whole pool, a layer."""
     pool_heads = n_heads if n_kv_heads is None else n_kv_heads
-    return (jax.default_backend() == "tpu" and head_dim % 128 == 0
-            and pool_heads % 8 == 0 and n_heads % pool_heads == 0)
+    return (jax.default_backend() == "tpu"
+            and (v_head_dim or head_dim) % 128 == 0
+            and (pool_heads % 8 == 0 or pool_heads == 4)
+            and n_heads % pool_heads == 0)
+
+
+def attention_widths(n_heads: int, head_dim: int, n_kv_heads: int,
+                     v_head_dim: int) -> tuple:
+    """`kernel_eligible`'s arguments for a layer's widths: the values'
+    width named only where it is not the keys'."""
+    widths = (n_heads, head_dim, n_kv_heads)
+    return widths if v_head_dim == head_dim else widths + (v_head_dim,)
+
+
+def kv_slots(head_dim: int, v_head_dim: int) -> int:
+    """Slots of ``v_head_dim`` values a key/value head takes in a pool
+    row: the value's one and the key's ``ceil(head_dim / v_head_dim)``."""
+    return 1 + -(-head_dim // v_head_dim)
+
+
+def kv_row(k, v):
+    """The pool rows of keys ``[T, Hkv, dk]`` and values ``[T, Hkv,
+    dv]``: ``[T, S, Hkv, dv]``, slot 0 the keys' first ``dv`` values,
+    slot 1 the values, and where keys are wider than values (``dk`` 192
+    over ``dv`` 128) slots 2 on the keys' further values, the last one
+    filled up with zeros. Every slot is whole lanes; ``[K, V]`` where
+    the two widths are one."""
+    t, hkv, dk = k.shape
+    dv = v.shape[-1]
+    n = kv_slots(dk, dv) - 1
+    if n == 1:
+        return jnp.stack([k, v], axis=1)
+    k = jnp.pad(k, ((0, 0), (0, 0), (0, n * dv - dk)))
+    k = k.reshape(t, hkv, n, dv).transpose(0, 2, 1, 3)
+    return jnp.concatenate([k[:, :1], v[:, None], k[:, 1:]], axis=1)
+
+
+def _keys_of(kv):
+    """The keys ``[..., Hkv, (S - 1) * dv]`` of rows ``[..., S, Hkv,
+    dv]`` (`kv_row`): the slots but the values', side by side."""
+    slots = kv.shape[-3]
+    if slots == 2:
+        return kv[..., 0, :, :]
+    return jnp.concatenate(
+        [kv[..., s, :, :] for s in range(slots) if s != 1], axis=-1)
+
+
+def _as_wide_as_the_pools_keys(x, pool):
+    """q or the step's own keys ``[B, H, dk]``, filled up with zeros to
+    the width the pool holds a key in (`kv_row`)."""
+    held = (pool.shape[3] - 1) * pool.shape[5]
+    return x if x.shape[-1] == held else jnp.pad(
+        x, ((0, 0), (0, 0), (0, held - x.shape[-1])))
 
 
 def paged_decode_attention_xla(q, k_new, v_new, pool, tables, positions,
-                               layer, window: int = None, starts=None):
-    """q ``[B, H, hd]``; k_new, v_new ``[B, Hkv, hd]``; pool ``[N, bs,
-    L, 2, Hkv, hd]``; tables ``[B, nb]`` int32; positions ``[B]`` int32;
-    layer a scalar; `window`, where the layer has one, with starts
-    ``[B]`` int32, the logical block a row's table begins at (None: 0).
-    ``H`` is a multiple of ``Hkv``. Returns ``[B, H,
-    hd]`` float32. Pool positions at or past a row's
-    `position` may hold anything (a reused block's stale rows, block 0
-    behind a padded table entry): they are masked, never read into the
-    result."""
-    b, h, hd = q.shape
-    hkv = pool.shape[4]
+                               layer, window: int = None, starts=None,
+                               sink=None):
+    """q ``[B, H, dk]``; k_new ``[B, Hkv, dk]``, v_new ``[B, Hkv, dv]``;
+    pool ``[N, bs, L, S, Hkv, dv]`` (`kv_row`: ``[N, bs, L, 2, Hkv, hd]``
+    where ``dk == dv``); tables ``[B, nb]`` int32; positions ``[B]``
+    int32; layer a scalar; `window`, where the layer has one, with starts
+    ``[B]`` int32, the logical block a row's table begins at (None: 0);
+    `sink` ``[H]``, where the layer has one: a logit a query head that
+    joins the softmax as a column with no value. ``H`` is a multiple of
+    ``Hkv``. Returns ``[B, H, dv]`` float32. Pool positions at or past a
+    row's `position` may hold anything (a reused block's stale rows,
+    block 0 behind a padded table entry): they are masked, never read
+    into the result."""
+    b, h, dk = q.shape
+    hkv, dv = pool.shape[4], pool.shape[5]
     s_pad = tables.shape[1] * pool.shape[1]
-    kv = pool[tables, :, layer].reshape(b, s_pad, 2, hkv, hd)
+    kv = pool[tables, :, layer].reshape((b, s_pad) + pool.shape[3:])
     kv = kv.astype(jnp.float32)
-    scale = hd ** -0.5
-    # Query head i reads key head i // group: [B, Hkv, group, hd].
-    q = q.astype(jnp.float32).reshape(b, hkv, h // hkv, hd)
-    k_new = k_new.astype(jnp.float32)[:, :, None]
+    keys, vals = _keys_of(kv), kv[:, :, 1]
+    scale = dk ** -0.5
+    # Query head i reads key head i // group: [B, Hkv, group, dk].
+    q = _as_wide_as_the_pools_keys(q.astype(jnp.float32), pool)
+    q = q.reshape(b, hkv, h // hkv, -1)
+    k_new = _as_wide_as_the_pools_keys(k_new.astype(jnp.float32),
+                                       pool)[:, :, None]
     v_new = v_new.astype(jnp.float32)[:, :, None]
-    scores = jnp.einsum("bkgd,bskd->bkgs", q, kv[:, :, 0],
+    scores = jnp.einsum("bkgd,bskd->bkgs", q, keys,
                         preferred_element_type=jnp.float32) * scale
     at = jnp.arange(s_pad)[None, :]                              # [B, S]
     if starts is not None:
@@ -121,11 +179,15 @@ def paged_decode_attention_xla(q, k_new, v_new, pool, tables, positions,
         cached &= positions[:, None] - at < window
     scores = jnp.where(cached[:, None, None, :], scores, _NEG_INF)
     own = jnp.sum(q * k_new, axis=-1, keepdims=True) * scale
-    probs = jax.nn.softmax(jnp.concatenate([scores, own], axis=-1),
-                           axis=-1)
-    out = (jnp.einsum("bkgs,bskd->bkgd", probs[..., :-1], kv[:, :, 1])
-           + probs[..., -1:] * v_new)
-    return out.reshape(b, h, hd)
+    columns = [scores, own]
+    if sink is not None:
+        columns.append(jnp.broadcast_to(
+            sink.astype(jnp.float32).reshape(1, hkv, h // hkv, 1),
+            own.shape))
+    probs = jax.nn.softmax(jnp.concatenate(columns, axis=-1), axis=-1)
+    out = (jnp.einsum("bkgs,bskd->bkgd", probs[..., :s_pad], vals)
+           + probs[..., s_pad:s_pad + 1] * v_new)
+    return out.reshape(b, h, dv)
 
 
 # Two slabs of a group's pages may take this much VMEM, and one pass of
@@ -153,7 +215,7 @@ def pages_per_step(page_bytes: int, table_width: int) -> int:
 
 
 def pool_pages_per_step(pool, table_width: int) -> int:
-    """`pages_per_step` for a pool ``[N, bs, L, 2, Hkv, hd]`` as it is
+    """`pages_per_step` for a pool ``[N, bs, L, S, Hkv, dv]`` as it is
     held and a table of `table_width` columns."""
     return pages_per_step(
         pool.shape[1] * math.prod(pool.shape[3:]) * pool.dtype.itemsize,
@@ -200,12 +262,14 @@ def _seen(at, position, until, window):
     return keep
 
 
-def _start_from_own_token(q, k_new_ref, v_new_ref, m_ref, l_ref, acc_ref,
-                          scale: float):
-    """The running softmax starts from the step's own key and value."""
+def _start_from_own_token(q, k_new_ref, v_new_ref, sink_ref, m_ref, l_ref,
+                          acc_ref, scale: float):
+    """The running softmax starts from the step's own key and value,
+    and from the layer's sink (a column with no value) where it has
+    one."""
     f32 = jnp.float32
     h, n_kv = q.shape[0], k_new_ref.shape[0]
-    k_own = k_new_ref[...].astype(f32)                       # [Hkv, hd]
+    k_own = k_new_ref[...].astype(f32)                       # [Hkv, dk]
     v_own = v_new_ref[...].astype(f32)
     if h != n_kv:
         # Query head i reads key head i // group.
@@ -213,18 +277,27 @@ def _start_from_own_token(q, k_new_ref, v_new_ref, m_ref, l_ref, acc_ref,
         k_own, v_own = (jnp.concatenate(
             [jnp.broadcast_to(x[j][None], (group, x.shape[-1]))
              for j in range(n_kv)], axis=0) for x in (k_own, v_own))
-    m_ref[...] = jnp.sum(q * k_own, axis=-1, keepdims=True) * scale
-    l_ref[...] = jnp.ones_like(l_ref)
-    acc_ref[...] = v_own
+    own = jnp.sum(q * k_own, axis=-1, keepdims=True) * scale
+    if sink_ref is None:
+        m_ref[...] = own
+        l_ref[...] = jnp.ones_like(l_ref)
+        acc_ref[...] = v_own
+        return
+    sink = sink_ref[...]                                     # [H, 1]
+    m = jnp.maximum(own, sink)
+    p_own = jnp.exp(own - m)
+    m_ref[...] = m
+    l_ref[...] = p_own + jnp.exp(sink - m)
+    acc_ref[...] = p_own * v_own
 
 
 def _attend(q, kv, first, position, until, m_ref, l_ref, acc_ref, *,
             scale: float, window):
-    """One online-softmax update over the keys of `kv` ``[T, 2, H, hd]``
+    """One online-softmax update over the keys of `kv` ``[T, S, H, dv]``
     (float32; key t at position ``first + t``, those from `until` on
     not this group's), one key head a query head: products and sums on
     the vector unit."""
-    keys, vals = kv[:, 0], kv[:, 1]                          # [T, H, hd]
+    keys, vals = _keys_of(kv), kv[:, 1]                      # [T, H, ..]
     scores = jnp.sum(q[None] * keys, axis=-1,
                      keepdims=True) * scale                  # [T, H, 1]
     at = first + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
@@ -250,10 +323,10 @@ def _attend_grouped(q, kv, first, position, until, m_ref, l_ref, acc_ref,
     of the slab first costs a pass over its sublanes a head: that pass,
     not the products, bounded the kernel a page a grid step.)"""
     f32 = jnp.float32
-    t, _, n_kv, hd = kv.shape
+    t, _, n_kv, dv = kv.shape
     h = q.shape[0]
-    keys = kv[:, 0].reshape(t * n_kv, hd)
-    vals = kv[:, 1].reshape(t * n_kv, hd)
+    keys = _keys_of(kv).reshape(t * n_kv, q.shape[1])
+    vals = kv[:, 1].reshape(t * n_kv, dv)
     scores = jax.lax.dot_general(
         q, keys, (((1,), (1,)), ((), ())),
         preferred_element_type=f32) * scale              # [H, T * Hkv]
@@ -302,9 +375,8 @@ def _row_walks(positions, starts, block_size: int, table_width: int,
 
 
 def _kernel_body(tables_ref, positions_ref, layer_ref, walks_ref, q_ref,
-                 k_new_ref, v_new_ref, pool_ref, o_ref, slabs, arrived,
-                 ahead_ref, m_ref, l_ref, acc_ref, *, block_size: int,
-                 scale: float, window):
+                 k_new_ref, v_new_ref, pool_ref, *rest, block_size: int,
+                 scale: float, window, with_sink: bool):
     """One grid step is one row. Its live table columns are walked in
     groups (`_row_walks`): a group's pages are copied from the pool in
     HBM into one of two VMEM slabs while the group before it is attended
@@ -316,6 +388,9 @@ def _kernel_body(tables_ref, positions_ref, layer_ref, walks_ref, q_ref,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    sink_ref = rest[0] if with_sink else None
+    (o_ref, slabs, arrived, ahead_ref, m_ref, l_ref,
+     acc_ref) = rest[int(with_sink):]
     i32 = jnp.int32
     row, last_row = pl.program_id(0), pl.num_programs(0) - 1
     layer = layer_ref[0]
@@ -343,8 +418,8 @@ def _kernel_body(tables_ref, positions_ref, layer_ref, walks_ref, q_ref,
         ahead_ref[1] = i32(0)   # whether the row before started its copy
 
     q = q_ref[...].astype(jnp.float32)                       # [H, hd]
-    _start_from_own_token(q, k_new_ref, v_new_ref, m_ref, l_ref, acc_ref,
-                          scale)
+    _start_from_own_token(q, k_new_ref, v_new_ref, sink_ref, m_ref, l_ref,
+                          acc_ref, scale)
     position = positions_ref[row]
     first, n, size, n_groups, block0 = (walks_ref[row, k] for k in range(5))
     attend = functools.partial(
@@ -409,7 +484,8 @@ def _kernel_body(tables_ref, positions_ref, layer_ref, walks_ref, q_ref,
 @functools.partial(jax.jit, static_argnames=("window", "pages", "interpret"))
 def paged_decode_attention_kernel(q, k_new, v_new, pool, tables,
                                   positions, layer, window: int = None,
-                                  starts=None, *, pages: int = None,
+                                  starts=None, sink=None, *,
+                                  pages: int = None,
                                   interpret: bool = False):
     """Same arguments and result as `paged_decode_attention_xla`. The
     pool stays in HBM as it stands; the kernel copies a row's live pages
@@ -422,57 +498,67 @@ def paged_decode_attention_kernel(q, k_new, v_new, pool, tables,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    b, h, hd = q.shape
+    b, h, dk = q.shape
     nb = tables.shape[1]
     bs = pool.shape[1]
-    hkv = k_new.shape[1]
-    if pool.shape[3:] != (2, hkv, hd) or h % hkv:
-        raise ValueError(f"pool {pool.shape} does not hold K and V rows "
-                         f"of {(hkv, hd)} for {h} query heads")
+    hkv, dv = v_new.shape[1:]
+    if (pool.shape[3:] != (kv_slots(dk, dv), hkv, dv) or h % hkv
+            or k_new.shape != (b, hkv, dk)):
+        raise ValueError(f"pool {pool.shape} does not hold K rows of "
+                         f"{(hkv, dk)} and V rows of {(hkv, dv)} for {h} "
+                         "query heads")
     if pages is None:
         pages = pool_pages_per_step(pool, nb)
     positions = positions.astype(jnp.int32)
     prefetched = [tables.astype(jnp.int32), positions,
                   jnp.reshape(layer, (1,)).astype(jnp.int32),
                   _row_walks(positions, starts, bs, nb, pages, window)]
+    q, k_new = (_as_wide_as_the_pools_keys(x, pool) for x in (q, k_new))
+    held = q.shape[2]
 
     def row_map(row, *prefetched_refs):
         return (row, 0, 0)
 
-    row_spec = pl.BlockSpec((None, h, hd), row_map)
-    kv_row_spec = pl.BlockSpec((None, hkv, hd), row_map)
+    in_specs = [pl.BlockSpec((None, h, held), row_map),
+                pl.BlockSpec((None, hkv, held), row_map),
+                pl.BlockSpec((None, hkv, dv), row_map),
+                pl.BlockSpec(memory_space=pl.ANY)]
+    operands = [q, k_new, v_new, pool]
+    if sink is not None:
+        in_specs.append(pl.BlockSpec((h, 1), lambda row, *refs: (0, 0)))
+        operands.append(sink.astype(jnp.float32).reshape(h, 1))
     return pl.pallas_call(
-        functools.partial(_kernel_body, block_size=bs, scale=hd ** -0.5,
-                          window=window),
+        functools.partial(_kernel_body, block_size=bs, scale=dk ** -0.5,
+                          window=window, with_sink=sink is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetched),
             grid=(b,),
-            in_specs=[row_spec, kv_row_spec, kv_row_spec,
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=row_spec,
-            scratch_shapes=[pltpu.VMEM((2, pages, bs, 2, hkv, hd),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((None, h, dv), row_map),
+            scratch_shapes=[pltpu.VMEM((2, pages, bs) + pool.shape[3:],
                                        pool.dtype),
                             pltpu.SemaphoreType.DMA((2,)),
                             pltpu.SMEM((2,), jnp.int32),
                             pltpu.VMEM((h, 1), jnp.float32),
                             pltpu.VMEM((h, 1), jnp.float32),
-                            pltpu.VMEM((h, hd), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((b, h, hd), jnp.float32),
+                            pltpu.VMEM((h, dv), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, h, dv), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         name=("paged_decode_attention" if window is None
               else "paged_window_decode_attention"),
         interpret=interpret,
-    )(*prefetched, q, k_new, v_new, pool)
+    )(*prefetched, *operands)
 
 
 def paged_decode_attention(q, k_new, v_new, pool, tables, positions,
-                           layer, window: int = None, starts=None):
+                           layer, window: int = None, starts=None,
+                           sink=None):
     """One layer's decode attention through the block tables: the
     kernel where `kernel_eligible`, the XLA body elsewhere."""
     body = (paged_decode_attention_kernel
-            if kernel_eligible(q.shape[1], q.shape[2], k_new.shape[1])
+            if kernel_eligible(*attention_widths(
+                q.shape[1], q.shape[2], k_new.shape[1], v_new.shape[2]))
             else paged_decode_attention_xla)
     return body(q, k_new, v_new, pool, tables, positions, layer, window,
-                starts)
-
+                starts, sink)

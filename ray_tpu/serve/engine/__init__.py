@@ -40,6 +40,7 @@ from ray_tpu.serve.engine.kv_cache import (CacheOverflowError,
                                            KVCacheManager)
 from ray_tpu.serve.engine.hybrid_model import HybridEngineModel
 from ray_tpu.serve.engine.laguna_model import LagunaEngineModel
+from ray_tpu.serve.engine.mimo_model import MimoEngineModel
 from ray_tpu.serve.engine.model import TinyLM, TransformerEngineModel
 from ray_tpu.serve.engine.prefix_index import PrefixIndex
 from ray_tpu.serve.engine.scheduler import (EngineConfig,
@@ -50,6 +51,6 @@ from ray_tpu.serve.engine.scheduler import (EngineConfig,
 __all__ = [
     "CacheOverflowError", "EngineConfig", "EngineOverloadedError",
     "EngineStoppedError", "HybridEngineModel", "InferenceEngine",
-    "KVCacheManager", "LagunaEngineModel",
+    "KVCacheManager", "LagunaEngineModel", "MimoEngineModel",
     "PrefixIndex", "TinyLM", "TokenStream", "TransformerEngineModel",
 ]

@@ -4,15 +4,10 @@ layers a period, different query-head counts over the same key/value
 heads, a gate a head, two rotary schemes, a dense MLP in layer 0 and
 held experts behind a softmax router in every other layer.
 
-It declares what no other model of the tree does: `kv_groups`, the layer
-groups of its KV. The full layers' rows go to the `global` group (this
-model's `kv_token_shape`), the sliding layers' to the `window` group,
-whose blocks the cache manager gives back as they leave the window
-(`kv_cache.py`). A prefill's result carries a row a group
-(`PromptGroups`), and `decode_paged` takes and hands back a dict of
-pools, reads each group through its own table (the window group's
-compact, with the logical block it begins at), and writes the step's
-rows into both.
+Its KV lives in two layer groups of the cache, and what a model over two
+layer groups does with them (the prefill's and the decode step's
+programs, the packed step buffer, the window table, the counters by
+group) is `layer_groups_model.py`'s; here are this decoder's layers.
 
 Arithmetic: weights and both KV pools in `cfg.dtype` (bf16 on the chip);
 the residual stream, norms, softmax, rotary tables, gates and the
@@ -20,84 +15,30 @@ router's product (at the highest precision) in float32; a matrix product
 takes both operands in `cfg.dtype` and accumulates in float32; logits
 float32. (The decode step's scores take the rotated query in float32
 against the pool's keys, as the hybrid model's do.)
-
-A decode step is one compiled program: in, one int32 array ``[b_pad, 6 +
-nb_pad + window_blocks]`` (token, position, write offset, the global and
-the window group's write blocks, the window table's first logical block,
-the global table, the window table); out, one int32 array ``[b_pad +
-3]``: the greedy ids and the step's three expert counters.
 """
 
 from __future__ import annotations
 
-import math
-from typing import Dict, List, Sequence
-
-import numpy as np
-
-from ray_tpu.core import flight
-from ray_tpu.serve.engine.model import PromptKV, _next_pow2
-from ray_tpu.serve.engine.sparse_model import SparseEngineModel
-
-GLOBAL, WINDOW = "global", "window"
+from ray_tpu.serve.engine.layer_groups_model import (  # noqa: F401
+    GLOBAL, WINDOW, LayerGroupsEngineModel, PromptGroups)
 
 
-class PromptGroups(PromptKV):
-    """A prefill's KV rows a layer group: itself the global group's
-    (every position), `groups["window"]` the sliding layers' (the same
-    positions; `KVCacheManager.write_range` stores only those the window
-    still reaches)."""
-
-    __slots__ = ("groups",)
-
-    def __init__(self, padded, n: int, groups: Dict[str, PromptKV]):
-        super().__init__(padded, n)
-        self.groups = groups
-
-
-class LagunaEngineModel(SparseEngineModel):
+class LagunaEngineModel(LayerGroupsEngineModel):
     """Incremental decoding over `models/laguna.py` weights.
 
     KV entry a token: ``[n_full_layers, 2, n_kv_heads, head_dim]`` in the
     global group and ``[n_sliding_layers, 2, n_kv_heads, head_dim]`` in
-    the window group (`kv_groups`). Prefill runs the prompt once in pow2
-    length buckets through `ops.attention.prefill_attention` (the
-    windowed, grouped flash forward on the chip); a decode step is jitted
-    a (batch, global table) bucket, the window table's width fixed by the
-    window. A prompt is never prefilled from an offset: the engine adopts
-    no prefix beside a window group."""
+    the window group (`kv_groups`)."""
 
-    def __init__(self, params, cfg, max_batch_size: int = 8,
-                 jit_cache_cap: int = 32):
-        from ray_tpu.ops.paged_attention import (kernel_eligible,
-                                                 page_groups)
+    def _attention_widths(self, full: bool):
+        cfg = self._cfg
+        return (cfg.heads_full if full else cfg.heads_sliding,
+                cfg.head_dim, cfg.n_kv_heads, cfg.head_dim)
 
-        super().__init__(params, cfg, jit_cache_cap)
-        self._page_groups = page_groups
-        row = (2, cfg.n_kv_heads, cfg.head_dim)
-        self.kv_token_shape = (cfg.n_full_layers,) + row
-        self.kv_groups = {WINDOW: {
-            "kv_shape": (cfg.n_sliding_layers,) + row,
-            "window": cfg.window}}
-        self._attn_inplace = (
-            kernel_eligible(cfg.heads_full, cfg.head_dim, cfg.n_kv_heads)
-            and kernel_eligible(cfg.heads_sliding, cfg.head_dim,
-                                cfg.n_kv_heads))
-        # Live pages the steps' tables named, a group: pages that hold a
-        # cached position the row's query sees (their sum is
-        # `decode_kv_pages_read`), and the groups of pages the kernel
-        # fetched them in (`ops.paged_attention.page_groups`; their sum
-        # is `decode_kv_page_groups_read`).
-        self.decode_kv_pages_read_global = 0
-        self.decode_kv_pages_read_window = 0
-        self.decode_kv_page_groups_read_global = 0
-        self.decode_kv_page_groups_read_window = 0
+    def _group_layers(self, full: bool) -> int:
+        return (self._cfg.n_full_layers if full
+                else self._cfg.n_sliding_layers)
 
-    def window_table_blocks(self, block_size: int) -> int:
-        """Blocks of the window group a sequence holds at the most."""
-        return math.ceil(self._cfg.window / block_size) + 1
-
-    # -- shared math ---------------------------------------------------
     def _rope(self, positions, full: bool):
         """cos, sin ``[T, rot_dim // 2]`` of a layer kind at
         `positions`."""
@@ -109,20 +50,21 @@ class LagunaEngineModel(SparseEngineModel):
         return rotary_cos_sin(positions, inv,
                               rope.get("attention_factor", 1.0))
 
-    def _qkv(self, y, lp, heads: int, rope):
+    def _qkv(self, y, lp, full: bool, rope):
         """The rotated q ``[T, H, hd]`` and k, and v ``[T, Hkv, hd]``,
         float32."""
         from ray_tpu.ops.rotary import apply_rotary_partial
 
         cfg = self._cfg
         t = y.shape[0]
+        heads = cfg.heads_full if full else cfg.heads_sliding
         q = self._mm(y, lp["wq"]).reshape(t, heads, cfg.head_dim)
         k = self._mm(y, lp["wk"]).reshape(t, cfg.n_kv_heads, cfg.head_dim)
         v = self._mm(y, lp["wv"]).reshape(t, cfg.n_kv_heads, cfg.head_dim)
         return (apply_rotary_partial(q, *rope),
                 apply_rotary_partial(k, *rope), v)
 
-    def _gated_out(self, y, o, lp):
+    def _mixer_out(self, y, o, lp):
         """``W_o`` of the heads' outputs ``[T, H, hd]``, each times its
         head's gate ``sigmoid(y W_g)``."""
         import jax
@@ -131,210 +73,11 @@ class LagunaEngineModel(SparseEngineModel):
         return self._mm((o * gate[:, :, None]).reshape(o.shape[0], -1),
                         lp["wo"])
 
-    def _feed_forward(self, x, ln2, mp, valid):
-        """A layer's second half: layer 0's dense MLP (no counts) or the
-        expert layer."""
-        import jax
-        import jax.numpy as jnp
-
-        if "router" in mp:
-            return self._experts(x, ln2, mp, valid)
-        with jax.named_scope("dense_mlp"):
-            y = self._norm(x, ln2)
-            out = self._gated_ffn(y, mp["gate"], mp["up"], mp["down"])
-        return x + out, jnp.zeros((3,), jnp.int32)
-
     def _layers(self, params):
-        """(period, index in the period, layer's tree, is it full) of
+        """(mixer's tree, ln1, ln2, feed-forward tree, is it full) of
         every layer, in order."""
-        for p, pp in enumerate(params["periods"]):
-            yield p, 0, pp["full"], True
-            for j, lp in enumerate(pp["sliding"]):
-                yield p, 1 + j, lp, False
-
-    # -- prefill -------------------------------------------------------
-    def _build_prefill(self, s_pad: int):
-        import jax
-        import jax.numpy as jnp
-
-        from ray_tpu.ops.attention import prefill_attention
-
-        self.jit_compiles += 1
-        cfg, f32 = self._cfg, jnp.float32
-
-        def prefill(params, tokens, length):
-            act = params["embed"].dtype
-            with jax.named_scope("embed"):
-                x = params["embed"][tokens].astype(f32)        # [S, d]
-            pos = jnp.arange(s_pad)
-            live = pos < length
-            ropes = {True: self._rope(pos, True),
-                     False: self._rope(pos, False)}
-            rows = {True: [], False: []}
-            for p, j, lp, full in self._layers(params):
-                pp = params["periods"][p]
-                heads = cfg.heads_full if full else cfg.heads_sliding
-                with jax.named_scope("attn_global" if full
-                                     else "attn_window"):
-                    y = self._norm(x, pp["ln1"][j])
-                    q, k, v = self._qkv(y, lp, heads, ropes[full])
-                    k, v = k.astype(act), v.astype(act)
-                    # A padded position lies after every live one: the
-                    # causal mask alone keeps it from a live query.
-                    o = prefill_attention(
-                        q.astype(act).transpose(1, 0, 2),
-                        k.transpose(1, 0, 2), v.transpose(1, 0, 2),
-                        None if full else cfg.window)           # [H, S, hd]
-                    x = x + self._gated_out(y, o.transpose(1, 0, 2), lp)
-                rows[full].append(jnp.stack([k, v], axis=1))
-                x, _ = self._feed_forward(x, pp["ln2"][j], pp["mlp"][j],
-                                          live)
-            with jax.named_scope("lm_head"):
-                last = self._norm(x[length - 1], params["ln_f"])
-                logits = self._mm(last[None], params["head"])[0]
-            # [S, layers of the group, 2, Hkv, hd]
-            return (logits, jnp.stack(rows[True], axis=1),
-                    jnp.stack(rows[False], axis=1))
-
-        return jax.jit(prefill)
-
-    # -- decode --------------------------------------------------------
-    def _build_decode_paged(self, b_pad: int, nb_pad: int,
-                            block_size: int):
-        import jax
-        import jax.numpy as jnp
-
-        from ray_tpu.ops.paged_attention import paged_decode_attention
-
-        self.jit_compiles += 1
-        cfg, f32 = self._cfg, jnp.float32
-
-        def decode_paged(pools, params, packed):
-            tokens, positions, woffs = (packed[:, 0], packed[:, 1],
-                                        packed[:, 2])
-            wblocks = {GLOBAL: packed[:, 3], WINDOW: packed[:, 4]}
-            starts = packed[:, 5]
-            tables = {GLOBAL: packed[:, 6:6 + nb_pad],
-                      WINDOW: packed[:, 6 + nb_pad:]}
-            # A padding row writes past both pools: it routes nowhere.
-            valid = wblocks[GLOBAL] < pools[GLOBAL].shape[0]
-            act = pools[GLOBAL].dtype
-            with jax.named_scope("embed"):
-                x = params["embed"][tokens].astype(f32)        # [B, d]
-            ropes = {True: self._rope(positions, True),
-                     False: self._rope(positions, False)}
-            rows = {True: [], False: []}
-            counts = jnp.zeros((3,), jnp.int32)
-            for p, j, lp, full in self._layers(params):
-                pp = params["periods"][p]
-                heads = cfg.heads_full if full else cfg.heads_sliding
-                group = GLOBAL if full else WINDOW
-                with jax.named_scope("attn_global" if full
-                                     else "attn_window"):
-                    y = self._norm(x, pp["ln1"][j])
-                    q, k, v = self._qkv(y, lp, heads, ropes[full])
-                    k, v = k.astype(act), v.astype(act)
-                    with jax.named_scope("kv_gather"):
-                        o = paged_decode_attention(
-                            q, k, v, pools[group], tables[group], positions,
-                            jnp.int32(len(rows[full])),
-                            None if full else cfg.window,
-                            None if full else starts)
-                    x = x + self._gated_out(y, o, lp)
-                rows[full].append(jnp.stack([k, v], axis=1))
-                x, c = self._feed_forward(x, pp["ln2"][j], pp["mlp"][j],
-                                          valid)
-                counts += c
-            with jax.named_scope("lm_head"):
-                logits = self._mm(self._norm(x, params["ln_f"]),
-                                  params["head"])
-            with jax.named_scope("kv_write"):
-                new_pools = {
-                    group: pools[group].at[wblocks[group], woffs].set(
-                        jnp.stack(rows[full], axis=1), mode="drop")
-                    for group, full in ((GLOBAL, True), (WINDOW, False))}
-            with jax.named_scope("sample"):
-                ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return jnp.concatenate([ids, counts]), logits, new_pools
-
-        return jax.jit(decode_paged, donate_argnums=(0,))
-
-    # -- engine interface ----------------------------------------------
-    def prefill(self, tokens: Sequence[int]):
-        """Run the prompt. Returns the host logits that predict the next
-        token and a `PromptGroups`: the prompt's KV rows of both layer
-        groups, still on the device."""
-        with flight.span("model", "prefill", len(tokens)):
-            logits, (kv_global, kv_window), n = self._run_prefill(tokens)
-            return logits, PromptGroups(
-                kv_global, n, {WINDOW: PromptKV(kv_window, n)})
-
-    def decode_paged(self, pools, block_tables: List[dict],
-                     last_tokens: Sequence[int],
-                     positions: Sequence[int], write_blocks: dict,
-                     write_offs: dict, block_size: int):
-        """One fused step over both layer groups. `pools`, `write_blocks`
-        and `write_offs` are dicts a group (`KVCacheManager.paged_step`
-        over groups); ``block_tables[i]`` is row i's ``{group: (base,
-        table)}`` (`step_tables`). A write list shorter than the batch
-        leaves the other rows unwritten, as in a warm-up. Returns
-        ``(step, new_pools)``; both pools were donated."""
-        with flight.span("model", "decode", len(last_tokens)):
-            return self._decode_paged(pools, block_tables, last_tokens,
-                                      positions, write_blocks, write_offs,
-                                      block_size)
-
-    def _decode_paged(self, pools, block_tables, last_tokens, positions,
-                      write_blocks, write_offs, block_size: int):
-        b = len(last_tokens)
-        self.decode_calls += 1
-        window = self._cfg.window
-        with flight.span("model", "decode.prep", None, self.phase,
-                         "decode_prep_s"):
-            b_pad = _next_pow2(max(b, 1))
-            tw = self.window_table_blocks(block_size)
-            # Pages that hold a cached position: [0, p) in the global
-            # group, [max(0, p - window + 1), p) in the window group.
-            cached = [-(-int(p) // block_size) for p in positions]
-            nb_pad = _next_pow2(max(max(int(p) // block_size + 1
-                                        for p in positions), 1))
-            if self._attn_inplace:
-                self.decode_attn_inplace_steps += 1
-                in_window = sum(
-                    c - max(0, int(p) - window + 1) // block_size
-                    for c, p in zip(cached, positions))
-                self.decode_kv_pages_read_global += sum(cached)
-                self.decode_kv_pages_read_window += in_window
-                self.decode_kv_pages_read += sum(cached) + in_window
-                groups = (
-                    self._page_groups(pools[GLOBAL], nb_pad, positions),
-                    self._page_groups(pools[WINDOW], tw, positions, window))
-                self.decode_kv_page_groups_read_global += groups[0]
-                self.decode_kv_page_groups_read_window += groups[1]
-                self.decode_kv_page_groups_read += sum(groups)
-            key = (b_pad, nb_pad, block_size)
-            fn = self._decode_paged_jit.get(key)
-            if fn is None:
-                fn = self._decode_paged_jit[key] = \
-                    self._build_decode_paged(*key)
-            # One host buffer, a row a sequence; a write block past a
-            # pool is dropped.
-            packed = np.zeros((b_pad, 6 + nb_pad + tw), np.int32)
-            packed[:, 3] = int(pools[GLOBAL].shape[0])
-            packed[:, 4] = int(pools[WINDOW].shape[0])
-            for i in range(b):
-                _, table = block_tables[i][GLOBAL]
-                start, near = block_tables[i][WINDOW]
-                table, near = table[:nb_pad], near[:tw]
-                packed[i, 0] = last_tokens[i]
-                packed[i, 1] = positions[i]
-                packed[i, 5] = start
-                packed[i, 6:6 + len(table)] = table
-                packed[i, 6 + nb_pad:6 + nb_pad + len(near)] = near
-            k = min(len(write_blocks.get(GLOBAL, ())), b)
-            packed[:k, 2] = write_offs[GLOBAL][:k]
-            packed[:k, 3] = write_blocks[GLOBAL][:k]
-            packed[:k, 4] = write_blocks[WINDOW][:k]
-            args = (pools, self._params, packed)
-        step, (new_pools,) = self._run_decode(fn, args, b, b_pad)
-        return step, new_pools
+        for pp in params["periods"]:
+            mixers = [(pp["full"], True)] + [(lp, False)
+                                             for lp in pp["sliding"]]
+            for j, (lp, full) in enumerate(mixers):
+                yield lp, pp["ln1"][j], pp["ln2"][j], pp["mlp"][j], full

@@ -51,7 +51,7 @@ window's rows, in a pool of their own whose blocks the cache manager
 gives back as they leave the window: `kv_cache.py`). The contract then
 changes in three places, for that model alone: the KV result of
 ``prefill`` carries ``groups``, a `PromptKV` a further group
-(`laguna_model.PromptGroups`); ``decode_paged`` takes ``pool``,
+(`layer_groups_model.PromptGroups`); ``decode_paged`` takes ``pool``,
 ``write_blocks`` and ``write_offs`` as dicts a group (the first group is
 ``"global"``) and ``block_tables[i]`` as ``{group: (base, table)}``, a
 window group's table compact from logical block ``base``, and returns
@@ -84,6 +84,13 @@ Four implementations:
   a softmax router. What it shares with the hybrid model (norm, product
   helper, expert layer, counters, the host side of a call) is
   `sparse_model.SparseEngineModel`.
+- **MimoEngineModel** (`mimo_model.py`) — the sparse decoder of
+  `models/mimo_v2.py`: global layers on 4 key/value heads beside window
+  layers on 8, keys of 192 values over values of 128, a sink logit a
+  head in the window layers' softmax, a sigmoid router without a shared
+  expert. What it shares with the Laguna model (the two layer groups'
+  programs, packed step and counters) is
+  `layer_groups_model.LayerGroupsEngineModel`.
 """
 
 from __future__ import annotations

@@ -1024,7 +1024,10 @@ class InferenceEngine:
         (`cache["groups"]` has the gauges); `decode_kv_pages_read_global`
         and `decode_kv_pages_read_window` split `decode_kv_pages_read`
         for a model with a window group (0 otherwise), and
-        `decode_kv_page_groups_read_global` / `_window` its groups;
+        `decode_kv_page_groups_read_global` / `_window` its groups, and
+        `decode_kv_bytes_read_held` / `_model` those pages' bytes as the
+        pools hold a position and as the model counts it (keys wider
+        than values are held in whole slots);
         `window_release_s` is the host time of that release (the span
         `engine.window_release`, inside `engine.capacity`, whose
         `phase.capacity_s` holds it too)."""
@@ -1084,6 +1087,10 @@ class InferenceEngine:
                 self.model, "decode_kv_page_groups_read_global", 0),
             "decode_kv_page_groups_read_window": getattr(
                 self.model, "decode_kv_page_groups_read_window", 0),
+            "decode_kv_bytes_read_held": getattr(
+                self.model, "decode_kv_bytes_read_held", 0),
+            "decode_kv_bytes_read_model": getattr(
+                self.model, "decode_kv_bytes_read_model", 0),
             **self._group_counters(cache["groups"]),
             "jit_bucket_evictions": getattr(
                 self.model, "jit_cache_evictions", 0),

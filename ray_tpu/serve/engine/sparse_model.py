@@ -1,5 +1,6 @@
 """What the engine models of the sparse decoders share
-(`hybrid_model.py`, `laguna_model.py`): the norm, the product helper, the
+(`hybrid_model.py`, and through `layer_groups_model.py` `laguna_model.py`
+and `mimo_model.py`): the norm, the product helper, the
 expert layer with its three counts, the prefill's bucket and dispatch,
 the decode step's one upload and one fetch, and the counters the engine's
 `stats()` reads. A model keeps its own layers, its packed row and its
@@ -97,7 +98,8 @@ class SparseEngineModel:
                         down)
 
     def _experts(self, x, ln2, mp, valid):
-        """The expert layer's residual add; returns the new `x` and the
+        """The expert layer's residual add (with the shared expert's,
+        where the layer's tree has one); returns the new `x` and the
         layer's three counts."""
         import jax
         import jax.numpy as jnp
@@ -118,11 +120,12 @@ class SparseEngineModel:
             routed, load = held_experts_ffn(
                 y, experts, weights, mp["w_gate"], mp["w_up"],
                 mp["w_down"], cfg.experts_held, valid)
-            shared = self._gated_ffn(y, mp["shared_gate"], mp["shared_up"],
-                                     mp["shared_down"])
+            if "shared_gate" in mp:
+                x = x + self._gated_ffn(y, mp["shared_gate"],
+                                        mp["shared_up"], mp["shared_down"])
         counts = jnp.stack([jnp.sum(load), jnp.sum(load > 0),
                             jnp.max(load)]).astype(jnp.int32)
-        return x + shared + routed, counts
+        return x + routed, counts
 
     def _count_experts_step(self, rows: int) -> None:
         """A program of `rows` rows has been dispatched (so traced)."""

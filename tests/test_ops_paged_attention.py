@@ -620,7 +620,8 @@ def test_grouped_eligibility_follows_the_pool_rows_heads(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert pa.kernel_eligible(64, 128, 8)
     assert pa.kernel_eligible(16, 128) and pa.kernel_eligible(16, 128, 16)
-    assert not pa.kernel_eligible(64, 128, 4)      # 4 rows: half a tile
+    assert pa.kernel_eligible(64, 128, 4)      # 4 rows: a tile of (4, 128)
+    assert not pa.kernel_eligible(64, 128, 2)
     assert not pa.kernel_eligible(64, 64, 8)
     assert not pa.kernel_eligible(12, 128, 8)      # no whole groups
 
@@ -974,3 +975,124 @@ def test_experts_prompt_kernel_compiles_for_v5e_at_the_cells_buckets(
     assert "while" not in text                  # no scan beside it
     assert compiled.memory_analysis().temp_size_in_bytes \
         < 3 * tokens * d * 4 + (1 << 20)
+
+
+# ---------------------------------------------------------------------------
+# keys wider than values, 4 key/value heads, a sink: the two layer kinds
+# of `mimo-v2.5.serve.doc-context` (`ops/paged_attention.py`, `kv_row`'s
+# pools; `ops/flash_attention.py`, the prefill's forward), compiled for
+# the chip: the paged kernel over each group's pool, the prefill's
+# forward at keys of 192 over values of 128, and the whole decode step.
+# ---------------------------------------------------------------------------
+MIMO_GLOBAL_POOL = (10240, 16, 2, 3, 4, 128)    # 1.0 GB in bf16
+MIMO_WINDOW_POOL = (160, 16, 5, 3, 8, 128)      # 0.08 GB
+
+
+@pytest.mark.parametrize("hkv, pool, window, nb_pad", [
+    (4, MIMO_GLOBAL_POOL, None, 512), (8, MIMO_WINDOW_POOL, 128, 9)],
+    ids=["global_4_key_heads", "window_8_key_heads_sink"])
+def test_wide_key_kernel_compiles_for_v5e_over_its_group_pool(
+        one_chip, no_compile_cache, hkv, pool, window, nb_pad):
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import paged_attention as pa
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def call(q, k, v, pool, tables, positions, layer, starts, sink):
+        return pa.paged_decode_attention_kernel(
+            q, k, v, pool, tables, positions, layer, window,
+            None if window is None else starts,
+            None if window is None else sink)
+
+    compiled = jax.jit(call).lower(
+        spec((16, 64, 192)), spec((16, hkv, 192), jnp.bfloat16),
+        spec((16, hkv, 128), jnp.bfloat16), spec(pool, jnp.bfloat16),
+        spec((16, nb_pad), jnp.int32), spec((16,), jnp.int32),
+        spec((), jnp.int32), spec((16,), jnp.int32),
+        spec((64,))).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert ("paged_window_decode_attention" in text) == (window is not None)
+    # The pool is an operand as it stands at 4 key/value heads too: no
+    # converted copy of it.
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("hkv, window, name", [
+    (4, None, "flash_prefill_fwd_causal"),
+    (8, 128, "flash_prefill_fwd_window_128")],
+    ids=["causal_4_key_heads", "window_128_sink"])
+def test_prefill_forward_compiles_for_v5e_at_keys_of_192(
+        one_chip, no_compile_cache, hkv, window, name):
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.flash_attention import prefill_attention_fwd
+
+    def spec(h, d):
+        return jax.ShapeDtypeStruct((h, 8192, d), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    sink = jax.ShapeDtypeStruct((64,), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(lambda q, k, v, b: prefill_attention_fwd(
+        q, k, v, window, None if window is None else b)).lower(
+        spec(64, 192), spec(hkv, 192), spec(hkv, 128), sink).compile()
+    assert name in compiled.as_text()
+    # Beside the float32 output: q and k filled up to whole lanes.
+    assert compiled.memory_analysis().temp_size_in_bytes < 400e6
+
+
+def test_mimo_decode_step_compiles_for_v5e_with_both_pools_in_place(
+        one_chip, no_compile_cache, monkeypatch):
+    """The whole (16, 512) decode step at the published widths as the
+    chip dispatches it: both groups' pools are aliased to the outputs,
+    seven attention kernel calls (2 global on 4 key/value heads, 5 window
+    on 8 with the sink), six of the experts' kernel, and beside its
+    arguments the program holds a few megabytes."""
+    import json
+    import os
+
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks.harness import manifest
+    from ray_tpu.models.mimo_v2 import init_params
+    from ray_tpu.serve.engine import MimoEngineModel
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    family = manifest.load_family("mimo_v2")
+    with open(os.path.join(manifest.ROOT, "benchmarks", "configs",
+                           "mimo-v2.5.json")) as f:
+        cfg = family.model_config(family.widths(json.load(f)))
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(on_chip, jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    weight_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                       for a in jax.tree_util.tree_leaves(params))
+    assert 6.8e9 < weight_bytes < 6.9e9
+    model = MimoEngineModel(params, cfg, max_batch_size=16)
+    assert model._attn_inplace
+    assert (10240, 16) + model.kv_token_shape == MIMO_GLOBAL_POOL
+    assert (160, 16) + model.kv_groups["window"]["kv_shape"] == \
+        MIMO_WINDOW_POOL
+    assert model.window_table_blocks(16) == 9
+    pools = {"global": jax.ShapeDtypeStruct(MIMO_GLOBAL_POOL, jnp.bfloat16,
+                                            sharding=one_chip),
+             "window": jax.ShapeDtypeStruct(MIMO_WINDOW_POOL, jnp.bfloat16,
+                                            sharding=one_chip)}
+    compiled = model._build_decode_paged(16, 512, 16).lower(
+        pools, params, jax.ShapeDtypeStruct((16, 6 + 512 + 9), jnp.int32,
+                                            sharding=one_chip)).compile()
+    memory, text = compiled.memory_analysis(), compiled.as_text()
+    assert text.count("paged_window_decode_attention") >= 5
+    assert text.count("held_experts_ffn_decode") >= 6
+    both = (int(np.prod(MIMO_GLOBAL_POOL))
+            + int(np.prod(MIMO_WINDOW_POOL))) * 2
+    assert both <= memory.alias_size_in_bytes < 1.01 * both
+    assert memory.temp_size_in_bytes < 100e6
