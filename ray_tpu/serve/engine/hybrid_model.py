@@ -339,7 +339,8 @@ class HybridEngineModel(SparseEngineModel):
                      positions: Sequence[int],
                      write_blocks: Sequence[int],
                      write_offs: Sequence[int], block_size: int,
-                     state=None, slots: Sequence[int] = ()):
+                     state=None, slots: Sequence[int] = (), *,
+                     meanwhile=None):
         """One fused step, as `TransformerEngineModel.decode_paged`,
         over both pools: `state` is the cache's state pool and
         `slots[i]` row i's slot (a list shorter than the batch leaves
@@ -349,11 +350,11 @@ class HybridEngineModel(SparseEngineModel):
         with flight.span("model", "decode", len(last_tokens)):
             return self._decode_paged(pool, block_tables, last_tokens,
                                       positions, write_blocks, write_offs,
-                                      block_size, state, slots)
+                                      block_size, state, slots, meanwhile)
 
     def _decode_paged(self, pool, block_tables, last_tokens, positions,
                       write_blocks, write_offs, block_size: int, state,
-                      slots):
+                      slots, meanwhile):
         phase = self.phase
         b = len(last_tokens)
         self.decode_calls += 1
@@ -387,5 +388,6 @@ class HybridEngineModel(SparseEngineModel):
             packed[:k, 3] = write_offs[:k]
             packed[:min(len(slots), b), 4] = slots[:b]
             args = (pool, state, self._params, packed)
-        step, (new_pool, new_state) = self._run_decode(fn, args, b, b_pad)
+        step, (new_pool, new_state) = self._run_decode(fn, args, b, b_pad,
+                                                       meanwhile)
         return step, new_pool, new_state

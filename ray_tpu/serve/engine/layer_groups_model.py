@@ -251,7 +251,8 @@ class LayerGroupsEngineModel(SparseEngineModel):
     def decode_paged(self, pools, block_tables: List[dict],
                      last_tokens: Sequence[int],
                      positions: Sequence[int], write_blocks: dict,
-                     write_offs: dict, block_size: int):
+                     write_offs: dict, block_size: int, *,
+                     meanwhile=None):
         """One fused step over both layer groups. `pools`, `write_blocks`
         and `write_offs` are dicts a group (`KVCacheManager.paged_step`
         over groups); ``block_tables[i]`` is row i's ``{group: (base,
@@ -261,10 +262,11 @@ class LayerGroupsEngineModel(SparseEngineModel):
         with flight.span("model", "decode", len(last_tokens)):
             return self._decode_paged(pools, block_tables, last_tokens,
                                       positions, write_blocks, write_offs,
-                                      block_size)
+                                      block_size, meanwhile)
 
     def _decode_paged(self, pools, block_tables, last_tokens, positions,
-                      write_blocks, write_offs, block_size: int):
+                      write_blocks, write_offs, block_size: int,
+                      meanwhile):
         b = len(last_tokens)
         self.decode_calls += 1
         window = self._cfg.window
@@ -322,5 +324,5 @@ class LayerGroupsEngineModel(SparseEngineModel):
             packed[:k, 3] = write_blocks[GLOBAL][:k]
             packed[:k, 4] = write_blocks[WINDOW][:k]
             args = (pools, self._params, packed)
-        step, (new_pools,) = self._run_decode(fn, args, b, b_pad)
+        step, (new_pools,) = self._run_decode(fn, args, b, b_pad, meanwhile)
         return step, new_pools

@@ -23,7 +23,16 @@ through block tables; the engine never gathers a sequence's KV for it:
   ``last_tokens[i]`` is its most recent token (not yet cached), and its
   KV lands at ``(write_blocks[i], write_offs[i])`` (a write list shorter
   than the batch writes only those rows; empty = read-only). ``step`` is
-  host logits ``[B, V]`` or a `DecodeStep`.
+  host logits ``[B, V]`` or a `DecodeStep`. It takes one keyword beside
+  them, ``meanwhile``: a callable that the model runs exactly once in the
+  call, where it has started the step and has nothing to do until the
+  step's result is there (between the ``decode.dispatch`` and the
+  ``decode.logits_wait`` span of a model that dispatches to a device;
+  before it computes, for one that computes on the host). It is the one
+  point of a step at which the host knows the device is busy, and the
+  model owns it; the scheduler delivers the step before's tokens there.
+  Keyword-only, because the families' further arguments (``state``,
+  ``slots``) trail the positional ones. None runs nothing.
 
 Beside them, three attributes: ``kv_token_shape`` and ``kv_dtype`` (a
 pool row) and ``kv_pool_ns``, the array namespace the model's step reads
@@ -293,13 +302,17 @@ class TinyLM:
                      last_tokens: Sequence[int],
                      positions: Sequence[int],
                      write_blocks: Sequence[int],
-                     write_offs: Sequence[int], block_size: int):
+                     write_offs: Sequence[int], block_size: int, *,
+                     meanwhile=None):
         """Fused paged step: read through the block tables, decode,
         write each new token's KV into its (block, off) slot, and
         return ``(logits, new_pool)``. `write_blocks` may be shorter
         than the batch (empty = read-only step, e.g. a full prefix
         hit). The oracle keeps everything on host; only the write-back
-        shape matters here."""
+        shape matters here. It has no device to wait for, so `meanwhile`
+        runs before it computes: the same order as a model that has."""
+        if meanwhile is not None:
+            meanwhile()
         kvs = [self._pool_gather(pool, block_tables[i],
                                  int(positions[i]), block_size)
                for i in range(len(last_tokens))]
@@ -816,7 +829,8 @@ class TransformerEngineModel:
                      last_tokens: Sequence[int],
                      positions: Sequence[int],
                      write_blocks: Sequence[int],
-                     write_offs: Sequence[int], block_size: int):
+                     write_offs: Sequence[int], block_size: int, *,
+                     meanwhile=None):
         """One fused incremental step reading KV straight out of the
         device pool and writing the new tokens' KV back in-place. The
         step crosses the host boundary once each way with a few
@@ -833,14 +847,16 @@ class TransformerEngineModel:
         re-bind, e.g. via `KVCacheManager.paged_step`). `write_blocks`
         may be shorter than the batch; missing rows (and batch padding
         rows) scatter past the pool and are dropped, so an empty write
-        list is a read-only step."""
+        list is a read-only step. `meanwhile` runs once the step is
+        dispatched, before the wait for its ids: beside a busy device."""
         with flight.span("model", "decode", len(last_tokens)):
             return self._decode_paged(pool, block_tables, last_tokens,
                                       positions, write_blocks, write_offs,
-                                      block_size)
+                                      block_size, meanwhile)
 
     def _decode_paged(self, pool, block_tables, last_tokens, positions,
-                      write_blocks, write_offs, block_size: int):
+                      write_blocks, write_offs, block_size: int,
+                      meanwhile):
         phase = self.phase
         b = len(last_tokens)
         self.decode_calls += 1
@@ -890,6 +906,8 @@ class TransformerEngineModel:
             # before the step is on the device.
             ids, logits, new_pool = fn(*args)
         self._count_products(b_pad)
+        if meanwhile is not None:
+            meanwhile()
         with flight.span("model", "decode.logits_wait", None, phase,
                          "decode_wait_s"):
             ids = np.asarray(ids)

@@ -264,18 +264,21 @@ class PrefixIndex:
         return self.evict(self._nodes)
 
     # -- observability -------------------------------------------------
+    # Both read plain ints and take no lock: the engine's gauges read
+    # them from inside a decode step, under the cache's lock
+    # (`scheduler._in_shadow`), while `insert` on another thread
+    # (`import_prefix`) holds this index's lock and waits for the
+    # cache's. A count may be one insert ahead of another's.
     def held_blocks(self) -> int:
-        with self._lock:
-            return self._nodes
+        return self._nodes
 
     def stats(self) -> Dict[str, int]:
-        with self._lock:
-            return {
-                "nodes": self._nodes,
-                "hits": self.hits,
-                "misses": self.misses,
-                "hit_tokens": self.hit_tokens,
-                "inserted": self.inserted,
-                "evictions": self.evictions,
-                "exports": self.exports,
-            }
+        return {
+            "nodes": self._nodes,
+            "hits": self.hits,
+            "misses": self.misses,
+            "hit_tokens": self.hit_tokens,
+            "inserted": self.inserted,
+            "evictions": self.evictions,
+            "exports": self.exports,
+        }

@@ -65,6 +65,20 @@ that returns host logits (`TinyLM`) has their argmax taken: the same
 tokens. Who may ask a `DecodeStep` for logits, through `np.asarray`: a
 fully cached prompt's first token below, a reference check, a test;
 never the steady step.
+
+A decode step's tokens are delivered while the device runs the next
+step. What the next step needs of them is done at once (the token
+joins its sequence, a sequence that ended leaves the batch and
+frees its blocks); what only the client needs (`stream._push` and the
+consumers it wakes, a finished stream's `_finish`, the gauges) waits in
+`_pending` and is handed over from inside the next `decode_paged`,
+between its dispatch and its wait for the ids (`meanwhile`, which the
+model runs there: `model.py`), when this thread would otherwise sleep.
+Nothing pending waits behind anything but that one dispatch: it is
+flushed at once where no decode step follows (nothing left running),
+before a prefill's call, and before anything else ends a stream (a
+cancellation, a failed step, `stop`), so a stream sees its tokens in
+order and never its end before a token generated for it.
 """
 
 from __future__ import annotations
@@ -376,6 +390,11 @@ class InferenceEngine:
         # Retirement stamps feeding the queue-drain-rate estimate behind
         # EngineOverloadedError.retry_after_s.
         self._finish_stamps: deque = deque(maxlen=64)
+        # Decode tokens not yet handed to their streams, in order:
+        # (sequence, token, whether it was the sequence's last). Only
+        # the loop's thread touches it.
+        self._pending: List[tuple] = []
+        self.tokens_delivered_overlapped = 0
 
     # -- submission ----------------------------------------------------
     def submit(self, prompt_tokens: Sequence[int],
@@ -554,7 +573,7 @@ class InferenceEngine:
         with self._lock:
             batch = list(self._running)
         if not batch:
-            self._update_gauges()
+            self._settle_idle()
             return None
         with flight.span("engine", "capacity", None, clocks, "capacity_s"):
             if self.cache.grouped:
@@ -573,18 +592,56 @@ class InferenceEngine:
         with self._lock:
             batch = list(self._running)
         if not batch:
-            self._update_gauges()
+            self._settle_idle()
             return None
         try:
             self._decode_once(batch)
         except Exception as e:  # noqa: BLE001 — the loop must survive
             logger.exception("decode step failed; failing %d stream(s)",
                              len(batch))
+            # Where the step failed before its `meanwhile`, the tokens
+            # of the step before are still pending: they come first.
+            self._deliver()
             for seq in batch:
                 self._retire(seq, error=e)
         self.steps += 1
-        self._update_gauges()
+        with self._lock:
+            follows = bool(self._running)
+        if not follows:
+            # Else the next step's `meanwhile` delivers this step's
+            # tokens and updates the gauges, beside a busy device.
+            self._settle_idle()
         return len(batch)
+
+    def _settle_idle(self) -> None:
+        """No decode step follows in whose shadow to deliver: hand over
+        what is pending and bring the gauges up to date, at once."""
+        self._deliver()
+        self._update_gauges()
+
+    def _in_shadow(self) -> None:
+        """A decode step's `meanwhile`: the device runs the step just
+        dispatched, and the step before's tokens and gauges go out. Under
+        the cache's lock (`paged_step`), so nothing here may wait for
+        another thread that wants the cache."""
+        self.tokens_delivered_overlapped += self._deliver()
+        self._update_gauges()
+
+    def _deliver(self) -> int:
+        """Hand the pending decode tokens to their streams, in order,
+        and end the streams of the sequences they ended. Returns how
+        many there were."""
+        pending = self._pending
+        if not pending:
+            return 0
+        self._pending = []
+        with flight.span("engine", "emit", len(pending), self._clocks,
+                         "emit_s"):
+            for seq, tok, last in pending:
+                seq.stream._push(tok)
+                if last:
+                    self._close(seq)
+        return len(pending)
 
     def _reap_cancelled(self) -> None:
         with self._lock:
@@ -593,6 +650,8 @@ class InferenceEngine:
                                  if s.stream.cancelled]
             for s in waiting_cancelled:
                 self._waiting.remove(s)
+        if cancelled or waiting_cancelled:
+            self._deliver()   # a pending token precedes its stream's end
         for s in cancelled + waiting_cancelled:
             self._retire(s)
 
@@ -645,6 +704,10 @@ class InferenceEngine:
 
     def _prefill_inner(self, seq: _Sequence, sp: flight.span) -> bool:
         clocks = self._clocks
+        # A prefill is milliseconds to tenths of a second of device
+        # time: no token waits behind it. (Nor may this sequence's own
+        # first token overtake what a preempted run of it left pending.)
+        self._deliver()
         admission = time.perf_counter()
         tokens = list(seq.all_tokens)
         n = len(tokens)
@@ -724,7 +787,9 @@ class InferenceEngine:
         self.prefix_hit_tokens += hit
         with flight.span("engine", "emit", None, clocks, "emit_s"):
             self._emit(seq, tok)
-            if not self._maybe_finish(seq):
+            if self._ended(seq):
+                self._retire(seq)
+            else:
                 with self._lock:
                     self._running.append(seq)
         return True
@@ -806,14 +871,20 @@ class InferenceEngine:
                 entries,
                 lambda pool, blocks, offs, *state: self.model.decode_paged(
                     pool, tables, lasts, poss, blocks, offs,
-                    self.config.block_size, *state))
+                    self.config.block_size, *state,
+                    meanwhile=self._in_shadow))
         self.paged_steps += 1
         with flight.span("engine", "sample", b, clocks, "sample_s"):
             toks = self._greedy(logits)
-        with flight.span("engine", "emit", b, clocks, "emit_s"):
-            for seq, tok in zip(batch, toks):
-                self._emit(seq, tok)
-                self._maybe_finish(seq)
+        # What the next step needs of the tokens, at once; the streams
+        # get them from its `meanwhile`.
+        for seq, tok in zip(batch, toks):
+            seq.all_tokens.append(tok)
+            last = self._ended(seq)
+            if last:
+                self._release(seq)
+            self._pending.append((seq, tok, last))
+        self.tokens_generated += b
 
     @staticmethod
     def _greedy(step) -> List[int]:
@@ -827,6 +898,8 @@ class InferenceEngine:
         return ids.tolist()
 
     def _emit(self, seq: _Sequence, tok: int) -> None:
+        """A prefill's token, the sequence's first: to its stream at
+        once."""
         seq.all_tokens.append(tok)
         if seq.first_token_at is None:
             seq.first_token_at = time.perf_counter()
@@ -842,23 +915,31 @@ class InferenceEngine:
         self.tokens_generated += 1
         seq.stream._push(tok)
 
-    def _maybe_finish(self, seq: _Sequence) -> bool:
+    def _ended(self, seq: _Sequence) -> bool:
+        """Whether the token just appended was the sequence's last."""
         eos = getattr(self.model, "eos_token", None)
-        if (seq.generated >= seq.max_new_tokens
-                or (eos is not None and seq.all_tokens[-1] == eos)):
-            self._retire(seq)
-            return True
-        return False
+        return (seq.generated >= seq.max_new_tokens
+                or (eos is not None and seq.all_tokens[-1] == eos))
 
-    def _retire(self, seq: _Sequence,
-                error: Optional[BaseException] = None) -> None:
+    def _release(self, seq: _Sequence) -> None:
+        """The engine's half of a retirement: out of the batch, its
+        blocks free for the next step."""
         with self._lock:
             if seq in self._running:
                 self._running.remove(seq)
         self.cache.free(seq.seq_id)
+
+    def _close(self, seq: _Sequence,
+               error: Optional[BaseException] = None) -> None:
+        """The client's half: the stream ends."""
         self.finished += 1
         self._finish_stamps.append(time.perf_counter())
         seq.stream._finish(error)
+
+    def _retire(self, seq: _Sequence,
+                error: Optional[BaseException] = None) -> None:
+        self._release(seq)
+        self._close(seq, error)
 
     # -- hosting -------------------------------------------------------
     def start(self) -> None:
@@ -898,6 +979,7 @@ class InferenceEngine:
     def _fail_in_flight(self, error: BaseException) -> None:
         """Finish every running and waiting stream with `error`, so
         consumers unblock and see it."""
+        self._deliver()
         with self._lock:
             leftovers = list(self._running) + list(self._waiting)
             self._running.clear()
@@ -911,7 +993,7 @@ class InferenceEngine:
         deadline = time.monotonic() + timeout_s
         while time.monotonic() < deadline:
             with self._lock:
-                if not self._running and not self._waiting:
+                if not (self._running or self._waiting or self._pending):
                     return True
             time.sleep(0.005)
         return False
@@ -974,6 +1056,10 @@ class InferenceEngine:
         step, which also writes the new tokens' KV).
         `paged` reads True and `paged_steps` counts the decode steps:
         every step is the paged one.
+        `tokens_delivered_overlapped` counts the decode tokens handed to
+        their streams from inside the next step's `meanwhile`, beside a
+        busy device; `tokens_generated` less `prefills` (a prefill's
+        token goes out at once) less it, those flushed early.
         `prefill_kv_device_writes` and `prefill_kv_host_writes` count
         the prompt-KV writes into the pool (`write_range`) that stayed
         on the device, and those that passed through host memory.
@@ -1042,6 +1128,7 @@ class InferenceEngine:
             "prefills": self.prefills,
             "preemptions": self.preemptions,
             "tokens_generated": self.tokens_generated,
+            "tokens_delivered_overlapped": self.tokens_delivered_overlapped,
             "prefix_hit_tokens": self.prefix_hit_tokens,
             "prefix_exports": self.prefix_exports,
             "prefix_imports": self.prefix_imports,

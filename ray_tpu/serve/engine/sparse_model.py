@@ -161,12 +161,14 @@ class SparseEngineModel:
             logits = np.asarray(logits)
         return logits, rest, n
 
-    def _run_decode(self, fn, args, b: int, b_pad: int):
+    def _run_decode(self, fn, args, b: int, b_pad: int, meanwhile=None):
         """One dispatch of a decode bucket's program `fn` over `args`
         (the packed host array among them: the step's one upload).
         Fetches its int32 result (``[b_pad + 3]``: greedy ids, then the
         step's three expert counters), counts both, and returns the
-        `DecodeStep` and what else the program returned (the pools)."""
+        `DecodeStep` and what else the program returned (the pools).
+        `meanwhile` (the protocol's: `model.py`) runs between the
+        dispatch and the fetch."""
         phase = self.phase
         self.decode_h2d_arrays += sum(
             isinstance(leaf, np.ndarray)
@@ -175,6 +177,8 @@ class SparseEngineModel:
                          "decode_dispatch_s"):
             out, logits, *rest = fn(*args)
         self._count_experts_step(b_pad)
+        if meanwhile is not None:
+            meanwhile()
         with flight.span("model", "decode.logits_wait", None, phase,
                          "decode_wait_s"):
             out = np.asarray(out)

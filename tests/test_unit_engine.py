@@ -447,6 +447,273 @@ def test_engine_stats_and_ttft():
 
 
 # ---------------------------------------------------------------------------
+# deferred delivery: a step's tokens go out beside the next step
+# ---------------------------------------------------------------------------
+class _Shadowed(TinyLM):
+    """Logs what the streams have seen (tokens so far, finished) at each
+    point of a call: a decode step's entry (`dispatch`), the end of its
+    `meanwhile` (`delivered`), its arithmetic (`compute`: what a device
+    model waits for), and a prefill's call (`prefill`)."""
+
+    def __init__(self, **kwargs):
+        super().__init__(**kwargs)
+        self.log = []
+        self.streams = []
+        self.boom = False
+
+    def _mark(self, what):
+        self.log.append((what, [(len(s.tokens_so_far()), s.finished)
+                                for s in self.streams]))
+
+    def decode_paged(self, *args, meanwhile=None):
+        self._mark("dispatch")
+
+        def delivering():
+            meanwhile()
+            self._mark("delivered")
+
+        return super().decode_paged(
+            *args, meanwhile=None if meanwhile is None else delivering)
+
+    def decode(self, kvs, last_tokens, positions):
+        self._mark("compute")
+        if self.boom:
+            raise RuntimeError("kaboom")
+        return super().decode(kvs, last_tokens, positions)
+
+    def prefill(self, tokens, prefix_kv=None):
+        self._mark("prefill")
+        return super().prefill(tokens, prefix_kv)
+
+
+def _shadowed(requests, **config):
+    model = _Shadowed()
+    eng = InferenceEngine(model, EngineConfig(block_size=4, num_blocks=64,
+                                              **config))
+    model.streams = [eng.submit(p, n) for p, n in requests]
+    return model, eng, model.streams
+
+
+def test_a_steps_tokens_reach_their_streams_inside_the_next_steps_meanwhile():
+    model, eng, (a, b) = _shadowed([([5, 9, 3], 6), ([2, 2], 6)])
+    assert eng.step()               # two prefills, the first decode step
+    # Each stream has its prefill's token, at once; the step's are held.
+    assert [len(s.tokens_so_far()) for s in (a, b)] == [1, 1]
+    assert eng.stats()["tokens_generated"] == 4
+    assert eng.stats()["tokens_delivered_overlapped"] == 0
+    model.log.clear()
+    assert eng.step()
+    # dispatch -> the step before's tokens -> the wait for this step's.
+    assert model.log == [("dispatch", [(1, False), (1, False)]),
+                         ("delivered", [(2, False), (2, False)]),
+                         ("compute", [(2, False), (2, False)])]
+    assert eng.stats()["tokens_delivered_overlapped"] == 2
+    _drive(eng)
+    for stream, (p, n) in zip((a, b), [([5, 9, 3], 6), ([2, 2], 6)]):
+        assert stream.tokens_so_far() == model.oracle(p, n)
+        assert stream.finished
+    stats = eng.stats()
+    # 5 decode steps of 2 rows; all but the last step's went out in a
+    # step's shadow, and the last step had none to follow it.
+    assert stats["tokens_generated"] - stats["prefills"] == 10
+    assert stats["tokens_delivered_overlapped"] == 8
+    assert [what for what, _ in model.log].count("delivered") == \
+        stats["paged_steps"] - 1 == 4
+
+
+def test_the_last_token_and_the_finish_arrive_without_a_further_step():
+    model, eng, (only,) = _shadowed([([7, 7], 3)])
+    assert eng.step() and eng.step()                # tokens 2 and 3
+    assert only.finished
+    assert only.tokens_so_far() == model.oracle([7, 7], 3)
+    assert eng.stats()["finished"] == 1
+    assert model.decode_calls == 2
+    assert eng.drain(timeout_s=0.1)
+    assert eng.step() is False and model.decode_calls == 2
+
+
+def test_a_sequence_that_ends_beside_others_ends_in_the_next_steps_shadow():
+    model, eng, (short, long) = _shadowed([([4], 2), ([3, 3, 3], 5)])
+    eng.step()                      # prefills; the step ends `short`
+    assert eng.batch_occupancy() == 1 and not short.finished
+    assert eng.cache.block_table("seq-0") == []     # its blocks are free
+    model.log.clear()
+    eng.step()
+    assert model.log[1] == ("delivered", [(2, True), (2, False)])
+    assert eng.stats()["finished"] == 1
+
+
+def test_pending_tokens_are_flushed_before_a_prefills_call():
+    model, eng, (first,) = _shadowed([([5, 9, 3], 8)], max_batch_size=2)
+    eng.step()
+    eng.step()
+    assert len(first.tokens_so_far()) == 2          # one token is pending
+    late = eng.submit([2, 2], 4)
+    model.streams.append(late)
+    model.log.clear()
+    overlapped = eng.stats()["tokens_delivered_overlapped"]
+    eng.step()
+    # The pending token went out before the prefill's call began, not
+    # behind it; the decode step that followed had nothing left to hand
+    # over.
+    assert model.log == [("prefill", [(3, False), (0, False)]),
+                         ("dispatch", [(3, False), (1, False)]),
+                         ("delivered", [(3, False), (1, False)]),
+                         ("compute", [(3, False), (1, False)])]
+    assert eng.stats()["tokens_delivered_overlapped"] == overlapped
+    _drive(eng)
+    assert first.tokens_so_far() == model.oracle([5, 9, 3], 8)
+    assert late.tokens_so_far() == model.oracle([2, 2], 4)
+
+
+@pytest.mark.parametrize("ending", ["cancel", "decode_raises", "stop"])
+def test_a_pending_token_precedes_whatever_ends_its_stream(ending):
+    from ray_tpu.serve.engine import EngineStoppedError
+
+    model, eng, (stream,) = _shadowed([([5, 5], 50)])
+    eng.step()
+    eng.step()
+    want = model.oracle([5, 5], 3)
+    assert stream.tokens_so_far() == want[:2] and not stream.finished
+    if ending == "cancel":
+        stream.cancel()
+        eng.step()
+        error = None
+    elif ending == "decode_raises":
+        model.boom = True           # after the dispatch and the meanwhile
+        eng.step()
+        error = RuntimeError
+        want = model.oracle([5, 5], 3)
+    else:
+        eng.stop()
+        error = EngineStoppedError
+    assert stream.finished
+    assert stream.tokens_so_far() == want
+    got = []
+    if error is None:
+        got = list(stream)
+    else:
+        with pytest.raises(error):
+            for tok in stream:
+                got.append(tok)
+    assert got == want              # every token, then the end
+    assert eng.cache.free_blocks() == eng.cache.num_blocks - (
+        eng.prefix_index.held_blocks())
+
+
+def test_a_step_that_fails_before_its_meanwhile_delivers_first():
+    """A failure before the model reached its `meanwhile` (here: in the
+    call itself) leaves the step before's token pending; it still comes
+    before the error."""
+    model, eng, (stream,) = _shadowed([([5, 5], 50)])
+    eng.step()
+    eng.step()
+
+    def broken(*args, **kwargs):
+        raise RuntimeError("no step")
+
+    model.decode_paged = broken
+    eng.step()
+    got = []
+    with pytest.raises(RuntimeError, match="no step"):
+        for tok in stream:
+            got.append(tok)
+    assert got == model.oracle([5, 5], 3)
+
+
+def test_a_preempted_sequences_pending_token_precedes_its_next_first_token():
+    """Preemption requeues a sequence whose last token may still be
+    pending; the prefill that readmits it delivers that token before its
+    own."""
+    m = TinyLM()
+    eng = InferenceEngine(m, EngineConfig(max_batch_size=4, block_size=4,
+                                          num_blocks=6))
+    hi = eng.submit([3, 5, 7], 18, priority=1)
+    lo = eng.submit([2, 4, 6], 18, priority=0)
+    seen = []
+    while eng.step():
+        seen.append((hi.tokens_so_far(), lo.tokens_so_far()))
+    assert eng.preemptions > 0
+    for (h0, l0), (h1, l1) in zip(seen, seen[1:]):
+        assert h1[:len(h0)] == h0 and l1[:len(l0)] == l0
+    assert hi.tokens_so_far() == m.oracle([3, 5, 7], 18)
+    assert lo.tokens_so_far() == m.oracle([2, 4, 6], 18)
+
+
+def test_the_hosted_loop_delivers_every_token_once_and_in_order():
+    m = TinyLM(step_delay_s=0.001)
+    eng = InferenceEngine(m, EngineConfig(max_batch_size=4, block_size=4,
+                                          num_blocks=64))
+    eng.start()
+    try:
+        reqs = [([3 + i, 5], 12 + i) for i in range(8)]
+        got = {}
+
+        def consume(i, stream):
+            got[i] = list(stream)
+
+        threads = [threading.Thread(target=consume,
+                                    args=(i, eng.submit(p, n)))
+                   for i, (p, n) in enumerate(reqs)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30)
+        assert not any(t.is_alive() for t in threads)
+        for i, (p, n) in enumerate(reqs):
+            assert got[i] == m.oracle(p, n)
+        assert eng.drain(timeout_s=5)
+        stats = eng.stats()
+        assert stats["finished"] == 8
+        assert 0 < stats["tokens_delivered_overlapped"] <= \
+            stats["tokens_generated"] - stats["prefills"]
+    finally:
+        eng.stop()
+
+
+def test_shipped_prefixes_arrive_while_steps_deliver_in_their_shadow():
+    """A step's `meanwhile` runs under the cache's lock, and the gauges
+    it updates read the prefix index; `import_prefix` on another thread
+    holds the index's lock while it takes the cache's. Neither may wait
+    for the other: both finish, in bounded time."""
+    import sys
+
+    m = TinyLM(step_delay_s=0.0005)
+    eng = InferenceEngine(m, EngineConfig(max_batch_size=4, block_size=4,
+                                          num_blocks=512))
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-4)
+    eng.start()
+    stuck = True        # a deadlocked loop holds the lock `stop` needs
+    try:
+        reqs = [([3 + i, 5, 7], 150) for i in range(4)]
+        streams = [eng.submit(p, n) for p, n in reqs]
+        shipped = []
+
+        def ship():
+            rng = np.random.default_rng(0)
+            for _ in range(600):
+                chunks = [tuple(int(t) for t in rng.integers(2, 30, 4))
+                          for _ in range(2)]
+                shipped.append(eng.import_prefix(
+                    chunks, [np.ones((4, 1), np.float32)] * 2))
+
+        shipper = threading.Thread(target=ship, daemon=True)
+        shipper.start()
+        shipper.join(timeout=30)
+        stuck = shipper.is_alive()
+        assert not stuck, "import_prefix and a decode step deadlocked"
+        assert len(shipped) == 600
+        assert eng.drain(timeout_s=30)
+        for (p, n), stream in zip(reqs, streams):
+            assert stream.tokens_so_far() == m.oracle(p, n)
+    finally:
+        sys.setswitchinterval(interval)
+        if not stuck:
+            eng.stop(timeout_s=1.0)
+
+
+# ---------------------------------------------------------------------------
 # transformer decode shim (real-model path, still CPU-fast)
 # ---------------------------------------------------------------------------
 @pytest.fixture(scope="module")
@@ -803,8 +1070,8 @@ def _wrap_decode_paged(model, after):
     """Pass every paged step's result through `after`."""
     inner = model.decode_paged
 
-    def decode_paged(*args):
-        step, pool = inner(*args)
+    def decode_paged(*args, **kwargs):
+        step, pool = inner(*args, **kwargs)
         return after(step), pool
 
     model.decode_paged = decode_paged

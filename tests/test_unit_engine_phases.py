@@ -144,12 +144,22 @@ def test_engine_spans_nest_in_the_flight_ring(recorder):
               if e[3] == "decode"]
     assert decode and all(e[5] == 2 for e in decode)      # arg: the batch
     inside = [e for e in flight.snapshot(categories={"engine"})
-              if e[3] in ("tables", "model_step", "sample", "emit")
-              and e[5] == 2]
-    assert len(inside) == 4 * len(decode)
+              if e[3] in ("tables", "model_step", "sample") and e[5] == 2]
+    assert len(inside) == 3 * len(decode)
     assert all(any(_contains(d, e) for d in decode) for e in inside)
+    # A step's tokens go out from inside the next step's model call; the
+    # last step's, which no step follows, after it and inside its
+    # `engine.step`.
+    emits = [e for e in flight.snapshot(categories={"engine"})
+             if e[3] == "emit" and e[5] == 2]
+    model_steps = [e for e in inside if e[3] == "model_step"]
+    assert len(emits) == len(decode) == 2
+    assert _contains(model_steps[1], emits[0])
+    assert not any(_contains(d, emits[1]) for d in decode)
     steps = [e for e in flight.snapshot(categories={"engine"})
              if e[3] == "step"]
+    assert any(_contains(s, emits[1]) and _contains(s, decode[1])
+               for s in steps)
     assert [e[5] for e in steps][-1] is None               # the idle one
     assert all(any(_contains(s, d) for s in steps) for d in decode)
 
@@ -361,3 +371,78 @@ def test_device_programs_are_named_after_their_functions(tiny_transformer):
     # (`mlp/mul`; under the gradient `jvp(embed)/...`).
     for scope in ("embed", "attn", "mlp", "lm_head"):
         assert f'"{scope}/' in text or f"({scope})/" in text, scope
+
+
+# Toy engines of the three model classes that dispatch to a device, each
+# as its family builds it (`benchmarks/families`): the dense model, the
+# hybrid with per-sequence state, and a model of two layer groups.
+_TOY_ENGINES = {
+    "dense": ("dense", "olmo-1b", {}),
+    "hybrid": ("solar_open2", "solar-open2-250b", {}),
+    "layer_groups": ("laguna", "laguna-s-2.1",
+                     {"group_blocks": {"window": 12}}),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(_TOY_ENGINES))
+def test_meanwhile_runs_once_a_step_between_its_dispatch_and_its_wait(
+        recorder, kind):
+    import json
+    import os
+
+    from benchmarks.harness import manifest
+
+    family_name, config, engine = _TOY_ENGINES[kind]
+    family = manifest.load_family(family_name)
+    with open(os.path.join(manifest.ROOT, "benchmarks", "configs",
+                           f"{config}.json")) as f:
+        widths = family.toy_widths(family.widths(json.load(f)))
+    served = family.build_serving(
+        widths, {"max_seq_len": 128, "engine": dict(
+            engine, max_batch_size=2, block_size=16, num_blocks=32)}, 7)
+    eng = InferenceEngine(served["model"], served["engine_config"])
+    delivering = eng._in_shadow
+
+    def recorded():
+        with flight.span("test", "meanwhile"):
+            delivering()
+
+    eng._in_shadow = recorded
+    streams = [eng.submit([3, 5, 7, 9, 2 + i], 5) for i in range(2)]
+    while eng.step():
+        pass
+    assert all(len(list(s)) == 5 for s in streams)
+    ring = flight.snapshot()
+    by_label = {}
+    for ev in ring:
+        if ev[2] in ("model", "test"):
+            by_label.setdefault(ev[3], []).append(ev)
+    calls = by_label["decode"]
+    assert len(calls) == eng.paged_steps == 4
+    for label in ("decode.dispatch", "meanwhile", "decode.logits_wait"):
+        assert len(by_label[label]) == len(calls), label
+    def end(event):
+        return event[0] + event[4] * 1e-6
+
+    for call, dispatch, meanwhile, wait in zip(
+            calls, by_label["decode.dispatch"], by_label["meanwhile"],
+            by_label["decode.logits_wait"]):
+        assert all(_contains(call, e) for e in (dispatch, meanwhile, wait))
+        assert end(dispatch) <= meanwhile[0] + 2e-6
+        assert end(meanwhile) <= wait[0] + 2e-6
+    # The streams' tokens and the gauges went out there, and nowhere in
+    # the model's own three phases.
+    emits = [e for e in ring if e[2] == "engine" and e[3] == "emit"
+             and e[5] == 2]
+    assert sum(any(_contains(m, e) for m in by_label["meanwhile"])
+               for e in emits) == 3
+    assert eng.stats()["tokens_delivered_overlapped"] == 6
+    assert _phase_sum(_clocks(eng)) == pytest.approx(
+        _clocks(eng)["loop_s"], rel=0.02)
+    # A caller that gives none gets a step and nothing else.
+    flight.reset()
+    family.warm_bucket(eng, served, 2, 1)
+    labels = [e[3] for e in flight.snapshot() if e[2] in ("model", "test")]
+    assert labels.count("decode.dispatch") == 1 == \
+        labels.count("decode.logits_wait")
+    assert "meanwhile" not in labels

@@ -367,10 +367,10 @@ class _HeldLM(TinyLM):
 
     hold = None
 
-    def decode_paged(self, *args):
+    def decode_paged(self, *args, **kwargs):
         if self.hold is not None:
             assert self.hold.wait(timeout=10.0)
-        return super().decode_paged(*args)
+        return super().decode_paged(*args, **kwargs)
 
 
 def test_all_replicas_dead_fails_conversations_not_hangs():
