@@ -26,7 +26,8 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.ops.flash_attention import (ATTN_OUT, FlashTiles, flash_mha,
-                                          prefill_attention_fwd)
+                                          prefill_attention_fwd,
+                                          prefill_block)
 
 _NEG_INF = -1e30
 
@@ -120,44 +121,56 @@ def plain_attention(q, k, v, *, causal: bool = True, positions=None):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def banded_attention(q, k, v, window: int = None, sink=None):
-    """The plain form of `prefill_attention`: q ``[H, S, D]`` over k
-    ``[Hkv, S, D]`` and v ``[Hkv, S, Dv]`` (query head ``i`` on key head
-    ``i // group``), causal, with `window` only the keys ``j`` with ``i -
-    j < window``, with `sink` ``[H]`` one more softmax column a head, of
-    that logit and no value. The whole ``[H, S, S]`` float32 score matrix
-    is built: for short prompts, the CPU and the tests. Float32 out,
-    ``[H, S, Dv]``."""
-    h, s, d = q.shape
-    hkv = k.shape[0]
-    qg = q.reshape(hkv, h // hkv, s, d)
+def banded_attention(q, k, v, window: int = None, sink=None, *, offset=0,
+                     live=None):
+    """The plain form of `prefill_attention`: q ``[H, Sq, D]`` over k
+    ``[Hkv, Sk, D]`` and v ``[Hkv, Sk, Dv]`` (query head ``i`` on key
+    head ``i // group``), causal, with `window` only the keys ``j`` with
+    ``i - j < window``, with `sink` ``[H]`` one more softmax column a
+    head, of that logit and no value; query ``i`` lies on key ``offset +
+    i`` and of the keys up to the last query's only the last `live`
+    exist (`flash_attention.prefill_attention_fwd`; a whole prompt's are
+    0 and every key). The whole ``[H, Sq, Sk]`` float32 score matrix is
+    built: for short prompts, the CPU and the tests. Float32 out, ``[H,
+    Sq, Dv]``."""
+    h, sq, d = q.shape
+    hkv, sk = k.shape[:2]
+    qg = q.reshape(hkv, h // hkv, sq, d)
     scores = jnp.einsum("kgqd,ksd->kgqs", qg, k,
                         preferred_element_type=jnp.float32) * d ** -0.5
-    idx = jnp.arange(s)
-    keep = idx[:, None] >= idx[None, :]
+    at_q = offset + jnp.arange(sq)[:, None]
+    at_k = jnp.arange(sk)[None, :]
+    keep = at_q >= at_k
     if window is not None:
-        keep &= idx[:, None] - idx[None, :] < window
+        keep &= at_q - at_k < window
+    if live is not None:
+        keep &= at_k >= offset + sq - live
     scores = jnp.where(keep, scores, _NEG_INF)
     if sink is not None:
         scores = jnp.concatenate([scores, jnp.broadcast_to(
             sink.astype(jnp.float32).reshape(hkv, h // hkv, 1, 1),
             scores.shape[:3] + (1,))], axis=-1)
-    probs = jax.nn.softmax(scores, axis=-1)[..., :s]
+    probs = jax.nn.softmax(scores, axis=-1)[..., :sk]
     out = jnp.einsum("kgqs,ksd->kgqd", probs.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
-    return out.reshape(h, s, v.shape[2])
+    return out.reshape(h, sq, v.shape[2])
 
 
-def prefill_attention(q, k, v, window: int = None, sink=None):
-    """One prompt's attention in the serving prefill (grouped heads, an
-    optional window, an optional sink, forward only): the Pallas forward
-    of `ops/flash_attention.py` on a TPU for values of a multiple of 128
-    (keys may be wider: 192 over 128) and a length that tiles,
-    `banded_attention` elsewhere."""
-    s, dv = q.shape[1], v.shape[2]
-    if jax.default_backend() == "tpu" and dv % 128 == 0 and s % 128 == 0:
-        return prefill_attention_fwd(q, k, v, window, sink)
-    return banded_attention(q, k, v, window, sink)
+def prefill_attention(q, k, v, window: int = None, sink=None, *, offset=0,
+                      live=None):
+    """One prompt's attention in the serving prefill, or one chunk's
+    over the keys it sees (`offset`, `live`: traced scalars or ints, as
+    `flash_attention.prefill_attention_fwd` takes them); grouped heads,
+    an optional window, an optional sink, forward only: the Pallas
+    forward of `ops/flash_attention.py` on a TPU for values of a
+    multiple of 128 (keys may be wider: 192 over 128) and lengths that
+    tile, `banded_attention` elsewhere."""
+    sq, sk, dv = q.shape[1], k.shape[1], v.shape[2]
+    if (jax.default_backend() == "tpu" and dv % 128 == 0
+            and sq % 128 == 0 and sk % prefill_block(sq, window) == 0):
+        return prefill_attention_fwd(q, k, v, window, sink, offset=offset,
+                                     live=live)
+    return banded_attention(q, k, v, window, sink, offset=offset, live=live)
 
 
 def ring_attention_manual(q, k, v, q_pos, *, axis_name: str = "sp",

@@ -401,13 +401,16 @@ flash_mha.defvjp(_flash_mha_fwd, _flash_mha_bwd)
 
 
 # -- the serving prefill's forward: grouped heads, an optional window ------
-def _prefill_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale: float, window,
-                        n_visit: int, with_sink: bool):
+def _prefill_fwd_kernel(at_ref, q_ref, k_ref, v_ref, *rest, scale: float,
+                        window, n_visit: int, with_sink: bool):
     """One query tile of one query head against the `kj`-th key tile it
     visits: all tiles up to the diagonal without a window, the last
-    `n_visit` up to the diagonal with one. A tile wholly above the
-    diagonal or wholly left of the window is skipped; the masks are
-    built only on a tile the diagonal or the window's edge crosses.
+    `n_visit` up to the diagonal with one. `at_ref` holds the key index
+    on the first query's diagonal and the count of live keys
+    (`prefill_attention_fwd`); positions below are key indices. A tile
+    wholly above the diagonal, wholly left of the window or wholly
+    before the first live key is skipped; the masks are built only on a
+    tile the diagonal, the window's edge or the first live key crosses.
     With a sink (``[1, 128]``, the head's logit on every lane) the
     running softmax starts from that column, which carries no value."""
     from jax.experimental import pallas as pl
@@ -416,8 +419,11 @@ def _prefill_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale: float, window,
     o_ref, m_ref, l_ref, acc_ref = rest[int(with_sink):]
     block, d = o_ref.shape
     qi, kj = pl.program_id(1), pl.program_id(2)
-    kt = kj if window is None else qi - (n_visit - 1) + kj
-    q0, k0 = qi * block, kt * block
+    offset = at_ref[0]
+    first_live = offset + pl.num_programs(1) * block - at_ref[1]
+    diag = qi + offset // block           # the tile the diagonal crosses
+    kt = kj if window is None else diag - (n_visit - 1) + kj
+    q0, k0 = diag * block, kt * block
 
     @pl.when(kj == 0)
     def _start():
@@ -435,7 +441,7 @@ def _prefill_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale: float, window,
         if masked:
             at_q = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
             at_k = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            keep = at_k <= at_q
+            keep = (at_k <= at_q) & (at_k >= first_live)
             if window is not None:
                 keep &= at_q - at_k < window
             s = jnp.where(keep, s, _MASK_VALUE)
@@ -449,8 +455,8 @@ def _prefill_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale: float, window,
             p.astype(v_ref.dtype), v_ref[...],
             preferred_element_type=jnp.float32)
 
-    runs = (kt >= 0) & (kt <= qi)
-    crossed = kt == qi
+    runs = (kt >= 0) & (kt <= diag) & (k0 + block > first_live)
+    crossed = (kt == diag) | (k0 < first_live)
     if window is not None:
         # The nearest and the farthest (query, key) pair of the tile.
         runs &= q0 - (k0 + block - 1) < window
@@ -477,15 +483,30 @@ def prefill_block(seq_len: int, window: int = None) -> int:
 
 
 def prefill_attention_fwd(q, k, v, window: int = None, sink=None, *,
-                          block: int = None, interpret: bool = False):
+                          offset=0, live=None, block: int = None,
+                          interpret: bool = False):
     """Causal softmax attention of one sequence for the serving prefill,
-    forward only: q ``[H, S, D]`` over k ``[Hkv, S, D]`` and v ``[Hkv,
-    S, Dv]``, query head ``i`` on key head ``i // (H // Hkv)``; with
+    forward only: q ``[H, Sq, D]`` over k ``[Hkv, Sk, D]`` and v ``[Hkv,
+    Sk, Dv]``, query head ``i`` on key head ``i // (H // Hkv)``; with
     `window` a query sees the keys ``j`` with ``i - j < window`` alone;
     with `sink` ``[H]`` a head's softmax has one more column of that
-    logit and no value. Float32 out, ``[H, S, Dv]``. ``S`` is a multiple
-    of `block` (`prefill_block`), ``Dv`` of 128; keys of another width
-    (192) go in filled up with zeros to whole lanes.
+    logit and no value. Float32 out, ``[H, Sq, Dv]``. ``Sq`` and ``Sk``
+    are multiples of `block` (`prefill_block`), ``Dv`` of 128; keys of
+    another width (192) go in filled up with zeros to whole lanes.
+
+    A whole prompt has as many queries as keys and query ``i`` lies on
+    key ``i``. A chunk of a prompt (`prefill_chunk`) brings its queries
+    and every key they may see: query ``i`` lies on key ``offset + i``
+    and of the keys up to the last query's the last `live` alone exist
+    (the chunk's own and those of the positions before it; a key before
+    them, the head of a window's tail that no position filled, is seen
+    by no query). Both may be traced scalars, which the kernel and its
+    index maps read from SMEM: one program whatever the chunk's place.
+    `offset` is a multiple of `block`, ``offset + Sq <= Sk`` and ``live
+    >= Sq``. A key tile past the diagonal or before the first live key
+    is neither computed nor fetched. A query tile walks the same key
+    tiles in the same order wherever its chunk begins: with `offset` 0
+    and every key live this is the whole prompt's result, bit for bit.
 
     The training kernels above are left as they are (their tiles, their
     names): this one runs under ``flash_prefill_fwd_causal`` or
@@ -493,31 +514,36 @@ def prefill_attention_fwd(q, k, v, window: int = None, sink=None, *,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    h, s, d = q.shape
-    hkv, dv = v.shape[0], v.shape[2]
-    if k.shape != (hkv, s, d) or v.shape[:2] != (hkv, s) or h % hkv:
+    h, sq, d = q.shape
+    hkv, sk, dv = v.shape
+    if k.shape != (hkv, sk, d) or sk < sq or h % hkv:
         raise ValueError(f"q {q.shape} does not go over k {k.shape}, "
                          f"v {v.shape}")
-    block = block or prefill_block(s, window)
-    if s % block or block % _LANES:
+    block = block or prefill_block(sq, window)
+    if sq % block or sk % block or block % _LANES:
         raise ValueError(f"block={block} is no multiple of {_LANES} that "
-                         f"divides {s}")
+                         f"divides {sq} and {sk}")
     scale = d ** -0.5
     if d % _LANES:
         fill = ((0, 0), (0, 0), (0, -d % _LANES))
         q, k = jnp.pad(q, fill), jnp.pad(k, fill)
         d = q.shape[2]
     group = h // hkv
-    n_tiles = s // block
+    n_tiles = sk // block
     n_visit = (n_tiles if window is None
                else min(n_tiles, -(-(window - 1) // block) + 1))
+    at = jnp.stack([jnp.asarray(offset, jnp.int32),
+                    jnp.asarray(offset + sq if live is None else live,
+                                jnp.int32)])
 
-    def kv_map(hi, qi, kj):
-        kt = kj if window is None else qi - (n_visit - 1) + kj
+    def kv_map(hi, qi, kj, at_ref):
+        diag = qi + at_ref[0] // block
+        kt = kj if window is None else diag - (n_visit - 1) + kj
+        first_live = jnp.maximum(at_ref[0] + sq - at_ref[1], 0) // block
         # A tile that is skipped is not fetched: stay on one that runs.
-        return (hi // group, jnp.clip(kt, 0, qi), 0)
+        return (hi // group, jnp.clip(kt, first_live, diag), 0)
 
-    def q_map(hi, qi, kj):
+    def q_map(hi, qi, kj, at_ref):
         return (hi, qi, 0)
 
     in_specs = [pl.BlockSpec((None, block, d), q_map),
@@ -526,23 +552,25 @@ def prefill_attention_fwd(q, k, v, window: int = None, sink=None, *,
     operands = [q, k, v]
     if sink is not None:
         in_specs.append(pl.BlockSpec((None, 1, _LANES),
-                                     lambda hi, qi, kj: (hi, 0, 0)))
+                                     lambda hi, qi, kj, at_ref: (hi, 0, 0)))
         operands.append(jnp.broadcast_to(
             sink.astype(jnp.float32)[:, None, None], (h, 1, _LANES)))
     return pl.pallas_call(
         functools.partial(_prefill_fwd_kernel, scale=scale,
                           window=window, n_visit=n_visit,
                           with_sink=sink is not None),
-        grid=(h, n_tiles, n_visit),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, block, dv), q_map),
-        out_shape=jax.ShapeDtypeStruct((h, s, dv), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block, _LANES), jnp.float32),
-                        pltpu.VMEM((block, _LANES), jnp.float32),
-                        pltpu.VMEM((block, dv), jnp.float32)],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(h, sq // block, n_visit),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((None, block, dv), q_map),
+            scratch_shapes=[pltpu.VMEM((block, _LANES), jnp.float32),
+                            pltpu.VMEM((block, _LANES), jnp.float32),
+                            pltpu.VMEM((block, dv), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((h, sq, dv), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         name=("flash_prefill_fwd_causal" if window is None
               else f"flash_prefill_fwd_window_{window}"),
         interpret=interpret,
-    )(*operands)
+    )(at, *operands)

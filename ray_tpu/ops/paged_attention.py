@@ -134,6 +134,12 @@ def _keys_of(kv):
         [kv[..., s, :, :] for s in range(slots) if s != 1], axis=-1)
 
 
+def kv_of_rows(kv, head_dim: int):
+    """`kv_row` back: the keys ``[..., Hkv, head_dim]`` and the values
+    ``[..., Hkv, dv]`` of pool rows ``[..., S, Hkv, dv]``."""
+    return _keys_of(kv)[..., :head_dim], kv[..., 1, :, :]
+
+
 def _as_wide_as_the_pools_keys(x, pool):
     """q or the step's own keys ``[B, H, dk]``, filled up with zeros to
     the width the pool holds a key in (`kv_row`)."""

@@ -790,6 +790,17 @@ class KVCacheManager:
         with self._lock:
             return fn(self._buffer)
 
+    def with_pools(self, fn):
+        """`with_pool` for a model that reads every layer group (a chunk
+        of a prompt reads the positions before it): ``fn(pool)``, or
+        with layer groups ``fn({group: pool})``, under the cache lock,
+        which every write into a group's pool is made under too."""
+        with self._lock:
+            if not self._groups:
+                return fn(self._buffer)
+            return fn({name: g._buffer
+                       for name, g in self._members().items()})
+
     def mutate_pool(self, fn):
         """Run ``fn(pool) -> (result, new_pool)`` under the cache lock
         and re-bind the buffer. For callers that hand the pool to a

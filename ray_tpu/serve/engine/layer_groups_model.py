@@ -18,11 +18,11 @@ A row of a group is ``[layers of the group, S, Hkv, dv]``
 keys and values are of one width. The two groups' rows may differ in
 their key/value heads.
 
-What is here: the prefill's and the decode step's programs, the packed
-step buffer, the window table, the page and byte counters by group. What
-a model keeps: its layers in order (`_layers`), a layer kind's widths
-(`_attention_widths`) and rotary tables (`_rope`), `_qkv`, and the
-mixer's way out (`_mixer_out`).
+What is here: the prefill's, a prefill chunk's and the decode step's
+programs, the packed step buffer, the window table, the page and byte
+counters by group. What a model keeps: its layers in order (`_layers`),
+a layer kind's widths (`_attention_widths`) and rotary tables (`_rope`),
+`_qkv`, and the mixer's way out (`_mixer_out`).
 
 A decode step is one compiled program: in, one int32 array ``[b_pad, 6 +
 nb_pad + window_blocks]`` (token, position, write offset, the global and
@@ -65,8 +65,9 @@ class LayerGroupsEngineModel(SparseEngineModel):
     `ops.attention.prefill_attention` (the windowed, grouped flash
     forward on the chip); a decode step is jitted a (batch, global table)
     bucket, the window table's width fixed by the window. A prompt is
-    never prefilled from an offset: the engine adopts no prefix beside a
-    window group.
+    never prefilled from an adopted prefix: the engine adopts none
+    beside a window group. A prompt longer than `prefill_chunk_tokens`
+    the scheduler runs through `prefill_chunk`, a chunk at a time.
 
     A subclass defines `_attention_widths(full)` (query heads, a key's
     values, key/value heads, a value's values), `_group_layers(full)`,
@@ -120,6 +121,33 @@ class LayerGroupsEngineModel(SparseEngineModel):
         """Blocks of the window group a sequence holds at the most."""
         return math.ceil(self._cfg.window / block_size) + 1
 
+    # Positions of a prompt a call of `prefill_chunk` runs; a prompt of
+    # at most as many is prefilled whole. A chunk is what a running row
+    # waits behind, and every chunk reads the weights once more. Swept
+    # on the chip at both configurations' published widths (PR 56; wall
+    # time of a call, the first chunk to the last, and a whole prompt's
+    # chunks together; one seed, the second run of each):
+    #   MiMo, 7,680 tokens in the 8,192 bucket (whole: 237.6 ms)
+    #     512: 16.3-19.2 ms a chunk, 292.9 ms   1,024: 26.6-31.2, 245.6
+    #     2,048: 49.5-57.1, 222.5
+    #   Laguna, 4,096 tokens (whole: 115.5 ms)
+    #     512: 20.3-22.0 ms a chunk, 185.4 ms   1,024: 30.1-32.6, 134.1
+    #     2,048: 56.2-59.0, 119.8
+    # 512 costs a prompt a fifth to a third more than 1,024 for 10 ms
+    # less of a stall; 2,048 saves it a tenth and doubles the stall.
+    # One value for both: nothing the code can see separates them.
+    prefill_chunk_tokens = 1024
+
+    def _chunk_tail_tokens(self) -> int:
+        """Positions before a chunk that a window layer's keys begin
+        with: the window, in whole tiles of the window layers' forward
+        (`ops.flash_attention.prefill_block`), so that the chunk's first
+        query lies on a tile's edge."""
+        from ray_tpu.ops.flash_attention import prefill_block
+
+        tile = prefill_block(self.prefill_chunk_tokens, self._cfg.window)
+        return -(-self._cfg.window // tile) * tile
+
     # -- shared math ---------------------------------------------------
     def _feed_forward(self, x, ln2, mp, valid):
         """A layer's second half: a dense MLP (no counts) or the expert
@@ -135,49 +163,116 @@ class LayerGroupsEngineModel(SparseEngineModel):
         return x + out, jnp.zeros((3,), jnp.int32)
 
     # -- prefill -------------------------------------------------------
+    def _prompt_layers(self, params, tokens, pos, length, attend):
+        """The layers over positions `pos` of one prompt, `tokens` there
+        (a whole prompt in its bucket, or a chunk), of which the first
+        `length` are live. ``attend(q, k, v, full, index, lp)`` is a
+        layer's attention: q ``[S, H, dk]``, its own k and v ``[S, Hkv,
+        ..]`` in the pools' dtype, whether it is global, its index in
+        its group; ``[H, S, dv]`` out. Returns the logits after the last
+        live position and both groups' rows, ``[S, layers of the group,
+        slots, Hkv, dv]``."""
+        import jax
+        import jax.numpy as jnp
+
+        from ray_tpu.ops.paged_attention import kv_row
+
+        act = params["embed"].dtype
+        with jax.named_scope("embed"):
+            x = params["embed"][tokens].astype(jnp.float32)    # [S, d]
+        live = jnp.arange(tokens.shape[0]) < length
+        ropes = {True: self._rope(pos, True), False: self._rope(pos, False)}
+        rows = {True: [], False: []}
+        for lp, ln1, ln2, mp, full in self._layers(params):
+            with jax.named_scope("attn_global" if full else "attn_window"):
+                y = self._norm(x, ln1)
+                q, k, v = self._qkv(y, lp, full, ropes[full])
+                k, v = k.astype(act), v.astype(act)
+                o = attend(q.astype(act), k, v, full, len(rows[full]), lp)
+                x = x + self._mixer_out(y, o.transpose(1, 0, 2), lp)
+            rows[full].append(kv_row(k, v))
+            x, _ = self._feed_forward(x, ln2, mp, live)
+        with jax.named_scope("lm_head"):
+            last = self._norm(x[length - 1], params["ln_f"])
+            logits = self._mm(last[None], params["head"])[0]
+        return (logits, jnp.stack(rows[True], axis=1),
+                jnp.stack(rows[False], axis=1))
+
     def _build_prefill(self, s_pad: int):
         import jax
         import jax.numpy as jnp
 
         from ray_tpu.ops.attention import prefill_attention
-        from ray_tpu.ops.paged_attention import kv_row
 
         self.jit_compiles += 1
-        cfg, f32 = self._cfg, jnp.float32
+        window = self._cfg.window
+
+        def attend(q, k, v, full, index, lp):
+            # A padded position lies after every live one: the causal
+            # mask alone keeps it from a live query.
+            return prefill_attention(
+                q.transpose(1, 0, 2), k.transpose(1, 0, 2),
+                v.transpose(1, 0, 2), None if full else window,
+                lp.get("sink"))                                # [H, S, dv]
 
         def prefill(params, tokens, length):
-            act = params["embed"].dtype
-            with jax.named_scope("embed"):
-                x = params["embed"][tokens].astype(f32)        # [S, d]
-            pos = jnp.arange(s_pad)
-            live = pos < length
-            ropes = {True: self._rope(pos, True),
-                     False: self._rope(pos, False)}
-            rows = {True: [], False: []}
-            for lp, ln1, ln2, mp, full in self._layers(params):
-                with jax.named_scope("attn_global" if full
-                                     else "attn_window"):
-                    y = self._norm(x, ln1)
-                    q, k, v = self._qkv(y, lp, full, ropes[full])
-                    k, v = k.astype(act), v.astype(act)
-                    # A padded position lies after every live one: the
-                    # causal mask alone keeps it from a live query.
-                    o = prefill_attention(
-                        q.astype(act).transpose(1, 0, 2),
-                        k.transpose(1, 0, 2), v.transpose(1, 0, 2),
-                        None if full else cfg.window,
-                        lp.get("sink"))                        # [H, S, dv]
-                    x = x + self._mixer_out(y, o.transpose(1, 0, 2), lp)
-                rows[full].append(kv_row(k, v))
-                x, _ = self._feed_forward(x, ln2, mp, live)
-            with jax.named_scope("lm_head"):
-                last = self._norm(x[length - 1], params["ln_f"])
-                logits = self._mm(last[None], params["head"])[0]
-            # [S, layers of the group, S, Hkv, dv]
-            return (logits, jnp.stack(rows[True], axis=1),
-                    jnp.stack(rows[False], axis=1))
+            return self._prompt_layers(params, tokens, jnp.arange(s_pad),
+                                       length, attend)
 
         return jax.jit(prefill)
+
+    def _build_prefill_chunk(self, s_keys: int, block_size: int):
+        """The program of one chunk of a prompt whose global keys lie in
+        `s_keys` positions: the chunk's place comes in as a scalar."""
+        import jax
+        import jax.numpy as jnp
+
+        from ray_tpu.ops.attention import prefill_attention
+        from ray_tpu.ops.paged_attention import kv_of_rows
+
+        self.jit_compiles += 1
+        window = self._cfg.window
+        c, tail = self.prefill_chunk_tokens, self._chunk_tail_tokens()
+        nb = s_keys // block_size
+
+        def prefill_chunk(pools, params, packed):
+            tokens, start, length = packed[:c], packed[c], packed[c + 1]
+            tables = {GLOBAL: packed[c + 2:c + 2 + nb],
+                      WINDOW: packed[c + 2 + nb:]}
+            # What the pools hold of the positions before the chunk, a
+            # position a row: the global group's at their positions
+            # (whatever a row from `start` on reads, no query sees it),
+            # the window group's last `tail` (a row of a position the
+            # group gave back lies outside every window; one before the
+            # prompt's first is not live).
+            with jax.named_scope("kv_gather"):
+                before = {group: pool[tables[group]].reshape(
+                    (-1,) + pool.shape[2:]) for group, pool in pools.items()}
+            zero = jnp.int32(0)
+
+            def attend(q, k, v, full, index, lp):
+                old_k, old_v = kv_of_rows(
+                    before[GLOBAL if full else WINDOW][:, index], k.shape[-1])
+                if full:
+                    # The keys at their positions, the chunk's own among
+                    # them; every key up to the chunk's last is live.
+                    at = (start, zero, zero)
+                    keys = jax.lax.dynamic_update_slice(old_k, k, at)
+                    vals = jax.lax.dynamic_update_slice(old_v, v, at)
+                    offset, live = start, start + c
+                else:
+                    keys = jnp.concatenate([old_k, k])
+                    vals = jnp.concatenate([old_v, v])
+                    offset, live = tail, jnp.minimum(start, tail) + c
+                return prefill_attention(
+                    q.transpose(1, 0, 2), keys.transpose(1, 0, 2),
+                    vals.transpose(1, 0, 2), None if full else window,
+                    lp.get("sink"), offset=offset, live=live)
+
+            return self._prompt_layers(params, tokens,
+                                       start + jnp.arange(c), length, attend)
+
+        return jax.jit(prefill_chunk)
 
     # -- decode --------------------------------------------------------
     def _build_decode_paged(self, b_pad: int, nb_pad: int,
@@ -247,6 +342,82 @@ class LayerGroupsEngineModel(SparseEngineModel):
             logits, (kv_global, kv_window), n = self._run_prefill(tokens)
             return logits, PromptGroups(
                 kv_global, n, {WINDOW: PromptKV(kv_window, n)})
+
+    def prefill_chunk(self, tokens: Sequence[int], pools, tables: dict,
+                      start: int, block_size: int, *, meanwhile=None):
+        """Run positions ``[start, start + prefill_chunk_tokens)`` of the
+        prompt `tokens` (those of them it has), whose positions before
+        `start` are in `pools`, read through `tables` (the sequence's
+        `step_tables`, as they stand before this chunk's blocks are
+        allocated and the window group's older ones given back). `start`
+        is a multiple of the chunk. Returns the host logits that predict
+        the next token for the chunk that holds the prompt's last token,
+        else None, and a `PromptGroups` of the chunk's rows, on the
+        device, for `write_range(seq, start, ...)`.
+
+        A program of its own between two decode steps, and the call
+        returns when the device has finished it. `meanwhile` (the
+        protocol's: `model.py`) runs between the dispatch and the wait.
+        One program a power of two of the prompt's length, in which the
+        global keys lie, and one for every prompt of up to four chunks:
+        the chunk's place is a scalar to it, a key tile past the chunk's
+        end costs a global layer's forward a third of a microsecond,
+        and a program more costs a replica 5-6 s of set-up (PR 56)."""
+        with flight.span("model", "prefill", len(tokens)):
+            return self._prefill_chunk(tokens, pools, tables, start,
+                                       block_size, meanwhile)
+
+    def _prefill_chunk(self, tokens, pools, tables, start: int,
+                       block_size: int, meanwhile):
+        phase, c = self.phase, self.prefill_chunk_tokens
+        n = len(tokens)
+        length = min(c, n - start)
+        self.prefill_calls += 1
+        self.prefill_tokens += length
+        with flight.span("model", "prefill.prep", None, phase,
+                         "prefill_prep_s"):
+            tail = self._chunk_tail_tokens()
+            if start % c or c % block_size or tail % block_size:
+                raise ValueError(
+                    f"a chunk of {c} positions at {start} over a tail of "
+                    f"{tail} does not lie on blocks of {block_size}")
+            s_keys = max(_next_pow2(-(-n // c) * c), 4 * c)
+            key = ("chunk", c, s_keys, block_size)
+            fn = self._prefill_jit.get(key)
+            if fn is None:
+                fn = self._prefill_jit[key] = \
+                    self._build_prefill_chunk(*key[2:])
+            nb, tb = s_keys // block_size, tail // block_size
+            packed = np.zeros((c + 2 + nb + tb,), np.int32)
+            packed[:length] = np.asarray(tokens[start:start + length],
+                                         np.int32)
+            packed[c], packed[c + 1] = start, length
+            _, table = tables[GLOBAL]
+            packed[c + 2:c + 2 + min(nb, len(table))] = table[:nb]
+            # The window group's blocks of the `tail` positions before
+            # `start`, by their place in its compact table; block 0 for
+            # one it no longer holds (or never did).
+            base, near = tables[WINDOW]
+            first = start // block_size - tb - base
+            lo, hi = max(0, -first), min(tb, len(near) - first)
+            if lo < hi:
+                at = c + 2 + nb
+                packed[at + lo:at + hi] = near[first + lo:first + hi]
+        with flight.span("model", "prefill.dispatch", None, phase,
+                         "prefill_dispatch_s"):
+            logits, kv_global, kv_window = fn(pools, self._params, packed)
+        self._count_experts_step(c)
+        if meanwhile is not None:
+            meanwhile()
+        with flight.span("model", "prefill.logits_wait", None, phase,
+                         "prefill_wait_s"):
+            if start + length == n:
+                logits = np.asarray(logits)
+            else:
+                kv_window.block_until_ready()
+                logits = None
+        return logits, PromptGroups(
+            kv_global, length, {WINDOW: PromptKV(kv_window, length)})
 
     def decode_paged(self, pools, block_tables: List[dict],
                      last_tokens: Sequence[int],

@@ -67,6 +67,21 @@ window group's table compact from logical block ``base``, and returns
 ``(step, new_pools)``; and the engine builds no prefix index (an adopted
 prefix would need the window layers' rows at its end).
 
+A model may offer a fourth call, with the attribute
+``prefill_chunk_tokens`` (``C``): ``prefill_chunk(tokens, pools, tables,
+start, block_size, *, meanwhile=None) -> (logits [V] or None, kv)``
+runs positions ``[start, start + C)`` of the prompt ``tokens`` (what it
+has of them; ``start`` a multiple of ``C``), whose positions before
+``start`` it reads out of ``pools`` through ``tables`` (the sequence's
+`KVCacheManager.step_tables`, in `with_pools`: a pool and a table, or
+with ``kv_groups`` a dict a group of each), and returns the chunk's KV
+for ``write_range(seq, start, kv)`` and, from the chunk that holds the
+prompt's last token alone, the logits ``prefill`` would return. The
+scheduler then prefills a prompt longer than ``C`` a chunk an
+iteration, a decode step of the running batch between two chunks
+(`scheduler.py`); ``meanwhile`` as in ``decode_paged``. The call returns
+when the chunk's work is done, not behind the host's back.
+
 Four implementations:
 
 - **TinyLM** — a deterministic pure-numpy model whose next token is a

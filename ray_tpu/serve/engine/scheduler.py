@@ -24,7 +24,8 @@ against, paying identical per-step bookkeeping.
 One loop drives every model, through three calls (`model.py` states
 the protocol): `prefill` -> `cache.write_range` for a prompt,
 `decode_paged` inside `cache.paged_step` for a step, `prefill_paged`
-for a prompt whose head is already cached. The model reads the KV pool
+for a prompt whose head is already cached (and `prefill_chunk`, where
+a model offers it: below). The model reads the KV pool
 itself, through block tables; the engine never gathers a sequence's KV.
 Where the pool lives is the model's to say (`kv_pool_ns`: numpy for
 `TinyLM`, `jax.numpy` for `TransformerEngineModel`).
@@ -79,6 +80,21 @@ flushed at once where no decode step follows (nothing left running),
 before a prefill's call, and before anything else ends a stream (a
 cancellation, a failed step, `stop`), so a stream sees its tokens in
 order and never its end before a token generated for it.
+
+A long prompt is prefilled in chunks, a decode step between two of
+them, where the model offers the call (`prefill_chunk`, with its
+`prefill_chunk_tokens`: `layer_groups_model.py`): a prompt longer than a
+chunk is admitted as any other (FIFO, the cache's estimate for the
+whole prompt) and is then *in flight*, neither waiting nor running: an
+iteration runs its next chunk (the model reads the positions before it
+out of the pools, the cache then grows by the chunk and takes its
+rows), then the decode step of the running batch, so no running row
+waits behind more than one chunk. One prompt is in flight at a time;
+nothing else is admitted until its last chunk has given its first token
+and it has joined the batch. With nothing running the chunks follow
+each other at once. A chunk's call takes `meanwhile` as a decode step's
+does. A shorter prompt, one with a prefix hit, and every prompt of a
+model without the call are prefilled whole.
 """
 
 from __future__ import annotations
@@ -294,6 +310,9 @@ class _Sequence:
     queued_at: float = field(default_factory=time.perf_counter)
     # Trace id of the `serve.replica` span that submitted it, if any.
     trace_id: Optional[str] = None
+    # Positions whose KV the chunks run so far have put in the cache,
+    # while the sequence is in flight.
+    prefilled: int = 0
 
     @property
     def generated(self) -> int:
@@ -355,6 +374,14 @@ class InferenceEngine:
                                      self.prefix_index.evictable_blocks)
         self._waiting: deque = deque()
         self._running: List[_Sequence] = []
+        # The prompt being prefilled in chunks, if any: neither waiting
+        # nor running. Only the loop's thread sets it.
+        self._in_flight: Optional[_Sequence] = None
+        # A prompt longer than this many tokens is prefilled in chunks
+        # of as many, where the model offers the call.
+        self._chunk = (int(model.prefill_chunk_tokens)
+                       if callable(getattr(model, "prefill_chunk", None))
+                       else None)
         self._lock = threading.Lock()
         self._work = threading.Event()
         self._stop = threading.Event()
@@ -372,6 +399,8 @@ class InferenceEngine:
         self.prefix_import_tokens = 0
         self.finished = 0
         self.paged_steps = 0
+        self.prefill_chunks = 0
+        self.prefill_chunk_tokens = 0
         # Every clock of the loop, in seconds, each fed by one
         # `flight.span` (so all of them stand still while the flight
         # recorder is off): the phases, the older clocks (`prefill_s`;
@@ -650,6 +679,10 @@ class InferenceEngine:
                                  if s.stream.cancelled]
             for s in waiting_cancelled:
                 self._waiting.remove(s)
+            flying = self._in_flight
+            if flying is not None and flying.stream.cancelled:
+                self._in_flight = None
+                cancelled.append(flying)
         if cancelled or waiting_cancelled:
             self._deliver()   # a pending token precedes its stream's end
         for s in cancelled + waiting_cancelled:
@@ -662,7 +695,10 @@ class InferenceEngine:
         against. The in-flight check happens ONCE per pass (not per
         admitted sequence: the first prefill populates `_running`, and
         re-checking would cap static batches at size one — serial
-        decoding, not static batching)."""
+        decoding, not static batching). A prompt in flight comes first:
+        its next chunk, and beside a running batch nothing more in this
+        iteration (the static policy forms its batch in one pass, so
+        there the chunks follow each other)."""
         with self._lock:
             if self.config.policy == "static" and self._running:
                 # A batch is in flight: hold admissions until it
@@ -670,19 +706,22 @@ class InferenceEngine:
                 # a full batch.
                 return
         while True:
-            with self._lock:
-                if not self._waiting:
-                    return
-                if len(self._running) >= self.config.max_batch_size:
-                    return
-                seq = self._waiting[0]
-                # Admission needs the prompt cached (len-1 after the
-                # invariant) plus the first decode write — i.e. blocks
-                # covering len(prompt) positions, +1 for growth.
-                need = len(seq.all_tokens)
-                if not self.cache.can_allocate(seq.seq_id, need):
-                    return
-                self._waiting.popleft()
+            seq, chunks = self._in_flight, self.prefill_chunks
+            if seq is None:
+                with self._lock:
+                    if not self._waiting:
+                        return
+                    if len(self._running) >= self.config.max_batch_size:
+                        return
+                    seq = self._waiting[0]
+                    # Admission needs the prompt cached (len-1 after the
+                    # invariant) plus the first decode write — i.e.
+                    # blocks covering len(prompt) positions, +1 for
+                    # growth.
+                    need = len(seq.all_tokens)
+                    if not self.cache.can_allocate(seq.seq_id, need):
+                        return
+                    self._waiting.popleft()
             try:
                 if not self._prefill(seq):
                     return   # allocation lost after the estimate: the
@@ -690,8 +729,20 @@ class InferenceEngine:
                              # progress before re-trying admission
             except Exception as e:  # noqa: BLE001
                 logger.exception("prefill of %s failed", seq.seq_id)
+                if self._in_flight is seq:
+                    self._in_flight = None
+                    # Where a chunk failed before its `meanwhile`.
+                    self._deliver()
                 self.cache.free(seq.seq_id)
                 seq.stream._finish(e)
+            if (self.prefill_chunks > chunks
+                    and self.config.policy != "static"):
+                # One chunk an iteration beside a running batch: its
+                # decode step comes before the next chunk, and before
+                # anything else is admitted.
+                with self._lock:
+                    if self._running:
+                        return
 
     def _prefill(self, seq: _Sequence) -> bool:
         # Engine steps in the flight ring: a decode-latency spike lines
@@ -703,14 +754,21 @@ class InferenceEngine:
             return self._prefill_inner(seq, sp)
 
     def _prefill_inner(self, seq: _Sequence, sp: flight.span) -> bool:
+        if seq is self._in_flight:
+            return self._prefill_chunk(seq, sp)
         clocks = self._clocks
-        # A prefill is milliseconds to tenths of a second of device
-        # time: no token waits behind it. (Nor may this sequence's own
-        # first token overtake what a preempted run of it left pending.)
-        self._deliver()
-        admission = time.perf_counter()
         tokens = list(seq.all_tokens)
         n = len(tokens)
+        # In chunks: a prompt longer than one, unless a prefix of it is
+        # cached already (the whole path reads that out of the pool).
+        chunked = self._chunk is not None and n > self._chunk
+        # A whole prefill is milliseconds to tenths of a second of
+        # device time: no token waits behind it; a chunk delivers from
+        # its `meanwhile`. (Nor may this sequence's own first token
+        # overtake what a preempted run of it left pending.)
+        if not chunked:
+            self._deliver()
+        admission = time.perf_counter()
         hit = 0
         with flight.span("engine", "prefill.match", None, clocks,
                          "prefill_match_s"):
@@ -718,14 +776,17 @@ class InferenceEngine:
                 blocks, hit = self.prefix_index.match(tokens)
                 if hit:
                     self.cache.adopt(seq.seq_id, blocks, hit)
+                    if chunked:
+                        chunked = False
+                        self._deliver()
             # Privatize from the first position this prefill writes: a
             # partially-adopted shared block COWs here, planned into the
-            # same atomic free-block arithmetic as table growth.
-            ok = self.cache.allocate(seq.seq_id, n, writable_from=hit)
+            # same atomic free-block arithmetic as table growth. A
+            # chunk allocates its own positions when it has run.
+            ok = chunked or self.cache.allocate(seq.seq_id, n,
+                                                writable_from=hit)
         if not ok:   # lost capacity since the admission check: requeue
-            self.cache.free(seq.seq_id)
-            with self._lock:
-                self._waiting.appendleft(seq)
+            self._requeue_at_head(seq)
             return False
         sp.arg = f"tokens={n} prefix_hit={hit}"
         if flight.enabled:
@@ -740,6 +801,10 @@ class InferenceEngine:
                 arg=(seq.seq_id if seq.trace_id is None
                      else f"{seq.seq_id} trace={seq.trace_id}"),
                 t=time.monotonic() - queued_ago)
+        if chunked:
+            self._in_flight = seq
+            seq.prefilled = 0
+            return self._prefill_chunk(seq, sp)
         if hit == n:
             # Full prefix hit: every prompt position is already cached.
             # The first generated token is ONE decode step over the
@@ -775,6 +840,55 @@ class InferenceEngine:
             with flight.span("engine", "prefill.kv_write", None, clocks,
                              "prefill_kv_write_s"):
                 self.cache.write_range(seq.seq_id, 0, kv)
+        self._first_token(seq, tokens, logits, hit)
+        return True
+
+    def _requeue_at_head(self, seq: _Sequence) -> None:
+        """A prefill lost the blocks the admission check saw: what it
+        holds goes back and it is the next to be admitted."""
+        self.cache.free(seq.seq_id)
+        with self._lock:
+            self._waiting.appendleft(seq)
+
+    def _prefill_chunk(self, seq: _Sequence, sp: flight.span) -> bool:
+        """The next chunk of the prompt in flight: the model runs it
+        over the positions before it as the pools hold them, then the
+        cache grows by the chunk (a window group gives back what the
+        chunk's end no longer sees: hence after the model's read) and
+        takes its rows. After the last chunk the sequence joins the
+        batch with its first token. False: the chunk lost its blocks,
+        and the sequence is requeued at the head to begin again."""
+        clocks = self._clocks
+        tokens = seq.all_tokens      # nothing joins it while in flight
+        n, start = len(tokens), seq.prefilled
+        end = min(n, start + self._chunk)
+        sp.arg = f"tokens={end - start} prefix_hit=0 chunk_at={start}"
+        tables = self.cache.step_tables(seq.seq_id)
+        logits, kv = self.cache.with_pools(
+            lambda pools: self.model.prefill_chunk(
+                tokens, pools, tables, start, self.config.block_size,
+                meanwhile=self._in_shadow))
+        if not self.cache.allocate(seq.seq_id, end, writable_from=start):
+            self._in_flight = None
+            self._requeue_at_head(seq)
+            return False
+        with flight.span("engine", "prefill.kv_write", None, clocks,
+                         "prefill_kv_write_s"):
+            self.cache.write_range(seq.seq_id, start, kv)
+        seq.prefilled = end
+        self.prefill_chunks += 1
+        self.prefill_chunk_tokens += end - start
+        if end == n:
+            self._in_flight = None
+            self._first_token(seq, tokens, logits, 0)
+        return True
+
+    def _first_token(self, seq: _Sequence, tokens: List[int], logits,
+                     hit: int) -> None:
+        """The end of a prefill, whole or in chunks: the prompt's blocks
+        sealed, its first token sampled and emitted, the sequence in the
+        batch (or ended by that token)."""
+        clocks = self._clocks
         if self.prefix_index is not None:
             # Seal: every full prompt block becomes adoptable.
             with flight.span("engine", "prefill.seal", None, clocks,
@@ -792,7 +906,6 @@ class InferenceEngine:
             else:
                 with self._lock:
                     self._running.append(seq)
-        return True
 
     def _ensure_capacity(self) -> None:
         """Every running sequence needs a cache slot for the token the
@@ -982,6 +1095,9 @@ class InferenceEngine:
         self._deliver()
         with self._lock:
             leftovers = list(self._running) + list(self._waiting)
+            if self._in_flight is not None:
+                leftovers.append(self._in_flight)
+                self._in_flight = None
             self._running.clear()
             self._waiting.clear()
         for seq in leftovers:
@@ -993,7 +1109,8 @@ class InferenceEngine:
         deadline = time.monotonic() + timeout_s
         while time.monotonic() < deadline:
             with self._lock:
-                if not (self._running or self._waiting or self._pending):
+                if not (self._running or self._waiting or self._pending
+                        or self._in_flight):
                     return True
             time.sleep(0.005)
         return False
@@ -1060,6 +1177,12 @@ class InferenceEngine:
         their streams from inside the next step's `meanwhile`, beside a
         busy device; `tokens_generated` less `prefills` (a prefill's
         token goes out at once) less it, those flushed early.
+        `prefill_chunks` counts the chunks of prompts prefilled in
+        chunks (the model's `prefill_chunk` calls whose rows reached the
+        cache), `prefill_chunk_tokens` the prompt tokens they ran: over
+        the model's `prefill_tokens`, the share of prefilled tokens that
+        went in chunks. `prefills` and `queue_wait_s` count such a
+        prompt once, `prefill_s` holds every chunk.
         `prefill_kv_device_writes` and `prefill_kv_host_writes` count
         the prompt-KV writes into the pool (`write_range`) that stayed
         on the device, and those that passed through host memory.
@@ -1142,6 +1265,8 @@ class InferenceEngine:
                              if self.prefix_index is not None else None),
             "paged": True,
             "paged_steps": self.paged_steps,
+            "prefill_chunks": self.prefill_chunks,
+            "prefill_chunk_tokens": self.prefill_chunk_tokens,
             "prefill_kv_device_writes": self.cache.range_writes_device,
             "prefill_kv_host_writes": self.cache.range_writes_host,
             "decode_h2d_arrays": getattr(

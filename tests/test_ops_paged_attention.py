@@ -1096,3 +1096,87 @@ def test_mimo_decode_step_compiles_for_v5e_with_both_pools_in_place(
             + int(np.prod(MIMO_WINDOW_POOL))) * 2
     assert both <= memory.alias_size_in_bytes < 1.01 * both
     assert memory.temp_size_in_bytes < 100e6
+
+
+# ---------------------------------------------------------------------------
+# a chunk of a long prompt (PR 56): the prefill's forward with the first
+# query's key and the live keys' count as scalars, and the whole chunk
+# program at `mimo-v2.5`'s published widths
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("heads, hkv, dk, keys, window, name", [
+    (64, 4, 192, 8192, None, "flash_prefill_fwd_causal"),
+    (64, 8, 192, 128 + 1024, 128, "flash_prefill_fwd_window_128"),
+    (48, 8, 128, 4096, None, "flash_prefill_fwd_causal"),
+    (72, 8, 128, 512 + 1024, 512, "flash_prefill_fwd_window_512")],
+    ids=["mimo_global", "mimo_window_sink", "laguna_global",
+         "laguna_window"])
+def test_a_chunks_forward_compiles_for_v5e_with_its_place_a_scalar(
+        one_chip, no_compile_cache, heads, hkv, dk, keys, window, name):
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.flash_attention import prefill_attention_fwd
+
+    def spec(h, s, d):
+        return jax.ShapeDtypeStruct((h, s, d), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    sink = jax.ShapeDtypeStruct((heads,), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(lambda q, k, v, b, offset, live: prefill_attention_fwd(
+        q, k, v, window, b if dk == 192 and window else None, offset=offset,
+        live=live)).lower(
+        spec(heads, 1024, dk), spec(hkv, keys, dk), spec(hkv, keys, 128),
+        sink, scalar, scalar).compile()
+    assert name in compiled.as_text()
+    # Beside the float32 output: q and k filled up to whole lanes.
+    assert compiled.memory_analysis().temp_size_in_bytes < 100e6
+
+
+def test_mimo_chunk_program_compiles_for_v5e_beside_both_pools(
+        one_chip, no_compile_cache, monkeypatch):
+    """A chunk of 1,024 positions of a prompt in the 8,192 bucket at the
+    published widths as the chip dispatches it: the pools go in and are
+    not copied (what the program holds beside its arguments is the
+    positions before the chunk, 50 MB of rows of two layers, and a
+    chunk's activations), seven calls of the prefill's forward and six
+    of the prompt's experts' kernel."""
+    import json
+    import os
+
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks.harness import manifest
+    from ray_tpu.models.mimo_v2 import init_params
+    from ray_tpu.serve.engine import MimoEngineModel
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    family = manifest.load_family("mimo_v2")
+    with open(os.path.join(manifest.ROOT, "benchmarks", "configs",
+                           "mimo-v2.5.json")) as f:
+        cfg = family.model_config(family.widths(json.load(f)))
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(on_chip, jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    model = MimoEngineModel(params, cfg, max_batch_size=16)
+    assert (model.prefill_chunk_tokens, model._chunk_tail_tokens()) == \
+        (1024, 128)
+    pools = {"global": jax.ShapeDtypeStruct(MIMO_GLOBAL_POOL, jnp.bfloat16,
+                                            sharding=one_chip),
+             "window": jax.ShapeDtypeStruct(MIMO_WINDOW_POOL, jnp.bfloat16,
+                                            sharding=one_chip)}
+    compiled = model._build_prefill_chunk(8192, 16).lower(
+        pools, params, jax.ShapeDtypeStruct((1024 + 2 + 512 + 8,), jnp.int32,
+                                            sharding=one_chip)).compile()
+    memory, text = compiled.memory_analysis(), compiled.as_text()
+    assert "jit_prefill_chunk" in text
+    assert text.count("flash_prefill_fwd_causal") >= 2
+    assert text.count("flash_prefill_fwd_window_128") >= 5
+    assert text.count("held_experts_ffn_prefill") >= 6
+    assert memory.temp_size_in_bytes < 200e6
+    # The chunk's rows of both groups and the logits.
+    assert memory.output_size_in_bytes < 40e6

@@ -1237,3 +1237,320 @@ def test_a_model_that_returns_logits_is_sampled_on_the_host():
     s = eng.stats()
     assert s["paged_steps"] == 8
     assert (s["decode_h2d_arrays"], s["decode_d2h_bytes"]) == (0, 0)
+
+
+# ---------------------------------------------------------------------------
+# chunked prefill: a long prompt a chunk an iteration, a decode step between
+# ---------------------------------------------------------------------------
+class _Rows:
+    """A prefill's payload a layer group, on the host."""
+
+    def __init__(self, rows, groups=None):
+        self.rows, self.groups = rows, groups or {}
+
+    def __len__(self):
+        return len(self.rows)
+
+    def __array__(self, dtype=None, copy=None):
+        return self.rows
+
+
+class _ChunkLM(TinyLM):
+    """`TinyLM` over a global and a window layer group (the token's value
+    in both) that offers `prefill_chunk` and records its calls. A chunk
+    computes from what the global pool holds of the positions before it,
+    and finds the window group's rows of the window before it through
+    the compact table, so a chunk the scheduler stored wrongly, or a
+    block given back too early, changes a token or fails the call."""
+
+    kv_groups = {"window": {"kv_shape": (1,), "window": 6}}
+    prefill_chunk_tokens = 4
+
+    def __init__(self):
+        super().__init__()
+        self.calls, self.seen, self.fail_at = [], [], None
+        self.streams = []
+
+    def _both(self, kv):
+        return _Rows(kv, {"window": _Rows(kv.copy())})
+
+    def prefill(self, tokens, prefix_kv=None):
+        self.calls.append(("prefill", len(tokens)))
+        logits, kv = super().prefill(tokens)
+        return logits, self._both(kv)
+
+    def prefill_chunk(self, tokens, pools, tables, start, block_size, *,
+                      meanwhile=None):
+        self.calls.append(("chunk", start))
+        if self.fail_at == start:
+            raise RuntimeError("kaboom")
+        before = [len(s.tokens_so_far()) for s in self.streams]
+        if meanwhile is not None:
+            meanwhile()
+        self.seen.append(
+            (before, [len(s.tokens_so_far()) for s in self.streams]))
+        end = min(len(tokens), start + self.prefill_chunk_tokens)
+        _, table = tables["global"]
+        cached = self._pool_gather(pools["global"], table, start, block_size)
+        base, near = tables["window"]
+        window = self.kv_groups["window"]["window"]
+        for pos in range(max(0, start - window + 1), start):
+            held = pools["window"][near[pos // block_size - base],
+                                   pos % block_size, 0]
+            assert held == tokens[pos], (pos, held)
+        self.prefill_calls += 1
+        self.prefill_tokens += end - start
+        logits = None
+        if end == len(tokens):
+            nxt = self._next(float(cached.sum()) + sum(tokens[start:-1]),
+                             tokens[-1], len(tokens) - 1)
+            logits = np.full((self.vocab_size,), -1e30, np.float32)
+            logits[nxt] = 0.0
+        return logits, self._both(
+            np.asarray(tokens[start:end], np.float32)[:, None])
+
+    def decode_paged(self, pools, block_tables, last_tokens, positions,
+                     write_blocks, write_offs, block_size, *,
+                     meanwhile=None):
+        self.calls.append(("decode", len(last_tokens)))
+        logits, pools["global"] = super().decode_paged(
+            pools["global"], [t["global"][1] for t in block_tables],
+            last_tokens, positions, write_blocks["global"],
+            write_offs["global"], block_size, meanwhile=meanwhile)
+        for tok, block, off in zip(last_tokens, write_blocks["window"],
+                                   write_offs["window"]):
+            pools["window"][block, off] = tok
+        return logits, pools
+
+
+LONG = [5, 9, 3, 7, 2, 11, 4, 6, 12, 8, 10, 3, 5, 9]    # 14: 4 + 4 + 4 + 2
+
+
+def _chunk_engine(model=None, **config):
+    model = model or _ChunkLM()
+    config = {"block_size": 4, "num_blocks": 64,
+              "group_blocks": {"window": 16}, "max_batch_size": 4, **config}
+    return model, InferenceEngine(model, EngineConfig(**config))
+
+
+def _free(eng):
+    return (eng.cache.free_blocks(), eng.cache.group("window").free_blocks())
+
+
+def test_every_running_row_gets_one_token_between_two_chunks():
+    model, eng = _chunk_engine()
+    rows = [eng.submit([5, 9, 3], 12), eng.submit([2, 2], 12)]
+    assert eng.step()                       # two whole prefills and a step
+    model.calls.clear()
+    late = eng.submit(LONG, 5)
+    running = list(eng._running)
+    lengths = []
+    for _ in range(4):
+        assert eng.step()
+        lengths.append([len(s.all_tokens) for s in running])
+    # An iteration: the prompt's next chunk, then the batch's step; the
+    # prompt joins it behind its last chunk.
+    assert model.calls == [("chunk", 0), ("decode", 2), ("chunk", 4),
+                           ("decode", 2), ("chunk", 8), ("decode", 2),
+                           ("chunk", 12), ("decode", 3)]
+    assert [[b - a for a, b in zip(x, y)]
+            for x, y in zip(lengths, lengths[1:])] == [[1, 1]] * 3
+    assert eng._in_flight is None and len(eng._running) == 3
+    _drive(eng)
+    for stream, (p, n) in zip(rows + [late], [([5, 9, 3], 12), ([2, 2], 12),
+                                              (LONG, 5)]):
+        assert stream.tokens_so_far() == model.oracle(p, n)
+        assert stream.finished
+    stats = eng.stats()
+    assert (stats["prefills"], stats["prefill_chunks"],
+            stats["prefill_chunk_tokens"]) == (3, 4, 14)
+    assert model.prefill_tokens == 5 + 14
+    assert _free(eng) == (64, 16)
+
+
+def test_with_nothing_running_the_chunks_follow_each_other_at_once():
+    model, eng = _chunk_engine()
+    stream = eng.submit(LONG, 3)
+    assert eng.step()
+    assert model.calls == [("chunk", 0), ("chunk", 4), ("chunk", 8),
+                           ("chunk", 12), ("decode", 1)]
+    _drive(eng)
+    assert stream.tokens_so_far() == model.oracle(LONG, 3)
+
+
+@pytest.mark.parametrize("policy", ["continuous", "static"])
+def test_a_chunked_runs_tokens_are_the_whole_paths(policy):
+    prompts = [(LONG, 6), ([7, 2, 11, 4], 9), (LONG[3:] + LONG, 4),
+               ([3, 3, 4, 4, 5], 7)]
+    got = {}
+    for chunked in (True, False):
+        model = _ChunkLM()
+        if not chunked:
+            model.prefill_chunk = None
+        _, eng = _chunk_engine(model, policy=policy, max_batch_size=3)
+        streams = [eng.submit(p, n) for p, n in prompts]
+        _drive(eng)
+        got[chunked] = [s.tokens_so_far() for s in streams]
+        chunks = [c for c in model.calls if c[0] == "chunk"]
+        # 14, 25 and 5 tokens in chunks of 4; 4 tokens whole.
+        assert len(chunks) == (4 + 7 + 2 if chunked else 0)
+        assert eng.stats()["prefills"] == 4
+        assert _free(eng) == (64, 16)
+    assert got[True] == got[False] == [model.oracle(p, n)
+                                       for p, n in prompts]
+
+
+def test_a_prompt_in_chunks_counts_once_and_its_clock_holds_every_chunk(
+        recorder):
+    model, eng = _chunk_engine()
+    first = eng.submit([5, 9, 3], 20)
+    eng.step()
+    late = eng.submit(LONG, 2)
+    _drive(eng)
+    assert len(list(first)) == 20 and len(list(late)) == 2
+    stats = eng.stats()
+    assert (stats["prefills"], stats["prefill_chunks"]) == (2, 4)
+    events = recorder.snapshot(categories={"engine"})
+    waits = [e for e in events if e[3] == "queue_wait"]
+    assert [e[5] for e in waits] == ["seq-0", "seq-1"]
+    assert sum(e[4] for e in waits) * 1e-6 == pytest.approx(
+        stats["queue_wait_s"], abs=1e-5)
+    prefills = [e for e in events if e[3] == "prefill"]
+    assert len(prefills) == 1 + 4
+    assert sum(e[4] for e in prefills) * 1e-6 == pytest.approx(
+        stats["prefill_s"], abs=1e-5 * 5)
+
+
+def test_short_prompts_and_models_without_the_call_never_see_a_chunk():
+    model, eng = _chunk_engine()
+    exact = eng.submit(LONG[:4], 3)         # as long as a chunk: whole
+    _drive(eng)
+    assert exact.tokens_so_far() == model.oracle(LONG[:4], 3)
+    assert model.calls[0] == ("prefill", 4)
+    assert not [c for c in model.calls if c[0] == "chunk"]
+    assert eng.stats()["prefill_chunks"] == 0
+    plain = TinyLM()
+    eng = InferenceEngine(plain, EngineConfig(block_size=4, num_blocks=64))
+    assert eng._chunk is None
+    stream = eng.submit(LONG, 3)
+    _drive(eng)
+    assert stream.tokens_so_far() == plain.oracle(LONG, 3)
+    stats = eng.stats()
+    assert (stats["prefill_chunks"], stats["prefill_chunk_tokens"]) == (0, 0)
+    assert plain.prefill_calls == 1
+
+
+def test_the_step_befores_tokens_go_out_from_a_chunks_meanwhile():
+    model, eng = _chunk_engine()
+    row = eng.submit([5, 9, 3], 12)
+    model.streams = [row]
+    eng.step()                              # its prefill and a step
+    eng.step()
+    assert eng.stats()["tokens_delivered_overlapped"] == 1
+    eng.submit(LONG, 2)
+    model.seen.clear()
+    eng.step()
+    # The chunk found the row's last step's token pending and handed it
+    # over from its `meanwhile`; its own step then had none to hand.
+    assert model.seen == [([2], [3])]
+    assert eng.stats()["tokens_delivered_overlapped"] == 2
+    eng.step()
+    assert model.seen[1] == ([3], [4])
+    assert eng.stats()["tokens_delivered_overlapped"] == 3
+    _drive(eng)
+    assert row.tokens_so_far() == model.oracle([5, 9, 3], 12)
+
+
+def _mid_prompt():
+    """An engine with one running row and a long prompt two chunks in."""
+    model, eng = _chunk_engine()
+    row = eng.submit([5, 9, 3], 30)
+    eng.step()
+    late = eng.submit(LONG, 5)
+    eng.step()
+    eng.step()
+    assert eng._in_flight is not None and eng._in_flight.prefilled == 8
+    assert _free(eng) == (64 - 2 - 2, 16 - 2 - 2)
+    return model, eng, row, late
+
+
+def test_a_cancellation_in_mid_prompt_frees_both_groups_and_ends_the_stream():
+    model, eng, row, late = _mid_prompt()
+    late.cancel()
+    model.calls.clear()
+    eng.step()
+    assert eng._in_flight is None and late.finished
+    assert late.tokens_so_far() == [] and list(late) == []
+    assert ("chunk", 8) not in model.calls
+    assert eng.stats()["finished"] == 1
+    _drive(eng)
+    assert row.tokens_so_far() == model.oracle([5, 9, 3], 30)
+    assert eng.stats()["finished"] == 2 and _free(eng) == (64, 16)
+    assert eng.cache.seq_len("seq-1") == 0
+
+
+def test_stop_in_mid_prompt_frees_both_groups_and_ends_the_stream():
+    from ray_tpu.serve.engine.scheduler import EngineStoppedError
+
+    model, eng, row, late = _mid_prompt()
+    eng.stop()
+    assert eng._in_flight is None and _free(eng) == (64, 16)
+    for stream in (row, late):
+        assert stream.finished
+        with pytest.raises(EngineStoppedError):
+            list(stream)
+    assert not eng.step()
+
+
+def test_a_failing_chunk_ends_its_stream_alone_and_frees_both_groups():
+    model, eng, row, late = _mid_prompt()
+    model.fail_at = 8
+    eng.step()
+    assert eng._in_flight is None and late.finished
+    with pytest.raises(RuntimeError, match="kaboom"):
+        list(late)
+    # The row's pending token went out before the stream was ended, and
+    # the row runs on.
+    _drive(eng)
+    assert row.tokens_so_far() == model.oracle([5, 9, 3], 30)
+    assert _free(eng) == (64, 16)
+    assert eng.stats()["prefills"] == 1
+
+
+def test_a_chunk_that_loses_its_blocks_requeues_its_prompt_at_the_head():
+    model, eng, row, late = _mid_prompt()
+    other = eng.submit([4, 4, 4], 2)        # waits behind the prompt
+    allocate, lost = eng.cache.allocate, []
+
+    def losing(seq_id, target, writable_from=None):
+        if seq_id == "seq-1" and not lost:
+            lost.append(target)
+            return False
+        return allocate(seq_id, target, writable_from=writable_from)
+
+    eng.cache.allocate = losing
+    eng.step()
+    assert lost == [12] and eng._in_flight is None
+    assert [s.seq_id for s in eng._waiting] == ["seq-1", "seq-2"]
+    assert eng.cache.seq_len("seq-1") == 0
+    assert _free(eng) == (64 - 2, 16 - 2)       # the row's alone
+    model.calls.clear()
+    _drive(eng)
+    # It began again, before the request behind it.
+    assert [c for c in model.calls if c[0] != "decode"][:5] == [
+        ("chunk", 0), ("chunk", 4), ("chunk", 8), ("chunk", 12),
+        ("prefill", 3)]
+    assert late.tokens_so_far() == model.oracle(LONG, 5)
+    assert other.tokens_so_far() == model.oracle([4, 4, 4], 2)
+    assert eng.stats()["prefills"] == 3 and _free(eng) == (64, 16)
+
+
+def test_the_victim_of_a_preemption_is_never_the_prompt_in_flight():
+    model, eng, row, late = _mid_prompt()
+    assert eng._pick_victim() is eng._running[0]
+    assert eng._in_flight not in eng._running
+    eng._preempt(eng._pick_victim())
+    assert eng._pick_victim() is None and eng._in_flight is not None
+    _drive(eng)
+    assert late.tokens_so_far() == model.oracle(LONG, 5)
+    assert row.tokens_so_far() == model.oracle([5, 9, 3], 30)
