@@ -122,7 +122,7 @@ def plain_attention(q, k, v, *, causal: bool = True, positions=None):
 
 
 def banded_attention(q, k, v, window: int = None, sink=None, *, offset=0,
-                     live=None):
+                     live=None, keep=None):
     """The plain form of `prefill_attention`: q ``[H, Sq, D]`` over k
     ``[Hkv, Sk, D]`` and v ``[Hkv, Sk, Dv]`` (query head ``i`` on key
     head ``i // group``), causal, with `window` only the keys ``j`` with
@@ -130,9 +130,10 @@ def banded_attention(q, k, v, window: int = None, sink=None, *, offset=0,
     head, of that logit and no value; query ``i`` lies on key ``offset +
     i`` and of the keys up to the last query's only the last `live`
     exist (`flash_attention.prefill_attention_fwd`; a whole prompt's are
-    0 and every key). The whole ``[H, Sq, Sk]`` float32 score matrix is
-    built: for short prompts, the CPU and the tests. Float32 out, ``[H,
-    Sq, Dv]``."""
+    0 and every key); with `keep` ``[Sq, Sk]`` bool a query attends to
+    the keys it sees and keeps. The whole ``[H, Sq, Sk]`` float32 score
+    matrix is built: for short prompts, the CPU and the tests. Float32
+    out, ``[H, Sq, Dv]``."""
     h, sq, d = q.shape
     hkv, sk = k.shape[:2]
     qg = q.reshape(hkv, h // hkv, sq, d)
@@ -140,12 +141,14 @@ def banded_attention(q, k, v, window: int = None, sink=None, *, offset=0,
                         preferred_element_type=jnp.float32) * d ** -0.5
     at_q = offset + jnp.arange(sq)[:, None]
     at_k = jnp.arange(sk)[None, :]
-    keep = at_q >= at_k
+    seen = at_q >= at_k
     if window is not None:
-        keep &= at_q - at_k < window
+        seen &= at_q - at_k < window
     if live is not None:
-        keep &= at_k >= offset + sq - live
-    scores = jnp.where(keep, scores, _NEG_INF)
+        seen &= at_k >= offset + sq - live
+    if keep is not None:
+        seen &= keep
+    scores = jnp.where(seen, scores, _NEG_INF)
     if sink is not None:
         scores = jnp.concatenate([scores, jnp.broadcast_to(
             sink.astype(jnp.float32).reshape(hkv, h // hkv, 1, 1),
@@ -157,20 +160,23 @@ def banded_attention(q, k, v, window: int = None, sink=None, *, offset=0,
 
 
 def prefill_attention(q, k, v, window: int = None, sink=None, *, offset=0,
-                      live=None):
+                      live=None, keep=None):
     """One prompt's attention in the serving prefill, or one chunk's
     over the keys it sees (`offset`, `live`: traced scalars or ints, as
     `flash_attention.prefill_attention_fwd` takes them); grouped heads,
-    an optional window, an optional sink, forward only: the Pallas
-    forward of `ops/flash_attention.py` on a TPU for values of a
+    an optional window, an optional sink, an optional selection (`keep`
+    ``[Sq, Sk]`` bool: a call without one compiles as it always has),
+    forward only: the Pallas forward of `ops/flash_attention.py` on a
+    TPU for values of a
     multiple of 128 (keys may be wider: 192 over 128) and lengths that
     tile, `banded_attention` elsewhere."""
     sq, sk, dv = q.shape[1], k.shape[1], v.shape[2]
     if (jax.default_backend() == "tpu" and dv % 128 == 0
             and sq % 128 == 0 and sk % prefill_block(sq, window) == 0):
         return prefill_attention_fwd(q, k, v, window, sink, offset=offset,
-                                     live=live)
-    return banded_attention(q, k, v, window, sink, offset=offset, live=live)
+                                     live=live, keep=keep)
+    return banded_attention(q, k, v, window, sink, offset=offset, live=live,
+                            keep=keep)
 
 
 def ring_attention_manual(q, k, v, q_pos, *, axis_name: str = "sp",

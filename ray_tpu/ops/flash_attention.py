@@ -402,7 +402,8 @@ flash_mha.defvjp(_flash_mha_fwd, _flash_mha_bwd)
 
 # -- the serving prefill's forward: grouped heads, an optional window ------
 def _prefill_fwd_kernel(at_ref, q_ref, k_ref, v_ref, *rest, scale: float,
-                        window, n_visit: int, with_sink: bool):
+                        window, n_visit: int, with_sink: bool,
+                        with_keep: bool = False):
     """One query tile of one query head against the `kj`-th key tile it
     visits: all tiles up to the diagonal without a window, the last
     `n_visit` up to the diagonal with one. `at_ref` holds the key index
@@ -412,11 +413,14 @@ def _prefill_fwd_kernel(at_ref, q_ref, k_ref, v_ref, *rest, scale: float,
     before the first live key is skipped; the masks are built only on a
     tile the diagonal, the window's edge or the first live key crosses.
     With a sink (``[1, 128]``, the head's logit on every lane) the
-    running softmax starts from that column, which carries no value."""
+    running softmax starts from that column, which carries no value.
+    With a keep tile (int8, a query's selection of its keys) every tile
+    that runs is masked, by it too."""
     from jax.experimental import pallas as pl
 
     sink_ref = rest[0] if with_sink else None
-    o_ref, m_ref, l_ref, acc_ref = rest[int(with_sink):]
+    keep_ref = rest[int(with_sink)] if with_keep else None
+    o_ref, m_ref, l_ref, acc_ref = rest[int(with_sink) + int(with_keep):]
     block, d = o_ref.shape
     qi, kj = pl.program_id(1), pl.program_id(2)
     offset = at_ref[0]
@@ -444,6 +448,8 @@ def _prefill_fwd_kernel(at_ref, q_ref, k_ref, v_ref, *rest, scale: float,
             keep = (at_k <= at_q) & (at_k >= first_live)
             if window is not None:
                 keep &= at_q - at_k < window
+            if with_keep:
+                keep &= keep_ref[...].astype(jnp.int32) != 0
             s = jnp.where(keep, s, _MASK_VALUE)
         m_prev = m_ref[...]
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -461,8 +467,12 @@ def _prefill_fwd_kernel(at_ref, q_ref, k_ref, v_ref, *rest, scale: float,
         # The nearest and the farthest (query, key) pair of the tile.
         runs &= q0 - (k0 + block - 1) < window
         crossed |= q0 + block - 1 - k0 >= window
-    pl.when(runs & jnp.logical_not(crossed))(functools.partial(step, False))
-    pl.when(runs & crossed)(functools.partial(step, True))
+    if with_keep:
+        pl.when(runs)(functools.partial(step, True))
+    else:
+        pl.when(runs & jnp.logical_not(crossed))(
+            functools.partial(step, False))
+        pl.when(runs & crossed)(functools.partial(step, True))
 
     @pl.when(kj == n_visit - 1)
     def _finish():
@@ -483,8 +493,8 @@ def prefill_block(seq_len: int, window: int = None) -> int:
 
 
 def prefill_attention_fwd(q, k, v, window: int = None, sink=None, *,
-                          offset=0, live=None, block: int = None,
-                          interpret: bool = False):
+                          offset=0, live=None, keep=None,
+                          block: int = None, interpret: bool = False):
     """Causal softmax attention of one sequence for the serving prefill,
     forward only: q ``[H, Sq, D]`` over k ``[Hkv, Sk, D]`` and v ``[Hkv,
     Sk, Dv]``, query head ``i`` on key head ``i // (H // Hkv)``; with
@@ -507,6 +517,12 @@ def prefill_attention_fwd(q, k, v, window: int = None, sink=None, *,
     is neither computed nor fetched. A query tile walks the same key
     tiles in the same order wherever its chunk begins: with `offset` 0
     and every key live this is the whole prompt's result, bit for bit.
+
+    `keep` ``[Sq, Sk]`` bool (a layer that selects its keys,
+    `ops/sparse_attention.py`): a query attends to the keys it sees AND
+    keeps; it goes in as int8 tiles, a head's tile the same as every
+    other's, and every tile up to the diagonal is then masked. Under
+    ``flash_prefill_fwd_selected`` in a device trace.
 
     The training kernels above are left as they are (their tiles, their
     names): this one runs under ``flash_prefill_fwd_causal`` or
@@ -555,10 +571,16 @@ def prefill_attention_fwd(q, k, v, window: int = None, sink=None, *,
                                      lambda hi, qi, kj, at_ref: (hi, 0, 0)))
         operands.append(jnp.broadcast_to(
             sink.astype(jnp.float32)[:, None, None], (h, 1, _LANES)))
+    if keep is not None:
+        in_specs.append(pl.BlockSpec(
+            (block, block),
+            lambda hi, qi, kj, at_ref: (qi, kv_map(hi, qi, kj, at_ref)[1])))
+        operands.append(keep.astype(jnp.int8))
     return pl.pallas_call(
         functools.partial(_prefill_fwd_kernel, scale=scale,
                           window=window, n_visit=n_visit,
-                          with_sink=sink is not None),
+                          with_sink=sink is not None,
+                          with_keep=keep is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(h, sq // block, n_visit),
@@ -570,7 +592,8 @@ def prefill_attention_fwd(q, k, v, window: int = None, sink=None, *,
         out_shape=jax.ShapeDtypeStruct((h, sq, dv), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        name=("flash_prefill_fwd_causal" if window is None
+        name=("flash_prefill_fwd_selected" if keep is not None
+              else "flash_prefill_fwd_causal" if window is None
               else f"flash_prefill_fwd_window_{window}"),
         interpret=interpret,
     )(at, *operands)

@@ -150,7 +150,7 @@ def _as_wide_as_the_pools_keys(x, pool):
 
 def paged_decode_attention_xla(q, k_new, v_new, pool, tables, positions,
                                layer, window: int = None, starts=None,
-                               sink=None):
+                               sink=None, keep=None, own_keep=None):
     """q ``[B, H, dk]``; k_new ``[B, Hkv, dk]``, v_new ``[B, Hkv, dv]``;
     pool ``[N, bs, L, S, Hkv, dv]`` (`kv_row`: ``[N, bs, L, 2, Hkv, hd]``
     where ``dk == dv``); tables ``[B, nb]`` int32; positions ``[B]``
@@ -161,7 +161,10 @@ def paged_decode_attention_xla(q, k_new, v_new, pool, tables, positions,
     ``Hkv``. Returns ``[B, H, dv]`` float32. Pool positions at or past a
     row's `position` may hold anything (a reused block's stale rows,
     block 0 behind a padded table entry): they are masked, never read
-    into the result."""
+    into the result. `keep` ``[B, nb * bs]`` bool, where the layer
+    selects its keys: of the cached positions a row sees, those it
+    attends to; `own_keep` ``[B]`` bool, whether it attends to the step's
+    own position (None: it does)."""
     b, h, dk = q.shape
     hkv, dv = pool.shape[4], pool.shape[5]
     s_pad = tables.shape[1] * pool.shape[1]
@@ -183,8 +186,12 @@ def paged_decode_attention_xla(q, k_new, v_new, pool, tables, positions,
     cached = at < positions[:, None]
     if window is not None:
         cached &= positions[:, None] - at < window
+    if keep is not None:
+        cached &= keep
     scores = jnp.where(cached[:, None, None, :], scores, _NEG_INF)
     own = jnp.sum(q * k_new, axis=-1, keepdims=True) * scale
+    if own_keep is not None:
+        own = jnp.where(own_keep[:, None, None, None], own, _NEG_INF)
     columns = [scores, own]
     if sink is not None:
         columns.append(jnp.broadcast_to(
@@ -269,10 +276,12 @@ def _seen(at, position, until, window):
 
 
 def _start_from_own_token(q, k_new_ref, v_new_ref, sink_ref, m_ref, l_ref,
-                          acc_ref, scale: float):
+                          acc_ref, scale: float, own_keep=None):
     """The running softmax starts from the step's own key and value,
     and from the layer's sink (a column with no value) where it has
-    one."""
+    one. With `own_keep` (a scalar, where the layer selects its keys) 0
+    the own key's score is the mask's: the first key the row does attend
+    to wipes the start out."""
     f32 = jnp.float32
     h, n_kv = q.shape[0], k_new_ref.shape[0]
     k_own = k_new_ref[...].astype(f32)                       # [Hkv, dk]
@@ -284,6 +293,8 @@ def _start_from_own_token(q, k_new_ref, v_new_ref, sink_ref, m_ref, l_ref,
             [jnp.broadcast_to(x[j][None], (group, x.shape[-1]))
              for j in range(n_kv)], axis=0) for x in (k_own, v_own))
     own = jnp.sum(q * k_own, axis=-1, keepdims=True) * scale
+    if own_keep is not None:
+        own = jnp.where(own_keep > 0, own, _NEG_INF)
     if sink_ref is None:
         m_ref[...] = own
         l_ref[...] = jnp.ones_like(l_ref)
@@ -318,8 +329,29 @@ def _attend(q, kv, first, position, until, m_ref, l_ref, acc_ref, *,
     acc_ref[...] = alpha * acc_ref[...] + jnp.sum(p * vals, axis=0)
 
 
+def _kept_lanes(kept, n_kv: int):
+    """kept ``[P, bs]`` float32 (a page a row, 1.0 at a position the row
+    attends to) -> ``[1, P * bs * n_kv]``, lane ``c`` the flag of key
+    ``c // n_kv``: as `_attend_grouped` lays its score columns. By one
+    small product (position ``s`` of every page onto the lanes of slot
+    ``s``) and a masked sum over the pages: a reshape from sublanes onto
+    lanes the compiler does not take."""
+    f32, i32 = jnp.float32, jnp.int32
+    pages, bs = kept.shape
+    lanes = pages * bs * n_kv
+    slot = jax.lax.broadcasted_iota(i32, (bs, lanes), 0)
+    lane = jax.lax.broadcasted_iota(i32, (bs, lanes), 1)
+    onto = (jax.lax.rem(jax.lax.div(lane, i32(n_kv)), i32(bs))
+            == slot).astype(f32)
+    spread = jnp.dot(kept, onto, preferred_element_type=f32)  # [P, lanes]
+    page = jax.lax.broadcasted_iota(i32, (pages, lanes), 0)
+    lane = jax.lax.broadcasted_iota(i32, (pages, lanes), 1)
+    own = jax.lax.div(lane, i32(bs * n_kv)) == page
+    return jnp.sum(jnp.where(own, spread, 0.0), axis=0, keepdims=True)
+
+
 def _attend_grouped(q, kv, first, position, until, m_ref, l_ref, acc_ref,
-                    *, scale: float, window):
+                    *, scale: float, window, kept=None):
     """`_attend` for ``H`` query heads over kv's ``Hkv`` key heads, query
     head i reading key head ``i // group``. The keys stay as they lie,
     ``T x Hkv`` rows of ``hd``: one matrix product gives every query
@@ -342,6 +374,8 @@ def _attend_grouped(q, kv, first, position, until, m_ref, l_ref, acc_ref,
     keep = (_seen(first + jax.lax.div(column, jnp.int32(n_kv)), position,
                   until, window)
             & (key_head == jax.lax.div(head, jnp.int32(h // n_kv))))
+    if kept is not None:
+        keep &= _kept_lanes(kept, n_kv) > 0.5
     scores = jnp.where(keep, scores, _NEG_INF)
     m_prev = m_ref[...]
     m_next = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
@@ -380,9 +414,9 @@ def _row_walks(positions, starts, block_size: int, table_width: int,
                      axis=1).astype(jnp.int32)
 
 
-def _kernel_body(tables_ref, positions_ref, layer_ref, walks_ref, q_ref,
-                 k_new_ref, v_new_ref, pool_ref, *rest, block_size: int,
-                 scale: float, window, with_sink: bool):
+def _kernel_body(tables_ref, positions_ref, layer_ref, walks_ref, *refs,
+                 block_size: int, scale: float, window, with_sink: bool,
+                 with_keep: bool = False, with_own_keep: bool = False):
     """One grid step is one row. Its live table columns are walked in
     groups (`_row_walks`): a group's pages are copied from the pool in
     HBM into one of two VMEM slabs while the group before it is attended
@@ -394,9 +428,12 @@ def _kernel_body(tables_ref, positions_ref, layer_ref, walks_ref, q_ref,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    own_keep_ref = refs[0] if with_own_keep else None
+    q_ref, k_new_ref, v_new_ref, pool_ref, *rest = refs[int(with_own_keep):]
     sink_ref = rest[0] if with_sink else None
+    keep_ref = rest[int(with_sink)] if with_keep else None
     (o_ref, slabs, arrived, ahead_ref, m_ref, l_ref,
-     acc_ref) = rest[int(with_sink):]
+     acc_ref) = rest[int(with_sink) + int(with_keep):]
     i32 = jnp.int32
     row, last_row = pl.program_id(0), pl.num_programs(0) - 1
     layer = layer_ref[0]
@@ -425,7 +462,8 @@ def _kernel_body(tables_ref, positions_ref, layer_ref, walks_ref, q_ref,
 
     q = q_ref[...].astype(jnp.float32)                       # [H, hd]
     _start_from_own_token(q, k_new_ref, v_new_ref, sink_ref, m_ref, l_ref,
-                          acc_ref, scale)
+                          acc_ref, scale,
+                          own_keep_ref[row] if with_own_keep else None)
     position = positions_ref[row]
     first, n, size, n_groups, block0 = (walks_ref[row, k] for k in range(5))
     attend = functools.partial(
@@ -475,8 +513,13 @@ def _kernel_body(tables_ref, positions_ref, layer_ref, walks_ref, q_ref,
                 page = lax.mul(c, i32(a_pass))
                 kv = slabs[slab, pl.ds(page, a_pass)].astype(jnp.float32)
                 kv = kv.reshape((a_pass * block_size,) + kv.shape[2:])
+                kept = {}
+                if with_keep:
+                    # The pass's pages by their table columns.
+                    column = lax.add(lax.add(first, done), page)
+                    kept["kept"] = keep_ref[pl.ds(column, a_pass), :]
                 attend(q, kv, lax.add(at, lax.mul(page, i32(block_size))),
-                       position, until, m_ref, l_ref, acc_ref)
+                       position, until, m_ref, l_ref, acc_ref, **kept)
             lax.fori_loop(i32(0), lax.div(lax.add(count, i32(a_pass - 1)),
                                           i32(a_pass)), a_pass_over, None)
             return other
@@ -487,12 +530,14 @@ def _kernel_body(tables_ref, positions_ref, layer_ref, walks_ref, q_ref,
     o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("window", "pages", "interpret"))
+@functools.partial(jax.jit, static_argnames=("window", "pages", "interpret",
+                                             "name"))
 def paged_decode_attention_kernel(q, k_new, v_new, pool, tables,
                                   positions, layer, window: int = None,
-                                  starts=None, sink=None, *,
-                                  pages: int = None,
-                                  interpret: bool = False):
+                                  starts=None, sink=None, keep=None,
+                                  own_keep=None, *, pages: int = None,
+                                  interpret: bool = False,
+                                  name: str = None):
     """Same arguments and result as `paged_decode_attention_xla`. The
     pool stays in HBM as it stands; the kernel copies a row's live pages
     of one layer into VMEM, `pages` at a time (`pages_per_step`, where
@@ -500,7 +545,14 @@ def paged_decode_attention_kernel(q, k_new, v_new, pool, tables,
     layers of one shape (the layer index is an argument) are then traced
     and lowered to the kernel's MLIR once, not once a layer, which a
     step's program pays at every start, compiled or fetched (12 calls
-    cost a `repo-context` bucket 2.4 s of 4.0: my chip run, PR 36)."""
+    cost a `repo-context` bucket 2.4 s of 4.0: my chip run, PR 36).
+    With `keep` (grouped heads only) the walk is the same, every live
+    page fetched, and a position the row does not attend to is masked
+    like one it does not see: a further operand ``[nb, bs]`` a row in
+    VMEM. A call without one traces what it always has. `name`: what a
+    device trace calls the kernel where the default (the module
+    docstring's two names) would mislead: `sparse_paged_decode_attention`
+    alone passes one."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -519,6 +571,10 @@ def paged_decode_attention_kernel(q, k_new, v_new, pool, tables,
     prefetched = [tables.astype(jnp.int32), positions,
                   jnp.reshape(layer, (1,)).astype(jnp.int32),
                   _row_walks(positions, starts, bs, nb, pages, window)]
+    if own_keep is not None:
+        prefetched.append(own_keep.astype(jnp.int32))
+    if keep is not None and h == hkv:
+        raise ValueError("a keep mask goes with grouped heads")
     q, k_new = (_as_wide_as_the_pools_keys(x, pool) for x in (q, k_new))
     held = q.shape[2]
 
@@ -533,9 +589,19 @@ def paged_decode_attention_kernel(q, k_new, v_new, pool, tables,
     if sink is not None:
         in_specs.append(pl.BlockSpec((h, 1), lambda row, *refs: (0, 0)))
         operands.append(sink.astype(jnp.float32).reshape(h, 1))
+    if keep is not None:
+        # A pass reads its pages' rows by their table columns, and its
+        # last may reach past the row's last live page: `pages` rows of
+        # nothing kept behind the table's.
+        in_specs.append(pl.BlockSpec((None, nb + pages, bs), row_map))
+        operands.append(jnp.pad(
+            keep.reshape(b, nb, bs).astype(jnp.float32),
+            ((0, 0), (0, pages), (0, 0))))
     return pl.pallas_call(
         functools.partial(_kernel_body, block_size=bs, scale=dk ** -0.5,
-                          window=window, with_sink=sink is not None),
+                          window=window, with_sink=sink is not None,
+                          with_keep=keep is not None,
+                          with_own_keep=own_keep is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetched),
             grid=(b,),
@@ -551,20 +617,69 @@ def paged_decode_attention_kernel(q, k_new, v_new, pool, tables,
         out_shape=jax.ShapeDtypeStruct((b, h, dv), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        name=("paged_decode_attention" if window is None
-              else "paged_window_decode_attention"),
+        name=name or ("paged_decode_attention" if window is None
+                      else "paged_window_decode_attention"),
         interpret=interpret,
     )(*prefetched, *operands)
 
 
 def paged_decode_attention(q, k_new, v_new, pool, tables, positions,
                            layer, window: int = None, starts=None,
-                           sink=None):
+                           sink=None, keep=None, own_keep=None):
     """One layer's decode attention through the block tables: the
-    kernel where `kernel_eligible`, the XLA body elsewhere."""
+    kernel where `kernel_eligible`, the XLA body elsewhere. With `keep`
+    ``[B, nb * bs]`` and `own_keep` ``[B]`` (a layer that selects its
+    keys) every live page is still walked, by the same kernel under the
+    same name, and the positions not kept are masked."""
     body = (paged_decode_attention_kernel
             if kernel_eligible(*attention_widths(
                 q.shape[1], q.shape[2], k_new.shape[1], v_new.shape[2]))
             else paged_decode_attention_xla)
     return body(q, k_new, v_new, pool, tables, positions, layer, window,
-                starts, sink)
+                starts, sink, keep, own_keep)
+
+
+# -- attention over chosen rows alone ---------------------------------------
+def chosen_slots(keep, tables, block_size: int, most: int):
+    """keep ``[B, nb * bs]`` bool -> the pool's slots (``block * bs +
+    offset``) of a row's kept positions, ``[B, most]`` int32 in the order
+    of their positions, and how many there are, ``[B]`` (a row keeps at
+    most `most`: one more is dropped)."""
+    b, s = keep.shape
+    rank = jnp.cumsum(keep, axis=-1, dtype=jnp.int32) - 1
+    at = jnp.arange(s, dtype=jnp.int32)
+    slot = (jnp.take_along_axis(tables, at[None] // block_size, axis=1)
+            * block_size + at[None] % block_size)
+    chosen = jnp.zeros((b, most), jnp.int32).at[
+        jnp.arange(b)[:, None], jnp.where(keep, rank, most)].set(
+        slot, mode="drop")
+    return chosen, jnp.minimum(rank[:, -1] + 1, most)
+
+
+def sparse_paged_decode_attention(q, k_new, v_new, pool, tables, positions,
+                                  layer, keep, own_keep, most: int, *,
+                                  interpret: bool = None):
+    """`paged_decode_attention` with `keep`, by fetching the kept rows
+    alone: the walk's kernel over the pool seen as pages of ONE position
+    (``pool[block, slot, layer]`` is one piece of a position's bytes),
+    through a table of the kept positions' slots (`chosen_slots`), under
+    the name ``sparse_paged_decode_attention``. A row's copies are as
+    many as it keeps (`most` at the most) and of a position's bytes
+    each, where the walk's are a page's. Off the chip (`interpret` None)
+    the XLA body over the same table. No model runs it: on the chip the
+    copies' starts bound it (1.48 ms a layer at 16 rows whatever their
+    length), and the walk under a mask is faster at every length a cell
+    reaches (PERF.md, Findings, PR 57; ROADMAP R13 a names the traffic
+    that would earn it a place). The tests hold it against the walk."""
+    del positions
+    n, bs = pool.shape[:2]
+    slots, count = chosen_slots(keep, tables, bs, most)
+    rows = pool.reshape((n * bs, 1) + pool.shape[2:])
+    if interpret is None and not kernel_eligible(*attention_widths(
+            q.shape[1], q.shape[2], k_new.shape[1], v_new.shape[2])):
+        return paged_decode_attention_xla(
+            q, k_new, v_new, rows, slots, count, layer, own_keep=own_keep)
+    return paged_decode_attention_kernel(
+        q, k_new, v_new, rows, slots, count, layer, own_keep=own_keep,
+        pages=min(512, most), interpret=bool(interpret),
+        name="sparse_paged_decode_attention")

@@ -78,3 +78,22 @@ def apply_rotary_partial(x, cos, sin):
     c, s = cos[:, None, :], sin[:, None, :]
     return jnp.concatenate([x1 * c - x2 * s, x1 * s + x2 * c, rest],
                            axis=-1)
+
+
+def rotary_cos_sin_sections(positions, inv_freq, sections):
+    """`rotary_cos_sin` over several position streams: positions ``[n,
+    T]``, a row a stream (temporal, height, width), and `sections`, how
+    many of the ``rot_dim // 2`` pairs each stream turns, in order
+    (``mrope_section`` [16, 24, 24]: pairs 0-15 by the first stream,
+    16-39 by the second, 40-63 by the third). cos, sin ``[T, rot_dim //
+    2]`` float32; with equal streams they are `rotary_cos_sin`'s."""
+    import numpy as np
+
+    half = inv_freq.shape[0]
+    if len(sections) != positions.shape[0] or sum(sections) != half:
+        raise ValueError(f"sections {list(sections)} do not share out "
+                         f"{half} pairs over {positions.shape[0]} streams")
+    stream = np.repeat(np.arange(len(sections)), sections)       # [half]
+    ang = positions.astype(jnp.float32)[..., None] * inv_freq   # [n, T, half]
+    ang = ang[stream, :, np.arange(half)].T                      # [T, half]
+    return jnp.cos(ang), jnp.sin(ang)

@@ -39,6 +39,7 @@ Typical replica:
 from ray_tpu.serve.engine.kv_cache import (CacheOverflowError,
                                            KVCacheManager)
 from ray_tpu.serve.engine.hybrid_model import HybridEngineModel
+from ray_tpu.serve.engine.keye_model import KeyeEngineModel
 from ray_tpu.serve.engine.laguna_model import LagunaEngineModel
 from ray_tpu.serve.engine.mimo_model import MimoEngineModel
 from ray_tpu.serve.engine.model import TinyLM, TransformerEngineModel
@@ -51,6 +52,6 @@ from ray_tpu.serve.engine.scheduler import (EngineConfig,
 __all__ = [
     "CacheOverflowError", "EngineConfig", "EngineOverloadedError",
     "EngineStoppedError", "HybridEngineModel", "InferenceEngine",
-    "KVCacheManager", "LagunaEngineModel", "MimoEngineModel",
+    "KVCacheManager", "KeyeEngineModel", "LagunaEngineModel", "MimoEngineModel",
     "PrefixIndex", "TinyLM", "TokenStream", "TransformerEngineModel",
 ]

@@ -97,6 +97,22 @@ layers' rows at its end), and groups are not combined with state slots.
 A model that declares no groups is one global group, with the calls and
 answers it has always had.
 
+**A pool that rides** (a group declared with ``"rides": True``): a
+second kind of row a position keeps beside its keys and values, in a
+pool of its own that has no free list and no table: block ``b`` of it
+belongs to block ``b`` of the global group, so it is allocated, freed,
+copied on a write and preempted with that block, and a step reads it
+through the global group's table. Its layout is ``[num_blocks, L,
+block_size, *rest]`` for a row ``(L, *rest)`` a position: a layer's page
+in one piece, because a model reads such a pool whole a layer (every
+live position, every step: an indexer's keys, `ops/sparse_attention.py`)
+where it reads the KV in part. `write_range` stores the payload's
+``groups[name]`` rows at the same slots as the KV's, `paged_step` and
+`with_pools` hand it over by name beside the groups' pools (donated and
+re-bound like them, its write slots the global group's), `step_tables`
+names no table for it, and `stats()` counts it under `groups` with the
+global group's blocks in use and its own bytes.
+
 Determinism contract (the scheduler's loop must never crash on OOM):
 `allocate` is atomic — it either extends the table (and privatizes the
 requested write range) or changes nothing and returns False; the
@@ -165,9 +181,27 @@ class _DevicePoolOps:
             # so one compile per pow2 row bucket suffices.
             return pool.at[blocks, offs].set(vals, mode="drop")
 
+        def scatter_rider(pool, blocks, offs, vals):
+            # The same write into a pool that rides, `[N, L, bs, ...]`:
+            # a slot a (row, layer). (`pool.at[blocks, :, offs]`, two
+            # indexed axes with one between them, XLA lowers through
+            # three transposed copies of the whole pool.)
+            return pool.at[rider_slots(pool, blocks, offs)].set(
+                vals.astype(pool.dtype), mode="drop")
+
         self.copy_block = jax.jit(copy_block, donate_argnums=0)
         self.set_block = jax.jit(set_block, donate_argnums=0)
         self.scatter = jax.jit(scatter, donate_argnums=0)
+        self.scatter_rider = jax.jit(scatter_rider, donate_argnums=0)
+
+
+def rider_slots(pool, blocks, offs):
+    """The index of rows' slots in a pool that rides, ``[N, L, bs,
+    ...]``: ``pool.at[rider_slots(...)]`` is ``[rows, L, ...]``, the
+    three indexed axes side by side, which the compiler writes in
+    place."""
+    layers = np.arange(pool.shape[1])
+    return blocks[:, None], layers[None, :], offs[:, None]
 
 
 _POOL_OPS: Dict[Tuple[int, Tuple[int, ...]], _DevicePoolOps] = {}
@@ -225,7 +259,13 @@ class KVCacheManager:
         of each, zeroed, in the pool's namespace. `groups`: the further
         layer groups, ``{name: {"num_blocks", "kv_shape", "window"}}``,
         each a manager of its own with this one's block size, dtype and
-        namespace; `window`: this group's own (a sub-manager's)."""
+        namespace; `window`: this group's own (a sub-manager's). A group
+        with ``"rides": True`` and a row ``kv_shape`` ``(L, *rest)`` is a
+        pool that rides this one's blocks (module docstring)."""
+        riders = {name: g for name, g in (groups or {}).items()
+                  if g.get("rides")}
+        groups = {name: g for name, g in (groups or {}).items()
+                  if name not in riders}
         if num_blocks <= 0 or block_size <= 0:
             raise ValueError("num_blocks and block_size must be positive")
         if state_shapes and state_slots <= 0:
@@ -281,6 +321,15 @@ class KVCacheManager:
                 g["num_blocks"], block_size, tuple(g["kv_shape"]), dtype,
                 array_ns, window=g.get("window"))
             for name, g in (groups or {}).items()}
+        if riders and not self._device:
+            raise ValueError("a pool that rides needs a device pool: no "
+                             "host model keeps a second kind of row")
+        # Pools that ride this group's blocks, by name.
+        self._riders = {
+            name: self._ns.zeros(
+                (self.num_blocks, g["kv_shape"][0], self.block_size)
+                + tuple(g["kv_shape"][1:]), dtype)
+            for name, g in riders.items()}
         # Reentrant: `with_pool` callbacks legitimately read tables /
         # lengths through the public accessors while the lock is held.
         self._lock = threading.RLock()
@@ -651,6 +700,8 @@ class KVCacheManager:
             self._buffer[new] = self._buffer[old]
         else:
             self._buffer = self._ops.copy_block(self._buffer, new, old)
+            for name, rider in self._riders.items():
+                self._riders[name] = self._ops.copy_block(rider, new, old)
             self.pool_updates += 1
         self._refs[new] = 1
         self._refs[old] -= 1          # shared => was > 1, stays >= 1
@@ -684,14 +735,29 @@ class KVCacheManager:
                               np.dtype(self._dtype))
             padded[:n] = np.asarray(values)[:n]
             vals = self._ns.asarray(padded)
-        rows = int(vals.shape[0])
+        self._buffer = self._ops.scatter(
+            self._buffer, *self._padded_slots(blocks, offs, n,
+                                              int(vals.shape[0])), vals)
+        self.pool_updates += 1
+
+    def _padded_slots(self, blocks, offs, n: int, rows: int):
+        """The first `n` (block, off) slots as device arrays of `rows`
+        entries, the rest past the pool (dropped)."""
         b = np.full((rows,), self.num_blocks, np.int32)
         o = np.zeros((rows,), np.int32)
         b[:n] = blocks[:n]
         o[:n] = offs[:n]
-        self._buffer = self._ops.scatter(
-            self._buffer, self._ns.asarray(b), self._ns.asarray(o), vals)
-        self.pool_updates += 1
+        return self._ns.asarray(b), self._ns.asarray(o)
+
+    def _write_riders(self, blocks, offs, values, n: int) -> None:
+        """The payload's rows of every pool that rides, ``[>= n, L,
+        ...]`` each (`values.groups[name]`), at the KV's own slots."""
+        for name, rider in self._riders.items():
+            rows = values.groups[name]
+            vals = self._ns.asarray(getattr(rows, "padded", rows))
+            self._riders[name] = self._ops.scatter_rider(
+                rider, *self._padded_slots(blocks, offs, n,
+                                           int(vals.shape[0])), vals)
 
     def write(self, seq_id: str, pos: int, value) -> None:
         """Store one token's KV entry at logical position `pos`. A
@@ -752,6 +818,7 @@ class KVCacheManager:
                     i += take
                     pos += take
                 self._pool_scatter(blocks, offs, values, n)
+                self._write_riders(blocks, offs, values, n)
             self._lens[seq_id] = max(self._lens.get(seq_id, 0), start + n)
             if state is not None and self._state is not None:
                 self._write_state(self._slots[seq_id], state)
@@ -796,10 +863,11 @@ class KVCacheManager:
         with layer groups ``fn({group: pool})``, under the cache lock,
         which every write into a group's pool is made under too."""
         with self._lock:
-            if not self._groups:
+            if not self.grouped:
                 return fn(self._buffer)
-            return fn({name: g._buffer
-                       for name, g in self._members().items()})
+            return fn({**{name: g._buffer
+                          for name, g in self._members().items()},
+                       **self._riders})
 
     def mutate_pool(self, fn):
         """Run ``fn(pool) -> (result, new_pool)`` under the cache lock
@@ -833,7 +901,7 @@ class KVCacheManager:
         re-bound here."""
         with self._lock:
             self._count_block_steps()
-            if self._groups:
+            if self.grouped:
                 return self._grouped_step(entries, fn)
             blocks, offs = self._write_slots(entries)
             if self._state is None:
@@ -878,9 +946,13 @@ class KVCacheManager:
                 g._count_block_steps()
             pools[name] = g._buffer
             blocks[name], offs[name] = g._write_slots(entries)
+        # A pool that rides is written at the global group's slots.
+        pools.update(self._riders)
         result, new_pools = fn(pools, blocks, offs)
         for name, g in members.items():
             g._rebind_after_step(new_pools[name], entries)
+        for name in self._riders:
+            self._riders[name] = new_pools[name]
         return result
 
     def step_tables(self, seq_id: str):
@@ -890,7 +962,7 @@ class KVCacheManager:
         group, the first block the window still reaches in a window
         group)."""
         with self._lock:
-            if not self._groups:
+            if not self.grouped:
                 return list(self._tables.get(seq_id, ()))
             return {name: (g._base.get(seq_id, 0),
                            list(g._tables.get(seq_id, ())))
@@ -898,7 +970,8 @@ class KVCacheManager:
 
     @property
     def grouped(self) -> bool:
-        return bool(self._groups)
+        """Whether a step takes and hands back pools by name."""
+        return bool(self._groups or self._riders)
 
     def group(self, name: str) -> "KVCacheManager":
         """The manager of one layer group (`global`: this one)."""
@@ -952,6 +1025,13 @@ class KVCacheManager:
         return sum(int(np.prod(pool.shape)) * np.dtype(pool.dtype).itemsize
                    for pool in self._state.values())
 
+    @property
+    def rider_bytes(self) -> Dict[str, int]:
+        """Bytes of each pool that rides this group's blocks."""
+        return {name: int(np.prod(rider.shape))
+                * np.dtype(self._dtype).itemsize
+                for name, rider in self._riders.items()}
+
     def _group_stats(self) -> Dict[str, float]:
         with self._lock:
             return {
@@ -970,6 +1050,9 @@ class KVCacheManager:
             shared = sum(1 for n in self._refs.values() if n > 1)
             groups = {name: g._group_stats()
                       for name, g in self._members().items()}
+            for name, nbytes in self.rider_bytes.items():
+                groups[name] = dict(groups[self.GLOBAL], pool_bytes=nbytes,
+                                    rides=self.GLOBAL)
             return {
                 "groups": groups,
                 "num_blocks": self.num_blocks,

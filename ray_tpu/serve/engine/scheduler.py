@@ -346,7 +346,10 @@ class InferenceEngine:
         # their own); the config says how many blocks each gets.
         kv_groups = getattr(model, "kv_groups", None) or {}
         sizes = self.config.group_blocks or {}
-        if set(kv_groups) != set(sizes):
+        # A pool that rides the global group's blocks has none of its
+        # own to size (`kv_cache.py`).
+        riding = {name for name, g in kv_groups.items() if g.get("rides")}
+        if set(kv_groups) - riding != set(sizes):
             raise ValueError(
                 f"the model's layer groups {sorted(kv_groups)} and the "
                 f"config's group_blocks {sorted(sizes)} differ")
@@ -357,7 +360,8 @@ class InferenceEngine:
             array_ns=getattr(model, "kv_pool_ns", None),
             state_shapes=state_shapes,
             state_slots=self.config.max_batch_size if state_shapes else 0,
-            groups={name: dict(group, num_blocks=sizes[name])
+            groups={name: (group if name in riding
+                           else dict(group, num_blocks=sizes[name]))
                     for name, group in kv_groups.items()})
         self.prefix_index: Optional[PrefixIndex] = None
         # Adopting blocks of KV restores a prefix only where KV is all a
@@ -1304,6 +1308,9 @@ class InferenceEngine:
             "decode_kv_bytes_read_model": getattr(
                 self.model, "decode_kv_bytes_read_model", 0),
             **self._group_counters(cache["groups"]),
+            # What a model counts of its own beside all of the above.
+            **{name: getattr(self.model, name)
+               for name in getattr(self.model, "own_counters", ())},
             "jit_bucket_evictions": getattr(
                 self.model, "jit_cache_evictions", 0),
             "prefill_s": round(self.prefill_s, 6),
