@@ -2,10 +2,32 @@
 
 One query token a row attends over that row's cached positions
 ``[0, position)``, which live in the serving engine's block pool
-``[num_blocks, block_size, n_layers, 2, n_kv_heads, head_dim]``
 (`serve/engine/kv_cache.py`) at the blocks its block table names, plus
 the step's own key and value (position ``position``, not yet in the
 pool). Nothing gathers the row's cache into a dense array first.
+
+**Two layouts of a pool, one rule between them** (`held_by_planes`, from
+the key/value heads alone; every reader tells them by the pool's rank,
+`by_planes`):
+
+- *rows*, ``[num_blocks, block_size, n_layers, S, n_kv_heads, dv]``
+  (`kv_row`: ``S`` slots of the values' width, ``[K, V]`` where keys and
+  values are of one width): a position's row in one piece. Where
+  ``n_kv_heads`` is a multiple of 8 a position's ``[n_kv_heads, dv]``
+  fills whole float32 tiles of 8 sublanes, and the body below meets the
+  keys as they lie.
+- *planes*, ``[num_blocks, n_layers, S * n_kv_heads, block_size, dv]``:
+  where the heads do not fill a tile (4 today: Keye's one group, MiMo's
+  global group) plane ``slot * n_kv_heads + head`` of a layer holds that
+  head's slot of the block's positions, positions on the sublanes and
+  ``dv`` on the lanes, as the index keys' pool holds its one row a
+  position (`kv_cache.py`, a pool that rides). A layer's page,
+  ``pool[block, layer]``, is one contiguous piece of whole ``[16, 128]``
+  bf16 tiles, a key head's keys of a pass are ``[keys, dk]`` by a
+  reshape that moves nothing, and the body forms one product a key head
+  (`_attend_planes`). The same arithmetic: float32 query, the slab
+  converted to float32, products with float32 accumulation, float32
+  softmax.
 
 Heads may be grouped: ``n_heads`` query heads over ``n_kv_heads`` key
 and value heads, query head ``i`` reading key head ``i // group``. The
@@ -32,13 +54,19 @@ Two bodies, one result:
   to the table's width. Online softmax in float32, started from the
   step's own key and value, `_BYTES_A_PASS` of pages an update (256 keys
   of a bf16 pool). With one key head a query head the scores are
-  products and sums on the vector unit; with grouped heads the keys stay
-  as they lie (``keys x n_kv_heads`` rows): one matrix product gives
-  every query head against every row, the rows of other key heads are
-  masked beside the positions the row does not see, and a second product
-  takes the probabilities to the values.
+  products and sums on the vector unit; with grouped heads over a pool
+  of rows the keys stay as they lie (``keys x n_kv_heads`` rows): one
+  matrix product gives every query head against every row, the rows of
+  other key heads are masked beside the positions the row does not see,
+  and a second product takes the probabilities to the values. Over a
+  pool held by planes the walk, the two slabs, the groups and the passes
+  are the same; a page is copied in one piece and the body is
+  `_attend_planes`: a key head's ``group`` query rows against that
+  head's keys alone, the heads' scores stacked to ``[H, keys]``, every
+  mask one row of lanes.
 - `paged_decode_attention_xla`: plain XLA, ``pool[tables, :, layer]``
-  for one layer and a masked softmax. The kernel's reference in the
+  (``pool[tables, layer]`` of a pool held by planes) for one layer and
+  a masked softmax. The kernel's reference in the
   tests, and what runs off the chip and for head sizes the kernel does
   not take.
 
@@ -77,15 +105,21 @@ from ray_tpu.ops.attention import _NEG_INF
 
 def kernel_eligible(n_heads: int, head_dim: int,
                     n_kv_heads: int = None, v_head_dim: int = None) -> bool:
-    """The kernel needs the TPU backend and a pool whose ``[n_kv_heads,
-    v_head_dim]`` slots (`kv_row`) are whole tiles: values that fill the
-    lanes (heads of 64 and the unit tests' tiny models take the XLA
-    body; keys may be wider than values, in slots of their own) and 4
-    key/value heads or a multiple of 8 (`n_kv_heads` where heads are
-    grouped, else `n_heads`). At 12 heads the chip keeps the pool in
-    another layout (it tiles the K/V axis instead, so as not to pad 12
-    to 16), and the compiler would hand the kernel a converted copy of
-    the whole pool, a layer."""
+    """The kernel needs the TPU backend and a pool it reads in whole
+    tiles: values that fill the lanes (heads of 64 and the unit tests'
+    tiny models take the XLA body; keys may be wider than values, in
+    slots of their own) and 4 key/value heads or a multiple of 8
+    (`n_kv_heads` where heads are grouped, else `n_heads`). A multiple
+    of 8 fills a float32 tile's sublanes in a pool of rows; 4 is held by
+    planes (`held_by_planes`), a block's positions on the sublanes, and
+    read by the per-head body. (A pool of rows at 4 heads, which no
+    model declares any more, still goes through the body over rows: the
+    tests hold the two against each other.) 1, 2 and 12 heads would lie
+    in whole tiles by planes too and are not taken yet (ROADMAP R0b): at
+    12 heads the chip keeps a pool of rows in another layout (it tiles
+    the K/V axis instead, so as not to pad 12 to 16), and the compiler
+    would hand the kernel a converted copy of the whole pool, a
+    layer."""
     pool_heads = n_heads if n_kv_heads is None else n_kv_heads
     return (jax.default_backend() == "tpu"
             and (v_head_dim or head_dim) % 128 == 0
@@ -99,6 +133,104 @@ def attention_widths(n_heads: int, head_dim: int, n_kv_heads: int,
     width named only where it is not the keys'."""
     widths = (n_heads, head_dim, n_kv_heads)
     return widths if v_head_dim == head_dim else widths + (v_head_dim,)
+
+
+def held_by_planes(n_kv_heads: int) -> bool:
+    """Which of the two layouts a pool of `n_kv_heads` key/value heads
+    has, from the head count alone: **rows** (``[N, bs, L, S, Hkv,
+    dv]``, a position's `kv_row` in one piece) where the heads fill a
+    float32 tile's 8 sublanes, **planes** (``[N, L, S * Hkv, bs, dv]``)
+    where they do not. The model declares its group with this
+    (`kv_cache.py`: ``"planes"``), and every reader tells the two by the
+    pool's rank (`by_planes`)."""
+    return n_kv_heads % 8 != 0
+
+
+def by_planes(pool) -> bool:
+    """Whether `pool` is held a block, a layer and a plane at a time,
+    ``[N, L, S * Hkv, bs, dv]`` (which is ``[N, L * S * Hkv, bs, dv]``
+    with a layer's planes named apart): plane ``slot * Hkv + head`` of a
+    layer holds that head's slot (`kv_row`) of the block's ``bs``
+    positions, positions on the sublanes and ``dv`` on the lanes, the
+    layout of the index keys' pool (`kv_cache.py`, a pool that rides).
+    A layer's page is then ``pool[block, layer]``, one contiguous piece
+    of whole ``[16, 128]`` bf16 tiles. The other kind has rank 6."""
+    return pool.ndim == 5
+
+
+def pool_block_size(pool) -> int:
+    return pool.shape[3] if by_planes(pool) else pool.shape[1]
+
+
+def _page_shape(pool) -> tuple:
+    """A layer's page of `pool` as a slab of the kernel holds it."""
+    return (pool.shape[2:] if by_planes(pool)
+            else pool.shape[1:2] + pool.shape[3:])
+
+
+def pool_page_bytes(pool) -> int:
+    """A layer's page of `pool`, in bytes: the same in both layouts."""
+    return math.prod(_page_shape(pool)) * pool.dtype.itemsize
+
+
+def plane_slots(pool, blocks, offs):
+    """The index of rows' slots in a pool held by planes, ``[N, L, P,
+    bs, dv]``: ``pool.at[plane_slots(...)]`` is ``[rows, L, P, dv]``,
+    the four indexed axes side by side, which the compiler writes in
+    place (`kv_cache.rider_slots`: an axis between two indexed ones
+    costs copies of the whole pool)."""
+    layers, planes = jnp.arange(pool.shape[1]), jnp.arange(pool.shape[2])
+    return (blocks[:, None, None], layers[None, :, None],
+            planes[None, None, :], offs[:, None, None])
+
+
+def write_rows(pool, blocks, offs, rows):
+    """`pool` with `rows` ``[B, L, S, Hkv, dv]`` (a position's `kv_row`
+    of every layer, a row) at the slots ``(blocks[i], offs[i])``, in
+    either layout; a block past the pool is dropped."""
+    if not by_planes(pool):
+        return pool.at[blocks, offs].set(rows, mode="drop")
+    return pool.at[plane_slots(pool, blocks, offs)].set(
+        rows.reshape((-1,) + pool.shape[1:3] + pool.shape[4:]),
+        mode="drop")
+
+
+def pages_as_rows(pages, n_kv_heads: int):
+    """Pages ``[..., nb, S * Hkv, bs, dv]`` of one layer of a planes pool
+    -> the positions' rows ``[..., nb * bs, S, Hkv, dv]`` (`kv_row`), as
+    a rows pool holds them."""
+    *lead, nb, planes, bs, dv = pages.shape
+    at = len(lead)
+    rows = pages.reshape(*lead, nb, planes // n_kv_heads, n_kv_heads, bs, dv)
+    rows = rows.transpose(*range(at), at, at + 3, at + 1, at + 2, at + 4)
+    return rows.reshape(*lead, nb * bs, planes // n_kv_heads, n_kv_heads, dv)
+
+
+def pages_as_heads(pages, n_kv_heads: int, head_dim: int):
+    """`pages_as_rows` then `kv_of_rows`, a head at a time: the keys
+    ``[Hkv, nb * bs, head_dim]`` and the values ``[Hkv, nb * bs, dv]`` of
+    pages ``[nb, S * Hkv, bs, dv]``, as a prefill's forward takes them.
+    A plane's ``[bs, dv]`` moves in one piece."""
+    nb, planes, bs, dv = pages.shape
+    slots = pages.reshape(nb, planes // n_kv_heads, n_kv_heads, bs, dv)
+    slots = slots.transpose(1, 2, 0, 3, 4).reshape(
+        planes // n_kv_heads, n_kv_heads, nb * bs, dv)
+    keys = jnp.concatenate([slots[0]] + list(slots[2:]), axis=-1)
+    return keys[..., :head_dim], slots[1]
+
+
+def heads_of_pages(pool, table, layer, n_kv_heads: int, head_dim: int):
+    """One layer's keys ``[Hkv, nb * bs, head_dim]`` and values ``[Hkv,
+    nb * bs, dv]`` of the pages `table` ``[nb]`` names in `pool`, in
+    either layout. From a pool held by planes a layer's pages are
+    gathered alone (``nb`` contiguous pieces); from a pool of rows every
+    layer's call gathers the same ``pool[table]``, which the compiler
+    makes once."""
+    if by_planes(pool):
+        return pages_as_heads(pool[table, layer], n_kv_heads, head_dim)
+    rows = pool[table][:, :, layer]
+    keys, vals = kv_of_rows(rows.reshape((-1,) + rows.shape[2:]), head_dim)
+    return keys.transpose(1, 0, 2), vals.transpose(1, 0, 2)
 
 
 def kv_slots(head_dim: int, v_head_dim: int) -> int:
@@ -140,10 +272,16 @@ def kv_of_rows(kv, head_dim: int):
     return _keys_of(kv)[..., :head_dim], kv[..., 1, :, :]
 
 
-def _as_wide_as_the_pools_keys(x, pool):
+def _pool_slots(pool, n_kv_heads: int) -> int:
+    """The slots a key/value head takes in `pool` (`kv_slots`)."""
+    return (pool.shape[2] // n_kv_heads if by_planes(pool)
+            else pool.shape[3])
+
+
+def _as_wide_as_the_pools_keys(x, pool, n_kv_heads: int):
     """q or the step's own keys ``[B, H, dk]``, filled up with zeros to
     the width the pool holds a key in (`kv_row`)."""
-    held = (pool.shape[3] - 1) * pool.shape[5]
+    held = (_pool_slots(pool, n_kv_heads) - 1) * pool.shape[-1]
     return x if x.shape[-1] == held else jnp.pad(
         x, ((0, 0), (0, 0), (0, held - x.shape[-1])))
 
@@ -153,7 +291,8 @@ def paged_decode_attention_xla(q, k_new, v_new, pool, tables, positions,
                                sink=None, keep=None, own_keep=None):
     """q ``[B, H, dk]``; k_new ``[B, Hkv, dk]``, v_new ``[B, Hkv, dv]``;
     pool ``[N, bs, L, S, Hkv, dv]`` (`kv_row`: ``[N, bs, L, 2, Hkv, hd]``
-    where ``dk == dv``); tables ``[B, nb]`` int32; positions ``[B]``
+    where ``dk == dv``) or, held by planes (`by_planes`), ``[N, L, S *
+    Hkv, bs, dv]``; tables ``[B, nb]`` int32; positions ``[B]``
     int32; layer a scalar; `window`, where the layer has one, with starts
     ``[B]`` int32, the logical block a row's table begins at (None: 0);
     `sink` ``[H]``, where the layer has one: a logit a query head that
@@ -166,23 +305,27 @@ def paged_decode_attention_xla(q, k_new, v_new, pool, tables, positions,
     attends to; `own_keep` ``[B]`` bool, whether it attends to the step's
     own position (None: it does)."""
     b, h, dk = q.shape
-    hkv, dv = pool.shape[4], pool.shape[5]
-    s_pad = tables.shape[1] * pool.shape[1]
-    kv = pool[tables, :, layer].reshape((b, s_pad) + pool.shape[3:])
+    hkv, dv = k_new.shape[1], pool.shape[-1]
+    bs = pool_block_size(pool)
+    s_pad = tables.shape[1] * bs
+    if by_planes(pool):
+        kv = pages_as_rows(pool[tables, layer], hkv)
+    else:
+        kv = pool[tables, :, layer].reshape((b, s_pad) + pool.shape[3:])
     kv = kv.astype(jnp.float32)
     keys, vals = _keys_of(kv), kv[:, :, 1]
     scale = dk ** -0.5
     # Query head i reads key head i // group: [B, Hkv, group, dk].
-    q = _as_wide_as_the_pools_keys(q.astype(jnp.float32), pool)
+    q = _as_wide_as_the_pools_keys(q.astype(jnp.float32), pool, hkv)
     q = q.reshape(b, hkv, h // hkv, -1)
     k_new = _as_wide_as_the_pools_keys(k_new.astype(jnp.float32),
-                                       pool)[:, :, None]
+                                       pool, hkv)[:, :, None]
     v_new = v_new.astype(jnp.float32)[:, :, None]
     scores = jnp.einsum("bkgd,bskd->bkgs", q, keys,
                         preferred_element_type=jnp.float32) * scale
     at = jnp.arange(s_pad)[None, :]                              # [B, S]
     if starts is not None:
-        at = at + starts[:, None] * pool.shape[1]
+        at = at + starts[:, None] * bs
     cached = at < positions[:, None]
     if window is not None:
         cached &= positions[:, None] - at < window
@@ -207,6 +350,13 @@ def paged_decode_attention_xla(q, k_new, v_new, pool, tables, positions,
 # the body this many bytes of pages (`pages_per_step`).
 _VMEM_FOR_PAGES = 4 << 20
 _BYTES_A_PASS = 1 << 20
+# Over a pool held by planes: how many copies are started, or waited
+# for, a turn of the loop that does so, in straight-line code. One a turn
+# (the walk over rows) leaves the body nothing to run beside: at Keye's
+# shape the copies alone take 0.57 ms a layer, the body alone 0.18-0.25,
+# and the walk 0.72 at one a turn, 0.574 at 4, 8 or 16 (my chip run,
+# PR 58).
+_COPIES_A_TURN = 8
 
 
 def _pow2_at_most(n: int) -> int:
@@ -228,11 +378,10 @@ def pages_per_step(page_bytes: int, table_width: int) -> int:
 
 
 def pool_pages_per_step(pool, table_width: int) -> int:
-    """`pages_per_step` for a pool ``[N, bs, L, S, Hkv, dv]`` as it is
-    held and a table of `table_width` columns."""
-    return pages_per_step(
-        pool.shape[1] * math.prod(pool.shape[3:]) * pool.dtype.itemsize,
-        table_width)
+    """`pages_per_step` for a pool as it is held, in either layout (a
+    page's bytes are the same in both), and a table of `table_width`
+    columns."""
+    return pages_per_step(pool_page_bytes(pool), table_width)
 
 
 def live_pages(position: int, block_size: int, window: int = None) -> int:
@@ -250,7 +399,7 @@ def page_groups(pool, table_width: int, positions, window: int = None
     row's live pages ÷ `pool_pages_per_step`, rounded up; a row with
     nothing cached has none."""
     pages = pool_pages_per_step(pool, table_width)
-    return sum(-(-live_pages(int(p), pool.shape[1], window) // pages)
+    return sum(-(-live_pages(int(p), pool_block_size(pool), window) // pages)
                for p in positions)
 
 
@@ -387,6 +536,48 @@ def _attend_grouped(q, kv, first, position, until, m_ref, l_ref, acc_ref,
         p, vals, preferred_element_type=f32)
 
 
+def _attend_planes(q, plane, first, position, until, m_ref, l_ref, acc_ref,
+                   *, n_kv: int, scale: float, window, kept=None):
+    """`_attend_grouped` over a pass's pages of a pool held by planes.
+    ``plane(p)`` is plane ``p`` of the pass's pages, ``[T, dv]`` float32
+    (key t at position ``first + t``): whole tiles, a key head's keys
+    apart from every other's. One product a key head: its ``group``
+    query rows against its keys, a key wider than ``dv`` as its slots'
+    products added (`kv_row`: slot 0, then slots 2 on); the heads'
+    scores stacked to ``[H, T]``. Every mask is one row of ``T`` lanes,
+    and no score is formed to be masked for its key head. Then a second
+    product a head, the probabilities against that head's values."""
+    f32 = jnp.float32
+    h, held = q.shape
+    group = h // n_kv
+    dv = acc_ref.shape[1]
+    key_slots = [0] + list(range(2, 1 + held // dv))
+
+    def scores_of(j):
+        rows = q[j * group:(j + 1) * group]
+        return sum(jax.lax.dot_general(
+            rows[:, i * dv:(i + 1) * dv], plane(slot * n_kv + j),
+            (((1,), (1,)), ((), ())), preferred_element_type=f32)
+            for i, slot in enumerate(key_slots))
+
+    scores = jnp.concatenate([scores_of(j) for j in range(n_kv)],
+                             axis=0) * scale                 # [H, T]
+    at = first + jax.lax.broadcasted_iota(jnp.int32, (1, scores.shape[1]), 1)
+    keep = _seen(at, position, until, window)                # [1, T]
+    if kept is not None:
+        keep &= _kept_lanes(kept, 1) > 0.5
+    scores = jnp.where(keep, scores, _NEG_INF)
+    m_prev = m_ref[...]
+    m_next = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_next)
+    p = jnp.exp(scores - m_next)                             # [H, T]
+    m_ref[...] = m_next
+    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+    acc_ref[...] = alpha * acc_ref[...] + jnp.concatenate(
+        [jnp.dot(p[j * group:(j + 1) * group], plane(n_kv + j),
+                 preferred_element_type=f32) for j in range(n_kv)], axis=0)
+
+
 # A row's walk, as `_row_walks` hands it to the kernel.
 _FIRST, _COUNT, _SIZE, _GROUPS, _BLOCK0 = range(5)
 
@@ -438,15 +629,40 @@ def _kernel_body(tables_ref, positions_ref, layer_ref, walks_ref, *refs,
     row, last_row = pl.program_id(0), pl.num_programs(0) - 1
     layer = layer_ref[0]
     pages = slabs.shape[1]
+    # A pool held by planes: a slab's page is ``[S * Hkv, bs, dv]``, and
+    # a layer's page in the pool one contiguous piece.
+    planes = len(slabs.shape) == 5
     a_pass = _pages_a_pass(pages, math.prod(slabs.shape[2:])
                            * jnp.dtype(slabs.dtype).itemsize)
+    # Copies are started and waited for `turn` a turn of their loop, in
+    # straight-line code (over planes; one a turn over rows, as ever).
+    turn = math.gcd(pages, _COPIES_A_TURN) if planes else 1
+
+    def page_of(block):
+        return (pool_ref.at[block, layer] if planes
+                else pool_ref.at[block, :, layer])
 
     def fetch(r, column, count, slab):
         def one(i, _):
             pltpu.make_async_copy(
-                pool_ref.at[tables_ref[r, lax.add(column, i)], :, layer],
+                page_of(tables_ref[r, lax.add(column, i)]),
                 slabs.at[slab, i], arrived.at[slab]).start()
-        lax.fori_loop(i32(0), count, one, None)
+
+        def some(t, _):
+            # A turn past the group's last page copies that page again,
+            # into a slot of the slab that no pass reads unmasked.
+            last = lax.sub(lax.add(column, count), i32(1))
+            for j in range(turn):
+                i = lax.add(lax.mul(t, i32(turn)), i32(j))
+                pltpu.make_async_copy(
+                    page_of(tables_ref[r, lax.min(lax.add(column, i), last)]),
+                    slabs.at[slab, i], arrived.at[slab]).start()
+        lax.fori_loop(i32(0), turns_of(count), one if turn == 1 else some,
+                      None)
+
+    def turns_of(count):
+        return count if turn == 1 else lax.div(
+            lax.add(count, i32(turn - 1)), i32(turn))
 
     @pl.when(lax.eq(row, i32(0)))
     def _first_row():
@@ -466,9 +682,13 @@ def _kernel_body(tables_ref, positions_ref, layer_ref, walks_ref, *refs,
                           own_keep_ref[row] if with_own_keep else None)
     position = positions_ref[row]
     first, n, size, n_groups, block0 = (walks_ref[row, k] for k in range(5))
-    attend = functools.partial(
-        _attend if q.shape[0] == k_new_ref.shape[0] else _attend_grouped,
-        scale=scale, window=window)
+    if planes:
+        attend = functools.partial(_attend_planes, n_kv=k_new_ref.shape[0],
+                                   scale=scale, window=window)
+    else:
+        attend = functools.partial(
+            _attend if q.shape[0] == k_new_ref.shape[0] else _attend_grouped,
+            scale=scale, window=window)
 
     @pl.when(lax.gt(n, i32(0)))
     def _attend_row():
@@ -499,10 +719,10 @@ def _kernel_body(tables_ref, positions_ref, layer_ref, walks_ref, *refs,
                                  walks_ref[following, _SIZE]), other)
 
             def arrive(i, _):
-                pltpu.make_async_copy(pool_ref.at[0, :, layer],
-                                      slabs.at[slab, 0],
-                                      arrived.at[slab]).wait()
-            lax.fori_loop(i32(0), count, arrive, None)
+                for _ in range(turn):
+                    pltpu.make_async_copy(page_of(0), slabs.at[slab, 0],
+                                          arrived.at[slab]).wait()
+            lax.fori_loop(i32(0), turns_of(count), arrive, None)
             # What the slab holds past the group's pages is another
             # group's, or nothing.
             at = lax.mul(lax.add(block0, done), i32(block_size))
@@ -511,8 +731,17 @@ def _kernel_body(tables_ref, positions_ref, layer_ref, walks_ref, *refs,
 
             def a_pass_over(c, _):
                 page = lax.mul(c, i32(a_pass))
-                kv = slabs[slab, pl.ds(page, a_pass)].astype(jnp.float32)
-                kv = kv.reshape((a_pass * block_size,) + kv.shape[2:])
+                if planes:
+                    # A plane of the pass's pages: whole tiles, and the
+                    # reshape moves nothing.
+                    def kv(p):
+                        return (slabs[slab, pl.ds(page, a_pass), p]
+                                .astype(jnp.float32)
+                                .reshape(a_pass * block_size,
+                                         slabs.shape[-1]))
+                else:
+                    kv = slabs[slab, pl.ds(page, a_pass)].astype(jnp.float32)
+                    kv = kv.reshape((a_pass * block_size,) + kv.shape[2:])
                 kept = {}
                 if with_keep:
                     # The pass's pages by their table columns.
@@ -558,9 +787,11 @@ def paged_decode_attention_kernel(q, k_new, v_new, pool, tables,
 
     b, h, dk = q.shape
     nb = tables.shape[1]
-    bs = pool.shape[1]
+    bs = pool_block_size(pool)
     hkv, dv = v_new.shape[1:]
-    if (pool.shape[3:] != (kv_slots(dk, dv), hkv, dv) or h % hkv
+    page = ((kv_slots(dk, dv) * hkv, bs, dv) if by_planes(pool)
+            else (bs, kv_slots(dk, dv), hkv, dv))
+    if (_page_shape(pool) != page or h % hkv
             or k_new.shape != (b, hkv, dk)):
         raise ValueError(f"pool {pool.shape} does not hold K rows of "
                          f"{(hkv, dk)} and V rows of {(hkv, dv)} for {h} "
@@ -575,7 +806,8 @@ def paged_decode_attention_kernel(q, k_new, v_new, pool, tables,
         prefetched.append(own_keep.astype(jnp.int32))
     if keep is not None and h == hkv:
         raise ValueError("a keep mask goes with grouped heads")
-    q, k_new = (_as_wide_as_the_pools_keys(x, pool) for x in (q, k_new))
+    q, k_new = (_as_wide_as_the_pools_keys(x, pool, hkv)
+                for x in (q, k_new))
     held = q.shape[2]
 
     def row_map(row, *prefetched_refs):
@@ -607,8 +839,7 @@ def paged_decode_attention_kernel(q, k_new, v_new, pool, tables,
             grid=(b,),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((None, h, dv), row_map),
-            scratch_shapes=[pltpu.VMEM((2, pages, bs) + pool.shape[3:],
-                                       pool.dtype),
+            scratch_shapes=[pltpu.VMEM((2, pages) + page, pool.dtype),
                             pltpu.SemaphoreType.DMA((2,)),
                             pltpu.SMEM((2,), jnp.int32),
                             pltpu.VMEM((h, 1), jnp.float32),
@@ -672,9 +903,19 @@ def sparse_paged_decode_attention(q, k_new, v_new, pool, tables, positions,
     reaches (PERF.md, Findings, PR 57; ROADMAP R13 a names the traffic
     that would earn it a place). The tests hold it against the walk."""
     del positions
-    n, bs = pool.shape[:2]
+    bs = pool_block_size(pool)
     slots, count = chosen_slots(keep, tables, bs, most)
-    rows = pool.reshape((n * bs, 1) + pool.shape[2:])
+    if by_planes(pool):
+        # A position's bytes lie a plane apart: the chosen rows are
+        # gathered into a pool of their own, a page a row, and the table
+        # names them in order.
+        picked = pool[slots // bs, layer, :, slots % bs]   # [B, most, P, dv]
+        rows = picked.reshape((-1, 1, 1, picked.shape[2] // k_new.shape[1],
+                               k_new.shape[1], picked.shape[3]))
+        slots = jnp.arange(slots.size, dtype=jnp.int32).reshape(slots.shape)
+        layer = jnp.int32(0)
+    else:
+        rows = pool.reshape((pool.shape[0] * bs, 1) + pool.shape[2:])
     if interpret is None and not kernel_eligible(*attention_widths(
             q.shape[1], q.shape[2], k_new.shape[1], v_new.shape[2])):
         return paged_decode_attention_xla(

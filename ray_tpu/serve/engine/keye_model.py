@@ -9,7 +9,9 @@ alone and passes one stream thrice), held experts behind a softmax router
 without a shared expert in every layer.
 
 What a position keeps: its keys and values, ``[layers, 2, Hkv, hd]`` in
-the cache's global group (`kv_token_shape`), and its index key,
+the cache's global group (`kv_token_shape`; at 4 key/value heads the
+pool is held by planes, ``[blocks, layers, 2 * Hkv, block_size, hd]``:
+`kv_planes`, `ops.paged_attention.held_by_planes`), and its index key,
 ``[layers, di]`` in rows of whole lanes (`index_row_width`: 64 values in
 128), in a pool that *rides* the global group's blocks
 (`kv_groups["index"]`, `kv_cache.py`: ``[blocks, layers, block_size,
@@ -66,7 +68,8 @@ class KeyeEngineModel(SparseEngineModel):
     """Incremental decoding over `models/keye_vl2.py` weights.
 
     KV entry a token: ``[n_layers, 2, n_kv_heads, head_dim]`` in the
-    global group and ``[n_layers, 128]`` in the index pool."""
+    global group (held by planes where `kv_planes` says so) and
+    ``[n_layers, 128]`` in the index pool."""
 
     # What the engine's `stats()` adds of this model's own (summed over
     # decode steps, rows x layers): positions the indexer scored, those
@@ -83,14 +86,18 @@ class KeyeEngineModel(SparseEngineModel):
     def __init__(self, params, cfg, max_batch_size: int = 8,
                  jit_cache_cap: int = 32):
         from ray_tpu.ops.paged_attention import (attention_widths,
+                                                 by_planes, held_by_planes,
                                                  kernel_eligible, page_groups)
         from ray_tpu.ops.sparse_attention import index_row_width
 
         super().__init__(params, cfg, jit_cache_cap)
-        self._page_groups = page_groups
+        self._page_groups, self._by_planes = page_groups, by_planes
         itemsize = self.kv_dtype.itemsize
         self.kv_token_shape = (cfg.n_layers, 2, cfg.n_kv_heads,
                                cfg.head_dim)
+        # How the cache holds the global group's pool, from the head
+        # count (`ops.paged_attention.held_by_planes`).
+        self.kv_planes = {GLOBAL: held_by_planes(cfg.n_kv_heads)}
         # An index key lies in a row of whole lanes (64 values in 128).
         self._index_row = index_row_width(cfg.index_dim)
         self.kv_groups = {INDEX: {"kv_shape": (cfg.n_layers, self._index_row),
@@ -211,22 +218,22 @@ class KeyeEngineModel(SparseEngineModel):
 
     def _selected_attention(self, q, keys, vals, qi, w, index_keys, offset,
                             live):
-        """A prompt's or a chunk's attention ``[H, Sq, hd]``: query ``i``
-        lies on key ``offset + i`` and of the keys the first `live`
-        exist; where they can be more than `index_topk`, under the
-        indexer's selection."""
+        """A prompt's or a chunk's attention ``[H, Sq, hd]``, q ``[Sq, H,
+        hd]`` over keys and vals ``[Hkv, Sk, hd]``: query ``i`` lies on
+        key ``offset + i`` and of the keys the first `live` exist; where
+        they can be more than `index_topk`, under the indexer's
+        selection."""
         from ray_tpu.ops.attention import prefill_attention
         from ray_tpu.ops.sparse_attention import (prefill_index_scores,
                                                   prefill_keep)
 
         keep = None
-        if keys.shape[0] > self._cfg.index_topk:
+        if keys.shape[1] > self._cfg.index_topk:
             scores = prefill_index_scores(qi.transpose(1, 0, 2), w,
                                           index_keys, offset)
             keep = prefill_keep(scores, offset, live, self._cfg.index_topk)
-        return prefill_attention(
-            q.transpose(1, 0, 2), keys.transpose(1, 0, 2),
-            vals.transpose(1, 0, 2), offset=offset, live=live, keep=keep)
+        return prefill_attention(q.transpose(1, 0, 2), keys, vals,
+                                 offset=offset, live=live, keep=keep)
 
     def _build_prefill(self, s_pad: int):
         import jax
@@ -237,7 +244,9 @@ class KeyeEngineModel(SparseEngineModel):
         def attend(q, k, v, qi, w, ki, layer):
             # A padded position lies after every live one: the causal
             # mask alone keeps it from a live query.
-            return self._selected_attention(q, k, v, qi, w, ki, 0, s_pad)
+            return self._selected_attention(
+                q, k.transpose(1, 0, 2), v.transpose(1, 0, 2), qi, w, ki, 0,
+                s_pad)
 
         def prefill(params, tokens, length):
             return self._prompt_layers(params, tokens, jnp.arange(s_pad),
@@ -251,7 +260,7 @@ class KeyeEngineModel(SparseEngineModel):
         import jax
         import jax.numpy as jnp
 
-        from ray_tpu.ops.paged_attention import kv_of_rows
+        from ray_tpu.ops.paged_attention import heads_of_pages
 
         self.jit_compiles += 1
         cfg, c = self._cfg, self.prefill_chunk_tokens
@@ -259,21 +268,24 @@ class KeyeEngineModel(SparseEngineModel):
         def prefill_chunk(pools, params, packed):
             tokens, start, length = packed[:c], packed[c], packed[c + 1]
             table = packed[c + 2:]
-            # What the pools hold of the positions before the chunk, a
-            # position a row (whatever a row from `start` on reads, no
-            # query sees it).
+            # What the pools hold of the positions before the chunk
+            # (whatever the pages hold from `start` on, no query sees
+            # it).
             with jax.named_scope("kv_gather"):
-                before = pools[GLOBAL][table].reshape(
-                    (-1,) + pools[GLOBAL].shape[2:])
                 index_before = pools[INDEX][table]     # [nb, L, bs, row]
             zero = jnp.int32(0)
 
             def attend(q, k, v, qi, w, ki, layer):
-                old_k, old_v = kv_of_rows(before[:, layer], cfg.head_dim)
-                at = (start, zero, zero)
+                with jax.named_scope("kv_gather"):
+                    old_k, old_v = heads_of_pages(
+                        pools[GLOBAL], table, layer, cfg.n_kv_heads,
+                        cfg.head_dim)
+                at = (zero, start, zero)
                 return self._selected_attention(
-                    q, jax.lax.dynamic_update_slice(old_k, k, at),
-                    jax.lax.dynamic_update_slice(old_v, v, at), qi, w,
+                    q, jax.lax.dynamic_update_slice(
+                        old_k, k.transpose(1, 0, 2), at),
+                    jax.lax.dynamic_update_slice(
+                        old_v, v.transpose(1, 0, 2), at), qi, w,
                     jax.lax.dynamic_update_slice(
                         index_before[:, layer].reshape(
                             s_keys, -1)[:, :cfg.index_dim],
@@ -296,7 +308,8 @@ class KeyeEngineModel(SparseEngineModel):
         import jax.numpy as jnp
 
         from ray_tpu.ops.paged_attention import (kv_row,
-                                                 paged_decode_attention)
+                                                 paged_decode_attention,
+                                                 write_rows)
         from ray_tpu.ops.sparse_attention import (own_index_scores,
                                                   paged_index_scores,
                                                   select_topk)
@@ -359,8 +372,8 @@ class KeyeEngineModel(SparseEngineModel):
                                   params["head"])
             with jax.named_scope("kv_write"):
                 new_pools = {
-                    GLOBAL: pools[GLOBAL].at[wblocks, woffs].set(
-                        jnp.stack(rows, axis=1), mode="drop"),
+                    GLOBAL: write_rows(pools[GLOBAL], wblocks, woffs,
+                                       jnp.stack(rows, axis=1)),
                     INDEX: pools[INDEX].at[
                         rider_slots(pools[INDEX], wblocks, woffs)].set(
                         jnp.stack(index_rows, axis=1), mode="drop")}
@@ -564,6 +577,8 @@ class KeyeEngineModel(SparseEngineModel):
             cached = sum(-(-int(p) // block_size) for p in positions)
             self.decode_attn_inplace_steps += 1
             self.decode_kv_pages_read += cached
+            if self._by_planes(pools[GLOBAL]):
+                self.decode_kv_pages_read_planes += cached
             self.decode_kv_page_groups_read += self._page_groups(
                 pools[GLOBAL], nb_pad, positions)
             index = ((self.index_token_bytes_held,

@@ -27,13 +27,32 @@ The manager owns two things:
   block tables and written lengths — `can_allocate` / `allocate` /
   `adopt` / `free` are what the iteration scheduler calls between
   decode steps;
-- **storage**: the preallocated `[num_blocks, block_size, *kv_shape]`
-  buffer itself. The engine's model reads it through block tables
+- **storage**: the preallocated buffer itself, in one of two layouts.
+  *Rows*, `[num_blocks, block_size, *kv_shape]`: a position's row in one
+  piece. *Planes* (a group declared so: the constructor's `planes`, a
+  further group's ``"planes": True``; device pools only), for a row
+  ``kv_shape = (L, ..., dv)``: `[num_blocks, L, planes, block_size, dv]`,
+  ``planes`` the product of the row's middle axes (a KV row ``(L, S,
+  Hkv, dv)`` has ``S * Hkv``), a layer's page in one piece and a block's
+  positions side by side in every plane: the layout of a pool that
+  rides, below, for a group's own pool. A model declares it for a group
+  whose key/value heads do not fill a float32 tile
+  (`ops.paged_attention.held_by_planes`: the engine reads the model's
+  `kv_planes`, group -> bool), and its programs tell the two by the
+  pool's rank. Nothing of the accounting differs, and a payload is a
+  position a row in both (`write_range`, `gather`); what differs is how
+  a row reaches the pool: by slots `(block, layer, plane, offset)`, the
+  indexed axes side by side, and a prefill's block-aligned range a whole
+  block at a time (`_write_planes`). Prefix shipping (`read_block`,
+  `install_block`) meets pools of rows alone and raises on the other:
+  the models that declare planes keep a window group or a pool that
+  rides, and the engine builds no prefix index over either. The
+  engine's model reads the buffer through block tables
   inside its own step, and where they lie: the transformer's decode
   attention fetches a row's blocks of one layer straight out of this
   buffer (`ops/paged_attention.py`; on the chip a Pallas kernel whose
-  block index is `table[row, page]`, so the `[block, slot, *kv_shape]`
-  layout here is that kernel's contract), and no step gathers a dense
+  block index is `table[row, page]`, so the layouts here are that
+  kernel's contract), and no step gathers a dense
   copy of a batch's cache. `paged_step` (a decode step: slots resolved,
   the donated pool re-bound), `mutate_pool` (a read-only step) and
   `with_pool` (the paged prefill) hand the live buffer to the dispatch
@@ -168,6 +187,8 @@ class _DevicePoolOps:
     def __init__(self, block_size: int, kv_shape: Tuple[int, ...]):
         import jax
 
+        from ray_tpu.ops.paged_attention import write_rows
+
         def copy_block(pool, dst, src):
             return pool.at[dst].set(pool[src])
 
@@ -189,10 +210,32 @@ class _DevicePoolOps:
             return pool.at[rider_slots(pool, blocks, offs)].set(
                 vals.astype(pool.dtype), mode="drop")
 
+        def scatter_planes(pool, blocks, offs, vals):
+            # The same write into a pool held by planes, `[N, L, P, bs,
+            # dv]`: a slot a (row, layer, plane).
+            return write_rows(pool, blocks, offs, vals.astype(pool.dtype))
+
+        def set_blocks_planes(pool, blocks, vals, tail_blocks, tail_offs,
+                              tail_from):
+            # A block-aligned range into a pool held by planes: payload
+            # block ``j`` (rows ``[j * bs, (j + 1) * bs)`` of `vals`)
+            # whole at ``blocks[j]`` (past the pool: dropped), then the
+            # `bs` rows from `tail_from` on by their slots (the ragged
+            # tail; a row past it names a block past the pool).
+            n, layers, planes, bs, dv = pool.shape
+            whole = vals.astype(pool.dtype).reshape(
+                -1, bs, layers, planes, dv).transpose(0, 2, 3, 1, 4)
+            pool = pool.at[blocks].set(whole, mode="drop")
+            tail = jax.lax.dynamic_slice_in_dim(vals, tail_from, bs)
+            return scatter_planes(pool, tail_blocks, tail_offs, tail)
+
         self.copy_block = jax.jit(copy_block, donate_argnums=0)
         self.set_block = jax.jit(set_block, donate_argnums=0)
         self.scatter = jax.jit(scatter, donate_argnums=0)
         self.scatter_rider = jax.jit(scatter_rider, donate_argnums=0)
+        self.scatter_planes = jax.jit(scatter_planes, donate_argnums=0)
+        self.set_blocks_planes = jax.jit(set_blocks_planes,
+                                         donate_argnums=0)
 
 
 def rider_slots(pool, blocks, offs):
@@ -253,7 +296,7 @@ class KVCacheManager:
                  kv_shape: Tuple[int, ...] = (), dtype=np.float32,
                  array_ns=None, state_shapes: Optional[dict] = None,
                  state_slots: int = 0, window: Optional[int] = None,
-                 groups: Optional[dict] = None):
+                 groups: Optional[dict] = None, planes: bool = False):
         """`state_shapes`: what the model keeps a sequence beside its KV
         rows, ``{name: (shape, dtype)}``; with it, `state_slots` slots
         of each, zeroed, in the pool's namespace. `groups`: the further
@@ -261,7 +304,10 @@ class KVCacheManager:
         each a manager of its own with this one's block size, dtype and
         namespace; `window`: this group's own (a sub-manager's). A group
         with ``"rides": True`` and a row ``kv_shape`` ``(L, *rest)`` is a
-        pool that rides this one's blocks (module docstring)."""
+        pool that rides this one's blocks (module docstring). `planes`
+        (a further group's ``"planes": True``): this group's own pool is
+        held a block, a layer and a plane at a time (module docstring:
+        storage)."""
         riders = {name: g for name, g in (groups or {}).items()
                   if g.get("rides")}
         groups = {name: g for name, g in (groups or {}).items()
@@ -284,15 +330,19 @@ class KVCacheManager:
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
         self.kv_shape = tuple(kv_shape)
+        self.planes = bool(planes)
         self._ns = array_ns if array_ns is not None else np
         self._device = self._ns is not np
+        if self.planes and not (self._device and len(self.kv_shape) >= 2):
+            raise ValueError(
+                "a pool held by planes needs a device pool and a row "
+                "(layers, ..., values): no host model declares one")
         self._dtype = dtype
         self._ops: Optional[_DevicePoolOps] = None
         if self._device:
             self._ops = _pool_ops(self.block_size, self.kv_shape)
         # THE preallocated cache: every sequence's KV lives here.
-        self._buffer = self._ns.zeros(
-            (self.num_blocks, self.block_size) + self.kv_shape, dtype)
+        self._buffer = self._ns.zeros(self._pool_shape(), dtype)
         # Data-movement honesty counters: `host_gathers` counts calls
         # that materialize per-sequence KV for host-side consumption
         # (`gather`: the engine makes none, and the benchmark asserts
@@ -319,7 +369,8 @@ class KVCacheManager:
         self._groups: Dict[str, "KVCacheManager"] = {
             name: KVCacheManager(
                 g["num_blocks"], block_size, tuple(g["kv_shape"]), dtype,
-                array_ns, window=g.get("window"))
+                array_ns, window=g.get("window"),
+                planes=g.get("planes", False))
             for name, g in (groups or {}).items()}
         if riders and not self._device:
             raise ValueError("a pool that rides needs a device pool: no "
@@ -358,6 +409,15 @@ class KVCacheManager:
                 for name, (shape, dt) in state_shapes.items()}
             self._free_slots = list(range(state_slots - 1, -1, -1))
             self._set_state = _state_writer() if self._device else None
+
+    def _pool_shape(self) -> Tuple[int, ...]:
+        """The own pool's shape: a position's row in one piece, or held
+        by planes (module docstring: storage)."""
+        if not self.planes:
+            return (self.num_blocks, self.block_size) + self.kv_shape
+        return (self.num_blocks, self.kv_shape[0],
+                math.prod(self.kv_shape[1:-1]), self.block_size,
+                self.kv_shape[-1])
 
     def set_reclaimer(self, reclaim: Optional[Callable[[int], int]],
                       evictable: Optional[Callable[[], int]] = None
@@ -637,11 +697,23 @@ class KVCacheManager:
         """Copy of one allocated block's contents
         (`[block_size, *kv_shape]`) — what prefix shipping exports. A
         copy, not a view: the frame outlives the lock, and the source
-        block may COW/evict underneath a view."""
+        block may COW/evict underneath a view. A pool of rows only
+        (`_no_planes_shipped`)."""
         with self._lock:
+            self._no_planes_shipped()
             if self._refs.get(block, 0) < 1:
                 raise ValueError(f"block {block} is not allocated")
             return np.array(np.asarray(self._buffer[block]))
+
+    def _no_planes_shipped(self) -> None:
+        """Prefix shipping meets pools of rows alone: the models whose
+        pools are held by planes adopt no prefix (they keep a window
+        group or a pool that rides), so no engine builds an index over
+        one."""
+        if self.planes:
+            raise ValueError(
+                "a block of a pool held by planes is not shipped: "
+                "read_block and install_block take rows")
 
     def install_block(self, values) -> Optional[int]:
         """Allocate one free block, fill it with `values`
@@ -650,6 +722,7 @@ class KVCacheManager:
         shipping (the caller hands the reference to the prefix index
         via `insert` + `release`). Asks the reclaimer under pressure
         like `allocate`; returns None when genuinely full."""
+        self._no_planes_shipped()
         values = np.asarray(values)
         expect = (self.block_size,) + self.kv_shape
         if tuple(values.shape) != expect:
@@ -718,26 +791,55 @@ class KVCacheManager:
             self._privatize_locked(seq_id, idx)
         return table[idx], off
 
-    def _pool_scatter(self, blocks: np.ndarray, offs: np.ndarray,
-                      values, n: int) -> None:
-        """ONE donated scatter for `n` token rows: a whole prefill
-        range (any number of blocks, any offsets) lands in a single
-        dispatch. Compiles are per row bucket:
-        rows past `n` point past the pool and drop. A payload whose
-        rows are on the device (`_device_rows`) is scattered as it
-        stands, its own padding being the bucket; a host payload pads
-        to a pow2 bucket in numpy (one transfer, one dispatch)."""
+    def _payload(self, values, n: int):
+        """`n` token rows as a device array of the pool's dtype, ``[>=
+        n, *kv_shape]``. A payload whose rows are on the device
+        (`_device_rows`) as it stands, its own padding being the bucket;
+        a host payload padded to a pow2 bucket in numpy (one
+        transfer)."""
         vals = _device_rows(values)
         if vals is not None:
-            vals = self._ns.asarray(vals, self._dtype)
-        else:
-            padded = np.zeros((_next_pow2(max(n, 1)),) + self.kv_shape,
-                              np.dtype(self._dtype))
-            padded[:n] = np.asarray(values)[:n]
-            vals = self._ns.asarray(padded)
-        self._buffer = self._ops.scatter(
+            return self._ns.asarray(vals, self._dtype)
+        padded = np.zeros((_next_pow2(max(n, 1)),) + self.kv_shape,
+                          np.dtype(self._dtype))
+        padded[:n] = np.asarray(values)[:n]
+        return self._ns.asarray(padded)
+
+    def _pool_scatter(self, blocks: np.ndarray, offs: np.ndarray,
+                      vals, n: int) -> None:
+        """ONE donated scatter for the first `n` rows of `vals`
+        (`_payload`): a whole prefill range (any number of blocks, any
+        offsets) lands in a single dispatch. Compiles are per row
+        bucket: rows past `n` point past the pool and drop."""
+        scatter = (self._ops.scatter_planes if self.planes
+                   else self._ops.scatter)
+        self._buffer = scatter(
             self._buffer, *self._padded_slots(blocks, offs, n,
                                               int(vals.shape[0])), vals)
+        self.pool_updates += 1
+
+    def _write_planes(self, start: int, skip: int, blocks: np.ndarray,
+                      offs: np.ndarray, vals, n: int) -> None:
+        """`_pool_scatter` into a pool held by planes, for rows ``[skip,
+        n)`` of a payload whose row 0 is position `start`. Where the
+        payload's blocks are the pool's (`start` on a block's edge, as a
+        prompt's and a chunk's is), every whole block is set in one
+        piece, ``[L, P, bs, dv]`` at its block, and only the ragged tail
+        goes by slots: a chunk of 1,024 positions is 64 such pieces,
+        where its slots are 1,024 x L x P pieces of `dv` values. One
+        dispatch, one compile a row bucket."""
+        bs = self.block_size
+        rows = int(vals.shape[0])
+        if start % bs or skip % bs or rows % bs:
+            return self._pool_scatter(blocks, offs, vals, n)
+        whole = n // bs
+        ids = np.full((rows // bs,), self.num_blocks, np.int32)
+        ids[skip // bs:whole] = blocks[skip:whole * bs:bs]
+        tail = slice(whole * bs, n)
+        self._buffer = self._ops.set_blocks_planes(
+            self._buffer, self._ns.asarray(ids), vals,
+            *self._padded_slots(blocks[tail], offs[tail], n - whole * bs,
+                                bs), np.int32(whole * bs))
         self.pool_updates += 1
 
     def _padded_slots(self, blocks, offs, n: int, rows: int):
@@ -767,9 +869,10 @@ class KVCacheManager:
             if self._ns is np:
                 self._buffer[block, off] = value
             else:
-                self._pool_scatter(np.asarray([block], np.int32),
-                                   np.asarray([off], np.int32),
-                                   np.asarray(value)[None], 1)
+                self._pool_scatter(
+                    np.asarray([block], np.int32),
+                    np.asarray([off], np.int32),
+                    self._payload(np.asarray(value)[None], 1), 1)
             self._lens[seq_id] = max(self._lens.get(seq_id, 0), pos + 1)
 
     def write_range(self, seq_id: str, start: int, values) -> None:
@@ -817,7 +920,11 @@ class KVCacheManager:
                     offs[i:i + take] = np.arange(off, off + take)
                     i += take
                     pos += take
-                self._pool_scatter(blocks, offs, values, n)
+                vals = self._payload(values, n)
+                if self.planes:
+                    self._write_planes(start, skip, blocks, offs, vals, n)
+                else:
+                    self._pool_scatter(blocks, offs, vals, n)
                 self._write_riders(blocks, offs, values, n)
             self._lens[seq_id] = max(self._lens.get(seq_id, 0), start + n)
             if state is not None and self._state is not None:
@@ -986,12 +1093,19 @@ class KVCacheManager:
             self.host_gathers += 1
             n = self._lens.get(seq_id, 0) if length is None else length
             if n == 0:
-                return self._buffer[0, 0:0]
+                return self._ns.zeros((0,) + self.kv_shape, self._dtype) \
+                    if self.planes else self._buffer[0, 0:0]
             nblocks = math.ceil(n / self.block_size)
             idx = np.asarray(self._tables.get(seq_id, ())[:nblocks],
                              np.int64)
             if self._ns is np:
                 out = self._buffer[idx].reshape(
+                    (nblocks * self.block_size,) + self.kv_shape)
+            elif self.planes:
+                # [nb, L, P, bs, dv] -> a position a row.
+                out = self._ns.reshape(
+                    self._buffer[self._ns.asarray(idx)].transpose(
+                        0, 3, 1, 2, 4),
                     (nblocks * self.block_size,) + self.kv_shape)
             else:
                 out = self._ns.reshape(

@@ -16,7 +16,13 @@ A row of a group is ``[layers of the group, S, Hkv, dv]``
 (`ops.paged_attention.kv_row`): the keys and the values of a layer kind's
 ``Hkv`` key/value heads in slots of the values' width, ``[K, V]`` where
 keys and values are of one width. The two groups' rows may differ in
-their key/value heads.
+their key/value heads, and with them in how the cache holds the group's
+pool (`kv_planes`: a group whose heads do not fill a float32 tile is
+held by planes, `ops.paged_attention.held_by_planes`; MiMo's global
+group on 4 heads is, its window group and both of Laguna's on 8 are
+not). The programs here read and write either layout
+(`paged_decode_attention`, `write_rows`, `heads_of_pages`); a group of
+rows runs what it always has.
 
 What is here: the prefill's, a prefill chunk's and the decode step's
 programs, the packed step buffer, the window table, the page and byte
@@ -78,12 +84,13 @@ class LayerGroupsEngineModel(SparseEngineModel):
     def __init__(self, params, cfg, max_batch_size: int = 8,
                  jit_cache_cap: int = 32):
         from ray_tpu.ops.paged_attention import (attention_widths,
+                                                 by_planes, held_by_planes,
                                                  kernel_eligible, kv_slots,
                                                  page_groups)
 
         super().__init__(params, cfg, jit_cache_cap)
-        self._page_groups = page_groups
-        rows, itemsize = {}, self.kv_dtype.itemsize
+        self._page_groups, self._by_planes = page_groups, by_planes
+        rows, planes, itemsize = {}, {}, self.kv_dtype.itemsize
         # A position's bytes in a group: as the pool holds it (whole
         # slots) and as the model counts it (its keys and values).
         self.kv_token_bytes_held: Dict[str, int] = {}
@@ -92,12 +99,15 @@ class LayerGroupsEngineModel(SparseEngineModel):
             _, dk, hkv, dv = self._attention_widths(full)
             layers = self._group_layers(full)
             rows[group] = (layers, kv_slots(dk, dv), hkv, dv)
+            # How the cache holds the group's pool, from its head count.
+            planes[group] = held_by_planes(hkv)
             self.kv_token_bytes_held[group] = math.prod(rows[group]) * itemsize
             self.kv_token_bytes_model[group] = (layers * hkv * (dk + dv)
                                                 * itemsize)
         self.kv_token_shape = rows[GLOBAL]
         self.kv_groups = {WINDOW: {"kv_shape": rows[WINDOW],
                                    "window": cfg.window}}
+        self.kv_planes = planes
         self._attn_inplace = all(
             kernel_eligible(*attention_widths(
                 *self._attention_widths(full)))
@@ -228,7 +238,8 @@ class LayerGroupsEngineModel(SparseEngineModel):
         import jax.numpy as jnp
 
         from ray_tpu.ops.attention import prefill_attention
-        from ray_tpu.ops.paged_attention import kv_of_rows
+        from ray_tpu.ops.paged_attention import (by_planes, heads_of_pages,
+                                                 kv_of_rows)
 
         self.jit_compiles += 1
         window = self._cfg.window
@@ -245,14 +256,19 @@ class LayerGroupsEngineModel(SparseEngineModel):
             # the window group's last `tail` (a row of a position the
             # group gave back lies outside every window; one before the
             # prompt's first is not live).
+            # (A pool held by planes is read a layer at a time, below.)
             with jax.named_scope("kv_gather"):
                 before = {group: pool[tables[group]].reshape(
-                    (-1,) + pool.shape[2:]) for group, pool in pools.items()}
+                    (-1,) + pool.shape[2:]) for group, pool in pools.items()
+                    if not by_planes(pool)}
             zero = jnp.int32(0)
 
             def attend(q, k, v, full, index, lp):
-                old_k, old_v = kv_of_rows(
-                    before[GLOBAL if full else WINDOW][:, index], k.shape[-1])
+                group = GLOBAL if full else WINDOW
+                if by_planes(pools[group]):
+                    return attend_by_heads(q, k, v, full, group, index, lp)
+                old_k, old_v = kv_of_rows(before[group][:, index],
+                                          k.shape[-1])
                 if full:
                     # The keys at their positions, the chunk's own among
                     # them; every key up to the chunk's last is live.
@@ -269,6 +285,29 @@ class LayerGroupsEngineModel(SparseEngineModel):
                     vals.transpose(1, 0, 2), None if full else window,
                     lp.get("sink"), offset=offset, live=live)
 
+            def attend_by_heads(q, k, v, full, group, index, lp):
+                """`attend` over a pool held by planes: the keys and
+                values come out a head at a time, ``[Hkv, nb * bs, ..]``,
+                as the forward takes them."""
+                with jax.named_scope("kv_gather"):
+                    old_k, old_v = heads_of_pages(
+                        pools[group], tables[group], index, k.shape[1],
+                        k.shape[-1])
+                k, v = k.transpose(1, 0, 2), v.transpose(1, 0, 2)
+                if full:
+                    at = (zero, start, zero)
+                    keys = jax.lax.dynamic_update_slice(old_k, k, at)
+                    vals = jax.lax.dynamic_update_slice(old_v, v, at)
+                    offset, live = start, start + c
+                else:
+                    keys = jnp.concatenate([old_k, k], axis=1)
+                    vals = jnp.concatenate([old_v, v], axis=1)
+                    offset, live = tail, jnp.minimum(start, tail) + c
+                return prefill_attention(
+                    q.transpose(1, 0, 2), keys, vals,
+                    None if full else window, lp.get("sink"),
+                    offset=offset, live=live)
+
             return self._prompt_layers(params, tokens,
                                        start + jnp.arange(c), length, attend)
 
@@ -281,7 +320,8 @@ class LayerGroupsEngineModel(SparseEngineModel):
         import jax.numpy as jnp
 
         from ray_tpu.ops.paged_attention import (kv_row,
-                                                 paged_decode_attention)
+                                                 paged_decode_attention,
+                                                 write_rows)
 
         self.jit_compiles += 1
         cfg, f32 = self._cfg, jnp.float32
@@ -324,8 +364,8 @@ class LayerGroupsEngineModel(SparseEngineModel):
                                   params["head"])
             with jax.named_scope("kv_write"):
                 new_pools = {
-                    group: pools[group].at[wblocks[group], woffs].set(
-                        jnp.stack(rows[full], axis=1), mode="drop")
+                    group: write_rows(pools[group], wblocks[group], woffs,
+                                      jnp.stack(rows[full], axis=1))
                     for group, full in ((GLOBAL, True), (WINDOW, False))}
             with jax.named_scope("sample"):
                 ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -460,6 +500,8 @@ class LayerGroupsEngineModel(SparseEngineModel):
                 self.decode_kv_pages_read += sum(cached) + in_window
                 for pages, group in ((sum(cached), GLOBAL),
                                      (in_window, WINDOW)):
+                    if self._by_planes(pools[group]):
+                        self.decode_kv_pages_read_planes += pages
                     self.decode_kv_bytes_read_held += (
                         pages * block_size * self.kv_token_bytes_held[group])
                     self.decode_kv_bytes_read_model += (

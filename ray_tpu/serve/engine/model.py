@@ -65,7 +65,13 @@ changes in three places, for that model alone: the KV result of
 ``"global"``) and ``block_tables[i]`` as ``{group: (base, table)}``, a
 window group's table compact from logical block ``base``, and returns
 ``(step, new_pools)``; and the engine builds no prefix index (an adopted
-prefix would need the window layers' rows at its end).
+prefix would need the window layers' rows at its end). Such a model may
+also declare ``kv_planes``, ``{group: bool}``: which of its groups' own
+pools the cache holds by planes (`kv_cache.py`, storage;
+`ops.paged_attention.held_by_planes` says from the group's key/value
+heads). The payloads stay a position a row; the pools the model's
+programs take and hand back are of the layout declared, and they tell
+the two by the pool's rank (`ops.paged_attention.by_planes`).
 
 A model may offer a fourth call, with the attribute
 ``prefill_chunk_tokens`` (``C``): ``prefill_chunk(tokens, pools, tables,

@@ -345,6 +345,10 @@ class InferenceEngine:
         # of sliding-window attention keep a window's rows, in a pool of
         # their own); the config says how many blocks each gets.
         kv_groups = getattr(model, "kv_groups", None) or {}
+        # And which of its groups' own pools are held by planes
+        # (`kv_planes`: group -> bool, from the group's key/value heads:
+        # `ops.paged_attention.held_by_planes`; `kv_cache.py`, storage).
+        kv_planes = getattr(model, "kv_planes", None) or {}
         sizes = self.config.group_blocks or {}
         # A pool that rides the global group's blocks has none of its
         # own to size (`kv_cache.py`).
@@ -356,12 +360,14 @@ class InferenceEngine:
         self.cache = KVCacheManager(
             self.config.num_blocks, self.config.block_size,
             kv_shape=tuple(getattr(model, "kv_token_shape", ())),
+            planes=kv_planes.get(KVCacheManager.GLOBAL, False),
             dtype=getattr(model, "kv_dtype", np.float32),
             array_ns=getattr(model, "kv_pool_ns", None),
             state_shapes=state_shapes,
             state_slots=self.config.max_batch_size if state_shapes else 0,
             groups={name: (group if name in riding
-                           else dict(group, num_blocks=sizes[name]))
+                           else dict(group, num_blocks=sizes[name],
+                                     planes=kv_planes.get(name, False)))
                     for name, group in kv_groups.items()})
         self.prefix_index: Optional[PrefixIndex] = None
         # Adopting blocks of KV restores a prefix only where KV is all a
@@ -1282,6 +1288,8 @@ class InferenceEngine:
                 self.model, "decode_kv_pages_read", 0),
             "decode_kv_page_groups_read": getattr(
                 self.model, "decode_kv_page_groups_read", 0),
+            "decode_kv_pages_read_planes": getattr(
+                self.model, "decode_kv_pages_read_planes", 0),
             "moe_local_assignments": getattr(
                 self.model, "moe_local_assignments", 0),
             "moe_expert_touches": getattr(

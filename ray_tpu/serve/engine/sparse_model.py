@@ -45,6 +45,9 @@ class SparseEngineModel:
         self.decode_attn_inplace_steps = 0
         self.decode_kv_pages_read = 0
         self.decode_kv_page_groups_read = 0
+        # Of `decode_kv_pages_read`, the pages read from a pool held by
+        # planes (`ops.paged_attention.by_planes`).
+        self.decode_kv_pages_read_planes = 0
         # The expert layers' counts over decode steps, summed over
         # layers, computed inside the step and fetched with its ids:
         # (token, expert) pairs on held experts; (layer, expert) pairs

@@ -103,7 +103,10 @@ def test_a_position_keeps_two_kinds_of_row(toy):
         48 * BLOCK * 3 * 2 * 2 * 16 * 4
     shapes = cache.with_pools(lambda pools: {k: v.shape
                                              for k, v in pools.items()})
-    assert shapes == {"global": (48, BLOCK, 3, 2, 2, 16),
+    # 2 key/value heads do not fill a float32 tile: the KV's own pool is
+    # held by planes (layer, slot x head), like the index keys'.
+    assert model.kv_planes == {"global": True}
+    assert shapes == {"global": (48, 3, 2 * 2, BLOCK, 16),
                       "index": (48, 3, BLOCK, 128)}
     assert set(cache.step_tables("nobody")) == {"global"}
 

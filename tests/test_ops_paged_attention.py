@@ -986,6 +986,10 @@ def test_experts_prompt_kernel_compiles_for_v5e_at_the_cells_buckets(
 # ---------------------------------------------------------------------------
 MIMO_GLOBAL_POOL = (10240, 16, 2, 3, 4, 128)    # 1.0 GB in bf16
 MIMO_WINDOW_POOL = (160, 16, 5, 3, 8, 128)      # 0.08 GB
+# The global group as the engine holds it since PR 58: 4 key/value heads
+# do not fill a float32 tile, so the pool lies by planes (layer, slot x
+# head), a layer's page of 48 KB in one piece.
+MIMO_GLOBAL_PLANES = (10240, 2, 3 * 4, 16, 128)
 
 
 @pytest.mark.parametrize("hkv, pool, window, nb_pad", [
@@ -1082,7 +1086,8 @@ def test_mimo_decode_step_compiles_for_v5e_with_both_pools_in_place(
     assert (160, 16) + model.kv_groups["window"]["kv_shape"] == \
         MIMO_WINDOW_POOL
     assert model.window_table_blocks(16) == 9
-    pools = {"global": jax.ShapeDtypeStruct(MIMO_GLOBAL_POOL, jnp.bfloat16,
+    assert model.kv_planes == {"global": True, "window": False}
+    pools = {"global": jax.ShapeDtypeStruct(MIMO_GLOBAL_PLANES, jnp.bfloat16,
                                             sharding=one_chip),
              "window": jax.ShapeDtypeStruct(MIMO_WINDOW_POOL, jnp.bfloat16,
                                             sharding=one_chip)}
@@ -1165,7 +1170,7 @@ def test_mimo_chunk_program_compiles_for_v5e_beside_both_pools(
     model = MimoEngineModel(params, cfg, max_batch_size=16)
     assert (model.prefill_chunk_tokens, model._chunk_tail_tokens()) == \
         (1024, 128)
-    pools = {"global": jax.ShapeDtypeStruct(MIMO_GLOBAL_POOL, jnp.bfloat16,
+    pools = {"global": jax.ShapeDtypeStruct(MIMO_GLOBAL_PLANES, jnp.bfloat16,
                                             sharding=one_chip),
              "window": jax.ShapeDtypeStruct(MIMO_WINDOW_POOL, jnp.bfloat16,
                                             sharding=one_chip)}
@@ -1179,4 +1184,142 @@ def test_mimo_chunk_program_compiles_for_v5e_beside_both_pools(
     assert text.count("held_experts_ffn_prefill") >= 6
     assert memory.temp_size_in_bytes < 200e6
     # The chunk's rows of both groups and the logits.
+    assert memory.output_size_in_bytes < 40e6
+
+
+# ---------------------------------------------------------------------------
+# a pool of 4 key/value heads held by planes (PR 58): the per-head body,
+# the cache's writes into such a pool, and `keye-vl-2.0-30b-a3b`'s two
+# programs over it at the published widths
+# ---------------------------------------------------------------------------
+KEYE_PLANES = (17408, 12, 2 * 4, 16, 128)       # 6.8 GB in bf16
+KEYE_INDEX_POOL = (17408, 12, 16, 128)
+
+
+@pytest.mark.parametrize("h, dk, pool, nb_pad, keep, sink", [
+    (32, 128, KEYE_PLANES, 1024, False, False),
+    (32, 128, KEYE_PLANES, 1024, True, False),
+    (64, 192, MIMO_GLOBAL_PLANES, 512, False, False),
+    (64, 192, MIMO_GLOBAL_PLANES, 512, True, True)],
+    ids=["keye", "keye_keep", "mimo_global_keys_of_192",
+         "keys_of_192_keep_sink"])
+def test_per_head_kernel_compiles_for_v5e_over_a_pool_held_by_planes(
+        one_chip, no_compile_cache, h, dk, pool, nb_pad, keep, sink):
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import paged_attention as pa
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def call(q, k, v, pool, tables, positions, layer, kept, own, logit):
+        return pa.paged_decode_attention_kernel(
+            q, k, v, pool, tables, positions, layer,
+            sink=logit if sink else None, keep=kept if keep else None,
+            own_keep=own if keep else None)
+
+    compiled = jax.jit(call).lower(
+        spec((16, h, dk)), spec((16, 4, dk), jnp.bfloat16),
+        spec((16, 4, 128), jnp.bfloat16), spec(pool, jnp.bfloat16),
+        spec((16, nb_pad), jnp.int32), spec((16,), jnp.int32),
+        spec((), jnp.int32), spec((16, nb_pad * 16), jnp.bool_),
+        spec((16,), jnp.bool_), spec((h,))).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "paged_decode_attention" in text
+    # The pool is an operand as it stands; beside it the keep mask's
+    # float32 rows, where there is one.
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        (8 << 20) if keep else (1 << 20))
+
+
+@pytest.mark.parametrize("rows", [1024, 256])
+def test_the_caches_writes_into_a_planes_pool_compile_for_v5e_in_place(
+        one_chip, no_compile_cache, rows):
+    """A prefill's range a block at a time with its ragged tail by slots
+    (`set_blocks_planes`), a range by slots alone and a copied block:
+    the pool is donated and aliased, and no program holds a second."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.serve.engine.kv_cache import _DevicePoolOps
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    ops = _DevicePoolOps(16, (12, 2, 4, 128))
+    pool = spec(KEYE_PLANES, jnp.bfloat16)
+    payload = spec((rows, 12, 2, 4, 128), jnp.bfloat16)
+    pool_bytes = int(np.prod(KEYE_PLANES)) * 2
+    for compiled in (
+            ops.set_blocks_planes.lower(
+                pool, spec((rows // 16,)), payload, spec((16,)), spec((16,)),
+                spec(())).compile(),
+            ops.scatter_planes.lower(pool, spec((rows,)), spec((rows,)),
+                                     payload).compile(),
+            ops.copy_block.lower(pool, spec(()), spec(())).compile()):
+        memory = compiled.memory_analysis()
+        assert pool_bytes <= memory.alias_size_in_bytes < 1.01 * pool_bytes
+        assert memory.temp_size_in_bytes < 64e6
+
+
+def _abstract_keye_model(one_chip, monkeypatch):
+    import json
+    import os
+
+    import jax
+
+    from benchmarks.harness import manifest
+    from ray_tpu.models.keye_vl2 import init_params
+    from ray_tpu.serve.engine import KeyeEngineModel
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    family = manifest.load_family("keye_vl2")
+    with open(os.path.join(manifest.ROOT, "benchmarks", "configs",
+                           "keye-vl-2.0-30b-a3b.json")) as f:
+        cfg = family.model_config(family.widths(json.load(f)))
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(on_chip, jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    model = KeyeEngineModel(params, cfg, max_batch_size=16)
+    assert model._attn_inplace and model.kv_planes == {"global": True}
+    assert model.kv_token_shape == (12, 2, 4, 128)
+    return model, params
+
+
+def test_keye_programs_compile_for_v5e_with_the_planes_pool_in_place(
+        one_chip, no_compile_cache, monkeypatch):
+    """The (16, 1024) decode step and the 16,384-key chunk program at the
+    published widths as the chip dispatches them, the KV pool held by
+    planes: the step aliases both pools to its outputs and holds a few
+    megabytes beside its arguments; the chunk reads the pools where they
+    lie, a layer's pages at a time, and holds no copy of a pool."""
+    import jax
+    import jax.numpy as jnp
+
+    model, params = _abstract_keye_model(one_chip, monkeypatch)
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pools = {"global": spec(KEYE_PLANES), "index": spec(KEYE_INDEX_POOL)}
+    both = (int(np.prod(KEYE_PLANES)) + int(np.prod(KEYE_INDEX_POOL))) * 2
+    compiled = model._build_decode_paged(16, 1024, 16).lower(
+        pools, params, spec((16, 4 + 1024), jnp.int32)).compile()
+    memory, text = compiled.memory_analysis(), compiled.as_text()
+    assert text.count("paged_decode_attention") >= 12
+    assert text.count("paged_index_scores") >= 12
+    assert both <= memory.alias_size_in_bytes < 1.01 * both
+    assert memory.temp_size_in_bytes < 100e6
+    compiled = model._build_prefill_chunk(16384, 16).lower(
+        pools, params, spec((1024 + 2 + 1024,), jnp.int32)).compile()
+    memory, text = compiled.memory_analysis(), compiled.as_text()
+    assert "jit_prefill_chunk" in text
+    assert text.count("flash_prefill_fwd_selected") >= 12
+    # The index keys of the positions before the chunk (50 MB), a
+    # layer's keys and values a head at a time, a chunk's activations.
+    assert memory.temp_size_in_bytes < 300e6
     assert memory.output_size_in_bytes < 40e6
