@@ -4050,6 +4050,11 @@ class ClusterRuntime:
                                     task_id: str, oid: str,
                                     inline: Optional[bytes] = None,
                                     node: Optional[str] = None) -> bool:
+        # The owner's side of `_execute_streaming`'s events, same `arg`
+        # (an item's index is its return index, the oid's tail), in the
+        # ring alone; None while nobody watches them.
+        arg = flight.stream_arg(oid)
+        began = time.monotonic() if arg is not None else 0.0
         entry = self._owned_entry(oid)
         if node:
             if node not in entry.nodes:
@@ -4063,6 +4068,10 @@ class ClusterRuntime:
         if gen is not None:
             gen._push(ObjectRef(ObjectID(bytes.fromhex(oid)),
                                 owner=self.address, runtime=self))
+        if began:
+            flight.record("stream", "item.recv",
+                          int((time.monotonic() - began) * 1e6), arg,
+                          t=began)
         return True
 
     async def handle_prune_object_location(self, conn: ServerConnection, *,
@@ -4836,11 +4845,27 @@ class ClusterRuntime:
                     idx += 1
                     oid = ObjectID.for_return(
                         TaskID(bytes.fromhex(task_id)), idx).hex()
-                    res = self._package_result(oid, item)
-                    fut = asyncio.run_coroutine_threadsafe(
-                        self._push_generator_item(owner_addr, task_id, res),
-                        loop)
-                    fut.result()
+                    # An item's way to its owner, stage by stage, while
+                    # someone watches the `stream` events (`arg` joins
+                    # them across the two threads here and the owner's
+                    # process); else the same three lines bare: sixteen
+                    # requests' spans cost the engine loop beside them
+                    # 2-3% of a step.
+                    arg = flight.stream_arg(oid)
+                    if arg is None:
+                        res = self._package_result(oid, item)
+                        asyncio.run_coroutine_threadsafe(
+                            self._push_generator_item(
+                                owner_addr, task_id, res), loop).result()
+                        continue
+                    with flight.span("stream", "item.submit", arg):
+                        res = self._package_result(oid, item)
+                        fut = asyncio.run_coroutine_threadsafe(
+                            self._push_generator_item(
+                                owner_addr, task_id, res, arg), loop)
+                    # A wait, not work: blocked until the owner answered.
+                    with flight.span("stream", "item.ack_wait", arg):
+                        fut.result()
                 return None
             except BaseException as e:  # noqa: BLE001
                 wrapped = (e if isinstance(e, RayTaskError)
@@ -4857,11 +4882,22 @@ class ClusterRuntime:
         return {"results": [], "done": True, "error_blob": error_blob}
 
     async def _push_generator_item(self, owner_addr: str, task_id: str,
-                                   res: dict) -> None:
+                                   res: dict,
+                                   arg: Optional[str] = None) -> None:
+        """One `generator_item` round trip to the owner, on the IO loop:
+        `item.rpc` in the flight ring, from this coroutine's first line
+        to the owner's answer. In the ring alone: the interval crosses
+        an await, where a profiler annotation's would hold whatever
+        else the loop ran meanwhile."""
+        began = time.monotonic() if arg is not None else 0.0
         client = await self._worker_client(owner_addr)
         await client.call("generator_item", task_id=task_id,
                           oid=res["oid"], inline=res.get("inline"),
                           node=res.get("node"), timeout=30.0)
+        if began:
+            flight.record("stream", "item.rpc",
+                          int((time.monotonic() - began) * 1e6), arg,
+                          t=began)
 
     # -- actor execution -----------------------------------------------
     def _apply_visible_chips(self, chips) -> None:

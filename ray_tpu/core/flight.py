@@ -59,7 +59,14 @@ ring-attached span), ``gc`` (collector pauses), ``loop`` (heartbeat
 scheduling delays), ``stall`` (finalized episodes), ``engine`` (the
 serve engine loop's phases), ``model`` (the engine model's host side of
 a prefill or decode call), ``train`` (a trainer loop's data wait and
-report).
+report), ``stream`` (a streamed task's item on its way to its owner:
+``item.submit`` (packaged and handed to the IO loop) / ``item.ack_wait``
+on the request's executor thread and ``item.rpc`` (the round trip to
+the owner, a plain ``record``: it crosses an await) on the IO loop of
+the process that produced it, ``item.recv`` / ``item.get`` in the
+owner's; every one's ``arg`` is ``"<task id, 12 hex digits>#<item>"``,
+which joins one request's events across threads and processes; made
+only while someone watches, see ``watched``).
 
 5. **Spans.** ``span(category, label)`` is the one way program code
    times an interval: a context manager that records the interval in
@@ -68,7 +75,9 @@ report).
    same interval sits in the profiler's host plane, on the profiler's
    clock, whenever anyone has a trace running. With ``into=dict,
    key=str`` its duration is also added to a plain float, from the same
-   two clock reads.
+   two clock reads. The prefix follows from the category: ``stream``
+   spans reach the profiler as ``st:<label>`` (``_PROFILER_PREFIX``
+   says why), every other category's as ``rt:<category>.<label>``.
 """
 
 from __future__ import annotations
@@ -109,6 +118,17 @@ def _env_enabled() -> bool:
 # Module-level guard, read directly by hot-path call sites:
 #   if flight.enabled: flight.record(...)
 enabled = _env_enabled()
+
+# Categories whose events are made only while someone watches them
+# (`watched`): several events a streamed item, on as many threads as
+# there are requests, all in line for the interpreter the engine loop
+# needs. Always on they cost the longest-context serve cell 2-3% of its
+# tokens a second and washed everything else out of the ring in under a
+# second. `watch` adds a category to the watched ones of this process
+# and of the processes spawned after the call.
+ENV_WATCH = "RAY_TPU_FLIGHT_WATCH"
+_ON_DEMAND = frozenset({"stream"})
+_watching = set(filter(None, os.environ.get(ENV_WATCH, "").split(",")))
 
 # Wall<->monotonic anchor for cross-process clock alignment: an event's
 # wall time is t_mono - anchor_mono + anchor_wall. Captured once per
@@ -166,6 +186,24 @@ def instant(category: str, label: str, arg: Any = None) -> None:
     record(category, label, 0, arg)
 
 
+# A span's name in the profiler: ``rt:<category>.<label>``, but for the
+# categories named here. The readers of ``rt:`` take every such event
+# on any thread: an idle instant of the device counts as attributed
+# when any thread is inside one, and each costs them a scan of the
+# device's idle gaps. ``stream`` spans run on as many threads as there
+# are requests, one or more nearly always open, several a token: under
+# ``rt:`` they would make that share read 100 whatever the engine loop
+# does, and take the scan from half a minute to minutes. They have a
+# prefix, and a reader, of their own.
+_PROFILER_PREFIX = {"stream": "st:"}
+
+
+def profiler_name(category: str, label: str) -> str:
+    prefix = _PROFILER_PREFIX.get(category)
+    return (f"rt:{category}.{label}" if prefix is None
+            else f"{prefix}{label}")
+
+
 # jax.profiler.TraceAnnotation, once JAX is imported in this process.
 # Never imported from here: a process that stays off JAX (the driver of
 # a chip run, the scheduler's module) must not be pulled onto it.
@@ -186,11 +224,14 @@ class span:
     given its duration (seconds) is added to ``into[key]`` from the same
     two clock reads, and it stays readable as ``.dur``. When JAX is
     imported the interval is also a profiler ``TraceAnnotation`` named
-    ``rt:<category>.<label>``: free when no trace is running, and in the
-    host plane of whatever trace is.
+    ``profiler_name(category, label)`` (``rt:<category>.<label>``;
+    ``st:<label>`` for the category ``stream``): free when no trace is
+    running, and in the host plane of whatever trace is.
 
-    ``enabled`` is the only guard: with the recorder off a span reads no
-    clock, records nothing and leaves ``into`` alone (``.dur`` is 0.0).
+    ``enabled`` is the only guard, and for an on-demand category
+    ``watched``: with the recorder off, or nobody watching, a span reads
+    no clock, records nothing and leaves ``into`` alone (``.dur`` is
+    0.0).
     ``arg`` may be set inside the block, for what is only known at its
     end."""
 
@@ -210,11 +251,13 @@ class span:
         self._ann = None
 
     def __enter__(self) -> "span":
-        if not enabled:
+        if not enabled or (self.category in _ON_DEMAND
+                           and not watched(self.category)):
             return self
         annotation = _annotation or _trace_annotation()
         if annotation is not None:
-            self._ann = annotation(f"rt:{self.category}.{self.label}")
+            self._ann = annotation(profiler_name(self.category,
+                                                 self.label))
             self._ann.__enter__()
         self._t0 = time.monotonic()
         return self
@@ -232,6 +275,42 @@ class span:
         if self.into is not None:
             self.into[self.key] += dur
         return False
+
+
+def watched(category: str) -> bool:
+    """Whether an on-demand category's events are made now: the
+    recorder is on and either `watch(category)` was called (here or in
+    an ancestor process) or a JAX profile is running in this process,
+    whose host plane the events are for."""
+    if not enabled:
+        return False
+    if category in _watching:
+        return True
+    annotation = _annotation or _trace_annotation()
+    return annotation is not None and annotation.is_enabled()
+
+
+def watch(category: str) -> None:
+    """Make an on-demand category's events from now on, in this process
+    AND processes spawned after this call (children read the env)."""
+    _watching.add(category)
+    os.environ[ENV_WATCH] = ",".join(sorted(_watching))
+
+
+def unwatch(category: str) -> None:
+    _watching.discard(category)
+    os.environ[ENV_WATCH] = ",".join(sorted(_watching))
+
+
+def stream_arg(oid: str) -> Optional[str]:
+    """The ``arg`` of a ``stream`` span, ``"<task>#<n>"``, from the hex
+    object id of a streamed task's n-th item (the task's id, then the
+    return index in eight digits); None while nobody watches the
+    category, which is how a call site with several events an item
+    asks once."""
+    if not watched("stream"):
+        return None
+    return f"{oid[:12]}#{int(oid[-8:], 16)}"
 
 
 def enable() -> None:

@@ -416,12 +416,14 @@ class InferenceEngine:
         # recorder is off): the phases, the older clocks (`prefill_s`;
         # the decode step's split into block tables / compiled step),
         # `step_s` (all of `engine.step`), and what is no
-        # phase: `thread_cpu_s`, `queue_wait_s`, `stream_wake_*`.
+        # phase: `thread_cpu_s`, `queue_wait_s`, `stream_wake_*`,
+        # `pending_wait_*`.
         self._clocks: Dict[str, float] = dict.fromkeys(
             _LEGACY_CLOCKS + tuple(f"{p}_s" for p in _STEP_PHASES)
             + ("window_release_s", "park_s", "other_s", "step_s",
                "thread_cpu_s",
-               "queue_wait_s", "stream_wake_s", "stream_wake_tokens"), 0.0)
+               "queue_wait_s", "stream_wake_s", "stream_wake_tokens",
+               "pending_wait_s", "pending_wait_tokens"), 0.0)
         self._cpu_thread: Optional[int] = None
         self._cpu_at = 0.0
         self._ttfts: List[float] = []
@@ -430,8 +432,9 @@ class InferenceEngine:
         # EngineOverloadedError.retry_after_s.
         self._finish_stamps: deque = deque(maxlen=64)
         # Decode tokens not yet handed to their streams, in order:
-        # (sequence, token, whether it was the sequence's last). Only
-        # the loop's thread touches it.
+        # (sequence, token, whether it was the sequence's last, its
+        # step's stamp on joining: perf_counter, 0.0 with the flight
+        # recorder off). Only the loop's thread touches it.
         self._pending: List[tuple] = []
         self.tokens_delivered_overlapped = 0
 
@@ -674,9 +677,16 @@ class InferenceEngine:
         if not pending:
             return 0
         self._pending = []
-        with flight.span("engine", "emit", len(pending), self._clocks,
-                         "emit_s"):
-            for seq, tok, last in pending:
+        clocks = self._clocks
+        if flight.enabled:
+            # How long the tokens waited here: one clock read a flush,
+            # against the one their step took when they joined.
+            now = time.perf_counter()
+            joined = [at for _, _, _, at in pending if at]
+            clocks["pending_wait_s"] += now * len(joined) - sum(joined)
+            clocks["pending_wait_tokens"] += len(joined)
+        with flight.span("engine", "emit", len(pending), clocks, "emit_s"):
+            for seq, tok, last, _ in pending:
                 seq.stream._push(tok)
                 if last:
                     self._close(seq)
@@ -1001,12 +1011,13 @@ class InferenceEngine:
             toks = self._greedy(logits)
         # What the next step needs of the tokens, at once; the streams
         # get them from its `meanwhile`.
+        joined = time.perf_counter() if flight.enabled else 0.0
         for seq, tok in zip(batch, toks):
             seq.all_tokens.append(tok)
             last = self._ended(seq)
             if last:
                 self._release(seq)
-            self._pending.append((seq, tok, last))
+            self._pending.append((seq, tok, last, joined))
         self.tokens_generated += b
 
     @staticmethod
@@ -1176,7 +1187,10 @@ class InferenceEngine:
         same iterations; `queue_wait_s` sums, over admissions, the time
         from entering the waiting queue to the start of the prefill;
         `stream_wake_s` / `stream_wake_tokens` sum, over tokens handed
-        to a consumer, the time from `TokenStream._push` to pickup.
+        to a consumer, the time from `TokenStream._push` to pickup;
+        `pending_wait_s` / `pending_wait_tokens`, over decode tokens,
+        the time from the end of the token's step (where it joins
+        `_pending`) to the flush that hands it to its stream.
         `prefill_s` is all of `_prefill`, `decode_s` a decode step up to
         its result on the host = `kv_gather_s` (the block-table build:
         no KV is gathered on the host) + `model_step_s` (the model's
@@ -1335,6 +1349,8 @@ class InferenceEngine:
             "queue_wait_s": clocks["queue_wait_s"],
             "stream_wake_s": clocks["stream_wake_s"],
             "stream_wake_tokens": int(clocks["stream_wake_tokens"]),
+            "pending_wait_s": clocks["pending_wait_s"],
+            "pending_wait_tokens": int(clocks["pending_wait_tokens"]),
         }
 
     @staticmethod

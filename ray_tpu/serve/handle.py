@@ -12,6 +12,8 @@ from __future__ import annotations
 import time
 from typing import Any, Optional
 
+from ray_tpu.core import flight
+
 
 class DeploymentResponse:
     def __init__(self, handle: "DeploymentHandle", replica_id: str, ref):
@@ -95,6 +97,18 @@ class DeploymentResponse:
         return self._ref
 
 
+def _get_item(ref) -> Any:
+    """A streamed item's value: the last of the item's `stream` events
+    (the others: `core/cluster_runtime.py:_execute_streaming`), while
+    someone watches them."""
+    import ray_tpu
+
+    if not flight.watched("stream"):
+        return ray_tpu.get(ref, timeout=60)
+    with flight.span("stream", "item.get", flight.stream_arg(ref.hex())):
+        return ray_tpu.get(ref, timeout=60)
+
+
 class DeploymentResponseGenerator:
     """Streaming counterpart of DeploymentResponse (reference:
     serve.handle DeploymentResponseGenerator): wraps the replica's
@@ -120,18 +134,14 @@ class DeploymentResponseGenerator:
         return self._gen.completed()
 
     def __iter__(self):
-        import ray_tpu
-
         try:
             for ref in self._gen:
-                yield ray_tpu.get(ref, timeout=60)
+                yield _get_item(ref)
         finally:
             self._complete()
 
     async def __aiter__(self):
         import asyncio
-
-        import ray_tpu
 
         end = object()   # StopIteration cannot cross a Future boundary
         it = iter(self._gen)
@@ -140,8 +150,7 @@ class DeploymentResponseGenerator:
                 ref = await asyncio.to_thread(next, it, end)
                 if ref is end:
                     return
-                yield await asyncio.to_thread(
-                    lambda r=ref: ray_tpu.get(r, timeout=60))
+                yield await asyncio.to_thread(_get_item, ref)
         finally:
             self._complete()
 

@@ -1554,3 +1554,64 @@ def test_the_victim_of_a_preemption_is_never_the_prompt_in_flight():
     _drive(eng)
     assert late.tokens_so_far() == model.oracle(LONG, 5)
     assert row.tokens_so_far() == model.oracle([5, 9, 3], 30)
+
+
+# ---------------------------------------------------------------------------
+# the wait in `_pending` (stats: `pending_wait_s`, `pending_wait_tokens`)
+# ---------------------------------------------------------------------------
+def _pending_wait(engine):
+    stats = engine.stats()
+    return stats["pending_wait_s"], stats["pending_wait_tokens"]
+
+
+def test_the_wait_in_pending_is_clocked_a_decode_token(recorder):
+    eng = InferenceEngine(TinyLM(), EngineConfig(block_size=4, num_blocks=64,
+                                                 max_batch_size=4))
+    assert _pending_wait(eng) == (0.0, 0)        # there from construction
+    streams = [eng.submit([3 + i, 5, 7], 9) for i in range(3)]
+    last = (0.0, 0)
+    while eng.step():
+        now = _pending_wait(eng)
+        assert now[0] >= last[0] and now[1] >= last[1]
+        last = now
+    assert all(len(s.tokens_so_far()) == 9 for s in streams)
+    stats = eng.stats()
+    seconds, tokens = _pending_wait(eng)
+    # Every decode token waited there, a prefill's never does.
+    assert tokens == stats["tokens_generated"] - stats["prefills"] == 24
+    assert seconds > 0
+    # Each waited at most from its step's end to the end of the run.
+    assert seconds < tokens * stats["loop_s"]
+    # No phase of the loop: the phases are the 19 they were.
+    assert len([k for k in stats if k.startswith("phase.")]) == 19
+
+
+def test_the_wait_in_pending_stands_still_with_the_recorder_off(recorder):
+    recorder.disable()
+    try:
+        eng = InferenceEngine(TinyLM(), EngineConfig(block_size=4,
+                                                     num_blocks=64))
+        stream = eng.submit([3, 4, 5, 6], 8)
+        _drive(eng)
+        assert len(stream.tokens_so_far()) == 8
+        assert _pending_wait(eng) == (0.0, 0)
+        assert all(at == 0.0 for *_, at in eng._pending)
+    finally:
+        recorder.enable()
+
+
+def test_a_token_that_joined_with_the_recorder_off_is_not_counted(recorder):
+    eng = InferenceEngine(TinyLM(), EngineConfig(block_size=4, num_blocks=64))
+    stream = eng.submit([3, 4, 5, 6], 8)
+    recorder.disable()
+    try:
+        eng.step()                # prefill and the first decode step
+        assert len(eng._pending) == 1
+    finally:
+        recorder.enable()
+    _drive(eng)
+    assert len(stream.tokens_so_far()) == 8
+    seconds, tokens = _pending_wait(eng)
+    # Seven decode tokens in all; the one that joined unstamped is left
+    # out and adds no time since the clock's zero.
+    assert tokens == 6 and 0 < seconds < 60
