@@ -34,6 +34,19 @@ Typical replica:
             stream = self.engine.submit(req["prompt"],
                                         req.get("max_new_tokens"))
             return [tok async for tok in stream]
+
+The engine drives a model through `prefill`, `prefill_paged` and
+`decode_paged` (`model.py` states the protocol). Two keywords of
+`decode_paged` are the scheduler's: `meanwhile`, which the model runs
+between a step's dispatch and the wait for its ids (the step before's
+tokens go to their streams there), and, for a model that has the
+parameter, `ahead`, by which a full batch's next step is dispatched
+before the last one's ids are read: it takes its tokens from those ids
+on the device and the call returns its own step unread
+(`DecodeStep.ids` waits when first looked at). A model without `ahead`
+is never called with it. `InferenceEngine.stats()` counts the steps that
+went out so (`decode_steps_ahead`, beside `paged_steps`) and the rows
+whose end was found a step late (`decode_ends_found_late`).
 """
 
 from ray_tpu.serve.engine.kv_cache import (CacheOverflowError,

@@ -18,9 +18,12 @@ delta rule's own products excepted (float32 at the highest precision);
 logits float32.
 
 A decode step is one compiled program, as the dense model's: in, one
-int32 array ``[b_pad, 5 + nb_pad]`` (token, position, write block, write
-offset, state slot, block table); out, one int32 array ``[b_pad + 3]``:
-the greedy ids and the step's three expert counters. The delta-rule
+int32 array ``[b_pad, 6 + nb_pad]`` (token, position, write block, write
+offset, state slot, block table, the row's place in the step before's
+ids or -1: `model.step_tokens`) and the step before's result where it
+lies on the device; out, one int32 array ``[width + 3]``: the greedy ids
+at the model's largest batch bucket's width and the step's three expert
+counters. The delta-rule
 layers of a step run in slot order over the whole state pool (a row's
 input scattered to its slot, the layer's output gathered back): the
 pool is read and written where it lies, a slot no row of the step uses
@@ -34,7 +37,8 @@ from typing import List, Sequence
 import numpy as np
 
 from ray_tpu.core import flight
-from ray_tpu.serve.engine.model import PromptKV, _next_pow2
+from ray_tpu.serve.engine.model import (PromptKV, _next_pow2,
+                                        place_sources, step_tokens)
 from ray_tpu.serve.engine.sparse_model import SparseEngineModel
 
 
@@ -72,7 +76,7 @@ class HybridEngineModel(SparseEngineModel):
         from ray_tpu.ops.paged_attention import (kernel_eligible,
                                                  page_groups)
 
-        super().__init__(params, cfg, jit_cache_cap)
+        super().__init__(params, cfg, jit_cache_cap, max_batch_size)
         self._page_groups = page_groups
         self._chunk = kda_chunk
         self.kv_token_shape = (cfg.n_periods, 2, cfg.n_kv_heads,
@@ -234,10 +238,10 @@ class HybridEngineModel(SparseEngineModel):
         hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
         per = self._kda_per_period
 
-        def decode_paged(pool, state, params, packed):
-            tokens, positions = packed[:, 0], packed[:, 1]
+        def decode_paged(pool, state, params, packed, before):
+            tokens, positions = step_tokens(packed, before), packed[:, 1]
             wblocks, woffs, slots = packed[:, 2], packed[:, 3], packed[:, 4]
-            tables = packed[:, 5:]
+            tables = packed[:, 5:-1]
             n_slots = state["s"].shape[0]
             # A padding row names slot `n_slots`: its scatter drops, it
             # routes to no expert, and what it gathers is thrown away.
@@ -318,7 +322,7 @@ class HybridEngineModel(SparseEngineModel):
                     new_kv.transpose(1, 0, 2, 3, 4), mode="drop")
             with jax.named_scope("sample"):
                 ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return jnp.concatenate([ids, counts]), logits, new_pool, state
+            return self._step_out(ids, counts, b_pad), logits, new_pool, state
 
         return jax.jit(decode_paged, donate_argnums=(0, 1))
 
@@ -340,7 +344,7 @@ class HybridEngineModel(SparseEngineModel):
                      write_blocks: Sequence[int],
                      write_offs: Sequence[int], block_size: int,
                      state=None, slots: Sequence[int] = (), *,
-                     meanwhile=None):
+                     meanwhile=None, ahead=None):
         """One fused step, as `TransformerEngineModel.decode_paged`,
         over both pools: `state` is the cache's state pool and
         `slots[i]` row i's slot (a list shorter than the batch leaves
@@ -350,11 +354,12 @@ class HybridEngineModel(SparseEngineModel):
         with flight.span("model", "decode", len(last_tokens)):
             return self._decode_paged(pool, block_tables, last_tokens,
                                       positions, write_blocks, write_offs,
-                                      block_size, state, slots, meanwhile)
+                                      block_size, state, slots, meanwhile,
+                                      ahead)
 
     def _decode_paged(self, pool, block_tables, last_tokens, positions,
                       write_blocks, write_offs, block_size: int, state,
-                      slots, meanwhile):
+                      slots, meanwhile, ahead):
         phase = self.phase
         b = len(last_tokens)
         self.decode_calls += 1
@@ -375,7 +380,7 @@ class HybridEngineModel(SparseEngineModel):
                     self._build_decode_paged(*key)
             # One host buffer, a row a sequence; a write block past the
             # pool and a slot past the state pool are dropped.
-            packed = np.zeros((b_pad, 5 + nb_pad), np.int32)
+            packed = np.zeros((b_pad, 6 + nb_pad), np.int32)
             packed[:, 2] = int(pool.shape[0])
             packed[:, 4] = int(state["s"].shape[0])
             for i in range(b):
@@ -387,7 +392,8 @@ class HybridEngineModel(SparseEngineModel):
             packed[:k, 2] = write_blocks[:k]
             packed[:k, 3] = write_offs[:k]
             packed[:min(len(slots), b), 4] = slots[:b]
+            place_sources(packed, ahead)
             args = (pool, state, self._params, packed)
-        step, (new_pool, new_state) = self._run_decode(fn, args, b, b_pad,
-                                                       meanwhile)
+        step, (new_pool, new_state) = self._run_decode(
+            fn, args, b, b_pad, meanwhile, ahead)
         return step, new_pool, new_state

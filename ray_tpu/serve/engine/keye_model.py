@@ -58,7 +58,8 @@ import numpy as np
 
 from ray_tpu.core import flight
 from ray_tpu.serve.engine.layer_groups_model import GLOBAL, PromptGroups
-from ray_tpu.serve.engine.model import PromptKV, _next_pow2
+from ray_tpu.serve.engine.model import (PromptKV, _next_pow2,
+                                        place_sources, step_tokens)
 from ray_tpu.serve.engine.sparse_model import SparseEngineModel
 
 INDEX = "index"
@@ -90,7 +91,7 @@ class KeyeEngineModel(SparseEngineModel):
                                                  kernel_eligible, page_groups)
         from ray_tpu.ops.sparse_attention import index_row_width
 
-        super().__init__(params, cfg, jit_cache_cap)
+        super().__init__(params, cfg, jit_cache_cap, max_batch_size)
         self._page_groups, self._by_planes = page_groups, by_planes
         itemsize = self.kv_dtype.itemsize
         self.kv_token_shape = (cfg.n_layers, 2, cfg.n_kv_heads,
@@ -339,11 +340,11 @@ class KeyeEngineModel(SparseEngineModel):
 
         kept = []       # a trace's: what each layer kept
 
-        def decode_paged(pools, params, packed):
+        def decode_paged(pools, params, packed, before):
             del kept[:]
-            tokens, positions, woffs, wblocks = (packed[:, i]
-                                                 for i in range(4))
-            tables = packed[:, 4:]
+            positions, woffs, wblocks = (packed[:, i] for i in range(1, 4))
+            tokens = step_tokens(packed, before)
+            tables = packed[:, 4:-1]
             # A padding row writes past both pools: it routes nowhere.
             valid = wblocks < pools[GLOBAL].shape[0]
             act = pools[GLOBAL].dtype
@@ -379,11 +380,13 @@ class KeyeEngineModel(SparseEngineModel):
                         jnp.stack(index_rows, axis=1), mode="drop")}
             with jax.named_scope("sample"):
                 ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return jnp.concatenate([ids, counts]), logits, new_pools
+            return self._step_out(ids, counts, b_pad), logits, new_pools
 
         if probe:
             def kept_by_layer(pools, params, packed):
-                decode_paged(pools, params, packed)
+                # Every token from the host: no step before.
+                decode_paged(pools, params, packed, jnp.zeros(
+                    (self._ids_width(b_pad) + self._ids_trail,), jnp.int32))
                 if not kept:      # nothing to select: every live position
                     cached = (jnp.arange(s_pad + 1)[None, :]
                               < packed[:, 1:2])
@@ -472,7 +475,7 @@ class KeyeEngineModel(SparseEngineModel):
                      last_tokens: Sequence[int],
                      positions: Sequence[int], write_blocks: dict,
                      write_offs: dict, block_size: int, *,
-                     meanwhile=None):
+                     meanwhile=None, ahead=None):
         """One fused step. `pools` is ``{"global": KV pool, "index":
         index pool}``, `write_blocks` and `write_offs` name the global
         group's slots (the index pool's are the same), ``block_tables[i]``
@@ -483,11 +486,11 @@ class KeyeEngineModel(SparseEngineModel):
         with flight.span("model", "decode", len(last_tokens)):
             return self._decode_paged(pools, block_tables, last_tokens,
                                       positions, write_blocks, write_offs,
-                                      block_size, meanwhile)
+                                      block_size, meanwhile, ahead)
 
     def _decode_paged(self, pools, block_tables, last_tokens, positions,
                       write_blocks, write_offs, block_size: int,
-                      meanwhile):
+                      meanwhile, ahead):
         b = len(last_tokens)
         self.decode_calls += 1
         with flight.span("model", "decode.prep", None, self.phase,
@@ -504,8 +507,10 @@ class KeyeEngineModel(SparseEngineModel):
             k = min(len(write_blocks.get(GLOBAL, ())), b)
             packed[:k, 2] = write_offs[GLOBAL][:k]
             packed[:k, 3] = write_blocks[GLOBAL][:k]
+            place_sources(packed, ahead)
             args = (pools, self._params, packed)
-        step, (new_pools,) = self._run_decode(fn, args, b, b_pad, meanwhile)
+        step, (new_pools,) = self._run_decode(fn, args, b, b_pad, meanwhile,
+                                              ahead)
         return step, new_pools
 
     def probe_selection(self, pools, block_tables: List[dict],
@@ -543,9 +548,11 @@ class KeyeEngineModel(SparseEngineModel):
                    nb_pad: int):
         """The step's one host buffer, a row a sequence: token, position,
         write offset, write block (past the pools: dropped, until the
-        caller names a slot), the table."""
-        packed = np.zeros((b_pad, 4 + nb_pad), np.int32)
+        caller names a slot), the table, the row's place in the step
+        before's ids (none, until the caller names one)."""
+        packed = np.zeros((b_pad, 5 + nb_pad), np.int32)
         packed[:, 3] = int(pools[GLOBAL].shape[0])
+        packed[:, -1] = -1
         for i, (token, position) in enumerate(zip(last_tokens, positions)):
             table = block_tables[i][GLOBAL][1][:nb_pad]
             packed[i, 0], packed[i, 1] = token, position

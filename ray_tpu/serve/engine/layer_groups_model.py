@@ -30,11 +30,14 @@ counters by group. What a model keeps: its layers in order (`_layers`),
 a layer kind's widths (`_attention_widths`) and rotary tables (`_rope`),
 `_qkv`, and the mixer's way out (`_mixer_out`).
 
-A decode step is one compiled program: in, one int32 array ``[b_pad, 6 +
+A decode step is one compiled program: in, one int32 array ``[b_pad, 7 +
 nb_pad + window_blocks]`` (token, position, write offset, the global and
 the window group's write blocks, the window table's first logical block,
-the global table, the window table); out, one int32 array ``[b_pad +
-3]``: the greedy ids and the step's three expert counters.
+the global table, the window table, the row's place in the step before's
+ids or -1: `model.step_tokens`) and the step before's result where it
+lies on the device; out, one int32 array ``[width + 3]``: the greedy ids
+at the model's largest batch bucket's width and the step's three expert
+counters.
 """
 
 from __future__ import annotations
@@ -45,7 +48,8 @@ from typing import Dict, List, Sequence
 import numpy as np
 
 from ray_tpu.core import flight
-from ray_tpu.serve.engine.model import PromptKV, _next_pow2
+from ray_tpu.serve.engine.model import (PromptKV, _next_pow2,
+                                        place_sources, step_tokens)
 from ray_tpu.serve.engine.sparse_model import SparseEngineModel
 
 GLOBAL, WINDOW = "global", "window"
@@ -88,7 +92,7 @@ class LayerGroupsEngineModel(SparseEngineModel):
                                                  kernel_eligible, kv_slots,
                                                  page_groups)
 
-        super().__init__(params, cfg, jit_cache_cap)
+        super().__init__(params, cfg, jit_cache_cap, max_batch_size)
         self._page_groups, self._by_planes = page_groups, by_planes
         rows, planes, itemsize = {}, {}, self.kv_dtype.itemsize
         # A position's bytes in a group: as the pool holds it (whole
@@ -326,13 +330,13 @@ class LayerGroupsEngineModel(SparseEngineModel):
         self.jit_compiles += 1
         cfg, f32 = self._cfg, jnp.float32
 
-        def decode_paged(pools, params, packed):
-            tokens, positions, woffs = (packed[:, 0], packed[:, 1],
-                                        packed[:, 2])
+        def decode_paged(pools, params, packed, before):
+            tokens, positions, woffs = (step_tokens(packed, before),
+                                        packed[:, 1], packed[:, 2])
             wblocks = {GLOBAL: packed[:, 3], WINDOW: packed[:, 4]}
             starts = packed[:, 5]
             tables = {GLOBAL: packed[:, 6:6 + nb_pad],
-                      WINDOW: packed[:, 6 + nb_pad:]}
+                      WINDOW: packed[:, 6 + nb_pad:-1]}
             # A padding row writes past both pools: it routes nowhere.
             valid = wblocks[GLOBAL] < pools[GLOBAL].shape[0]
             act = pools[GLOBAL].dtype
@@ -369,7 +373,7 @@ class LayerGroupsEngineModel(SparseEngineModel):
                     for group, full in ((GLOBAL, True), (WINDOW, False))}
             with jax.named_scope("sample"):
                 ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return jnp.concatenate([ids, counts]), logits, new_pools
+            return self._step_out(ids, counts, b_pad), logits, new_pools
 
         return jax.jit(decode_paged, donate_argnums=(0,))
 
@@ -463,7 +467,7 @@ class LayerGroupsEngineModel(SparseEngineModel):
                      last_tokens: Sequence[int],
                      positions: Sequence[int], write_blocks: dict,
                      write_offs: dict, block_size: int, *,
-                     meanwhile=None):
+                     meanwhile=None, ahead=None):
         """One fused step over both layer groups. `pools`, `write_blocks`
         and `write_offs` are dicts a group (`KVCacheManager.paged_step`
         over groups); ``block_tables[i]`` is row i's ``{group: (base,
@@ -473,11 +477,11 @@ class LayerGroupsEngineModel(SparseEngineModel):
         with flight.span("model", "decode", len(last_tokens)):
             return self._decode_paged(pools, block_tables, last_tokens,
                                       positions, write_blocks, write_offs,
-                                      block_size, meanwhile)
+                                      block_size, meanwhile, ahead)
 
     def _decode_paged(self, pools, block_tables, last_tokens, positions,
                       write_blocks, write_offs, block_size: int,
-                      meanwhile):
+                      meanwhile, ahead):
         b = len(last_tokens)
         self.decode_calls += 1
         window = self._cfg.window
@@ -520,7 +524,7 @@ class LayerGroupsEngineModel(SparseEngineModel):
                     self._build_decode_paged(*key)
             # One host buffer, a row a sequence; a write block past a
             # pool is dropped.
-            packed = np.zeros((b_pad, 6 + nb_pad + tw), np.int32)
+            packed = np.zeros((b_pad, 7 + nb_pad + tw), np.int32)
             packed[:, 3] = int(pools[GLOBAL].shape[0])
             packed[:, 4] = int(pools[WINDOW].shape[0])
             for i in range(b):
@@ -536,6 +540,8 @@ class LayerGroupsEngineModel(SparseEngineModel):
             packed[:k, 2] = write_offs[GLOBAL][:k]
             packed[:k, 3] = write_blocks[GLOBAL][:k]
             packed[:k, 4] = write_blocks[WINDOW][:k]
+            place_sources(packed, ahead)
             args = (pools, self._params, packed)
-        step, (new_pools,) = self._run_decode(fn, args, b, b_pad, meanwhile)
+        step, (new_pools,) = self._run_decode(fn, args, b, b_pad, meanwhile,
+                                              ahead)
         return step, new_pools

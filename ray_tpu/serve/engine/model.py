@@ -34,6 +34,30 @@ through block tables; the engine never gathers a sequence's KV for it:
   Keyword-only, because the families' further arguments (``state``,
   ``slots``) trail the positional ones. None runs nothing.
 
+  A model whose step runs on a device may take a second keyword,
+  ``ahead``, by which the scheduler dispatches a step BEFORE it has read
+  the step before's ids (`scheduler.py`: behind a full batch none of
+  whose rows is known to end). ``ahead = (before, sources)``: ``before``
+  is the `DecodeStep` an earlier call returned and nobody has read, or
+  None where there is none (the first of a run); ``sources[i]`` is row
+  i's place in ``before``'s ids, or -1 for ``last_tokens[i]``. With it
+  the call (a) takes a row's token from ``before``'s ids where they lie
+  on the device wherever a place is given, in the bucket's ONE program
+  (it always takes an array of the step before's ids, zeros where every
+  token is the host's, and always returns its own at that width: no
+  second program, nothing to compile in a serving window); (b) once
+  dispatched, and after ``meanwhile``, waits for ``before``'s ids where
+  it would have waited for its own (the same ``decode.logits_wait``
+  span; no wait where ``before`` is None); (c) returns its own step
+  UNREAD: a `DecodeStep` whose ``ids`` wait for the device when first
+  looked at, and whose ``on_device`` the next call takes as
+  ``before``. The model holds nothing between two calls: the scheduler
+  holds the step in flight and hands it back. Without the keyword a
+  call does what it always did and its step comes back read. A model
+  whose ``decode_paged`` has no parameter of that name (`TinyLM`, a
+  user's own) is never called with it, and its every step is read
+  before the next is dispatched.
+
 Beside them, three attributes: ``kv_token_shape`` and ``kv_dtype`` (a
 pool row) and ``kv_pool_ns``, the array namespace the model's step reads
 its pool in (numpy or `jax.numpy`; absent means numpy). It is no option:
@@ -196,11 +220,20 @@ class PromptKV:
 
 
 class DecodeStep:
-    """A paged decode step's result as its jit returned it: `ids`, the
-    `[len()]` greedy tokens the program sampled on the device
-    (`jnp.argmax`, ties to the lowest index as in `np.argmax`), already
-    on the host, and the step's `[b_pad, V]` float32 logits, left on the
-    device.
+    """A paged decode step's result as its jit returned it: the int32
+    array its program sampled on the device (the greedy ids at a fixed
+    width, `jnp.argmax`, ties to the lowest index as in `np.argmax`;
+    behind them whatever else the model's program put there) and the
+    step's `[b_pad, V]` float32 logits, both still on the device.
+
+    `ids` is the `[len()]` greedy tokens on the host. A plain
+    `decode_paged` call has read them before it returns; a call with
+    `ahead` hands its step back UNREAD, and the first look at `ids`
+    waits for the device and fetches them (`model._fetch_ids`: the
+    `decode.logits_wait` span, the bytes and the counters the program
+    sent along are counted there, once, when the step is read).
+    `on_device` is the array itself, which the next step's program takes
+    its tokens from without the host's having seen them.
 
     The scheduler's sampler takes `ids` and nothing else crosses.
     `np.asarray()` is the `[len(), V]` host logits for whoever asks (a
@@ -208,23 +241,92 @@ class DecodeStep:
     test): one fetch of the padded bucket, counted in the model's
     `decode_d2h_bytes`, cut on the host and kept."""
 
-    __slots__ = ("ids", "_logits", "_model")
+    __slots__ = ("on_device", "_ids", "_n", "_logits", "_model")
 
-    def __init__(self, ids: np.ndarray, logits, model):
-        self.ids = ids
+    def __init__(self, on_device, n: int, logits, model):
+        self.on_device = on_device
+        self._ids = None
+        self._n = n
         self._logits = logits
         self._model = model
 
+    @property
+    def ids(self) -> np.ndarray:
+        if self._ids is None:
+            self._ids = self._model._fetch_ids(self.on_device)[:self._n]
+        return self._ids
+
     def __len__(self) -> int:
-        return len(self.ids)
+        return self._n
 
     def __array__(self, dtype=None, copy=None):
         if not isinstance(self._logits, np.ndarray):
             padded = np.asarray(self._logits)
             self._model.decode_d2h_bytes += padded.nbytes
-            self._logits = padded[:len(self.ids)]
+            self._logits = padded[:self._n]
         host = self._logits
         return host if dtype is None else host.astype(dtype, copy=False)
+
+
+def step_tokens(packed, before):
+    """Inside a decode program: the rows' input tokens. `packed`'s first
+    column is a row's token as the host knew it, its LAST column the
+    row's place in `before` (the int32 array the step before's program
+    returned, `DecodeStep.on_device`) or -1: where a place is given the
+    token comes from there, and the host has not seen it."""
+    import jax.numpy as jnp
+
+    src = packed[:, -1]
+    return jnp.where(src >= 0, before[jnp.maximum(src, 0)], packed[:, 0])
+
+
+def place_sources(packed: np.ndarray, ahead) -> None:
+    """The host's side of `step_tokens`: `packed`'s last column from the
+    call's `ahead` (`(before, sources)` or None)."""
+    packed[:, -1] = -1
+    if ahead is not None and ahead[0] is not None:
+        packed[:len(ahead[1]), -1] = ahead[1]
+
+
+class StepIds:
+    """What the device models share of a decode step's int32 result
+    (the base of `TransformerEngineModel` and of `SparseEngineModel`,
+    which set `_max_batch` and `_jnp`): how wide a bucket's program
+    returns its ids, and what it takes as the step before's."""
+
+    # What a program returns behind its ids (expert counters).
+    _ids_trail = 0
+
+    def _ids_width(self, b_pad: int) -> int:
+        """How wide a bucket's program returns its ids, and takes the
+        step before's: the model's largest batch bucket (a bucket of more
+        rows than the model was built for, its own)."""
+        return max(_next_pow2(self._max_batch), b_pad)
+
+    def _before(self, ahead, b_pad: int):
+        """What a step's program takes as the step before's result: the
+        one `ahead` names, where it lies on the device; for a step that
+        takes every token from the host zeros, on the device, made once
+        a width."""
+        if ahead is not None and ahead[0] is not None:
+            return ahead[0].on_device
+        width = self._ids_width(b_pad) + self._ids_trail
+        zeros = getattr(self, "_zeros", None)
+        if zeros is None:
+            zeros = self._zeros = {}
+        if width not in zeros:
+            zeros[width] = self._jnp.zeros((width,), self._jnp.int32)
+        return zeros[width]
+
+
+def read_after_dispatch(step: DecodeStep, ahead) -> None:
+    """What a `decode_paged` call waits for once its step is on the
+    device: its own step's ids, or with `ahead` the step before's (none
+    where there is no step before: the call returns at once)."""
+    if ahead is None:
+        step.ids
+    elif ahead[0] is not None:
+        ahead[0].ids
 
 
 class TinyLM:
@@ -371,7 +473,7 @@ class TinyLM:
         return out
 
 
-class TransformerEngineModel:
+class TransformerEngineModel(StepIds):
     """Incremental KV decoding over `models/transformer.py` weights.
 
     KV entry per token: ``[n_layers, 2, n_heads, head_dim]`` float32.
@@ -785,25 +887,31 @@ class TransformerEngineModel:
         and no KV payload crossing the host boundary in either
         direction.
 
-        What does cross: in, ONE int32 array `packed` `[b_pad, 4 +
+        What does cross: in, ONE int32 array `packed` `[b_pad, 5 +
         nb_pad]`, a row a sequence: token, position, write block, write
-        offset, then its block table. Out, the `[b_pad]` int32 greedy
-        ids. The `[b_pad, V]` float32 logits are an output too, and stay
-        on the device unless fetched (`DecodeStep`)."""
+        offset, then its block table, then the row's place in `before`
+        or -1. Out, the `[width]` int32 greedy ids (`width` the model's
+        largest batch bucket, whatever this bucket's rows: what the next
+        step's program takes as `before`, so a bucket has ONE program
+        whether its tokens come from the host or from the step before's
+        ids on the device: `step_tokens`). The `[b_pad, V]` float32
+        logits are an output too, and stay on the device unless fetched
+        (`DecodeStep`)."""
         import jax
         import jax.numpy as jnp
 
         self.jit_compiles += 1
+        width = self._ids_width(b_pad)
 
-        def decode_paged(pool, params, packed):
+        def decode_paged(pool, params, packed, before):
             # tables [b_pad, nb_pad], zero-padded (rows past the batch
             # and blocks past a row's coverage name block 0; attention
             # masks by position, so nothing of it is read). wblocks
             # padding rows point past the pool, so mode="drop" skips
             # them — dummy batch rows never touch real blocks.
-            tokens, positions = packed[:, 0], packed[:, 1]
+            tokens, positions = step_tokens(packed, before), packed[:, 1]
             wblocks, woffs = packed[:, 2], packed[:, 3]
-            tables = packed[:, 4:]
+            tables = packed[:, 4:-1]
             logits, new_kv = self._decode_math(
                 params, tokens, positions, pool, tables, b_pad)
             with jax.named_scope("kv_write"):
@@ -811,9 +919,18 @@ class TransformerEngineModel:
                     new_kv.astype(pool.dtype), mode="drop")
             with jax.named_scope("sample"):
                 ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return ids, logits, new_pool
+            return jnp.pad(ids, (0, width - b_pad)), logits, new_pool
 
         return jax.jit(decode_paged, donate_argnums=0)
+
+    def _fetch_ids(self, on_device) -> np.ndarray:
+        """A decode step is read (`DecodeStep.ids`): the wait for the
+        device and the ids' trip to the host."""
+        with flight.span("model", "decode.logits_wait", None, self.phase,
+                         "decode_wait_s"):
+            ids = np.asarray(on_device)
+            self.decode_d2h_bytes += ids.nbytes
+        return ids
 
     # -- engine interface ----------------------------------------------
     def prefill(self, tokens: Sequence[int]):
@@ -851,7 +968,7 @@ class TransformerEngineModel:
                      positions: Sequence[int],
                      write_blocks: Sequence[int],
                      write_offs: Sequence[int], block_size: int, *,
-                     meanwhile=None):
+                     meanwhile=None, ahead=None):
         """One fused incremental step reading KV straight out of the
         device pool and writing the new tokens' KV back in-place. The
         step crosses the host boundary once each way with a few
@@ -869,15 +986,21 @@ class TransformerEngineModel:
         may be shorter than the batch; missing rows (and batch padding
         rows) scatter past the pool and are dropped, so an empty write
         list is a read-only step. `meanwhile` runs once the step is
-        dispatched, before the wait for its ids: beside a busy device."""
+        dispatched, before the wait for ids: beside a busy device.
+
+        `ahead` (the protocol's: the module's text), ``(before,
+        sources)``: the step comes back unread, row i's token is taken
+        on the device from `before`'s ids at ``sources[i]`` where that
+        is not -1, and the call waits for `before`'s ids where it would
+        have waited for its own. The bucket's one program serves both."""
         with flight.span("model", "decode", len(last_tokens)):
             return self._decode_paged(pool, block_tables, last_tokens,
                                       positions, write_blocks, write_offs,
-                                      block_size, meanwhile)
+                                      block_size, meanwhile, ahead)
 
     def _decode_paged(self, pool, block_tables, last_tokens, positions,
                       write_blocks, write_offs, block_size: int,
-                      meanwhile):
+                      meanwhile, ahead):
         phase = self.phase
         b = len(last_tokens)
         self.decode_calls += 1
@@ -903,8 +1026,8 @@ class TransformerEngineModel:
                     self._build_decode_paged(*key)
             # One host buffer, a row a sequence: token, position,
             # write block (default past the pool: dropped), write
-            # offset, block table.
-            packed = np.zeros((b_pad, 4 + nb_pad), np.int32)
+            # offset, block table, place in the step before's ids.
+            packed = np.zeros((b_pad, 5 + nb_pad), np.int32)
             packed[:, 2] = int(pool.shape[0])
             for i in range(b):
                 table = block_tables[i][:nb_pad]
@@ -914,9 +1037,10 @@ class TransformerEngineModel:
             k = min(len(write_blocks), b)
             packed[:k, 2] = write_blocks[:k]
             packed[:k, 3] = write_offs[:k]
+            place_sources(packed, ahead)
             # The call's arguments that are still host arrays: what the
             # jitted call below uploads for this step.
-            args = (pool, self._params, packed)
+            args = (pool, self._params, packed, self._before(ahead, b_pad))
             self.decode_h2d_arrays += sum(
                 isinstance(leaf, np.ndarray)
                 for leaf in self._tree_leaves(args))
@@ -927,13 +1051,11 @@ class TransformerEngineModel:
             # before the step is on the device.
             ids, logits, new_pool = fn(*args)
         self._count_products(b_pad)
+        step = DecodeStep(ids, b, logits, self)
         if meanwhile is not None:
             meanwhile()
-        with flight.span("model", "decode.logits_wait", None, phase,
-                         "decode_wait_s"):
-            ids = np.asarray(ids)
-            self.decode_d2h_bytes += ids.nbytes
-        return DecodeStep(ids[:b], logits, self), new_pool
+        read_after_dispatch(step, ahead)
+        return step, new_pool
 
     def prefill_paged(self, tokens: Sequence[int], pool,
                       block_table: Sequence[int], prefix_len: int,

@@ -81,6 +81,32 @@ before a prefill's call, and before anything else ends a stream (a
 cancellation, a failed step, `stop`), so a stream sees its tokens in
 order and never its end before a token generated for it.
 
+A full batch's next decode step is dispatched before the last one's
+ids are read, where the model takes them on the device (`ahead`:
+`model.py`). A decode step may be *in flight*: dispatched, its ids not
+read (`_ahead`; the PROMPT in flight below is another thing). At the
+next iteration, iff every row of the batch is taken and no prompt is in
+flight in chunks (so nothing waiting could be admitted in the turn this
+skips), no row of the step in flight is known to end with it
+(`generated + 1 >= max_new_tokens`), none is cancelled and every row has
+room for one more position without a preemption, the scheduler builds
+the next step from what it knows without the tokens (every row a
+position further, its token the row's place in the step in flight's
+ids) and calls `decode_paged` with it: the program takes the tokens on
+the device, and only once it is dispatched does the call wait for the
+step in flight's ids. Those tokens are sampled, appended and checked
+for their end at once, and handed to their streams from the next call's
+`meanwhile` as every step's are, beside a device that is busy either
+way. In every other case the loop first
+reads the step in flight and goes on as it always has: admit, prefill, a
+chunk, a batch that is not full, a retirement, a cancellation, a
+preemption, `stop`, a failed step. An end the host cannot foresee (the
+model's `eos_token` among the ids) is found a step late: the row's token
+of the step already dispatched is dropped, never emitted, and its blocks
+go back once that step is read (`_take`). No option decides any of it;
+a model without the keyword runs the loop as it was.
+`decode_steps_ahead` and `decode_ends_found_late` count both.
+
 A long prompt is prefilled in chunks, a decode step between two of
 them, where the model offers the call (`prefill_chunk`, with its
 `prefill_chunk_tokens`: `layer_groups_model.py`): a prompt longer than a
@@ -99,6 +125,7 @@ model without the call are prefilled whole.
 
 from __future__ import annotations
 
+import inspect
 import itertools
 import logging
 import threading
@@ -313,6 +340,9 @@ class _Sequence:
     # Positions whose KV the chunks run so far have put in the cache,
     # while the sequence is in flight.
     prefilled: int = 0
+    # A decode step's token was its last (a preempted sequence never
+    # is one: it ends where it ends).
+    ended: bool = False
 
     @property
     def generated(self) -> int:
@@ -387,6 +417,16 @@ class InferenceEngine:
         # The prompt being prefilled in chunks, if any: neither waiting
         # nor running. Only the loop's thread sets it.
         self._in_flight: Optional[_Sequence] = None
+        # The decode step on the device whose ids the host has not read,
+        # with its batch: (the model's step, the rows). While there is
+        # one, the running batch is its rows, less those whose end the
+        # step before's ids brought. Only the loop's thread sets it.
+        self._ahead: Optional[tuple] = None
+        # Whether the model's `decode_paged` takes `ahead` (`model.py`);
+        # one that does not is never called with it and runs no step
+        # ahead.
+        self._takes_ahead = "ahead" in inspect.signature(
+            model.decode_paged).parameters
         # A prompt longer than this many tokens is prefilled in chunks
         # of as many, where the model offers the call.
         self._chunk = (int(model.prefill_chunk_tokens)
@@ -409,6 +449,8 @@ class InferenceEngine:
         self.prefix_import_tokens = 0
         self.finished = 0
         self.paged_steps = 0
+        self.decode_steps_ahead = 0
+        self.decode_ends_found_late = 0
         self.prefill_chunks = 0
         self.prefill_chunk_tokens = 0
         # Every clock of the loop, in seconds, each fed by one
@@ -604,6 +646,14 @@ class InferenceEngine:
         """The iteration itself: the decode batch's size, None when
         idle."""
         clocks = self._clocks
+        if self._ahead is not None:
+            # The batch is as the step in flight left it: where the rule
+            # allows, the next step goes out before its ids are read and
+            # that is the iteration. Else they are read first, and the
+            # iteration is what it always was.
+            rows = self._step_ahead()
+            if rows is not None:
+                return rows
         with flight.span("engine", "reap", None, clocks, "reap_s"):
             self._reap_cancelled()
         prefilled = clocks["prefill_s"]
@@ -619,33 +669,31 @@ class InferenceEngine:
             return None
         with flight.span("engine", "capacity", None, clocks, "capacity_s"):
             if self.cache.grouped:
-                # Blocks every position of which has left a window
-                # group's window go back to its free list before the
-                # tables grow. A span and a clock of its own inside
-                # `capacity` (whose clock holds it too: the phases stay
-                # a partition), so that its host time has a name in the
-                # idle attribution.
-                with flight.span("engine", "window_release", None, clocks,
-                                 "window_release_s"):
-                    for seq in batch:
-                        self.cache.release_expired(seq.seq_id,
-                                                   len(seq.all_tokens))
+                self._release_expired(batch, 0)
             self._ensure_capacity()
         with self._lock:
             batch = list(self._running)
         if not batch:
             self._settle_idle()
             return None
+        return self._decode_step(batch)
+
+    def _decode_step(self, batch: List[_Sequence]) -> int:
+        """The batch's decode step and what an iteration does behind
+        it."""
         try:
             self._decode_once(batch)
         except Exception as e:  # noqa: BLE001 — the loop must survive
             logger.exception("decode step failed; failing %d stream(s)",
                              len(batch))
-            # Where the step failed before its `meanwhile`, the tokens
-            # of the step before are still pending: they come first.
+            # The step before's tokens come first: those of a step in
+            # flight that can still be read, and what is pending where
+            # the step failed before its `meanwhile`.
+            self._land()
             self._deliver()
             for seq in batch:
-                self._retire(seq, error=e)
+                if not seq.ended:   # else its last token came just now
+                    self._retire(seq, error=e)
         self.steps += 1
         with self._lock:
             follows = bool(self._running)
@@ -654,6 +702,85 @@ class InferenceEngine:
             # tokens and updates the gauges, beside a busy device.
             self._settle_idle()
         return len(batch)
+
+    # -- a decode step in flight ---------------------------------------
+    def _full_after(self, batch: List[_Sequence]) -> bool:
+        """Whether the step of `batch` that is about to go out, or is on
+        the device unread, can be followed by the next one before its
+        ids are read, by what the loop can see: every row of the batch
+        is taken and no prompt is in flight in chunks, so nothing that
+        waits could be admitted in the turn the step ahead skips; no row
+        is known to end with this step, so the batch is as full after
+        it; none is cancelled. (An end the host cannot foresee, the
+        model's `eos_token` among the ids, is found a step late:
+        `_take`.) No option: a batch that is not full runs the loop as
+        it always was."""
+        if not self._takes_ahead or self._in_flight is not None:
+            return False
+        with self._lock:
+            if not (len(batch) == len(self._running)
+                    == self.config.max_batch_size):
+                return False
+        return not any(s.stream.cancelled
+                       or s.generated + 1 >= s.max_new_tokens
+                       for s in batch)
+
+    def _step_ahead(self) -> Optional[int]:
+        """An iteration that begins with a decode step in flight. Where
+        the batch is still full behind it and every row has room for one
+        more position without a preemption, the next step is dispatched
+        at once, over the same rows a position further, and the step in
+        flight is read while the device runs it. Else the step in flight
+        is read here (None), and the iteration goes on as one without."""
+        _, batch = self._ahead
+        clocks = self._clocks
+        if self._full_after(batch):
+            with flight.span("engine", "capacity", None, clocks,
+                             "capacity_s"):
+                if self.cache.grouped:
+                    self._release_expired(batch, 1)
+                room = self._ensure_capacity(preempt=False, unread=1)
+            if room:
+                return self._decode_step(batch)
+        self._land()
+        return None
+
+    def _land(self) -> None:
+        """Read the decode step in flight, if there is one, and take its
+        tokens: nothing follows it on the device. Its streams fail
+        where its ids do not come."""
+        if self._ahead is None:
+            return
+        step, batch = self._ahead
+        self._ahead = None
+        try:
+            with flight.span("engine", "model_step", len(batch),
+                             self._clocks, "model_step_s"):
+                step.ids     # the wait: `model.decode.logits_wait`
+        except Exception as e:  # noqa: BLE001 — the loop must survive
+            logger.exception("the decode step in flight failed; failing "
+                             "%d stream(s)", len(batch))
+            self._deliver()
+            for seq in batch:
+                if seq.ended:
+                    self.cache.free(seq.seq_id)
+                else:
+                    seq.ended = True
+                    self._retire(seq, error=e)
+            return
+        self._take(step, batch)
+
+    def _release_expired(self, batch: List[_Sequence], unread: int) -> None:
+        """Blocks every position of which has left a window group's
+        window go back to its free list before the tables grow. A span
+        and a clock of its own inside `capacity` (whose clock holds it
+        too: the phases stay a partition), so that its host time has a
+        name in the idle attribution."""
+        with flight.span("engine", "window_release", None, self._clocks,
+                         "window_release_s"):
+            for seq in batch:
+                self.cache.release_expired(
+                    seq.seq_id, len(seq.all_tokens) + unread)
 
     def _settle_idle(self) -> None:
         """No decode step follows in whose shadow to deliver: hand over
@@ -927,11 +1054,16 @@ class InferenceEngine:
                 with self._lock:
                     self._running.append(seq)
 
-    def _ensure_capacity(self) -> None:
+    def _ensure_capacity(self, preempt: bool = True,
+                         unread: int = 0) -> bool:
         """Every running sequence needs a cache slot for the token the
         next decode step writes. Deterministic OOM: preempt the
         lowest-priority / youngest sequence and requeue it for
-        recompute; never crash, never stall the rest of the batch."""
+        recompute; never crash, never stall the rest of the batch.
+        `unread`: a row's tokens on the device that the host has not
+        read (one, behind a decode step in flight), each a position.
+        Without `preempt`, False where a row is short (what the rows
+        before it took they need a step later anyway)."""
         while True:
             with self._lock:
                 running = list(self._running)
@@ -941,13 +1073,15 @@ class InferenceEngine:
                 # writable_from additionally COWs that slot's block if
                 # it is shared (a fully-adopted prompt ending mid-block
                 # faults here on its first generated token).
-                if not self.cache.allocate(
-                        seq.seq_id, len(seq.all_tokens),
-                        writable_from=len(seq.all_tokens) - 1):
+                n = len(seq.all_tokens) + unread
+                if not self.cache.allocate(seq.seq_id, n,
+                                           writable_from=n - 1):
                     short = seq
                     break
             if short is None:
-                return
+                return True
+            if not preempt:
+                return False
             victim = self._pick_victim()
             if victim is None or victim is short:
                 # Nothing lower-priority to evict: preempt `short`
@@ -991,34 +1125,84 @@ class InferenceEngine:
         # are the model's one call (`TransformerEngineModel`: one
         # donated jit). The engine's own work is the int32 tables, which
         # `kv_gather_s` times; no KV payload passes through here.
+        # With a step in flight (`before`: its rows are this batch, in
+        # this order) every row is a position further than the host has
+        # tokens for, and its token is where the step in flight left it:
+        # row i's place in that step's ids, on the device.
+        before = self._ahead[0] if self._ahead is not None else None
+        unread = int(before is not None)
         with flight.span("engine", "tables", b, clocks, "kv_gather_s"):
-            lasts = [s.all_tokens[-1] for s in batch]
-            poss = [len(s.all_tokens) - 1 for s in batch]
+            lasts = ([0] * b if unread
+                     else [s.all_tokens[-1] for s in batch])
+            poss = [len(s.all_tokens) - 1 + unread for s in batch]
             tables = [self.cache.step_tables(s.seq_id) for s in batch]
             entries = [(s.seq_id, poss[i]) for i, s in enumerate(batch)]
+        keywords = {"meanwhile": self._in_shadow}
+        if unread:
+            keywords["ahead"] = (before, list(range(b)))
+        elif self._full_after(batch):
+            # The first of a run: it takes the host's tokens and stays
+            # unread, for the next step to go out ahead of its ids.
+            keywords["ahead"] = (None, [-1] * b)
         with flight.span("engine", "model_step", b, clocks,
                          "model_step_s"):
             # `state`: the state pool and the rows' slots, where the
             # model keeps a state a sequence; nothing otherwise.
-            logits = self.cache.paged_step(
+            step = self.cache.paged_step(
                 entries,
                 lambda pool, blocks, offs, *state: self.model.decode_paged(
                     pool, tables, lasts, poss, blocks, offs,
-                    self.config.block_size, *state,
-                    meanwhile=self._in_shadow))
+                    self.config.block_size, *state, **keywords))
         self.paged_steps += 1
-        with flight.span("engine", "sample", b, clocks, "sample_s"):
-            toks = self._greedy(logits)
-        # What the next step needs of the tokens, at once; the streams
-        # get them from its `meanwhile`.
+        # (A model that takes `ahead` and still hands back host logits
+        # has nothing a later step could take on the device: read.)
+        self._ahead = ((step, batch) if "ahead" in keywords
+                       and hasattr(step, "on_device") else None)
+        if unread:
+            # The call has read the step before; this one runs.
+            self.decode_steps_ahead += 1
+            self._take(before, batch)
+        if self._ahead is None:
+            self._take(step, batch)
+
+    def _take(self, step, batch: List[_Sequence]) -> None:
+        """A decode step's tokens, its ids read or its logits here: what
+        the next step needs of them at once (the token joins its
+        sequence, a sequence that ended leaves the batch); the streams
+        get them from the next call's `meanwhile`, whether that step
+        goes out ahead or not: behind its dispatch and in front of its
+        wait, where this thread sleeps next and the consumers it wakes
+        find the interpreter free. (Handing them over right here, with a
+        later step on the device already, put the consumers' wake-up
+        between two `decode_paged` calls: 1.3 ms a step of this thread
+        waiting for the interpreter, PR 60's first traced run.)
+
+        A row whose end these ids bring while a later step holds it (the
+        model's `eos_token`: no end the host could foresee) leaves the
+        batch now and keeps its blocks until that step is read: its
+        token there is dropped, never emitted, and its write there went
+        to a slot it still owned (the device runs programs in dispatch
+        order, so a later owner's write comes after it)."""
+        clocks = self._clocks
+        held_later = self._ahead is not None
+        with flight.span("engine", "sample", len(batch), clocks,
+                         "sample_s"):
+            toks = self._greedy(step)
         joined = time.perf_counter() if flight.enabled else 0.0
         for seq, tok in zip(batch, toks):
+            if seq.ended:
+                self.cache.free(seq.seq_id)
+                continue
             seq.all_tokens.append(tok)
-            last = self._ended(seq)
-            if last:
+            self.tokens_generated += 1
+            seq.ended = self._ended(seq)
+            if seq.ended and held_later:
+                self.decode_ends_found_late += 1
+                with self._lock:
+                    self._running.remove(seq)
+            elif seq.ended:
                 self._release(seq)
-            self._pending.append((seq, tok, last, joined))
-        self.tokens_generated += b
+            self._pending.append((seq, tok, seq.ended, joined))
 
     @staticmethod
     def _greedy(step) -> List[int]:
@@ -1112,7 +1296,9 @@ class InferenceEngine:
 
     def _fail_in_flight(self, error: BaseException) -> None:
         """Finish every running and waiting stream with `error`, so
-        consumers unblock and see it."""
+        consumers unblock and see it; the tokens of a decode step in
+        flight and what is pending come first."""
+        self._land()
         self._deliver()
         with self._lock:
             leftovers = list(self._running) + list(self._waiting)
@@ -1131,7 +1317,7 @@ class InferenceEngine:
         while time.monotonic() < deadline:
             with self._lock:
                 if not (self._running or self._waiting or self._pending
-                        or self._in_flight):
+                        or self._in_flight or self._ahead):
                     return True
             time.sleep(0.005)
         return False
@@ -1196,11 +1382,18 @@ class InferenceEngine:
         no KV is gathered on the host) + `model_step_s` (the model's
         step, which also writes the new tokens' KV).
         `paged` reads True and `paged_steps` counts the decode steps:
-        every step is the paged one.
+        every step is the paged one. `decode_steps_ahead` counts those
+        of them dispatched before the step before's ids were read (a
+        full batch none of whose rows was known to end: the module's
+        text), `decode_ends_found_late` the rows whose end such a step's
+        ids brought when their next step was already dispatched: that
+        step's token of theirs was dropped, never emitted.
         `tokens_delivered_overlapped` counts the decode tokens handed to
         their streams from inside the next step's `meanwhile`, beside a
-        busy device; `tokens_generated` less `prefills` (a prefill's
-        token goes out at once) less it, those flushed early.
+        busy device (behind a step dispatched ahead too: two steps are
+        then on the device); `tokens_generated` less `prefills` (a
+        prefill's token goes out at once) less it, those flushed
+        early.
         `prefill_chunks` counts the chunks of prompts prefilled in
         chunks (the model's `prefill_chunk` calls whose rows reached the
         cache), `prefill_chunk_tokens` the prompt tokens they ran: over
@@ -1214,8 +1407,10 @@ class InferenceEngine:
         paged decode steps moved across the host boundary: host arrays
         among the arguments of its jitted calls, which the call uploads
         (one a step), and bytes brought back (a step's sampled
-        ids, 4 a padded row; its `[b_pad, V]` logits only where
-        somebody fetched them); 0 for a model that keeps no such count.
+        ids, 4 a row of the model's largest batch bucket, whatever the
+        step's rows, and three counters more where the model has
+        experts; its `[b_pad, V]` logits only where somebody fetched
+        them); 0 for a model that keeps no such count.
         `decode_attn_inplace_steps` counts the paged steps whose
         attention read the pool's pages in place through the Pallas
         kernel (every step on the chip at heads of 128; none on the CPU,
@@ -1289,6 +1484,8 @@ class InferenceEngine:
                              if self.prefix_index is not None else None),
             "paged": True,
             "paged_steps": self.paged_steps,
+            "decode_steps_ahead": self.decode_steps_ahead,
+            "decode_ends_found_late": self.decode_ends_found_late,
             "prefill_chunks": self.prefill_chunks,
             "prefill_chunk_tokens": self.prefill_chunk_tokens,
             "prefill_kv_device_writes": self.cache.range_writes_device,

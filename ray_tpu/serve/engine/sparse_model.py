@@ -13,22 +13,25 @@ from typing import Dict, Sequence
 import numpy as np
 
 from ray_tpu.core import flight
-from ray_tpu.serve.engine.model import DecodeStep, _JitLRU, _next_pow2
+from ray_tpu.serve.engine.model import (DecodeStep, StepIds, _JitLRU,
+                                        _next_pow2, read_after_dispatch)
 
 
-class SparseEngineModel:
+class SparseEngineModel(StepIds):
     """Base of an engine model over seeded weights `params` and a config
     `cfg` with `vocab_size`, `norm_eps`, `dtype`, `top_k`,
     `routed_scaling`, `experts_held` (and `router_scoring`, where the
     router does not score by sigmoid). A subclass builds its jitted
     programs (`_build_prefill(s_pad)`) and packs its decode row."""
 
-    def __init__(self, params, cfg, jit_cache_cap: int = 32):
+    def __init__(self, params, cfg, jit_cache_cap: int = 32,
+                 max_batch_size: int = 8):
         import jax
         import jax.numpy as jnp
 
         self._params = params
         self._cfg = cfg
+        self._max_batch = max_batch_size
         self.vocab_size = cfg.vocab_size
         self.eos_token = 1
         self.kv_dtype = jnp.dtype(cfg.dtype)
@@ -164,32 +167,54 @@ class SparseEngineModel:
             logits = np.asarray(logits)
         return logits, rest, n
 
-    def _run_decode(self, fn, args, b: int, b_pad: int, meanwhile=None):
+    # A decode program's int32 result: the ids at `_ids_width`, then
+    # the step's three expert counters.
+    _ids_trail = 3
+
+    def _step_out(self, ids, counts, b_pad: int):
+        """Inside a decode program: its one int32 result, the greedy ids
+        at the fixed width, then the step's three expert counters."""
+        import jax.numpy as jnp
+
+        return jnp.concatenate(
+            [jnp.pad(ids, (0, self._ids_width(b_pad) - b_pad)), counts])
+
+    def _run_decode(self, fn, args, b: int, b_pad: int, meanwhile=None,
+                    ahead=None):
         """One dispatch of a decode bucket's program `fn` over `args`
-        (the packed host array among them: the step's one upload).
-        Fetches its int32 result (``[b_pad + 3]``: greedy ids, then the
-        step's three expert counters), counts both, and returns the
-        `DecodeStep` and what else the program returned (the pools).
-        `meanwhile` (the protocol's: `model.py`) runs between the
-        dispatch and the fetch."""
+        (the packed host array among them: the step's one upload) and
+        the step before's result on the device (`ahead`'s, else zeros).
+        Returns the `DecodeStep` and what else the program returned (the
+        pools). `meanwhile` and `ahead` are the protocol's (`model.py`):
+        the one runs between the dispatch and the wait, the other says
+        whose ids the wait is for."""
         phase = self.phase
         self.decode_h2d_arrays += sum(
             isinstance(leaf, np.ndarray)
             for leaf in self._tree_leaves(args))
+        before = self._before(ahead, b_pad)
         with flight.span("model", "decode.dispatch", None, phase,
                          "decode_dispatch_s"):
-            out, logits, *rest = fn(*args)
+            out, logits, *rest = fn(*args, before)
         self._count_experts_step(b_pad)
+        step = DecodeStep(out, b, logits, self)
         if meanwhile is not None:
             meanwhile()
-        with flight.span("model", "decode.logits_wait", None, phase,
+        read_after_dispatch(step, ahead)
+        return step, rest
+
+    def _fetch_ids(self, on_device) -> np.ndarray:
+        """A decode step is read (`DecodeStep.ids`): the wait for the
+        device, the trip of its int32 result to the host (the greedy
+        ids, then the step's three expert counters), both counted."""
+        with flight.span("model", "decode.logits_wait", None, self.phase,
                          "decode_wait_s"):
-            out = np.asarray(out)
+            out = np.asarray(on_device)
             self.decode_d2h_bytes += out.nbytes
-        self.moe_local_assignments += int(out[b_pad])
-        self.moe_expert_touches += int(out[b_pad + 1])
-        self.moe_max_expert_load += int(out[b_pad + 2])
-        return DecodeStep(out[:b], logits, self), rest
+        self.moe_local_assignments += int(out[-3])
+        self.moe_expert_touches += int(out[-2])
+        self.moe_max_expert_load += int(out[-1])
+        return out[:-3]
 
     def prefill_paged(self, tokens: Sequence[int], pool,
                       block_table: Sequence[int], prefix_len: int,

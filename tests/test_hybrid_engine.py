@@ -335,10 +335,11 @@ def test_the_steps_keep_every_value_of_the_states_shape_in_float32():
              for name, (shape, dtype) in model.state_shapes.items()}
     pool = jnp.zeros((ENGINE["num_blocks"], ENGINE["block_size"])
                      + tuple(model.kv_token_shape), model.kv_dtype)
-    packed = np.zeros((2, 5 + 2), np.int32)
+    packed = np.zeros((2, 6 + 2), np.int32)
     step = model._build_decode_paged(2, 2, ENGINE["block_size"])
     assert dtypes_of_state_shaped_values(
-        step, pool, state, served["params"], packed) == {"float32"}
+        step, pool, state, served["params"], packed,
+        np.zeros(model._ids_width(2) + 3, np.int32)) == {"float32"}
     prefill = model._build_prefill(32)
     assert dtypes_of_state_shaped_values(
         prefill, served["params"], np.zeros(32, np.int32),

@@ -495,7 +495,8 @@ def test_decode_step_compiles_for_v5e_without_a_copy_of_the_pool(
     assert (1024, 16) + model.kv_token_shape == POOL
     compiled = model._build_decode_paged(8, 64, 16).lower(
         jax.ShapeDtypeStruct(POOL, jnp.float32, sharding=one_chip), laid,
-        jax.ShapeDtypeStruct((8, 4 + 64), jnp.int32,
+        jax.ShapeDtypeStruct((8, 5 + 64), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((model._ids_width(8),), jnp.int32,
                              sharding=one_chip)).compile()
     text, memory = compiled.as_text(), compiled.memory_analysis()
     # One paged attention call and four weight products a layer.
@@ -688,8 +689,10 @@ def test_hybrid_decode_step_compiles_for_v5e_with_both_pools_in_place(
              for name, (shape, dtype) in model.state_shapes.items()}
     compiled = model._build_decode_paged(32, 64, 16).lower(
         jax.ShapeDtypeStruct(HYBRID_POOL, jnp.bfloat16, sharding=one_chip),
-        state, params, jax.ShapeDtypeStruct((32, 5 + 64), jnp.int32,
-                                            sharding=one_chip)).compile()
+        state, params, jax.ShapeDtypeStruct((32, 6 + 64), jnp.int32,
+                                            sharding=one_chip),
+        jax.ShapeDtypeStruct((model._ids_width(32) + 3,), jnp.int32,
+                             sharding=one_chip)).compile()
     memory = compiled.memory_analysis()
     assert "tpu_custom_call" in compiled.as_text()
     pools = int(np.prod(HYBRID_POOL)) * 2 + 33 * (
@@ -881,8 +884,10 @@ def test_laguna_decode_step_compiles_for_v5e_with_both_pools_in_place(
              "window": jax.ShapeDtypeStruct(WINDOW_POOL, jnp.bfloat16,
                                             sharding=one_chip)}
     compiled = model._build_decode_paged(16, 512, 16).lower(
-        pools, params, jax.ShapeDtypeStruct((16, 6 + 512 + 33), jnp.int32,
-                                            sharding=one_chip)).compile()
+        pools, params, jax.ShapeDtypeStruct((16, 7 + 512 + 33), jnp.int32,
+                                            sharding=one_chip),
+        jax.ShapeDtypeStruct((model._ids_width(16) + 3,), jnp.int32,
+                             sharding=one_chip)).compile()
     memory, text = compiled.memory_analysis(), compiled.as_text()
     assert text.count("paged_window_decode_attention") >= 9
     # Eleven expert layers, each one call of the grouped FFN kernel.
@@ -1092,8 +1097,10 @@ def test_mimo_decode_step_compiles_for_v5e_with_both_pools_in_place(
              "window": jax.ShapeDtypeStruct(MIMO_WINDOW_POOL, jnp.bfloat16,
                                             sharding=one_chip)}
     compiled = model._build_decode_paged(16, 512, 16).lower(
-        pools, params, jax.ShapeDtypeStruct((16, 6 + 512 + 9), jnp.int32,
-                                            sharding=one_chip)).compile()
+        pools, params, jax.ShapeDtypeStruct((16, 7 + 512 + 9), jnp.int32,
+                                            sharding=one_chip),
+        jax.ShapeDtypeStruct((model._ids_width(16) + 3,), jnp.int32,
+                             sharding=one_chip)).compile()
     memory, text = compiled.memory_analysis(), compiled.as_text()
     assert text.count("paged_window_decode_attention") >= 5
     assert text.count("held_experts_ffn_decode") >= 6
@@ -1308,7 +1315,8 @@ def test_keye_programs_compile_for_v5e_with_the_planes_pool_in_place(
     pools = {"global": spec(KEYE_PLANES), "index": spec(KEYE_INDEX_POOL)}
     both = (int(np.prod(KEYE_PLANES)) + int(np.prod(KEYE_INDEX_POOL))) * 2
     compiled = model._build_decode_paged(16, 1024, 16).lower(
-        pools, params, spec((16, 4 + 1024), jnp.int32)).compile()
+        pools, params, spec((16, 5 + 1024), jnp.int32),
+        spec((model._ids_width(16) + 3,), jnp.int32)).compile()
     memory, text = compiled.memory_analysis(), compiled.as_text()
     assert text.count("paged_decode_attention") >= 12
     assert text.count("paged_index_scores") >= 12

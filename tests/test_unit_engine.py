@@ -1134,7 +1134,8 @@ def test_a_tie_in_the_logits_goes_to_the_lowest_index(tiny_transformer):
 def test_a_step_uploads_one_array_and_brings_back_its_ids(
         tiny_transformer, b):
     """`decode_h2d_arrays` grows by 1 a step and `decode_d2h_bytes` by
-    the padded ids; `np.asarray(step)` has `b` rows, fetches the padded
+    the ids (at the width of the model's largest batch bucket, whatever
+    the step's rows: PR 60); `np.asarray(step)` has `b` rows, fetches the padded
     logits once and adds their bytes; an empty write list leaves the
     pool as it was."""
     from ray_tpu.serve.engine.model import _next_pow2
@@ -1153,7 +1154,7 @@ def test_a_step_uploads_one_array_and_brings_back_its_ids(
     after = eng.stats()
     assert after["decode_h2d_arrays"] - before["decode_h2d_arrays"] == 1
     assert after["decode_d2h_bytes"] - before["decode_d2h_bytes"] \
-        == 4 * b_pad
+        == 4 * model._ids_width(b_pad) == 4 * 8
     assert len(step) == b and step.ids.dtype == np.int32
 
     logits = np.asarray(step)
@@ -1195,7 +1196,7 @@ def test_the_upload_count_follows_the_calls_arguments(tiny_transformer):
 
 def test_a_served_stream_fetches_no_logits(tiny_transformer):
     """Through the engine, every paged step is one upload and 4 bytes a
-    padded row back; a fully cached prompt's first token is the one
+    row of the model's largest batch bucket back; a fully cached prompt's first token is the one
     place the scheduler asks a step for its logits."""
     params, cfg = tiny_transformer
     model, eng = _paged_transformer_engine(params, cfg, max_batch_size=4)
@@ -1207,17 +1208,18 @@ def test_a_served_stream_fetches_no_logits(tiny_transformer):
     _drive(eng)
     s = eng.stats()
     assert s["paged_steps"] == 5 == s["decode_h2d_arrays"]
-    assert s["decode_d2h_bytes"] == 5 * 4 * 4
+    assert s["decode_d2h_bytes"] == 5 * 4 * model._ids_width(4) == 5 * 4 * 8
     again = eng.submit(prompt, 2)
     _drive(eng)
     assert again.tokens_so_far() == handles[-1].tokens_so_far()[:2]
     s2 = eng.stats()
     assert s2["prefix_hit_tokens"] == 32
-    # The read-only step of the full hit (b_pad 1) and one decode step;
-    # the first fetched its [1, V] logits.
+    # The read-only step of the full hit (b_pad 1) and one decode step,
+    # each its ids at the model's width; the first fetched its [1, V]
+    # logits.
     assert s2["decode_h2d_arrays"] - s["decode_h2d_arrays"] == 2
     assert s2["decode_d2h_bytes"] - s["decode_d2h_bytes"] \
-        == 2 * 4 + 4 * cfg.vocab_size
+        == 2 * 4 * model._ids_width(1) + 4 * cfg.vocab_size
 
 
 def test_a_model_that_returns_logits_is_sampled_on_the_host():

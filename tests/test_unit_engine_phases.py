@@ -359,7 +359,8 @@ def test_device_programs_are_named_after_their_functions(tiny_transformer):
         i32(4), i32(3), pool, jnp.zeros((2,), i32)) == "jit_prefill_paged"
     assert _module_name(
         model._build_decode_paged(2, 2, 4), pool, laid,
-        jnp.zeros((2, 4 + 2), i32)) == "jit_decode_paged"
+        jnp.zeros((2, 5 + 2), i32),
+        jnp.zeros((2,), i32)) == "jit_decode_paged"
 
     optimizer = optax.sgd(0.1)
     step = make_train_step(lambda p, b: lm_loss(p, b, cfg), optimizer)
@@ -397,9 +398,13 @@ def test_meanwhile_runs_once_a_step_between_its_dispatch_and_its_wait(
     with open(os.path.join(manifest.ROOT, "benchmarks", "configs",
                            f"{config}.json")) as f:
         widths = family.toy_widths(family.widths(json.load(f)))
+    # A batch of three slots that holds two rows: never full, so every
+    # step is read inside the call that dispatched it (behind a full
+    # batch a step is read inside the NEXT call, PR 60:
+    # `test_unit_engine_ahead.py`).
     served = family.build_serving(
         widths, {"max_seq_len": 128, "engine": dict(
-            engine, max_batch_size=2, block_size=16, num_blocks=32)}, 7)
+            engine, max_batch_size=3, block_size=16, num_blocks=32)}, 7)
     eng = InferenceEngine(served["model"], served["engine_config"])
     delivering = eng._in_shadow
 

@@ -477,7 +477,8 @@ def test_decode_step_builds_no_dense_copy_of_the_batchs_cache(
         (eng.config.num_blocks, bs) + model.kv_token_shape, jnp.float32)
     jaxpr = jax.make_jaxpr(model._build_decode_paged(b_pad, nb_pad, bs))(
         pool, model._params,
-        jax.ShapeDtypeStruct((b_pad, 4 + nb_pad), jnp.int32))
+        jax.ShapeDtypeStruct((b_pad, 5 + nb_pad), jnp.int32),
+        jax.ShapeDtypeStruct((model._ids_width(b_pad),), jnp.int32))
     one_layer = b_pad * nb_pad * bs * 2 * heads * hd
     sizes = sorted({int(np.prod(a.shape)) for a in _all_avals(jaxpr.jaxpr)
                     if a.shape != pool.shape})
