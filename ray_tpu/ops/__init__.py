@@ -6,6 +6,8 @@ code); these are designed new for the ICI mesh. `flash_attention` holds
 the one-chip train step's causal flash kernels (Pallas; `attention()`
 picks them and their tiles). `paged_attention` (decode
 attention over the serving engine's paged KV pool, a Pallas kernel),
+`latent_attention` (multi-head latent attention over a pool of one row a
+position: the expanded and the absorbed form, the walk's latent body),
 `delta_rule` (the gated delta rule: chunked for prefill, one step for
 decode) and `experts` (a dropless expert layer that holds a range of the
 routed experts) are imported by the engine's models alone; `moe` is the

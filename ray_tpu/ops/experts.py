@@ -88,6 +88,15 @@ def route(y, router_w, select_bias, top_k: int, scaling: float = 1.0,
     return experts.astype(jnp.int32), weights
 
 
+def gated(gate, up, limit: float = None):
+    """``silu(gate) * up``, an expert's middle; with `limit` (a model
+    with a `swiglu_limit`) ``silu(min(gate, limit)) * clip(up, -limit,
+    limit)``. A call without one traces what it always has."""
+    if limit is not None:
+        gate, up = jnp.minimum(gate, limit), jnp.clip(up, -limit, limit)
+    return jax.nn.silu(gate) * up
+
+
 # Rows of a tile at the most: a decode step's (padded) batch is one tile
 # and `grouped_ffn_kernel`'s, a longer prompt several.
 _ROWS_MOST = 128
@@ -214,7 +223,7 @@ def prefill_tiles(rows: int, k: int, held: int, d: int, f: int,
 
 
 def _ffn_body(expert_ref, live_ref, x_ref, share_ref, gate_ref, up_ref,
-              down_ref, o_ref):
+              down_ref, o_ref, *, limit=None):
     """One grid step: the batch's rows against block ``j`` of tile
     ``i``'s expert, weighted by each row's share in that expert and
     added to the ``[tile, d]`` float32 output block, which stays in VMEM
@@ -233,14 +242,15 @@ def _ffn_body(expert_ref, live_ref, x_ref, share_ref, gate_ref, up_ref,
         x = x_ref[...]
         gate = jnp.dot(x, gate_ref[...], preferred_element_type=f32)
         up = jnp.dot(x, up_ref[...], preferred_element_type=f32)
-        out = jnp.dot((jax.nn.silu(gate) * up).astype(x.dtype),
+        out = jnp.dot(gated(gate, up, limit).astype(x.dtype),
                       down_ref[...], preferred_element_type=f32)
         o_ref[...] += out * share_ref[...]
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block", "interpret", "limit"))
 def grouped_ffn_kernel(x, tile_expert, share, w_gate, w_up, w_down, *,
-                       block: int = None, interpret: bool = False):
+                       block: int = None, interpret: bool = False,
+                       limit: float = None):
     """x ``[tile, d]`` in the weights' dtype, a batch that is one tile;
     tile_expert ``[n_tiles]`` int32, the experts with a pair in ascending
     order, then ``n_held``; share ``[n_tiles, tile, 1]`` float32, each
@@ -291,7 +301,8 @@ def grouped_ffn_kernel(x, tile_expert, share, w_gate, w_up, w_down, *,
         return (expert_ref[i], block_of(i, j, live_ref), 0)
 
     return pl.pallas_call(
-        _ffn_body,
+        _ffn_body if limit is None else functools.partial(_ffn_body,
+                                                          limit=limit),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetched),
             grid=(n_tiles, n_blocks),
@@ -311,7 +322,7 @@ def grouped_ffn_kernel(x, tile_expert, share, w_gate, w_up, w_down, *,
     )(*prefetched, x, share, w_gate, w_up, w_down)
 
 
-def _one_tile(y, local, weights, w_gate, w_up, w_down):
+def _one_tile(y, local, weights, w_gate, w_up, w_down, limit=None):
     """A batch of one tile through `grouped_ffn_kernel`: every expert
     with a pair meets the whole batch, and a row's weight in it, 0 where
     the row did not choose it, takes the place of the sort, the gather
@@ -332,7 +343,7 @@ def _one_tile(y, local, weights, w_gate, w_up, w_down):
     share = jnp.pad(share, ((0, 1), (0, tile - t)))[tile_expert]
     out = grouped_ffn_kernel(
         jnp.pad(y, ((0, tile - t), (0, 0))).astype(w_gate.dtype),
-        tile_expert, share[..., None], w_gate, w_up, w_down)
+        tile_expert, share[..., None], w_gate, w_up, w_down, limit=limit)
     return out[:t], load
 
 
@@ -383,7 +394,7 @@ def _tiles_by_expert(pair_expert, n_held: int, tile: int, n_tiles: int):
     return load, tiles_of, tiles_end, tile_expert
 
 
-def _scan_of_tiles(y, local, weights, w_gate, w_up, w_down):
+def _scan_of_tiles(y, local, weights, w_gate, w_up, w_down, limit=None):
     """The scan: a `lax.scan` over row tiles of one expert each, a tile
     without a pair skipping its branch."""
     f32 = jnp.float32
@@ -419,7 +430,7 @@ def _scan_of_tiles(y, local, weights, w_gate, w_up, w_down):
             x = y_ext[tokens].astype(act)
             gate = jnp.dot(x, w_gate[expert], preferred_element_type=f32)
             up = jnp.dot(x, w_up[expert], preferred_element_type=f32)
-            out = jnp.dot((jax.nn.silu(gate) * up).astype(act),
+            out = jnp.dot(gated(gate, up, limit).astype(act),
                           w_down[expert], preferred_element_type=f32)
             return acc.at[tokens].add(out * gates[:, None])
 
@@ -434,7 +445,8 @@ def _scan_of_tiles(y, local, weights, w_gate, w_up, w_down):
 
 def _prefill_body(expert_ref, live_ref, first_ref, rows_ref, token_ref,
                   weight_ref, y_ref, gate_ref, up_ref, down_ref, o_ref,
-                  slabs, arrived, x_ref, z_ref, acc_ref, left, *sum_ref):
+                  slabs, arrived, x_ref, z_ref, acc_ref, left, *sum_ref,
+                  limit=None):
     """One grid step: tile ``i``'s rows against block ``j`` of its
     expert. A row of ``d`` values lies as ``d / 128`` sublanes of 128
     lanes in `y_ref`, the slabs, `z_ref` and the sum (a row is then whole
@@ -513,7 +525,7 @@ def _prefill_body(expert_ref, live_ref, first_ref, rows_ref, token_ref,
         x = x_ref[...]
         gate = jnp.dot(x, gate_ref[...], preferred_element_type=f32)
         up = jnp.dot(x, up_ref[...], preferred_element_type=f32)
-        out = jnp.dot((jax.nn.silu(gate) * up).astype(x.dtype),
+        out = jnp.dot(gated(gate, up, limit).astype(x.dtype),
                       down_ref[...], preferred_element_type=f32)
         if sum_ref:             # several blocks: their sum, block by block
             @pl.when(lax.eq(j, i32(0)))
@@ -548,11 +560,12 @@ def _prefill_body(expert_ref, live_ref, first_ref, rows_ref, token_ref,
         whole.wait()
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("tile", "block", "interpret",
+                                             "limit"))
 def held_experts_ffn_prefill(y, tile_expert, tile_first, tile_rows,
                              pair_token, pair_weight, w_gate, w_up, w_down,
                              *, tile: int, block: int,
-                             interpret: bool = False):
+                             interpret: bool = False, limit: float = None):
     """y ``[T, d]`` float32, T a multiple of 128; the sorted pairs'
     tokens and weights `pair_token`, `pair_weight` ``[T k]``; a tile's
     expert, the sorted pair its first row is and how many rows it has in
@@ -615,7 +628,8 @@ def held_experts_ffn_prefill(y, tile_expert, tile_first, tile_rows,
     if n_blocks > 1:
         scratch.append(pltpu.VMEM((tile, d), f32))
     out = pl.pallas_call(
-        _prefill_body,
+        _prefill_body if limit is None else functools.partial(
+            _prefill_body, limit=limit),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetched),
             grid=(n_tiles, n_blocks),
@@ -636,7 +650,7 @@ def held_experts_ffn_prefill(y, tile_expert, tile_first, tile_rows,
     return out.reshape(t, d)
 
 
-def _sorted_tiles(y, local, weights, w_gate, w_up, w_down):
+def _sorted_tiles(y, local, weights, w_gate, w_up, w_down, limit=None):
     """A prompt through `held_experts_ffn_prefill`. What XLA makes is no
     wider than the pairs: their sort by held expert, `load`, and a
     tile's expert, first pair and live rows; the kernel gathers its own
@@ -662,20 +676,21 @@ def _sorted_tiles(y, local, weights, w_gate, w_up, w_down):
     out = held_experts_ffn_prefill(
         jnp.pad(y.astype(jnp.float32), ((0, t_pad - t), (0, 0))),
         tile_expert, tile_first, tile_rows, pair_token, pair_weight,
-        w_gate, w_up, w_down, tile=tile, block=block)
+        w_gate, w_up, w_down, tile=tile, block=block, limit=limit)
     return out[:t], load
 
 
 def held_experts_ffn(y, experts, weights, w_gate, w_up, w_down,
-                     held: Tuple[int, int], valid=None):
+                     held: Tuple[int, int], valid=None, limit: float = None):
     """The held experts' part of a sparse-expert layer.
 
     y ``[T, d]``; experts, weights ``[T, k]`` from `route`; w_gate, w_up
     ``[hi - lo, d, f]`` and w_down ``[hi - lo, f, d]``, the matrices of
     experts ``lo .. hi - 1``; valid ``[T]`` bool (rows of a padded batch
     that are no sequence route nowhere). An expert is
-    ``W_down(silu(W_gate y) * W_up y)``; products take their operands in
-    the weights' dtype and accumulate in float32.
+    ``W_down(silu(W_gate y) * W_up y)`` (`gated`, which clamps both
+    factors at `limit` where the model has one); products take their
+    operands in the weights' dtype and accumulate in float32.
 
     Returns ``(out [T, d] float32, load [hi - lo] int32)``: the weighted
     sum over a token's chosen experts that are held here, and the pairs
@@ -688,7 +703,7 @@ def held_experts_ffn(y, experts, weights, w_gate, w_up, w_down,
         here &= valid[:, None]
     local = jnp.where(here, experts - lo, n_held)
     if not kernel_eligible(t, d, w_gate.shape[2], w_gate.dtype):
-        return _scan_of_tiles(y, local, weights, w_gate, w_up, w_down)
+        return _scan_of_tiles(y, local, weights, w_gate, w_up, w_down, limit)
     if t <= _ROWS_MOST:
-        return _one_tile(y, local, weights, w_gate, w_up, w_down)
-    return _sorted_tiles(y, local, weights, w_gate, w_up, w_down)
+        return _one_tile(y, local, weights, w_gate, w_up, w_down, limit)
+    return _sorted_tiles(y, local, weights, w_gate, w_up, w_down, limit)

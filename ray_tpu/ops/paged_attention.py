@@ -87,6 +87,15 @@ The model counts the live pages its steps' tables named a group
 groups they were fetched in (`decode_kv_page_groups_read*`, by
 `page_groups`, the same arithmetic on the host).
 
+A **latent pool** (`ops/latent_attention.py`: a position's row is one
+piece of values for every query head, held in planes of 128 lanes, its
+value the row's own first lanes) is walked by the same kernel, tables,
+walks, slabs and eight-a-turn copies, with a body of its own
+(`_attend_latent`, under the name ``paged_latent_decode_attention``):
+the step's query heads meet a pass's rows in one product a plane, in the
+pool's dtype, and a second product a value plane reads the same slab
+again: one fetch a page, never two.
+
 `paged_decode_attention` picks by what it can see (`kernel_eligible`:
 the backend, the head size and the head count), as
 `attention._flash_eligible` picks flash.
@@ -578,6 +587,40 @@ def _attend_planes(q, plane, first, position, until, m_ref, l_ref, acc_ref,
                  preferred_element_type=f32) for j in range(n_kv)], axis=0)
 
 
+def _attend_latent(q, plane, first, position, until, m_ref, l_ref, acc_ref,
+                   *, scale: float, window=None):
+    """One online-softmax update over a pass's pages of a *latent* pool
+    (`ops/latent_attention.py`): a position's row is one piece of
+    ``held`` values for every query head, the planes side by side
+    (``plane(p)`` ``[T, 128]``, the row's lanes ``128 p`` on, in the
+    pool's dtype), and its value is the row's own first ``acc_ref.shape[1]``
+    lanes: the pass is fetched once and read twice. The step's ``H``
+    query heads meet the pass in one product a plane, added; a second
+    product a value plane takes the probabilities to the values. Both
+    take their operands in the pool's dtype and accumulate in float32
+    (the model's rule; a float32 pool is met in float32)."""
+    f32 = jnp.float32
+    lanes = plane(0).shape[1]
+    planes = [plane(p) for p in range(q.shape[1] // lanes)]
+    q = q.astype(planes[0].dtype)
+    scores = sum(jax.lax.dot_general(
+        q[:, p * lanes:(p + 1) * lanes], rows, (((1,), (1,)), ((), ())),
+        preferred_element_type=f32)
+        for p, rows in enumerate(planes)) * scale            # [H, T]
+    at = first + jax.lax.broadcasted_iota(jnp.int32, (1, scores.shape[1]), 1)
+    scores = jnp.where(_seen(at, position, until, window), scores, _NEG_INF)
+    m_prev = m_ref[...]
+    m_next = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_next)
+    p = jnp.exp(scores - m_next)                             # [H, T]
+    m_ref[...] = m_next
+    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+    p = p.astype(planes[0].dtype)
+    acc_ref[...] = alpha * acc_ref[...] + jnp.concatenate(
+        [jnp.dot(p, rows, preferred_element_type=f32)
+         for rows in planes[:acc_ref.shape[1] // lanes]], axis=1)
+
+
 # A row's walk, as `_row_walks` hands it to the kernel.
 _FIRST, _COUNT, _SIZE, _GROUPS, _BLOCK0 = range(5)
 
@@ -607,7 +650,8 @@ def _row_walks(positions, starts, block_size: int, table_width: int,
 
 def _kernel_body(tables_ref, positions_ref, layer_ref, walks_ref, *refs,
                  block_size: int, scale: float, window, with_sink: bool,
-                 with_keep: bool = False, with_own_keep: bool = False):
+                 with_keep: bool = False, with_own_keep: bool = False,
+                 latent: bool = False):
     """One grid step is one row. Its live table columns are walked in
     groups (`_row_walks`): a group's pages are copied from the pool in
     HBM into one of two VMEM slabs while the group before it is attended
@@ -682,7 +726,9 @@ def _kernel_body(tables_ref, positions_ref, layer_ref, walks_ref, *refs,
                           own_keep_ref[row] if with_own_keep else None)
     position = positions_ref[row]
     first, n, size, n_groups, block0 = (walks_ref[row, k] for k in range(5))
-    if planes:
+    if latent:
+        attend = functools.partial(_attend_latent, scale=scale)
+    elif planes:
         attend = functools.partial(_attend_planes, n_kv=k_new_ref.shape[0],
                                    scale=scale, window=window)
     else:
@@ -734,11 +780,14 @@ def _kernel_body(tables_ref, positions_ref, layer_ref, walks_ref, *refs,
                 if planes:
                     # A plane of the pass's pages: whole tiles, and the
                     # reshape moves nothing.
+                    # (A latent pool's planes go to their products in
+                    # the pool's dtype.)
                     def kv(p):
-                        return (slabs[slab, pl.ds(page, a_pass), p]
-                                .astype(jnp.float32)
-                                .reshape(a_pass * block_size,
-                                         slabs.shape[-1]))
+                        rows = slabs[slab, pl.ds(page, a_pass), p]
+                        if not latent:
+                            rows = rows.astype(jnp.float32)
+                        return rows.reshape(a_pass * block_size,
+                                            slabs.shape[-1])
                 else:
                     kv = slabs[slab, pl.ds(page, a_pass)].astype(jnp.float32)
                     kv = kv.reshape((a_pass * block_size,) + kv.shape[2:])
@@ -760,13 +809,14 @@ def _kernel_body(tables_ref, positions_ref, layer_ref, walks_ref, *refs,
 
 
 @functools.partial(jax.jit, static_argnames=("window", "pages", "interpret",
-                                             "name"))
+                                             "name", "latent_scale"))
 def paged_decode_attention_kernel(q, k_new, v_new, pool, tables,
                                   positions, layer, window: int = None,
                                   starts=None, sink=None, keep=None,
                                   own_keep=None, *, pages: int = None,
                                   interpret: bool = False,
-                                  name: str = None):
+                                  name: str = None,
+                                  latent_scale: float = None):
     """Same arguments and result as `paged_decode_attention_xla`. The
     pool stays in HBM as it stands; the kernel copies a row's live pages
     of one layer into VMEM, `pages` at a time (`pages_per_step`, where
@@ -781,7 +831,12 @@ def paged_decode_attention_kernel(q, k_new, v_new, pool, tables,
     VMEM. A call without one traces what it always has. `name`: what a
     device trace calls the kernel where the default (the module
     docstring's two names) would mislead: `sparse_paged_decode_attention`
-    alone passes one."""
+    and the latent walk pass one. `latent_scale` (the softmax scale, a
+    float): `pool` is a *latent* pool held by planes, ``[N, L, P, bs,
+    128]`` (`ops/latent_attention.py`), q ``[B, H, 128 P]`` and k_new
+    ``[B, 1, 128 P]`` are as wide as its row, v_new ``[B, 1, dv]`` is
+    the row's first ``dv`` lanes, and the body is `_attend_latent`: the
+    same tables, walks, slabs and copies."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -789,8 +844,12 @@ def paged_decode_attention_kernel(q, k_new, v_new, pool, tables,
     nb = tables.shape[1]
     bs = pool_block_size(pool)
     hkv, dv = v_new.shape[1:]
-    page = ((kv_slots(dk, dv) * hkv, bs, dv) if by_planes(pool)
-            else (bs, kv_slots(dk, dv), hkv, dv))
+    latent = latent_scale is not None
+    if latent:
+        page = (dk // pool.shape[-1], bs, pool.shape[-1])
+    else:
+        page = ((kv_slots(dk, dv) * hkv, bs, dv) if by_planes(pool)
+                else (bs, kv_slots(dk, dv), hkv, dv))
     if (_page_shape(pool) != page or h % hkv
             or k_new.shape != (b, hkv, dk)):
         raise ValueError(f"pool {pool.shape} does not hold K rows of "
@@ -806,8 +865,9 @@ def paged_decode_attention_kernel(q, k_new, v_new, pool, tables,
         prefetched.append(own_keep.astype(jnp.int32))
     if keep is not None and h == hkv:
         raise ValueError("a keep mask goes with grouped heads")
-    q, k_new = (_as_wide_as_the_pools_keys(x, pool, hkv)
-                for x in (q, k_new))
+    if not latent:
+        q, k_new = (_as_wide_as_the_pools_keys(x, pool, hkv)
+                    for x in (q, k_new))
     held = q.shape[2]
 
     def row_map(row, *prefetched_refs):
@@ -830,10 +890,11 @@ def paged_decode_attention_kernel(q, k_new, v_new, pool, tables,
             keep.reshape(b, nb, bs).astype(jnp.float32),
             ((0, 0), (0, pages), (0, 0))))
     return pl.pallas_call(
-        functools.partial(_kernel_body, block_size=bs, scale=dk ** -0.5,
+        functools.partial(_kernel_body, block_size=bs,
+                          scale=latent_scale if latent else dk ** -0.5,
                           window=window, with_sink=sink is not None,
                           with_keep=keep is not None,
-                          with_own_keep=own_keep is not None),
+                          with_own_keep=own_keep is not None, latent=latent),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetched),
             grid=(b,),

@@ -97,3 +97,16 @@ def rotary_cos_sin_sections(positions, inv_freq, sections):
     ang = positions.astype(jnp.float32)[..., None] * inv_freq   # [n, T, half]
     ang = ang[stream, :, np.arange(half)].T                      # [T, half]
     return jnp.cos(ang), jnp.sin(ang)
+
+
+def apply_rotary_interleaved(x, cos, sin):
+    """Rotate every head of x ``[T, H, D]`` whole, pairing value ``2 i``
+    with value ``2 i + 1`` (`rope_interleave`: the pairs lie side by
+    side, where `apply_rotary_partial` pairs ``i`` with ``i + D / 2``).
+    cos, sin ``[T, D // 2]`` at the tokens' positions (`rotary_cos_sin`).
+    Computed and returned in float32."""
+    x = x.astype(jnp.float32)
+    pairs = x.reshape(x.shape[:-1] + (x.shape[-1] // 2, 2))
+    a, b = pairs[..., 0], pairs[..., 1]
+    c, s = cos[:, None, :], sin[:, None, :]
+    return jnp.stack([a * c - b * s, a * s + b * c], axis=-1).reshape(x.shape)

@@ -51,6 +51,7 @@ whose end was found a step late (`decode_ends_found_late`).
 
 from ray_tpu.serve.engine.kv_cache import (CacheOverflowError,
                                            KVCacheManager)
+from ray_tpu.serve.engine.gigachat_model import GigaChatEngineModel
 from ray_tpu.serve.engine.hybrid_model import HybridEngineModel
 from ray_tpu.serve.engine.keye_model import KeyeEngineModel
 from ray_tpu.serve.engine.laguna_model import LagunaEngineModel
@@ -64,7 +65,8 @@ from ray_tpu.serve.engine.scheduler import (EngineConfig,
 
 __all__ = [
     "CacheOverflowError", "EngineConfig", "EngineOverloadedError",
-    "EngineStoppedError", "HybridEngineModel", "InferenceEngine",
+    "EngineStoppedError", "GigaChatEngineModel", "HybridEngineModel",
+    "InferenceEngine",
     "KVCacheManager", "KeyeEngineModel", "LagunaEngineModel", "MimoEngineModel",
     "PrefixIndex", "TinyLM", "TokenStream", "TransformerEngineModel",
 ]

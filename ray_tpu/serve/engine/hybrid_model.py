@@ -32,29 +32,12 @@ keeps its state bit for bit, and no copy of the batch's state is built.
 
 from __future__ import annotations
 
-from typing import List, Sequence
-
-import numpy as np
-
-from ray_tpu.core import flight
-from ray_tpu.serve.engine.model import (PromptKV, _next_pow2,
-                                        place_sources, step_tokens)
-from ray_tpu.serve.engine.sparse_model import SparseEngineModel
+from ray_tpu.serve.engine.model import step_tokens
+from ray_tpu.serve.engine.state_model import (PromptState,  # noqa: F401
+                                              StateEngineModel)
 
 
-class PromptState(PromptKV):
-    """A prefill's KV rows with the state the prompt ended on: `state`,
-    a dict of device arrays a sequence (`state_shapes`), which
-    `KVCacheManager.write_range` stores in the sequence's slot."""
-
-    __slots__ = ("state",)
-
-    def __init__(self, padded, n: int, state: dict):
-        super().__init__(padded, n)
-        self.state = state
-
-
-class HybridEngineModel(SparseEngineModel):
+class HybridEngineModel(StateEngineModel):
     """Incremental decoding over `models/hybrid_moe.py` weights.
 
     KV entry a token: ``[n_periods, 2, n_kv_heads, head_dim]`` (the GQA
@@ -63,10 +46,17 @@ class HybridEngineModel(SparseEngineModel):
     taps - 1, 3 H dk]``, the last inputs of the short convolutions.
     Prefill runs the prompt once (chunked delta rule, `kda_chunk`
     positions a chunk) in pow2 length buckets; a decode step is jitted a
-    (batch, table) bucket. A prompt is never prefilled from an offset:
-    the engine adopts no prefix over a model with state. The norm, the
-    product helper, the expert layer, the counters and the host side of
-    a call are `sparse_model.SparseEngineModel`'s."""
+    (batch, table) bucket. A prompt is prefilled whole, never from an
+    adopted prefix (the engine adopts none over a model with state) and
+    never in chunks: this model offers no `prefill_chunk`, and its prompt
+    attention builds a prompt's whole score matrix. (The chunk that
+    carries a state from its sequence's slot is
+    `gigachat_model.GigaChatEngineModel.prefill_chunk`, over
+    `state_model.py`, which also holds what the two models share: the
+    payload, the host side of a decode step, the short convolution and
+    the slot-order ends of a delta-rule layer's step.) The norm, the
+    product helper, the expert layer and the counters are
+    `sparse_model.SparseEngineModel`'s."""
 
     def __init__(self, params, cfg, max_batch_size: int = 8,
                  jit_cache_cap: int = 32, kda_chunk: int = 64):
@@ -111,16 +101,11 @@ class HybridEngineModel(SparseEngineModel):
         cfg, f32 = self._cfg, jnp.float32
         t = y.shape[0]
         h, dk = cfg.kda_heads, cfg.kda_head_dim
-        mixed = jax.nn.silu(jnp.sum(
-            window.astype(f32) * lp["conv"][:, None, :], axis=0))
+        mixed = self._short_conv(window, lp["conv"])
         q, k, v = (mixed[:, i * h * dk:(i + 1) * h * dk].reshape(t, h, dk)
                    for i in range(3))
 
-        def l2norm(x):
-            return x * jax.lax.rsqrt(
-                jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
-
-        q, k = l2norm(q) * dk ** -0.5, l2norm(k)
+        q, k = self._l2norm(q) * dk ** -0.5, self._l2norm(k)
         g = -jnp.exp(lp["a_log"])[None, :, None] * jax.nn.softplus(
             self._mm(self._mm(y, lp["wf1"]), lp["wf2"])
             + lp["dt_bias"]).reshape(t, h, dk)
@@ -182,10 +167,8 @@ class HybridEngineModel(SparseEngineModel):
                 pre = jnp.concatenate(
                     [self._mm(y, lp[w]) for w in ("wq", "wk", "wv")],
                     axis=-1).astype(act)                   # [S, 3 H dk]
-                padded = jnp.concatenate(
-                    [jnp.zeros((taps - 1, pre.shape[1]), act), pre])
-                window = jnp.stack([padded[j:j + s_pad]
-                                    for j in range(taps)])
+                padded, window = self._prompt_window(
+                    pre, jnp.zeros((taps - 1, pre.shape[1]), act), taps)
                 q, k, v, g, beta = self._kda_inputs(y, lp, window, live)
                 o, s_end = delta_rule_chunked(
                     q, k, v, g, beta, jnp.zeros((h, dk, dk), f32), chunk)
@@ -267,26 +250,16 @@ class HybridEngineModel(SparseEngineModel):
 
             def kda(x, ln, lp, state, layer):
                 # Slot order: row i's input at slot slots[i].
-                y = jnp.zeros((n_slots, x.shape[1]), f32).at[slots].set(
-                    self._norm(x, ln), mode="drop")
-                tail = jax.lax.dynamic_index_in_dim(
-                    state["conv"], layer, axis=1, keepdims=False)
-                s = jax.lax.dynamic_index_in_dim(
-                    state["s"], layer, axis=1, keepdims=False)
-                pre = jnp.concatenate(
-                    [self._mm(y, lp[w]) for w in ("wq", "wk", "wv")],
-                    axis=-1).astype(tail.dtype)
-                window = jnp.concatenate([tail, pre[:, None]], axis=1)
+                y, tail, s, window = self._slot_inputs(
+                    x, ln, state, layer, slots,
+                    lambda y: jnp.concatenate(
+                        [self._mm(y, lp[w]) for w in ("wq", "wk", "wv")],
+                        axis=-1))
                 q, k, v, g, beta = self._kda_inputs(
                     y, lp, window.transpose(1, 0, 2), used)
                 o, s = delta_rule_step(s, q, k, v, g, beta)
-                new_tail = jnp.where(used[:, None, None], window[:, 1:],
-                                     tail)
-                state = {
-                    "s": jax.lax.dynamic_update_index_in_dim(
-                        state["s"], s, layer, axis=1),
-                    "conv": jax.lax.dynamic_update_index_in_dim(
-                        state["conv"], new_tail, layer, axis=1)}
+                state = self._slot_store(state, layer, s, window, tail,
+                                         used)
                 out = self._kda_output(y, o, lp)
                 return x + out[jnp.minimum(slots, n_slots - 1)], state
 
@@ -325,75 +298,3 @@ class HybridEngineModel(SparseEngineModel):
             return self._step_out(ids, counts, b_pad), logits, new_pool, state
 
         return jax.jit(decode_paged, donate_argnums=(0, 1))
-
-    # -- engine interface ----------------------------------------------
-    def prefill(self, tokens: Sequence[int]):
-        """Run the prompt. Returns the host logits that predict the next
-        token and a `PromptState`: the prompt's KV rows and the state it
-        ended on, both still on the device."""
-        with flight.span("model", "prefill", len(tokens)):
-            return self._prefill(tokens)
-
-    def _prefill(self, tokens: Sequence[int]):
-        logits, (kv, state), n = self._run_prefill(tokens)
-        return logits, PromptState(kv, n, state)
-
-    def decode_paged(self, pool, block_tables: List[Sequence[int]],
-                     last_tokens: Sequence[int],
-                     positions: Sequence[int],
-                     write_blocks: Sequence[int],
-                     write_offs: Sequence[int], block_size: int,
-                     state=None, slots: Sequence[int] = (), *,
-                     meanwhile=None, ahead=None):
-        """One fused step, as `TransformerEngineModel.decode_paged`,
-        over both pools: `state` is the cache's state pool and
-        `slots[i]` row i's slot (a list shorter than the batch leaves
-        the other rows without a slot: they read and write no state,
-        as in a warm-up). Returns ``(step, new_pool, new_state)``; both
-        pools were donated."""
-        with flight.span("model", "decode", len(last_tokens)):
-            return self._decode_paged(pool, block_tables, last_tokens,
-                                      positions, write_blocks, write_offs,
-                                      block_size, state, slots, meanwhile,
-                                      ahead)
-
-    def _decode_paged(self, pool, block_tables, last_tokens, positions,
-                      write_blocks, write_offs, block_size: int, state,
-                      slots, meanwhile, ahead):
-        phase = self.phase
-        b = len(last_tokens)
-        self.decode_calls += 1
-        with flight.span("model", "decode.prep", None, phase,
-                         "decode_prep_s"):
-            b_pad = _next_pow2(max(b, 1))
-            pages = [int(p) // block_size + 1 for p in positions]
-            nb_pad = _next_pow2(max(max(pages), 1))
-            if self._attn_inplace:
-                self.decode_attn_inplace_steps += 1
-                self.decode_kv_pages_read += sum(pages)
-                self.decode_kv_page_groups_read += self._page_groups(
-                    pool, nb_pad, positions)
-            key = (b_pad, nb_pad, block_size)
-            fn = self._decode_paged_jit.get(key)
-            if fn is None:
-                fn = self._decode_paged_jit[key] = \
-                    self._build_decode_paged(*key)
-            # One host buffer, a row a sequence; a write block past the
-            # pool and a slot past the state pool are dropped.
-            packed = np.zeros((b_pad, 6 + nb_pad), np.int32)
-            packed[:, 2] = int(pool.shape[0])
-            packed[:, 4] = int(state["s"].shape[0])
-            for i in range(b):
-                table = block_tables[i][:nb_pad]
-                packed[i, 0] = last_tokens[i]
-                packed[i, 1] = positions[i]
-                packed[i, 5:5 + len(table)] = table
-            k = min(len(write_blocks), b)
-            packed[:k, 2] = write_blocks[:k]
-            packed[:k, 3] = write_offs[:k]
-            packed[:min(len(slots), b), 4] = slots[:b]
-            place_sources(packed, ahead)
-            args = (pool, state, self._params, packed)
-        step, (new_pool, new_state) = self._run_decode(
-            fn, args, b, b_pad, meanwhile, ahead)
-        return step, new_pool, new_state

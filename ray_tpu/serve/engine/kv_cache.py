@@ -291,6 +291,8 @@ class KVCacheManager:
     `stats()` callers race)."""
 
     GLOBAL = "global"       # the name of the group this manager is
+    STATE = "state"         # and of the state pool, where `with_pools`
+                            # hands a model with state both by name
 
     def __init__(self, num_blocks: int, block_size: int,
                  kv_shape: Tuple[int, ...] = (), dtype=np.float32,
@@ -968,8 +970,14 @@ class KVCacheManager:
         """`with_pool` for a model that reads every layer group (a chunk
         of a prompt reads the positions before it): ``fn(pool)``, or
         with layer groups ``fn({group: pool})``, under the cache lock,
-        which every write into a group's pool is made under too."""
+        which every write into a group's pool is made under too. Beside
+        a state pool (a chunk of a prompt begins from its sequence's
+        slot: `slot_of`) ``fn({"global": pool, "state": {name:
+        pool}})``; the state pool is read, not donated."""
         with self._lock:
+            if self._state is not None:
+                return fn({self.GLOBAL: self._buffer,
+                           self.STATE: self._state})
             if not self.grouped:
                 return fn(self._buffer)
             return fn({**{name: g._buffer

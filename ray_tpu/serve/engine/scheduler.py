@@ -45,9 +45,11 @@ A model with per-sequence state (`state_shapes`: a recurrent or
 linear-attention layer's state) gets a slot a sequence in the cache
 beside its blocks, sized here from `max_batch_size`, and no prefix
 sharing: blocks of KV do not restore such a sequence's prefix, so no
-index is built, every prompt is prefilled whole, and a preempted row's
-slot is freed and its state recomputed by prefill. Decided from what the
-model declares; no option.
+index is built, every prompt is prefilled from position 0, and a
+preempted row's slot is freed and its state recomputed by prefill (as is
+a prompt's that was cancelled, failed or requeued in flight: `free`
+gives the slot back with the blocks). Decided from what the model
+declares; no option.
 
 A prefill hands its KV over in two calls, with or without a prefix hit:
 the model's prefill returns the logits and the KV (`len()` rows), and
@@ -119,7 +121,9 @@ waits behind more than one chunk. One prompt is in flight at a time;
 nothing else is admitted until its last chunk has given its first token
 and it has joined the batch. With nothing running the chunks follow
 each other at once. A chunk's call takes `meanwhile` as a decode step's
-does. A shorter prompt, one with a prefix hit, and every prompt of a
+does. Over a model with state (`gigachat_model.py`) the chunk is also
+handed the sequence's state slot, begins from what it holds, and its
+payload carries the state it ended on back into the slot. A shorter prompt, one with a prefix hit, and every prompt of a
 model without the call are prefilled whole.
 """
 
@@ -1011,10 +1015,16 @@ class InferenceEngine:
         end = min(n, start + self._chunk)
         sp.arg = f"tokens={end - start} prefix_hit=0 chunk_at={start}"
         tables = self.cache.step_tables(seq.seq_id)
+        # Over a model with state the chunk begins from the sequence's
+        # slot (None before its first chunk is stored: that one begins
+        # from nothing) and its payload carries the state it ended on,
+        # which `write_range` below puts back.
+        carried = ({"slot": self.cache.slot_of(seq.seq_id)}
+                   if self.cache.state_slots else {})
         logits, kv = self.cache.with_pools(
             lambda pools: self.model.prefill_chunk(
                 tokens, pools, tables, start, self.config.block_size,
-                meanwhile=self._in_shadow))
+                meanwhile=self._in_shadow, **carried))
         if not self.cache.allocate(seq.seq_id, end, writable_from=start):
             self._in_flight = None
             self._requeue_at_head(seq)

@@ -21,7 +21,8 @@ class SparseEngineModel(StepIds):
     """Base of an engine model over seeded weights `params` and a config
     `cfg` with `vocab_size`, `norm_eps`, `dtype`, `top_k`,
     `routed_scaling`, `experts_held` (and `router_scoring`, where the
-    router does not score by sigmoid). A subclass builds its jitted
+    router does not score by sigmoid; `swiglu_limit`, where a gated
+    feed-forward clamps its two factors). A subclass builds its jitted
     programs (`_build_prefill(s_pad)`) and packs its decode row."""
 
     def __init__(self, params, cfg, jit_cache_cap: int = 32,
@@ -97,16 +98,36 @@ class SparseEngineModel(StepIds):
                        preferred_element_type=jnp.float32)
 
     def _gated_ffn(self, y, gate, up, down):
-        """``W_down(silu(W_gate y) * W_up y)``."""
+        """``W_down(silu(W_gate y) * W_up y)``, both factors clamped at
+        the model's `swiglu_limit` where it has one (as
+        `ops.experts.gated`)."""
         import jax
+        import jax.numpy as jnp
 
-        return self._mm(jax.nn.silu(self._mm(y, gate)) * self._mm(y, up),
-                        down)
+        # (Written out, not through `gated`: the products and the silu
+        # keep the order they have always had in a program.)
+        limit = getattr(self._cfg, "swiglu_limit", None)
+        a = self._mm(y, gate)
+        if limit is not None:
+            a = jnp.minimum(a, limit)
+        a, b = jax.nn.silu(a), self._mm(y, up)
+        if limit is not None:
+            b = jnp.clip(b, -limit, limit)
+        return self._mm(a * b, down)
 
     def _experts(self, x, ln2, mp, valid):
         """The expert layer's residual add (with the shared expert's,
         where the layer's tree has one); returns the new `x` and the
         layer's three counts."""
+        routed, x, counts = self._experts_of(self._norm(x, ln2), mp, valid,
+                                             x)
+        return x + routed, counts
+
+    def _experts_of(self, y, mp, valid, onto=None):
+        """The expert layer over its normed input `y`: the held experts'
+        part of the routed sum, the shared expert's output (None where
+        the layer's tree has none; added onto `onto`, the residual
+        stream, where one is given) and the layer's three counts."""
         import jax
         import jax.numpy as jnp
 
@@ -114,7 +135,6 @@ class SparseEngineModel(StepIds):
                                          route)
 
         cfg = self._cfg
-        y = self._norm(x, ln2)
         self._experts_kernel_at[y.shape[0]] = kernel_eligible(
             *y.shape, mp["w_gate"].shape[2], mp["w_gate"].dtype)
         with jax.named_scope("moe_route"):
@@ -125,13 +145,17 @@ class SparseEngineModel(StepIds):
         with jax.named_scope("moe_experts"):
             routed, load = held_experts_ffn(
                 y, experts, weights, mp["w_gate"], mp["w_up"],
-                mp["w_down"], cfg.experts_held, valid)
+                mp["w_down"], cfg.experts_held, valid,
+                getattr(cfg, "swiglu_limit", None))
+            shared = onto
             if "shared_gate" in mp:
-                x = x + self._gated_ffn(y, mp["shared_gate"],
-                                        mp["shared_up"], mp["shared_down"])
+                shared = self._gated_ffn(y, mp["shared_gate"],
+                                         mp["shared_up"], mp["shared_down"])
+                if onto is not None:
+                    shared = onto + shared
         counts = jnp.stack([jnp.sum(load), jnp.sum(load > 0),
                             jnp.max(load)]).astype(jnp.int32)
-        return x + routed, counts
+        return routed, shared, counts
 
     def _count_experts_step(self, rows: int) -> None:
         """A program of `rows` rows has been dispatched (so traced)."""

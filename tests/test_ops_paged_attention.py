@@ -1331,3 +1331,114 @@ def test_keye_programs_compile_for_v5e_with_the_planes_pool_in_place(
     # layer's keys and values a head at a time, a chunk's activations.
     assert memory.temp_size_in_bytes < 300e6
     assert memory.output_size_in_bytes < 40e6
+
+
+# ---------------------------------------------------------------------------
+# a latent pool (PR 61): the walk's latent body and
+# `gigachat3.5-432b-a28b`'s programs over it at the published widths
+# ---------------------------------------------------------------------------
+GIGACHAT_POOL = (34816, 1, 5, 16, 128)          # 0.71 GB in bf16
+
+
+def _gigachat_on(one_chip, monkeypatch):
+    """The family's model over described parameters, steered onto the
+    chip's branches."""
+    import json
+    import os
+
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks.harness import manifest
+    from ray_tpu.models.gigachat35 import init_params
+    from ray_tpu.serve.engine import GigaChatEngineModel
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    family = manifest.load_family("gigachat3_5")
+    with open(os.path.join(manifest.ROOT, "benchmarks", "configs",
+                           "gigachat3.5-432b-a28b.json")) as f:
+        cfg = family.model_config(family.widths(json.load(f)))
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(on_chip, jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    model = GigaChatEngineModel(params, cfg, max_batch_size=32)
+    assert model._attn_inplace and model.kv_token_shape == GIGACHAT_POOL[1:3] \
+        + GIGACHAT_POOL[4:]
+    pool = jax.ShapeDtypeStruct(GIGACHAT_POOL, jnp.bfloat16,
+                                sharding=one_chip)
+    state = {name: jax.ShapeDtypeStruct((32,) + tuple(shape), dt,
+                                        sharding=one_chip)
+             for name, (shape, dt) in model.state_shapes.items()}
+    return model, params, pool, state
+
+
+def test_latent_kernel_compiles_for_v5e_at_the_published_widths(
+        one_chip, no_compile_cache):
+    """32 rows of 64 query heads of 640 over a table of 1,024 blocks of a
+    pool of 576-value rows held in 5 planes: the walk's kernel under the
+    latent body's name, the pool not copied."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import latent_attention as la
+
+    def spec(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(lambda q, row, pool, tables, positions:
+                       la.paged_latent_decode_attention(
+                           q, row, pool, tables, positions, jnp.int32(0),
+                           512, 0.1, interpret=False)).lower(
+        spec(32, 64, 640), spec(32, 640, dtype=jnp.bfloat16),
+        spec(*GIGACHAT_POOL, dtype=jnp.bfloat16),
+        spec(32, 1024, dtype=jnp.int32),
+        spec(32, dtype=jnp.int32)).compile()
+    assert "paged_latent_decode_attention" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 20e6
+
+
+def test_gigachat_decode_program_compiles_for_v5e_in_place(
+        one_chip, no_compile_cache, monkeypatch):
+    """A full step of 32 rows over the 1,024-block table bucket at the
+    published widths: one call of the latent walk, four of the decode
+    experts' kernel (d 7,168 x f 2,048), both pools donated and neither
+    copied."""
+    import jax
+    import jax.numpy as jnp
+
+    model, params, pool, state = _gigachat_on(one_chip, monkeypatch)
+    compiled = model._build_decode_paged(32, 1024, 16).lower(
+        pool, state, params,
+        jax.ShapeDtypeStruct((32, 6 + 1024), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((model._ids_width(32) + 3,), jnp.int32,
+                             sharding=one_chip)).compile()
+    memory, text = compiled.memory_analysis(), compiled.as_text()
+    assert text.count("paged_latent_decode_attention") >= 1
+    assert text.count("held_experts_ffn_decode") >= 4
+    assert memory.temp_size_in_bytes < 100e6
+
+
+def test_gigachat_chunk_program_compiles_for_v5e_beside_both_pools(
+        one_chip, no_compile_cache, monkeypatch):
+    """A chunk of 1,024 positions of a prompt in the 16,384 bucket: the
+    expanded keys and values of 16,384 rows a head are what the program
+    holds beside its arguments (1.2 GB), one call of the causal forward
+    from an offset, four expert layers through the prompt's kernel."""
+    import jax
+    import jax.numpy as jnp
+
+    model, params, pool, state = _gigachat_on(one_chip, monkeypatch)
+    compiled = model._build_prefill_chunk(16384, 16).lower(
+        pool, state, params,
+        jax.ShapeDtypeStruct((1024 + 3 + 1024,), jnp.int32,
+                             sharding=one_chip)).compile()
+    memory, text = compiled.memory_analysis(), compiled.as_text()
+    assert "jit_prefill_chunk" in text
+    assert text.count("flash_prefill_fwd_causal") >= 1
+    assert text.count("held_experts_ffn_prefill") >= 4
+    assert memory.temp_size_in_bytes < 1.5e9
+    # The chunk's latent rows, the state it ended on and the logits.
+    assert memory.output_size_in_bytes < 40e6
