@@ -432,7 +432,10 @@ class GigaChatEngineModel(StateEngineModel):
         `write_range(seq, start, ...)`.
 
         A program of its own between two decode steps; `meanwhile` (the
-        protocol's: `model.py`) runs between the dispatch and the wait.
+        protocol's: `model.py`) runs behind the dispatch, and only the
+        prompt's last chunk is waited for
+        (`sparse_model._prompt_logits`): the state an earlier chunk ended
+        on goes into its slot as the unfinished device value it is.
         One program a power of two of the prompt's length, and one for
         every prompt of up to four chunks, as the layer-groups models'."""
         with flight.span("model", "prefill", len(tokens)):
@@ -479,13 +482,7 @@ class GigaChatEngineModel(StateEngineModel):
         self._count_experts_step(c)
         if meanwhile is not None:
             meanwhile()
-        with flight.span("model", "prefill.logits_wait", None, phase,
-                         "prefill_wait_s"):
-            if start + length == n:
-                logits = np.asarray(logits)
-            else:
-                state["s"].block_until_ready()
-                logits = None
+        logits = self._prompt_logits(logits, start + length == n)
         return logits, PromptState(rows, length, state)
 
     def _count_pages(self, pool, pages, nb_pad: int, positions,

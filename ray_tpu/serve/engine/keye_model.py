@@ -462,13 +462,7 @@ class KeyeEngineModel(SparseEngineModel):
         self._count_experts_step(c)
         if meanwhile is not None:
             meanwhile()
-        with flight.span("model", "prefill.logits_wait", None, phase,
-                         "prefill_wait_s"):
-            if start + length == n:
-                logits = np.asarray(logits)
-            else:
-                index_rows.block_until_ready()
-                logits = None
+        logits = self._prompt_logits(logits, start + length == n)
         return logits, self._prompt_rows(kv, index_rows, length)
 
     def decode_paged(self, pools, block_tables: List[dict],

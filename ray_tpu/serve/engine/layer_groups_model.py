@@ -399,9 +399,16 @@ class LayerGroupsEngineModel(SparseEngineModel):
         else None, and a `PromptGroups` of the chunk's rows, on the
         device, for `write_range(seq, start, ...)`.
 
-        A program of its own between two decode steps, and the call
-        returns when the device has finished it. `meanwhile` (the
-        protocol's: `model.py`) runs between the dispatch and the wait.
+        A program of its own between two decode steps. `meanwhile` (the
+        protocol's: `model.py`) runs behind the dispatch. The chunk that
+        holds the prompt's last token is read (its logits); any other
+        returns as soon as it is dispatched, its rows unfinished device
+        values (`sparse_model._prompt_logits`). The device runs programs
+        in dispatch order, and that is what orders things: a block that
+        `allocate` gives back after this call (``writable_from=start``,
+        a window group's expired blocks) and hands to another sequence
+        can only be written by a program behind this chunk, which has
+        read it by then.
         One program a power of two of the prompt's length, in which the
         global keys lie, and one for every prompt of up to four chunks:
         the chunk's place is a scalar to it, a key tile past the chunk's
@@ -453,13 +460,7 @@ class LayerGroupsEngineModel(SparseEngineModel):
         self._count_experts_step(c)
         if meanwhile is not None:
             meanwhile()
-        with flight.span("model", "prefill.logits_wait", None, phase,
-                         "prefill_wait_s"):
-            if start + length == n:
-                logits = np.asarray(logits)
-            else:
-                kv_window.block_until_ready()
-                logits = None
+        logits = self._prompt_logits(logits, start + length == n)
         return logits, PromptGroups(
             kv_global, length, {WINDOW: PromptKV(kv_window, length)})
 

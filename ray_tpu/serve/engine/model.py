@@ -109,8 +109,11 @@ for ``write_range(seq, start, kv)`` and, from the chunk that holds the
 prompt's last token alone, the logits ``prefill`` would return. The
 scheduler then prefills a prompt longer than ``C`` a chunk an
 iteration, a decode step of the running batch between two chunks
-(`scheduler.py`); ``meanwhile`` as in ``decode_paged``. The call returns
-when the chunk's work is done, not behind the host's back.
+(`scheduler.py`); ``meanwhile`` as in ``decode_paged``, behind the
+dispatch. Only the chunk that yields logits is waited for: any other
+returns once dispatched, its ``kv`` unfinished device values that
+``write_range`` and the next decode step take as they are (the device
+runs programs in dispatch order; `sparse_model._prompt_logits`).
 
 Four implementations:
 

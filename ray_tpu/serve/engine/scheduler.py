@@ -121,10 +121,16 @@ waits behind more than one chunk. One prompt is in flight at a time;
 nothing else is admitted until its last chunk has given its first token
 and it has joined the batch. With nothing running the chunks follow
 each other at once. A chunk's call takes `meanwhile` as a decode step's
-does. Over a model with state (`gigachat_model.py`) the chunk is also
+does. A chunk that is not its prompt's last hands the host nothing and
+is not waited for (the model's rule, `sparse_model._prompt_logits`): its
+rows' write and the iteration's decode step are dispatched behind it,
+and the step's ids are the next thing the loop reads, so the device runs
+chunk, write, step back to back and the host's turn passes beside it.
+Over a model with state (`gigachat_model.py`) the chunk is also
 handed the sequence's state slot, begins from what it holds, and its
-payload carries the state it ended on back into the slot. A shorter prompt, one with a prefix hit, and every prompt of a
-model without the call are prefilled whole.
+payload carries the state it ended on back into the slot. A shorter
+prompt, one with a prefix hit, and every prompt of a model without the
+call are prefilled whole.
 """
 
 from __future__ import annotations
@@ -690,8 +696,10 @@ class InferenceEngine:
         except Exception as e:  # noqa: BLE001 — the loop must survive
             logger.exception("decode step failed; failing %d stream(s)",
                              len(batch))
-            # The step before's tokens come first: those of a step in
-            # flight that can still be read, and what is pending where
+            # (What only the device can raise behind a chunk of a
+            # prompt nobody waited for surfaces here too, at the ids'
+            # read.) The step before's tokens come first: those of a step
+            # in flight that can still be read, and what is pending where
             # the step failed before its `meanwhile`.
             self._land()
             self._deliver()
@@ -849,7 +857,15 @@ class InferenceEngine:
         decoding, not static batching). A prompt in flight comes first:
         its next chunk, and beside a running batch nothing more in this
         iteration (the static policy forms its batch in one pass, so
-        there the chunks follow each other)."""
+        there the chunks follow each other).
+
+        A chunk that is not its prompt's last is not waited for, and
+        this is what bounds how far the host runs ahead of the device:
+        beside a running batch the iteration's decode step, whose ids
+        the loop reads before it comes here again (one chunk, one write,
+        one step in the device's queue at most); with no batch running
+        the loop below dispatches a prompt's chunks one behind the other
+        up to its last, whose logits it reads."""
         with self._lock:
             if self.config.policy == "static" and self._running:
                 # A batch is in flight: hold admissions until it
@@ -879,6 +895,10 @@ class InferenceEngine:
                              # seq is requeued; let the batch make
                              # progress before re-trying admission
             except Exception as e:  # noqa: BLE001
+                # What the host raised, at dispatch or before, or a last
+                # chunk's read: this prompt's alone. (What only the
+                # device raises behind a chunk nobody waited for comes
+                # with the decode step's ids: `_decode_step`.)
                 logger.exception("prefill of %s failed", seq.seq_id)
                 if self._in_flight is seq:
                     self._in_flight = None
@@ -1005,10 +1025,17 @@ class InferenceEngine:
         """The next chunk of the prompt in flight: the model runs it
         over the positions before it as the pools hold them, then the
         cache grows by the chunk (a window group gives back what the
-        chunk's end no longer sees: hence after the model's read) and
-        takes its rows. After the last chunk the sequence joins the
-        batch with its first token. False: the chunk lost its blocks,
-        and the sequence is requeued at the head to begin again."""
+        chunk's end no longer sees: hence behind the model's dispatch)
+        and takes its rows. Only the last chunk is read; any other is on
+        the device, or in front of it, when the call returns, and the
+        rows' write goes out behind it as a donated program like any
+        other. Nothing here needs the chunk finished: the device runs
+        programs in dispatch order, so a block given back below and
+        handed to another sequence is written only behind this chunk's
+        read of it (the sentence `_take` rests on). After the last chunk
+        the sequence joins the batch with its first token. False: the
+        chunk lost its blocks, and the sequence is requeued at the head
+        to begin again."""
         clocks = self._clocks
         tokens = seq.all_tokens      # nothing joins it while in flight
         n, start = len(tokens), seq.prefilled
@@ -1408,8 +1435,12 @@ class InferenceEngine:
         chunks (the model's `prefill_chunk` calls whose rows reached the
         cache), `prefill_chunk_tokens` the prompt tokens they ran: over
         the model's `prefill_tokens`, the share of prefilled tokens that
-        went in chunks. `prefills` and `queue_wait_s` count such a
-        prompt once, `prefill_s` holds every chunk.
+        went in chunks. `prefill_chunks_unwaited` counts, in the model
+        where the wait is skipped, the chunks that handed the host
+        nothing and were not waited for (every chunk but a prompt's
+        last); 0 for a model without the call. `prefills` and
+        `queue_wait_s` count a prompt in chunks once, `prefill_s` holds
+        every chunk's host side.
         `prefill_kv_device_writes` and `prefill_kv_host_writes` count
         the prompt-KV writes into the pool (`write_range`) that stayed
         on the device, and those that passed through host memory.
@@ -1498,6 +1529,8 @@ class InferenceEngine:
             "decode_ends_found_late": self.decode_ends_found_late,
             "prefill_chunks": self.prefill_chunks,
             "prefill_chunk_tokens": self.prefill_chunk_tokens,
+            "prefill_chunks_unwaited": getattr(
+                self.model, "prefill_chunks_unwaited", 0),
             "prefill_kv_device_writes": self.cache.range_writes_device,
             "prefill_kv_host_writes": self.cache.range_writes_host,
             "decode_h2d_arrays": getattr(

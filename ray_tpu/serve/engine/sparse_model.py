@@ -40,6 +40,9 @@ class SparseEngineModel(StepIds):
         self._decode_paged_jit = _JitLRU(jit_cache_cap)
         self.prefill_calls = 0
         self.prefill_tokens = 0
+        # Chunks of prompts that handed the host nothing and were not
+        # waited for (`_prompt_logits`).
+        self.prefill_chunks_unwaited = 0
         self.decode_calls = 0
         self.jit_compiles = 0
         # As `TransformerEngineModel`'s: what a decode step moves across
@@ -186,10 +189,31 @@ class SparseEngineModel(StepIds):
                          "prefill_dispatch_s"):
             logits, *rest = fn(self._params, *args)
         self._count_experts_step(s_pad)
-        with flight.span("model", "prefill.logits_wait", None, phase,
+        return self._prompt_logits(logits), rest, n
+
+    def _prompt_logits(self, logits, last: bool = True):
+        """What a prefill program hands the host, the one rule of every
+        model's `prefill` and `prefill_chunk`. From the program that
+        holds the prompt's last token (`last`: a whole prompt's, or the
+        chunk with ``start + length == n``) the logits of its first
+        token, read here: the call's one wait. From any other chunk
+        None, and NOTHING is waited for: the chunk's rows and state are
+        unfinished device values, which `write_range`'s donated scatter
+        and the batch's decode step take as they are, dispatched behind
+        the chunk, so the host's turn between them passes beside a busy
+        device. What keeps that sound is the device's order, not the
+        host's wait: programs run in dispatch order, so whatever the
+        host does after this call returns (a block `allocate` gives
+        back and hands to another sequence) can reach the device only
+        behind the chunk that still reads it. An error only the device
+        can raise surfaces where its values are next read: the decode
+        step's ids, or the prompt's last chunk here."""
+        if not last:
+            self.prefill_chunks_unwaited += 1
+            return None
+        with flight.span("model", "prefill.logits_wait", None, self.phase,
                          "prefill_wait_s"):
-            logits = np.asarray(logits)
-        return logits, rest, n
+            return np.asarray(logits)
 
     # A decode program's int32 result: the ids at `_ids_width`, then
     # the step's three expert counters.
