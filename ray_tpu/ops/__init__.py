@@ -9,7 +9,11 @@ attention over the serving engine's paged KV pool, a Pallas kernel),
 `latent_attention` (multi-head latent attention over a pool of one row a
 position: the expanded and the absorbed form, the walk's latent body),
 `delta_rule` (the gated delta rule: chunked for prefill, one step for
-decode) and `experts` (a dropless expert layer that holds a range of the
+decode), `lightning_attention` (linear attention of one constant decay a
+head, in the same two forms), `block_sparse_attention` (attention that
+selects its key blocks from compressed keys of the cache itself: the
+scores, the selection, a step over the chosen pages a key/value head at
+a time) and `experts` (a dropless expert layer that holds a range of the
 routed experts) are imported by the engine's models alone; `moe` is the
 training model's capacity-drop dispatch.
 """

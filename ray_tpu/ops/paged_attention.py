@@ -117,13 +117,16 @@ def kernel_eligible(n_heads: int, head_dim: int,
     """The kernel needs the TPU backend and a pool it reads in whole
     tiles: values that fill the lanes (heads of 64 and the unit tests'
     tiny models take the XLA body; keys may be wider than values, in
-    slots of their own) and 4 key/value heads or a multiple of 8
+    slots of their own) and 2 or 4 key/value heads or a multiple of 8
     (`n_kv_heads` where heads are grouped, else `n_heads`). A multiple
-    of 8 fills a float32 tile's sublanes in a pool of rows; 4 is held by
-    planes (`held_by_planes`), a block's positions on the sublanes, and
-    read by the per-head body. (A pool of rows at 4 heads, which no
-    model declares any more, still goes through the body over rows: the
-    tests hold the two against each other.) 1, 2 and 12 heads would lie
+    of 8 fills a float32 tile's sublanes in a pool of rows; 2 and 4 are
+    held by planes (`held_by_planes`), a block's positions on the
+    sublanes, and read by the per-head body (`_attend_planes`; a model
+    whose key/value heads each select their own pages holds the planes
+    head-major and walks a head at a time, one key/value head a call:
+    `ops/block_sparse_attention.py`). (A pool of rows at 4 heads, which
+    no model declares any more, still goes through the body over rows:
+    the tests hold the two against each other.) 1 and 12 heads would lie
     in whole tiles by planes too and are not taken yet (ROADMAP R0b): at
     12 heads the chip keeps a pool of rows in another layout (it tiles
     the K/V axis instead, so as not to pad 12 to 16), and the compiler
@@ -132,7 +135,7 @@ def kernel_eligible(n_heads: int, head_dim: int,
     pool_heads = n_heads if n_kv_heads is None else n_kv_heads
     return (jax.default_backend() == "tpu"
             and (v_head_dim or head_dim) % 128 == 0
-            and (pool_heads % 8 == 0 or pool_heads == 4)
+            and (pool_heads % 8 == 0 or pool_heads in (2, 4))
             and n_heads % pool_heads == 0)
 
 

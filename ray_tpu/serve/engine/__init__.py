@@ -56,6 +56,7 @@ from ray_tpu.serve.engine.hybrid_model import HybridEngineModel
 from ray_tpu.serve.engine.keye_model import KeyeEngineModel
 from ray_tpu.serve.engine.laguna_model import LagunaEngineModel
 from ray_tpu.serve.engine.mimo_model import MimoEngineModel
+from ray_tpu.serve.engine.minicpm_sala_model import MiniCPMSALAEngineModel
 from ray_tpu.serve.engine.model import TinyLM, TransformerEngineModel
 from ray_tpu.serve.engine.prefix_index import PrefixIndex
 from ray_tpu.serve.engine.scheduler import (EngineConfig,
@@ -68,5 +69,6 @@ __all__ = [
     "EngineStoppedError", "GigaChatEngineModel", "HybridEngineModel",
     "InferenceEngine",
     "KVCacheManager", "KeyeEngineModel", "LagunaEngineModel", "MimoEngineModel",
+    "MiniCPMSALAEngineModel",
     "PrefixIndex", "TinyLM", "TokenStream", "TransformerEngineModel",
 ]

@@ -35,17 +35,12 @@ float32.
 
 from __future__ import annotations
 
-from typing import List, Sequence
-
-import numpy as np
-
-from ray_tpu.core import flight
 from ray_tpu.serve.engine.kv_cache import KVCacheManager
-from ray_tpu.serve.engine.model import _next_pow2, step_tokens
-from ray_tpu.serve.engine.state_model import PromptState, StateEngineModel
+from ray_tpu.serve.engine.model import step_tokens
+from ray_tpu.serve.engine.state_model import StateChunks, StateEngineModel
 
 
-class GigaChatEngineModel(StateEngineModel):
+class GigaChatEngineModel(StateChunks, StateEngineModel):
     """Incremental decoding over `models/gigachat35.py` weights.
 
     KV entry a token: ``[n_mla_layers, P, 128]``, the latent rows. State
@@ -414,77 +409,7 @@ class GigaChatEngineModel(StateEngineModel):
 
         return jax.jit(decode_paged, donate_argnums=(0, 1))
 
-    # -- engine interface ----------------------------------------------
-    def prefill_chunk(self, tokens: Sequence[int], pools: dict,
-                      table: List[int], start: int, block_size: int, *,
-                      meanwhile=None, slot: int = None):
-        """Run positions ``[start, start + prefill_chunk_tokens)`` of the
-        prompt `tokens` (those of them it has), whose positions before
-        `start` are in the latent pool ``pools["global"]``, read through
-        `table` (the sequence's block table as it stands before this
-        chunk's blocks are allocated), and whose state at `start` is in
-        the state pool ``pools["state"]`` at `slot` (the sequence's: None
-        before its first chunk has been stored, when `start` is 0 and
-        nothing is read). `start` is a multiple of the chunk. Returns the
-        host logits that predict the next token for the chunk that holds
-        the prompt's last token, else None, and a `PromptState` of the
-        chunk's latent rows and the state it ended on, on the device, for
-        `write_range(seq, start, ...)`.
-
-        A program of its own between two decode steps; `meanwhile` (the
-        protocol's: `model.py`) runs behind the dispatch, and only the
-        prompt's last chunk is waited for
-        (`sparse_model._prompt_logits`): the state an earlier chunk ended
-        on goes into its slot as the unfinished device value it is.
-        One program a power of two of the prompt's length, and one for
-        every prompt of up to four chunks, as the layer-groups models'."""
-        with flight.span("model", "prefill", len(tokens)):
-            return self._prefill_chunk(tokens, pools, table, start,
-                                       block_size, meanwhile, slot)
-
-    def _prefill_chunk(self, tokens, pools, table, start: int,
-                       block_size: int, meanwhile, slot):
-        phase, c = self.phase, self.prefill_chunk_tokens
-        n = len(tokens)
-        length = min(c, n - start)
-        self.prefill_calls += 1
-        self.prefill_tokens += length
-        if start:
-            self.prefill_later_chunks += 1
-            if slot is None:
-                raise ValueError(
-                    f"a chunk at {start} without its sequence's state slot")
-            self.prefill_state_chunks += 1
-        with flight.span("model", "prefill.prep", None, phase,
-                         "prefill_prep_s"):
-            if start % c or c % block_size:
-                raise ValueError(
-                    f"a chunk of {c} positions at {start} does not lie on "
-                    f"blocks of {block_size}")
-            s_keys = max(_next_pow2(-(-n // c) * c), 4 * c)
-            key = ("chunk", c, s_keys, block_size)
-            fn = self._prefill_jit.get(key)
-            if fn is None:
-                fn = self._prefill_jit[key] = \
-                    self._build_prefill_chunk(*key[2:])
-            nb = s_keys // block_size
-            packed = np.zeros((c + 3 + nb,), np.int32)
-            packed[:length] = np.asarray(tokens[start:start + length],
-                                         np.int32)
-            packed[c], packed[c + 1] = start, length
-            packed[c + 2] = slot or 0
-            packed[c + 3:c + 3 + min(nb, len(table))] = table[:nb]
-        with flight.span("model", "prefill.dispatch", None, phase,
-                         "prefill_dispatch_s"):
-            logits, rows, state = fn(pools[KVCacheManager.GLOBAL],
-                                     pools[KVCacheManager.STATE], self._params,
-                                     packed)
-        self._count_experts_step(c)
-        if meanwhile is not None:
-            meanwhile()
-        logits = self._prompt_logits(logits, start + length == n)
-        return logits, PromptState(rows, length, state)
-
+    # -- engine interface (`prefill_chunk`: `StateChunks`) --------------
     def _count_pages(self, pool, pages, nb_pad: int, positions,
                      block_size: int) -> None:
         """As the base's, by the pages that hold a cached position (what

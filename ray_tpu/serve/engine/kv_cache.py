@@ -1148,6 +1148,14 @@ class KVCacheManager:
                    for pool in self._state.values())
 
     @property
+    def state_stores(self) -> Dict[str, int]:
+        """Bytes of each store of the state pool, by the name the model
+        declared it under (`state_shapes`: a recurrent layer's ``s``, a
+        selecting layer's compressed keys ``ck``, ...)."""
+        return {name: int(np.prod(pool.shape)) * np.dtype(pool.dtype).itemsize
+                for name, pool in (self._state or {}).items()}
+
+    @property
     def rider_bytes(self) -> Dict[str, int]:
         """Bytes of each pool that rides this group's blocks."""
         return {name: int(np.prod(rider.shape))
@@ -1193,4 +1201,5 @@ class KVCacheManager:
                 "state_slots": self.state_slots,
                 "state_slots_in_use": len(self._slots),
                 "state_bytes": self.state_bytes,
+                "state_stores": self.state_stores,
             }

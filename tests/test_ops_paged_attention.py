@@ -621,8 +621,9 @@ def test_grouped_eligibility_follows_the_pool_rows_heads(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert pa.kernel_eligible(64, 128, 8)
     assert pa.kernel_eligible(16, 128) and pa.kernel_eligible(16, 128, 16)
-    assert pa.kernel_eligible(64, 128, 4)      # 4 rows: a tile of (4, 128)
-    assert not pa.kernel_eligible(64, 128, 2)
+    assert pa.kernel_eligible(64, 128, 4)      # by planes, the per-head body
+    assert pa.kernel_eligible(32, 128, 2)      # likewise (and a head at a time)
+    assert not pa.kernel_eligible(64, 128, 1)  # not taken yet: ROADMAP R0b
     assert not pa.kernel_eligible(64, 64, 8)
     assert not pa.kernel_eligible(12, 128, 8)      # no whole groups
 
@@ -1442,3 +1443,70 @@ def test_gigachat_chunk_program_compiles_for_v5e_beside_both_pools(
     assert memory.temp_size_in_bytes < 1.5e9
     # The chunk's latent rows, the state it ended on and the logits.
     assert memory.output_size_in_bytes < 40e6
+
+
+@pytest.mark.parametrize("pages,with_counts_a_head", [(512, True),
+                                                      (256, False)])
+def test_head_walk_compiles_for_v5e_over_a_head_major_pool(
+        one_chip, no_compile_cache, monkeypatch, pages, with_counts_a_head):
+    """The block-selecting model's decode attention at its cell's widths:
+    32 query heads over 2 key/value heads of 128, a head-major planes
+    pool of 24,576 blocks, a row's chosen pages a head (512 at the most)
+    or one table for both: two calls of the paged walk's kernel under
+    its own name, the pool an operand as it stands."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import block_sparse_attention as bsa
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    tables = (8, 2, pages) if with_counts_a_head else (8, pages)
+    counts = (8, 2) if with_counts_a_head else (8,)
+    compiled = jax.jit(bsa.head_walk_attention).lower(
+        spec((8, 32, 128)), spec((8, 2, 128), jnp.bfloat16),
+        spec((8, 2, 128), jnp.bfloat16),
+        spec((24576, 2, 4, 16, 128), jnp.bfloat16),
+        spec(tables, jnp.int32), spec(counts, jnp.int32),
+        spec((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert bsa.DECODE_KERNEL_NAME in text
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
+def test_lightning_step_compiles_for_v5e_in_place_over_the_state_pool(
+        one_chip, no_compile_cache, monkeypatch):
+    """The block-selecting model's six lightning layers' decode steps at
+    its cell's widths (6 slots, 32 heads of 128 x 128 float32: 75 MB of
+    states), one after another over a donated pool: six calls of the
+    step's kernel under its own name, the pool their operand and their
+    result as it stands (no layer copied out or put back)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import lightning_attention as la
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def spec(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def six_layers(pool, q, k, v, g):
+        outs = []
+        for layer in range(6):
+            o, pool = la.lightning_step_in_pool(pool, layer, q, k, v, g)
+            outs.append(o)
+        return jnp.stack(outs), pool
+
+    compiled = jax.jit(six_layers, donate_argnums=(0,)).lower(
+        spec((6, 6, 32, 128, 128)), spec((6, 32, 128)), spec((6, 32, 128)),
+        spec((6, 32, 128)), spec((6, 32))).compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    assert text.count("tpu_custom_call") == 6
+    assert la.STEP_KERNEL_NAME in text
+    assert memory.alias_size_in_bytes >= 6 * 6 * 32 * 128 * 128 * 4
+    assert memory.temp_size_in_bytes < (4 << 20)

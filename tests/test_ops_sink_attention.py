@@ -165,7 +165,8 @@ def test_eligibility_takes_4_key_heads_and_values_of_128(monkeypatch):
     assert pa.kernel_eligible(64, 192, 4, 128)
     assert pa.kernel_eligible(64, 192, 8, 128)
     assert pa.kernel_eligible(48, 128, 8)       # as before
-    assert not pa.kernel_eligible(64, 192, 2, 128)
+    assert pa.kernel_eligible(64, 192, 2, 128)      # 2 heads by planes too
+    assert not pa.kernel_eligible(64, 192, 1, 128)
     assert not pa.kernel_eligible(64, 192, 4, 64)
     assert not pa.kernel_eligible(12, 128)
     monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
